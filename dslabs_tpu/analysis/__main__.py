@@ -13,10 +13,6 @@ if "xla_force_host_platform_device_count" not in xla_flags:
     os.environ["XLA_FLAGS"] = (
         xla_flags + " --xla_force_host_platform_device_count=8").strip()
 
-import jax  # noqa: E402
-
-jax.config.update("jax_platforms", "cpu")
-
 from dslabs_tpu.analysis import main  # noqa: E402
 
 sys.exit(main())

@@ -34,6 +34,13 @@ class SearchResults:
     # degraded one.
     dropped: int = 0
     visited_overflow: int = 0
+    # The tensor engine's own SearchOutcome (None on the object
+    # checker): platform / device_kind the verdict was computed on,
+    # explored / depth / per-level records, recovery counters — and
+    # the seconds a dfs call site's rollout probe took (None = no
+    # probe ran).
+    tensor_outcome = None
+    probe_secs = None
 
     def __init__(self, invariants: List[StatePredicate],
                  goals: List[StatePredicate]):

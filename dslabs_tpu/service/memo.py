@@ -620,13 +620,7 @@ def introspect_child(factory: str, factory_kwargs: Optional[dict],
 
 
 def _introspect_main() -> int:
-    os.environ["JAX_PLATFORMS"] = "cpu"
-    try:
-        import jax
-
-        jax.config.update("jax_platforms", "cpu")
-    except Exception:  # noqa: BLE001 — jax may be absent for pure lint
-        pass
+    os.environ["JAX_PLATFORMS"] = "cpu"         # before jax loads
     spec = json.load(sys.stdin)
     try:
         from dslabs_tpu.service.server import _resolve
@@ -812,7 +806,7 @@ class MemoStore:
             return False
         keep = {k: verdict.get(k) for k in (
             "end", "unique", "explored", "depth", "engine",
-            "predicate", "witness")}
+            "platform", "device_kind", "predicate", "witness")}
         rec = {"t": "memo_verdict",
                "key": verdict_key(fields, max_depth, max_secs),
                "sig": sig_key(fields), "fields": fields,

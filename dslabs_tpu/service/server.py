@@ -13,8 +13,8 @@ in earlier PRs and this module only WIRES them —
   admission child is itself a rejection, never a server stall.
 * **One fault domain per job** (PR 4): accepted jobs run as warden
   children with their own run dir
-  (``<root>/jobs/<job_id>/`` — checkpoint, flight.jsonl, STATUS.json,
-  compile_cache: tpu/checkpoint.py ``run_dir_layout``), heartbeat-
+  (``<root>/jobs/<job_id>/`` — checkpoint, flight.jsonl, STATUS.json:
+  tpu/checkpoint.py ``run_dir_layout``), heartbeat-
   reaped, so one tenant's OOM/hang/crash is a SIGKILL + classified
   death in ITS domain — a neighbor's verdict stays bit-exact (proven
   by the chaos soak in tests/test_service.py).
@@ -144,7 +144,9 @@ class CheckServer:
     """The resident server: bounded persistent queue + admission gate
     + DRR scheduler + per-job warden fault domains.  Thread-safe;
     ``drain`` runs the backlog on ``workers`` worker threads (each job
-    is its own child process tree, so workers only pay coordination).
+    is its own child process tree, so workers only pay coordination;
+    default ONE — each child takes the chip — raise it on a host with
+    N chips).
     """
 
     def __init__(self, root: str,
@@ -183,8 +185,11 @@ class CheckServer:
         # scheduler-level events become flight-log events when one is
         # attached (the bench's service phase does).
         self.telemetry = telemetry
+        # ONE job child at a time by default: every job child takes
+        # the chip, and a chip belongs to one process at a time.
+        # ``workers=N`` / ``--workers N`` is for hosts with N chips.
         self.workers = (workers if workers is not None
-                        else _env_int("DSLABS_SERVICE_WORKERS", 2))
+                        else _env_int("DSLABS_SERVICE_WORKERS", 1))
         if admission is None:
             admission = os.environ.get(
                 "DSLABS_SERVICE_ADMISSION", "1").strip().lower() not in (
@@ -423,6 +428,8 @@ class CheckServer:
             "explored": cached.get("explored"),
             "depth": cached.get("depth"),
             "engine": cached.get("engine"),
+            "platform": cached.get("platform"),
+            "device_kind": cached.get("device_kind"),
             "predicate": cached.get("predicate"),
             "witness": cached.get("witness"),
             "attempts": 0, "failovers": 0, "child_restarts": 0,
@@ -654,6 +661,8 @@ class CheckServer:
                 "explored": out.states_explored,
                 "depth": out.depth,
                 "engine": out.engine,
+                "platform": out.platform,
+                "device_kind": out.device_kind,
                 "predicate": out.predicate_name,
                 "witness": memo_mod.witness_digest(
                     out.predicate_name, out.violating_state,
@@ -775,6 +784,8 @@ class CheckServer:
                 "explored": out.states_explored,
                 "depth": out.depth,
                 "engine": "lanes",
+                "platform": out.platform,
+                "device_kind": out.device_kind,
                 "attempts": 1,
                 "failovers": 0,
                 "child_restarts": out.child_restarts,
@@ -1123,13 +1134,7 @@ def _admission_main() -> int:
     waiver-applied findings as one JSON line.  Any escape is the
     parent's "child died" rejection — a hostile spec cannot get past
     the gate by crashing it."""
-    os.environ["JAX_PLATFORMS"] = "cpu"
-    try:
-        import jax
-
-        jax.config.update("jax_platforms", "cpu")
-    except Exception:  # noqa: BLE001 — jax may be absent for pure lint
-        pass
+    os.environ["JAX_PLATFORMS"] = "cpu"         # before jax loads
     spec = json.load(sys.stdin)
     factory = spec["factory"]
     mod_name = factory.partition(":")[0]

@@ -608,7 +608,7 @@ def tensor_bfs(initial_state, settings=None, _probe_first=False):
 
     settings = settings if settings is not None else SearchSettings()
     binding = resolve_binding(initial_state)
-    trip = None
+    trip = probe_secs = None
     if _probe_first:
         trip, probe_secs = _rollout_probe(binding, settings,
                                           initial_state)
@@ -633,6 +633,8 @@ def tensor_bfs(initial_state, settings=None, _probe_first=False):
     # treat-as-fresh re-exploration) are both 0 on strict runs.
     results.dropped = outcome.dropped
     results.visited_overflow = outcome.visited_overflow
+    results.tensor_outcome = outcome
+    results.probe_secs = probe_secs
     end = outcome.end_condition
     by_name = {p.name: p for p in (settings.invariants + settings.goals)}
     if end == "GOAL_FOUND":

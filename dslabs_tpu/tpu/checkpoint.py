@@ -82,22 +82,8 @@ import numpy as np
 __all__ = ["FORMAT_VERSION", "CheckpointMismatch", "CheckpointCorrupt",
            "SearchCheckpoint", "config_fingerprint", "save", "load",
            "peek_fingerprint", "peek_depth", "AsyncCheckpointWriter",
-           "default_compile_cache_dir", "default_flight_log",
+           "default_flight_log",
            "default_status_path", "run_dir_layout"]
-
-
-def default_compile_cache_dir(checkpoint_path) -> "Optional[str]":
-    """The documented default location of the persistent XLA compile
-    cache (tpu/compile_cache.py) for a checkpointed search: a
-    ``compile_cache/`` directory beside the dump, so a resumable job
-    keeps its compiled programs with its state.  ``None`` when no
-    checkpoint is configured (the env knob ``DSLABS_COMPILE_CACHE``
-    overrides either way)."""
-    if not checkpoint_path:
-        return None
-    return os.path.join(
-        os.path.dirname(os.path.abspath(checkpoint_path)),
-        "compile_cache")
 
 
 def default_flight_log(checkpoint_path) -> "Optional[str]":
@@ -143,7 +129,6 @@ def run_dir_layout(checkpoint_path) -> dict:
     place the layout is defined (docs/observability.md):
 
       checkpoint        the atomic .npz dump (+ ``.prev`` rotation)
-      compile_cache     persistent XLA compile cache (tpu/compile_cache)
       flight_log        telemetry flight recorder (tpu/telemetry.py)
       status            live-monitor STATUS.json (telemetry watch)
       costs             append-only cost ledger (tpu/tracing.py)
@@ -151,7 +136,6 @@ def run_dir_layout(checkpoint_path) -> dict:
     return {
         "checkpoint": checkpoint_path,
         "prev": (checkpoint_path + ".prev") if checkpoint_path else None,
-        "compile_cache": default_compile_cache_dir(checkpoint_path),
         "flight_log": default_flight_log(checkpoint_path),
         "status": default_status_path(checkpoint_path),
         "costs": default_costs_path(checkpoint_path),

@@ -238,6 +238,13 @@ class SearchOutcome:
     failovers: int = 0               # ladder rungs abandoned before this one
     resumed_from_depth: int = 0      # checkpoint depth resumed from (0=root)
     engine: Optional[str] = None     # ladder rung that produced the verdict
+    # The device the verdict was COMPUTED on (``jax.Device.platform`` /
+    # ``.device_kind``), stamped by the engine that ran and carried
+    # through the supervisor, the warden/lane pipes and the service's
+    # verdict records — so a last-rung CPU verdict can always be told
+    # from a chip verdict.
+    platform: Optional[str] = None
+    device_kind: Optional[str] = None
     # Process-isolation accounting (tpu/warden.py): children the warden
     # spawned beyond the first on the way to this verdict, and
     # dispatches SIGKILLed mid-flight after heartbeat silence.  Zero in
@@ -842,13 +849,13 @@ class TensorSearch:
         self.checkpoint_path = checkpoint_path
         self.checkpoint_every = checkpoint_every
         self._resumed_from_depth = 0
-        # Persistent XLA compile cache (tpu/compile_cache.py): the
-        # DSLABS_COMPILE_CACHE knob, defaulting to a compile_cache/
-        # dir beside the checkpoint when one is configured — so the
-        # second run of any config pays near-zero compile.
+        # Persistent XLA compile cache (tpu/compile_cache.py): one
+        # fixed place (JAX_COMPILATION_CACHE_DIR, else
+        # <checkout>/.jax_cache), so the second construction of any
+        # config — in this process or the next — pays near-zero compile.
         from dslabs_tpu.tpu import compile_cache
 
-        compile_cache.setup_for_checkpoint(checkpoint_path)
+        compile_cache.setup()
         self.frontier_cap = frontier_cap
         self.chunk = chunk
         self.max_depth = max_depth
@@ -1324,6 +1331,15 @@ class TensorSearch:
     def _frontier_encoding(self) -> str:
         """The marker dumped with every checkpoint's frontier rows."""
         return "raw" if self._pk is None else self._pk.signature()
+
+    def _stamp_device(self, out: "SearchOutcome") -> "SearchOutcome":
+        """Name the device this engine's programs ran on (the sharded
+        and swarm engines: their mesh's first device)."""
+        mesh = getattr(self, "mesh", None)
+        dev = (mesh.devices.flat[0] if mesh is not None
+               else jax.devices()[0])
+        out.platform, out.device_kind = dev.platform, dev.device_kind
+        return out
 
     def _stamp_capacity(self, out: "SearchOutcome") -> "SearchOutcome":
         """Attach the capacity-round-2 accounting every verdict
@@ -2109,6 +2125,7 @@ class TensorSearch:
             out = self._run_device(check_initial, initial,
                                    resume=resume)
             eng = "device"
+        self._stamp_device(out)
         self._stamp_capacity(out)
         self._stamp_faults(out)
         if tel is not None:

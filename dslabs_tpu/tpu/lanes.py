@@ -482,6 +482,7 @@ class LaneSearch(TensorSearch):
                     error: Optional[str]) -> None:
             if out is not None:
                 out.engine = "lanes"
+                self._stamp_device(out)
                 out.lane = ln.idx if ln is not None else None
                 out.lane_width = L
                 if out.trace_id is None:
@@ -1062,10 +1063,7 @@ def _child_main() -> int:
     _send({"t": "hb", "phase": "boot", "stage": "spawned",
            "grace": boot_g})
     if spec.get("force_cpu"):
-        os.environ["JAX_PLATFORMS"] = "cpu"
-        import jax
-
-        jax.config.update("jax_platforms", "cpu")
+        os.environ["JAX_PLATFORMS"] = "cpu"     # before jax loads
     proto = _resolve(spec["factory"])(**(spec.get("factory_kwargs")
                                          or {}))
     if spec.get("transform"):

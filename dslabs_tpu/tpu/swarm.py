@@ -91,7 +91,7 @@ from typing import List, Optional, Tuple
 import jax
 import jax.numpy as jnp
 import numpy as np
-from jax.experimental.shard_map import shard_map
+from jax import shard_map
 from jax.sharding import NamedSharding, PartitionSpec as P
 
 from dslabs_tpu.tpu import checkpoint as ckpt_mod
@@ -669,11 +669,11 @@ class SwarmSearch(TensorSearch):
             return shard_map(
                 lambda c, b, m: round_local(c, b, m), mesh=self.mesh,
                 in_specs=(spec, P(), (P(), P())),
-                out_specs=(spec, P()), check_rep=False)
+                out_specs=(spec, P()), check_vma=False)
         return shard_map(
             lambda c, b: round_local(c, b), mesh=self.mesh,
             in_specs=(spec, P()), out_specs=(spec, P()),
-            check_rep=False)
+            check_vma=False)
 
     def _round_call(self, carry, budget: int):
         """Dispatch one round through the supervisor seam; the
@@ -756,7 +756,7 @@ class SwarmSearch(TensorSearch):
         fn = jax.jit(shard_map(local, mesh=self.mesh,
                                in_specs=(in_spec,),
                                out_specs=(self._carry_specs(), P(ax)),
-                               check_rep=False))
+                               check_vma=False))
 
         def build(inputs):
             carry, unres = fn(inputs)
@@ -989,7 +989,7 @@ class SwarmSearch(TensorSearch):
         fn = jax.jit(shard_map(local, mesh=self.mesh,
                                in_specs=(in_spec,),
                                out_specs=(self._carry_specs(), P(ax)),
-                               check_rep=False))
+                               check_vma=False))
         with self.mesh:
             carry, unres = fn(dev_in)
         if int(np.asarray(unres).sum()):
@@ -1017,7 +1017,7 @@ class SwarmSearch(TensorSearch):
         if check_initial:
             out = self._check_initial(state, t0)
             if out is not None:
-                return out
+                return self._stamp_device(out)
         try:
             with self.mesh:
                 return self._run_rounds(state, resume)
@@ -1158,6 +1158,7 @@ class SwarmSearch(TensorSearch):
         out.swarm_overflow = sd["overflow_restarts"]
         out.visited_overflow = sd["vis_over"]
         out.compile_secs = round(self.compile_secs, 3)
+        self._stamp_device(out)
         out.resumed_from_depth = getattr(self, "_resumed_from_depth", 0)
         if out.swarm_overflow > OVERFLOW_WARN:
             warnings.warn(

@@ -3,8 +3,8 @@
 Before this module every subsystem emitted its own ad-hoc signals —
 SearchOutcome counters, warden heartbeat lines, bench JSON fragments,
 ``DSLABS_LEVEL_TIMING`` records — and a wedged run left almost nothing
-behind (BENCH_r05 died in preflight with one scraped stderr line to
-explain a 300-second hang).  This is the one observability substrate
+behind (one scraped stderr line to explain a hang).  This is the one
+observability substrate
 they all feed, built on the paper's discipline that **every signal must
 come from scalar readbacks already paid for**: the recorder never adds
 a device dispatch and never reads anything off the device beyond the
@@ -24,8 +24,7 @@ Pieces:
   (tpu/checkpoint.py ``default_flight_log``).  The file is opened
   line-buffered append-only and every dispatch writes a begin marker
   BEFORE the device call, so a SIGKILL'd or wedged run leaves a
-  readable trail whose torn tail names the in-flight dispatch —
-  exactly what the BENCH_r05 shape lacked.
+  readable trail whose torn tail names the in-flight dispatch.
 
 * **Metrics registry.**  Counters / gauges / histograms fed from the
   host scalars the run already holds: per-level fused-stats records
@@ -163,10 +162,12 @@ DISPATCH_SITES = {
                                   program=False),
     "host.expand":           dict(hot=True, donated=False, multi=False,
                                   program=False),
-    # The visited-table bucket-probe kernel (ISSUE 12): Pallas on TPU
-    # (interpret mode off-TPU), jnp oracle otherwise — inlined into
-    # every expanding dispatch, and audited/profiled standalone
-    # through this site (visited.dispatch_site_program).
+    # The visited-table bucket probe/insert (ISSUE 12): the jnp path
+    # on every backend (the Pallas version is written but refused by
+    # Mosaic — scatter — and runs only on an explicit
+    # DSLABS_VISITED_PALLAS request) — inlined into every expanding
+    # dispatch, and audited/profiled standalone through this site
+    # (visited.dispatch_site_program).
     "visited.insert":        dict(hot=True, donated=True, multi=False,
                                   program=True),
     # Batched job lanes (ISSUE 14, tpu/lanes.py): the lane superstep
@@ -1473,7 +1474,7 @@ def read_ledger(path: str) -> List[dict]:
 # JSON's top-level value — the number the BENCH_r0N trajectory tracks).
 _LEDGER_PHASES = ("headline", "mesh", "strict", "beam", "swarm",
                   "spill", "capacity2", "service", "lanes", "memo",
-                  "scenarios", "labs", "cpu_fallback")
+                  "scenarios", "labs")
 
 # Resilience counters the ledger tracks beside the rates (ISSUE 9):
 # a bench run that suddenly needs mesh shrinks / knob re-levels /

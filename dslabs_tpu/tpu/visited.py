@@ -27,19 +27,20 @@ Strict drivers raise :class:`~dslabs_tpu.tpu.engine.CapacityOverflow`
 on a nonzero count (exact unique counts would otherwise drift); beam
 drivers report it via ``SearchOutcome.visited_overflow``.
 
-Pallas kernel (ISSUE 12): the probe/insert — the hot instruction on
-every expanded state — also exists as a Pallas TPU kernel
-(:func:`pallas_insert`) whose body is the SAME traced algorithm as the
-jnp path (:func:`insert_jnp`), so the two are bit-identical by
+Pallas kernel (ISSUE 12): the probe/insert also exists as a Pallas
+kernel (:func:`pallas_insert`) whose body is the SAME traced algorithm
+as the jnp path (:func:`insert_jnp`), so the two are bit-identical by
 construction: same probe order, same reservation tie-breaks, same
-unresolved set.  :func:`insert` dispatches between them by the
-``DSLABS_VISITED_PALLAS`` knob (``auto`` compiles the kernel on TPU
-when the table fits the VMEM budget; ``interpret`` runs the Mosaic
-interpreter — the CPU/test path; ``0`` pins the jnp oracle).  The
-kernel is a canonical dispatch site (``visited.insert`` in
-``telemetry.DISPATCH_SITES``) so the profiler's hot-site selection and
-the jaxpr auditor cover it; :func:`dispatch_site_program` builds the
-audit entry.
+unresolved set.  The TPU compiler (Mosaic) REFUSES the kernel — its
+body scatters, and ``scatter`` has no Pallas TPU lowering — so it is on
+no default path: :func:`insert` resolves to :func:`insert_jnp` unless
+``DSLABS_VISITED_PALLAS`` asks for the kernel by name (``on`` compiles
+it or raises; ``interpret`` runs the Pallas interpreter — the CPU
+parity path the tests use).  An explicit request never degrades.
+``visited.insert`` is a canonical dispatch site
+(``telemetry.DISPATCH_SITES``) so the profiler's hot-site selection and
+the jaxpr auditor cover whichever variant is active;
+:func:`dispatch_site_program` builds the audit entry.
 """
 
 from __future__ import annotations
@@ -257,41 +258,31 @@ def insert_jnp(table: jnp.ndarray, keys: jnp.ndarray, valid: jnp.ndarray,
 
 # ------------------------------------------------- Pallas bucket kernel
 #
-# ISSUE 12 leg (c): the probe/insert as a Pallas TPU kernel.  The body
-# runs the SAME traced algorithm as insert_jnp over the table resident
-# in VMEM (one load, the whole bounded probe on-chip, one aliased
-# store), so jnp-vs-Pallas parity is bit-exact by construction and the
-# jnp path stays the oracle.  Compiled Mosaic only makes sense when the
-# table fits the VMEM budget; bigger tables and non-TPU backends keep
-# the jnp path (interpret mode exists for parity tests and debugging).
+# ISSUE 12 leg (c): the probe/insert as a Pallas kernel.  The body runs
+# the SAME traced algorithm as insert_jnp over the table resident in
+# VMEM, so jnp-vs-Pallas parity is bit-exact by construction and the
+# jnp path stays the oracle.  Mosaic refuses the kernel today (scatter
+# has no Pallas TPU lowering), so it runs only where it is asked for by
+# name: ``on`` compiles it (and raises what the compiler raises),
+# ``interpret`` is the CPU parity path.
 
 def pallas_mode() -> str:
-    """Resolved DSLABS_VISITED_PALLAS knob: ``off`` (jnp oracle) |
-    ``on`` (compiled on TPU, interpreter elsewhere) | ``interpret``
-    (force the Mosaic interpreter — the CPU parity/test path) |
-    ``auto`` (default: compiled on TPU when the table fits the VMEM
-    budget, jnp everywhere else)."""
-    v = os.environ.get("DSLABS_VISITED_PALLAS", "auto").strip().lower()
-    if v in ("0", "off", "false", "no", ""):
+    """Resolved DSLABS_VISITED_PALLAS knob: ``off`` (default: the jnp
+    path) | ``on`` (the compiled kernel — raises off-TPU and whatever
+    the TPU compiler raises; never degrades) | ``interpret`` (the
+    Pallas interpreter — the CPU parity/test path)."""
+    v = os.environ.get("DSLABS_VISITED_PALLAS", "off").strip().lower()
+    if v in ("0", "off", "false", "no", "", "auto"):
         return "off"
     if v == "interpret":
         return "interpret"
     if v in ("1", "on", "true", "yes", "pallas"):
         return "on"
-    return "auto"
+    raise ValueError(
+        f"DSLABS_VISITED_PALLAS={v!r}: expected off | on | interpret")
 
 
-def _pallas_vmem_budget() -> int:
-    """Table-bytes ceiling for the compiled kernel (the table must sit
-    in VMEM beside the key batch); ~half a v5e core's 16 MB."""
-    try:
-        return int(os.environ.get("DSLABS_VISITED_PALLAS_VMEM", "")
-                   or (8 << 20))
-    except ValueError:
-        return 8 << 20
-
-
-def _pallas_interpret(table_bytes: int) -> Optional[bool]:
+def _pallas_interpret() -> Optional[bool]:
     """None = use the jnp path; True/False = pallas_call's interpret
     flag.  Decided at TRACE time (env + backend are trace-stable, so
     rebuilt programs lower identically — the J5 retrace contract)."""
@@ -300,32 +291,28 @@ def _pallas_interpret(table_bytes: int) -> Optional[bool]:
         return None
     if mode == "interpret":
         return True
-    on_tpu = jax.default_backend() == "tpu"
-    fits = table_bytes <= _pallas_vmem_budget()
-    if mode == "on":
-        if not on_tpu:
-            return True          # no Mosaic backend: interpreter
-        return False if fits else None   # over-VMEM tables: jnp path
-    # auto: the compiled kernel only where it is actually the win.
-    if on_tpu and fits:
-        return False
-    return None
+    if jax.default_backend() != "tpu":
+        raise RuntimeError(
+            "DSLABS_VISITED_PALLAS=on asks for the compiled Pallas "
+            f"insert, but the backend is {jax.default_backend()!r}; "
+            "use DSLABS_VISITED_PALLAS=interpret for the interpreter")
+    return False
 
 
 def pallas_insert(table: jnp.ndarray, keys: jnp.ndarray,
-                  valid: jnp.ndarray, max_iters: int = 64,
-                  interpret: Optional[bool] = None,
+                  valid: jnp.ndarray, max_iters: int = 64, *,
+                  interpret: bool,
                   ) -> Tuple[jnp.ndarray, jnp.ndarray, jnp.ndarray]:
     """:func:`insert_jnp` as one Pallas kernel: table + key batch load
     into VMEM, the bounded probe runs on-chip, and the table writes
     back through an input/output alias (the in-place update the
     engines' donated carries rely on).  Same signature and bit-exact
-    results as the jnp path; ``interpret=True`` runs the Mosaic
-    interpreter (the CPU parity path — no TPU hardware needed)."""
+    results as the jnp path; ``interpret=True`` runs the Pallas
+    interpreter (the CPU parity path — no TPU hardware needed);
+    ``interpret=False`` compiles for the TPU, which Mosaic refuses
+    today (scatter)."""
     from jax.experimental import pallas as pl
 
-    if interpret is None:
-        interpret = jax.default_backend() != "tpu"
     n = keys.shape[0]
 
     def kernel(table_ref, keys_ref, valid_ref, out_table_ref,
@@ -361,14 +348,14 @@ def pallas_insert(table: jnp.ndarray, keys: jnp.ndarray,
 def insert(table: jnp.ndarray, keys: jnp.ndarray, valid: jnp.ndarray,
            max_iters: int = 64,
            ) -> Tuple[jnp.ndarray, jnp.ndarray, jnp.ndarray]:
-    """THE probe/insert entry point both engines trace: dispatches to
-    the Pallas kernel per :func:`pallas_mode` (compiled on TPU when the
-    table fits VMEM; interpreter when forced) with :func:`insert_jnp`
-    as the everywhere-else fallback and parity oracle.  Contract and
-    return values are identical across paths (see ``insert_jnp``)."""
+    """THE probe/insert entry point both engines trace:
+    :func:`insert_jnp` by default on every backend; the Pallas kernel
+    only where :func:`pallas_mode` asks for it by name (``on`` compiles
+    or raises, ``interpret`` interprets).  Contract and return values
+    are identical across paths (see ``insert_jnp``)."""
     if _FORCE_JNP:
         return insert_jnp(table, keys, valid, max_iters)
-    interp = _pallas_interpret(int(table.shape[0]) * 16)
+    interp = _pallas_interpret()
     if interp is None:
         return insert_jnp(table, keys, valid, max_iters)
     return pallas_insert(table, keys, valid, max_iters,

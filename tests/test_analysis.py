@@ -448,9 +448,7 @@ def test_j2_float64_upcast():
     def prog(x):
         return x.astype(jnp.float64) * 1.5
     sds = jax.ShapeDtypeStruct((4,), jnp.int32)
-    from jax.experimental import enable_x64
-
-    with enable_x64():
+    with jax.enable_x64():
         found = audit_sites(
             {"device.promote": _entry(jax.jit(prog), (sds,))},
             "Fixture")
@@ -473,10 +471,7 @@ def test_j3_large_carry_not_donated():
 
 def test_j4_collective_in_single_device_program():
     from jax.sharding import Mesh, PartitionSpec as P
-    try:
-        from jax.experimental.shard_map import shard_map
-    except ImportError:                      # pragma: no cover
-        from jax.sharding import shard_map
+    from jax import shard_map
 
     mesh = Mesh(np.array(jax.devices("cpu")[:1]), ("d",))
     fn = jax.jit(shard_map(lambda x: jax.lax.psum(x, "d"), mesh=mesh,

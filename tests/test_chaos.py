@@ -47,7 +47,7 @@ from dslabs_tpu.tpu.warden import Warden  # noqa: E402
 
 pytestmark = pytest.mark.chaos
 
-CHILD_ENV = {"DSLABS_COMPILE_CACHE": "/tmp/jaxcache-cpu"}
+CHILD_ENV = {"JAX_COMPILATION_CACHE_DIR": "/tmp/jaxcache-cpu"}
 
 
 class FatalError(RuntimeError):

@@ -1,0 +1,219 @@
+"""Compile the main path for the chip that is described, not attached.
+
+The TPU's compiler is installed in the CPU-only sandbox and compiles for
+a ``v5e:2x2`` topology that is DESCRIBED: what it refuses here (a kernel
+Mosaic cannot lower, a program that does not fit 16 GB of HBM) costs no
+chip time.  Nothing runs — a compile that passes is not a chip run
+(``chip_smoke.py`` is) — but these guard every later PR for free.
+
+This is the ONLY file that describes a topology, and it does so inside a
+module-scoped fixture: never at import, never in a ``skipif`` or
+``parametrize`` argument, never in ``conftest.py`` — only one process at
+a time may load the TPU's library, and every xdist worker imports every
+test file.  The compiles run in the test's own process, with the
+persistent compile cache switched off around them (an entry compiled
+for a described chip cannot be read back without one).
+
+The flagship whole-program compiles take minutes and are marked
+``slow``; run them with ``-m slow`` (their memory analysis is printed,
+``-s`` shows it).
+"""
+
+import os
+
+import numpy as np
+import pytest
+
+jax = pytest.importorskip("jax")
+import jax.numpy as jnp  # noqa: E402
+from jax.sharding import Mesh, SingleDeviceSharding  # noqa: E402
+
+import chip_smoke  # noqa: E402
+from dslabs_tpu.tpu import backend, kernels, visited  # noqa: E402
+from dslabs_tpu.tpu.sharded import ShardedTensorSearch  # noqa: E402
+
+HBM_BYTES = 15.75 * (1 << 30)     # what the compiler grants on one v5e
+
+
+@pytest.fixture(scope="module")
+def topo():
+    os.environ.setdefault("TPU_LOG_DIR", "disabled")
+    from jax.experimental import topologies
+    from jax.experimental.compilation_cache import \
+        compilation_cache as cc
+
+    try:
+        desc = topologies.get_topology_desc(platform="tpu",
+                                            topology_name="v5e:2x2")
+    except Exception as e:  # noqa: BLE001 — no TPU compiler here
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+    was = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    cc.reset_cache()
+    yield desc
+    jax.config.update("jax_enable_compilation_cache", was)
+    cc.reset_cache()
+
+
+@pytest.fixture(scope="module")
+def one_chip(topo):
+    return SingleDeviceSharding(topo.devices[0])
+
+
+def _mesh(topo, n):
+    return Mesh(np.array(topo.devices[:n]), ("search",))
+
+
+def _insert_args(cap, batch, sharding):
+    return (jax.ShapeDtypeStruct((cap + 1, 4), jnp.uint32,
+                                 sharding=sharding),
+            jax.ShapeDtypeStruct((batch, 4), jnp.uint32,
+                                 sharding=sharding),
+            jax.ShapeDtypeStruct((batch,), jnp.bool_, sharding=sharding))
+
+
+# ------------------------------------------------------------------ kernels
+
+@pytest.mark.parametrize("cap", [1 << 15, 1 << 24],
+                         ids=["cap2^15", "cap2^24-flagship"])
+def test_default_visited_insert_compiles(one_chip, cap, monkeypatch):
+    """``visited.insert`` as the DEFAULT path resolves it (no knob set)
+    compiles for the chip at the small-table size every lab ``dfs``
+    probe uses and at the flagship's 2^24 slots — and is the jnp path,
+    not the Pallas kernel."""
+    monkeypatch.delenv("DSLABS_VISITED_PALLAS", raising=False)
+    fn = jax.jit(lambda t, k, v: visited.insert(t, k, v),
+                 donate_argnums=0)
+    compiled = fn.lower(*_insert_args(cap, 8192, one_chip)).compile()
+    assert "tpu_custom_call" not in compiled.as_text()
+    mem = compiled.memory_analysis()
+    assert (mem.argument_size_in_bytes + mem.temp_size_in_bytes
+            < HBM_BYTES)
+
+
+def test_pallas_insert_is_refused_by_mosaic(one_chip):
+    """The Pallas insert, asked for by name, is REFUSED by the TPU
+    compiler (scatter has no Pallas TPU lowering) — the reason it is on
+    no default path.  When a kernel lands that Mosaic accepts, this
+    test is the one to turn around."""
+    fn = jax.jit(lambda t, k, v: visited.pallas_insert(
+        t, k, v, interpret=False))
+    with pytest.raises(NotImplementedError, match="scatter"):
+        fn.lower(*_insert_args(1 << 15, 4096, one_chip)).compile()
+
+
+def test_fingerprint_kernel_compiles(one_chip):
+    """``kernels.fingerprint_rows(mode="pallas")`` at [8192, 842] — the
+    flagship protocol's lane width — lowers through Mosaic."""
+    x = jax.ShapeDtypeStruct((8192, 842), jnp.int32, sharding=one_chip)
+    compiled = jax.jit(
+        lambda f: kernels.fingerprint_rows(f, mode="pallas")
+    ).lower(x).compile()
+    assert "tpu_custom_call" in compiled.as_text()
+
+
+# ----------------------------------------------------------- whole programs
+
+def _lab1_search(mesh):
+    """The lab 1 client-server twin exactly as ``backend._run_tensor``
+    builds it at the ladder's first rung (runtime masks, trace
+    recording, chunk 512) — on the described mesh."""
+    from dslabs_tpu.search.settings import SearchSettings
+    from dslabs_tpu.testing.predicates import CLIENTS_DONE, RESULTS_OK
+
+    state = chip_smoke._lab1_state(2, 2)
+    settings = (SearchSettings().add_invariant(RESULTS_OK)
+                .add_prune(CLIENTS_DONE))
+    binding = backend.resolve_binding(state)
+    binding.check_settings(settings)
+    net_cap, timer_cap = binding.initial_caps()
+    protocol, marr, tarr = backend._bind_protocol(
+        binding, settings, net_cap, timer_cap)
+    f_cap, v_cap = backend._LADDER[0]
+    search = ShardedTensorSearch(
+        protocol, mesh, chunk_per_device=512, frontier_cap=f_cap,
+        visited_cap=v_cap, strict=True, record_trace=True)
+    search.set_runtime_masks(marr, tarr)
+    return search
+
+
+def _aot(search):
+    """Compile superstep, promote and root init through the seam the
+    engine already has (``aot_warmup`` over ``_carry_sds()``); returns
+    {name: compiled}."""
+    search.aot_warmup()
+    exes = search._aot_exes
+    init = [k for k in exes if isinstance(k, tuple) and k[0] == "init"]
+    assert "superstep" in exes and "promote" in exes and len(init) == 1
+    return {"superstep": exes["superstep"], "promote": exes["promote"],
+            "init": exes[init[0]]}
+
+
+def _fits(exes):
+    for name, exe in exes.items():
+        mem = exe.memory_analysis()
+        # Arguments alias the outputs (donated carry): live bytes are
+        # the larger of the two plus the temporaries.
+        live = (max(mem.argument_size_in_bytes, mem.output_size_in_bytes)
+                + mem.temp_size_in_bytes)
+        print(f"{name}: live={live / 2**30:.2f} GiB {mem}")
+        assert live < HBM_BYTES, (name, mem)
+
+
+@pytest.mark.parametrize("n_devices", [1, 4])
+def test_lab1_programs_compile(topo, n_devices):
+    """The sharded superstep, promote and root init of the lab 1 twin
+    at the lab ladder's first rung, on a one-device and on a
+    four-device mesh of the described chip; the four-device superstep
+    carries the owner-hashed all-to-all."""
+    exes = _aot(_lab1_search(_mesh(topo, n_devices)))
+    _fits(exes)
+    text = exes["superstep"].as_text()
+    assert ("all-to-all" in text) == (n_devices > 1)
+
+
+def _flagship_search(mesh, chunk):
+    from bench import _bench_protocol
+
+    caps = dict(chip_smoke.FLAGSHIP, chunk=chunk)
+    return ShardedTensorSearch(
+        _bench_protocol(), mesh, chunk_per_device=caps["chunk"],
+        frontier_cap=caps["frontier_cap"],
+        visited_cap=caps["visited_cap"], strict=True,
+        ev_budget=chip_smoke.EV_BUDGET)
+
+
+@pytest.mark.slow
+@pytest.mark.parametrize("n_devices", [1, 4])
+def test_flagship_programs_compile(topo, n_devices):
+    """The flagship protocol at chip_smoke's caps: superstep, promote,
+    root init and the bare ``_expand_chunk`` — carry plus temporaries
+    must fit one chip's HBM.  Minutes of compile: ``-m slow``."""
+    search = _flagship_search(_mesh(topo, n_devices),
+                              chip_smoke.FLAGSHIP["chunk"])
+    assert search.lanes == 842
+    # The frontier pair is sized in PACKED words, not 842-lane rows.
+    assert search.plane < search.lanes // 3
+    exes = _aot(search)
+    if n_devices == 1:
+        c = search.cpd
+        sh = SingleDeviceSharding(topo.devices[0])
+        exes["expand_chunk"] = jax.jit(search._expand_chunk).lower(
+            jax.ShapeDtypeStruct((c, search.lanes), jnp.int32,
+                                 sharding=sh),
+            jax.ShapeDtypeStruct((c,), jnp.bool_, sharding=sh)).compile()
+    _fits(exes)
+    assert ("all-to-all" in exes["superstep"].as_text()) == (
+        n_devices > 1)
+
+
+@pytest.mark.slow
+def test_flagship_chunk_8192_does_not_fit(topo):
+    """THE FINDING, pinned: at bench.py's chunk 8192 the compiler
+    refuses the flagship superstep — 42 GB of HBM against 15.75 GB,
+    nearly all of it [chunk*48, 1] uint32 columns padded 128x by the
+    (8, 128) tile.  When this starts compiling, raise chip_smoke's
+    chunk back to bench.py's."""
+    search = _flagship_search(_mesh(topo, 1), 8192)
+    with pytest.raises(Exception, match="RESOURCE_EXHAUSTED"):
+        search.aot_warmup()

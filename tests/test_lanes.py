@@ -32,7 +32,7 @@ pytestmark = pytest.mark.lanes
 
 # Children share the suite's persistent compile cache
 # (tests/conftest.py) or every spawn pays a cold XLA build.
-CHILD_ENV = {"DSLABS_COMPILE_CACHE": "/tmp/jaxcache-cpu"}
+CHILD_ENV = {"JAX_COMPILATION_CACHE_DIR": "/tmp/jaxcache-cpu"}
 
 KW = dict(frontier_cap=1 << 10, chunk=64, visited_cap=1 << 12)
 

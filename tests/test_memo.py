@@ -36,7 +36,7 @@ from dslabs_tpu.tpu import spill as spill_mod
 
 pytestmark = [pytest.mark.service, pytest.mark.memo]
 
-CHILD_ENV = {"DSLABS_COMPILE_CACHE": "/tmp/jaxcache-cpu"}
+CHILD_ENV = {"JAX_COMPILATION_CACHE_DIR": "/tmp/jaxcache-cpu"}
 FACTORY = ("dslabs_tpu.tpu.protocols.pingpong:"
            "make_exhaustive_pingpong")
 SMALL = dict(factory_kwargs={"workload_size": 2}, chunk=64,

@@ -266,17 +266,34 @@ def test_pallas_mode_knob():
     os.environ["DSLABS_VISITED_PALLAS"] = "0"
     try:
         assert visited_mod.pallas_mode() == "off"
-        assert visited_mod._pallas_interpret(1 << 10) is None
+        assert visited_mod._pallas_interpret() is None
     finally:
         os.environ["DSLABS_VISITED_PALLAS"] = "interpret"
     try:
         assert visited_mod.pallas_mode() == "interpret"
-        assert visited_mod._pallas_interpret(1 << 30) is True
+        assert visited_mod._pallas_interpret() is True
     finally:
         del os.environ["DSLABS_VISITED_PALLAS"]
-    # auto on CPU: the jnp oracle (no Mosaic backend to win on).
-    assert visited_mod.pallas_mode() == "auto"
-    assert visited_mod._pallas_interpret(1 << 10) is None
+    # Default on every backend: the jnp path (Mosaic refuses the
+    # kernel, so it is never picked unasked).
+    assert visited_mod.pallas_mode() == "off"
+    assert visited_mod._pallas_interpret() is None
+
+
+def test_pallas_on_never_degrades(monkeypatch):
+    """``DSLABS_VISITED_PALLAS=on`` off-TPU raises: an explicit request
+    for the compiled kernel is never answered by the interpreter or
+    the jnp path."""
+    monkeypatch.setenv("DSLABS_VISITED_PALLAS", "on")
+    assert visited_mod.pallas_mode() == "on"
+    table = visited_mod.empty_table(visited_mod.BKT * 2)
+    keys = jnp.zeros((4, 4), jnp.uint32)
+    valid = jnp.ones((4,), jnp.bool_)
+    with pytest.raises(RuntimeError, match="DSLABS_VISITED_PALLAS=on"):
+        visited_mod.insert(table, keys, valid)
+    monkeypatch.setenv("DSLABS_VISITED_PALLAS", "sometimes")
+    with pytest.raises(ValueError, match="DSLABS_VISITED_PALLAS"):
+        visited_mod.pallas_mode()
 
 
 def test_pallas_engine_parity(monkeypatch):

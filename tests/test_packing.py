@@ -247,10 +247,6 @@ def test_sigkill_mid_packed_run_resume_parity(tmp_path):
     full = TensorSearch(_lab1(), chunk=16, frontier_cap=1 << 11,
                         visited_cap=1 << 14, max_depth=9).run()
     child_src = (
-        "import jax\n"
-        "jax.config.update('jax_platforms', 'cpu')\n"
-        "jax.config.update('jax_compilation_cache_dir',"
-        " '/tmp/jaxcache-cpu')\n"
         "import dataclasses\n"
         "from dslabs_tpu.tpu.engine import TensorSearch\n"
         "from dslabs_tpu.tpu.specs import clientserver_spec\n"
@@ -261,7 +257,7 @@ def test_sigkill_mid_packed_run_resume_parity(tmp_path):
         f" visited_cap=1 << 14, frontier_cap=2048,"
         f" checkpoint_path={pth!r}, checkpoint_every=1).run()\n")
     env = dict(os.environ, JAX_PLATFORMS="cpu",
-               DSLABS_COMPILE_CACHE="/tmp/jaxcache-cpu")
+               JAX_COMPILATION_CACHE_DIR="/tmp/jaxcache-cpu")
     proc = subprocess.Popen(
         [sys.executable, "-c", child_src], env=env,
         cwd=os.path.dirname(os.path.dirname(os.path.abspath(__file__))),

@@ -314,10 +314,6 @@ def test_sigkill_mid_scenario_resume_parity(part_base, tmp_path):
     exact counts."""
     pth = str(tmp_path / "kill.ckpt")
     child_src = (
-        "import jax\n"
-        "jax.config.update('jax_platforms', 'cpu')\n"
-        "jax.config.update('jax_compilation_cache_dir',"
-        " '/tmp/jaxcache-cpu')\n"
         "import dataclasses\n"
         "from dslabs_tpu.tpu.engine import TensorSearch\n"
         "from dslabs_tpu.tpu.specs import paxos_partition_spec\n"
@@ -328,7 +324,7 @@ def test_sigkill_mid_scenario_resume_parity(part_base, tmp_path):
         f" visited_cap={1 << 16}, checkpoint_path={pth!r},"
         " checkpoint_every=1).run()\n")
     env = dict(os.environ, JAX_PLATFORMS="cpu",
-               DSLABS_COMPILE_CACHE="/tmp/jaxcache-cpu")
+               JAX_COMPILATION_CACHE_DIR="/tmp/jaxcache-cpu")
     proc = subprocess.Popen(
         [sys.executable, "-c", child_src], env=env,
         cwd=os.path.dirname(os.path.dirname(os.path.abspath(__file__))),

@@ -43,7 +43,7 @@ pytestmark = pytest.mark.service
 
 # Children are fresh processes: share the suite's persistent compile
 # cache (tests/conftest.py) or every spawn pays a cold XLA build.
-CHILD_ENV = {"DSLABS_COMPILE_CACHE": "/tmp/jaxcache-cpu"}
+CHILD_ENV = {"JAX_COMPILATION_CACHE_DIR": "/tmp/jaxcache-cpu"}
 FACTORY = ("dslabs_tpu.tpu.protocols.pingpong:"
            "make_exhaustive_pingpong")
 SMALL = dict(factory_kwargs={"workload_size": 2}, chunk=64,
@@ -450,7 +450,7 @@ def test_service_cli_submit_status_drain(tmp_path, capsys):
     st = json.loads(capsys.readouterr().out.strip().splitlines()[-1])
     assert rc == 0 and st["queue"]["queue_depth"] == 1
 
-    os.environ.setdefault("DSLABS_COMPILE_CACHE", "/tmp/jaxcache-cpu")
+    os.environ.setdefault("JAX_COMPILATION_CACHE_DIR", "/tmp/jaxcache-cpu")
     rc = main(["drain", "--root", root, "--no-admission",
                "--workers", "1"])
     dr = json.loads(capsys.readouterr().out.strip().splitlines()[-1])

@@ -24,6 +24,42 @@ def _pruned_pingpong():
         pp, goals={}, prunes={"CLIENTS_DONE": pp.goals["CLIENTS_DONE"]})
 
 
+def test_make_mesh_raises_on_too_few_devices(monkeypatch):
+    """``make_mesh(n)`` takes the DEFAULT backend's devices or raises:
+    a CPU mesh never stands in for accelerators that are not there."""
+    n = len(jax.devices())
+    assert make_mesh(n).devices.size == n
+    with pytest.raises(RuntimeError, match=f"need {n + 1} devices"):
+        make_mesh(n + 1)
+    # A one-accelerator default backend asked for four: raise, although
+    # jax.devices("cpu") could have supplied them.
+    real = jax.devices
+
+    def fake(backend=None):
+        return real(backend) if backend else real()[:1]
+
+    monkeypatch.setattr(jax, "devices", fake)
+    with pytest.raises(RuntimeError, match="need 4 devices"):
+        make_mesh(4)
+
+
+def test_outcomes_name_their_platform():
+    """Every verdict says which device computed it — engine, sharded
+    engine, and through the warden's pipe format."""
+    from dslabs_tpu.tpu.warden import outcome_from_dict, outcome_to_dict
+
+    proto = _pruned_pingpong()
+    dev = jax.devices()[0]
+    single = TensorSearch(proto, chunk=64).run()
+    sharded = ShardedTensorSearch(
+        proto, make_mesh(2), chunk_per_device=16, frontier_cap=1 << 8,
+        visited_cap=1 << 10).run()
+    for out in (single, sharded, outcome_from_dict(
+            outcome_to_dict(sharded))):
+        assert out.platform == dev.platform == "cpu"
+        assert out.device_kind == dev.device_kind
+
+
 @pytest.mark.parametrize("strict", [True, False])
 def test_sharded_exhaustive_parity(strict):
     """SPACE_EXHAUSTED verdict and exact unique counts, both with the

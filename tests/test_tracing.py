@@ -38,7 +38,7 @@ FACTORY = ("dslabs_tpu.tpu.protocols.pingpong:"
            "make_exhaustive_pingpong")
 SMALL = dict(factory_kwargs={"workload_size": 2}, chunk=64,
              frontier_cap=1 << 8, visited_cap=1 << 12)
-CHILD_ENV = {"DSLABS_COMPILE_CACHE": "/tmp/jaxcache-cpu"}
+CHILD_ENV = {"JAX_COMPILATION_CACHE_DIR": "/tmp/jaxcache-cpu"}
 GRACES = {"boot_grace": 120.0, "first_grace": 120.0,
           "steady_grace": 30.0, "idle_grace": 60.0, "grace_slack": 1.0}
 

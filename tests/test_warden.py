@@ -43,7 +43,7 @@ pytestmark = pytest.mark.fault
 
 # Children are fresh processes: share the suite's persistent compile
 # cache (tests/conftest.py) or every spawn pays a cold XLA build.
-CHILD_ENV = {"DSLABS_COMPILE_CACHE": "/tmp/jaxcache-cpu"}
+CHILD_ENV = {"JAX_COMPILATION_CACHE_DIR": "/tmp/jaxcache-cpu"}
 
 
 # Module-level so warden children can import them by reference

@@ -34,13 +34,12 @@ import tempfile
 
 sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
 
-import jax
+os.environ["JAX_PLATFORMS"] = "cpu"     # before jax loads
 
-jax.config.update("jax_platforms", "cpu")
-jax.config.update("jax_compilation_cache_dir", "/tmp/jaxcache-cpu")
-jax.config.update("jax_persistent_cache_min_compile_time_secs", 1.0)
-
+from dslabs_tpu.tpu import compile_cache
 from dslabs_tpu.tpu import telemetry as tel_mod
+
+compile_cache.setup()
 
 
 def run_search(run_dir: str):
@@ -229,10 +228,7 @@ def main() -> int:
 
     memo_root = tempfile.mkdtemp(prefix="dslabs_obs_smoke_memo_")
     srv = CheckServer(
-        memo_root, workers=1, admission=False, elastic=False,
-        env={"DSLABS_COMPILE_CACHE":
-             os.environ.get("DSLABS_COMPILE_CACHE",
-                            "/tmp/jaxcache-cpu")})
+        memo_root, workers=1, admission=False, elastic=False)
     job = dict(factory="dslabs_tpu.tpu.protocols.pingpong:"
                        "make_exhaustive_pingpong",
                factory_kwargs={"workload_size": 2}, chunk=64,

@@ -13,8 +13,9 @@ sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
 
 import jax
 
-jax.config.update("jax_compilation_cache_dir", "/tmp/jaxcache")
-jax.config.update("jax_persistent_cache_min_compile_time_secs", 1.0)
+from dslabs_tpu.tpu import compile_cache
+
+compile_cache.setup()
 import jax.numpy as jnp
 
 from dslabs_tpu.tpu.telemetry import Telemetry, render_sites

@@ -12,8 +12,9 @@ import time
 
 import jax
 
-jax.config.update("jax_compilation_cache_dir", "/tmp/jaxcache")
-jax.config.update("jax_persistent_cache_min_compile_time_secs", 1.0)
+from dslabs_tpu.tpu import compile_cache
+
+compile_cache.setup()
 import jax.numpy as jnp
 import numpy as np
 

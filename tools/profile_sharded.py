@@ -8,8 +8,9 @@ import sys
 
 import jax
 
-jax.config.update("jax_compilation_cache_dir", "/tmp/jaxcache")
-jax.config.update("jax_persistent_cache_min_compile_time_secs", 1.0)
+from dslabs_tpu.tpu import compile_cache
+
+compile_cache.setup()
 import numpy as np
 
 from dslabs_tpu.tpu.specs_lab3 import make_paxos_protocol
