@@ -29,7 +29,7 @@ one phase child at a time (a chip belongs to one process):
   finish in its window, the bench reports "TPU runtime wedged" and
   exits non-zero — no CPU number stands in for the device.
 * **Heartbeats on stderr**: phase start/end lines here plus per-level
-  lines from the search children (DSLABS_LEVEL_TIMING) — stderr passes
+  lines from the search children (their recorder's ``on_level``) — stderr passes
   straight through, stdout carries exactly one JSON line.
 * **compile_secs** is measured (the warm-up run) and reported per phase.
 * **Calibration is cached** (/tmp/dslabs_bench_cal.json, keyed by the
@@ -153,6 +153,18 @@ def _phase_telemetry(label: str):
     wins; standalone phase invocations land in the run dir."""
     from dslabs_tpu.tpu.telemetry import Telemetry
 
+    class Heartbeat(Telemetry):
+        """One stderr line a level: the parent's silence watch reads
+        them as heartbeats."""
+
+        def on_level(self, engine, record):
+            super().on_level(engine, record)
+            _hb(f"[level {record.get('depth')}] {engine} "
+                f"dt={record.get('wall', 0.0):.2f}s "
+                f"explored={record.get('explored')} "
+                f"unique={record.get('unique')} "
+                f"next={record.get('next_frontier')}")
+
     path = os.environ.get("DSLABS_BENCH_FLIGHT")
     if not path:
         path = os.path.join(_rundir(), f"{label}.flight.jsonl")
@@ -162,7 +174,7 @@ def _phase_telemetry(label: str):
         pass
     # Telemetry itself degrades to RAM-only recording if even this
     # path is unwritable (summary() then carries flight_error).
-    return Telemetry(flight_log=path, engine_hint=label)
+    return Heartbeat(flight_log=path, engine_hint=label)
 
 
 def _note_wedge(label: str, message: str, watch, flight) -> None:
@@ -1257,8 +1269,7 @@ def _sub(args, child_budget: float, label: str,
         # while it runs, or post-mortem after a kill.
         _hb(f"phase {label}: watch with `python -m "
             f"dslabs_tpu.tpu.telemetry watch {_rundir()}`")
-        env = dict(os.environ, DSLABS_LEVEL_TIMING="1",
-                   DSLABS_BENCH_FLIGHT=flight)
+        env = dict(os.environ, DSLABS_BENCH_FLIGHT=flight)
         proc = subprocess.Popen(
             [sys.executable, os.path.abspath(__file__)] + args,
             stdout=subprocess.PIPE, stderr=subprocess.PIPE, text=True,

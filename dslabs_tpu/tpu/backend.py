@@ -47,6 +47,8 @@ from typing import Any, Callable, Dict, List, Optional, Tuple
 
 import numpy as np
 
+from dslabs_tpu.tpu import telemetry
+
 __all__ = ["NoTensorTwin", "TensorProvenance", "TwinBinding",
            "register_adapter", "tensor_bfs", "tensor_dfs"]
 
@@ -385,27 +387,40 @@ def _run_tensor(binding: TwinBinding, settings, state, chunk=512):
     net_cap, timer_cap = binding.initial_caps()
     mesh = make_mesh(len(jax.devices()))
     last: Optional[Exception] = None
-    # check_settings BEFORE build_protocol: bindings bind settings-
-    # dependent modelling flags there (lab4's live-master-timer /
-    # controller-debris surface) and the protocol shape must reflect
-    # them on the FIRST attempt, not after a capacity retry.
-    binding.check_settings(settings)
+    # Where a call's seconds go, stage by stage (telemetry.PHASES):
+    # each stage below is a phase of the call ``tensor_bfs`` opened.
     for attempt, (f_cap, v_cap) in enumerate(_LADDER):
-        protocol, marr, tarr = _bind_protocol(
-            binding, settings, net_cap << attempt,
-            timer_cap + 2 * attempt)
-        search = ShardedTensorSearch(
-            protocol, mesh, chunk_per_device=chunk, frontier_cap=f_cap,
-            visited_cap=v_cap, strict=True, record_trace=True)
-        # Transient-dispatch retry (tpu/supervisor.py): a preemption or
-        # transient XLA error mid-search retries with backoff instead of
-        # failing the lab test; verdict flow is untouched (semantic
-        # errors like CapacityOverflow pass straight through to the
-        # capacity ladder below).
-        from dslabs_tpu.tpu.supervisor import install_retry
+        with telemetry.phase("entry.bind", attempt=attempt):
+            if attempt == 0:
+                # check_settings BEFORE build_protocol: bindings bind
+                # settings-dependent modelling flags there (lab4's
+                # live-master-timer / controller-debris surface) and the
+                # protocol shape must reflect them on the FIRST attempt,
+                # not after a capacity retry.
+                binding.check_settings(settings)
+            protocol, marr, tarr = _bind_protocol(
+                binding, settings, net_cap << attempt,
+                timer_cap + 2 * attempt)
+        with telemetry.phase("entry.build_engine", attempt=attempt,
+                             frontier_cap=f_cap, visited_cap=v_cap):
+            search = ShardedTensorSearch(
+                protocol, mesh, chunk_per_device=chunk,
+                frontier_cap=f_cap, visited_cap=v_cap, strict=True,
+                record_trace=True)
+            # The caller's recorder, if one is current
+            # (telemetry.use), records this search's dispatches.
+            recorder = telemetry.current()
+            if recorder is not None:
+                recorder.attach(search)
+            # Transient-dispatch retry (tpu/supervisor.py): a preemption
+            # or transient XLA error mid-search retries with backoff
+            # instead of failing the lab test; verdict flow is untouched
+            # (semantic errors like CapacityOverflow pass straight
+            # through to the capacity ladder below).
+            from dslabs_tpu.tpu.supervisor import install_retry
 
-        install_retry(search)
-        search.set_runtime_masks(marr, tarr)
+            install_retry(search)
+            search.set_runtime_masks(marr, tarr)
         rel = None
         if settings.depth_limited():
             rel = settings.max_depth - state.depth
@@ -415,7 +430,8 @@ def _run_tensor(binding: TwinBinding, settings, state, chunk=512):
             # Inside the attempt: a root recorded by a phase that ran at
             # a higher ladder rung can overflow this rung's caps, and
             # must escalate rather than fail the test (ADVICE r4).
-            root, history = binding.derive_root(search, state)
+            with telemetry.phase("entry.derive_root"):
+                root, history = binding.derive_root(search, state)
             if settings.max_time_secs is not None and (
                     rel is None or rel > 2):
                 # Warm-up excludes compile time from the test's time
@@ -425,7 +441,8 @@ def _run_tensor(binding: TwinBinding, settings, state, chunk=512):
                 # phase within 2 levels of its depth limit skips it —
                 # the warm-up WOULD BE the whole search.
                 search.max_depth = 2
-                search.run(initial=root, check_initial=False)
+                with telemetry.phase("entry.warm_run"):
+                    search.run(initial=root, check_initial=False)
             search.max_depth = rel
             if settings.max_time_secs is not None:
                 from dslabs_tpu.utils.flags import GlobalSettings
@@ -434,7 +451,8 @@ def _run_tensor(binding: TwinBinding, settings, state, chunk=512):
                                    * GlobalSettings.time_scale)
             else:
                 search.max_secs = None
-            outcome = search.run(initial=root)
+            with telemetry.phase("entry.search"):
+                outcome = search.run(initial=root)
             return search, outcome, history
         except CapacityOverflow as e:
             last = e
@@ -545,6 +563,9 @@ def _rollout_probe(binding, settings, state):
         from dslabs_tpu.tpu.supervisor import install_retry
 
         install_retry(search)
+        recorder = telemetry.current()
+        if recorder is not None:
+            recorder.attach(search)
         search.set_runtime_masks(marr, tarr)
         root, history = binding.derive_root(search, state)
         budget = 10.0 * GlobalSettings.time_scale
@@ -602,92 +623,105 @@ def _object_minimize_verify(obj, pred, result):
 
 def tensor_bfs(initial_state, settings=None, _probe_first=False):
     """The tensor-strategy analog of search.bfs (Search.java:390-402 via
-    SURVEY §8.1): same inputs, same SearchResults contract."""
+    SURVEY §8.1): same inputs, same SearchResults contract.  One call is
+    one ``entry.tensor_bfs`` (or ``entry.tensor_dfs``) phase whose id
+    every stage and dispatch inside carries (tpu/telemetry.py) — opened
+    by a ``with`` in this frame, not by a wrapper: how long JAX takes to
+    trace and lower the engine's first dispatch depends on the Python
+    stack depth it is made at (PERF.md, PR 25)."""
     from dslabs_tpu.search.results import EndCondition, SearchResults
     from dslabs_tpu.search.settings import SearchSettings
 
     settings = settings if settings is not None else SearchSettings()
     binding = resolve_binding(initial_state)
-    trip = probe_secs = None
-    if _probe_first:
-        trip, probe_secs = _rollout_probe(binding, settings,
-                                          initial_state)
-        if trip is None and settings.max_time_secs is not None:
-            # The probe spends part of the SAME maxTime contract the
-            # object RandomDFS honours — deduct it from the BFS's
-            # budget (on a copy; the caller's settings are theirs).
-            import copy as _copy
+    with telemetry.call("entry.tensor_dfs" if _probe_first
+                        else "entry.tensor_bfs",
+                        key=str(binding.key)[:96]):
+        trip = probe_secs = None
+        if _probe_first:
+            with telemetry.phase("entry.probe"):
+                trip, probe_secs = _rollout_probe(binding, settings,
+                                                  initial_state)
+            if trip is None and settings.max_time_secs is not None:
+                # The probe spends part of the SAME maxTime contract the
+                # object RandomDFS honours — deduct it from the BFS's
+                # budget (on a copy; the caller's settings are theirs).
+                import copy as _copy
 
-            settings = _copy.copy(settings)
-            settings.max_time_secs = max(
-                1.0, settings.max_time_secs - probe_secs)
-    if trip is not None:
-        search, outcome, history = trip
-    else:
-        search, outcome, history = _run_tensor(binding, settings,
-                                               initial_state)
-    results = SearchResults(settings.invariants, settings.goals)
-    results.discovered_count = outcome.unique_states
-    # Degradation stats ride along so exhaust verdicts are auditable:
-    # dropped (beam truncation) and visited_overflow (table-full
-    # treat-as-fresh re-exploration) are both 0 on strict runs.
-    results.dropped = outcome.dropped
-    results.visited_overflow = outcome.visited_overflow
-    results.tensor_outcome = outcome
-    results.probe_secs = probe_secs
-    end = outcome.end_condition
-    by_name = {p.name: p for p in (settings.invariants + settings.goals)}
-    if end == "GOAL_FOUND":
-        obj = _materialize(binding, search, outcome, initial_state,
-                           history)
-        pred = by_name[outcome.predicate_name]
-        r = pred.check(obj)
-        if not r.value:
-            raise NoTensorTwin(
-                f"twin/object divergence: tensor goal "
-                f"{outcome.predicate_name!r} does not hold on the "
-                "replayed object state")
-        results.goal_found(obj, r)
-        results.end_condition = EndCondition.GOAL_FOUND
-    elif end == "INVARIANT_VIOLATED":
-        obj = _materialize(binding, search, outcome, initial_state,
-                           history)
-        pred = by_name[outcome.predicate_name]
-        r = pred.check(obj)
-        if r.value:
-            raise NoTensorTwin(
-                f"twin/object divergence: tensor invariant violation "
-                f"{outcome.predicate_name!r} holds on the replayed "
-                "object state")
+                settings = _copy.copy(settings)
+                settings.max_time_secs = max(
+                    1.0, settings.max_time_secs - probe_secs)
         if trip is not None:
-            # Probe (swarm) witnesses: object-level minimize + replay
-            # verification on top of the tensor-level pipeline the
-            # swarm already ran (outcome.witness).
-            obj, r = _object_minimize_verify(obj, pred, r)
-            if outcome.witness is not None:
-                outcome.witness.object_verified = True
-        results.invariant_violated(obj, r)
-        results.end_condition = EndCondition.INVARIANT_VIOLATED
-    elif end == "EXCEPTION_THROWN":
-        obj = _materialize(binding, search, outcome, initial_state,
-                           history)
-        results.exception_thrown(obj)
-        results.end_condition = EndCondition.EXCEPTION_THROWN
-    else:
-        hit = _sampled_value_recheck(binding, search, outcome, settings,
-                                     initial_state)
-        if hit is not None:
-            obj, pred, r = hit
+            search, outcome, history = trip
+        else:
+            search, outcome, history = _run_tensor(binding, settings,
+                                                   initial_state)
+        results = SearchResults(settings.invariants, settings.goals)
+        results.discovered_count = outcome.unique_states
+        # Degradation stats ride along so exhaust verdicts are auditable:
+        # dropped (beam truncation) and visited_overflow (table-full
+        # treat-as-fresh re-exploration) are both 0 on strict runs.
+        results.dropped = outcome.dropped
+        results.visited_overflow = outcome.visited_overflow
+        results.tensor_outcome = outcome
+        results.probe_secs = probe_secs
+        end = outcome.end_condition
+        by_name = {p.name: p for p in (settings.invariants + settings.goals)}
+        if end == "GOAL_FOUND":
+            with telemetry.phase("entry.replay"):
+                obj = _materialize(binding, search, outcome, initial_state,
+                                   history)
+                pred = by_name[outcome.predicate_name]
+                r = pred.check(obj)
+                if not r.value:
+                    raise NoTensorTwin(
+                        f"twin/object divergence: tensor goal "
+                        f"{outcome.predicate_name!r} does not hold on the "
+                        "replayed object state")
+            results.goal_found(obj, r)
+            results.end_condition = EndCondition.GOAL_FOUND
+        elif end == "INVARIANT_VIOLATED":
+            with telemetry.phase("entry.replay"):
+                obj = _materialize(binding, search, outcome, initial_state,
+                                   history)
+                pred = by_name[outcome.predicate_name]
+                r = pred.check(obj)
+                if r.value:
+                    raise NoTensorTwin(
+                        f"twin/object divergence: tensor invariant violation "
+                        f"{outcome.predicate_name!r} holds on the replayed "
+                        "object state")
+                if trip is not None:
+                    # Probe (swarm) witnesses: object-level minimize + replay
+                    # verification on top of the tensor-level pipeline the
+                    # swarm already ran (outcome.witness).
+                    obj, r = _object_minimize_verify(obj, pred, r)
+                    if outcome.witness is not None:
+                        outcome.witness.object_verified = True
             results.invariant_violated(obj, r)
             results.end_condition = EndCondition.INVARIANT_VIOLATED
-        elif end == "TIME_EXHAUSTED":
-            results.end_condition = EndCondition.TIME_EXHAUSTED
+        elif end == "EXCEPTION_THROWN":
+            with telemetry.phase("entry.replay"):
+                obj = _materialize(binding, search, outcome, initial_state,
+                                   history)
+            results.exception_thrown(obj)
+            results.end_condition = EndCondition.EXCEPTION_THROWN
         else:
-            # SPACE_EXHAUSTED, DEPTH_EXHAUSTED, CAPACITY_EXHAUSTED: the
-            # object checker treats the depth limit as a prune and
-            # reports SPACE_EXHAUSTED (Search.java:222-229).
-            results.end_condition = EndCondition.SPACE_EXHAUSTED
-    return results
+            with telemetry.phase("entry.recheck"):
+                hit = _sampled_value_recheck(binding, search, outcome,
+                                             settings, initial_state)
+            if hit is not None:
+                obj, pred, r = hit
+                results.invariant_violated(obj, r)
+                results.end_condition = EndCondition.INVARIANT_VIOLATED
+            elif end == "TIME_EXHAUSTED":
+                results.end_condition = EndCondition.TIME_EXHAUSTED
+            else:
+                # SPACE_EXHAUSTED, DEPTH_EXHAUSTED, CAPACITY_EXHAUSTED: the
+                # object checker treats the depth limit as a prune and
+                # reports SPACE_EXHAUSTED (Search.java:222-229).
+                results.end_condition = EndCondition.SPACE_EXHAUSTED
+        return results
 
 
 def tensor_dfs(initial_state, settings=None):

@@ -64,6 +64,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from dslabs_tpu.tpu import telemetry as tel_mod
 from dslabs_tpu.tpu import visited as visited_mod
 
 __all__ = ["TensorProtocol", "TensorState", "TensorSearch", "SearchOutcome",
@@ -258,7 +259,7 @@ class SearchOutcome:
     # Structured per-level throughput records from the sharded driver
     # (dicts of depth / chunks / wall / explored / unique /
     # next_frontier) — the bench emits them as its throughput series;
-    # DSLABS_LEVEL_TIMING pretty-prints the same records live.
+    # the ``search.level`` phases carry the same counters in a profile.
     levels: Optional[list] = None
     # Wall seconds spent in explicit AOT compilation (the construction-
     # time .lower().compile() warm-up) — reported SEPARATELY from
@@ -1077,14 +1078,25 @@ class TensorSearch:
         supervisor's fault plan and per-rung counters.  An attached
         telemetry recorder (tpu/telemetry.py) wraps the WHOLE chain —
         hook included — so every dispatch becomes one structured span
-        with zero extra device work."""
+        with zero extra device work.  Recorder or not, the dispatch is
+        a ``dslabs:dispatch.<site>`` annotation in any profile being
+        taken (tpu/telemetry.py ``annotate``: nothing otherwise)."""
         hook = getattr(self, "_dispatch_hook", None)
         tel = getattr(self, "_telemetry", None)
-        if tel is not None:
-            return tel.record_dispatch(self, tag, hook, fn, *args)
-        if hook is None:
-            return fn(*args)
-        return hook(tag, fn, *args)
+        with self._dispatch_note(tag):
+            if tel is not None:
+                return tel.record_dispatch(self, tag, hook, fn, *args)
+            if hook is None:
+                return fn(*args)
+            return hook(tag, fn, *args)
+
+    def _dispatch_note(self, tag: str):
+        """The ``dslabs:dispatch.<site>`` annotation of this search's
+        next dispatch: which one it is, and the level it belongs to."""
+        i = self._dispatch_i = getattr(self, "_dispatch_i", -1) + 1
+        return tel_mod.annotate(
+            "dispatch." + tag.partition(".")[2], i=i,
+            depth=int(getattr(self, "_current_depth", 0) or 0))
 
     def lane_signature(self) -> Optional[str]:
         """The batched-lane packing key (ISSUE 14, tpu/lanes.py): two
@@ -1858,8 +1870,12 @@ class TensorSearch:
                                          ("prune", p.prunes))
                      for name in preds})
 
-        msg_ids, tmr_ids, flt_ids, ev_drops = self._event_tables(
-            chunk_rows, chunk_valid, ev_pass, masks)
+        # The stages below are named in the HLO's metadata
+        # (``dslabs.<scope>``, tpu/telemetry.py DEVICE_SCOPES) so that a
+        # profile can say which stage a device operation belongs to.
+        with tel_mod.device_scope("expand.events"):
+            msg_ids, tmr_ids, flt_ids, ev_drops = self._event_tables(
+                chunk_rows, chunk_valid, ev_pass, masks)
         if stop == "events":
             return _cut(msg_ids, tmr_ids)
         # TWO flat vmaps — one per event kind, each running only its own
@@ -1872,67 +1888,72 @@ class TensorSearch:
         # per-state repeat is a broadcast (XLA fuses it into the reads).
         # Only the HANDLER half is vmapped; the network merge runs as
         # ONE batched transposed program per kind (_batched_tail).
-        rep_m = jnp.repeat(chunk_rows, bm, axis=0)
-        (nodes_m, sends_m, timers_m, exc_m, ok_m,
-         tover_m) = jax.vmap(self._msg_step_raw)(
-            rep_m, jnp.maximum(msg_ids, 0).reshape(-1))
-        rep_t = jnp.repeat(chunk_rows, bt, axis=0)
-        (nodes_t, sends_t, timers_t, exc_t, ok_t,
-         tover_t) = jax.vmap(self._tmr_step_raw)(
-            rep_t, jnp.maximum(tmr_ids, 0).reshape(-1))
+        with tel_mod.device_scope("expand.handlers"):
+            rep_m = jnp.repeat(chunk_rows, bm, axis=0)
+            (nodes_m, sends_m, timers_m, exc_m, ok_m,
+             tover_m) = jax.vmap(self._msg_step_raw)(
+                rep_m, jnp.maximum(msg_ids, 0).reshape(-1))
+            rep_t = jnp.repeat(chunk_rows, bt, axis=0)
+            (nodes_t, sends_t, timers_t, exc_t, ok_t,
+             tover_t) = jax.vmap(self._tmr_step_raw)(
+                rep_t, jnp.maximum(tmr_ids, 0).reshape(-1))
         if stop == "handlers":
             return _cut(nodes_m, sends_m, timers_m, ok_m,
                         nodes_t, sends_t, timers_t, ok_t)
-        rows_m, over_m = self._batched_tail(
-            chunk_rows, c, bm, nodes_m, sends_m, timers_m, exc_m, ok_m,
-            tover_m)
-        val_m = ok_m & (msg_ids >= 0).reshape(-1)
-        rows_t, over_t = self._batched_tail(
-            chunk_rows, c, bt, nodes_t, sends_t, timers_t, exc_t, ok_t,
-            tover_t)
-        val_t = ok_t & (tmr_ids >= 0).reshape(-1)
+        with tel_mod.device_scope("expand.canon"):
+            rows_m, over_m = self._batched_tail(
+                chunk_rows, c, bm, nodes_m, sends_m, timers_m, exc_m,
+                ok_m, tover_m)
+            val_m = ok_m & (msg_ids >= 0).reshape(-1)
+            rows_t, over_t = self._batched_tail(
+                chunk_rows, c, bt, nodes_t, sends_t, timers_t, exc_t,
+                ok_t, tover_t)
+            val_t = ok_t & (tmr_ids >= 0).reshape(-1)
         if stop == "tail":
             return _cut(rows_m, rows_t)
-        # Fault segment (ISSUE 19): no handlers, no sends — _flt_step
-        # returns full successor rows directly, so the pairs skip the
-        # batched merge tail entirely.
-        if has_flt:
-            rep_f = jnp.repeat(chunk_rows, bf, axis=0)
-            rows_f, ok_f, over_f = jax.vmap(self._flt_step)(
-                rep_f, jnp.maximum(flt_ids, 0).reshape(-1))
-            val_f = ok_f & (flt_ids >= 0).reshape(-1)
+        with tel_mod.device_scope("expand.canon"):
+            # Fault segment (ISSUE 19): no handlers, no sends —
+            # _flt_step returns full successor rows directly, so the
+            # pairs skip the batched merge tail entirely.
+            if has_flt:
+                rep_f = jnp.repeat(chunk_rows, bf, axis=0)
+                rows_f, ok_f, over_f = jax.vmap(self._flt_step)(
+                    rep_f, jnp.maximum(flt_ids, 0).reshape(-1))
+                val_f = ok_f & (flt_ids >= 0).reshape(-1)
 
-        widths = [bm, bt] + ([bf] if has_flt else [])
+            widths = [bm, bt] + ([bf] if has_flt else [])
 
-        def _inter(*parts):
-            return jnp.concatenate(
-                [x.reshape((c, w) + x.shape[1:])
-                 for x, w in zip(parts, widths)],
-                axis=1).reshape((c * sum(widths),) + parts[0].shape[1:])
+            def _inter(*parts):
+                return jnp.concatenate(
+                    [x.reshape((c, w) + x.shape[1:])
+                     for x, w in zip(parts, widths)],
+                    axis=1).reshape((c * sum(widths),)
+                                    + parts[0].shape[1:])
 
-        if has_flt:
-            rows = _inter(rows_m, rows_t, rows_f)
-            valids = _inter(val_m, val_t, val_f)
-            overs = _inter(over_m, over_t, over_f)
-        else:
-            rows = _inter(rows_m, rows_t)
-            valids = _inter(val_m, val_t)
-            overs = _inter(over_m, over_t)
-        # Grid event ids for trace spills: timer table entries are
-        # net_cap + t_idx in the flat grid numbering; fault entries
-        # follow at net_cap + NN*T_CAP + f_idx.
-        ev_segs = [msg_ids,
-                   jnp.where(tmr_ids >= 0, p.net_cap + tmr_ids, -1)]
-        if has_flt:
-            tgrid = p.n_nodes * p.timer_cap
-            ev_segs.append(jnp.where(flt_ids >= 0,
-                                     p.net_cap + tgrid + flt_ids, -1))
-        event_ids = jnp.concatenate(ev_segs, axis=1)       # [C, B]
-        overflow = jnp.sum(overs * valids.astype(jnp.int32))
+            if has_flt:
+                rows = _inter(rows_m, rows_t, rows_f)
+                valids = _inter(val_m, val_t, val_f)
+                overs = _inter(over_m, over_t, over_f)
+            else:
+                rows = _inter(rows_m, rows_t)
+                valids = _inter(val_m, val_t)
+                overs = _inter(over_m, over_t)
+            # Grid event ids for trace spills: timer table entries are
+            # net_cap + t_idx in the flat grid numbering; fault entries
+            # follow at net_cap + NN*T_CAP + f_idx.
+            ev_segs = [msg_ids,
+                       jnp.where(tmr_ids >= 0, p.net_cap + tmr_ids, -1)]
+            if has_flt:
+                tgrid = p.n_nodes * p.timer_cap
+                ev_segs.append(jnp.where(flt_ids >= 0,
+                                         p.net_cap + tgrid + flt_ids, -1))
+            event_ids = jnp.concatenate(ev_segs, axis=1)       # [C, B]
+            overflow = jnp.sum(overs * valids.astype(jnp.int32))
         # Symmetry hash step (ISSUE 15b): fingerprints — and through
         # them the sharded owner-hash — key on the canonical orbit
         # representative; the stored rows stay the real states.
-        fp = row_fingerprints(self._canon_rows(rows))
+        with tel_mod.device_scope("fingerprint"):
+            fp = row_fingerprints(self._canon_rows(rows))
         if stop == "fp":
             return _cut(fp, valids)
 
@@ -1940,14 +1961,16 @@ class TensorSearch:
             # In-chunk sort-unique on device: first occurrence of each
             # 128-bit key among valid rows (invalid rows sort last and are
             # never unique).  Cuts host dedup work before any readback.
-            inv = ~valids
-            order = jnp.lexsort((fp[:, 3], fp[:, 2], fp[:, 1], fp[:, 0],
-                                 inv))
-            fps = fp[order]
-            vs = valids[order]
-            first = jnp.ones(fps.shape[0], bool).at[1:].set(
-                jnp.any(fps[1:] != fps[:-1], axis=1))
-            unique = jnp.zeros_like(vs).at[order].set(first & vs)
+            # Scoped with the fingerprints it sorts.
+            with tel_mod.device_scope("fingerprint"):
+                inv = ~valids
+                order = jnp.lexsort((fp[:, 3], fp[:, 2], fp[:, 1],
+                                     fp[:, 0], inv))
+                fps = fp[order]
+                vs = valids[order]
+                first = jnp.ones(fps.shape[0], bool).at[1:].set(
+                    jnp.any(fps[1:] != fps[:-1], axis=1))
+                unique = jnp.zeros_like(vs).at[order].set(first & vs)
         else:
             # Sharded path: the owner-side hash table (and its in-batch
             # key sort) is the dedup authority — the prefilter sort here
@@ -1956,11 +1979,13 @@ class TensorSearch:
             unique = valids
 
         flags = {}
-        succ_states = self.unflatten_rows(rows)    # views for predicates
-        for kind, preds in (("inv", p.invariants), ("goal", p.goals),
-                            ("prune", p.prunes)):
-            for name, fn in preds.items():
-                flags[f"{kind}:{name}"] = jax.vmap(fn)(succ_states) & valids
+        with tel_mod.device_scope("flags"):
+            succ_states = self.unflatten_rows(rows)  # views for predicates
+            for kind, preds in (("inv", p.invariants), ("goal", p.goals),
+                                ("prune", p.prunes)):
+                for name, fn in preds.items():
+                    flags[f"{kind}:{name}"] = (jax.vmap(fn)(succ_states)
+                                               & valids)
         return (rows, valids, fp, unique, overflow, ev_drops, event_ids,
                 flags)
 
@@ -2212,114 +2237,116 @@ class TensorSearch:
             # Live depth for supervision heartbeats (the dispatch
             # observer reads it — tpu/supervisor.py, tpu/warden.py).
             self._current_depth = depth
-            t_lvl = time.time()
-            if self.record_trace:
-                self._levels.append({"parent_rows": parent_rows,
-                                     "event_ids": []})
-            # ---- expand all chunks (device), collect level arrays (host)
-            lvl_states: List[np.ndarray] = []
-            lvl_keys: List[Tuple[np.ndarray, np.ndarray]] = []
-            lvl_pruned: List[np.ndarray] = []
-            lvl_rows: List[np.ndarray] = []
-            ne = self._num_events()
-            for start in range(0, frontier_n, self.chunk):
-                end = min(start + self.chunk, frontier_n)
-                c = end - start
-                pad = self.chunk - c
-                chunk_rows = (jnp.concatenate(
-                    [frontier[start:end],
-                     jnp.repeat(frontier[:1], pad, axis=0)], axis=0)
-                    if pad else frontier[start:end])
-                chunk_valid = jnp.concatenate(
-                    [jnp.ones(c, bool), jnp.zeros(pad, bool)])
-                rt = getattr(self, "_rt_masks", None)
-                (rows_d, valids, fp, unique, overflow, ev_drops, event_ids,
-                 flags) = (self._dispatch("host.expand", self._expand,
-                                          chunk_rows, chunk_valid, 0, rt)
-                           if rt is not None
-                           else self._dispatch("host.expand", self._expand,
-                                               chunk_rows, chunk_valid))
-                if int(overflow):
-                    raise CapacityOverflow(
-                        f"{self.p.name}: net_cap={self.p.net_cap}, "
-                        f"timer_cap={self.p.timer_cap}, or max_live_sends="
-                        f"{self.p.max_live_sends} overflowed at depth "
-                        f"{depth} ({int(overflow)} drops); raise the caps")
-                if int(ev_drops):
-                    raise CapacityOverflow(
-                        f"{self.p.name}: ev_budget={self._ev_slots} < "
-                        f"valid events of some state at depth {depth} "
-                        f"({int(ev_drops)} skipped); raise the budget")
+            with tel_mod.phase("search.level", depth=depth,
+                               explored0=int(explored)) as lvl:
+                t_lvl = time.time()
                 if self.record_trace:
-                    self._levels[-1]["event_ids"].append(
-                        np.asarray(event_ids))
-                np_valids = np.asarray(valids)
-                explored += int(np_valids.sum())
-                if self.p.fault is not None:
-                    self._accum_fault_counts(event_ids, np_valids)
-                np_exc = np.asarray(rows_d[:, -1])
-                out = self._terminal_outcome(
-                    rows_d, np_valids, np_exc, flags, explored,
-                    len(visited[0]), depth, t0,
-                    level_base_row=start * ne)
-                if out is not None:
-                    return out
+                    self._levels.append({"parent_rows": parent_rows,
+                                         "event_ids": []})
+                # ---- expand all chunks (device), collect level arrays (host)
+                lvl_states: List[np.ndarray] = []
+                lvl_keys: List[Tuple[np.ndarray, np.ndarray]] = []
+                lvl_pruned: List[np.ndarray] = []
+                lvl_rows: List[np.ndarray] = []
+                ne = self._num_events()
+                for start in range(0, frontier_n, self.chunk):
+                    end = min(start + self.chunk, frontier_n)
+                    c = end - start
+                    pad = self.chunk - c
+                    chunk_rows = (jnp.concatenate(
+                        [frontier[start:end],
+                         jnp.repeat(frontier[:1], pad, axis=0)], axis=0)
+                        if pad else frontier[start:end])
+                    chunk_valid = jnp.concatenate(
+                        [jnp.ones(c, bool), jnp.zeros(pad, bool)])
+                    rt = getattr(self, "_rt_masks", None)
+                    (rows_d, valids, fp, unique, overflow, ev_drops, event_ids,
+                     flags) = (self._dispatch("host.expand", self._expand,
+                                              chunk_rows, chunk_valid, 0, rt)
+                               if rt is not None
+                               else self._dispatch("host.expand", self._expand,
+                                                   chunk_rows, chunk_valid))
+                    if int(overflow):
+                        raise CapacityOverflow(
+                            f"{self.p.name}: net_cap={self.p.net_cap}, "
+                            f"timer_cap={self.p.timer_cap}, or max_live_sends="
+                            f"{self.p.max_live_sends} overflowed at depth "
+                            f"{depth} ({int(overflow)} drops); raise the caps")
+                    if int(ev_drops):
+                        raise CapacityOverflow(
+                            f"{self.p.name}: ev_budget={self._ev_slots} < "
+                            f"valid events of some state at depth {depth} "
+                            f"({int(ev_drops)} skipped); raise the budget")
+                    if self.record_trace:
+                        self._levels[-1]["event_ids"].append(
+                            np.asarray(event_ids))
+                    np_valids = np.asarray(valids)
+                    explored += int(np_valids.sum())
+                    if self.p.fault is not None:
+                        self._accum_fault_counts(event_ids, np_valids)
+                    np_exc = np.asarray(rows_d[:, -1])
+                    out = self._terminal_outcome(
+                        rows_d, np_valids, np_exc, flags, explored,
+                        len(visited[0]), depth, t0,
+                        level_base_row=start * ne)
+                    if out is not None:
+                        return out
 
-                pruned = np.zeros(len(np_valids), dtype=bool)
-                for name, f in flags.items():
-                    if name.startswith("prune:"):
-                        pruned |= np.asarray(f)
-                # Exception states are terminal even when the search
-                # continues past them (none here: exceptions end the run).
-                keep = np.asarray(unique)
-                if keep.any():
-                    h1, h2 = host_keys(np.asarray(fp))
-                    idxs = np.nonzero(keep)[0]
-                    lvl_keys.append((h1[idxs], h2[idxs]))
-                    lvl_pruned.append(pruned[idxs])
-                    lvl_rows.append(idxs + start * ne)
-                    lvl_states.append(np.asarray(rows_d)[idxs])
+                    pruned = np.zeros(len(np_valids), dtype=bool)
+                    for name, f in flags.items():
+                        if name.startswith("prune:"):
+                            pruned |= np.asarray(f)
+                    # Exception states are terminal even when the search
+                    # continues past them (none here: exceptions end the run).
+                    keep = np.asarray(unique)
+                    if keep.any():
+                        h1, h2 = host_keys(np.asarray(fp))
+                        idxs = np.nonzero(keep)[0]
+                        lvl_keys.append((h1[idxs], h2[idxs]))
+                        lvl_pruned.append(pruned[idxs])
+                        lvl_rows.append(idxs + start * ne)
+                        lvl_states.append(np.asarray(rows_d)[idxs])
 
-            if not lvl_keys:
-                return SearchOutcome("SPACE_EXHAUSTED", explored,
-                                     len(visited[0]), depth,
-                                     time.time() - t0)
+                if not lvl_keys:
+                    return SearchOutcome("SPACE_EXHAUSTED", explored,
+                                         len(visited[0]), depth,
+                                         time.time() - t0)
 
-            # ---- one level-wide dedup (sort-unique + visited membership)
-            h1 = np.concatenate([k[0] for k in lvl_keys])
-            h2 = np.concatenate([k[1] for k in lvl_keys])
-            pruned = np.concatenate(lvl_pruned)
-            rows = np.concatenate(lvl_rows)
-            order = np.lexsort((h2, h1))
-            h1s, h2s = h1[order], h2[order]
-            first = np.ones(len(order), dtype=bool)
-            first[1:] = (h1s[1:] != h1s[:-1]) | (h2s[1:] != h2s[:-1])
-            unique_mask = np.zeros(len(order), dtype=bool)
-            unique_mask[order] = first
-            fresh = unique_mask & ~sorted_member(visited[0], visited[1],
-                                                 h1, h2)
+                # ---- one level-wide dedup (sort-unique + visited membership)
+                h1 = np.concatenate([k[0] for k in lvl_keys])
+                h2 = np.concatenate([k[1] for k in lvl_keys])
+                pruned = np.concatenate(lvl_pruned)
+                rows = np.concatenate(lvl_rows)
+                order = np.lexsort((h2, h1))
+                h1s, h2s = h1[order], h2[order]
+                first = np.ones(len(order), dtype=bool)
+                first[1:] = (h1s[1:] != h1s[:-1]) | (h2s[1:] != h2s[:-1])
+                unique_mask = np.zeros(len(order), dtype=bool)
+                unique_mask[order] = first
+                fresh = unique_mask & ~sorted_member(visited[0], visited[1],
+                                                     h1, h2)
 
-            # ---- merge visited (sorted-merge, stays sorted by (h1, h2))
-            if fresh.any():
-                nk = np.nonzero(fresh)[0]
-                no = np.lexsort((h2[nk], h1[nk]))
-                mh1 = np.concatenate([visited[0], h1[nk][no]])
-                mh2 = np.concatenate([visited[1], h2[nk][no]])
-                mo = np.lexsort((mh2, mh1))
-                visited = (mh1[mo], mh2[mo])
-                self._host_visited = visited
+                # ---- merge visited (sorted-merge, stays sorted by (h1, h2))
+                if fresh.any():
+                    nk = np.nonzero(fresh)[0]
+                    no = np.lexsort((h2[nk], h1[nk]))
+                    mh1 = np.concatenate([visited[0], h1[nk][no]])
+                    mh2 = np.concatenate([visited[1], h2[nk][no]])
+                    mo = np.lexsort((mh2, mh1))
+                    visited = (mh1[mo], mh2[mo])
+                    self._host_visited = visited
 
-            expand = fresh & ~pruned
-            if not expand.any():
-                return SearchOutcome("SPACE_EXHAUSTED", explored,
-                                     len(visited[0]), depth,
-                                     time.time() - t0)
+                expand = fresh & ~pruned
+                if not expand.any():
+                    return SearchOutcome("SPACE_EXHAUSTED", explored,
+                                         len(visited[0]), depth,
+                                         time.time() - t0)
 
-            keep_idx = np.nonzero(expand)[0]
+                keep_idx = np.nonzero(expand)[0]
+                lvl.set(explored=explored, unique=int(len(visited[0])),
+                        next_frontier=int(len(keep_idx)))
             tel = getattr(self, "_telemetry", None)
             if tel is not None:
-                from dslabs_tpu.tpu import telemetry as tel_mod
-
                 delta = [explored - getattr(self, "_host_prev_explored",
                                             0)]
                 self._host_prev_explored = explored
@@ -2806,94 +2833,96 @@ class TensorSearch:
             depth += 1
             # Live depth for supervision heartbeats (tpu/warden.py).
             self._current_depth = depth
-            t_wave = time.time()
-            # A checkpoint-due wave skips the speculative next-wave
-            # dispatch: the snapshot must see the carry at a clean wave
-            # boundary, not mid-way through wave depth+1.
-            ckpt_due = bool(self.checkpoint_path and self.checkpoint_every
-                            and depth % self.checkpoint_every == 0)
-            for _ in range(n_chunks - spec):
-                carry, sdev = self._dispatch("device.step", step,
-                                             carry, rt)
-            if spill:
-                while True:
-                    s = self._dispatch("device.sync", device_get, sdev)
-                    if int(s[6]) >= n_chunks:
-                        break
-                    for _ in range(n_chunks - int(s[6])):
-                        carry, sdev = self._dispatch("device.step", step,
-                                                     carry, rt)
-                carry = self._dispatch("device.promote", promote, carry)
-                spec = 0
-            else:
-                # Double-buffering: the next wave's promotion AND its
-                # first chunk dispatch BEFORE this wave's scalars are
-                # read, so host bookkeeping overlaps device compute.  A
-                # terminal/empty wave makes the speculative chunk a
-                # no-op (flags keep first-hit; empty frontier expands
-                # nothing) — the readback below still reports wave k.
-                # Single-chunk waves skip the speculation: the chunk
-                # would BE the whole next wave, and on termination it is
-                # a full expand wasted (the measured 20% overhead on
-                # small search spaces).  When the wave's last chunk WAS
-                # last wave's speculative dispatch (n_chunks == spec),
-                # its stats vector is already in hand.
-                wave_stats = sdev
-                carry = self._dispatch("device.promote", promote, carry)
-                if n_chunks > 1 and not ckpt_due:
+            with tel_mod.phase("search.level", depth=depth,
+                               explored0=int(last[0])) as lvl:
+                t_wave = time.time()
+                # A checkpoint-due wave skips the speculative next-wave
+                # dispatch: the snapshot must see the carry at a clean wave
+                # boundary, not mid-way through wave depth+1.
+                ckpt_due = bool(self.checkpoint_path and self.checkpoint_every
+                                and depth % self.checkpoint_every == 0)
+                for _ in range(n_chunks - spec):
                     carry, sdev = self._dispatch("device.step", step,
                                                  carry, rt)
-                    spec = 1
-                else:
+                if spill:
+                    while True:
+                        s = self._dispatch("device.sync", device_get, sdev)
+                        if int(s[6]) >= n_chunks:
+                            break
+                        for _ in range(n_chunks - int(s[6])):
+                            carry, sdev = self._dispatch("device.step", step,
+                                                         carry, rt)
+                    carry = self._dispatch("device.promote", promote, carry)
                     spec = 0
-                s = self._dispatch("device.sync", device_get, wave_stats)
-            (explored, overflow, vis_over, f_drop, vis_n,
-             nxt_n) = (int(x) for x in s[:6])
-            nf = len(self._flag_names)
-            flag_counts = np.asarray(s[7:7 + nf])
-            if self.p.fault is not None and self._ev_flt > 0:
-                # Cumulative from the carry — overwrite, never add.
-                self._fault_counts[:] = np.asarray(
-                    s[7 + nf:7 + nf + 4])
-            if overflow:
-                raise CapacityOverflow(
-                    f"{p.name}: net_cap={p.net_cap}, timer_cap="
-                    f"{p.timer_cap}, or max_live_sends={p.max_live_sends} "
-                    f"overflowed at depth {depth} ({overflow} drops); "
-                    "raise the caps")
-            # Early-warning instrumentation (ISSUE 6 satellite): table
-            # pressure is visible BEFORE the overflow contract fires.
-            limit = (3 * self.visited_cap // 4 if self.strict
-                     else self.visited_cap)
-            if (not getattr(self, "_warned_visited", False)
-                    and vis_n >= int(_visited_warn() * limit)):
-                self._warned_visited = True
-                import warnings
+                else:
+                    # Double-buffering: the next wave's promotion AND its
+                    # first chunk dispatch BEFORE this wave's scalars are
+                    # read, so host bookkeeping overlaps device compute.  A
+                    # terminal/empty wave makes the speculative chunk a
+                    # no-op (flags keep first-hit; empty frontier expands
+                    # nothing) — the readback below still reports wave k.
+                    # Single-chunk waves skip the speculation: the chunk
+                    # would BE the whole next wave, and on termination it is
+                    # a full expand wasted (the measured 20% overhead on
+                    # small search spaces).  When the wave's last chunk WAS
+                    # last wave's speculative dispatch (n_chunks == spec),
+                    # its stats vector is already in hand.
+                    wave_stats = sdev
+                    carry = self._dispatch("device.promote", promote, carry)
+                    if n_chunks > 1 and not ckpt_due:
+                        carry, sdev = self._dispatch("device.step", step,
+                                                     carry, rt)
+                        spec = 1
+                    else:
+                        spec = 0
+                    s = self._dispatch("device.sync", device_get, wave_stats)
+                (explored, overflow, vis_over, f_drop, vis_n,
+                 nxt_n) = (int(x) for x in s[:6])
+                nf = len(self._flag_names)
+                flag_counts = np.asarray(s[7:7 + nf])
+                if self.p.fault is not None and self._ev_flt > 0:
+                    # Cumulative from the carry — overwrite, never add.
+                    self._fault_counts[:] = np.asarray(
+                        s[7 + nf:7 + nf + 4])
+                if overflow:
+                    raise CapacityOverflow(
+                        f"{p.name}: net_cap={p.net_cap}, timer_cap="
+                        f"{p.timer_cap}, or max_live_sends={p.max_live_sends} "
+                        f"overflowed at depth {depth} ({overflow} drops); "
+                        "raise the caps")
+                # Early-warning instrumentation (ISSUE 6 satellite): table
+                # pressure is visible BEFORE the overflow contract fires.
+                limit = (3 * self.visited_cap // 4 if self.strict
+                         else self.visited_cap)
+                if (not getattr(self, "_warned_visited", False)
+                        and vis_n >= int(_visited_warn() * limit)):
+                    self._warned_visited = True
+                    import warnings
 
-                warnings.warn(
-                    f"{p.name}: visited table at {vis_n}/"
-                    f"{self.visited_cap} at depth {depth} — capacity "
-                    "pressure; raise visited_cap or enable the spill "
-                    "tier (spill=True / DSLABS_SPILL=1) before this "
-                    "becomes CapacityOverflow",
-                    RuntimeWarning, stacklevel=2)
-            if vis_over and self.strict:
-                raise CapacityOverflow(
-                    f"{p.name}: visited table full at depth {depth} "
-                    f"({vis_over} unresolved keys, cap "
-                    f"{self.visited_cap}); raise visited_cap or run "
-                    "strict=False for sound treat-as-fresh degradation")
-            if self.strict and vis_n > 3 * self.visited_cap // 4:
-                raise CapacityOverflow(
-                    f"{p.name}: visited table > 75% full "
-                    f"({vis_n}/{self.visited_cap}) at depth {depth}; "
-                    "raise visited_cap")
-            prev_explored = last[0]
-            last = (explored, vis_n, vis_over)
+                    warnings.warn(
+                        f"{p.name}: visited table at {vis_n}/"
+                        f"{self.visited_cap} at depth {depth} — capacity "
+                        "pressure; raise visited_cap or enable the spill "
+                        "tier (spill=True / DSLABS_SPILL=1) before this "
+                        "becomes CapacityOverflow",
+                        RuntimeWarning, stacklevel=2)
+                if vis_over and self.strict:
+                    raise CapacityOverflow(
+                        f"{p.name}: visited table full at depth {depth} "
+                        f"({vis_over} unresolved keys, cap "
+                        f"{self.visited_cap}); raise visited_cap or run "
+                        "strict=False for sound treat-as-fresh degradation")
+                if self.strict and vis_n > 3 * self.visited_cap // 4:
+                    raise CapacityOverflow(
+                        f"{p.name}: visited table > 75% full "
+                        f"({vis_n}/{self.visited_cap}) at depth {depth}; "
+                        "raise visited_cap")
+                prev_explored = last[0]
+                last = (explored, vis_n, vis_over)
+                lvl.set(explored=explored, unique=vis_n,
+                        chunks=int(n_chunks), next_frontier=int(nxt_n))
             tel = getattr(self, "_telemetry", None)
             if tel is not None:
-                from dslabs_tpu.tpu import telemetry as tel_mod
-
                 # Fed from the wave's fused stats vector — scalars this
                 # loop just read anyway (zero extra transfers).  The
                 # per-device lanes are length-1 on the single-device
@@ -3008,8 +3037,10 @@ class TensorSearch:
         progs = self._spill_progs(cap)
         fn = progs["fp"].get(m)
         if fn is None:
-            fn = progs["fp"][m] = jax.jit(
-                lambda r: fingerprint_rows(self._canon_rows(r)))
+            def spill_fingerprints(r):
+                return fingerprint_rows(self._canon_rows(r))
+
+            fn = progs["fp"][m] = jax.jit(spill_fingerprints)
         pad = np.zeros((m, rows.shape[1]), np.int32)
         pad[:n] = rows
         return np.asarray(fn(jnp.asarray(pad)))[:n]
@@ -3275,56 +3306,58 @@ class TensorSearch:
                 return out
             depth += 1
             self._current_depth = depth
-            t_lvl = time.time()
-            # ---- expand the level: cur, then every spooled segment of
-            # the same level as deferred re-expansion waves.
-            while True:
-                carry, s = self._spill_wave(carry, step, rt, cap, n_cur)
-                explored, overflow = int(s[0]), int(s[1])
-                vis_over, vis_n, nxt_n = int(s[2]), int(s[4]), int(s[5])
-                nf = len(self._flag_names)
-                flag_counts = np.asarray(s[7:7 + nf])
-                if self.p.fault is not None and self._ev_flt > 0:
-                    self._fault_counts[:] = np.asarray(
-                        s[7 + nf:7 + nf + 4])
-                if overflow:
-                    raise CapacityOverflow(
-                        f"{p.name}: net_cap={p.net_cap}, timer_cap="
-                        f"{p.timer_cap}, or max_live_sends="
-                        f"{p.max_live_sends} overflowed at depth "
-                        f"{depth} ({overflow} drops); raise the caps")
-                if vis_over:
-                    raise AssertionError(
-                        "spill mode committed unresolved keys (abort "
-                        "contract violated)")
-                unique = sp.unique(vis_n)
-                if flag_counts.any():
-                    out = self._dev_terminal(carry, flag_counts,
-                                             explored, unique, depth,
-                                             t0, 0)
-                    sp.attach(out)
-                    return out
-                load = vis_n / self.visited_cap
-                if load >= warn_at and not getattr(
-                        self, "_warned_visited", False):
-                    self._warned_visited = True
-                    import warnings
+            with tel_mod.phase("search.level", depth=depth,
+                               explored0=int(explored)) as lvl:
+                t_lvl = time.time()
+                # ---- expand the level: cur, then every spooled segment of
+                # the same level as deferred re-expansion waves.
+                while True:
+                    carry, s = self._spill_wave(carry, step, rt, cap, n_cur)
+                    explored, overflow = int(s[0]), int(s[1])
+                    vis_over, vis_n, nxt_n = int(s[2]), int(s[4]), int(s[5])
+                    nf = len(self._flag_names)
+                    flag_counts = np.asarray(s[7:7 + nf])
+                    if self.p.fault is not None and self._ev_flt > 0:
+                        self._fault_counts[:] = np.asarray(
+                            s[7 + nf:7 + nf + 4])
+                    if overflow:
+                        raise CapacityOverflow(
+                            f"{p.name}: net_cap={p.net_cap}, timer_cap="
+                            f"{p.timer_cap}, or max_live_sends="
+                            f"{p.max_live_sends} overflowed at depth "
+                            f"{depth} ({overflow} drops); raise the caps")
+                    if vis_over:
+                        raise AssertionError(
+                            "spill mode committed unresolved keys (abort "
+                            "contract violated)")
+                    unique = sp.unique(vis_n)
+                    if flag_counts.any():
+                        out = self._dev_terminal(carry, flag_counts,
+                                                 explored, unique, depth,
+                                                 t0, 0)
+                        sp.attach(out)
+                        return out
+                    load = vis_n / self.visited_cap
+                    if load >= warn_at and not getattr(
+                            self, "_warned_visited", False):
+                        self._warned_visited = True
+                        import warnings
 
-                    warnings.warn(
-                        f"{p.name}: visited table at "
-                        f"{load:.0%} of visited_cap="
-                        f"{self.visited_cap} at depth {depth} — "
-                        "capacity pressure; the spill tier will evict "
-                        f"at {sp.config.high_water:.0%}",
-                        RuntimeWarning, stacklevel=2)
-                seg = sp.pop_current()
-                if seg is None:
-                    break
-                carry, n_cur = self._spill_inject(carry, seg, cap)
+                        warnings.warn(
+                            f"{p.name}: visited table at "
+                            f"{load:.0%} of visited_cap="
+                            f"{self.visited_cap} at depth {depth} — "
+                            "capacity pressure; the spill tier will evict "
+                            f"at {sp.config.high_water:.0%}",
+                            RuntimeWarning, stacklevel=2)
+                    seg = sp.pop_current()
+                    if seg is None:
+                        break
+                    carry, n_cur = self._spill_inject(carry, seg, cap)
+                lvl.set(explored=explored, unique=unique,
+                        next_frontier=int(nxt_n))
             tel = getattr(self, "_telemetry", None)
             if tel is not None:
-                from dslabs_tpu.tpu import telemetry as tel_mod
-
                 # Per-level record WITH the spill-overlap wall split
                 # (ISSUE 15c satellite): drain_wall = host seconds in
                 # drain jobs this level, drain_wait = seconds the

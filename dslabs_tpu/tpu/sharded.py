@@ -60,6 +60,7 @@ import numpy as np
 from jax import shard_map
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
+from dslabs_tpu.tpu import telemetry as tel_mod
 from dslabs_tpu.tpu import visited as visited_mod
 from dslabs_tpu.tpu.engine import (CapacityOverflow, SearchOutcome,
                                    TensorProtocol, TensorSearch,
@@ -86,8 +87,6 @@ OVERFLOW_FACTOR = 2
 # wave loop (engine.py _run_device).
 MAXU32 = visited_mod.MAXU32
 BKT = visited_mod.BKT
-# Dev: print per-level wall time / chunk rate from run().
-_LEVEL_TIMING = bool(os.environ.get("DSLABS_LEVEL_TIMING"))
 
 
 # ------------------------------------------------------- carry placement
@@ -383,7 +382,7 @@ class ShardedTensorSearch(TensorSearch):
         # (per-readback latency: not measured on this machine).
         nf = len(self._flag_names)
 
-        def stats(carry):
+        def level_stats(carry):
             return jnp.concatenate([
                 jnp.asarray([
                     jnp.sum(carry["overflow"]),
@@ -413,7 +412,7 @@ class ShardedTensorSearch(TensorSearch):
                 carry["drops"].astype(jnp.int32),
             ])
 
-        self._stats = jax.jit(stats)
+        self._stats = jax.jit(level_stats)
 
         # Explicit AOT warm-up (ISSUE 3): .lower().compile() the hot
         # programs at construction so compile wall-time is measured
@@ -560,12 +559,18 @@ class ShardedTensorSearch(TensorSearch):
             cur, cur_n = carry["cur"], carry["cur_n"][0]
             j = carry["j"][0]
             start = j * C
-            rows_chunk = jax.lax.dynamic_slice(cur, (start, 0), (C, plane))
-            base_cur = self._base_vec(carry["pb_cur"]) if delta else None
-            if pk is not None:
-                # In-register decode at expand time: the frontier shard
-                # stores packed words, the expansion grid wants lanes.
-                rows_chunk = pk.unpack_jnp(rows_chunk, base_cur)
+            # Stage names for a profile (tpu/telemetry.py
+            # DEVICE_SCOPES): HLO metadata only.
+            with tel_mod.device_scope("pack"):
+                rows_chunk = jax.lax.dynamic_slice(cur, (start, 0),
+                                                   (C, plane))
+                base_cur = (self._base_vec(carry["pb_cur"]) if delta
+                            else None)
+                if pk is not None:
+                    # In-register decode at expand time: the frontier
+                    # shard stores packed words, the expansion grid
+                    # wants lanes.
+                    rows_chunk = pk.unpack_jnp(rows_chunk, base_cur)
             valid = (start + jnp.arange(C)) < cur_n
             ev_pass = carry["evp"][0]
             (rows, valids, fp, unique, overflow, ev_rem, event_ids,
@@ -589,13 +594,14 @@ class ShardedTensorSearch(TensorSearch):
                 # event id — spilled to host per level for fp-chain
                 # reconstruction (the sharded analog of the base
                 # engine's per-level (parent, event) spill).
-                fp_par = row_fingerprints(rows_chunk)          # [C, 4]
-                ne_slots = self._num_events()
-                meta = jnp.concatenate([
-                    fp,
-                    jnp.repeat(fp_par, ne_slots, axis=0),
-                    event_ids.reshape(-1, 1).astype(jnp.uint32),
-                ], axis=1)                                     # [C*B, 9]
+                with tel_mod.device_scope("trace_meta"):
+                    fp_par = row_fingerprints(rows_chunk)      # [C, 4]
+                    ne_slots = self._num_events()
+                    meta = jnp.concatenate([
+                        fp,
+                        jnp.repeat(fp_par, ne_slots, axis=0),
+                        event_ids.reshape(-1, 1).astype(jnp.uint32),
+                    ], axis=1)                                 # [C*B, 9]
             if stop_after in ("events", "handlers", "tail", "fp",
                               "expand"):
                 # The engine-internal stages already truncated inside
@@ -604,140 +610,147 @@ class ShardedTensorSearch(TensorSearch):
                 return _stopped(carry, rows, fp, unique,
                                 jnp.asarray([overflow]))
 
-            # ---- terminal flags, checkState order (exception first)
-            hit_list = [valids & (rows[:, -1] != 0)]
-            for n in p.invariants:
-                hit_list.append(valids & ~flags[f"inv:{n}"])
-            for n in p.goals:
-                hit_list.append(flags[f"goal:{n}"])
-            hits = jnp.stack(hit_list)                       # [nf, C*E]
-            cnts = jnp.sum(hits, axis=1).astype(jnp.int32)
-            idxs = jnp.argmax(hits, axis=1)
-            new_rows_f = rows[idxs]                          # [nf, lanes]
-            fresh_flag = (carry["flag_cnt"] == 0) & (cnts > 0)
-            flag_rows = jnp.where(fresh_flag[:, None], new_rows_f,
-                                  carry["flag_rows"])
-            flag_cnt = carry["flag_cnt"] + cnts
-            if self.record_trace:
-                flag_meta = jnp.where(fresh_flag[:, None], meta[idxs],
-                                      carry["flag_meta"])
-
-            pruned = rows[:, -1] != 0
-            for n in p.prunes:
-                pruned = pruned | flags[f"prune:{n}"]
-
-            # ---- encode the successor batch ONCE: the same packed rows
-            # ride the owner-hashed all_to_all (the ~pack_ratio x ICI
-            # cut) AND the nxt store.  Out-of-domain values (a wrong
-            # Field bound, or a delta value past its window) are counted
-            # on LIVE rows only and folded into the semantic-overflow
-            # counter — _sync_checks raises the loud CapacityOverflow.
-            pack_bad = jnp.int32(0)
-            if pk is not None:
-                rows_store, bad = pk.pack_jnp(rows, base_cur,
-                                              count_bad=True)
-                pack_bad = jnp.sum(
-                    jnp.where(valids, bad, 0)).astype(jnp.int32)
-            else:
-                rows_store = rows
-            if delta:
-                # Candidate next-level base: per-lane min of the live
-                # successors' delta values, pmin'd across the mesh so
-                # every device carries the identical base and the
-                # promote re-encode needs no collective.  The min over
-                # ALL live successors (pruned included) is a lower
-                # bound of the stored subset — a valid (just possibly
-                # looser) base.
-                dvals = rows[:, jnp.asarray(self._delta_lanes)]
-                dvals = jnp.where(valids[:, None], dvals,
-                                  jnp.int32(self._PB_EMPTY))
-                cand = jnp.min(dvals, axis=0).astype(jnp.int32)
-                pb_nxt = jax.lax.pmin(
-                    jnp.minimum(carry["pb_nxt"], cand), ax)
-
-            # ---- ownership routing: exchange FINGERPRINTS ONLY, never
-            # state rows.  Successor rows stay on the device that produced
-            # them; owners deduplicate the 16-byte keys and return a fresh
-            # flag via a second (reverse) all_to_all.  Any cross-row
-            # permutation of the [B, lanes] successor matrix — gather or
-            # scatter — measured ~2 GB/s effective (137 ms per chunk, 80%
-            # of the level step) in the round-2 bisection, and the key
-            # exchange also cuts ICI traffic by the full lane width
-            # (1354 lanes -> 4).  Successors sorted by owner form
-            # contiguous segments, so the [D, bucket] key buckets are
-            # narrow gathers at segment offsets.
-            owner = (fp[:, 0] % jnp.uint32(D)).astype(jnp.int32)
-            owner = jnp.where(unique, owner, D)     # non-unique -> nowhere
-            order = jnp.argsort(owner, stable=True)
-            owner_s = owner[order]
-            dev = jnp.arange(D)
-            starts = jnp.searchsorted(owner_s, dev, side="left")
-            ends = jnp.searchsorted(owner_s, dev, side="right")
-            src = starts[:, None] + jnp.arange(bucket)[None, :]  # [D, bkt]
-            send_valid = src < ends[:, None]
-            gidx = order[src.clip(0, owner.shape[0] - 1)]  # [D, bkt] row idx
-            send_keys = fp[gidx.reshape(-1)].reshape(D, bucket, 4)
-            counts = ends - starts
-            route_drop = jnp.sum(jnp.maximum(counts - bucket, 0)).astype(
-                jnp.int32)
-            if route_rows:
-                # Fused row exchange (ISSUE 12): the successor ROW,
-                # its pruned flag, and (in trace mode) its meta ride
-                # the SAME owner buckets as the keys — one extra
-                # all_to_all per chunk lands every fresh state on its
-                # OWNER's frontier shard as it is produced.  The
-                # reverse fresh-flag exchange and the promote-boundary
-                # rebalance (the per-level wide row movement + its
-                # compaction scatter) both disappear; the level
-                # promote shrinks to a local buffer swap
-                # (_build_finish).
-                parts = [rows_store, pruned[:, None].astype(jnp.int32)]
+            with tel_mod.device_scope("flags"):
+                # ---- terminal flags, checkState order (exception first)
+                hit_list = [valids & (rows[:, -1] != 0)]
+                for n in p.invariants:
+                    hit_list.append(valids & ~flags[f"inv:{n}"])
+                for n in p.goals:
+                    hit_list.append(flags[f"goal:{n}"])
+                hits = jnp.stack(hit_list)                       # [nf, C*E]
+                cnts = jnp.sum(hits, axis=1).astype(jnp.int32)
+                idxs = jnp.argmax(hits, axis=1)
+                new_rows_f = rows[idxs]                          # [nf, lanes]
+                fresh_flag = (carry["flag_cnt"] == 0) & (cnts > 0)
+                flag_rows = jnp.where(fresh_flag[:, None], new_rows_f,
+                                      carry["flag_rows"])
+                flag_cnt = carry["flag_cnt"] + cnts
                 if self.record_trace:
-                    parts.append(jax.lax.bitcast_convert_type(
-                        meta, jnp.int32))
-                payload = jnp.concatenate(parts, axis=1)
-                send_rows = payload[gidx.reshape(-1)].reshape(
-                    D, bucket, payload.shape[1])
+                    flag_meta = jnp.where(fresh_flag[:, None], meta[idxs],
+                                          carry["flag_meta"])
+
+                pruned = rows[:, -1] != 0
+                for n in p.prunes:
+                    pruned = pruned | flags[f"prune:{n}"]
+
+            with tel_mod.device_scope("pack"):
+                # ---- encode the successor batch ONCE: the same packed rows
+                # ride the owner-hashed all_to_all (the ~pack_ratio x ICI
+                # cut) AND the nxt store.  Out-of-domain values (a wrong
+                # Field bound, or a delta value past its window) are counted
+                # on LIVE rows only and folded into the semantic-overflow
+                # counter — _sync_checks raises the loud CapacityOverflow.
+                pack_bad = jnp.int32(0)
+                if pk is not None:
+                    rows_store, bad = pk.pack_jnp(rows, base_cur,
+                                                  count_bad=True)
+                    pack_bad = jnp.sum(
+                        jnp.where(valids, bad, 0)).astype(jnp.int32)
+                else:
+                    rows_store = rows
+                if delta:
+                    # Candidate next-level base: per-lane min of the live
+                    # successors' delta values, pmin'd across the mesh so
+                    # every device carries the identical base and the
+                    # promote re-encode needs no collective.  The min over
+                    # ALL live successors (pruned included) is a lower
+                    # bound of the stored subset — a valid (just possibly
+                    # looser) base.
+                    dvals = rows[:, jnp.asarray(self._delta_lanes)]
+                    dvals = jnp.where(valids[:, None], dvals,
+                                      jnp.int32(self._PB_EMPTY))
+                    cand = jnp.min(dvals, axis=0).astype(jnp.int32)
+                    pb_nxt = jax.lax.pmin(
+                        jnp.minimum(carry["pb_nxt"], cand), ax)
+
+            with tel_mod.device_scope("route"):
+                # ---- ownership routing: exchange FINGERPRINTS ONLY, never
+                # state rows.  Successor rows stay on the device that produced
+                # them; owners deduplicate the 16-byte keys and return a fresh
+                # flag via a second (reverse) all_to_all.  Any cross-row
+                # permutation of the [B, lanes] successor matrix — gather or
+                # scatter — measured ~2 GB/s effective (137 ms per chunk, 80%
+                # of the level step) in the round-2 bisection, and the key
+                # exchange also cuts ICI traffic by the full lane width
+                # (1354 lanes -> 4).  Successors sorted by owner form
+                # contiguous segments, so the [D, bucket] key buckets are
+                # narrow gathers at segment offsets.
+                owner = (fp[:, 0] % jnp.uint32(D)).astype(jnp.int32)
+                owner = jnp.where(unique, owner, D)     # non-unique -> nowhere
+                order = jnp.argsort(owner, stable=True)
+                owner_s = owner[order]
+                dev = jnp.arange(D)
+                starts = jnp.searchsorted(owner_s, dev, side="left")
+                ends = jnp.searchsorted(owner_s, dev, side="right")
+                src = (starts[:, None]
+                       + jnp.arange(bucket)[None, :])       # [D, bkt]
+                send_valid = src < ends[:, None]
+                # [D, bkt] row idx
+                gidx = order[src.clip(0, owner.shape[0] - 1)]
+                send_keys = fp[gidx.reshape(-1)].reshape(D, bucket, 4)
+                counts = ends - starts
+                route_drop = jnp.sum(jnp.maximum(counts - bucket, 0)).astype(
+                    jnp.int32)
+                if route_rows:
+                    # Fused row exchange (ISSUE 12): the successor ROW,
+                    # its pruned flag, and (in trace mode) its meta ride
+                    # the SAME owner buckets as the keys — one extra
+                    # all_to_all per chunk lands every fresh state on its
+                    # OWNER's frontier shard as it is produced.  The
+                    # reverse fresh-flag exchange and the promote-boundary
+                    # rebalance (the per-level wide row movement + its
+                    # compaction scatter) both disappear; the level
+                    # promote shrinks to a local buffer swap
+                    # (_build_finish).
+                    parts = [rows_store, pruned[:, None].astype(jnp.int32)]
+                    if self.record_trace:
+                        parts.append(jax.lax.bitcast_convert_type(
+                            meta, jnp.int32))
+                    payload = jnp.concatenate(parts, axis=1)
+                    send_rows = payload[gidx.reshape(-1)].reshape(
+                        D, bucket, payload.shape[1])
             if stop_after == "route":
                 return _stopped(carry, rows, send_keys, send_valid)
 
-            # ---- the exchange: every device receives the key bucket
-            # destined to it from every other device (ICI all_to_all)
-            recv_keys = jax.lax.all_to_all(send_keys, ax, 0, 0)
-            recv_valid = jax.lax.all_to_all(send_valid, ax, 0, 0)
-            rb = D * bucket
-            recv_keys = jnp.where(recv_valid.reshape(rb, 1),
-                                  recv_keys.reshape(rb, 4), MAXU32)
-            recv_valid = recv_valid.reshape(rb)
-            if route_rows:
-                recv_rows = jax.lax.all_to_all(
-                    send_rows, ax, 0, 0).reshape(rb, -1)
+            with tel_mod.device_scope("exchange"):
+                # ---- the exchange: every device receives the key bucket
+                # destined to it from every other device (ICI all_to_all)
+                recv_keys = jax.lax.all_to_all(send_keys, ax, 0, 0)
+                recv_valid = jax.lax.all_to_all(send_valid, ax, 0, 0)
+                rb = D * bucket
+                recv_keys = jnp.where(recv_valid.reshape(rb, 1),
+                                      recv_keys.reshape(rb, 4), MAXU32)
+                recv_valid = recv_valid.reshape(rb)
+                if route_rows:
+                    recv_rows = jax.lax.all_to_all(
+                        send_rows, ax, 0, 0).reshape(rb, -1)
             if stop_after == "a2a":
                 return _stopped(carry, rows, recv_keys, recv_valid)
 
-            # ---- owner-side dedup via the SHARED open-addressing hash
-            # table (dslabs_tpu/tpu/visited.py — one implementation for
-            # this driver and the single-device device-resident loop).
-            # The recv batch may hold the same key several times (from
-            # different producers, or in-chunk duplicates when the
-            # prefilter is off); the table's per-bucket reservation
-            # guarantees exactly one copy ever inserts.  Bucket index
-            # comes from lane 2 (b_hi), NOT lane 0: ownership routing
-            # already fixed lane0 ≡ device (mod D), so a lane0-derived
-            # home bucket would cluster every owned key into 1/D of the
-            # table (visited.py keys buckets by lane 2 for this reason).
-            #
-            # Probe exhaustion (table effectively full) leaves keys
-            # UNRESOLVED: per the visited.py contract they are treated
-            # as FRESH (sound — re-explored, never silently dropped) and
-            # counted into the vis_over flag, which _sync_checks raises
-            # on in strict mode and reports via
-            # SearchOutcome.visited_overflow in beam mode.
-            new_visited, ins_s, unres_s = visited_mod.insert(
-                carry["visited"], recv_keys, recv_valid)
-            fresh_s = ins_s | unres_s
-            vis_over = jnp.sum(unres_s).astype(jnp.int32)
-            n_fresh = jnp.sum(ins_s).astype(jnp.int32)
+            with tel_mod.device_scope("visited_insert"):
+                # ---- owner-side dedup via the SHARED open-addressing hash
+                # table (dslabs_tpu/tpu/visited.py — one implementation for
+                # this driver and the single-device device-resident loop).
+                # The recv batch may hold the same key several times (from
+                # different producers, or in-chunk duplicates when the
+                # prefilter is off); the table's per-bucket reservation
+                # guarantees exactly one copy ever inserts.  Bucket index
+                # comes from lane 2 (b_hi), NOT lane 0: ownership routing
+                # already fixed lane0 ≡ device (mod D), so a lane0-derived
+                # home bucket would cluster every owned key into 1/D of the
+                # table (visited.py keys buckets by lane 2 for this reason).
+                #
+                # Probe exhaustion (table effectively full) leaves keys
+                # UNRESOLVED: per the visited.py contract they are treated
+                # as FRESH (sound — re-explored, never silently dropped) and
+                # counted into the vis_over flag, which _sync_checks raises
+                # on in strict mode and reports via
+                # SearchOutcome.visited_overflow in beam mode.
+                new_visited, ins_s, unres_s = visited_mod.insert(
+                    carry["visited"], recv_keys, recv_valid)
+                fresh_s = ins_s | unres_s
+                vis_over = jnp.sum(unres_s).astype(jnp.int32)
+                n_fresh = jnp.sum(ins_s).astype(jnp.int32)
             if stop_after == "probe":
                 out = _stopped(carry, rows, fresh_s, unres_s)
                 out["visited"] = new_visited
@@ -749,29 +762,31 @@ class ShardedTensorSearch(TensorSearch):
                 # placement — the distribution the per-device skew
                 # lanes judge).  No flag needs to travel back to the
                 # producer, so the reverse all_to_all is gone.
-                app_rows = recv_rows[:, :plane]
-                app_pruned = recv_rows[:, plane] != 0
-                app_fresh = fresh_s            # implies recv_valid
-                if self.record_trace:
-                    app_meta = jax.lax.bitcast_convert_type(
-                        recv_rows[:, plane + 1:], jnp.uint32)
+                with tel_mod.device_scope("append"):
+                    app_rows = recv_rows[:, :plane]
+                    app_pruned = recv_rows[:, plane] != 0
+                    app_fresh = fresh_s        # implies recv_valid
+                    if self.record_trace:
+                        app_meta = jax.lax.bitcast_convert_type(
+                            recv_rows[:, plane + 1:], jnp.uint32)
                 if stop_after == "back":
                     out = _stopped(carry, rows, app_fresh, app_pruned)
                     out["visited"] = new_visited
                     return out
             else:
-                # ---- return each key's fresh flag to its producer
-                # (reverse all_to_all — an involution on the leading
-                # axis; recv order was never permuted) and map it back
-                # onto the producer's local successor rows.  Narrow
-                # bool scatters only; `.max` (boolean or) so the
-                # clipped dump writes of invalid slots can never
-                # clobber a true flag.
-                fresh_back = jax.lax.all_to_all(
-                    fresh_s.reshape(D, bucket), ax, 0, 0)
-                fresh_rows = jnp.zeros(owner.shape[0], bool).at[
-                    gidx.reshape(-1)].max(
-                    fresh_back.reshape(-1) & send_valid.reshape(-1))
+                with tel_mod.device_scope("exchange"):
+                    # ---- return each key's fresh flag to its producer
+                    # (reverse all_to_all — an involution on the leading
+                    # axis; recv order was never permuted) and map it back
+                    # onto the producer's local successor rows.  Narrow
+                    # bool scatters only; `.max` (boolean or) so the
+                    # clipped dump writes of invalid slots can never
+                    # clobber a true flag.
+                    fresh_back = jax.lax.all_to_all(
+                        fresh_s.reshape(D, bucket), ax, 0, 0)
+                    fresh_rows = jnp.zeros(owner.shape[0], bool).at[
+                        gidx.reshape(-1)].max(
+                        fresh_back.reshape(-1) & send_valid.reshape(-1))
                 if stop_after == "back":
                     out = _stopped(carry, rows, fresh_rows)
                     out["visited"] = new_visited
@@ -782,95 +797,96 @@ class ShardedTensorSearch(TensorSearch):
                 if self.record_trace:
                     app_meta = meta
 
-            # ---- append fresh, un-pruned successors (producer order
-            # under the legacy exchange, owner-received order under the
-            # fused row exchange — BFS level semantics are order-free)
-            # to the local next frontier.
-            # noapp (set by run() for the FINAL depth-limited level):
-            # fresh states still count into vis_n/flags — discovered,
-            # checked, never expanded — but skip the frontier append, so
-            # a last level D times larger than frontier_cap needs no
-            # frontier memory (the depth limit ends the search exactly as
-            # DEPTH_EXHAUSTED would; the reference's BFS likewise never
-            # queues states at the cutoff depth).
-            noapp = carry["noapp"][0] == 1
-            sel_would = app_fresh & ~app_pruned
-            # Spill mode appends pruned-but-fresh rows too: every fresh
-            # insert must reach the host refilter (the drain recomputes
-            # the prune/exception mask before anything re-expands), or
-            # a post-eviction re-discovery of a pruned state would
-            # double-count.  noapp counting stays on sel_would — the
-            # DEPTH-vs-SPACE decision is about expandable successors.
-            sel = (app_fresh if spill_on else sel_would) & ~noapp
-            spos = jnp.cumsum(sel) - 1
-            nxt, nxt_n = carry["nxt"], carry["nxt_n"][0]
-            sdst = jnp.where(sel & (nxt_n + spos < F), nxt_n + spos, F)
-            nxt = nxt.at[sdst].set(app_rows)
-            n_sel = jnp.sum(sel).astype(jnp.int32)
-            frontier_drop = jnp.maximum(nxt_n + n_sel - F, 0)
-            # Occupancy counts only rows that actually landed (<= F), else
-            # the next level's chunk loop would re-expand the tail.
-            n_sel = n_sel - frontier_drop
+            with tel_mod.device_scope("append"):
+                # ---- append fresh, un-pruned successors (producer order
+                # under the legacy exchange, owner-received order under the
+                # fused row exchange — BFS level semantics are order-free)
+                # to the local next frontier.
+                # noapp (set by run() for the FINAL depth-limited level):
+                # fresh states still count into vis_n/flags — discovered,
+                # checked, never expanded — but skip the frontier append, so
+                # a last level D times larger than frontier_cap needs no
+                # frontier memory (the depth limit ends the search exactly as
+                # DEPTH_EXHAUSTED would; the reference's BFS likewise never
+                # queues states at the cutoff depth).
+                noapp = carry["noapp"][0] == 1
+                sel_would = app_fresh & ~app_pruned
+                # Spill mode appends pruned-but-fresh rows too: every fresh
+                # insert must reach the host refilter (the drain recomputes
+                # the prune/exception mask before anything re-expands), or
+                # a post-eviction re-discovery of a pruned state would
+                # double-count.  noapp counting stays on sel_would — the
+                # DEPTH-vs-SPACE decision is about expandable successors.
+                sel = (app_fresh if spill_on else sel_would) & ~noapp
+                spos = jnp.cumsum(sel) - 1
+                nxt, nxt_n = carry["nxt"], carry["nxt_n"][0]
+                sdst = jnp.where(sel & (nxt_n + spos < F), nxt_n + spos, F)
+                nxt = nxt.at[sdst].set(app_rows)
+                n_sel = jnp.sum(sel).astype(jnp.int32)
+                frontier_drop = jnp.maximum(nxt_n + n_sel - F, 0)
+                # Occupancy counts only rows that actually landed (<= F), else
+                # the next level's chunk loop would re-expand the tail.
+                n_sel = n_sel - frontier_drop
 
-            out = {
-                "cur": cur, "cur_n": carry["cur_n"],
-                "j": j_next, "evp": evp_next, "noapp": carry["noapp"],
-                # On a noapp level nxt_n counts the WOULD-BE appends
-                # (rows themselves are skipped, no frontier-cap drops):
-                # run() reads it to tell DEPTH_EXHAUSTED (successors
-                # remained) from SPACE_EXHAUSTED (space ended exactly at
-                # the depth limit) — the base engine's verdict for the
-                # same boundary (engine.py run(): not lvl_keys).
-                "nxt": nxt, "nxt_n": carry["nxt_n"].at[0].add(
-                    jnp.where(noapp,
-                              jnp.sum(sel_would).astype(jnp.int32),
-                              n_sel)),
-                "visited": new_visited,
-                "vis_n": carry["vis_n"].at[0].add(n_fresh),
-                "explored": carry["explored"].at[0].add(
-                    jnp.sum(valids).astype(jnp.int32)),
-                # Semantic overflow (net/timer caps) corrupts state
-                # contents — always fatal.  Capacity drops (routing
-                # bucket, frontier cap) only truncate *expansion
-                # coverage* (beam-style) and are tolerable when the
-                # caller opts in (bench throughput runs).  A full
-                # visited table is its own flag (vis_over): sound
-                # treat-as-fresh degradation, fatal only in strict.
-                "overflow": carry["overflow"].at[0].add(
-                    overflow + pack_bad),
-                "vis_over": carry["vis_over"].at[0].add(vis_over),
-                # ev_drops (valid events past the ev_budget) truncate
-                # expansion coverage like a routing/frontier drop: fatal
-                # in strict mode (via _sync_checks), beam-tolerable else.
-                "drops": carry["drops"].at[0].add(
-                    route_drop + frontier_drop + ev_drops),
-                "flag_cnt": flag_cnt, "flag_rows": flag_rows,
-            }
-            if self.record_trace:
-                # Trace meta rides the SAME append scatter as the rows.
-                out["tmeta"] = carry["tmeta"].at[sdst].set(app_meta)
-                out["flag_meta"] = flag_meta
-            if delta:
-                out["pb_cur"] = carry["pb_cur"]
-                out["pb_nxt"] = pb_nxt
-            if spill_on:
-                front_full = (nxt_n + jnp.sum(sel).astype(jnp.int32)
-                              ) > F
-                tbl_full = jnp.any(unres_s)
-                fa = jax.lax.psum(front_full.astype(jnp.int32), ax) > 0
-                tb = jax.lax.psum(tbl_full.astype(jnp.int32), ax) > 0
-                abort = fa | tb
-                code = fa.astype(jnp.int32) + 2 * tb.astype(jnp.int32)
-                revert = ["j", "evp", "nxt", "nxt_n", "visited",
-                          "vis_n", "explored", "overflow", "vis_over",
-                          "drops", "flag_cnt", "flag_rows"]
+                out = {
+                    "cur": cur, "cur_n": carry["cur_n"],
+                    "j": j_next, "evp": evp_next, "noapp": carry["noapp"],
+                    # On a noapp level nxt_n counts the WOULD-BE appends
+                    # (rows themselves are skipped, no frontier-cap drops):
+                    # run() reads it to tell DEPTH_EXHAUSTED (successors
+                    # remained) from SPACE_EXHAUSTED (space ended exactly at
+                    # the depth limit) — the base engine's verdict for the
+                    # same boundary (engine.py run(): not lvl_keys).
+                    "nxt": nxt, "nxt_n": carry["nxt_n"].at[0].add(
+                        jnp.where(noapp,
+                                  jnp.sum(sel_would).astype(jnp.int32),
+                                  n_sel)),
+                    "visited": new_visited,
+                    "vis_n": carry["vis_n"].at[0].add(n_fresh),
+                    "explored": carry["explored"].at[0].add(
+                        jnp.sum(valids).astype(jnp.int32)),
+                    # Semantic overflow (net/timer caps) corrupts state
+                    # contents — always fatal.  Capacity drops (routing
+                    # bucket, frontier cap) only truncate *expansion
+                    # coverage* (beam-style) and are tolerable when the
+                    # caller opts in (bench throughput runs).  A full
+                    # visited table is its own flag (vis_over): sound
+                    # treat-as-fresh degradation, fatal only in strict.
+                    "overflow": carry["overflow"].at[0].add(
+                        overflow + pack_bad),
+                    "vis_over": carry["vis_over"].at[0].add(vis_over),
+                    # ev_drops (valid events past the ev_budget) truncate
+                    # expansion coverage like a routing/frontier drop: fatal
+                    # in strict mode (via _sync_checks), beam-tolerable else.
+                    "drops": carry["drops"].at[0].add(
+                        route_drop + frontier_drop + ev_drops),
+                    "flag_cnt": flag_cnt, "flag_rows": flag_rows,
+                }
+                if self.record_trace:
+                    # Trace meta rides the SAME append scatter as the rows.
+                    out["tmeta"] = carry["tmeta"].at[sdst].set(app_meta)
+                    out["flag_meta"] = flag_meta
                 if delta:
-                    revert.append("pb_nxt")
-                for k in revert:
-                    out[k] = jnp.where(abort, carry[k], out[k])
-                out["f_full"] = jnp.where(abort, code,
-                                          jnp.int32(0))[None]
-            return out
+                    out["pb_cur"] = carry["pb_cur"]
+                    out["pb_nxt"] = pb_nxt
+                if spill_on:
+                    front_full = (nxt_n + jnp.sum(sel).astype(jnp.int32)
+                                  ) > F
+                    tbl_full = jnp.any(unres_s)
+                    fa = jax.lax.psum(front_full.astype(jnp.int32), ax) > 0
+                    tb = jax.lax.psum(tbl_full.astype(jnp.int32), ax) > 0
+                    abort = fa | tb
+                    code = fa.astype(jnp.int32) + 2 * tb.astype(jnp.int32)
+                    revert = ["j", "evp", "nxt", "nxt_n", "visited",
+                              "vis_n", "explored", "overflow", "vis_over",
+                              "drops", "flag_cnt", "flag_rows"]
+                    if delta:
+                        revert.append("pb_nxt")
+                    for k in revert:
+                        out[k] = jnp.where(abort, carry[k], out[k])
+                    out["f_full"] = jnp.where(abort, code,
+                                              jnp.int32(0))[None]
+                return out
 
         return local
 
@@ -888,10 +904,19 @@ class ShardedTensorSearch(TensorSearch):
             # Runtime delivery masks ride as a replicated ARGUMENT: every
             # staged phase (different partition/timer gating, same
             # protocol shape) shares one compiled program.
-            return shard_map(local, mesh=self.mesh,
+            def chunk_step(c, m):
+                return local(c, m)
+
+            return shard_map(chunk_step, mesh=self.mesh,
                              in_specs=(spec, (P(), P())), out_specs=spec,
                              check_vma=False)
-        return shard_map(lambda c: local(c), mesh=self.mesh,
+
+        # The function's name is the program's in a profile
+        # (``jit_chunk_step``).
+        def chunk_step(c):
+            return local(c)
+
+        return shard_map(chunk_step, mesh=self.mesh,
                          in_specs=(spec,), out_specs=spec,
                          check_vma=False)
 
@@ -980,16 +1005,26 @@ class ShardedTensorSearch(TensorSearch):
 
             carry, k = jax.lax.while_loop(cond, body,
                                           (carry, jnp.int32(0)))
-            return carry, stats_local(carry, k)
+            with tel_mod.device_scope("level_sync"):
+                return carry, stats_local(carry, k)
 
         spec = self._carry_specs()
+        # The function's name is the program's in a profile
+        # (``jit_superstep``).
         if self._has_rt_masks():
+            def superstep(c, b, m):
+                return super_local(c, b, m)
+
             return shard_map(
-                lambda c, b, m: super_local(c, b, m), mesh=self.mesh,
+                superstep, mesh=self.mesh,
                 in_specs=(spec, P(), (P(), P())),
                 out_specs=(spec, P()), check_vma=False)
+
+        def superstep(c, b):
+            return super_local(c, b)
+
         return shard_map(
-            lambda c, b: super_local(c, b), mesh=self.mesh,
+            superstep, mesh=self.mesh,
             in_specs=(spec, P()), out_specs=(spec, P()),
             check_vma=False)
 
@@ -1048,72 +1083,74 @@ class ShardedTensorSearch(TensorSearch):
         ax = self.axis
         share = F // D
 
-        def local(carry):
-            carry = dict(carry)
-            nxt, nxt_n = carry["nxt"], carry["nxt_n"][0]
-            if D == 1 or self.row_exchange:
-                # Fused row exchange (ISSUE 12): successors already
-                # landed on their owner's shard inside the superstep,
-                # so the promote is a LOCAL buffer swap — zero ICI
-                # traffic, zero wide compaction; on one device the
-                # round-5 rebalance was an identity anyway.
-                carry["cur"] = nxt[:F]
-                carry["cur_n"] = carry["nxt_n"]
-            else:
-                per = (nxt_n + D - 1) // D          # rows per share
-                send = jnp.stack([
-                    jax.lax.dynamic_slice(nxt, (s * per, 0), (share, plane))
-                    for s in range(D)])             # [D, share, plane]
-                r = jnp.arange(share)
-                send_valid = jnp.stack([
-                    (r < per) & (s * per + r < nxt_n) for s in range(D)])
-                recv = jax.lax.all_to_all(send, ax, 0, 0)
-                recv_valid = jax.lax.all_to_all(send_valid, ax, 0, 0)
-                rows = recv.reshape(D * share, plane)
-                v = recv_valid.reshape(-1)
-                pos = jnp.cumsum(v) - 1
-                dst = jnp.where(v, pos, F)
-                carry["cur"] = jnp.zeros(
-                    (F + 1, plane), jnp.int32).at[dst].set(rows)[:F]
-                carry["cur_n"] = jnp.sum(v).astype(jnp.int32)[None]
-            if delta:
-                # Delta re-base (ISSUE 18 leg (b)): the promoted rows
-                # were packed against the OLD level base; re-encode them
-                # against the accumulated next-level base (pb_nxt, a
-                # global pmin computed inside the chunk steps — already
-                # value-identical on every device, so this stays
-                # elementwise: the fused promote keeps ZERO collectives).
-                pb_old = carry["pb_cur"]
-                # A lane whose pb_nxt never saw a successor (empty next
-                # frontier) keeps the old base so the (vacuous)
-                # re-encode stays in-window.
-                pb_new = jnp.where(
-                    carry["pb_nxt"] == jnp.int32(self._PB_EMPTY),
-                    pb_old, carry["pb_nxt"])
-                raw_rows = pk.unpack_jnp(carry["cur"],
-                                         self._base_vec(pb_old))
-                repacked, bad = pk.pack_jnp(raw_rows,
-                                            self._base_vec(pb_new),
-                                            count_bad=True)
-                occ = jnp.arange(F) < carry["cur_n"][0]
-                carry["cur"] = jnp.where(occ[:, None], repacked,
-                                         jnp.int32(0))
-                carry["overflow"] = carry["overflow"].at[0].add(
-                    jnp.sum(jnp.where(occ, bad, 0)).astype(jnp.int32))
-                carry["pb_cur"] = pb_new
-                carry["pb_nxt"] = jnp.full_like(
-                    pb_old, jnp.int32(self._PB_EMPTY))
-            carry["nxt"] = jnp.zeros((F + 1, plane), jnp.int32)
-            carry["nxt_n"] = jnp.zeros((1,), jnp.int32)
-            carry["j"] = jnp.zeros((1,), jnp.int32)
-            carry["evp"] = jnp.zeros((1,), jnp.int32)
-            if self.record_trace:
-                # The level's meta was spilled to host before this runs.
-                carry["tmeta"] = jnp.zeros((F + 1, 9), jnp.uint32)
-            return carry
+        def promote(carry):
+            with tel_mod.device_scope("promote"):
+                carry = dict(carry)
+                nxt, nxt_n = carry["nxt"], carry["nxt_n"][0]
+                if D == 1 or self.row_exchange:
+                    # Fused row exchange (ISSUE 12): successors already
+                    # landed on their owner's shard inside the superstep,
+                    # so the promote is a LOCAL buffer swap — zero ICI
+                    # traffic, zero wide compaction; on one device the
+                    # round-5 rebalance was an identity anyway.
+                    carry["cur"] = nxt[:F]
+                    carry["cur_n"] = carry["nxt_n"]
+                else:
+                    per = (nxt_n + D - 1) // D          # rows per share
+                    send = jnp.stack([
+                        jax.lax.dynamic_slice(nxt, (s * per, 0),
+                                              (share, plane))
+                        for s in range(D)])             # [D, share, plane]
+                    r = jnp.arange(share)
+                    send_valid = jnp.stack([
+                        (r < per) & (s * per + r < nxt_n) for s in range(D)])
+                    recv = jax.lax.all_to_all(send, ax, 0, 0)
+                    recv_valid = jax.lax.all_to_all(send_valid, ax, 0, 0)
+                    rows = recv.reshape(D * share, plane)
+                    v = recv_valid.reshape(-1)
+                    pos = jnp.cumsum(v) - 1
+                    dst = jnp.where(v, pos, F)
+                    carry["cur"] = jnp.zeros(
+                        (F + 1, plane), jnp.int32).at[dst].set(rows)[:F]
+                    carry["cur_n"] = jnp.sum(v).astype(jnp.int32)[None]
+                if delta:
+                    # Delta re-base (ISSUE 18 leg (b)): the promoted rows
+                    # were packed against the OLD level base; re-encode them
+                    # against the accumulated next-level base (pb_nxt, a
+                    # global pmin computed inside the chunk steps — already
+                    # value-identical on every device, so this stays
+                    # elementwise: the fused promote keeps ZERO collectives).
+                    pb_old = carry["pb_cur"]
+                    # A lane whose pb_nxt never saw a successor (empty next
+                    # frontier) keeps the old base so the (vacuous)
+                    # re-encode stays in-window.
+                    pb_new = jnp.where(
+                        carry["pb_nxt"] == jnp.int32(self._PB_EMPTY),
+                        pb_old, carry["pb_nxt"])
+                    raw_rows = pk.unpack_jnp(carry["cur"],
+                                             self._base_vec(pb_old))
+                    repacked, bad = pk.pack_jnp(raw_rows,
+                                                self._base_vec(pb_new),
+                                                count_bad=True)
+                    occ = jnp.arange(F) < carry["cur_n"][0]
+                    carry["cur"] = jnp.where(occ[:, None], repacked,
+                                             jnp.int32(0))
+                    carry["overflow"] = carry["overflow"].at[0].add(
+                        jnp.sum(jnp.where(occ, bad, 0)).astype(jnp.int32))
+                    carry["pb_cur"] = pb_new
+                    carry["pb_nxt"] = jnp.full_like(
+                        pb_old, jnp.int32(self._PB_EMPTY))
+                carry["nxt"] = jnp.zeros((F + 1, plane), jnp.int32)
+                carry["nxt_n"] = jnp.zeros((1,), jnp.int32)
+                carry["j"] = jnp.zeros((1,), jnp.int32)
+                carry["evp"] = jnp.zeros((1,), jnp.int32)
+                if self.record_trace:
+                    # The level's meta was spilled to host before this runs.
+                    carry["tmeta"] = jnp.zeros((F + 1, 9), jnp.uint32)
+                return carry
 
         spec = self._carry_specs()
-        return shard_map(local, mesh=self.mesh,
+        return shard_map(promote, mesh=self.mesh,
                          in_specs=(spec,), out_specs=spec,
                          check_vma=False)
 
@@ -1145,7 +1182,7 @@ class ShardedTensorSearch(TensorSearch):
         plane = self.plane
         ax = self.axis
 
-        def local(carry, plan):
+        def steal(carry, plan):
             carry = dict(carry)
             cur, cur_n = carry["cur"], carry["cur_n"][0]
             s = jax.lax.axis_index(ax)
@@ -1182,7 +1219,7 @@ class ShardedTensorSearch(TensorSearch):
 
         spec = self._carry_specs()
         return self._sharded_jit(
-            shard_map(local, mesh=self.mesh, in_specs=(spec, P()),
+            shard_map(steal, mesh=self.mesh, in_specs=(spec, P()),
                       out_specs=spec, check_vma=False),
             extra_in=(self._replicated(),))
 
@@ -1338,7 +1375,7 @@ class ShardedTensorSearch(TensorSearch):
         plane, pk, delta = self.plane, self._pk, self._mesh_delta
         nf = len(self._flag_names)
 
-        def build(row0, k0):
+        def init_carry(row0, k0):
             onehot_d = jnp.arange(D) == owner
             if delta:
                 # Level-0 base = the root row's own delta values (the
@@ -1381,7 +1418,7 @@ class ShardedTensorSearch(TensorSearch):
                     (D * pb0.shape[0],), jnp.int32(self._PB_EMPTY))
             return out
 
-        fn = jax.jit(build, out_shardings=self._carry_shardings())
+        fn = jax.jit(init_carry, out_shardings=self._carry_shardings())
         cache[(owner, home)] = fn
         return fn
 
@@ -1454,18 +1491,28 @@ class ShardedTensorSearch(TensorSearch):
         # would absorb the XLA half, but not the tracing).  A compile
         # error propagates: a program the backend refuses is a fault
         # to report, not a warm-up to skip.
-        if self.use_superstep:
-            exes["superstep"] = self._superstep.lower(
-                sds, b, *mask_args).compile()
-        else:
-            exes["step"] = self._chunk_step.lower(
-                sds, *mask_args).compile()
-            exes["stats"] = self._stats.lower(sds).compile()
-        exes["promote"] = self._finish_level.lower(sds).compile()
-        rows0, key0, owner, home = self._root_ids(
-            self.initial_state())
-        exes[("init", owner, home)] = self._init_prog(
-            owner, home).lower(rows0[0], jnp.asarray(key0)).compile()
+        def compile_(key, name, jitted, *args):
+            # ``name`` is the program's in a profile (``jit_<name>``);
+            # the executable is also registered under that name for
+            # telemetry.program_scopes (a set insert).
+            with tel_mod.phase("compile.aot." + name):
+                exes[key] = jitted.lower(*args).compile()
+            tel_mod.register_program(name, exes[key])
+
+        with tel_mod.phase("compile.aot"):
+            if self.use_superstep:
+                compile_("superstep", "superstep", self._superstep,
+                         sds, b, *mask_args)
+            else:
+                compile_("step", "chunk_step", self._chunk_step,
+                         sds, *mask_args)
+                compile_("stats", "level_stats", self._stats, sds)
+            compile_("promote", "promote", self._finish_level, sds)
+            rows0, key0, owner, home = self._root_ids(
+                self.initial_state())
+            compile_(("init", owner, home), "init_carry",
+                     self._init_prog(owner, home), rows0[0],
+                     jnp.asarray(key0))
         secs = time.time() - t0
         self.compile_secs = getattr(self, "compile_secs", 0.0) + secs
         tel = getattr(self, "_telemetry", None)
@@ -1567,8 +1614,14 @@ class ShardedTensorSearch(TensorSearch):
             if pk.has_delta:
                 base_sds = jax.ShapeDtypeStruct((self.lanes,),
                                                 jnp.int32)
-                mk_p = lambda: jax.jit(lambda r, b: pk.pack_jnp(r, b))
-                mk_u = lambda: jax.jit(lambda r, b: pk.unpack_jnp(r, b))
+                def pack_delta(r, b):
+                    return pk.pack_jnp(r, b)
+
+                def unpack_delta(r, b):
+                    return pk.unpack_jnp(r, b)
+
+                mk_p = lambda: jax.jit(pack_delta)      # noqa: E731
+                mk_u = lambda: jax.jit(unpack_delta)    # noqa: E731
                 sites["packing.pack"] = dict(
                     fn=mk_p(), args=(rows_sds, base_sds), donate=(),
                     multi=False, builder=mk_p)
@@ -1669,7 +1722,7 @@ class ShardedTensorSearch(TensorSearch):
             with self.mesh:
                 return cache[m](carry)
 
-        def local(c):
+        def checkpoint_snapshot(c):
             out = {
                 "cur": jax.lax.dynamic_slice(
                     c["cur"], (0, 0), (m, plane)),
@@ -1693,7 +1746,8 @@ class ShardedTensorSearch(TensorSearch):
         if self._mesh_delta:
             keys.append("pb_cur")
         snap_spec = {k: spec[k] for k in keys}
-        fn = jax.jit(shard_map(local, mesh=self.mesh, in_specs=(spec,),
+        fn = jax.jit(shard_map(checkpoint_snapshot, mesh=self.mesh,
+                               in_specs=(spec,),
                                out_specs=snap_spec, check_vma=False))
         cache[m] = fn
         with self.mesh:
@@ -1872,7 +1926,7 @@ class ShardedTensorSearch(TensorSearch):
             "drops": spread0(ck.dropped),
         }.items()}
 
-        def local(s):
+        def resume_carry(s):
             table, ins, unres = visited_mod.insert(
                 visited_mod.empty_table(V), s["keys"], s["kval"])
             out = {
@@ -1907,7 +1961,7 @@ class ShardedTensorSearch(TensorSearch):
         ax = self.axis
         in_spec = {k: P(ax) for k in dev_in}
         fn = jax.jit(shard_map(
-            local, mesh=self.mesh, in_specs=(in_spec,),
+            resume_carry, mesh=self.mesh, in_specs=(in_spec,),
             out_specs=(self._carry_specs(), P(ax)), check_vma=False))
         with self.mesh:
             carry, unres = fn(dev_in)
@@ -1936,14 +1990,14 @@ class ShardedTensorSearch(TensorSearch):
         F, V, lanes = self.f_cap, self.v_cap, self.lanes
         spec = self._carry_specs()
 
-        def reset(c):
+        def spill_reset(c):
             out = dict(c)
             out["nxt"] = jnp.zeros((F + 1, self.plane), jnp.int32)
             out["nxt_n"] = jnp.zeros((1,), jnp.int32)
             out["f_full"] = jnp.zeros((1,), jnp.int32)
             return out
 
-        def evict(c):
+        def spill_evict(c):
             out = dict(c)
             out["visited"] = jnp.full((V + 1, 4), MAXU32, jnp.uint32)
             out["vis_n"] = jnp.zeros((1,), jnp.int32)
@@ -1952,10 +2006,10 @@ class ShardedTensorSearch(TensorSearch):
 
         progs = self._sh_spill_prog_cache = {
             "reset": self._sharded_jit(shard_map(
-                reset, mesh=self.mesh, in_specs=(spec,),
+                spill_reset, mesh=self.mesh, in_specs=(spec,),
                 out_specs=spec, check_vma=False)),
             "evict": self._sharded_jit(shard_map(
-                evict, mesh=self.mesh, in_specs=(spec,),
+                spill_evict, mesh=self.mesh, in_specs=(spec,),
                 out_specs=spec, check_vma=False)),
             "inject": {},
         }
@@ -2076,7 +2130,7 @@ class ShardedTensorSearch(TensorSearch):
             spec = self._carry_specs()
             ax = self.axis
 
-            def inject(c, seg, nn):
+            def spill_inject(c, seg, nn):
                 out = dict(c)
                 out["cur"] = jnp.zeros((F, plane),
                                        jnp.int32).at[:m].set(seg)
@@ -2088,7 +2142,7 @@ class ShardedTensorSearch(TensorSearch):
 
             seg_shard = NamedSharding(self.mesh, P(ax))
             fn = progs["inject"][m] = self._sharded_jit(shard_map(
-                inject, mesh=self.mesh,
+                spill_inject, mesh=self.mesh,
                 in_specs=(spec, P(ax), P(ax)), out_specs=spec,
                 check_vma=False), extra_in=(seg_shard, seg_shard))
         buf = np.zeros((D, m, plane), np.int32)
@@ -2152,8 +2206,8 @@ class ShardedTensorSearch(TensorSearch):
         self._deep_samples = None
         # Structured per-level throughput records (depth, chunks, wall,
         # explored, unique, next_frontier) — attached to the outcome as
-        # SearchOutcome.levels; DSLABS_LEVEL_TIMING pretty-prints the
-        # same records to stderr as they land.
+        # SearchOutcome.levels; the ``search.level`` phase carries the
+        # same counters into a profile.
         self._level_records: List[dict] = []
         self._pd_prev_explored = [0] * self.n_devices
         self._root_fp = tuple(np.asarray(
@@ -2252,120 +2306,110 @@ class ShardedTensorSearch(TensorSearch):
                 depth += 1
                 # Live depth for supervision heartbeats (tpu/warden.py).
                 self._current_depth = depth
-                t_lvl = time.time()
-                # Final depth-limited level: count/check fresh successors
-                # without building the next frontier (it would never be
-                # expanded — and at bench scale it would not even FIT:
-                # the depth-10 strict probe's last level is ~4x the
-                # frontier cap).  The explicit DEPTH_EXHAUSTED return
-                # below replaces the loop-top check for this level.
-                noapp_level = (self.max_depth is not None
-                               and depth >= self.max_depth)
-                if noapp_level and not self._spill_on:
-                    # Spill mode keeps appends ON for the final level:
-                    # the host spool absorbs an over-cap last level
-                    # (noapp's reason to exist), and every fresh insert
-                    # must reach the boundary refilter or a tier
-                    # re-discovery would double-count (exact unique
-                    # parity is the whole point of the tier).
-                    shard = NamedSharding(self.mesh, P(self.axis))
-                    carry["noapp"] = jax.device_put(
-                        np.ones(self.n_devices, np.int32), shard)
-                if self.use_superstep:
-                    (carry, out, explored, vis_total, drops, max_n,
-                     chunks) = self._level_superstep(carry, depth, t0,
-                                                     max_n)
-                else:
-                    (carry, out, explored, vis_total, drops, max_n,
-                     chunks) = self._level_chunks(carry, depth, t0, max_n)
-                if out is not None:
-                    return out
-                if self._spill_on:
-                    # Deferred re-expansion waves: spooled segments of
-                    # THIS level (frontier rows that outgrew the device
-                    # buffer, or a resumed dump's tail) run at the same
-                    # depth before the level closes — depth accounting,
-                    # and therefore DEPTH_EXHAUSTED soundness, is
-                    # preserved exactly.
-                    while True:
-                        seg = self._spill.pop_current()
-                        if seg is None:
-                            break
-                        carry, per = self._sh_spill_inject(carry, seg)
+                with tel_mod.phase("search.level", depth=depth,
+                                   explored0=int(explored)) as lvl:
+                    t_lvl = time.time()
+                    # Final depth-limited level: count/check fresh successors
+                    # without building the next frontier (it would never be
+                    # expanded — and at bench scale it would not even FIT:
+                    # the depth-10 strict probe's last level is ~4x the
+                    # frontier cap).  The explicit DEPTH_EXHAUSTED return
+                    # below replaces the loop-top check for this level.
+                    noapp_level = (self.max_depth is not None
+                                   and depth >= self.max_depth)
+                    if noapp_level and not self._spill_on:
+                        # Spill mode keeps appends ON for the final level:
+                        # the host spool absorbs an over-cap last level
+                        # (noapp's reason to exist), and every fresh insert
+                        # must reach the boundary refilter or a tier
+                        # re-discovery would double-count (exact unique
+                        # parity is the whole point of the tier).
+                        shard = NamedSharding(self.mesh, P(self.axis))
+                        carry["noapp"] = jax.device_put(
+                            np.ones(self.n_devices, np.int32), shard)
+                    if self.use_superstep:
                         (carry, out, explored, vis_total, drops, max_n,
-                         ch2) = self._level_superstep(carry, depth, t0,
-                                                      per)
-                        chunks += ch2
-                        if out is not None:
-                            return out
-                rec = {
-                    "depth": depth, "chunks": int(chunks),
-                    "wall": round(time.time() - t_lvl, 4),
-                    "explored": int(explored), "unique": int(vis_total),
-                    "next_frontier": int(max_n),
-                    # Per-level visited-table load factor (ISSUE 6
-                    # satellite): pressure is visible in bench JSON
-                    # before the overflow contract can fire.
-                    "load_factor": round(
-                        getattr(self, "_last_load", 0.0), 4),
-                    # Wire/storage codec this level ran under (ISSUE
-                    # 18): 1.0 = raw exchange — the identity-fallback
-                    # gap the run()-level telemetry event makes loud.
-                    "pack_ratio": (round(self._pk.pack_ratio, 3)
-                                   if self._pk is not None else 1.0)}
-                # Mesh-scope lanes (ISSUE 8): the pre-psum per-device
-                # scalars the fused stats vector already carried, plus
-                # skew metrics — what the owner-hashed all_to_all
-                # design is decided on (ROADMAP #1).  Explored is
-                # cumulative per device, so the level's work share is
-                # the delta against the previous level sync.
-                pdev = getattr(self, "_last_per_device", None)
-                if pdev is not None:
-                    from dslabs_tpu.tpu import telemetry as tel_mod
-
-                    prev = getattr(self, "_pd_prev_explored",
-                                   [0] * self.n_devices)
-                    delta = [e - p for e, p in zip(pdev["explored"],
-                                                   prev)]
-                    self._pd_prev_explored = list(pdev["explored"])
-                    rec["per_device"] = {
-                        "explored": delta,
-                        "frontier": pdev["frontier"],
-                        "load_factor": [round(v / self.v_cap, 4)
-                                        for v in pdev["vis_n"]],
-                        "drops": pdev["drops"]}
-                    rec["skew"] = {
-                        "explored": tel_mod.skew_metrics(delta),
-                        "frontier": tel_mod.skew_metrics(
-                            pdev["frontier"])}
-                tel = getattr(self, "_telemetry", None)
-                if tel is not None:
-                    # Host-side HBM high-water per device, polled via
-                    # the runtime's memory stats at level boundaries
-                    # ONLY (a host syscall — never a device dispatch
-                    # or readback; CPU meshes report nothing and the
-                    # lane is omitted).
-                    from dslabs_tpu.tpu import telemetry as tel_mod
-
-                    hbm = tel_mod.device_memory_stats(
-                        self.mesh.devices.flat)
-                    if hbm is not None:
-                        rec["hbm_peak"] = hbm
-                self._level_records.append(rec)
+                         chunks) = self._level_superstep(carry, depth, t0,
+                                                         max_n)
+                    else:
+                        (carry, out, explored, vis_total, drops, max_n,
+                         chunks) = self._level_chunks(carry, depth, t0, max_n)
+                    if out is not None:
+                        return out
+                    if self._spill_on:
+                        # Deferred re-expansion waves: spooled segments of
+                        # THIS level (frontier rows that outgrew the device
+                        # buffer, or a resumed dump's tail) run at the same
+                        # depth before the level closes — depth accounting,
+                        # and therefore DEPTH_EXHAUSTED soundness, is
+                        # preserved exactly.
+                        while True:
+                            seg = self._spill.pop_current()
+                            if seg is None:
+                                break
+                            carry, per = self._sh_spill_inject(carry, seg)
+                            (carry, out, explored, vis_total, drops, max_n,
+                             ch2) = self._level_superstep(carry, depth, t0,
+                                                          per)
+                            chunks += ch2
+                            if out is not None:
+                                return out
+                    rec = {
+                        "depth": depth, "chunks": int(chunks),
+                        "wall": round(time.time() - t_lvl, 4),
+                        "explored": int(explored), "unique": int(vis_total),
+                        "next_frontier": int(max_n),
+                        # Per-level visited-table load factor (ISSUE 6
+                        # satellite): pressure is visible in bench JSON
+                        # before the overflow contract can fire.
+                        "load_factor": round(
+                            getattr(self, "_last_load", 0.0), 4),
+                        # Wire/storage codec this level ran under (ISSUE
+                        # 18): 1.0 = raw exchange — the identity-fallback
+                        # gap the run()-level telemetry event makes loud.
+                        "pack_ratio": (round(self._pk.pack_ratio, 3)
+                                       if self._pk is not None else 1.0)}
+                    # Mesh-scope lanes (ISSUE 8): the pre-psum per-device
+                    # scalars the fused stats vector already carried, plus
+                    # skew metrics — what the owner-hashed all_to_all
+                    # design is decided on (ROADMAP #1).  Explored is
+                    # cumulative per device, so the level's work share is
+                    # the delta against the previous level sync.
+                    pdev = getattr(self, "_last_per_device", None)
+                    if pdev is not None:
+                        prev = getattr(self, "_pd_prev_explored",
+                                       [0] * self.n_devices)
+                        delta = [e - p for e, p in zip(pdev["explored"],
+                                                       prev)]
+                        self._pd_prev_explored = list(pdev["explored"])
+                        rec["per_device"] = {
+                            "explored": delta,
+                            "frontier": pdev["frontier"],
+                            "load_factor": [round(v / self.v_cap, 4)
+                                            for v in pdev["vis_n"]],
+                            "drops": pdev["drops"]}
+                        rec["skew"] = {
+                            "explored": tel_mod.skew_metrics(delta),
+                            "frontier": tel_mod.skew_metrics(
+                                pdev["frontier"])}
+                    tel = getattr(self, "_telemetry", None)
+                    if tel is not None:
+                        # Host-side HBM high-water per device, polled via
+                        # the runtime's memory stats at level boundaries
+                        # ONLY (a host syscall — never a device dispatch
+                        # or readback; CPU meshes report nothing and the
+                        # lane is omitted).
+                        hbm = tel_mod.device_memory_stats(
+                            self.mesh.devices.flat)
+                        if hbm is not None:
+                            rec["hbm_peak"] = hbm
+                    self._level_records.append(rec)
+                    lvl.set(explored=int(explored), unique=int(vis_total),
+                            chunks=int(chunks), next_frontier=int(max_n))
                 if tel is not None:
                     # The SAME host scalars the fused stats readback
                     # already delivered — telemetry adds no transfers.
                     tel.on_level("sharded", self._level_records[-1])
-                if _LEVEL_TIMING:
-                    import sys as _sys
-                    r = self._level_records[-1]
-                    print(f"[level {r['depth']}] chunks={r['chunks']} "
-                          f"dt={r['wall']:.2f}s "
-                          f"chunk={r['wall']/max(r['chunks'],1)*1e3:.1f}ms "
-                          f"explored={r['explored']} "
-                          f"unique={r['unique']} "
-                          f"next={r['next_frontier']}",
-                          flush=True, file=_sys.stderr)
                 if noapp_level and self._spill_on:
                     # Final level, spill mode: drain through the
                     # refilter for the exact dedup accounting, then

@@ -2,7 +2,7 @@
 
 Before this module every subsystem emitted its own ad-hoc signals —
 SearchOutcome counters, warden heartbeat lines, bench JSON fragments,
-``DSLABS_LEVEL_TIMING`` records — and a wedged run left almost nothing
+per-level stderr lines — and a wedged run left almost nothing
 behind (one scraped stderr line to explain a hang).  This is the one
 observability substrate
 they all feed, built on the paper's discipline that **every signal must
@@ -33,11 +33,19 @@ Pieces:
   re-emitted from the child→parent JSON protocol.  ``summary()`` is
   the JSON block bench phases attach to their output.
 
-* **Profiler windows.**  ``DSLABS_PROFILE=<dir>`` wraps the first
-  ``DSLABS_PROFILE_STEPS`` post-warmup hot-loop dispatches (the first
-  dispatch at each site pays the XLA compile and is skipped) in
-  ``jax.profiler.trace`` — an opt-in deep dive that rides the same
-  seam, zero cost when the knob is unset.
+* **Program spans.**  :func:`phase` / :func:`mark` name the host's
+  work where it happens — the lab entry point's stages, every level,
+  every dispatch, every compile — as ``dslabs:<name>``
+  ``jax.profiler.TraceAnnotation`` events, so that in ANY profile (the
+  benchmark's traced slice, ``jax.profiler.trace`` around a test) the
+  program's doing lies on the device trace's own clock, fields and
+  all; with a recorder current (:func:`use`) each also lands in the
+  ring and the flight log as a ``phase`` record.  Every name is in
+  :data:`PHASES`.  Inside the device programs ``jax.named_scope``
+  (``dslabs.<stage>``, :data:`DEVICE_SCOPES`) names the stages of the
+  chunk step; :func:`program_scopes` maps a compiled program's
+  instructions back to them.  With no profiler running and no recorder
+  current a phase costs one ``TraceMe`` activity check.
 
 * **Run reports.**  ``python -m dslabs_tpu.tpu.telemetry report
   <run-dir-or-flight-log>`` renders the flight log alone into per-level
@@ -84,13 +92,17 @@ host-side Python + stdlib — importing this module never imports jax.
 from __future__ import annotations
 
 import contextlib
+import contextvars
+import itertools
 import json
 import math
 import os
+import re
 import threading
 import time
+import weakref
 from collections import deque
-from typing import Dict, List, Optional
+from typing import Dict, List, Optional, Tuple
 
 __all__ = ["Telemetry", "MetricsRegistry", "Counter", "Gauge",
            "Histogram", "read_flight", "tail_records", "build_report",
@@ -98,17 +110,20 @@ __all__ = ["Telemetry", "MetricsRegistry", "Counter", "Gauge",
            "device_memory_stats", "default_status_path", "load_status",
            "render_watch", "watch_frame", "append_ledger",
            "read_ledger", "compare_ledger", "render_compare",
-           "DISPATCH_SITES", "main"]
+           "DISPATCH_SITES", "PHASES", "DEVICE_SCOPES", "AOT_PROGRAMS",
+           "phase", "mark", "call", "annotate", "use", "current",
+           "device_scope", "register_program", "program_scopes",
+           "scopes_of_hlo", "main"]
 
 # THE canonical dispatch-site registry (ISSUE 10): every tag the
 # engines route through ``TensorSearch._dispatch``, with the static
 # contract each site's lowered program is audited against by the
 # soundness sanitizer (dslabs_tpu/analysis/jaxpr_audit.py — the same
-# enumeration feeds the profiler-site selection below and the
-# sanitizer's coverage check, so a new dispatch site that skips this
-# table is a loud J0 finding, not silent audit rot).
+# enumeration feeds the ``dispatch.<site>`` names of PHASES below and
+# the sanitizer's coverage check, so a new dispatch site that skips
+# this table is a loud J0 finding, not silent audit rot).
 #
-#   hot      — steady-state dispatches worth a profiler capture
+#   hot      — steady-state dispatches of the hot loop
 #   donated  — the program's carry is declared jit(donate_argnums=0);
 #              the auditor verifies the lowering kept the aliasing
 #   multi    — cross-device collectives are EXPECTED (mesh programs);
@@ -205,14 +220,6 @@ DISPATCH_SITES = {
     "symmetry.canonicalize": dict(hot=False, donated=False, multi=False,
                                   program=True),
 }
-
-# Hot-loop sites whose steady-state dispatches are worth a profiler
-# capture (the compile-paying first dispatch at a site is skipped) —
-# derived from the registry so the two views cannot drift.
-_PROFILE_SITES = tuple(sorted({t.split(".", 1)[1]
-                               for t, m in DISPATCH_SITES.items()
-                               if m["hot"]}))
-
 
 def _env_float(name: str, default: float) -> float:
     try:
@@ -373,57 +380,278 @@ class MetricsRegistry:
         }
 
 
-# ------------------------------------------------------ profiler window
+# -------------------------------------------------------- program spans
 
-class _ProfileWindow:
-    """Opt-in ``jax.profiler.trace`` capture of the first K post-warmup
-    hot-loop dispatches (DSLABS_PROFILE=<dir>, DSLABS_PROFILE_STEPS).
-    The first dispatch at each site pays the XLA compile and is never
-    captured (a compile trace drowns the steady-state picture).  All
-    failures degrade to "window off" — profiling must never take a
-    search down."""
+# THE table of span and mark names (as DISPATCH_SITES is of sites):
+# ``phase``/``mark``/``annotate`` are only ever handed one of these, and
+# tests/test_program_spans.py holds every name a run emits to it.
+# PERF.md section 3 says which per-layer metric reads which.
+AOT_PROGRAMS = ("superstep", "chunk_step", "level_stats", "promote",
+                "init_carry")
+PHASES = (
+    "entry.tensor_bfs", "entry.tensor_dfs",     # root of one lab call
+    "entry.bind", "entry.build_engine", "entry.derive_root",
+    "entry.warm_run", "entry.search", "entry.replay", "entry.recheck",
+    "entry.probe",
+    "search.level",                 # one BFS level / wave
+    "compile.aot",                  # aot_warmup, one child per program
+    "compile.event",                # mark: one jax.monitoring event
+) + tuple(f"compile.aot.{name}" for name in AOT_PROGRAMS) + tuple(
+    sorted({"dispatch." + tag.split(".", 1)[1] for tag in DISPATCH_SITES}))
 
-    def __init__(self):
-        self.dir = os.environ.get("DSLABS_PROFILE") or None
-        try:
-            self.steps = int(os.environ.get("DSLABS_PROFILE_STEPS",
-                                            "4"))
-        except ValueError:
-            self.steps = 4
-        self.active = False
-        self.done = self.dir is None
-        self._left = 0
-        self._seen: Dict[str, int] = {}
+# The ``jax.named_scope`` names inside the device programs
+# (``dslabs.<scope>``): the stages of the chunk step, the level sync
+# and the promote.  Scopes are HLO metadata only.
+DEVICE_SCOPES = (
+    "expand.events", "expand.handlers", "expand.canon", "fingerprint",
+    "flags", "pack", "trace_meta", "route", "exchange", "visited_insert",
+    "append", "level_sync", "promote")
 
-    def on_start(self, site: str) -> None:
-        if self.done or self.active or site not in _PROFILE_SITES:
-            return
-        n = self._seen.get(site, 0)
-        self._seen[site] = n + 1
-        if n == 0:
-            return                     # compile-paying warm-up dispatch
-        try:
-            import jax
+ANNOTATION_PREFIX = "dslabs:"
+SCOPE_PREFIX = "dslabs."
+# The tests turn this on (tests/conftest.py): a name that is not in
+# PHASES then raises where it is used.
+check_names = False
+_PHASE_SET = frozenset(PHASES)
 
-            jax.profiler.start_trace(self.dir)
-            self.active = True
-            self._left = self.steps
-        except Exception:
-            self.done = True
+_CURRENT: contextvars.ContextVar = contextvars.ContextVar(
+    "dslabs_telemetry", default=None)
+_CALL: contextvars.ContextVar = contextvars.ContextVar(
+    "dslabs_call", default=None)
+_PARENT: contextvars.ContextVar = contextvars.ContextVar(
+    "dslabs_phase", default=None)
+_CALL_SEQ = itertools.count(1)
+_TRACE_ME = None
+_PACKED = re.compile(r"""[#,="'()]""")
 
-    def on_done(self, site: str) -> None:
-        if not self.active or site not in _PROFILE_SITES:
-            return
-        self._left -= 1
-        if self._left <= 0:
+
+def current() -> Optional["Telemetry"]:
+    """The recorder that phases opened in this context write to."""
+    return _CURRENT.get()
+
+
+@contextlib.contextmanager
+def use(recorder):
+    """Make ``recorder`` current for the body: every phase and mark
+    opened inside lands in its ring and flight log, and the lab entry
+    point attaches it to the search it builds."""
+    token = _CURRENT.set(recorder)
+    try:
+        yield recorder
+    finally:
+        _CURRENT.reset(token)
+
+
+def device_scope(name: str):
+    """``jax.named_scope("dslabs.<name>")`` — one stage of a device
+    program, named in the HLO's metadata."""
+    import jax
+
+    return jax.named_scope(SCOPE_PREFIX + name)
+
+
+class _NoAnnotation:
+    """Stands for a TraceAnnotation while no profiler is running."""
+
+    def __enter__(self):
+        return self
+
+    def __exit__(self, *exc):
+        return False
+
+    def set_metadata(self, **fields):
+        pass
+
+
+_NO_ANNOTATION = _NoAnnotation()
+
+
+def annotate(name: str, **fields):
+    """The profiler half of a phase alone: a ``dslabs:<name>``
+    TraceAnnotation whose stats are ``fields`` plus the current call's
+    id — or nothing at all while no profiler is running (one
+    ``TraceMe`` activity check)."""
+    global _TRACE_ME
+    if check_names and name not in _PHASE_SET:
+        raise ValueError(f"{name!r} is not in telemetry.PHASES")
+    if _TRACE_ME is None:
+        from jax.profiler import TraceAnnotation
+
+        _TRACE_ME = TraceAnnotation
+    if not _TRACE_ME.is_enabled():
+        return _NO_ANNOTATION
+    call = _CALL.get()
+    if call is not None:
+        fields.setdefault("call", call)
+    # TraceMe packs the stats into the event's name (``name#k=v,k=v#``)
+    # and reads them back by its separators and quotes: keep those out
+    # of a value, or it swallows the stats after it.
+    for k, v in fields.items():
+        if isinstance(v, str):
+            fields[k] = _PACKED.sub("_", v)
+    return _TRACE_ME(ANNOTATION_PREFIX + name, **fields)
+
+
+class phase:
+    """One named span of the host's work: a ``dslabs:<name>``
+    annotation in the profiler's trace and, with a recorder current, a
+    ``phase`` record.  ``set(**fields)`` adds what is only known at the
+    close (a level's counters) to both."""
+
+    __slots__ = ("name", "fields", "_note", "_recorder", "_t0", "_parent",
+                 "_token")
+
+    def __init__(self, name: str, **fields):
+        self.name = name
+        self.fields = fields
+
+    def __enter__(self):
+        self._note = annotate(self.name, **self.fields)
+        self._note.__enter__()
+        self._recorder = _CURRENT.get()
+        self._t0 = time.time()
+        self._parent = _PARENT.get()
+        self._token = _PARENT.set(self.name)
+        return self
+
+    def set(self, **fields) -> None:
+        self.fields.update(fields)
+        self._note.set_metadata(**fields)
+
+    def __exit__(self, *exc):
+        self._note.__exit__(*exc)
+        _PARENT.reset(self._token)
+        if self._recorder is not None:
+            self._recorder.record_phase(
+                self.name, self._t0, time.time() - self._t0,
+                self._parent, self.fields)
+        return False
+
+
+@contextlib.contextmanager
+def call(name: str, **fields):
+    """The root phase of one call of an entry point.  Mints the call's
+    id (a per-process sequence number): every phase opened inside, and
+    every dispatch of the search it builds, carries it."""
+    token = _CALL.set(next(_CALL_SEQ))
+    try:
+        with phase(name, **fields) as ph:
+            yield ph
+    finally:
+        _CALL.reset(token)
+
+
+def mark(name: str, **fields) -> None:
+    """An instant: a zero-length phase."""
+    with phase(name, **fields):
+        pass
+
+
+# The compiled programs registered under each name, kept (weakly: a
+# program lives as long as the search that compiled it) so that a
+# TRACED run can say which stage an operation of the device trace
+# belongs to.  Registering is a set insert; an executable's text is
+# parsed when first asked for.
+_PROGRAMS: Dict[str, "weakref.WeakSet"] = {}
+_PROGRAM_SCOPES: "weakref.WeakKeyDictionary" = weakref.WeakKeyDictionary()
+_OP_NAME = re.compile(r'op_name="([^"]*)"')
+_CALLS = re.compile(r"calls=%?([\w.\-]+)")
+_INSTRUCTION = re.compile(r"^\s*(ROOT\s+)?%?([\w.\-]+)\s*=")
+_OPERAND = re.compile(r"%([\w.\-]+)")
+_COMPUTATION = re.compile(r"^(?:ENTRY\s+)?%?([\w.\-]+)\s*\(.*\{\s*$")
+
+
+def register_program(name: str, exe) -> None:
+    """Keep the compiled executable ``exe`` under ``name`` (the name
+    its module has in a trace, less ``jit_``)."""
+    _PROGRAMS.setdefault(name, weakref.WeakSet()).add(exe)
+
+
+def scopes_of_hlo(text: str) -> Dict[str, Tuple[str, bool]]:
+    """``{instruction: (scope, named)}`` of an optimised HLO module's
+    text.  ``named``: the scope is the innermost ``dslabs.<scope>`` of
+    the instruction's own ``op_name`` (a fusion without one takes its
+    root's).  The compiler's own operations carry no ``op_name`` (on
+    the chip: the relayout reshapes and copies around the visited
+    table, a third of the superstep); each takes the scope of what it
+    feeds — if all its scoped users agree — else of what feeds it,
+    repeated until nothing changes, and is ``named`` False: a guess by
+    neighbourhood, which a reader keeps apart from what the program
+    said itself.  Instructions left with no scope are left out."""
+    scopes: Dict[str, Tuple[str, bool]] = {}
+    root_of: Dict[str, Optional[str]] = {}
+    pending: List[tuple] = []
+    operands: Dict[str, List[str]] = {}
+    comp = None
+    for line in text.splitlines():
+        m = _INSTRUCTION.match(line)
+        if m is None:
+            c = _COMPUTATION.match(line)
+            if c is not None:
+                comp = c.group(1)
+            continue
+        name = m.group(2)
+        scope = None
+        op = _OP_NAME.search(line)
+        if op is not None:
+            for part in reversed(op.group(1).split("/")):
+                if part.startswith(SCOPE_PREFIX):
+                    scope = part[len(SCOPE_PREFIX):]
+                    break
+        if scope is not None:
+            scopes[name] = (scope, True)
+        else:
+            called = _CALLS.search(line)
+            if called is not None:
+                pending.append((name, called.group(1)))
+        # every %name after the "=": operands (and computations called,
+        # which are no instruction's name)
+        body = line[m.end():].split(", metadata=")[0]
+        operands[name] = _OPERAND.findall(body)
+        if m.group(1) and comp is not None:
+            root_of[comp] = scope
+    for name, called in pending:
+        if root_of.get(called) is not None:
+            scopes[name] = (root_of[called], True)
+    users: Dict[str, List[str]] = {}
+    for name, ops in operands.items():
+        for o in ops:
+            users.setdefault(o, []).append(name)
+    changed = True
+    while changed:
+        changed = False
+        for neighbours in (users, operands):
+            moved = True
+            while moved:
+                moved = False
+                for name in operands:
+                    if name in scopes:
+                        continue
+                    near = {scopes[n][0] for n in neighbours.get(name, ())
+                            if n in scopes}
+                    if len(near) == 1:
+                        scopes[name] = (near.pop(), False)
+                        moved = changed = True
+    return scopes
+
+
+def program_scopes(name: str) -> Optional[Dict[str, Tuple[str, bool]]]:
+    """:func:`scopes_of_hlo` of THE program registered as ``name``.
+    None if there is none, if the backend gives no text — or if two
+    live programs of that name differ (a second engine in the process):
+    which of them a trace ran cannot be told from here, and no
+    attribution is better than the other engine's."""
+    maps: List[dict] = []
+    for exe in list(_PROGRAMS.get(name, ())):
+        if exe not in _PROGRAM_SCOPES:
             try:
-                import jax
-
-                jax.profiler.stop_trace()
-            except Exception:
-                pass
-            self.active = False
-            self.done = True
+                text = exe.as_text()
+            except Exception:  # noqa: BLE001 — a backend without text
+                text = None
+            _PROGRAM_SCOPES[exe] = scopes_of_hlo(text) if text else None
+        if _PROGRAM_SCOPES[exe] not in maps:
+            maps.append(_PROGRAM_SCOPES[exe])
+    return maps[0] if len(maps) == 1 else None
 
 
 # ------------------------------------------------------------- recorder
@@ -469,7 +697,6 @@ class Telemetry:
         self.engine_hint = engine_hint
         self._counts: Dict[str, int] = {}
         self._lock = threading.Lock()
-        self._profile = _ProfileWindow()
         self._t0 = time.time()
         self._fh = None
         self.flight_error: Optional[str] = None
@@ -555,7 +782,8 @@ class Telemetry:
         if not force and now - self._status_last < self._status_secs:
             return
         self._status_last = now
-        last_span = self.ring[-1] if self.ring else None
+        last_span = next((r for r in reversed(self.ring)
+                          if r["t"] == "span"), None)
         st = {
             "t": "status", "pid": os.getpid(),
             "hint": self.engine_hint,
@@ -638,7 +866,6 @@ class Telemetry:
             self._write(start)
             self._open_dispatch = start
             self._write_status()
-        self._profile.on_start(site)
         t0 = time.time()
         outcome = "ok"
         try:
@@ -650,7 +877,6 @@ class Telemetry:
             raise
         finally:
             wall = time.time() - t0
-            self._profile.on_done(site)
             retries = ((boundary.retries - r0)
                        if boundary is not None else 0)
             span = {"t": "span", "ts": self._ts(), "tag": tag,
@@ -709,6 +935,30 @@ class Telemetry:
                 self.registry.counter(f"dispatches.{engine}").inc()
                 self.registry.histogram(f"dispatch_secs.{tag}").observe(
                     wall)
+
+    def record_phase(self, name: str, t0: float, wall: float,
+                     parent: Optional[str], fields: dict) -> None:
+        """One closed :class:`phase` (or mark, ``wall`` 0).  A record
+        type of its own: span counts stay equal to dispatch counts."""
+        rec = {"t": "phase", "name": name,
+               "ts": round(t0 - self._t0, 4), "wall": round(wall, 6),
+               "call": fields.get("call", _CALL.get()), "parent": parent,
+               **fields}
+        if self.trace_id:
+            rec["trace"] = self.trace_id
+        with self._lock:
+            self._write(rec)
+            if name == "compile.event":
+                # One lab call makes a thousand of these (every jitted
+                # jnp helper it re-traces): they would push everything
+                # else out of the ring, so they go to the flight log
+                # and to a histogram of their own seconds.
+                self.registry.histogram(
+                    f"compile_secs.{fields.get('kind')}").observe(
+                    float(fields.get("secs", 0.0)))
+                return
+            self.ring.append(rec)
+            self.registry.histogram(f"phase_secs.{name}").observe(wall)
 
     # -------------------------------------------------------- other feeds
 
@@ -1123,7 +1373,21 @@ def build_report(records: List[dict]) -> dict:
                     drain[k] = round(drain.get(k, 0.0) + float(v), 4)
                 except (TypeError, ValueError):
                     pass
+    # Program spans (phase records), by the call they belong to: per
+    # call and name, how many and how many seconds; marks (wall 0) are
+    # counted, not timed.  Calls in the order they were made; phases
+    # outside any call under "-".
+    calls: Dict[str, Dict[str, dict]] = {}
+    for r in records:
+        if r.get("t") != "phase":
+            continue
+        key = "-" if r.get("call") is None else str(r["call"])
+        row = calls.setdefault(key, {}).setdefault(
+            r["name"], {"n": 0, "secs": 0.0, "parent": r.get("parent")})
+        row["n"] += 1
+        row["secs"] = round(row["secs"] + float(r.get("wall", 0.0)), 6)
     return {"meta": meta, "n_spans": len(spans),
+            "phases": calls,
             "sites": {t: h.snapshot() for t, h in sites.items()},
             "series": series, "timeline": timeline,
             "outcomes": outcomes, "counts": counts,
@@ -1252,6 +1516,17 @@ def render_report(report: dict, source: str = "") -> str:
             f"explored={o.get('states_explored')} "
             f"elapsed={o.get('elapsed_secs')}s "
             f"compile={o.get('compile_secs')}s")
+
+    if report.get("phases"):
+        out.append("")
+        out.append("-- phases by call --")
+        out.append(f"{'call':>6s} {'phase':30s} {'under':22s} {'n':>5s} "
+                   f"{'secs':>9s}")
+        for key, rows in report["phases"].items():
+            for name, row in rows.items():
+                out.append(f"{key:>6s} {name:30s} "
+                           f"{row['parent'] or '-':22s} {row['n']:5d} "
+                           f"{row['secs']:9.3f}")
 
     if report["in_flight"] is not None:
         r = report["in_flight"]

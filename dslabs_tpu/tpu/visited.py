@@ -195,65 +195,70 @@ def insert_jnp(table: jnp.ndarray, keys: jnp.ndarray, valid: jnp.ndarray,
     Pure jnp — usable under jit, inside shard_map bodies, and inside
     the Pallas kernel body.
     """
-    V = table.shape[0] - 1
-    check_cap(V)
-    VB = V // BKT
-    n = keys.shape[0]
-    skeys = sanitize_keys(keys, valid)
-    slot0 = (skeys[:, 2] & jnp.uint32(VB - 1)).astype(jnp.int32)
-    pstep = (skeys[:, 1] | jnp.uint32(1)).astype(jnp.uint32)
-    # Reservations go through a small HASHED table (bkt_i mod RT): a
-    # collision between two DISTINCT buckets just makes one contender
-    # retry next iteration — a winner must still re-win its own cell.
-    RT = 1 << max((n * 2 - 1).bit_length(), 10)
-    # Tail threshold: once fewer than T keys remain unresolved, compact
-    # them so late iterations stop re-scanning the whole batch.
-    T = max(n // 8, min(256, n))
-    ridx = jnp.arange(n, dtype=jnp.int32)
+    # Named in the HLO's metadata wherever it is traced
+    # (tpu/telemetry.py DEVICE_SCOPES).  A ``with`` in this frame, not a
+    # wrapper function: a frame more under all that the insert traces
+    # changes how long the tracing takes (PERF.md section 7).
+    with jax.named_scope("dslabs.visited_insert"):
+        V = table.shape[0] - 1
+        check_cap(V)
+        VB = V // BKT
+        n = keys.shape[0]
+        skeys = sanitize_keys(keys, valid)
+        slot0 = (skeys[:, 2] & jnp.uint32(VB - 1)).astype(jnp.int32)
+        pstep = (skeys[:, 1] | jnp.uint32(1)).astype(jnp.uint32)
+        # Reservations go through a small HASHED table (bkt_i mod RT): a
+        # collision between two DISTINCT buckets just makes one contender
+        # retry next iteration — a winner must still re-win its own cell.
+        RT = 1 << max((n * 2 - 1).bit_length(), 10)
+        # Tail threshold: once fewer than T keys remain unresolved, compact
+        # them so late iterations stop re-scanning the whole batch.
+        T = max(n // 8, min(256, n))
+        ridx = jnp.arange(n, dtype=jnp.int32)
 
-    def full_cond(st):
-        _, _, resolved, _, it = st
-        # ONE guaranteed full-batch iteration: below 50% table load the
-        # first bucket read resolves all but the full-bucket collisions,
-        # which fit the tail buffer.
-        return ((it < 1) | (jnp.sum(~resolved) > T)) & (
-            it < max_iters) & jnp.any(~resolved)
+        def full_cond(st):
+            _, _, resolved, _, it = st
+            # ONE guaranteed full-batch iteration: below 50% table load the
+            # first bucket read resolves all but the full-bucket collisions,
+            # which fit the tail buffer.
+            return ((it < 1) | (jnp.sum(~resolved) > T)) & (
+                it < max_iters) & jnp.any(~resolved)
 
-    def full_body(st):
-        tbl, bkt_i, resolved, ins, it = st
-        tbl, bkt_i, newly, winner = _probe_iter(
-            tbl, skeys, bkt_i, pstep, ~resolved, ridx, V, RT, n)
-        return tbl, bkt_i, resolved | newly, ins | winner, it + 1
+        def full_body(st):
+            tbl, bkt_i, resolved, ins, it = st
+            tbl, bkt_i, newly, winner = _probe_iter(
+                tbl, skeys, bkt_i, pstep, ~resolved, ridx, V, RT, n)
+            return tbl, bkt_i, resolved | newly, ins | winner, it + 1
 
-    table, bkt_i, resolved, inserted, _ = jax.lax.while_loop(
-        full_cond, full_body,
-        (table, slot0, ~valid, jnp.zeros(n, bool), jnp.int32(0)))
+        table, bkt_i, resolved, inserted, _ = jax.lax.while_loop(
+            full_cond, full_body,
+            (table, slot0, ~valid, jnp.zeros(n, bool), jnp.int32(0)))
 
-    # ---- tail phase: compact the unresolved few into [T] slots.
-    tail_idx = jnp.nonzero(~resolved, size=T, fill_value=n)[0]
-    tclip = tail_idx.clip(0, n - 1)
-    tval = tail_idx < n
-    t_keys = skeys[tclip]
-    t_bkt = bkt_i[tclip]
-    t_ps = pstep[tclip]
-    t_id = jnp.arange(T, dtype=jnp.int32)
+        # ---- tail phase: compact the unresolved few into [T] slots.
+        tail_idx = jnp.nonzero(~resolved, size=T, fill_value=n)[0]
+        tclip = tail_idx.clip(0, n - 1)
+        tval = tail_idx < n
+        t_keys = skeys[tclip]
+        t_bkt = bkt_i[tclip]
+        t_ps = pstep[tclip]
+        t_id = jnp.arange(T, dtype=jnp.int32)
 
-    def tail_cond(st):
-        _, _, t_unres, _, it = st
-        return (it < max_iters) & jnp.any(t_unres)
+        def tail_cond(st):
+            _, _, t_unres, _, it = st
+            return (it < max_iters) & jnp.any(t_unres)
 
-    def tail_body(st):
-        tbl, tb, t_unres, t_ins, it = st
-        tbl, tb, newly, winner = _probe_iter(
-            tbl, t_keys, tb, t_ps, t_unres, t_id, V, RT, n)
-        return tbl, tb, t_unres & ~newly, t_ins | winner, it + 1
+        def tail_body(st):
+            tbl, tb, t_unres, t_ins, it = st
+            tbl, tb, newly, winner = _probe_iter(
+                tbl, t_keys, tb, t_ps, t_unres, t_id, V, RT, n)
+            return tbl, tb, t_unres & ~newly, t_ins | winner, it + 1
 
-    table, _, t_unres, t_ins, _ = jax.lax.while_loop(
-        tail_cond, tail_body,
-        (table, t_bkt, tval, jnp.zeros(T, bool), jnp.int32(0)))
-    resolved = resolved.at[tclip].max(tval & ~t_unres)
-    inserted = inserted.at[tclip].max(t_ins & tval)
-    return table, inserted, ~resolved
+        table, _, t_unres, t_ins, _ = jax.lax.while_loop(
+            tail_cond, tail_body,
+            (table, t_bkt, tval, jnp.zeros(T, bool), jnp.int32(0)))
+        resolved = resolved.at[tclip].max(tval & ~t_unres)
+        inserted = inserted.at[tclip].max(t_ins & tval)
+        return table, inserted, ~resolved
 
 
 # ------------------------------------------------- Pallas bucket kernel
@@ -374,9 +379,11 @@ def dispatch_site_program(cap: int, batch: int):
             jax.ShapeDtypeStruct((batch, 4), jnp.uint32),
             jax.ShapeDtypeStruct((batch,), jnp.bool_))
 
+    def visited_insert(t, k, v):
+        return insert(t, k, v)
+
     def build():
-        return jax.jit(lambda t, k, v: insert(t, k, v),
-                       donate_argnums=0)
+        return jax.jit(visited_insert, donate_argnums=0)
 
     return dict(fn=build(), args=args, donate=(0,), multi=False,
                 builder=build)

@@ -26,3 +26,9 @@ os.environ.setdefault("JAX_COMPILATION_CACHE_DIR", "/tmp/jaxcache-cpu")
 import jax  # noqa: E402
 
 jax.config.update("jax_persistent_cache_min_compile_time_secs", 1.0)
+
+# A span or mark whose name is not in telemetry.PHASES raises in the
+# tests (ISSUE 25): the table is the one place names live.
+from dslabs_tpu.tpu import telemetry as _telemetry  # noqa: E402
+
+_telemetry.check_names = True

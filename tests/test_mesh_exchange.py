@@ -309,15 +309,13 @@ def test_pallas_engine_parity(monkeypatch):
 
 def test_pallas_site_registered_and_clean():
     """The bucket kernel is a canonical dispatch site: registered in
-    telemetry.DISPATCH_SITES (hot -> profiler selection), present in
-    both engines' site maps, and its lowering audits clean."""
+    telemetry.DISPATCH_SITES (hot), present in both engines' site maps,
+    and its lowering audits clean."""
     from dslabs_tpu.analysis.jaxpr_audit import audit_sites
-    from dslabs_tpu.tpu.telemetry import (DISPATCH_SITES,
-                                          _PROFILE_SITES)
+    from dslabs_tpu.tpu.telemetry import DISPATCH_SITES
 
     assert "visited.insert" in DISPATCH_SITES
     assert DISPATCH_SITES["visited.insert"]["hot"]
-    assert "insert" in _PROFILE_SITES
     proto = _pruned_pingpong()
     search = _build(proto, 2)
     sites = search.dispatch_site_programs()

@@ -1,0 +1,453 @@
+"""Spans and scopes inside the program (ISSUE 25, tpu/telemetry.py
+``phase`` / ``mark`` / ``annotate`` / ``use``, ``PHASES``,
+``DEVICE_SCOPES``, ``program_scopes``; tpu/compile_cache.py ``totals``).
+
+What is held here:
+
+* every name a lab call and a sharded run emit — into a profile and
+  into a current recorder — is in ``PHASES``, and a name that is not
+  raises (``tests/conftest.py`` turns ``telemetry.check_names`` on);
+* the phases of one ``tensor_bfs`` call share its ``call`` id and nest
+  under ``entry.tensor_bfs``; the dispatches counted from the profile's
+  ``dslabs:dispatch.*`` annotations are the recorder's own spans;
+* with a recorder current AND a profiler running, dispatch counts and
+  ``device_get`` counts are bit-identical to a bare run;
+* every scope of ``DEVICE_SCOPES`` is in the compiled programs' text,
+  ``program_scopes`` maps most of the superstep's instructions, and the
+  programs have names of their own in a profile;
+* a cold jit leaves ``compile.event`` marks and grows
+  ``compile_cache.totals()``; a phase with neither recorder nor profiler
+  writes nothing.
+"""
+
+import dataclasses
+import glob
+
+import pytest
+
+jax = pytest.importorskip("jax")
+
+from dslabs_tpu.tpu import compile_cache, engine  # noqa: E402
+from dslabs_tpu.tpu import telemetry as tel_mod  # noqa: E402
+from dslabs_tpu.tpu.protocols.pingpong import \
+    make_pingpong_protocol  # noqa: E402
+from dslabs_tpu.tpu.sharded import ShardedTensorSearch, make_mesh  # noqa: E402
+from dslabs_tpu.tpu.telemetry import Telemetry  # noqa: E402
+
+pytestmark = pytest.mark.obs
+
+PREFIX = tel_mod.ANNOTATION_PREFIX
+
+
+def _pruned_pingpong():
+    pp = make_pingpong_protocol(workload_size=2)
+    return dataclasses.replace(
+        pp, goals={}, prunes={"CLIENTS_DONE": pp.goals["CLIENTS_DONE"]})
+
+
+def _sharded(n_devices=2, **kw):
+    return ShardedTensorSearch(
+        _pruned_pingpong(), make_mesh(n_devices), chunk_per_device=16,
+        frontier_cap=1 << 8, visited_cap=1 << 10, max_depth=8,
+        record_trace=True, **kw)
+
+
+def _lab0_call():
+    """One ``tensor_bfs`` call on lab 0's pingpong state."""
+    import tests.test_lab0_search as L0
+    from dslabs_tpu.search.settings import SearchSettings
+    from dslabs_tpu.testing.predicates import CLIENTS_DONE, RESULTS_OK
+    from dslabs_tpu.tpu import backend
+
+    settings = (SearchSettings().add_invariant(RESULTS_OK)
+                .add_goal(CLIENTS_DONE))
+    return backend.tensor_bfs(L0.make_state(), settings)
+
+
+class Profile:
+    """What a profile holds of the program: the ``dslabs:`` annotations
+    with their stats, and the names of the modules that ran."""
+
+    def __init__(self, out_dir):
+        path = sorted(glob.glob(str(out_dir / "plugins" / "profile" / "*"
+                                    / "*.xplane.pb")))[-1]
+        self.notes, self.modules = [], set()
+        for plane in jax.profiler.ProfileData.from_file(path).planes:
+            for line in plane.lines:
+                for ev in line.events:
+                    name = str(ev.name)
+                    stats = {k: v for k, v in ev.stats}
+                    if name.startswith(PREFIX):
+                        self.notes.append(dict(
+                            stats, name=name[len(PREFIX):],
+                            start=float(ev.start_ns),
+                            end=float(ev.start_ns + ev.duration_ns)))
+                    elif "hlo_module" in stats:
+                        self.modules.add(str(stats["hlo_module"]))
+
+    def named(self, prefix):
+        return [n for n in self.notes if n["name"].startswith(prefix)]
+
+
+def _profiled(out_dir, body):
+    options = jax.profiler.ProfileOptions()
+    options.python_tracer_level = 0
+    options.host_tracer_level = 2
+    jax.profiler.start_trace(str(out_dir), profiler_options=options)
+    try:
+        result = body()
+    finally:
+        jax.profiler.stop_trace()
+    return result, Profile(out_dir)
+
+
+@pytest.fixture(scope="module")
+def lab_call(tmp_path_factory):
+    """One lab call with a recorder current and a profiler running
+    (after an untraced call, so that the traced one is warm)."""
+    _lab0_call()
+    tel = Telemetry(ring=1 << 14)
+
+    def body():
+        with tel_mod.use(tel):
+            return _lab0_call()
+
+    results, profile = _profiled(tmp_path_factory.mktemp("lab"), body)
+    return results, tel, profile
+
+
+@pytest.fixture(scope="module")
+def sharded_run(tmp_path_factory):
+    """A two-device sharded run, recorder current and attached,
+    profiler running."""
+    tel = Telemetry(ring=1 << 14)
+
+    def body():
+        with tel_mod.use(tel):
+            return _sharded(telemetry=tel).run()
+
+    out, profile = _profiled(tmp_path_factory.mktemp("mesh"), body)
+    return out, tel, profile
+
+
+def _phases(tel):
+    return [r for r in tel.ring if r["t"] == "phase"]
+
+
+# ------------------------------------------------------ the table of names
+
+def test_every_name_emitted_is_in_the_table(lab_call, sharded_run):
+    for _res, tel, profile in (lab_call, sharded_run):
+        emitted = ({n["name"] for n in profile.notes}
+                   | {r["name"] for r in _phases(tel)})
+        assert emitted and emitted <= set(tel_mod.PHASES), \
+            emitted - set(tel_mod.PHASES)
+    names = {n["name"] for n in lab_call[2].notes}
+    assert {"entry.tensor_bfs", "entry.bind", "entry.build_engine",
+            "entry.derive_root", "entry.search", "entry.replay",
+            "search.level", "dispatch.superstep",
+            "compile.event"} <= names
+    assert len(set(tel_mod.PHASES)) == len(tel_mod.PHASES)
+    assert len(set(tel_mod.DEVICE_SCOPES)) == len(tel_mod.DEVICE_SCOPES)
+
+
+def test_a_name_outside_the_table_raises():
+    assert tel_mod.check_names          # tests/conftest.py turned it on
+    with pytest.raises(ValueError, match="not in telemetry.PHASES"):
+        with tel_mod.phase("entry.made_up"):
+            pass
+    with pytest.raises(ValueError, match="not in telemetry.PHASES"):
+        tel_mod.mark("made.up")
+    with pytest.raises(ValueError, match="not in telemetry.PHASES"):
+        tel_mod.annotate("dispatch.made_up")
+
+
+# ------------------------------------------------------------ the span tree
+
+def test_phases_of_a_call_share_its_id_and_nest_under_the_entry(lab_call):
+    results, tel, profile = lab_call
+    assert results.end_condition.name == "GOAL_FOUND"
+    roots = [r for r in _phases(tel) if r["name"] == "entry.tensor_bfs"]
+    assert len(roots) == 1 and roots[0]["parent"] is None
+    call = roots[0]["call"]
+    assert isinstance(call, int) and call >= 1
+    rest = [r for r in _phases(tel) if r is not roots[0]]
+    assert rest and all(r["call"] == call for r in rest)
+    # the stages are the entry's children; levels lie under a run
+    stages = {r["name"]: r for r in rest if r["name"].startswith("entry.")}
+    assert set(stages) >= {"entry.bind", "entry.build_engine",
+                           "entry.derive_root", "entry.search",
+                           "entry.replay"}
+    assert all(r["parent"] == "entry.tensor_bfs" for r in stages.values())
+    assert {r["parent"] for r in rest if r["name"] == "search.level"} \
+        <= {"entry.search", "entry.warm_run"}
+    # the children's seconds fit inside the root's
+    assert sum(r["wall"] for r in stages.values()) <= roots[0]["wall"]
+    # and the same tree lies in the profile, on its clock
+    (root,) = profile.named("entry.tensor_bfs")
+    assert root["call"] == call and "pingpong" in root["key"]
+    # a binding's key is a tuple's repr: its quotes, commas and brackets
+    # would break the packing of the stats after it
+    assert not set(root["key"]) & set("#,='\"()")
+    inside = [n for n in profile.notes if n is not root]
+    assert all(n["call"] == call for n in inside)
+    assert all(root["start"] <= n["start"] and n["end"] <= root["end"]
+               for n in inside)
+
+
+def test_level_phases_carry_the_levels_own_counters(sharded_run):
+    out, tel, profile = sharded_run
+    levels = {n["depth"]: n for n in profile.named("search.level")}
+    assert sorted(levels) == [lv["depth"] for lv in out.levels]
+    before = 0
+    for lv in out.levels:
+        note = levels[lv["depth"]]
+        assert note["explored0"] == before
+        assert (note["explored"], note["unique"], note["chunks"],
+                note["next_frontier"]) == (
+            lv["explored"], lv["unique"], lv["chunks"],
+            lv["next_frontier"])
+        before = lv["explored"]
+    assert before == out.states_explored
+
+
+def test_dispatch_annotations_are_the_recorders_own_spans(lab_call,
+                                                          sharded_run):
+    for _res, tel, profile in (lab_call, sharded_run):
+        spans = [r for r in tel.ring if r["t"] == "span"]
+        notes = profile.named("dispatch.")
+        assert len(notes) == len(spans) == tel.summary()["spans"]
+        by_site = {}
+        for s in spans:
+            by_site[s["site"]] = by_site.get(s["site"], 0) + 1
+        by_note = {}
+        for n in notes:
+            site = n["name"][len("dispatch."):]
+            by_note[site] = by_note.get(site, 0) + 1
+        assert by_note == by_site
+        # dispatch phases are annotations only: the span is the record
+        assert not [r for r in _phases(tel)
+                    if r["name"].startswith("dispatch.")]
+
+
+# ------------------------------------------------------- the overhead guard
+
+def test_overhead_guard_with_a_recorder_current_and_a_profiler_running(
+        monkeypatch, tmp_path):
+    """The guard of tests/test_telemetry.py, with everything on:
+    dispatch counts and ``device_get`` counts are bit-identical."""
+    gets = []
+    real = engine.device_get
+
+    def spy(x):
+        gets.append(1)
+        return real(x)
+
+    monkeypatch.setattr(engine, "device_get", spy)
+    import dslabs_tpu.tpu.sharded as sharded_mod
+
+    monkeypatch.setattr(sharded_mod, "device_get", spy)
+
+    def run(telemetry):
+        counts = {}
+        s = _sharded(telemetry=telemetry)
+
+        def hook(tag, fn, *args):
+            counts[tag] = counts.get(tag, 0) + 1
+            return fn(*args)
+
+        s._dispatch_hook = hook
+        del gets[:]
+        out = s.run()
+        return counts, len(gets), (out.unique_states, out.end_condition,
+                                   out.depth)
+
+    bare = run(None)
+    assert bare[1] > 0
+    tel = Telemetry(flight_log=str(tmp_path / "run" / "flight.jsonl"))
+
+    def body():
+        with tel_mod.use(tel):
+            return run(tel)
+
+    traced, profile = _profiled(tmp_path / "prof", body)
+    assert traced == bare
+    assert len(profile.named("search.level")) == traced[2][2] > 1
+
+
+# ----------------------------------------------------------- device scopes
+
+@pytest.fixture(scope="module")
+def compiled():
+    s = _sharded()
+    s.aot_warmup()
+    return s
+
+
+def test_every_device_scope_is_in_the_compiled_programs(compiled):
+    text = compiled._aot_exes["superstep"].as_text()
+    for scope in tel_mod.DEVICE_SCOPES:
+        where = (compiled._aot_exes["promote"].as_text()
+                 if scope == "promote" else text)
+        assert f"/{tel_mod.SCOPE_PREFIX}{scope}/" in where, scope
+    scopes = tel_mod.scopes_of_hlo(text)
+    assert {s for s, _named in scopes.values()} <= set(
+        tel_mod.DEVICE_SCOPES)
+    instructions = [ln.split("=")[0].split()[-1].lstrip("%")
+                    for ln in text.splitlines()
+                    if " = " in ln and not ln.lstrip().startswith("//")]
+    named = sum(1 for name in instructions
+                if scopes.get(name, (None, False))[1])
+    assert named > len(instructions) / 2, (named, len(instructions))
+    assert {s for s, _named in tel_mod.scopes_of_hlo(
+        compiled._aot_exes["promote"].as_text()).values()} == {"promote"}
+
+
+class _Exe:
+    """Stands for a compiled program: all the registry asks of one."""
+
+    def __init__(self, text):
+        self.text = text
+
+    def as_text(self):
+        return self.text
+
+
+def test_a_name_maps_to_its_program_or_to_nothing(compiled):
+    assert tel_mod.program_scopes("no_such_program") is None
+    one = 'ROOT %a.1 = u32[] add(), metadata={op_name="jit(f)/dslabs.route/add"}'
+    two = one.replace("dslabs.route", "dslabs.append")
+    first, again, other = _Exe(one), _Exe(one), _Exe(two)
+    tel_mod.register_program("_test_program", first)
+    assert tel_mod.program_scopes("_test_program") == {
+        "a.1": ("route", True)}
+    # the same program compiled again (an engine rebuilt) changes nothing
+    tel_mod.register_program("_test_program", again)
+    assert tel_mod.program_scopes("_test_program") == {
+        "a.1": ("route", True)}
+    # a different program under the same name: which one a trace ran
+    # cannot be told, so no attribution — until its engine is gone
+    tel_mod.register_program("_test_program", other)
+    assert tel_mod.program_scopes("_test_program") is None
+    del other
+    assert tel_mod.program_scopes("_test_program") == {
+        "a.1": ("route", True)}
+    # aot_warmup registered the real ones
+    assert compiled._aot_exes["superstep"] in tel_mod._PROGRAMS["superstep"]
+
+
+def test_scopes_of_hlo_reads_op_names_fusions_and_leaves_the_rest():
+    text = """HloModule jit_superstep
+
+%fused_computation.1 (p: u32[8]) -> u32[8] {
+  %p = u32[8]{0} parameter(0)
+  ROOT %add.9 = u32[8]{0} add(%p, %p), metadata={op_name="jit(superstep)/while/body/dslabs.visited_insert/dslabs.visited_insert/add"}
+}
+
+ENTRY %main.3 (a: u32[8]) -> u32[8] {
+  %a = u32[8]{0} parameter(0)
+  %copy.1 = u32[8]{0} copy(%a)
+  %sort.2 = u32[8]{0} sort(%copy.1), metadata={op_name="jit(superstep)/dslabs.route/sort" stack_frame_id=3}
+  %fusion.7 = u32[8]{0} fusion(%sort.2), kind=kLoop, calls=%fused_computation.1
+  ROOT %all-to-all.4 = u32[8]{0} all-to-all(%fusion.7), metadata={op_name="jit(superstep)/dslabs.exchange/all_to_all"}
+}
+"""
+    # named by the operation's own op_name (a fusion: its root's) ...
+    named = {"add.9": ("visited_insert", True), "sort.2": ("route", True),
+             "fusion.7": ("visited_insert", True),
+             "all-to-all.4": ("exchange", True)}
+    # ... and the compiler's own copy, which names none, under the scope
+    # of what it feeds, marked as a guess; so the parameters
+    assert tel_mod.scopes_of_hlo(text) == dict(
+        named, **{"copy.1": ("route", False), "a": ("route", False),
+                  "p": ("visited_insert", False)})
+
+
+def test_programs_have_names_of_their_own_in_a_profile(lab_call,
+                                                       sharded_run):
+    for _res, _tel, profile in (lab_call, sharded_run):
+        assert {"jit_superstep", "jit_promote",
+                "jit_init_carry"} <= profile.modules
+        assert not [m for m in profile.modules if "lambda" in m]
+
+
+# ---------------------------------------------------------- compile events
+
+def test_a_cold_jit_leaves_compile_events_and_the_totals_grow():
+    compile_cache.setup()
+    before = compile_cache.totals()
+    tel = Telemetry()
+
+    def never_seen_before(x):
+        return (x * 3 + 1) % 7
+
+    with tel_mod.use(tel):
+        jax.jit(never_seen_before)(jax.numpy.arange(5))
+    after = compile_cache.totals()
+    for kind in ("trace", "lower", "backend_compile"):
+        assert after[kind + "_n"] > before[kind + "_n"], kind
+        assert after[kind + "_s"] > before[kind + "_s"], kind
+    h = tel.registry.histograms
+    assert h["compile_secs.trace"].count >= 1
+    assert h["compile_secs.backend_compile"].count >= 1
+    # they are too many for the ring: flight log and histograms only
+    assert not [r for r in tel.ring if r.get("name") == "compile.event"]
+    # nested traces are counted once: the totals' seconds fit inside
+    # the wall clock since ``before``
+    assert after["trace_s"] - before["trace_s"] < 60
+
+
+def test_compile_events_reach_the_flight_log(tmp_path):
+    compile_cache.setup()
+    flight = str(tmp_path / "flight.jsonl")
+    tel = Telemetry(flight_log=flight)
+
+    def also_never_seen(x):
+        return x * 5 - 2
+
+    with tel_mod.use(tel):
+        jax.jit(also_never_seen)(jax.numpy.arange(3))
+    tel.close()
+    marks = [r for r in tel_mod.read_flight(flight)
+             if r.get("t") == "phase" and r["name"] == "compile.event"]
+    assert {"trace", "lower", "backend_compile"} <= {m["kind"]
+                                                     for m in marks}
+    mine = [m for m in marks if "also_never_seen" in m.get("fun", "")]
+    assert mine and all(m["wall"] < 0.01 and m["secs"] >= 0 for m in mine)
+
+
+# ------------------------------------------------------------ off means off
+
+def test_a_phase_with_no_recorder_and_no_profiler_writes_nothing():
+    assert tel_mod.current() is None
+    tel = Telemetry()
+    assert tel_mod.annotate("dispatch.superstep", i=1) is \
+        tel_mod.annotate("entry.search")      # the one null annotation
+    with tel_mod.phase("entry.search", note=1) as ph:
+        ph.set(more=2)
+        tel_mod.mark("compile.event", kind="trace", secs=0.0)
+    with tel_mod.call("entry.tensor_bfs"):
+        pass
+    assert not _phases(tel) and tel_mod.current() is None
+    # and with the recorder current, the same lines do
+    with tel_mod.use(tel):
+        assert tel_mod.current() is tel
+        with tel_mod.phase("entry.search", note=1) as ph:
+            ph.set(more=2)
+    assert tel_mod.current() is None
+    (rec,) = _phases(tel)
+    assert (rec["name"], rec["note"], rec["more"], rec["parent"],
+            rec["call"]) == ("entry.search", 1, 2, None, None)
+
+
+def test_report_prints_a_phase_table_per_call(lab_call):
+    _res, tel, _profile = lab_call
+    report = tel_mod.build_report(list(tel.ring))
+    (call,) = report["phases"]
+    rows = report["phases"][call]
+    assert rows["entry.tensor_bfs"]["n"] == 1
+    assert rows["search.level"]["n"] >= 2
+    text = tel_mod.render_report(report)
+    assert "-- phases by call --" in text
+    assert any(ln.split()[:2] == [call, "entry.build_engine"]
+               for ln in text.splitlines())

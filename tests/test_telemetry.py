@@ -632,21 +632,6 @@ def test_supervisor_retries_become_events_and_span_retries():
     assert sum(s["retries"] for s in _spans(tel)) == out.retries
 
 
-def test_profiler_window_knob_is_safe(tmp_path, monkeypatch):
-    """DSLABS_PROFILE wraps post-warmup dispatches in jax.profiler
-    windows; whatever the platform does with that, the search itself
-    must be unaffected (the knob can never take a run down)."""
-    monkeypatch.setenv("DSLABS_PROFILE", str(tmp_path / "prof"))
-    monkeypatch.setenv("DSLABS_PROFILE_STEPS", "2")
-    tel = Telemetry()
-    search = TensorSearch(_pruned_pingpong(), max_depth=8,
-                          frontier_cap=1 << 10, visited_cap=1 << 12)
-    tel.attach(search)
-    out = search.run()
-    assert out.end_condition == "SPACE_EXHAUSTED"
-    assert not tel._profile.active          # window closed behind itself
-
-
 # ------------------------------------------------- bench JSON schema pin
 
 @pytest.mark.slow
