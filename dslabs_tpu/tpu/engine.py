@@ -2774,10 +2774,9 @@ class TensorSearch:
             frontier = np.asarray(carry["cur"][:nxt_n])
         else:
             frontier = np.zeros((0, self.plane), np.int32)
-        table = np.asarray(carry["visited"])[:-1]
-        occ = ~(table == visited_mod.MAXU32).all(axis=1)
-        self._kick_ckpt(frontier, table[occ], depth, explored, elapsed,
-                        vis_over)
+        self._kick_ckpt(frontier,
+                        visited_mod.host_occupied(carry["visited"]),
+                        depth, explored, elapsed, vis_over)
 
     def _device_attempt(self, state, cap: int, user_cap: int,
                         t0, ck=None) -> Optional[SearchOutcome]:

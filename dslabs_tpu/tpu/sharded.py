@@ -108,8 +108,8 @@ CARRY_PARTITION_RULES = (
     # the packed wire format (ISSUE 18) cur/nxt hold PACKED words
     # (width = descriptor.words), same placement.
     (r"^(cur|nxt|tmeta)$", lambda ax: P(ax)),
-    # The owner-sharded visited hash table (one [V+1, 4] shard per
-    # device; owner = key lane 0 mod D picks the shard).
+    # The owner-sharded visited hash table (one visited.table_shape
+    # block of rows per device; owner = key lane 0 mod D picks it).
     (r"^visited$", lambda ax: P(ax)),
     # Terminal-flag rows/meta/counters: one n_flags block per device.
     (r"^(flag_rows|flag_meta|flag_cnt)$", lambda ax: P(ax)),
@@ -170,8 +170,9 @@ class ShardedTensorSearch(TensorSearch):
       cur_n    [1]        int32   occupancy of cur
       nxt      [F+1, lanes]       next-frontier accumulator (+1 dump row)
       nxt_n    [1]                occupancy of nxt
-      visited  [V+1, 4]   uint32  open-addressing hash table of 128-bit
-                                  keys (+1 dump row); EMPTY = all-MAX
+      visited  [32, V/8]  uint32  open-addressing hash table of 128-bit
+                                  keys, one bucket a column
+                                  (visited.table_shape); EMPTY = all-MAX
       vis_n    [1]                number of keys inserted
       counters: explored / overflow / routed-drop / frontier-drop
       flag_cnt [n_flags], flag_rows [n_flags, lanes]: terminal detection
@@ -1396,9 +1397,8 @@ class ShardedTensorSearch(TensorSearch):
                 "noapp": jnp.zeros((D,), jnp.int32),
                 "nxt": jnp.zeros((D * (F + 1), plane), jnp.int32),
                 "nxt_n": jnp.zeros((D,), jnp.int32),
-                "visited": jnp.full((D * (V + 1), 4), MAXU32,
-                                    jnp.uint32).at[
-                    owner * (V + 1) + home].set(k0),
+                "visited": visited_mod.with_root(
+                    visited_mod.empty_table(V, D), k0, home, owner),
                 "vis_n": onehot_d.astype(jnp.int32),
                 "explored": jnp.zeros((D,), jnp.int32),
                 "overflow": jnp.zeros((D,), jnp.int32),
@@ -1443,7 +1443,8 @@ class ShardedTensorSearch(TensorSearch):
             "noapp": sd("noapp", (D,)),
             "nxt": sd("nxt", (D * (F + 1), self.plane)),
             "nxt_n": sd("nxt_n", (D,)),
-            "visited": sd("visited", (D * (V + 1), 4), jnp.uint32),
+            "visited": sd("visited", visited_mod.table_shape(V, D),
+                          jnp.uint32),
             "vis_n": sd("vis_n", (D,)),
             "explored": sd("explored", (D,)),
             "overflow": sd("overflow", (D,)),
@@ -1768,9 +1769,6 @@ class ShardedTensorSearch(TensorSearch):
         parts = [cur[d, :cur_n[d]] for d in range(D)]
         frontier = (np.concatenate(parts) if cur_n.sum()
                     else np.zeros((0, self.plane), np.int32))
-        vis = np.asarray(snap["visited"]).reshape(
-            D, self.v_cap + 1, 4)[:, :-1]
-        occ = ~(vis == MAXU32).all(axis=2)
         fp_map = None
         if self.record_trace and self._fp_map:
             fp_map = np.asarray(
@@ -1794,7 +1792,8 @@ class ShardedTensorSearch(TensorSearch):
         ckpt_mod.save(self.checkpoint_path, ckpt_mod.SearchCheckpoint(
             fingerprint=self._ckpt_fingerprint(), depth=depth,
             explored=int(np.asarray(snap["explored"]).sum()),
-            elapsed=elapsed, frontier=frontier, visited_keys=vis[occ],
+            elapsed=elapsed, frontier=frontier,
+            visited_keys=visited_mod.host_occupied(snap["visited"]),
             vis_over=int(np.asarray(snap["vis_over"]).sum()),
             dropped=int(np.asarray(snap["drops"]).sum()),
             fp_map=fp_map, extra=extra))
@@ -1999,7 +1998,7 @@ class ShardedTensorSearch(TensorSearch):
 
         def spill_evict(c):
             out = dict(c)
-            out["visited"] = jnp.full((V + 1, 4), MAXU32, jnp.uint32)
+            out["visited"] = visited_mod.empty_table(V)
             out["vis_n"] = jnp.zeros((1,), jnp.int32)
             out["f_full"] = jnp.zeros((1,), jnp.int32)
             return out
@@ -2069,12 +2068,9 @@ class ShardedTensorSearch(TensorSearch):
         """Bulk eviction: every shard's occupied table lines -> the
         (global) host tier; all tables restart empty."""
         sp = self._spill
-        D, V = self.n_devices, self.v_cap
 
         def fetch():
-            vis = np.asarray(carry["visited"]).reshape(D, V + 1, 4)
-            return np.concatenate(
-                [visited_mod.host_occupied(vis[d]) for d in range(D)])
+            return visited_mod.host_occupied(carry["visited"])
 
         occ = self._dispatch("sharded.spill_evict", fetch)
         sp.submit_drain(lambda: sp.evict(occ), evict=True)
@@ -2167,10 +2163,7 @@ class ShardedTensorSearch(TensorSearch):
         from dslabs_tpu.tpu import checkpoint as ckpt_mod
 
         sp = self._spill
-        D, V = self.n_devices, self.v_cap
-        vis = np.asarray(carry["visited"]).reshape(D, V + 1, 4)
-        occ = np.concatenate(
-            [visited_mod.host_occupied(vis[d]) for d in range(D)])
+        occ = visited_mod.host_occupied(carry["visited"])
         # The spool holds packed rows for non-delta descriptors
         # (_sh_spill_drain) — the dump then carries the encoding
         # marker; delta spools are raw, so their dump is raw too.

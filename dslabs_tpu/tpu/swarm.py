@@ -793,12 +793,11 @@ class SwarmSearch(TensorSearch):
         """Host copies at the round boundary (before the next round's
         dispatch donates the buffers), file write drained async — the
         engine checkpoint discipline."""
-        D, K, S, V = (self.n_devices, self.walkers, self.max_steps,
-                      self.visited_cap)
-        vis = np.asarray(carry["visited"]).reshape(D, V + 1, 4)[:, :-1]
-        occ = ~(vis == visited_mod.MAXU32).all(axis=2)
-        vdev = occ.sum(axis=1).astype(np.int64)
-        keys = vis[occ]
+        D, K, S = self.n_devices, self.walkers, self.max_steps
+        per_dev = [visited_mod.host_occupied(t) for t in
+                   np.split(np.asarray(carry["visited"]), D)]
+        vdev = np.asarray([len(k) for k in per_dev], np.int64)
+        keys = np.concatenate(per_dev)
         extra = {
             "depths": np.asarray(carry["depths"]),
             "hists": np.asarray(carry["hists"]),

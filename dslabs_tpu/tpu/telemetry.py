@@ -572,8 +572,8 @@ def scopes_of_hlo(text: str) -> Dict[str, Tuple[str, bool]]:
     text.  ``named``: the scope is the innermost ``dslabs.<scope>`` of
     the instruction's own ``op_name`` (a fusion without one takes its
     root's).  The compiler's own operations carry no ``op_name`` (on
-    the chip: the relayout reshapes and copies around the visited
-    table, a third of the superstep); each takes the scope of what it
+    the chip: copies and reshapes it adds around the program's own,
+    6 % of the superstep); each takes the scope of what it
     feeds — if all its scoped users agree — else of what feeds it,
     repeated until nothing changes, and is ``named`` False: a guess by
     neighbourhood, which a reader keeps apart from what the program

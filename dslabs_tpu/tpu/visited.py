@@ -7,14 +7,30 @@ Extracted from sharded.py so the probe/insert machinery exists exactly
 once (hash compaction after Stern & Dill; the GPUexplore-style BFS table
 in PAPERS.md).
 
-Layout: ``[V + 1, 4]`` uint32 where V (a power of two) is the slot
-count, viewed as ``[V/8, 8]``-slot buckets so one probe iteration reads
-a whole aligned 128-byte line; the trailing row is the scatter dump for
-clipped writes.  EMPTY slots are all-MAX (a real all-MAX key — the
-2^-128 collider — is remapped by :func:`sanitize_keys`).  Membership and
-insert happen in one bounded probe loop; claim conflicts (equal keys or
-distinct keys hashing to one bucket) are serialised by a hashed
-per-bucket min-index reservation, so no sort of the batch is needed.
+Layout: ``[BKT * 4, V / BKT]`` uint32 (``[32, VB]``) where V (a power of
+two) is the slot count: COLUMN ``b`` is bucket ``b`` and row ``s * 4 +
+l`` holds lane ``l`` of its slot ``s``.  This module owns the layout —
+:func:`table_shape`, :func:`empty_table`, :func:`with_root`,
+:func:`insert`, :func:`build_table` and :func:`host_occupied` are the
+only places that know it; per-device tables stack along the rows
+(``[D * 32, VB]``, sharded ``P(axis)``).  One probe iteration gathers
+each key's whole bucket column (``table[:, bkt_i]``), patches the
+winner's four words into the gathered column and scatters whole columns
+back; a key that did not win scatters to column ``VB``, which
+``mode="drop"`` discards — the no-op write the old ``[V + 1, 4]``
+layout spent a dump row on.  Gather and scatter address the table in
+the one layout the carry has (32 rows = 4 sublane tiles, VB a multiple
+of 128), so the chip converts nothing: compiled for a v5e at 2^24
+slots and 49,152 keys the insert takes 28 MB of temporaries, where
+``table[:V].reshape(VB, 8, 4)[bkt_i]`` + ``table.at[dst].set`` took
+4,592 MB and relaid the 268 MB table out four times per probe
+iteration (PERF.md, PR 26).  EMPTY slots are all-MAX (a real all-MAX
+key — the 2^-128 collider — is remapped by :func:`sanitize_keys`).
+Membership and insert happen in one bounded probe loop; claim conflicts
+(equal keys or distinct keys hashing to one bucket) are serialised by a
+hashed per-bucket min-index reservation — at most one contender wins a
+bucket per iteration, which is what makes the whole-column write
+race-free — so no sort of the batch is needed.
 After ~2 full-batch iterations only deep bucket chains remain; those are
 compacted into a small tail so late iterations stop re-scanning the
 whole batch (the measured high-load pathology in round 3).
@@ -53,8 +69,9 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-__all__ = ["BKT", "MAXU32", "empty_table", "sanitize_keys",
-           "host_sanitize_key", "host_home_slot", "host_occupied",
+__all__ = ["BKT", "MAXU32", "table_shape", "empty_table", "with_root",
+           "sanitize_keys", "host_sanitize_key", "host_home_slot",
+           "host_occupied",
            "insert", "insert_jnp", "pallas_insert", "pallas_mode",
            "force_jnp", "dispatch_site_program", "build_table"]
 
@@ -79,9 +96,10 @@ def force_jnp():
     finally:
         _FORCE_JNP -= 1
 
-# Slots per bucket: the probe loop reads whole buckets (one aligned
-# 128-byte line of 8 x 16-byte keys).
+# Slots per bucket: the probe loop reads whole buckets (one column of
+# 8 x 16-byte keys).
 BKT = 8
+ROWS = BKT * 4          # table rows: row s * 4 + l = lane l of slot s
 MAXU32 = np.uint32(0xFFFFFFFF)
 
 
@@ -92,10 +110,25 @@ def check_cap(cap: int) -> None:
             f"(hash-table slot arithmetic), got {cap}")
 
 
-def empty_table(cap: int) -> jnp.ndarray:
-    """A fresh ``[cap + 1, 4]`` all-EMPTY table (+1 scatter-dump row)."""
+def table_shape(cap: int, n_devices: int = 1) -> Tuple[int, int]:
+    """Shape of ``n_devices`` ``cap``-slot tables stacked along the rows
+    (one ``[ROWS, cap / BKT]`` block per device)."""
     check_cap(cap)
-    return jnp.full((cap + 1, 4), MAXU32, jnp.uint32)
+    return (n_devices * ROWS, cap // BKT)
+
+
+def empty_table(cap: int, n_devices: int = 1) -> jnp.ndarray:
+    """Fresh all-EMPTY table(s) of :func:`table_shape`."""
+    return jnp.full(table_shape(cap, n_devices), MAXU32, jnp.uint32)
+
+
+def with_root(table: jnp.ndarray, key: jnp.ndarray, home: int,
+              owner: int = 0) -> jnp.ndarray:
+    """``table`` (possibly stacked) with the [4] ``key`` written to slot
+    ``home`` (:func:`host_home_slot`) of device ``owner``'s block — how
+    a carry initialiser places the root without a probe."""
+    rows = owner * ROWS + (home % BKT) * 4 + np.arange(4)
+    return table.at[rows, home // BKT].set(key)
 
 
 def sanitize_keys(keys: jnp.ndarray, valid: jnp.ndarray) -> jnp.ndarray:
@@ -124,13 +157,15 @@ def host_home_slot(key: np.ndarray, cap: int) -> int:
 
 
 def host_occupied(table: np.ndarray) -> np.ndarray:
-    """Occupied key lines of a HOST copy of a ``[V + 1, 4]`` table (the
-    trailing scatter-dump row excluded) — the bulk-eviction readback of
-    the spill tier (tpu/spill.py) and the checkpoint writers share this
-    one definition of "occupied" (any lane != EMPTY's all-MAX)."""
-    table = np.asarray(table)[:-1]
-    occ = ~(table == MAXU32).all(axis=1)
-    return table[occ]
+    """Occupied keys, ``[K, 4]``, of a HOST copy of a table or of a
+    per-device stack of tables (device by device, then in slot order) —
+    the bulk-eviction readback of the spill tier (tpu/spill.py) and the
+    checkpoint writers share this one definition of "occupied" (any
+    lane != EMPTY's all-MAX)."""
+    table = np.asarray(table)
+    slots = table.reshape(-1, BKT, 4, table.shape[-1]).transpose(
+        0, 3, 1, 2).reshape(-1, 4)
+    return slots[~(slots == MAXU32).all(axis=1)]
 
 
 def build_table(cap: int, keys) -> Tuple[jnp.ndarray, int, int]:
@@ -148,26 +183,30 @@ def build_table(cap: int, keys) -> Tuple[jnp.ndarray, int, int]:
             int(np.asarray(jnp.sum(unres))))
 
 
-def _probe_iter(table, keys, bkt_i, ps, unres, idx, V, RT, batch_n):
-    """One probe iteration over any batch (idx = each row's identity for
-    reservation tie-breaks; rows with unres=False are inert).  Reads each
-    key's whole bucket, resolves membership across its BKT slots, and
-    lets the minimum-index contender of each bucket claim the first
-    empty slot; losers re-read the same bucket next iteration, full
-    buckets advance by the key's double-hash step."""
-    VB = V // BKT
-    bkt = table[:V].reshape(VB, BKT, 4)[bkt_i]
-    eq = jnp.any(jnp.all(bkt == keys[:, None, :], axis=2), axis=1)
-    empty = jnp.all(bkt == MAXU32, axis=2)
-    has_empty = jnp.any(empty, axis=1)
-    first_empty = jnp.argmax(empty, axis=1)
+def _probe_iter(table, keys_t, bkt_i, ps, unres, idx, RT, batch_n):
+    """One probe iteration over any batch (keys_t = the keys, [4, n];
+    idx = each row's identity for reservation tie-breaks; rows with
+    unres=False are inert).  Gathers each key's whole bucket column,
+    resolves membership across its BKT slots, and lets the
+    minimum-index contender of each bucket claim the first empty slot
+    and write the column back; losers re-read the same bucket next
+    iteration, full buckets advance by the key's double-hash step."""
+    VB = table.shape[1]
+    cols = table[:, bkt_i]
+    bkt = cols.reshape(BKT, 4, -1)
+    eq = jnp.any(jnp.all(bkt == keys_t, axis=1), axis=0)
+    empty = jnp.all(bkt == MAXU32, axis=1)
+    has_empty = jnp.any(empty, axis=0)
+    first_empty = jnp.argmax(empty, axis=0)
     want = unres & ~eq & has_empty
     rcell = bkt_i & (RT - 1)
     res = jnp.full((RT + 1,), batch_n, jnp.int32).at[
         jnp.where(want, rcell, RT)].min(idx)
     winner = want & (res[rcell] == idx)
-    dst = jnp.where(winner, bkt_i * BKT + first_empty, V)
-    table = table.at[dst].set(keys)
+    new = jnp.where(jnp.arange(BKT)[:, None, None] == first_empty,
+                    keys_t, bkt).reshape(cols.shape)
+    table = table.at[:, jnp.where(winner, bkt_i, VB)].set(
+        new, mode="drop")
     newly = eq | winner
     nb = (bkt_i.astype(jnp.uint32) + ps).astype(jnp.int32) & (VB - 1)
     bkt_i = jnp.where(unres & ~newly & ~has_empty, nb, bkt_i)
@@ -181,8 +220,8 @@ def insert_jnp(table: jnp.ndarray, keys: jnp.ndarray, valid: jnp.ndarray,
     pure-jnp reference implementation (the Pallas kernel's parity
     oracle AND the CPU/interpret fallback; :func:`insert` dispatches).
 
-    ``table`` [V+1, 4] uint32 (V a power of two; last row = scatter
-    dump), ``keys`` [N, 4] uint32 (pre-:func:`sanitize_keys`-ed or raw —
+    ``table`` [32, V/8] uint32 (:func:`table_shape`; V a power of
+    two), ``keys`` [N, 4] uint32 (pre-:func:`sanitize_keys`-ed or raw —
     sanitisation is applied here), ``valid`` [N] bool.
 
     Returns ``(table', inserted, unresolved)`` where ``inserted[i]`` is
@@ -200,11 +239,11 @@ def insert_jnp(table: jnp.ndarray, keys: jnp.ndarray, valid: jnp.ndarray,
     # wrapper function: a frame more under all that the insert traces
     # changes how long the tracing takes (PERF.md section 7).
     with jax.named_scope("dslabs.visited_insert"):
-        V = table.shape[0] - 1
-        check_cap(V)
-        VB = V // BKT
+        VB = table.shape[1]
+        check_cap(VB * BKT)
         n = keys.shape[0]
         skeys = sanitize_keys(keys, valid)
+        keys_t = skeys.T
         slot0 = (skeys[:, 2] & jnp.uint32(VB - 1)).astype(jnp.int32)
         pstep = (skeys[:, 1] | jnp.uint32(1)).astype(jnp.uint32)
         # Reservations go through a small HASHED table (bkt_i mod RT): a
@@ -227,7 +266,7 @@ def insert_jnp(table: jnp.ndarray, keys: jnp.ndarray, valid: jnp.ndarray,
         def full_body(st):
             tbl, bkt_i, resolved, ins, it = st
             tbl, bkt_i, newly, winner = _probe_iter(
-                tbl, skeys, bkt_i, pstep, ~resolved, ridx, V, RT, n)
+                tbl, keys_t, bkt_i, pstep, ~resolved, ridx, RT, n)
             return tbl, bkt_i, resolved | newly, ins | winner, it + 1
 
         table, bkt_i, resolved, inserted, _ = jax.lax.while_loop(
@@ -238,7 +277,7 @@ def insert_jnp(table: jnp.ndarray, keys: jnp.ndarray, valid: jnp.ndarray,
         tail_idx = jnp.nonzero(~resolved, size=T, fill_value=n)[0]
         tclip = tail_idx.clip(0, n - 1)
         tval = tail_idx < n
-        t_keys = skeys[tclip]
+        t_keys_t = keys_t[:, tclip]
         t_bkt = bkt_i[tclip]
         t_ps = pstep[tclip]
         t_id = jnp.arange(T, dtype=jnp.int32)
@@ -250,7 +289,7 @@ def insert_jnp(table: jnp.ndarray, keys: jnp.ndarray, valid: jnp.ndarray,
         def tail_body(st):
             tbl, tb, t_unres, t_ins, it = st
             tbl, tb, newly, winner = _probe_iter(
-                tbl, t_keys, tb, t_ps, t_unres, t_id, V, RT, n)
+                tbl, t_keys_t, tb, t_ps, t_unres, t_id, RT, n)
             return tbl, tb, t_unres & ~newly, t_ins | winner, it + 1
 
         table, _, t_unres, t_ins, _ = jax.lax.while_loop(
@@ -374,8 +413,7 @@ def dispatch_site_program(cap: int, batch: int):
     auditor lowers (J1/J2/J4: no callbacks, no f64, no collectives in
     the single-device kernel) and the profiler's hot-site table counts
     via ``telemetry.DISPATCH_SITES``."""
-    check_cap(cap)
-    args = (jax.ShapeDtypeStruct((cap + 1, 4), jnp.uint32),
+    args = (jax.ShapeDtypeStruct(table_shape(cap), jnp.uint32),
             jax.ShapeDtypeStruct((batch, 4), jnp.uint32),
             jax.ShapeDtypeStruct((batch,), jnp.bool_))
 

@@ -20,6 +20,7 @@ The flagship whole-program compiles take minutes and are marked
 """
 
 import os
+import re
 
 import numpy as np
 import pytest
@@ -65,7 +66,7 @@ def _mesh(topo, n):
 
 
 def _insert_args(cap, batch, sharding):
-    return (jax.ShapeDtypeStruct((cap + 1, 4), jnp.uint32,
+    return (jax.ShapeDtypeStruct(visited.table_shape(cap), jnp.uint32,
                                  sharding=sharding),
             jax.ShapeDtypeStruct((batch, 4), jnp.uint32,
                                  sharding=sharding),
@@ -89,6 +90,89 @@ def test_default_visited_insert_compiles(one_chip, cap, monkeypatch):
     mem = compiled.memory_analysis()
     assert (mem.argument_size_in_bytes + mem.temp_size_in_bytes
             < HBM_BYTES)
+
+
+_PLUMBING = {"parameter", "tuple", "get-tuple-element", "while",
+             "bitcast"}
+
+
+def _table_sized_in_loops(text, limit=1 << 21):
+    """``[(computation, instruction, opcode)]`` of an optimised HLO
+    module: every instruction with ``limit`` or more elements in a
+    computation that a ``while`` reaches (body, condition, and what they
+    call), less the plumbing that only passes the carry along and less
+    the scatter that updates it in place (the ``scatter`` itself and
+    the fusion around it)."""
+    comps, cur = {}, None
+    for line in text.splitlines():
+        head = re.match(r"(?:ENTRY\s+)?%?([\w.\-]+)\s*\(.*->.*\{\s*$", line)
+        if head:
+            cur = comps.setdefault(head.group(1), [])
+        elif line.startswith("}"):
+            cur = None
+        elif cur is not None and " = " in line:
+            cur.append(line.strip())
+    called = re.compile(
+        r"(?:body|condition|calls|to_apply|true_computation|"
+        r"false_computation)=%?([\w.\-]+)")
+    todo = [c for lines in comps.values() for ln in lines
+            if " while(" in ln
+            for c in re.findall(r"(?:body|condition)=%?([\w.\-]+)", ln)]
+    assert todo, "no while loop in the program"
+    reached = set()
+    while todo:
+        c = todo.pop()
+        if c not in reached and c in comps:
+            reached.add(c)
+            for ln in comps[c]:
+                todo += called.findall(ln)
+                for group in re.findall(
+                        r"branch_computations=\{([^}]*)\}", ln):
+                    todo += [x.strip().lstrip("%")
+                             for x in group.split(",")]
+    big = []
+    for c in sorted(reached):
+        for ln in comps[c]:
+            m = re.match(
+                r"(?:ROOT\s+)?%?([\w.\-]+) = (.*?) ([\w\-]+)\(", ln)
+            if m is None or m.group(3) in _PLUMBING:
+                continue
+            sizes = [int(np.prod([int(d) for d in dims.split(",") if d]))
+                     for dims in re.findall(r"\w+\[([\d,]*)\]",
+                                            m.group(2))]
+            in_place = m.group(3) == "scatter" or (
+                m.group(3) == "fusion" and any(
+                    " scatter(" in x
+                    for x in comps.get(called.search(ln).group(1), ())))
+            if max(sizes, default=0) >= limit and not in_place:
+                big.append((c, m.group(1), m.group(3)))
+    return big
+
+
+@pytest.mark.parametrize("nested", [False, True],
+                         ids=["alone", "in-a-16-step-loop"])
+def test_flagship_insert_converts_no_table(one_chip, nested, monkeypatch):
+    """The default insert at the flagship's 2^24 slots and one chunk
+    step's 49,152 keys keeps the table in ONE layout: under 64 MB of
+    temporaries (the ``[V + 1, 4]`` table took 4,592 MB: a slice, a
+    relayout loop, a reshape and a transposing copy of the 268 MB
+    table per probe iteration) and, inside the loops, no table-sized
+    instruction but the scatter that updates the carry in place —
+    alone and as the superstep nests it, in an outer loop."""
+    monkeypatch.delenv("DSLABS_VISITED_PALLAS", raising=False)
+
+    def insert16(t, k, v):
+        def body(i, c):
+            t, n = c
+            t, ins, _ = visited.insert(t, k + i.astype(jnp.uint32), v)
+            return t, n + jnp.sum(ins)
+        return jax.lax.fori_loop(0, 16, body, (t, jnp.int32(0)))
+
+    fn = jax.jit(insert16 if nested else visited.insert,
+                 donate_argnums=0)
+    compiled = fn.lower(*_insert_args(1 << 24, 49152, one_chip)).compile()
+    assert compiled.memory_analysis().temp_size_in_bytes < 64 << 20
+    assert _table_sized_in_loops(compiled.as_text()) == []
 
 
 def test_pallas_insert_is_refused_by_mosaic(one_chip):

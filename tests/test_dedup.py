@@ -58,8 +58,8 @@ def test_bucket_collision_spills_to_probe_chain():
     assert int(ins.sum()) == keys.shape[0]
     assert int(unres.sum()) == 0
     # Home bucket completely full, spill landed elsewhere.
-    home = np.asarray(table)[3 * BKT:(3 + 1) * BKT]
-    assert (home != np.uint32(0xFFFFFFFF)).any(axis=1).all()
+    home = visited_mod.host_occupied(np.asarray(table)[:, 3:4])
+    assert home.shape[0] == BKT
     _, ins2, unres2 = visited_mod.insert(table, keys, valid)
     assert int(ins2.sum()) == 0 and int(unres2.sum()) == 0
 
@@ -73,8 +73,7 @@ def test_in_batch_duplicates_insert_once():
         visited_mod.empty_table(cap), dup, valid)
     assert int(ins.sum()) == 4          # one copy of each distinct key
     assert int(unres.sum()) == 0
-    occupied = (np.asarray(table)[:cap] != np.uint32(0xFFFFFFFF)).any(axis=1)
-    assert int(occupied.sum()) == 4
+    assert visited_mod.host_occupied(table).shape[0] == 4
 
 
 def test_full_table_overflow_is_visible_and_fresh():
@@ -98,6 +97,182 @@ def test_full_table_overflow_is_visible_and_fresh():
     assert int(ins3.sum()) == 0 and int(unres3.sum()) == 0
 
 
+# ------------------------------------------- the independent host model
+
+class _HostTable:
+    """A sequential numpy open-addressing table with the insert's
+    addressing and nothing else of it: ``cap / BKT`` buckets of BKT
+    slots, home bucket ``lane 2 mod buckets``, double-hash step ``lane 1
+    | 1`` (odd, so a probe walks every bucket), first empty slot of the
+    first bucket with room.  One key at a time, in batch order."""
+
+    def __init__(self, cap):
+        self.vb = cap // BKT
+        self.buckets = [[] for _ in range(self.vb)]
+
+    def insert(self, keys, valid):
+        """(inserted, unresolved) flags of one batch."""
+        ins = np.zeros(len(keys), bool)
+        unres = np.zeros(len(keys), bool)
+        for i, (key, ok) in enumerate(zip(keys, valid)):
+            if not ok:
+                continue
+            key = tuple(int(x) for x in visited_mod.host_sanitize_key(key))
+            b = key[2] % self.vb
+            for _ in range(self.vb):
+                if key in self.buckets[b]:
+                    break
+                if len(self.buckets[b]) < BKT:
+                    self.buckets[b].append(key)
+                    ins[i] = True
+                    break
+                b = (b + (key[1] | 1)) % self.vb
+            else:
+                unres[i] = True
+        return ins, unres
+
+    def keys(self):
+        return sorted(k for b in self.buckets for k in b)
+
+
+def _rand_keys(n, seed, cap=None, buckets=None):
+    """n distinct random keys; with ``buckets``, crowded into that many
+    home buckets of a ``cap``-slot table."""
+    rng = np.random.default_rng(seed)
+    keys = rng.integers(0, 2 ** 32, size=(n, 4), dtype=np.uint64).astype(
+        np.uint32)
+    keys[:, 3] = np.arange(n, dtype=np.uint32) + np.uint32(seed << 16)
+    if buckets is not None:
+        vb = cap // BKT
+        keys[:, 2] = (keys[:, 2] & ~np.uint32(vb - 1)) | rng.integers(
+            0, buckets, size=n).astype(np.uint32)
+    return keys
+
+
+def _dup_batches():
+    base = _rand_keys(40, 1)
+    return 1 << 10, [(np.concatenate([base, base[::-1], base[5:25]]),
+                      None)]
+
+
+def _crowded_batches():
+    cap = 1 << 9
+    return cap, [(_rand_keys(150, 2, cap, buckets=3), None),
+                 (_rand_keys(90, 3, cap, buckets=2), None)]
+
+
+def _reinsert_batches():
+    first, more = _rand_keys(200, 4), _rand_keys(60, 5)
+    return 1 << 10, [(first, None),
+                     (np.concatenate([more[:30], first, more[30:]]),
+                      None)]
+
+
+def _overflow_full_batches():
+    """Exactly ``cap`` keys fill the table (every probe walks every
+    bucket); of the next batch the known keys are seen and every new
+    key is unresolved."""
+    cap = 4 * BKT
+    fill, more = _rand_keys(cap, 6), _rand_keys(12, 7)
+    return cap, [(fill, None),
+                 (np.concatenate([more[:6], fill[::3], more[6:]]), None)]
+
+
+def _invalid_batches():
+    keys = _rand_keys(120, 8)
+    batch = np.concatenate([keys, keys[:40]])
+    valid = np.ones(len(batch), bool)
+    valid[:60:2] = False          # an invalid first copy: the later one wins
+    valid[100:120] = False        # keys that appear on no valid row
+    batch[6] = 0xFFFFFFFF         # the EMPTY marker on an invalid row
+    batch[130] = 0xFFFFFFFF       # ... and on a valid one (sanitised)
+    return 1 << 10, [(batch, valid), (batch, ~valid)]
+
+
+@pytest.mark.parametrize("make", [
+    _dup_batches, _crowded_batches, _reinsert_batches,
+    _overflow_full_batches, _invalid_batches,
+], ids=["in-batch-duplicates", "crowded-buckets", "re-insert",
+        "overflow-full-table", "invalid-rows-inert"])
+def test_insert_matches_sequential_host_model(make):
+    """``inserted``, ``unresolved`` and the table's key set against the
+    host model, batch after batch on one table."""
+    cap, batches = make()
+    table, model = visited_mod.empty_table(cap), _HostTable(cap)
+    for keys, valid in batches:
+        valid = np.ones(len(keys), bool) if valid is None else valid
+        table, ins, unres = visited_mod.insert(
+            table, jnp.asarray(keys), jnp.asarray(valid))
+        want_ins, want_unres = model.insert(keys, valid)
+        assert np.array_equal(np.asarray(ins), want_ins)
+        assert np.array_equal(np.asarray(unres), want_unres)
+        got = visited_mod.host_occupied(table)
+        assert sorted(map(tuple, got.tolist())) == model.keys()
+    assert table.shape == visited_mod.table_shape(cap)
+
+
+def test_overflow_unresolved_are_exactly_the_keys_that_did_not_fit():
+    """A table with room for SOME of a batch: which keys win the last
+    slots is the reservation's business, but ``unresolved`` is exactly
+    the valid keys that are not in the table afterwards, ``inserted``
+    exactly those that are, and the two fill it to the last slot."""
+    cap = 2 * BKT
+    first, more = _rand_keys(cap - 5, 9), _rand_keys(11, 10)
+    table, ins, unres = visited_mod.insert(
+        visited_mod.empty_table(cap), jnp.asarray(first),
+        jnp.ones((len(first),), bool))
+    assert int(ins.sum()) == cap - 5 and int(unres.sum()) == 0
+    table, ins, unres = visited_mod.insert(
+        table, jnp.asarray(more), jnp.ones((len(more),), bool))
+    ins, unres = np.asarray(ins), np.asarray(unres)
+    stored = set(map(tuple, visited_mod.host_occupied(table).tolist()))
+    assert len(stored) == cap
+    assert int(ins.sum()) == 5 and int(unres.sum()) == 6
+    assert not (ins & unres).any()
+    for key, i, u in zip(more.tolist(), ins, unres):
+        assert (tuple(key) in stored) == bool(i) == (not u)
+    assert stored >= set(map(tuple, first.tolist()))
+
+
+@pytest.mark.parametrize("n_devices", [1, 3],
+                         ids=["one-device", "stacked"])
+def test_host_occupied_returns_exactly_the_inserted_keys(n_devices):
+    """``host_occupied`` of a table, and of per-device tables stacked
+    the way the sharded carry stacks them, is the inserted keys —
+    device by device; and a root placed by ``with_root`` sits where
+    the probe looks for it."""
+    cap = 1 << 8
+    per_dev = [_rand_keys(50 + 7 * d, 20 + d) for d in range(n_devices)]
+    stacked = visited_mod.empty_table(cap, n_devices)
+    assert stacked.shape == visited_mod.table_shape(cap, n_devices)
+    rows = visited_mod.table_shape(cap)[0]
+    root = _rand_keys(1, 30)[0]
+    owner = n_devices - 1
+    stacked = visited_mod.with_root(
+        stacked, jnp.asarray(root),
+        visited_mod.host_home_slot(root, cap), owner)
+    tables = []
+    for d, keys in enumerate(per_dev):
+        t, ins, unres = visited_mod.insert(
+            stacked[d * rows:(d + 1) * rows], jnp.asarray(keys),
+            jnp.ones((len(keys),), bool))
+        assert int(ins.sum()) == len(keys) and int(unres.sum()) == 0
+        tables.append(np.asarray(t))
+    _, ins, _ = visited_mod.insert(
+        jnp.asarray(tables[owner]), jnp.asarray(root[None]),
+        jnp.ones((1,), bool))
+    assert int(ins.sum()) == 0          # the root was found, not added
+    per_dev[owner] = np.concatenate([per_dev[owner], root[None]])
+    got = visited_mod.host_occupied(np.concatenate(tables))
+    assert got.shape == (sum(map(len, per_dev)), 4)
+    at = 0
+    for keys in per_dev:                # device by device, in order
+        part = got[at:at + len(keys)]
+        assert sorted(map(tuple, part.tolist())) == sorted(
+            map(tuple, keys.tolist()))
+        at += len(keys)
+
+
 def _pruned_pingpong(w=2):
     pp = make_pingpong_protocol(w)
     return dataclasses.replace(
@@ -113,10 +288,8 @@ def _pruned_clientserver(nc=2, w=1):
 def _table_key_set(search):
     """Extract the device table's occupied keys as a set of
     (h1, h2) uint64 pairs (the host oracle's key format)."""
-    table = np.asarray(search._last_dev_carry["visited"],
-                       dtype=np.uint64)[:-1]
-    occ = (table != np.uint64(0xFFFFFFFF)).any(axis=1)
-    rows = table[occ]
+    rows = visited_mod.host_occupied(
+        search._last_dev_carry["visited"]).astype(np.uint64)
     h1 = (rows[:, 0] << np.uint64(32)) | rows[:, 1]
     h2 = (rows[:, 2] << np.uint64(32)) | rows[:, 3]
     return set(zip(h1.tolist(), h2.tolist()))
