@@ -308,65 +308,78 @@ def derive_root(binding: TwinBinding, search, state):
         raise NoTensorTwin(
             f"staged state's provenance {prov.key} does not match the "
             f"current binding {binding.key}")
-    row_state = search.initial_state()
-    row = np.asarray(flatten_state(row_state))[0]
-    # Replay UNMASKED: the history's events were valid under the masks
-    # of the phases that produced them, not under THIS phase's masks
-    # (e.g. a deliver_timers(False) phase 3 must still replay phase 1's
-    # election timers).  Masks only gate validity, never the transition,
-    # so unmasked replay reproduces each original successor exactly.
-    p = dataclasses.replace(search.p, deliver_message=None,
-                            deliver_timer=None)
-    from dslabs_tpu.tpu.engine import TensorSearch as _TS
+    events = sum(op[0] in ("ev_msg", "ev_tmr") for op in prov.history)
+    with telemetry.phase("entry.root.build"):
+        row_state = search.initial_state()
+        row = np.asarray(flatten_state(row_state))[0]
+        # Replay UNMASKED: the history's events were valid under the
+        # masks of the phases that produced them, not under THIS phase's
+        # masks (e.g. a deliver_timers(False) phase 3 must still replay
+        # phase 1's election timers).  Masks only gate validity, never
+        # the transition, so unmasked replay reproduces each original
+        # successor exactly.
+        p = dataclasses.replace(search.p, deliver_message=None,
+                                deliver_timer=None)
+        from dslabs_tpu.tpu.engine import TensorSearch as _TS
 
-    replayer = _TS(p, chunk=1)
-    step = jax.jit(replayer._step_one)
+        replayer = _TS(p, chunk=1)
+        # Compiled here, ahead of the first event, so that the replay
+        # below is the history's round trips and nothing else.
+        step = jax.jit(replayer._step_one).lower(
+            jax.ShapeDtypeStruct(row.shape, jnp.int32),
+            jax.ShapeDtypeStruct((), jnp.int32)).compile()
     o0, o1 = search._off[0], search._off[1]
     dropped: List[np.ndarray] = []
-    for op in prov.history:
-        if op[0] in ("ev_msg", "ev_tmr"):
-            ev = _denorm_event(p, op)
-            succ, valid, over = step(jnp.asarray(row), jnp.asarray(ev))
-            if int(over):
-                # The replayed transition itself overflowed this rung's
-                # net/timer caps — a truncated root would corrupt every
-                # downstream verdict, so escalate the ladder instead.
-                raise CapacityOverflow(
-                    f"provenance replay of {op!r} overflowed caps "
-                    f"(net_cap={p.net_cap}, timer_cap={p.timer_cap})")
-            if not bool(valid):
-                raise NoTensorTwin(
-                    f"provenance replay hit undeliverable event {op!r}")
-            row = np.asarray(succ)
-        elif op[0] == "drop":
-            net = row[o0:o1].reshape(p.net_cap, p.msg_width)
-            dropped.extend(r.copy() for r in net if r[0] != SENTINEL)
-            row = row.copy()
-            row[o0:o1] = SENTINEL
-        elif op[0].startswith("undrop"):
-            net = row[o0:o1].reshape(p.net_cap, p.msg_width).copy()
-            want = (binding.addr_index[op[1]] if len(op) > 1 else None)
-            back = []
-            for r in dropped:
-                if op[0] == "undrop_from" and int(r[1]) != want:
-                    continue
-                if op[0] == "undrop_to" and int(r[2]) != want:
-                    continue
-                back.append(r)
-            have = [r for r in net if r[0] != SENTINEL]
-            merged = {tuple(r) for r in have} | {tuple(r) for r in back}
-            rows = sorted(merged)
-            if len(rows) > p.net_cap:
-                raise CapacityOverflow(
-                    f"undrop needs {len(rows)} net slots > cap "
-                    f"{p.net_cap}")
-            net[:] = SENTINEL
-            for i, r in enumerate(rows):
-                net[i] = r
-            row = row.copy()
-            row[o0:o1] = net.reshape(-1)
-        else:
-            raise NoTensorTwin(f"unknown staged op {op!r}")
+    with telemetry.phase("entry.root.replay", events=events,
+                         staged_ops=len(prov.history) - events):
+        for op in prov.history:
+            if op[0] in ("ev_msg", "ev_tmr"):
+                ev = _denorm_event(p, op)
+                succ, valid, over = step(jnp.asarray(row), jnp.int32(ev))
+                if int(over):
+                    # The replayed transition itself overflowed this
+                    # rung's net/timer caps — a truncated root would
+                    # corrupt every downstream verdict, so escalate the
+                    # ladder instead.
+                    raise CapacityOverflow(
+                        f"provenance replay of {op!r} overflowed caps "
+                        f"(net_cap={p.net_cap}, timer_cap={p.timer_cap})")
+                if not bool(valid):
+                    raise NoTensorTwin(
+                        f"provenance replay hit undeliverable event "
+                        f"{op!r}")
+                row = np.asarray(succ)
+            elif op[0] == "drop":
+                net = row[o0:o1].reshape(p.net_cap, p.msg_width)
+                dropped.extend(r.copy() for r in net if r[0] != SENTINEL)
+                row = row.copy()
+                row[o0:o1] = SENTINEL
+            elif op[0].startswith("undrop"):
+                net = row[o0:o1].reshape(p.net_cap, p.msg_width).copy()
+                want = (binding.addr_index[op[1]] if len(op) > 1
+                        else None)
+                back = []
+                for r in dropped:
+                    if op[0] == "undrop_from" and int(r[1]) != want:
+                        continue
+                    if op[0] == "undrop_to" and int(r[2]) != want:
+                        continue
+                    back.append(r)
+                have = [r for r in net if r[0] != SENTINEL]
+                merged = ({tuple(r) for r in have}
+                          | {tuple(r) for r in back})
+                rows = sorted(merged)
+                if len(rows) > p.net_cap:
+                    raise CapacityOverflow(
+                        f"undrop needs {len(rows)} net slots > cap "
+                        f"{p.net_cap}")
+                net[:] = SENTINEL
+                for i, r in enumerate(rows):
+                    net[i] = r
+                row = row.copy()
+                row[o0:o1] = net.reshape(-1)
+            else:
+                raise NoTensorTwin(f"unknown staged op {op!r}")
     return search.unflatten_rows(jnp.asarray(row[None])), list(prov.history)
 
 
@@ -455,6 +468,8 @@ def _run_tensor(binding: TwinBinding, settings, state, chunk=512):
                 outcome = search.run(initial=root)
             return search, outcome, history
         except CapacityOverflow as e:
+            telemetry.mark("entry.capacity_retry", attempt=attempt,
+                           overflow=str(e)[:96])
             last = e
             continue
     raise last

@@ -149,8 +149,12 @@ def setup() -> str:
         cc.reset_cache()
     # Cache quick compiles too: the search programs are rebuilt by
     # every process that constructs an engine, and the key is
-    # platform-specific anyway.
-    if jax.config.jax_persistent_cache_min_compile_time_secs > 0.5:
+    # platform-specific anyway.  0.3 s and not 0.5: on the v5e lab 3's
+    # ``_step_one``, which every staged call rebuilds, compiles in about
+    # 0.5 s — under the floor a few times and over it later, so a cold
+    # process cached it at a moment chance chose (PERF.md, PR 27); the
+    # small programs every call rebuilds take 0.02-0.21 s there.
+    if jax.config.jax_persistent_cache_min_compile_time_secs > 0.3:
         jax.config.update("jax_persistent_cache_min_compile_time_secs",
-                          0.5)
+                          0.3)
     return jax.config.jax_compilation_cache_dir

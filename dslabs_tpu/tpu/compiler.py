@@ -930,11 +930,12 @@ class ProtocolSpec:
             return st
 
         def repack(st):
-            parts = []
-            for key, (off, size) in table.items():
-                v = st[key]
-                parts.append(v[None] if size == 1 else v)
-            return jnp.concatenate(parts).astype(jnp.int32)
+            # A size-1 field unpacks as a scalar, but the slot ops hand
+            # a ONE-slot array back as a [1] vector (atleast_1d): take
+            # either form for what the table says it is.
+            return jnp.concatenate(
+                [jnp.reshape(st[key], (size,))
+                 for key, (_off, size) in table.items()]).astype(jnp.int32)
 
         # Static send/set budgets: trace each handler once with a dummy
         # context to COUNT its effect rows (the finalize-assert

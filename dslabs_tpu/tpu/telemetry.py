@@ -391,8 +391,12 @@ AOT_PROGRAMS = ("superstep", "chunk_step", "level_stats", "promote",
 PHASES = (
     "entry.tensor_bfs", "entry.tensor_dfs",     # root of one lab call
     "entry.bind", "entry.build_engine", "entry.derive_root",
+    # derive_root of a STAGED state: the chunk-1 replayer built and its
+    # step compiled; the history replayed (events, staged_ops)
+    "entry.root.build", "entry.root.replay",
     "entry.warm_run", "entry.search", "entry.replay", "entry.recheck",
     "entry.probe",
+    "entry.capacity_retry",         # mark: a ladder attempt overflowed
     "search.level",                 # one BFS level / wave
     "compile.aot",                  # aot_warmup, one child per program
     "compile.event",                # mark: one jax.monitoring event

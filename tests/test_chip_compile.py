@@ -256,6 +256,55 @@ def test_lab1_programs_compile(topo, n_devices):
     assert ("all-to-all" in text) == (n_devices > 1)
 
 
+def _lab3_suite_search(mesh, attempt):
+    """The lab 3 twin of PaxosTest test22's state (3 servers, 2 clients,
+    one APPEND each — the benchmark's ``paxos3-suite`` cell) exactly as
+    ``backend._run_tensor`` builds it on rung ``attempt`` of the capacity
+    ladder, under the first phase's settings."""
+    from benchmark.harness import manifest
+
+    cell = manifest.load_cell(
+        os.path.dirname(os.path.dirname(os.path.abspath(__file__))),
+        "paxos3-suite")
+    state = cell.driver.build_state(
+        cell.config["deployment"]["object_state"], 1)
+    settings = cell.driver.build_settings(
+        cell.config["phases"]["decide"], state)
+    binding = backend.resolve_binding(state)
+    net_cap, timer_cap = binding.initial_caps()
+    protocol, marr, tarr = backend._bind_protocol(
+        binding, settings, net_cap << attempt, timer_cap + 2 * attempt)
+    f_cap, v_cap = backend._LADDER[attempt]
+    search = ShardedTensorSearch(
+        protocol, mesh, chunk_per_device=512, frontier_cap=f_cap,
+        visited_cap=v_cap, strict=True, record_trace=True)
+    search.set_runtime_masks(marr, tarr)
+    return search
+
+
+@pytest.mark.slow
+@pytest.mark.parametrize("attempt", [0, 1])
+def test_lab3_suite_programs_compile(topo, one_chip, attempt):
+    """What a staged lab 3 call builds on one chip, on the ladder's
+    first two rungs: superstep (trace recording on), promote, root init,
+    and the chunk-1 step that ``derive_root`` replays a staged state's
+    history through and ``trace.decode_trace`` a witness."""
+    import dataclasses
+
+    from dslabs_tpu.tpu.engine import TensorSearch
+
+    search = _lab3_suite_search(_mesh(topo, 1), attempt)
+    exes = _aot(search)
+    replayer = TensorSearch(dataclasses.replace(
+        search.p, deliver_message=None, deliver_timer=None), chunk=1)
+    exes["step_one"] = jax.jit(replayer._step_one).lower(
+        jax.ShapeDtypeStruct((search.lanes,), jnp.int32,
+                             sharding=one_chip),
+        jax.ShapeDtypeStruct((), jnp.int32, sharding=one_chip)).compile()
+    _fits(exes)
+    assert "all-to-all" not in exes["superstep"].as_text()
+
+
 def _flagship_search(mesh, chunk):
     from bench import _bench_protocol
 

@@ -1,0 +1,284 @@
+"""The parts of the lab entry point (tpu/backend.py) that a STAGED lab 3
+search stands on, each without a lab 3 search (those are minutes on the
+CPU: tests/test_search_backend.py, ``DSLABS_SLOW_TESTS``):
+
+* ``compile_masks`` turns each of PaxosTest test22's five settings — as
+  the benchmark's configuration ``lab3-paxos-test22-staged`` states them
+  and its driver builds them — into the expected delivery matrix and
+  timer vector on the 3-server 2-client binding (no engine built);
+* ``derive_root`` replays a staged state's provenance to the very row
+  the recording search reached, also after ``drop`` / ``undrop_from``,
+  and a history that needs a higher rung of the capacity ladder raises
+  ``CapacityOverflow`` (which the ladder retries), never ``NoTensorTwin``
+  (which fails the test);
+* the ladder's retry leaves an ``entry.capacity_retry`` mark, and the
+  names ISSUE 27 added are in ``telemetry.PHASES``;
+* a lab 3 spec with a ONE-slot log traces (tpu/compiler.py ``repack``).
+"""
+
+import os
+import types
+
+import numpy as np
+import pytest
+
+jax = pytest.importorskip("jax")
+
+from dslabs_tpu.tpu import backend  # noqa: E402
+from dslabs_tpu.tpu import telemetry as tel_mod  # noqa: E402
+
+ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+
+
+# ----------------------------------------------------------- compile_masks
+
+@pytest.fixture(scope="module")
+def suite():
+    """``(configuration, driver, binding, state)`` of the benchmark's
+    ``paxos3-suite`` cell: the phases as data, the functions that build
+    test22's state and settings from them, the twin binding and the
+    seeded state it was resolved from."""
+    from benchmark.harness import manifest
+
+    cell = manifest.load_cell(ROOT, "paxos3-suite")
+    state = cell.driver.build_state(
+        cell.config["deployment"]["object_state"], 2**31 + 5)
+    return cell.config, cell.driver, backend.resolve_binding(state), state
+
+
+NODES = ["server1", "server2", "server3", "client1", "client2"]
+# phase -> (the partition's nodes, the nodes whose timers stay on)
+EXPECTED_MASKS = {
+    "decide": ("server1 server2 client1", NODES),
+    "finish13": ("server1 server3 client2", ["server1", "server3"]),
+    "finish23": ("server2 server3 client2", ["server2", "server3"]),
+    "exhaust13": ("server1 server3 client2", []),
+    "exhaust23": ("server2 server3 client2", []),
+}
+
+
+@pytest.mark.parametrize("phase", list(EXPECTED_MASKS))
+def test_compile_masks_of_test22s_settings(suite, phase):
+    config, driver, binding, state = suite
+    assert [binding.addr_index[n] for n in NODES] == [0, 1, 2, 3, 4]
+    settings = driver.build_settings(config["phases"][phase], state)
+    mat, tvec = backend.compile_masks(binding, settings)
+    part, timers_on = EXPECTED_MASKS[phase]
+    want = np.zeros((5, 5), bool)
+    for f in part.split():
+        for t in part.split():
+            want[NODES.index(f), NODES.index(t)] = f != t
+    assert mat.dtype == bool and mat.shape == (25,)
+    assert (mat.reshape(5, 5) == want).all(), mat.reshape(5, 5)
+    assert tvec.tolist() == [n in timers_on for n in NODES]
+    # 3 nodes inside: 6 directed links, nothing to or from an outsider
+    assert int(mat.sum()) == 6
+
+
+def test_the_suites_state_is_test22s(suite):
+    """Both clients APPEND to one key; client 2 expects client 1's value
+    before its own; the same seed gives the same commands."""
+    config, driver, binding, state = suite
+    assert (binding.n, binding.nc, binding.w, binding.S) == (3, 2, 1, 2)
+    (c1, r1), (c2, r2) = [(binding.cmd_objs[i], binding.results[i])
+                          for i in (1, 2)]
+    assert c1.key == c2.key and c1.value != c2.value
+    assert (r1.value, r2.value) == (c1.value, c1.value + c2.value)
+    again = backend.resolve_binding(driver.build_state(
+        config["deployment"]["object_state"], 2**31 + 5))
+    assert again.key == binding.key
+    assert sorted(config["phases"]) == sorted(EXPECTED_MASKS)
+
+
+# ------------------------------------------------------------- derive_root
+
+class _GenBinding(backend.TwinBinding):
+    """The single-decree ``paxos_spec``'s nodes, as a binding."""
+    key = ("paxos-gen", 3)
+    addr_index = {"proposer": 0, "acceptor1": 1, "acceptor2": 2,
+                  "acceptor3": 3}
+
+
+def _gen_search(net_cap=None):
+    """A recording search of single-decree Paxos whose goal is the state
+    in which all three acceptors have promised and no PROMISE has been
+    delivered yet: three events deep, with the three PREPAREs (a
+    delivered message stays in the network) and a PROMISE from each
+    acceptor in flight."""
+    from dslabs_tpu.tpu.sharded import ShardedTensorSearch, make_mesh
+    from dslabs_tpu.tpu.specs import paxos_spec
+
+    spec = paxos_spec(3)
+    if net_cap is not None:
+        spec.net_cap = net_cap
+    spec.goals = {"ALL_PROMISED": lambda v: (
+        (v.get("acceptor", 0, "bal") == 1)
+        & (v.get("acceptor", 1, "bal") == 1)
+        & (v.get("acceptor", 2, "bal") == 1)
+        & (v.get("proposer", 0, "ph") == 0))}
+    return ShardedTensorSearch(
+        spec.compile(), make_mesh(1), chunk_per_device=16,
+        frontier_cap=1 << 8, visited_cap=1 << 10, strict=True,
+        record_trace=True)
+
+
+def _row(state):
+    from dslabs_tpu.tpu.engine import flatten_state
+
+    return np.asarray(flatten_state(
+        jax.tree.map(jax.numpy.asarray, state)))[0]
+
+
+def _staged(history):
+    return types.SimpleNamespace(
+        depth=len(history),
+        _tensor_provenance=backend.TensorProvenance(_GenBinding.key,
+                                                    list(history)))
+
+
+@pytest.fixture(scope="module")
+def recorded():
+    """``(search, outcome, history)``: the recording search, its goal
+    outcome, and the goal's provenance as ``_materialize`` writes it."""
+    search = _gen_search()
+    outcome = search.run()
+    assert outcome.end_condition == "GOAL_FOUND" and outcome.depth == 3
+    return search, outcome, [backend._norm_event(search.p, e)
+                             for e in outcome.trace]
+
+
+def test_derive_root_reproduces_the_row_the_search_reached(recorded):
+    search, outcome, history = recorded
+    assert [op[0] for op in history] == ["ev_msg"] * 3
+    root, got = backend.derive_root(_GenBinding(), search,
+                                    _staged(history))
+    assert got == history
+    assert (_row(root) == _row(outcome.goal_state)).all()
+
+
+def test_derive_root_after_drop_and_undrop_from(recorded):
+    from dslabs_tpu.tpu.engine import SENTINEL
+
+    search, outcome, history = recorded
+    p = search.p
+    o0, o1 = search._off[0], search._off[1]
+    reached = _row(outcome.goal_state)
+    net = reached[o0:o1].reshape(p.net_cap, p.msg_width)
+    live = net[net[:, 0] != SENTINEL]
+    # three PREPAREs from the proposer, a PROMISE from each acceptor
+    assert sorted(live[:, 1].tolist()) == [0, 0, 0, 1, 2, 3]
+
+    root, _ = backend.derive_root(
+        _GenBinding(), search, _staged(history + [("drop",)]))
+    dropped = _row(root)
+    assert (dropped[o0:o1] == SENTINEL).all()
+    assert (np.delete(dropped, np.s_[o0:o1])
+            == np.delete(reached, np.s_[o0:o1])).all()
+
+    root, _ = backend.derive_root(
+        _GenBinding(), search,
+        _staged(history + [("drop",), ("undrop_from", "acceptor2")]))
+    back = _row(root)
+    want = np.full_like(net, SENTINEL)
+    want[0] = live[live[:, 1] == 2][0]
+    assert (back[o0:o1].reshape(net.shape) == want).all()
+    assert (np.delete(back, np.s_[o0:o1])
+            == np.delete(reached, np.s_[o0:o1])).all()
+
+
+def test_a_history_beyond_this_rungs_caps_is_a_capacity_overflow(recorded):
+    from dslabs_tpu.tpu.engine import CapacityOverflow
+
+    search, _outcome, history = recorded
+    # a slot no net of this rung has: the recording phase stood higher
+    with pytest.raises(CapacityOverflow, match="beyond net_cap"):
+        backend.derive_root(
+            _GenBinding(), search,
+            _staged(history + [("ev_msg", search.p.net_cap)]))
+    # a transition that overflows this rung's net: the second PROMISE
+    # (slots 3 and 4, after the PREPAREs) makes the proposer send three
+    # ACCEPTs beside the six messages there are
+    more = history + [("ev_msg", 3), ("ev_msg", 4)]
+    backend.derive_root(_GenBinding(), search, _staged(more))   # fits here
+    with pytest.raises(CapacityOverflow, match="overflowed caps"):
+        backend.derive_root(_GenBinding(), _gen_search(net_cap=8),
+                            _staged(more))
+    # what no rung can cure stays a NoTensorTwin
+    with pytest.raises(backend.NoTensorTwin, match="undeliverable"):
+        backend.derive_root(
+            _GenBinding(), search,
+            _staged(history + [("ev_msg", search.p.net_cap - 1)]))
+
+
+# ------------------------------------------------------------- the ladder
+
+def test_new_names_are_in_the_table():
+    assert {"entry.root.build", "entry.root.replay",
+            "entry.capacity_retry"} <= set(tel_mod.PHASES)
+
+
+def test_derive_root_writes_its_two_phases(recorded):
+    search, _outcome, history = recorded
+    tel = tel_mod.Telemetry(ring=256)
+    with tel_mod.use(tel):
+        backend.derive_root(_GenBinding(), search,
+                            _staged(history + [("drop",)]))
+    phases = [r for r in tel.ring if r["t"] == "phase"]
+    assert [r["name"] for r in phases] == ["entry.root.build",
+                                           "entry.root.replay"]
+    assert (phases[1]["events"], phases[1]["staged_ops"]) == (3, 1)
+
+
+def test_a_ladder_retry_leaves_a_mark(monkeypatch):
+    """Lab 1 (2 clients, 2 appends each: 80 states) on a ladder whose
+    first rung's visited table holds 8 slots a device: the strict search
+    overflows, the entry point climbs one rung, and the call's record
+    says so."""
+    from benchmark.harness import states
+    from dslabs_tpu.search.settings import SearchSettings
+    from dslabs_tpu.testing.predicates import CLIENTS_DONE, RESULTS_OK
+
+    monkeypatch.setattr(backend, "_LADDER",
+                        [(1 << 9, 1 << 3), (1 << 9, 1 << 12)])
+    state = states.build({"kind": "clientserver", "clients": 2,
+                          "commands_per_client": 2}, 5)
+    settings = (SearchSettings().add_invariant(RESULTS_OK)
+                .add_prune(CLIENTS_DONE))
+    tel = tel_mod.Telemetry(ring=1 << 12)
+    with tel_mod.use(tel), pytest.warns(RuntimeWarning,
+                                        match="capacity pressure"):
+        results = backend.tensor_bfs(state, settings)
+    assert results.end_condition.name == "SPACE_EXHAUSTED"
+    assert results.discovered_count == 80
+    phases = [r for r in tel.ring if r["t"] == "phase"]
+    marks = [r for r in phases if r["name"] == "entry.capacity_retry"]
+    assert [m["attempt"] for m in marks] == [0]
+    assert "visited" in marks[0]["overflow"] and marks[0]["wall"] < 0.01
+    binds = [r for r in phases if r["name"] == "entry.bind"]
+    assert [r["attempt"] for r in binds] == [0, 1]
+    assert {r["call"] for r in marks + binds} == {binds[0]["call"]}
+
+
+# ------------------------------------------------------ the one-slot log
+
+def test_a_one_slot_lab3_spec_traces():
+    """One client, one command: the lab 3 twin's log has ONE slot, a
+    size-1 field that the slot ops hand back as a [1] vector."""
+    import jax.numpy as jnp
+
+    from dslabs_tpu.tpu.specs_lab3 import make_paxos_protocol
+
+    p = make_paxos_protocol(n=3, n_clients=1, w=1, max_slots=1,
+                            net_cap=8, timer_cap=4)
+    nodes = jax.ShapeDtypeStruct((p.node_width,), jnp.int32)
+    msg = jax.ShapeDtypeStruct((p.msg_width,), jnp.int32)
+    timer = jax.ShapeDtypeStruct((p.timer_width,), jnp.int32)
+    for out in (jax.eval_shape(p.step_message, nodes, msg),
+                jax.eval_shape(p.step_timer, nodes, jnp.int32(0), timer)):
+        assert out[0].shape == (p.node_width,) and out[0].dtype == jnp.int32
+    # and batched, as the engine's flat vmap traces it
+    batched = jax.eval_shape(
+        jax.vmap(p.step_message),
+        jax.ShapeDtypeStruct((4, p.node_width), jnp.int32),
+        jax.ShapeDtypeStruct((4, p.msg_width), jnp.int32))
+    assert batched[0].shape == (4, p.node_width)

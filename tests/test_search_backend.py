@@ -215,6 +215,49 @@ def test_lab3_singleton_goal_parity(tensor_backend):
     assert obj.goal_matching_state.depth == res.goal_matching_state.depth
 
 
+@pytest.mark.skipif(SLOW, reason="lab3 twin compile is slow on CPU "
+                    "(DSLABS_SLOW_TESTS=1 enables)")
+def test_lab3_test22_five_phase_parity(tensor_backend):
+    """PaxosTest test22's five phases as the benchmark's ``paxos3-suite``
+    cell makes them (its configuration's phases, its driver's state and
+    settings): the partitioned goal search from the root, then four
+    searches from THAT goal state.  The object checker runs every phase
+    from the very state the tensor phase was given — two checkers may
+    stop at different goal states of equal depth, and what lies beyond a
+    goal state depends on which — and must agree on verdict, minimal
+    goal depth and exhausted count."""
+    import os
+
+    from benchmark.harness import manifest
+    from dslabs_tpu.search.search import BFS
+
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    cell = manifest.load_cell(root, "paxos3-suite")
+    phases = cell.config["phases"]
+    state = cell.driver.build_state(
+        cell.config["deployment"]["object_state"], 22)
+    goals = {}
+    for name in phases:         # all five, not only the cell's cycle
+        start = (state if phases[name]["start"] == "root"
+                 else goals[phases[name]["start"][len("goal of "):]])
+        settings = cell.driver.build_settings(phases[name], start)
+        res = bfs(start, settings)
+        assert res.tensor_outcome.dropped == 0
+        obj = BFS(settings).run(start)
+        want = cell.config["reference"][name]
+        assert (obj.end_condition.name == res.end_condition.name
+                == want["end_condition"]), name
+        if res.end_condition == EndCondition.GOAL_FOUND:
+            goals[name] = goal = res.goal_matching_state
+            assert goal.depth == obj.goal_matching_state.depth, name
+            assert goal.depth == want.get("terminal_depth", goal.depth)
+            assert any(g.check(goal).value for g in settings.goals)
+            assert goal._tensor_provenance.history, name
+        else:
+            assert res.discovered_count == obj.discovered_count, name
+    assert sorted(goals) == ["decide", "finish13", "finish23"]
+
+
 def test_lab2_single_server_verdicts(tensor_backend):
     """test16-shaped lab2 search through the tensor backend: the
     ViewServer + PBServer + client stack reaches CLIENTS_DONE with the
