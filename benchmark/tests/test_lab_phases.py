@@ -39,26 +39,9 @@ REFERENCE = {"decide": {"end_condition": "GOAL_FOUND",
              "exhaust": {"end_condition": "SPACE_EXHAUSTED"}}
 
 
-@pytest.fixture(autouse=True)
-def every_compile_is_cached():
-    """The runner counts a program compiled AND WRITTEN to the persistent
-    cache inside the window as a miss, and JAX writes only what took
-    longer than a floor to compile (1 s in these tests).  On the CPU this
-    twin's programs compile in about that: one that stayed under the
-    floor in set-up and passed it in the window would fail the run by
-    chance.  With the floor at 0 everything set-up compiles is cached,
-    and a miss in the window is a program set-up never saw."""
-    import jax
-
-    floor = jax.config.jax_persistent_cache_min_compile_time_secs
-    jax.config.update("jax_persistent_cache_min_compile_time_secs", 0.0)
-    yield
-    jax.config.update("jax_persistent_cache_min_compile_time_secs", floor)
-
-
 def small_cell(**params):
-    cell = tiny_cell("paxos3-suite", cycle=list(PHASES),
-                     traced_phases=["finish"], **params)
+    cell = tiny_cell("paxos3-suite", **{
+        "cycle": list(PHASES), "traced_phases": ["finish"], **params})
     config = copy.deepcopy(cell.config)
     config["deployment"]["object_state"]["servers"] = 1
     config.update(phases=PHASES, reference=REFERENCE)
@@ -79,9 +62,15 @@ def test_lab_phases_rehearsal_builds_the_last_line():
            "limit=2 ok" in lines
 
 
-def test_lab_phases_traced_rehearsal_reads_the_per_layer_metrics():
-    res, _ = run_cell(small_cell(), seconds=1, trace=True)
-    assert res["correct"] is True
+@pytest.mark.parametrize("cycle,traced", [
+    (["decide", "finish", "exhaust"], "finish"),
+    (["decide", "exhaust"], "exhaust")])    # the cell's own since PR 29
+def test_lab_phases_traced_rehearsal_reads_the_per_layer_metrics(cycle,
+                                                                 traced):
+    res, lines = run_cell(small_cell(cycle=cycle, traced_phases=[traced]),
+                          seconds=1, trace=True)
+    assert res["correct"] is True and failed_checks(lines) == []
+    assert res["attempted"] == len(cycle) and res["failed"] == 0
     m = res["metrics"]
     assert set(m) >= {"entry_overhead_s.lab", "search_s.lab",
                       "warmup_s.lab", "engine_build_s.lab",
@@ -96,7 +85,7 @@ def test_lab_phases_traced_rehearsal_reads_the_per_layer_metrics():
     assert 0 < m["derive_root_s.suite"]["value"] \
         <= m["engine_build_s.lab"]["value"]
     assert 0 < res["device"]["busy_s"] <= res["device"]["window_s"]
-    assert any(n == "call.finish" for n, _s
+    assert any(n == "call." + traced for n, _s
                in res["breakdown"]["idle_gaps"])
 
 

@@ -67,7 +67,7 @@ def test_timeboxed_bfs_traced_rehearsal_reads_the_per_layer_metrics():
         seconds=60, trace=True)
     assert res["correct"] is True
     m = res["metrics"]
-    assert set(m) == {"dispatches_per_level.deep", "useful_ratio.deep",
+    assert set(m) >= {"dispatches_per_level.deep", "useful_ratio.deep",
                       "superstep_us_per_state.deep",
                       "superstep_roofline.deep", "compile_s"}
     # exact counters of the traced level (level 4: 162 -> 713 unique)
@@ -170,7 +170,7 @@ def test_lab_calls_rehearsal_builds_the_last_line():
 def test_lab_calls_traced_rehearsal_reads_the_per_layer_metrics():
     res, _ = run_cell(tiny_cell("lab1-entry"), seconds=1, trace=True)
     assert res["correct"] is True
-    assert set(res["metrics"]) == {"entry_overhead_s.lab", "search_s.lab",
+    assert set(res["metrics"]) >= {"entry_overhead_s.lab", "search_s.lab",
                                    "warmup_s.lab"}
     assert 0 < res["device"]["busy_s"] <= res["device"]["window_s"]
     assert any(n.startswith("call.") for n, _s
