@@ -108,6 +108,12 @@ class PaxosBinding(TwinBinding):
     def initial_caps(self):
         return 32, 6
 
+    def twin_key(self):
+        # The REPLY decoder's fallback reads the workloads' expected
+        # results, which ``key`` (commands only) does not hold.
+        return self.key + (tuple(repr(self.results.get(i))
+                                 for i in sorted(self.cmd_objs)),)
+
     # ------------------------------------------------------------ protocol
 
     def build_protocol(self, net_cap, timer_cap):

@@ -146,6 +146,12 @@ class JoinBinding(TwinBinding):
     def initial_caps(self):
         return 12, 4
 
+    def twin_key(self):
+        # Beyond ``key``: what the decoders read (expected results, the
+        # master's ballot).
+        return self.key + (tuple(repr(r) for _, r in self.pairs),
+                           repr(self.master_ballot))
+
     def check_settings(self, settings) -> None:
         from dslabs_tpu.core.address import LocalAddress
 
@@ -387,7 +393,17 @@ class ShardStoreBinding(TwinBinding):
     def initial_caps(self):
         return 48, 6
 
-    # ------------------------------------------------------------- settings
+    def twin_key(self):
+        # Beyond ``key``: the modelling flags ``check_settings`` binds
+        # (they change the protocol's shape) and what the decoders and
+        # the message mask read (ballots, the final config's number,
+        # the controller and its join workload, expected results).
+        return self.key + (
+            self._model_mh, self._model_ctl, tuple(self.ctl_names),
+            repr(self.master_ballot), repr(self.ballots),
+            self.configs[-1].config_num,
+            repr([[r for _, r in p] for p in self.pairs]),
+            repr(self.ctl_pairs))
 
     def check_settings(self, settings) -> None:
         """Bind the settings-dependent modelling flags: live master
@@ -771,6 +787,13 @@ class ShardStoreTxBinding(TwinBinding):
 
     def initial_caps(self):
         return 48, 6
+
+    def twin_key(self):
+        # Beyond ``key``: what the decoders read (ballots, the final
+        # config's number, expected results).
+        return self.key + (repr(self.ballots),
+                           self.configs[-1].config_num,
+                           repr([r for _, r in self.pairs]))
 
     def check_settings(self, settings) -> None:
         from dslabs_tpu.core.address import LocalAddress

@@ -201,6 +201,14 @@ class ClientServerBinding(TwinBinding):
     def initial_caps(self):
         return 16, 4
 
+    def twin_key(self):
+        # The Reply decoder's fallback reads the expected results (an
+        # infinite workload's are in ``key`` as its template).
+        if isinstance(self.pairs[0], _StreamPairs):
+            return self.key
+        return self.key + (tuple(repr(r) for p in self.pairs
+                                 for _, r in p),)
+
     def build_protocol(self, net_cap, timer_cap):
         from dslabs_tpu.tpu.protocols.clientserver import \
             make_clientserver_protocol
@@ -373,6 +381,11 @@ class PrimaryBackupBinding(TwinBinding):
 
     def initial_caps(self):
         return 32, 4
+
+    def twin_key(self):
+        # The Reply decoder's fallback reads the expected results.
+        return self.key + (tuple(repr(r) for p in self.pairs
+                                 for _, r in p),)
 
     def build_protocol(self, net_cap, timer_cap):
         from dslabs_tpu.tpu.protocols.primarybackup import make_pb_protocol

@@ -11,7 +11,14 @@ an object ``SearchResults`` whose terminal states are REAL object states
 staged searches (``results.goal_matching_state`` fed into the next
 ``bfs``) and trace assertions keep working unchanged.
 
-Pipeline per call:
+Pipeline per call (what a call BUILDS on the way — the bound twin, the
+engine with its traced and compiled programs, the compiled trace step —
+the process keeps, in one bounded least-recently-used table keyed by the
+call's input: :class:`_Kept`.  The first call of a shape, caps and
+predicate structure pays for all of it, a compile included — seconds on
+the CPU, a minute on a chip; a repeated one finds it and pays for the
+search, the replays and the object twin's steps: tenths of a second.
+Roots, results and witnesses are derived anew every call):
 
 1. **Twin resolution** — registered :class:`TwinAdapter`\\ s inspect the
    object state's node composition and return a :class:`TwinBinding`
@@ -31,7 +38,10 @@ Pipeline per call:
    structurally).  Untranslatable predicate = loud NoTensorTwin.
 4. **Run** — ShardedTensorSearch, strict=True (drops are fatal: lab
    verdicts must be exact), record_trace=True; capacity ladder retries
-   CapacityOverflow with doubled caps (no hand-tuned budgets).
+   CapacityOverflow with doubled caps (no hand-tuned budgets).  Every
+   call starts on the first rung; each rung's engine is kept on its own.
+   Partition, timer gating, ``max_depth``, ``max_time`` and the
+   recorder are runtime settings, set on the engine every call.
 5. **Results adaptation** — end conditions map onto the object
    ``EndCondition`` (the object checker treats the depth limit as a
    prune, so tensor DEPTH_EXHAUSTED reports SPACE_EXHAUSTED); terminal
@@ -42,7 +52,11 @@ Pipeline per call:
 
 from __future__ import annotations
 
+import collections
+import contextlib
 import dataclasses
+import os
+import threading
 from typing import Any, Callable, Dict, List, Optional, Tuple
 
 import numpy as np
@@ -50,7 +64,8 @@ import numpy as np
 from dslabs_tpu.tpu import telemetry
 
 __all__ = ["NoTensorTwin", "TensorProvenance", "TwinBinding",
-           "register_adapter", "tensor_bfs", "tensor_dfs"]
+           "cache_info", "clear_cache", "register_adapter", "tensor_bfs",
+           "tensor_dfs"]
 
 
 class NoTensorTwin(RuntimeError):
@@ -128,6 +143,17 @@ class TwinBinding:
 
     def initial_caps(self) -> Tuple[int, int]:
         raise NotImplementedError
+
+    def twin_key(self) -> tuple:
+        """Hashable identity of the twin ``build_protocol`` returns at
+        given caps, read AFTER ``check_settings``: everything that
+        ``build_protocol``, the decoders it binds and ``predicate``
+        read off this binding.  The lab entry keeps twins, engines and
+        trace steps under it (:class:`_Kept`), so two bindings of equal
+        ``twin_key`` must be interchangeable.  Default ``key``;
+        bindings whose decoders or modelling flags read more extend
+        it."""
+        return self.key
 
     def predicate(self, tkey) -> Callable:
         raise NotImplementedError
@@ -234,6 +260,202 @@ def translate_predicate(binding: TwinBinding, pred) -> Callable:
     return fn
 
 
+def _predicates_key(settings) -> tuple:
+    """For each of invariants, goals and prunes, in order, the ``(name,
+    predicate_signature)`` of every predicate: what an engine's flag
+    programs are traced from."""
+    return tuple(tuple((q.name, predicate_signature(q)) for q in group)
+                 for group in (settings.invariants, settings.goals,
+                               settings.prunes))
+
+
+def predicate_signature(pred) -> tuple:
+    """What :func:`translate_predicate` reads of ``pred``, as a value:
+    the combinator tree down to the ``tkey``\\ s, by the very recursion
+    it makes.  Two predicates of equal signature translate to the same
+    lane predicate on one binding; a name says nothing."""
+    st = getattr(pred, "structure", None)
+    if st is not None and st[0] in ("not", "and", "or", "implies"):
+        return (st[0],) + tuple(predicate_signature(q) for q in st[1:])
+    return ("tkey", getattr(pred, "tkey", None))
+
+
+# ---------------------------------------------- what is kept across calls
+
+@dataclasses.dataclass
+class _Engine:
+    """A kept ``ShardedTensorSearch`` — its traced and compiled programs
+    — and whether a warm run has completed on it."""
+    search: Any
+    warmed: bool = False
+
+    def rest(self) -> None:
+        """Drop what the finished run left on the engine for the replay.
+        No device buffer is among it (the carry is a local of ``run()``),
+        but the host's child-to-parent map holds a row for every state
+        discovered: ``run()`` empties these when it starts, a kept engine
+        should not hold them until then."""
+        s = self.search
+        s._fp_map, s._level_records = {}, []
+        s._deep_samples = s._trace_root = None
+
+
+class _Kept:
+    """What a lab call builds and the next call of equal input needs
+    again, in ONE least-recently-used table of at most ``BOUND`` entries
+    for the process:
+
+    * ``("twin", twin_key, net_cap, timer_cap, env)`` — what
+      ``binding.build_protocol`` returned;
+    * ``("engine", twin_key, net_cap, timer_cap, frontier_cap,
+      visited_cap, chunk, devices, predicates, env)`` — an
+      :class:`_Engine`; ``predicates`` is :func:`_predicates_key`;
+    * ``("step", twin_key, net_cap, timer_cap, env)`` — the one compiled
+      trace step (:func:`_trace_step`), by the caps the protocol HAS.
+
+    ``env`` is every ``DSLABS_*`` variable as it stands: the engine's
+    constructor reads several.  A key is derived from the call's input
+    alone; one that cannot be hashed is ``None``, and ``None`` is never
+    found and never kept (a bypass: the caller builds what it needs, as
+    every call did before).  The table holds programs, never answers.
+
+    ``BOUND``: test22's five phases take five entries (a twin, a step,
+    three predicate sets), lab 1's three searches five, a rung climbed
+    three more.  Nothing outside this module sets it."""
+
+    BOUND = 16
+
+    def __init__(self):
+        self._lock = threading.Lock()
+        self._table: "collections.OrderedDict" = collections.OrderedDict()
+        self._leased: set = set()
+        self.hits = self.misses = self.bypasses = 0
+
+    @property
+    def built(self) -> int:
+        """How often a caller was sent away to build."""
+        return self.misses + self.bypasses
+
+    def get(self, key, lease: Optional[set] = None):
+        """The value kept under ``key``, or None.  With ``lease`` (the
+        keys one call holds) the value is an engine: one that another
+        call is running is not handed out a second time, and one handed
+        out is this call's until its ``leasing`` ends."""
+        with self._lock:
+            if key is None or (lease is not None and key in self._leased):
+                self.bypasses += 1
+                return None
+            if key not in self._table:
+                self.misses += 1
+                return None
+            self.hits += 1
+            self._table.move_to_end(key)
+            if lease is not None:
+                self._leased.add(key)
+                lease.add(key)
+            return self._table[key]
+
+    def put(self, key, value, lease: Optional[set] = None):
+        """Keep ``value`` under ``key`` (the least recently used entries
+        beyond ``BOUND`` go) unless the key is ``None`` or taken: a call
+        that was refused a running engine keeps its own to itself."""
+        with self._lock:
+            if (key is None or key in self._table
+                    or (lease is not None and key in self._leased)):
+                return value
+            self._table[key] = value
+            if lease is not None:
+                self._leased.add(key)
+                lease.add(key)
+            while len(self._table) > self.BOUND:
+                self._table.popitem(last=False)
+        return value
+
+    @contextlib.contextmanager
+    def leasing(self):
+        """The set of engine keys one call holds, released at the end."""
+        lease: set = set()
+        try:
+            yield lease
+        finally:
+            with self._lock:
+                mine = [self._table[k] for k in lease if k in self._table]
+            for engine in mine:
+                engine.rest()
+            with self._lock:
+                self._leased -= lease
+
+    def clear(self) -> None:
+        with self._lock:
+            self._table.clear()
+            self.hits = self.misses = self.bypasses = 0
+
+    def info(self) -> dict:
+        with self._lock:
+            kinds = [k[0] for k in self._table]
+            return {"hits": self.hits, "misses": self.misses,
+                    "bypasses": self.bypasses, "bound": self.BOUND,
+                    "entries": len(kinds),
+                    **{kind: kinds.count(kind)
+                       for kind in ("twin", "engine", "step")}}
+
+
+_KEPT = _Kept()
+
+
+def clear_cache() -> None:
+    """Forget every kept twin, engine and trace step (for tests: the
+    next call builds everything, as a fresh process would)."""
+    _KEPT.clear()
+
+
+def cache_info() -> dict:
+    """Counters and contents of the lab entry's cache."""
+    return _KEPT.info()
+
+
+def _key(kind: str, binding: TwinBinding, *parts) -> Optional[tuple]:
+    """The key of one kept thing, or None where the input cannot be
+    expressed as one (an unhashable ``tkey`` or ``twin_key``)."""
+    key = (kind, binding.twin_key()) + parts + (tuple(sorted(
+        kv for kv in os.environ.items() if kv[0].startswith("DSLABS_"))),)
+    try:
+        hash(key)
+    except TypeError:
+        return None
+    return key
+
+
+def _trace_step(binding: TwinBinding, p):
+    """The compiled single-event step of twin ``p`` — ``step(row,
+    event_id) -> (successor row, valid, over)`` — that root derivation,
+    witness replay and the sampled re-check all step through: built once
+    a (twin, caps) a process (``entry.root.build``: absent when it was
+    kept), UNMASKED.  A history's events were valid under the masks of
+    the phases that produced them, not under THIS phase's masks (a
+    ``deliver_timers(False)`` phase 3 must still replay phase 1's
+    election timers); masks only gate validity, never the transition,
+    so the unmasked step reproduces each original successor exactly."""
+    key = _key("step", binding, p.net_cap, p.timer_cap)
+    step = _KEPT.get(key)
+    if step is None:
+        import jax
+        import jax.numpy as jnp
+
+        from dslabs_tpu.tpu.engine import TensorSearch
+
+        with telemetry.phase("entry.root.build"):
+            replayer = TensorSearch(
+                dataclasses.replace(p, deliver_message=None,
+                                    deliver_timer=None), chunk=1)
+            # Compiled here, ahead of the first event, so that a replay
+            # is its events' round trips and nothing else.
+            step = _KEPT.put(key, jax.jit(replayer._step_one).lower(
+                jax.ShapeDtypeStruct((replayer.lanes,), jnp.int32),
+                jax.ShapeDtypeStruct((), jnp.int32)).compile())
+    return step
+
+
 # -------------------------------------------------------------- settings
 
 def _addr_name(a) -> str:
@@ -283,7 +505,6 @@ def derive_root(binding: TwinBinding, search, state):
     """Object initial state -> (tensor root pytree or None for the twin
     initial, provenance history list).  Depth-0 canonical states map to
     the twin initial; staged states replay their provenance history."""
-    import jax
     import jax.numpy as jnp
 
     from dslabs_tpu.tpu.engine import (CapacityOverflow, SENTINEL,
@@ -309,25 +530,9 @@ def derive_root(binding: TwinBinding, search, state):
             f"staged state's provenance {prov.key} does not match the "
             f"current binding {binding.key}")
     events = sum(op[0] in ("ev_msg", "ev_tmr") for op in prov.history)
-    with telemetry.phase("entry.root.build"):
-        row_state = search.initial_state()
-        row = np.asarray(flatten_state(row_state))[0]
-        # Replay UNMASKED: the history's events were valid under the
-        # masks of the phases that produced them, not under THIS phase's
-        # masks (e.g. a deliver_timers(False) phase 3 must still replay
-        # phase 1's election timers).  Masks only gate validity, never
-        # the transition, so unmasked replay reproduces each original
-        # successor exactly.
-        p = dataclasses.replace(search.p, deliver_message=None,
-                                deliver_timer=None)
-        from dslabs_tpu.tpu.engine import TensorSearch as _TS
-
-        replayer = _TS(p, chunk=1)
-        # Compiled here, ahead of the first event, so that the replay
-        # below is the history's round trips and nothing else.
-        step = jax.jit(replayer._step_one).lower(
-            jax.ShapeDtypeStruct(row.shape, jnp.int32),
-            jax.ShapeDtypeStruct((), jnp.int32)).compile()
+    p = search.p
+    step = _trace_step(binding, p)
+    row = np.asarray(flatten_state(search.initial_state()))[0]
     o0, o1 = search._off[0], search._off[1]
     dropped: List[np.ndarray] = []
     with telemetry.phase("entry.root.replay", events=events,
@@ -391,19 +596,31 @@ def derive_root(binding: TwinBinding, search, state):
 _LADDER = [(1 << 14, 1 << 19), (1 << 17, 1 << 22), (1 << 19, 1 << 24)]
 
 
-def _run_tensor(binding: TwinBinding, settings, state, chunk=512):
+def _run_tensor(binding: TwinBinding, settings, state, lease: set,
+                chunk=512):
+    """One BFS through the capacity ladder.  Each rung's twin and engine
+    are looked up in what the process kept (:class:`_Kept`) and built
+    only where nothing was; ``lease`` collects the engines this call
+    holds, for ``tensor_bfs`` to release when it is through with them.
+    The lookups stand in this frame, around the very constructor calls
+    they save: a build is made at the stack depth it always was."""
     import jax
 
     from dslabs_tpu.tpu.engine import CapacityOverflow
     from dslabs_tpu.tpu.sharded import ShardedTensorSearch, make_mesh
+    from dslabs_tpu.tpu.supervisor import install_retry
+    from dslabs_tpu.utils.flags import GlobalSettings
 
     net_cap, timer_cap = binding.initial_caps()
     mesh = make_mesh(len(jax.devices()))
+    devices = tuple(d.id for d in mesh.devices.flat)
     last: Optional[Exception] = None
     # Where a call's seconds go, stage by stage (telemetry.PHASES):
-    # each stage below is a phase of the call ``tensor_bfs`` opened.
+    # each stage below is a phase of the call ``tensor_bfs`` opened, on
+    # every attempt, with ``cached`` = 1 where it built nothing.
     for attempt, (f_cap, v_cap) in enumerate(_LADDER):
-        with telemetry.phase("entry.bind", attempt=attempt):
+        caps = (net_cap << attempt, timer_cap + 2 * attempt)
+        with telemetry.phase("entry.bind", attempt=attempt) as span:
             if attempt == 0:
                 # check_settings BEFORE build_protocol: bindings bind
                 # settings-dependent modelling flags there (lab4's
@@ -411,29 +628,44 @@ def _run_tensor(binding: TwinBinding, settings, state, chunk=512):
                 # protocol shape must reflect them on the FIRST attempt,
                 # not after a capacity retry.
                 binding.check_settings(settings)
-            protocol, marr, tarr = _bind_protocol(
-                binding, settings, net_cap << attempt,
-                timer_cap + 2 * attempt)
+            key = _key("twin", binding, *caps)
+            twin = _KEPT.get(key)
+            span.set(cached=int(twin is not None))
+            if twin is None:
+                twin = _KEPT.put(key, binding.build_protocol(*caps))
+            protocol, marr, tarr = _bind_protocol(binding, settings,
+                                                  *caps, twin=twin)
         with telemetry.phase("entry.build_engine", attempt=attempt,
-                             frontier_cap=f_cap, visited_cap=v_cap):
-            search = ShardedTensorSearch(
-                protocol, mesh, chunk_per_device=chunk,
-                frontier_cap=f_cap, visited_cap=v_cap, strict=True,
-                record_trace=True)
-            # The caller's recorder, if one is current
-            # (telemetry.use), records this search's dispatches.
+                             frontier_cap=f_cap, visited_cap=v_cap) as span:
+            key = _key("engine", binding, *caps, f_cap, v_cap, chunk,
+                       devices, _predicates_key(settings))
+            kept = _KEPT.get(key, lease)
+            span.set(cached=int(kept is not None))
+            if kept is None:
+                kept = _KEPT.put(key, _Engine(ShardedTensorSearch(
+                    protocol, mesh, chunk_per_device=chunk,
+                    frontier_cap=f_cap, visited_cap=v_cap, strict=True,
+                    record_trace=True)), lease)
+            search = kept.search
+            # Everything a call sets on its engine is set on EVERY call,
+            # kept engine or new, so that nothing of the call before
+            # leaks: the caller's recorder if one is current
+            # (telemetry.use), or none; the transient-dispatch retry
+            # (tpu/supervisor.py: a preemption or transient XLA error
+            # mid-search retries with backoff instead of failing the lab
+            # test; semantic errors like CapacityOverflow pass straight
+            # through to the capacity ladder below), with this call's
+            # own budget; the delivery masks; the once-a-search
+            # capacity-pressure warning and the dispatch annotations'
+            # running index; and, below, the limits.
             recorder = telemetry.current()
             if recorder is not None:
                 recorder.attach(search)
-            # Transient-dispatch retry (tpu/supervisor.py): a preemption
-            # or transient XLA error mid-search retries with backoff
-            # instead of failing the lab test; verdict flow is untouched
-            # (semantic errors like CapacityOverflow pass straight
-            # through to the capacity ladder below).
-            from dslabs_tpu.tpu.supervisor import install_retry
-
+            else:
+                search._telemetry = None
             install_retry(search)
             search.set_runtime_masks(marr, tarr)
+            search._warned_visited, search._dispatch_i = False, -1
         rel = None
         if settings.depth_limited():
             rel = settings.max_depth - state.depth
@@ -443,27 +675,29 @@ def _run_tensor(binding: TwinBinding, settings, state, chunk=512):
             # Inside the attempt: a root recorded by a phase that ran at
             # a higher ladder rung can overflow this rung's caps, and
             # must escalate rather than fail the test (ADVICE r4).
-            with telemetry.phase("entry.derive_root"):
+            built = _KEPT.built
+            with telemetry.phase("entry.derive_root") as span:
                 root, history = binding.derive_root(search, state)
-            if settings.max_time_secs is not None and (
-                    rel is None or rel > 2):
+                span.set(cached=int(_KEPT.built == built))
+            if (settings.max_time_secs is not None
+                    and (rel is None or rel > 2) and not kept.warmed):
                 # Warm-up excludes compile time from the test's time
                 # budget (the reference charges neither JIT nor class
                 # loading to maxTime; on the accelerator a cold twin
                 # compile alone can exceed a 30 s search budget).  A
                 # phase within 2 levels of its depth limit skips it —
-                # the warm-up WOULD BE the whole search.
-                search.max_depth = 2
+                # the warm-up WOULD BE the whole search — and so does
+                # an engine that has completed one: its programs are
+                # compiled (a root it has not started from yet still
+                # compiles its small carry initialiser).
+                search.max_depth, search.max_secs = 2, None
                 with telemetry.phase("entry.warm_run"):
                     search.run(initial=root, check_initial=False)
+                kept.warmed = True
             search.max_depth = rel
-            if settings.max_time_secs is not None:
-                from dslabs_tpu.utils.flags import GlobalSettings
-
-                search.max_secs = (settings.max_time_secs
-                                   * GlobalSettings.time_scale)
-            else:
-                search.max_secs = None
+            search.max_secs = (
+                None if settings.max_time_secs is None
+                else settings.max_time_secs * GlobalSettings.time_scale)
             with telemetry.phase("entry.search"):
                 outcome = search.run(initial=root)
             return search, outcome, history
@@ -480,7 +714,8 @@ def _materialize(binding, search, outcome, state, history):
     provenance attached for the next staged phase."""
     from dslabs_tpu.tpu.trace import replay_on_object
 
-    obj = replay_on_object(search, outcome, state)
+    obj = replay_on_object(search, outcome, state,
+                           step=_trace_step(binding, search.p))
     obj._tensor_provenance = TensorProvenance(
         binding.key, list(history) + [_norm_event(search.p, e)
                                       for e in outcome.trace])
@@ -503,9 +738,10 @@ def _sampled_value_recheck(binding, search, outcome, settings, state):
         return None
     from dslabs_tpu.tpu.trace import replay_on_object
 
+    step = _trace_step(binding, search.p)
     for tr in outcome.samples:
         shim = dataclasses.replace(outcome, trace=list(tr))
-        obj = replay_on_object(search, shim, state)
+        obj = replay_on_object(search, shim, state, step=step)
         for p in value_preds:
             r = p.check(obj)
             if not r.value:
@@ -514,13 +750,15 @@ def _sampled_value_recheck(binding, search, outcome, settings, state):
 
 
 def _bind_protocol(binding, settings, net_cap, timer_cap,
-                   with_goals=True):
+                   with_goals=True, twin=None):
     """Assemble the runnable twin for one capacity rung: protocol with
     translated predicates + runtime mask arrays — ONE code path for the
     BFS ladder and the rollout probe, so both always search identically
-    configured twins."""
+    configured twins.  ``twin``: what ``build_protocol`` gave for these
+    caps, where the caller has it already."""
     marr, tarr = compile_masks(binding, settings)
-    protocol = binding.build_protocol(net_cap, timer_cap)
+    protocol = (twin if twin is not None
+                else binding.build_protocol(net_cap, timer_cap))
     inv = {p.name: translate_predicate(binding, p)
            for p in settings.invariants}
     goals = ({p.name: translate_predicate(binding, p)
@@ -651,7 +889,8 @@ def tensor_bfs(initial_state, settings=None, _probe_first=False):
     binding = resolve_binding(initial_state)
     with telemetry.call("entry.tensor_dfs" if _probe_first
                         else "entry.tensor_bfs",
-                        key=str(binding.key)[:96]):
+                        key=str(binding.key)[:96]), \
+            _KEPT.leasing() as lease:
         trip = probe_secs = None
         if _probe_first:
             with telemetry.phase("entry.probe"):
@@ -669,8 +908,8 @@ def tensor_bfs(initial_state, settings=None, _probe_first=False):
         if trip is not None:
             search, outcome, history = trip
         else:
-            search, outcome, history = _run_tensor(binding, settings,
-                                                   initial_state)
+            search, outcome, history = _run_tensor(
+                binding, settings, initial_state, lease)
         results = SearchResults(settings.invariants, settings.goals)
         results.discovered_count = outcome.unique_states
         # Degradation stats ride along so exhaust verdicts are auditable:

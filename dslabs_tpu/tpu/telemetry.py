@@ -390,9 +390,13 @@ AOT_PROGRAMS = ("superstep", "chunk_step", "level_stats", "promote",
                 "init_carry")
 PHASES = (
     "entry.tensor_bfs", "entry.tensor_dfs",     # root of one lab call
+    # one of each a ladder attempt, with ``cached`` = 1 where the lab
+    # entry had kept what the stage would build (tpu/backend.py _Kept)
     "entry.bind", "entry.build_engine", "entry.derive_root",
-    # derive_root of a STAGED state: the chunk-1 replayer built and its
-    # step compiled; the history replayed (events, staged_ops)
+    # the trace step built and compiled (absent where it was kept: once
+    # a twin and caps a process, in whichever of derive_root, replay or
+    # recheck needs it first); a STAGED state's history replayed
+    # (events, staged_ops)
     "entry.root.build", "entry.root.replay",
     "entry.warm_run", "entry.search", "entry.replay", "entry.recheck",
     "entry.probe",

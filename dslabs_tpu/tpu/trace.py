@@ -74,10 +74,13 @@ class MessageTemplate:
             f"{self.cls.__name__} candidates from {frm} to {to}")
 
 
-def decode_trace(search: TensorSearch,
-                 outcome: SearchOutcome) -> List[Tuple[str, tuple]]:
+def decode_trace(search: TensorSearch, outcome: SearchOutcome,
+                 step=None) -> List[Tuple[str, tuple]]:
     """Replay ``outcome.trace`` (event-id list) in tensor space; return
-    root-first records ``("message", lanes)`` / ``("timer", node, lanes)``."""
+    root-first records ``("message", lanes)`` / ``("timer", node, lanes)``.
+    ``step``: a compiled ``search._step_one`` (``(row, int32 event id) ->
+    (successor row, valid, over)``) where the caller keeps one — the lab
+    entry does (tpu/backend.py ``_trace_step``); jitted here otherwise."""
     if outcome.trace is None:
         raise ValueError("outcome has no trace "
                          "(run the search with record_trace=True)")
@@ -90,7 +93,8 @@ def decode_trace(search: TensorSearch,
     from dslabs_tpu.tpu.engine import flatten_state
     row = np.asarray(flatten_state(
         jax.tree.map(jax.numpy.asarray, root)))[0]
-    step = jax.jit(search._step_one)
+    if step is None:
+        step = jax.jit(search._step_one)
     records: List[Tuple[str, tuple]] = []
     tgrid = p.n_nodes * p.timer_cap
     for ev in outcome.trace:
@@ -110,7 +114,7 @@ def decode_trace(search: TensorSearch,
             f_idx = ev - p.net_cap - tgrid
             records.append(("fault", (p.fault.event_label(f_idx),)))
         succ_row, valid, _ = step(jax.numpy.asarray(row),
-                                  jax.numpy.asarray(ev))
+                                  jax.numpy.int32(ev))
         assert bool(valid), (
             f"trace replay hit an undeliverable event {ev} — "
             "reconstruction mapping is corrupt")
@@ -120,14 +124,15 @@ def decode_trace(search: TensorSearch,
 
 def replay_on_object(search: TensorSearch, outcome: SearchOutcome,
                      initial_object_state,
-                     settings=None):
+                     settings=None, step=None):
     """Replay the reconstructed record list on the object twin, returning
-    the final object SearchState (whose parent chain IS the trace)."""
+    the final object SearchState (whose parent chain IS the trace).
+    ``step``: as :func:`decode_trace`'s."""
     p = search.p
     if p.decode_message is None or p.decode_timer is None:
         raise ValueError(f"{p.name}: protocol has no object-twin decoders")
     state = initial_object_state
-    for kind, payload in decode_trace(search, outcome):
+    for kind, payload in decode_trace(search, outcome, step):
         if kind == "fault":
             # The object twin has no fault controller — a scenario
             # witness replays in tensor space only (decode_trace's

@@ -406,3 +406,457 @@ def test_lab1_deep_probe_dfs(tensor_backend):
     assert len(bad.client_workers()[LocalAddress("client1")].results) \
         >= w - 1
     assert bad.depth >= 2 * (w - 1)       # deep, as constructed
+
+
+# ------------------------------------------------ what the lab entry keeps
+# tpu/backend.py keeps a call's twin, engine (with its traced and
+# compiled programs) and trace step in one bounded table, keyed by what
+# the call's input lets it observe.  A hit may change how long a call
+# takes and nothing else.
+
+def _lab1(seed=5):
+    """Lab 1, 2 clients, 2 seeded APPENDs each: 80 states."""
+    from benchmark.harness import states
+
+    return states.build({"kind": "clientserver", "clients": 2,
+                         "commands_per_client": 2}, seed)
+
+
+def _lab1_settings(kind):
+    from dslabs_tpu.testing.predicates import (CLIENTS_DONE, NONE_DECIDED,
+                                               RESULTS_OK)
+
+    s = SearchSettings().max_time(60)
+    if kind == "goal":
+        return s.add_invariant(RESULTS_OK).add_goal(CLIENTS_DONE)
+    if kind == "exhaust":
+        return s.add_invariant(RESULTS_OK).add_prune(CLIENTS_DONE)
+    return s.add_invariant(NONE_DECIDED)
+
+
+def _recorded(state, settings):
+    """``(results, phases)`` of one ``tensor_bfs`` call: the ``entry.*``
+    phase records it wrote, in order."""
+    from dslabs_tpu.tpu import backend
+    from dslabs_tpu.tpu import telemetry as tel_mod
+
+    tel = tel_mod.Telemetry(ring=1 << 12)
+    with tel_mod.use(tel):
+        results = backend.tensor_bfs(state, settings)
+    return results, [r for r in tel.ring if r["t"] == "phase"
+                     and r["name"].startswith("entry.")]
+
+
+def _answer(results):
+    """Everything of a call's answer that a kept engine could spoil."""
+    out = results.tensor_outcome
+    terminal = (results.goal_matching_state
+                or results.invariant_violating_state)
+    return {
+        "end": results.end_condition, "discovered": results.discovered_count,
+        "explored": out.states_explored, "depth": out.depth,
+        "predicate": out.predicate_name,
+        "witness": None if out.trace is None else list(map(int, out.trace)),
+        "samples": out.samples and [list(map(int, t)) for t in out.samples],
+        "terminal_depth": terminal and terminal.depth,
+        "provenance": terminal and (terminal._tensor_provenance.key,
+                                    terminal._tensor_provenance.history),
+        "counters": (out.dropped, out.visited_overflow, out.retries),
+    }
+
+
+def _cached(phases, name):
+    return [r.get("cached") for r in phases if r["name"] == name]
+
+
+_BUILD_STAGES = ("entry.bind", "entry.build_engine", "entry.derive_root")
+# What a call that builds nothing leaves alone in compile_cache.totals():
+# nothing is lowered, compiled, or asked of the persistent cache.  (Its
+# ``trace_n`` moves: the eager operations of ``initial_state()`` look
+# their jaxprs up, in milliseconds.)
+_NO_COMPILE = ("lower_n", "lower_s", "backend_compile_n",
+               "backend_compile_s", "cache_hit_n", "cache_miss_n")
+
+
+@pytest.mark.parametrize("kind", ["goal", "exhaust", "violation"])
+def test_a_repeated_lab1_call_builds_nothing_and_answers_alike(kind):
+    from dslabs_tpu.tpu import backend, compile_cache
+
+    backend.clear_cache()
+    fresh, first = _recorded(_lab1(), _lab1_settings(kind))
+    assert [_cached(first, s) for s in _BUILD_STAGES] == [[0], [0], [1]]
+    assert _cached(first, "entry.warm_run") == [None]
+    before = compile_cache.totals()
+    again, second = _recorded(_lab1(), _lab1_settings(kind))
+    after = compile_cache.totals()
+    assert [_cached(second, s) for s in _BUILD_STAGES] == [[1], [1], [1]]
+    assert not {"entry.warm_run", "entry.root.build"} & {
+        r["name"] for r in second}
+    assert {k: after[k] for k in _NO_COMPILE} == {
+        k: before[k] for k in _NO_COMPILE}
+    assert after["trace_s"] - before["trace_s"] < 0.25
+    assert _answer(again) == _answer(fresh)
+    assert (fresh.end_condition.name, fresh.discovered_count) == {
+        "goal": ("GOAL_FOUND", fresh.discovered_count),
+        "exhaust": ("SPACE_EXHAUSTED", 80),
+        "violation": ("INVARIANT_VIOLATED", fresh.discovered_count)}[kind]
+    info = backend.cache_info()
+    assert (info["twin"], info["engine"], info["bypasses"]) == (1, 1, 0)
+    # the witness replay and the sampled re-check step through ONE kept
+    # program, built by whichever needed it first
+    assert info["step"] == 1
+
+
+def test_predicate_signature_is_the_structure_not_the_name():
+    from dslabs_tpu.testing.predicates import (CLIENTS_DONE, NONE_DECIDED,
+                                               StatePredicate, client_done)
+    from dslabs_tpu.tpu.backend import predicate_signature as sig
+
+    a = LocalAddress("client1")
+    assert sig(CLIENTS_DONE) == ("tkey", ("CLIENTS_DONE",))
+    assert sig(NONE_DECIDED.negate()) == ("not", ("tkey",
+                                                  ("NONE_DECIDED",)))
+    assert sig(client_done(a)) == sig(client_done(LocalAddress("client1")))
+    assert sig(client_done(a)) != sig(client_done(LocalAddress("client2")))
+    both = CLIENTS_DONE.and_(NONE_DECIDED)
+    assert sig(both) == ("and", sig(CLIENTS_DONE), sig(NONE_DECIDED))
+    assert len({sig(both), sig(CLIENTS_DONE.or_(NONE_DECIDED)),
+                sig(CLIENTS_DONE.implies(NONE_DECIDED)),
+                sig(NONE_DECIDED.and_(CLIENTS_DONE))}) == 4
+    renamed = StatePredicate("Clients got expected results",
+                             lambda s: True, tkey=("CLIENTS_DONE",))
+    assert sig(renamed) == sig(CLIENTS_DONE)
+    assert sig(StatePredicate("no key", lambda s: True)) == ("tkey", None)
+
+
+def _same_name_other_goal():
+    """A goal that carries CLIENTS_DONE's NAME and means something else:
+    client 1 alone is done."""
+    from dslabs_tpu.testing.predicates import (CLIENTS_DONE, RESULTS_OK,
+                                               StatePredicate, client_done)
+
+    one = client_done(LocalAddress("client1"))
+    other = StatePredicate(CLIENTS_DONE.name, one._fn, tkey=one.tkey)
+    return (_lab1(), SearchSettings().max_time(60)
+            .add_invariant(RESULTS_OK).add_goal(other))
+
+
+def _other_commands():
+    return _lab1(seed=6), _lab1_settings("goal")
+
+
+@pytest.mark.parametrize("variant, twin_kept", [
+    (_same_name_other_goal, 1), (_other_commands, 0), ("symmetry", 1)])
+def test_the_key_tells_apart(variant, twin_kept, monkeypatch):
+    """A call that differs from a kept one in what the engine was built
+    from gets an engine of its own — other predicates under equal names,
+    other command values, a changed ``DSLABS_SYMMETRY`` — and the call
+    it differs from still finds its own afterwards."""
+    from dslabs_tpu.tpu import backend
+
+    backend.clear_cache()
+    base, _ = _recorded(_lab1(), _lab1_settings("goal"))
+    if variant == "symmetry":
+        monkeypatch.setenv("DSLABS_SYMMETRY", "0")
+        state, settings = _lab1(), _lab1_settings("goal")
+    else:
+        state, settings = variant()
+    res, phases = _recorded(state, settings)
+    assert _cached(phases, "entry.build_engine") == [0]
+    # the environment is part of the twin's key too: one discipline
+    assert _cached(phases, "entry.bind") == [
+        0 if variant == "symmetry" else twin_kept]
+    assert backend.cache_info()["engine"] == 2
+    assert res.end_condition == EndCondition.GOAL_FOUND
+    goal = res.goal_matching_state
+    assert any(g.check(goal).value for g in settings.goals)
+    if variant is _same_name_other_goal:
+        # client 1 is done sooner than both are
+        assert goal.depth < base.goal_matching_state.depth
+    else:
+        assert goal.depth == base.goal_matching_state.depth
+    if variant is _other_commands:
+        assert (goal._tensor_provenance.key
+                != base.goal_matching_state._tensor_provenance.key)
+    monkeypatch.delenv("DSLABS_SYMMETRY", raising=False)
+    back, phases = _recorded(_lab1(), _lab1_settings("goal"))
+    assert _cached(phases, "entry.build_engine") == [1]
+    assert _answer(back) == _answer(base)
+
+
+def test_shardstore_modelling_flags_are_in_the_twins_key(tensor_backend):
+    """``ShardStoreBinding.check_settings`` binds ``_model_mh`` /
+    ``_model_ctl``, which change the protocol ``build_protocol``
+    returns: the key is read after it and holds them."""
+    from dslabs_tpu.labs.clientserver.kv_workload import kv_workload
+    from dslabs_tpu.tpu import backend
+    import tests.test_lab4_shardstore as lab4
+
+    joined = lab4._joined_state(lab4.make_search(1, 1, 1, 10), 1)
+    joined.add_client_worker(
+        LocalAddress("client1"),
+        kv_workload(["PUT:foo:bar", "GET:foo"], ["PutOk", "bar"]))
+    binding = backend.resolve_binding(joined)
+    assert binding.key[0] == "shardstore"
+
+    def frozen():
+        s = SearchSettings()
+        s.node_active(lab4.CCA, False)
+        s.deliver_timers(lab4.CCA, False)
+        return s
+
+    keys = {}
+    for name, settings in (
+            ("frozen", frozen().deliver_timers(lab4.shard_master(1), False)),
+            ("master timers", frozen()),
+            ("controller", SearchSettings().deliver_timers(
+                lab4.shard_master(1), False))):
+        binding.check_settings(settings)
+        keys[name] = (binding._model_mh, binding._model_ctl,
+                      backend._key("twin", binding, 48, 6))
+    assert [k[:2] for k in keys.values()] == [
+        (False, False), (True, False), (False, True)]
+    assert len({k[2] for k in keys.values()}) == 3
+    assert all(k[2] is not None and k[2][1][:len(binding.key)]
+               == binding.key for k in keys.values())
+
+
+@pytest.mark.parametrize("leaky", ["max_time", "partition", "max_depth"])
+def test_a_kept_engine_carries_no_setting_over(leaky):
+    """A call with a time budget, a partition or a depth limit, then one
+    without, on the very same engine (all three are runtime settings,
+    none is in the key): the second explores the whole space."""
+    s = _lab1_settings("exhaust")
+    if leaky == "max_time":
+        s.max_time(1e-9)
+    elif leaky == "partition":
+        s.partition(LocalAddress("server"), LocalAddress("client1"))
+    else:
+        s.set_max_depth(3)
+    narrowed, _ = _recorded(_lab1(), s)
+    assert narrowed.discovered_count < 80
+    assert narrowed.end_condition == (
+        EndCondition.TIME_EXHAUSTED if leaky == "max_time"
+        else EndCondition.SPACE_EXHAUSTED)
+    from dslabs_tpu.testing.predicates import CLIENTS_DONE, RESULTS_OK
+
+    unbounded = (SearchSettings().add_invariant(RESULTS_OK)
+                 .add_prune(CLIENTS_DONE))
+    whole, phases = _recorded(_lab1(), unbounded)
+    assert _cached(phases, "entry.build_engine") == [1]
+    assert "entry.warm_run" not in {r["name"] for r in phases}
+    assert (whole.end_condition, whole.discovered_count) == (
+        EndCondition.SPACE_EXHAUSTED, 80)
+    assert whole.tensor_outcome.depth > 3
+
+
+def test_what_a_call_sets_on_a_kept_engine_it_sets_once():
+    """The recorder and the retry boundary of a call replace the call
+    before's: a kept engine's dispatches are recorded once, by the
+    current recorder alone, through one boundary; with no recorder
+    current, by none."""
+    from dslabs_tpu.tpu import backend
+    from dslabs_tpu.tpu import telemetry as tel_mod
+
+    backend.clear_cache()
+    tels = [tel_mod.Telemetry(ring=1 << 12) for _ in range(2)]
+    engines, boundaries, dispatches = [], [], []
+    for tel in tels + [None]:
+        with tel_mod.use(tel):
+            res = backend.tensor_bfs(_lab1(), _lab1_settings("exhaust"))
+        assert res.discovered_count == 80
+        (kept,) = [v for k, v in backend._KEPT._table.items()
+                   if k[0] == "engine"]
+        engines.append(kept.search)
+        boundaries.append(kept.search._dispatch_boundary)
+        assert kept.search._telemetry is tel
+        assert kept.search._dispatch_hook == boundaries[-1].dispatch
+        # and nothing of the finished search stays on it but programs
+        assert kept.search._fp_map == {} and kept.search._trace_root is None
+        dispatches.append([len([r for r in t.ring if r["t"] == "span"])
+                           for t in tels])
+    assert engines[0] is engines[1] is engines[2]
+    assert len({id(b) for b in boundaries}) == 3
+    # call 1 warmed (to depth 2) and searched; call 2 searched, through
+    # the second recorder only; call 3 was recorded by neither
+    first, second = dispatches[0][0], dispatches[1][1]
+    assert dispatches == [[first, 0], [first, second], [first, second]]
+    assert 0 < second < first
+
+
+def test_a_reentrant_call_gets_an_engine_of_its_own():
+    """A call made while a call of equal key still holds the kept engine
+    (here: from inside the goal predicate, which the outer call checks on
+    its replayed object state) is not handed that engine."""
+    from dslabs_tpu.testing.predicates import (CLIENTS_DONE, RESULTS_OK,
+                                               StatePredicate)
+    from dslabs_tpu.tpu import backend
+
+    inner = []
+
+    def goal(s):
+        if not inner:
+            inner.append(None)
+            inner[0] = _recorded(_lab1(), settings())
+        return CLIENTS_DONE.check(s).value
+
+    def settings():
+        return (SearchSettings().max_time(60).add_invariant(RESULTS_OK)
+                .add_goal(StatePredicate("done, and asks again", goal,
+                                         tkey=CLIENTS_DONE.tkey)))
+
+    backend.clear_cache()
+    base, _ = _recorded(_lab1(), _lab1_settings("goal"))
+    inner.append(None)          # this call's predicate asks nothing
+    _recorded(_lab1(), settings())              # keeps the engine
+    inner.clear()
+    assert backend.cache_info()["bypasses"] == 0
+    outer, phases = _recorded(_lab1(), settings())
+    assert _cached(phases, "entry.build_engine") == [1]
+    nested, nested_phases = inner[0]
+    assert _cached(nested_phases, "entry.build_engine") == [0]
+    info = backend.cache_info()
+    assert (info["bypasses"], info["engine"]) == (1, 2)
+    for res in (outer, nested):
+        assert (res.goal_matching_state.depth, res.discovered_count) == (
+            base.goal_matching_state.depth, base.discovered_count)
+    # and the engine is free again once its call has returned
+    _, phases = _recorded(_lab1(), settings())
+    assert _cached(phases, "entry.build_engine") == [1]
+    assert backend.cache_info()["bypasses"] == 1
+
+
+def test_the_table_is_bounded_and_least_recently_used_goes(monkeypatch):
+    from dslabs_tpu.tpu import backend
+
+    kept = backend._Kept()
+    monkeypatch.setattr(backend._Kept, "BOUND", 3)
+    for i in range(3):
+        assert kept.get(("twin", i)) is None
+        assert kept.put(("twin", i), i) == i
+    assert kept.get(("twin", 0)) == 0           # now the most recent
+    kept.put(("twin", 3), 3)                    # ("twin", 1) goes
+    assert [kept.get(("twin", i)) for i in range(4)] == [0, None, 2, 3]
+    assert (kept.hits, kept.misses, len(kept._table)) == (4, 4, 3)
+    # a key that cannot be expressed is never found and never kept
+    assert kept.put(None, "x") == "x" and kept.get(None) is None
+    assert (kept.bypasses, len(kept._table)) == (1, 3)
+    # an engine handed out is not handed out again until its call ends,
+    # and the call that was refused keeps its own to itself
+    import types
+
+    e0, mine = (backend._Engine(types.SimpleNamespace(_fp_map={1: 2}))
+                for _ in range(2))
+    kept.put(("engine", 0), e0)
+    with kept.leasing() as outer:
+        assert kept.get(("engine", 0), outer) is e0
+        with kept.leasing() as inner:
+            assert kept.get(("engine", 0), inner) is None
+            assert kept.put(("engine", 0), mine, inner) is mine
+        assert kept.get(("engine", 0)) is e0
+        # what a run leaves for the replay stays while its call runs
+        assert e0.search._fp_map == {1: 2}
+    assert e0.search._fp_map == {} and e0.search._trace_root is None
+    with kept.leasing() as again:
+        assert kept.get(("engine", 0), again) is e0
+    assert backend.cache_info()["bound"] == 3 and backend._KEPT is not kept
+
+
+def test_concurrent_calls_never_share_a_kept_engine():
+    """More threads than cores take, use and release the engine of ONE
+    key (and now and then clear the table): at no time do two hold the
+    same object, and every thread gets one."""
+    import sys
+    import threading
+    import time
+    import types
+
+    from dslabs_tpu.tpu import backend
+
+    kept = backend._Kept()
+    held, clashes, done = set(), [], []
+    guard = threading.Lock()
+
+    def caller(n):
+        for i in range(300):
+            with kept.leasing() as lease:
+                engine = kept.get(("engine", 0), lease)
+                if engine is None:
+                    engine = kept.put(("engine", 0), backend._Engine(
+                        types.SimpleNamespace()), lease)
+                with guard:
+                    if id(engine) in held:
+                        clashes.append((n, i))
+                    held.add(id(engine))
+                if i % 7 == n % 7:
+                    time.sleep(0)
+                if i % 97 == n:
+                    kept.clear()
+                with guard:
+                    held.discard(id(engine))
+        done.append(n)
+
+    interval = sys.getswitchinterval()
+    sys.setswitchinterval(1e-5)
+    try:
+        threads = [threading.Thread(target=caller, args=(n,))
+                   for n in range(32)]
+        for t in threads:
+            t.start()
+        for t in threads:
+            t.join(timeout=60)
+    finally:
+        sys.setswitchinterval(interval)
+    assert not any(t.is_alive() for t in threads)
+    assert sorted(done) == list(range(32)) and not clashes
+    assert not kept._leased
+
+
+@pytest.mark.skipif(SLOW, reason="lab3 twin compile is slow on CPU "
+                    "(DSLABS_SLOW_TESTS=1 enables)")
+def test_lab3_test22_five_phases_twice_in_a_row(tensor_backend):
+    """test22's five phases, then the five again from a fresh state of
+    the same seed: the second round constructs nothing (on every rung a
+    phase stands on), warms nothing, and gives the first round's
+    answers, witnesses and provenances."""
+    import os
+
+    from benchmark.harness import manifest
+    from dslabs_tpu.tpu import backend
+
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    cell = manifest.load_cell(root, "paxos3-suite")
+    phases = cell.config["phases"]
+    backend.clear_cache()
+    rounds = []
+    for _ in range(2):
+        state = cell.driver.build_state(
+            cell.config["deployment"]["object_state"], 22)
+        goals, got = {}, {}
+        for name in phases:
+            start = (state if phases[name]["start"] == "root"
+                     else goals[phases[name]["start"][len("goal of "):]])
+            res, recs = _recorded(
+                start, cell.driver.build_settings(phases[name], start))
+            goals[name] = res.goal_matching_state
+            got[name] = (_answer(res), recs)
+        rounds.append(got)
+    for name in phases:
+        assert rounds[1][name][0] == rounds[0][name][0], name
+        assert (rounds[0][name][0]["end"].name
+                == cell.config["reference"][name]["end_condition"])
+        first, recs = rounds[0][name][1], rounds[1][name][1]
+        for stage in _BUILD_STAGES:     # as many rungs, nothing built
+            assert _cached(recs, stage) == [1] * len(
+                _cached(first, stage)), (name, stage)
+        assert not {"entry.warm_run", "entry.root.build"} & {
+            r["name"] for r in recs}, name
+    # what round one built is what is kept: three predicate sets on the
+    # first rung, and a rung more for the phase that climbs (finish23)
+    built = {stage: sum(c == 0 for name in phases for c in _cached(
+        rounds[0][name][1], stage)) for stage in _BUILD_STAGES}
+    info = backend.cache_info()
+    assert (info["twin"], info["engine"]) == (
+        built["entry.bind"], built["entry.build_engine"])
+    assert info["engine"] >= 3 and info["step"] == info["twin"]
+    assert info["entries"] <= info["bound"] and info["bypasses"] == 0
