@@ -39,7 +39,7 @@ bench:           ## TPU states/min benchmark (one JSON line)
 	$(PY) bench.py
 
 # perf-smoke = the BASELINE.json states/min floor PLUS the dry-run
-# 8-virtual-device superstep-vs-legacy parity gate (exact unique/
+# 8-virtual-device superstep-vs-host-reference parity gate (exact unique/
 # explored/verdict match on pingpong + paxos d5 + shardstore —
 # tests/test_superstep.py, ISSUE 3 acceptance).
 perf-smoke:      ## fast CPU perf gate vs the BASELINE.json floor
@@ -157,9 +157,10 @@ trace-smoke:     ## causal tracing + cost-ledger suite (assembler / COSTS / rete
 
 # mesh-smoke = the owner-sharded multi-chip superstep suite
 # (tests/test_mesh_exchange.py, ISSUE 12): the width-parity matrix —
-# exact unique/explored/verdict parity between the fused in-superstep
-# row exchange and the legacy promote-boundary driver at n_devices in
-# {1, 2, 4, 8} on pingpong + lab1 — the <= 2 dispatches/level budget
+# exact unique/explored/verdict parity between the sharded engine and
+# the host-dedup reference (and the object checker's count) at
+# n_devices in {1, 2, 4, 8} on pingpong + lab1 — the <= 2
+# dispatches/level budget
 # pin with a zero-collective promote lowering, Pallas-vs-jnp
 # visited-table bit-exact parity (incl. the table-full overflow
 # contract) standalone AND through a full sharded search, the
@@ -170,12 +171,11 @@ trace-smoke:     ## causal tracing + cost-ledger suite (assembler / COSTS / rete
 # (tests/test_mesh_packing.py): packed-vs-raw exchange parity across
 # widths {1,2,4,8} + the >= 8x wire bytes-per-state floor, the
 # delta-lane (varint) pb parity, cross-width resume through the packed
-# checkpoint format, the root-fanout/work-stealing imbalance
-# acceptance, packed-spill parity at 1/8 capacity, the
-# pack/decode/steal dispatch-site audits, and the mesh_unpacked /
+# checkpoint format, packed-spill parity at 1/8 capacity, the
+# pack/decode dispatch-site audits, and the mesh_unpacked /
 # skew_agg observability pins.  docs/perf.md "mesh dispatch model" +
 # "The wire format" are the field guides.
-mesh-smoke:      ## owner-sharded superstep width-parity + packed-wire/steal suite on CPU
+mesh-smoke:      ## owner-sharded superstep width-parity + packed-wire suite on CPU
 	JAX_PLATFORMS=cpu $(PY) -m pytest tests/ -q -m mesh -p no:cacheprovider
 
 # lanes-smoke = the batched-job-lanes suite (tests/test_lanes.py,

@@ -317,10 +317,8 @@ def _calibrate(max_depth: int = 7) -> dict:
         max_n, depth, t0 = 1, 0, time.time()
         while max_n > 0 and depth < max_depth:
             depth += 1
-            n_chunks = -(-(max_n + search.n_devices - 1) // search.cpd)
-            for _ in range(n_chunks):
-                carry = search._chunk_step(carry)
-            _, _, _, _, max_n, _ = search._sync_checks(carry, depth, t0)
+            carry, lvl = search._superstep_call(carry, 1 << 30)
+            max_n = search._sync_checks(carry, depth, t0, lvl)[4]
             carry = search._finish_level(carry)
             m, t = (int(x) for x in jax.tree.map(jnp.asarray,
                                                  jstats(carry)))
@@ -484,11 +482,6 @@ def _run_mesh(budget_secs: float) -> dict:
     import dataclasses
 
     width = int(os.environ.get("DSLABS_MESH_WIDTH", "8") or "8")
-    # The headline benches the balanced mesh (ISSUE 18): root-fanout
-    # seeding plus chunk-granular stealing at level boundaries.  An
-    # explicit DSLABS_MESH_STEAL_THRESHOLD (including "0" = off, the
-    # parity oracle) wins.
-    os.environ.setdefault("DSLABS_MESH_STEAL_THRESHOLD", "1.5")
     _persistent_cache()
     import jax
 
@@ -539,22 +532,11 @@ def _run_mesh(budget_secs: float) -> dict:
            if lv.get("skew")]
     cv = [lv["skew"]["explored"]["cv"] for lv in levels
           if lv.get("skew")]
-    post = [lv["skew"]["frontier_post_steal"]["imbalance"]
-            for lv in levels
-            if lv.get("skew", {}).get("frontier_post_steal")]
-    stolen = sum(int(lv["steal"]["moved"]) for lv in levels
-                 if lv.get("steal"))
     skew = {
         "imbalance_max": round(max(imb), 4) if imb else 1.0,
         "imbalance_mean": round(sum(imb) / len(imb), 4) if imb else 1.0,
         "cv_max": round(max(cv), 4) if cv else 0.0,
         "levels_measured": len(imb),
-        # Post-rebalance frontier skew (ISSUE 18c): the imbalance the
-        # NEXT level actually expands with, after fanout + stealing.
-        "imbalance_max_post_steal": round(max(post), 4) if post else
-        (round(max(imb), 4) if imb else 1.0),
-        "steal_levels": len(post),
-        "stolen_rows": stolen,
     }
     # Estimated ICI wire bytes per exchanged state (ISSUE 18a): the
     # packed row width the all_to_all actually ships (the engine stamps
@@ -588,7 +570,7 @@ def _run_mesh(budget_secs: float) -> dict:
         # Top-level copies the ledger guards read (telemetry
         # compare_ledger: mesh:wire_bytes_per_state rises or
         # mesh:imbalance_max rises past threshold -> rc 1).
-        "imbalance_max": skew["imbalance_max_post_steal"],
+        "imbalance_max": skew["imbalance_max"],
         "wire": wire,
         "levels": levels,
         "retries": outcome.retries,

@@ -1848,36 +1848,12 @@ class TensorSearch:
         bf = self._ev_flt
         has_flt = p.fault is not None and bf > 0
         c = chunk_valid.shape[0]
-        # Dev bisect hook (tools/profile_sharded2.py): expand-internal
-        # stages.  Each truncation returns dummy outputs whose shapes
-        # match the contract, folding the live stage outputs into the
-        # overflow scalar so XLA cannot DCE the work under test.
-        stop = getattr(self, "_stop_after", None)
-
-        def _cut(*live):
-            b = bm + bt + bf
-            acc = jnp.int32(0)
-            for x in live:
-                acc = acc + jnp.sum(x).astype(jnp.int32)
-            return (jnp.zeros((c * b, self.lanes), jnp.int32),
-                    jnp.zeros((c * b,), bool),
-                    jnp.zeros((c * b, 4), jnp.uint32),
-                    jnp.zeros((c * b,), bool), acc, jnp.int32(0),
-                    jnp.zeros((c, b), jnp.int32),
-                    {f"{kind}:{name}": jnp.zeros((c * b,), bool)
-                     for kind, preds in (("inv", p.invariants),
-                                         ("goal", p.goals),
-                                         ("prune", p.prunes))
-                     for name in preds})
-
         # The stages below are named in the HLO's metadata
         # (``dslabs.<scope>``, tpu/telemetry.py DEVICE_SCOPES) so that a
         # profile can say which stage a device operation belongs to.
         with tel_mod.device_scope("expand.events"):
             msg_ids, tmr_ids, flt_ids, ev_drops = self._event_tables(
                 chunk_rows, chunk_valid, ev_pass, masks)
-        if stop == "events":
-            return _cut(msg_ids, tmr_ids)
         # TWO flat vmaps — one per event kind, each running only its own
         # machinery (the round-2 select-both design ran BOTH handlers for
         # every pair).  Flat, not nested: a nested
@@ -1897,9 +1873,6 @@ class TensorSearch:
             (nodes_t, sends_t, timers_t, exc_t, ok_t,
              tover_t) = jax.vmap(self._tmr_step_raw)(
                 rep_t, jnp.maximum(tmr_ids, 0).reshape(-1))
-        if stop == "handlers":
-            return _cut(nodes_m, sends_m, timers_m, ok_m,
-                        nodes_t, sends_t, timers_t, ok_t)
         with tel_mod.device_scope("expand.canon"):
             rows_m, over_m = self._batched_tail(
                 chunk_rows, c, bm, nodes_m, sends_m, timers_m, exc_m,
@@ -1909,9 +1882,6 @@ class TensorSearch:
                 chunk_rows, c, bt, nodes_t, sends_t, timers_t, exc_t,
                 ok_t, tover_t)
             val_t = ok_t & (tmr_ids >= 0).reshape(-1)
-        if stop == "tail":
-            return _cut(rows_m, rows_t)
-        with tel_mod.device_scope("expand.canon"):
             # Fault segment (ISSUE 19): no handlers, no sends —
             # _flt_step returns full successor rows directly, so the
             # pairs skip the batched merge tail entirely.
@@ -1954,8 +1924,6 @@ class TensorSearch:
         # representative; the stored rows stay the real states.
         with tel_mod.device_scope("fingerprint"):
             fp = row_fingerprints(self._canon_rows(rows))
-        if stop == "fp":
-            return _cut(fp, valids)
 
         if self._in_chunk_dedup if dedup is None else dedup:
             # In-chunk sort-unique on device: first occurrence of each

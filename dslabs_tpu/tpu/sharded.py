@@ -4,39 +4,29 @@ Scaling design (SURVEY §2.10, §5): the frontier, the visited set, and the
 next-frontier accumulator all live in device HBM, sharded over the
 ``search`` mesh axis.  Each BFS level is a sequence of chunk steps — every
 device expands a chunk of its frontier shard with the same vmapped
-transition the single-chip engine uses, then successor FINGERPRINTS
-(16 bytes each — state rows never ride the interconnect per chunk) are
-exchanged by **key ownership** (device = key_hi mod D) with
-``lax.all_to_all`` over ICI.  Each owner deduplicates the keys it owns
-against its **open-addressing hash table in HBM** — 8-slot buckets read
-as one aligned 128-byte line, membership and insert in one bounded probe
-loop (the Pallas bucket kernel / jnp oracle in tpu/visited.py), claim
-conflicts serialised by a per-bucket min-index reservation.  Under the
-default **fused row exchange** (ISSUE 12, ``DSLABS_SHARDED_EXCHANGE``)
-the successor rows ride the same owner buckets as their keys, so fresh
-states land on their OWNER's frontier shard as they are produced and
-the between-level promote is a local buffer swap — no reverse
-fresh-flag exchange, no boundary rebalance, no wide compaction.  The
-round-5 promote-boundary exchange (fresh flags returned to the
-producer via a reverse all_to_all, frontier REBALANCED between levels
-with contiguous shares + one all_to_all + one compaction) survives in
-the legacy per-chunk driver as the width-parity oracle.  This is the
-classic hash-partitioned distributed BFS,
-mapped onto XLA collectives instead of the reference's shared-memory
-ConcurrentHashMap (Search.java:405-505); with a 1-device mesh the
-collectives are identities, which is how the TPU bench runs.
+transition the single-chip engine uses, then successor fingerprints
+(16 bytes each) AND rows are exchanged by **key ownership**
+(device = key_hi mod D) with ``lax.all_to_all`` over ICI, in the same
+owner buckets.  Each owner deduplicates the keys it owns against its
+**open-addressing hash table in HBM** — 8-slot buckets read as one
+aligned 128-byte line, membership and insert in one bounded probe loop
+(tpu/visited.py), claim conflicts serialised by a per-bucket min-index
+reservation — and appends the fresh rows it received to its own
+next-frontier shard, so the between-level promote is a local buffer
+swap: no collective, no compaction.  This is the classic
+hash-partitioned distributed BFS, mapped onto XLA collectives instead
+of the reference's shared-memory ConcurrentHashMap
+(Search.java:405-505); with a 1-device mesh the collectives are
+identities, which is how a one-chip search runs.
 
 Host involvement per level: ONE on-device **superstep** dispatch — a
 ``lax.while_loop`` of chunk steps inside a single ``shard_map`` program
 that drains every device's own frontier shard (occupancy-driven trip
-count read from the carry, not the host's worst-case bound) and returns
-the fused scalar stats vector — plus the between-level promote, so at
-most two host dispatches per level where the round-5 driver issued
-``n_chunks + 1`` (one jitted dispatch per chunk plus the stats sync).
-The legacy host-driven per-chunk driver survives behind
-``DSLABS_SHARDED_SUPERSTEP=0`` as the parity oracle (docs/perf.md).  No
-state rows cross the host boundary until a terminal state must be
-reported; even the initial carry is built on device.
+count read from the carry, not a host bound) and returns the fused
+scalar stats vector — plus the between-level promote, so at most two
+host dispatches per level.  No state rows cross the host boundary
+until a terminal state must be reported; even the initial carry is
+built on device.
 
 Everything on device is int32/uint32 (TPU-native dtypes; no x64).  All
 fixed-capacity structures (routing buckets, frontier shards, visited
@@ -82,6 +72,10 @@ def _env_on(name: str, default: bool = True) -> bool:
     return v.strip().lower() not in ("0", "", "off", "false", "no")
 
 OVERFLOW_FACTOR = 2
+# Chunk steps per superstep dispatch under a wall-clock budget: the
+# host reads its clock between dispatches, so a time box ends at one of
+# these boundaries (PERF.md §2).
+SUPERSTEP_CHUNKS = 16
 # The visited hash table itself lives in dslabs_tpu/tpu/visited.py — ONE
 # implementation shared with the single-device engine's device-resident
 # wave loop (engine.py _run_device).
@@ -163,7 +157,8 @@ def make_mesh(n_devices: int = None, axis: str = "search") -> Mesh:
 class ShardedTensorSearch(TensorSearch):
     """BFS driver whose frontier, visited set, and expansion all live
     sharded on a device mesh; ``run()`` executes the full multi-level
-    search with one scalar sync per level.
+    search with one superstep dispatch (stats readback included) and
+    one promote per level.
 
     Per-device carry (global shapes have a leading D factor):
       cur      [F, lanes] int32   current frontier shard (owned states)
@@ -192,15 +187,12 @@ class ShardedTensorSearch(TensorSearch):
                  record_trace: bool = False,
                  checkpoint_path: Optional[str] = None,
                  checkpoint_every: int = 0,
-                 superstep: Optional[bool] = None,
-                 superstep_chunks: Optional[int] = None,
-                 row_exchange: Optional[bool] = None,
+                 superstep_chunks: int = SUPERSTEP_CHUNKS,
                  aot_warmup: Optional[bool] = None,
                  spill=None,
                  telemetry=None,
                  symmetry: Optional[bool] = None,
-                 mesh_pack: Optional[bool] = None,
-                 steal_threshold: Optional[float] = None):
+                 mesh_pack: bool = True):
         # Frontier checkpointing (SURVEY §5 "dump SoA tensors"): every
         # ``checkpoint_every`` levels the live carry — the OCCUPIED
         # frontier prefix, the occupied visited-table lines, and the
@@ -226,8 +218,9 @@ class ShardedTensorSearch(TensorSearch):
         # strict (unique counts must be exact), counted and reported via
         # SearchOutcome.visited_overflow in beam.
         # F must divide evenly by the chunk (chunk-loop slicing) AND the
-        # device count (level-rebalance shares); pad to the lcm so neither
-        # pad breaks the other's invariant.
+        # device count (resume and spill re-inject split the frontier
+        # into per-device shares); pad to the lcm so neither pad breaks
+        # the other's invariant.
         quantum = math.lcm(chunk_per_device, self.n_devices)
         if frontier_cap % quantum:
             frontier_cap += quantum - frontier_cap % quantum
@@ -255,7 +248,7 @@ class ShardedTensorSearch(TensorSearch):
         # ~60% of a loaded chunk step).  Multi-device strict keeps it:
         # per-owner buckets have only 2x-mean headroom.
         # Packed wire format (ISSUE 18): the sharded carry — frontier
-        # shards, routing buckets, the fused row-exchange payload — is
+        # shards, routing buckets, the row-exchange payload — is
         # re-typed to the spec-derived bit-packed encoding, so the
         # owner-hashed all_to_all ships descriptor.words int32 words per
         # state instead of ``lanes``.  super() still gets packed=False:
@@ -278,12 +271,11 @@ class ShardedTensorSearch(TensorSearch):
                          checkpoint_every=checkpoint_every,
                          spill=spill, telemetry=telemetry,
                          packed=False, symmetry=symmetry)
-        # Mesh wire codec: DSLABS_MESH_PACK=0 (or mesh_pack=False) keeps
-        # the legacy raw int32 exchange as the parity oracle.  Identity
-        # descriptors (hand twins without domain metadata) fall back to
-        # the raw wire — loudly, via the run()-time telemetry event.
-        self.mesh_pack = (_env_on("DSLABS_MESH_PACK", True)
-                          if mesh_pack is None else bool(mesh_pack))
+        # Mesh wire codec: mesh_pack=False keeps the raw int32 exchange
+        # (the codec's reference in the packed-vs-raw tests).  Identity
+        # descriptors (hand twins without domain metadata) run the raw
+        # wire too — loudly, via the run()-time telemetry event.
+        self.mesh_pack = bool(mesh_pack)
         if self.mesh_pack:
             from dslabs_tpu.tpu.packing import derive_packing
             pk = derive_packing(protocol, self.lanes, delta=True)
@@ -294,21 +286,6 @@ class ShardedTensorSearch(TensorSearch):
         self._mesh_delta = (self._pk is not None and self._pk.has_delta)
         if self._mesh_delta:
             self._delta_lanes = np.asarray(self._pk.delta_lanes, np.int32)
-        # Chunk-granular work stealing at level boundaries (ISSUE 18
-        # leg (c)): when the per-owner frontier occupancy skew exceeds
-        # the threshold, overfull owners donate packed rows through one
-        # extra all_to_all; dedup ownership (visited shards) never
-        # moves, only expand work, so counts stay bit-identical.
-        # Threshold <= 0 / unset = off (the default keeps today's
-        # dispatch counts byte-identical).  Only meaningful under the
-        # fused row exchange: the legacy promote already rebalances.
-        if steal_threshold is None:
-            _st = os.environ.get("DSLABS_MESH_STEAL_THRESHOLD", "")
-            steal_threshold = float(_st) if _st.strip() else 0.0
-        self._steal_threshold = float(steal_threshold)
-        self._steal_prog_cache = None
-        self._steal_events = 0
-        self._steal_moved = 0
         # Host-RAM spill tier (tpu/spill.py, docs/capacity.md): the
         # carry gains an ``f_full`` abort-code lane, the chunk step
         # aborts-and-reverts GLOBALLY (a psum'd decision — owner-side
@@ -321,99 +298,22 @@ class ShardedTensorSearch(TensorSearch):
         # Trace mode: each level spills (child_fp, parent_fp, event_id)
         # for every appended successor; reconstruction walks fingerprints
         # back to the root on the HOST (fps are stable identities, so the
-        # level rebalance needs no permutation bookkeeping) and replays
+        # owner routing needs no permutation bookkeeping) and replays
         # the grid event ids on the object twin via tpu/trace.py.
         self._fp_map = {}                  # child fp bytes -> (parent, ev)
-        # On-device level superstep (default; DSLABS_SHARDED_SUPERSTEP=0
-        # keeps the legacy host-driven per-chunk driver as the parity
-        # oracle).  The superstep fuses each level's whole chunk loop —
-        # lax.while_loop of chunk steps until every device's OWN frontier
-        # shard is drained — into ONE dispatch that also returns the
-        # fused stats vector, so host involvement per level drops from
-        # n_chunks + 1 dispatches to superstep + promote.
-        self.use_superstep = (_env_on("DSLABS_SHARDED_SUPERSTEP", True)
-                              if superstep is None else bool(superstep))
-        if self._spill_on:
-            # The spill abort protocol rides the superstep's drain
-            # condition; the legacy per-chunk parity driver stays the
-            # oracle for UNCAPPED runs only.
-            self.use_superstep = True
-        # In-superstep owner-routed row exchange (ISSUE 12): the fused
-        # chunk body routes the successor ROWS through the same
-        # owner-hashed all_to_all as their keys, so fresh states land
-        # on their owner's frontier shard as they are produced — the
-        # promote-boundary rebalance (one wide all_to_all + compaction
-        # per level) and the reverse fresh-flag exchange both
-        # disappear, and the level promote shrinks to a local buffer
-        # swap.  Default ON under the superstep driver;
-        # DSLABS_SHARDED_EXCHANGE=0 (or the legacy per-chunk driver,
-        # which IS the promote-boundary oracle) keeps the round-5
-        # exchange for the width-parity matrix.
-        self.row_exchange = (_env_on("DSLABS_SHARDED_EXCHANGE", True)
-                             if row_exchange is None
-                             else bool(row_exchange))
-        if not self.use_superstep:
-            self.row_exchange = False
-        # Steal rides the fused row exchange only (the legacy promote
-        # already rebalances evenly, so stealing there is redundant).
-        self._steal_on = (self._steal_threshold > 0.0
-                          and self.n_devices > 1 and self.row_exchange)
         # _flag_names is set by super().__init__ (shared with the
         # single-device device-resident loop).  Hot programs are jitted
         # with the rule-derived carry shardings pinned on BOTH sides
         # (in_shardings/out_shardings): placement is an explicit
         # contract, not an inference XLA re-derives per dispatch.
-        self._chunk_step = self._chunk_jit()
         self._finish_level = self._sharded_jit(self._build_finish())
         self._superstep = self._superstep_jit()
         # Chunk-step budget per superstep dispatch when a wall-clock
         # budget is active: bounds device work between host clock checks
-        # so mid-level TIME_EXHAUSTED keeps its round-3 granularity (the
-        # legacy driver blocked every 16 chunks for the same reason).
-        # First-class constructor knob since ISSUE 9: the supervisor's
-        # adaptive OOM backoff halves it per knob-shrink re-level
-        # (docs/resilience.md "knob-shrink ladder").
-        self._superstep_chunks = (
-            int(superstep_chunks) if superstep_chunks is not None
-            else int(os.environ.get("DSLABS_SUPERSTEP_CHUNKS", "16")
-                     or "16"))
-
-        # ONE fused scalar vector per host sync: every device->host
-        # readback is a host round-trip, and the naive sync did six
-        # (per-readback latency: not measured on this machine).
-        nf = len(self._flag_names)
-
-        def level_stats(carry):
-            return jnp.concatenate([
-                jnp.asarray([
-                    jnp.sum(carry["overflow"]),
-                    jnp.sum(carry["drops"]),
-                    jnp.sum(carry["vis_over"]),
-                    jnp.sum(carry["explored"]),
-                    jnp.max(carry["vis_n"]),
-                    jnp.sum(carry["vis_n"]),
-                    jnp.max(carry["nxt_n"]),
-                    # Slowest device's completed-chunk count: the spill
-                    # re-dispatch loop reads it from the SAME readback as
-                    # the level sync (no extra host round-trips when no
-                    # chunk spilled).
-                    jnp.min(carry["j"]),
-                ], jnp.int32),
-                jnp.sum(carry["flag_cnt"].reshape(self.n_devices, nf),
-                        axis=0).astype(jnp.int32),
-                # Per-device stats lanes (ISSUE 8): the pre-reduction
-                # per-device scalars ride the SAME readback vector —
-                # [explored×D, vis_n×D, nxt_n×D, drops×D], always the
-                # LAST 4D slots of either driver's layout — so shard
-                # skew / table load / frontier occupancy per device
-                # cost zero extra transfers.
-                carry["explored"].astype(jnp.int32),
-                carry["vis_n"].astype(jnp.int32),
-                carry["nxt_n"].astype(jnp.int32),
-                carry["drops"].astype(jnp.int32),
-            ])
-
-        self._stats = jax.jit(level_stats)
+        # so mid-level TIME_EXHAUSTED keeps chunk granularity.  The
+        # supervisor's adaptive OOM backoff halves it per knob-shrink
+        # re-level (docs/resilience.md "knob-shrink ladder").
+        self._superstep_chunks = int(superstep_chunks)
 
         # Explicit AOT warm-up (ISSUE 3): .lower().compile() the hot
         # programs at construction so compile wall-time is measured
@@ -487,52 +387,35 @@ class ShardedTensorSearch(TensorSearch):
         return self._sharded_jit(self._build_superstep(),
                                  extra_in=extra, extra_out=(rep,))
 
-    def _chunk_jit(self):
-        rep = self._replicated()
-        extra = (((rep, rep),) if self._has_rt_masks() else ())
-        return self._sharded_jit(self._build_chunk_step(),
-                                 extra_in=extra)
-
     # --------------------------------------------------------- level chunk
 
-    def _make_local_step(self, route_rows: bool = False):
-        """The per-device chunk-step body (runs INSIDE shard_map): one
-        chunk expand + key routing + owner dedup + frontier append.
-        Shared by the legacy per-chunk program (_build_chunk_step, one
-        shard_map dispatch per chunk) and the fused level superstep
-        (_build_superstep, a lax.while_loop of these bodies in one
-        dispatch)."""
+    def _make_local_step(self):
+        """The per-device chunk-step body (runs INSIDE shard_map, as
+        the body of the superstep's ``lax.while_loop``): one chunk
+        expand + owner routing of keys and rows + owner dedup +
+        frontier append."""
         p = self.p
         D = self.n_devices
         C = self.cpd
         F = self.f_cap
-        V = self.v_cap
         ne = self._num_events()
         ax = self.axis
-        lanes = self.lanes
-        # Packed wire format (ISSUE 18): frontier shards and the fused
+        # Packed wire format (ISSUE 18): frontier shards and the
         # row-exchange payload hold PACKED words; owners decode
         # in-register at expand time (unpack below), producers encode
         # each successor batch ONCE and both the wire and the nxt store
         # reuse the same packed rows.  plane == lanes when the codec is
         # identity / disabled — every shape below degenerates to the
-        # legacy raw layout.
+        # raw layout.
         pk = self._pk
         plane = self.plane
         delta = self._mesh_delta
         # On one device every successor routes to the sole owner, so the
         # bucket can hold the whole batch exactly (no overflow headroom
-        # needed) — halving the rows the probe loop and flag exchange
+        # needed) — halving the rows the probe loop and the append
         # touch.  Multi-device buckets keep 2x-mean headroom for skew.
         bucket = (C * ne if D == 1
                   else (C * ne // D + 1) * OVERFLOW_FACTOR)
-        nf = len(self._flag_names)
-        # Dev bisect hook (tools/profile_sharded2.py): truncate the step
-        # after a named stage, folding that stage's outputs into the
-        # explored counter so XLA cannot DCE the work under test.  None in
-        # production; the bisect tool measures the REAL step this way
-        # instead of maintaining a drifting copy.
-        stop_after = getattr(self, "_stop_after", None)
         # Spill mode (tpu/spill.py): frontier/table exhaustion ABORTS
         # the chunk step GLOBALLY — the decision is psum'd and every
         # device reverts its whole update (owner-side inserts included:
@@ -542,15 +425,6 @@ class ShardedTensorSearch(TensorSearch):
         # lane (bit 0 frontier full, bit 1 table full) for the host to
         # answer with a drain/evict before re-dispatching.
         spill_on = self._spill is not None
-
-        def _stopped(carry, *live):
-            out = dict(carry)
-            acc = carry["explored"][0]
-            for x in live:
-                acc = acc + jnp.sum(x).astype(jnp.int32)
-            out["explored"] = carry["explored"].at[0].set(acc)
-            out["j"] = carry["j"] + 1
-            return out
 
         def local(carry, masks=None):
             # The chunk index lives IN the carry (device-resident,
@@ -603,13 +477,6 @@ class ShardedTensorSearch(TensorSearch):
                         jnp.repeat(fp_par, ne_slots, axis=0),
                         event_ids.reshape(-1, 1).astype(jnp.uint32),
                     ], axis=1)                                 # [C*B, 9]
-            if stop_after in ("events", "handlers", "tail", "fp",
-                              "expand"):
-                # The engine-internal stages already truncated inside
-                # _expand_chunk (dummy outputs, live sums folded into
-                # `overflow`); fold here and skip the rest of the step.
-                return _stopped(carry, rows, fp, unique,
-                                jnp.asarray([overflow]))
 
             with tel_mod.device_scope("flags"):
                 # ---- terminal flags, checkState order (exception first)
@@ -665,15 +532,7 @@ class ShardedTensorSearch(TensorSearch):
                         jnp.minimum(carry["pb_nxt"], cand), ax)
 
             with tel_mod.device_scope("route"):
-                # ---- ownership routing: exchange FINGERPRINTS ONLY, never
-                # state rows.  Successor rows stay on the device that produced
-                # them; owners deduplicate the 16-byte keys and return a fresh
-                # flag via a second (reverse) all_to_all.  Any cross-row
-                # permutation of the [B, lanes] successor matrix — gather or
-                # scatter — measured ~2 GB/s effective (137 ms per chunk, 80%
-                # of the level step) in the round-2 bisection, and the key
-                # exchange also cuts ICI traffic by the full lane width
-                # (1354 lanes -> 4).  Successors sorted by owner form
+                # ---- ownership routing: successors sorted by owner form
                 # contiguous segments, so the [D, bucket] key buckets are
                 # narrow gathers at segment offsets.
                 owner = (fp[:, 0] % jnp.uint32(D)).astype(jnp.int32)
@@ -692,26 +551,19 @@ class ShardedTensorSearch(TensorSearch):
                 counts = ends - starts
                 route_drop = jnp.sum(jnp.maximum(counts - bucket, 0)).astype(
                     jnp.int32)
-                if route_rows:
-                    # Fused row exchange (ISSUE 12): the successor ROW,
-                    # its pruned flag, and (in trace mode) its meta ride
-                    # the SAME owner buckets as the keys — one extra
-                    # all_to_all per chunk lands every fresh state on its
-                    # OWNER's frontier shard as it is produced.  The
-                    # reverse fresh-flag exchange and the promote-boundary
-                    # rebalance (the per-level wide row movement + its
-                    # compaction scatter) both disappear; the level
-                    # promote shrinks to a local buffer swap
-                    # (_build_finish).
-                    parts = [rows_store, pruned[:, None].astype(jnp.int32)]
-                    if self.record_trace:
-                        parts.append(jax.lax.bitcast_convert_type(
-                            meta, jnp.int32))
-                    payload = jnp.concatenate(parts, axis=1)
-                    send_rows = payload[gidx.reshape(-1)].reshape(
-                        D, bucket, payload.shape[1])
-            if stop_after == "route":
-                return _stopped(carry, rows, send_keys, send_valid)
+                # The successor ROW, its pruned flag, and (in trace
+                # mode) its meta ride the SAME owner buckets as the
+                # keys — one more all_to_all per chunk lands every
+                # fresh state on its OWNER's frontier shard as it is
+                # produced, so the level promote is a local buffer swap
+                # (_build_finish).
+                parts = [rows_store, pruned[:, None].astype(jnp.int32)]
+                if self.record_trace:
+                    parts.append(jax.lax.bitcast_convert_type(
+                        meta, jnp.int32))
+                payload = jnp.concatenate(parts, axis=1)
+                send_rows = payload[gidx.reshape(-1)].reshape(
+                    D, bucket, payload.shape[1])
 
             with tel_mod.device_scope("exchange"):
                 # ---- the exchange: every device receives the key bucket
@@ -722,11 +574,8 @@ class ShardedTensorSearch(TensorSearch):
                 recv_keys = jnp.where(recv_valid.reshape(rb, 1),
                                       recv_keys.reshape(rb, 4), MAXU32)
                 recv_valid = recv_valid.reshape(rb)
-                if route_rows:
-                    recv_rows = jax.lax.all_to_all(
-                        send_rows, ax, 0, 0).reshape(rb, -1)
-            if stop_after == "a2a":
-                return _stopped(carry, rows, recv_keys, recv_valid)
+                recv_rows = jax.lax.all_to_all(
+                    send_rows, ax, 0, 0).reshape(rb, -1)
 
             with tel_mod.device_scope("visited_insert"):
                 # ---- owner-side dedup via the SHARED open-addressing hash
@@ -752,57 +601,19 @@ class ShardedTensorSearch(TensorSearch):
                 fresh_s = ins_s | unres_s
                 vis_over = jnp.sum(unres_s).astype(jnp.int32)
                 n_fresh = jnp.sum(ins_s).astype(jnp.int32)
-            if stop_after == "probe":
-                out = _stopped(carry, rows, fresh_s, unres_s)
-                out["visited"] = new_visited
-                return out
 
-            if route_rows:
-                # Owner-side append: the received rows ARE this
-                # device's share of the next frontier (owner-hashed
-                # placement — the distribution the per-device skew
-                # lanes judge).  No flag needs to travel back to the
-                # producer, so the reverse all_to_all is gone.
-                with tel_mod.device_scope("append"):
-                    app_rows = recv_rows[:, :plane]
-                    app_pruned = recv_rows[:, plane] != 0
-                    app_fresh = fresh_s        # implies recv_valid
-                    if self.record_trace:
-                        app_meta = jax.lax.bitcast_convert_type(
-                            recv_rows[:, plane + 1:], jnp.uint32)
-                if stop_after == "back":
-                    out = _stopped(carry, rows, app_fresh, app_pruned)
-                    out["visited"] = new_visited
-                    return out
-            else:
-                with tel_mod.device_scope("exchange"):
-                    # ---- return each key's fresh flag to its producer
-                    # (reverse all_to_all — an involution on the leading
-                    # axis; recv order was never permuted) and map it back
-                    # onto the producer's local successor rows.  Narrow
-                    # bool scatters only; `.max` (boolean or) so the
-                    # clipped dump writes of invalid slots can never
-                    # clobber a true flag.
-                    fresh_back = jax.lax.all_to_all(
-                        fresh_s.reshape(D, bucket), ax, 0, 0)
-                    fresh_rows = jnp.zeros(owner.shape[0], bool).at[
-                        gidx.reshape(-1)].max(
-                        fresh_back.reshape(-1) & send_valid.reshape(-1))
-                if stop_after == "back":
-                    out = _stopped(carry, rows, fresh_rows)
-                    out["visited"] = new_visited
-                    return out
-                app_rows = rows_store
-                app_pruned = pruned
-                app_fresh = fresh_rows
-                if self.record_trace:
-                    app_meta = meta
-
+            # Owner-side append: the received rows ARE this device's
+            # share of the next frontier (owner-hashed placement — the
+            # distribution the per-device skew lanes judge).
             with tel_mod.device_scope("append"):
-                # ---- append fresh, un-pruned successors (producer order
-                # under the legacy exchange, owner-received order under the
-                # fused row exchange — BFS level semantics are order-free)
-                # to the local next frontier.
+                app_rows = recv_rows[:, :plane]
+                app_pruned = recv_rows[:, plane] != 0
+                if self.record_trace:
+                    app_meta = jax.lax.bitcast_convert_type(
+                        recv_rows[:, plane + 1:], jnp.uint32)
+                # ---- append fresh, un-pruned successors, in owner-
+                # received order (BFS level semantics are order-free), to
+                # the local next frontier.
                 # noapp (set by run() for the FINAL depth-limited level):
                 # fresh states still count into vis_n/flags — discovered,
                 # checked, never expanded — but skip the frontier append, so
@@ -811,14 +622,14 @@ class ShardedTensorSearch(TensorSearch):
                 # DEPTH_EXHAUSTED would; the reference's BFS likewise never
                 # queues states at the cutoff depth).
                 noapp = carry["noapp"][0] == 1
-                sel_would = app_fresh & ~app_pruned
+                sel_would = fresh_s & ~app_pruned   # fresh implies valid
                 # Spill mode appends pruned-but-fresh rows too: every fresh
                 # insert must reach the host refilter (the drain recomputes
                 # the prune/exception mask before anything re-expands), or
                 # a post-eviction re-discovery of a pruned state would
                 # double-count.  noapp counting stays on sel_would — the
                 # DEPTH-vs-SPACE decision is about expandable successors.
-                sel = (app_fresh if spill_on else sel_would) & ~noapp
+                sel = (fresh_s if spill_on else sel_would) & ~noapp
                 spos = jnp.cumsum(sel) - 1
                 nxt, nxt_n = carry["nxt"], carry["nxt_n"][0]
                 sdst = jnp.where(sel & (nxt_n + spos < F), nxt_n + spos, F)
@@ -895,32 +706,6 @@ class ShardedTensorSearch(TensorSearch):
         return (self.p.deliver_message_rt is not None
                 or self.p.deliver_timer_rt is not None)
 
-    def _build_chunk_step(self):
-        # The legacy per-chunk driver IS the promote-boundary exchange
-        # oracle: rows stay with their producer, the rebalance moves
-        # them between levels (route_rows never applies here).
-        local = self._make_local_step(route_rows=False)
-        spec = self._carry_specs()
-        if self._has_rt_masks():
-            # Runtime delivery masks ride as a replicated ARGUMENT: every
-            # staged phase (different partition/timer gating, same
-            # protocol shape) shares one compiled program.
-            def chunk_step(c, m):
-                return local(c, m)
-
-            return shard_map(chunk_step, mesh=self.mesh,
-                             in_specs=(spec, (P(), P())), out_specs=spec,
-                             check_vma=False)
-
-        # The function's name is the program's in a profile
-        # (``jit_chunk_step``).
-        def chunk_step(c):
-            return local(c)
-
-        return shard_map(chunk_step, mesh=self.mesh,
-                         in_specs=(spec,), out_specs=spec,
-                         check_vma=False)
-
     # ---------------------------------------------------- level superstep
 
     def _build_superstep(self):
@@ -932,21 +717,19 @@ class ShardedTensorSearch(TensorSearch):
         a host wall-clock budget keeps mid-level granularity.
 
         The trip count is occupancy-driven FROM THE CARRY: device d runs
-        ``ceil(cur_n_d / C)`` chunk steps (its actual post-rebalance
-        share) instead of the host's pre-rebalance ``max_n + D - 1``
-        worst case, and the loop condition is the psum of the per-device
-        "still draining" flags — every device executes the same trip
-        count (the body contains collectives) but that count is the max
-        of the ACTUAL needs, not the host's bound.
+        ``ceil(cur_n_d / C)`` chunk steps (its own share), and the loop
+        condition is the psum of the per-device "still draining" flags —
+        every device executes the same trip count (the body contains
+        collectives), the max of the ACTUAL needs.
 
         Returns ``(carry', stats)`` where ``stats`` is the fused scalar
-        vector — the legacy 8 + n_flags layout (_sync_checks parses both
-        drivers identically) plus two superstep-only slots:
-        ``[..., remaining_devices, steps_taken]``.  Computing the stats
-        in-program (psum/pmax over the mesh axis) folds the level sync
-        into the same dispatch: host involvement per level becomes
+        vector _sync_checks parses: 8 scalars, the n_flags counts,
+        ``[remaining_devices, steps_taken]``, the spill abort code when
+        the host tier is wired, and the per-device lanes.  Computing the
+        stats in-program (psum/pmax over the mesh axis) folds the level
+        sync into the same dispatch: host involvement per level is
         superstep + promote."""
-        local = self._make_local_step(route_rows=self.row_exchange)
+        local = self._make_local_step()
         C = self.cpd
         ax = self.axis
 
@@ -964,6 +747,8 @@ class ShardedTensorSearch(TensorSearch):
                 jax.lax.pmax(c["vis_n"][0], ax),
                 _psum(c["vis_n"][0]),
                 jax.lax.pmax(c["nxt_n"][0], ax),
+                # The slowest device's chunk count: a diagnostic slot,
+                # _sync_checks does not read it.
                 jax.lax.pmin(c["j"][0], ax),
             ]).astype(jnp.int32)
             flags = _psum(c["flag_cnt"]).astype(jnp.int32)
@@ -972,9 +757,8 @@ class ShardedTensorSearch(TensorSearch):
             tail = jnp.stack([remaining, steps]).astype(jnp.int32)
             parts = [core, flags, tail]
             if spill_on:
-                # Spill abort code after the tail so every legacy index
-                # parse is untouched; the abort is global, so any
-                # device's copy is the fleet's (pmax for robustness).
+                # The abort is global, so any device's copy is the
+                # fleet's (pmax for robustness).
                 parts.append(jax.lax.pmax(
                     c["f_full"], ax).astype(jnp.int32))
             # Per-device stats lanes (ISSUE 8), LAST so all absolute
@@ -1053,74 +837,28 @@ class ShardedTensorSearch(TensorSearch):
             return self._dispatch("sharded.superstep", run, carry, b, rt)
         return self._dispatch("sharded.superstep", run, carry, b)
 
-    def _step(self, carry):
-        """Dispatch one chunk step, passing the runtime masks when the
-        protocol declares them.  Routed through the supervisor's
-        dispatch boundary (engine._dispatch) like every hot-loop
-        dispatch."""
-        rt = getattr(self, "_rt_masks", None)
-        prog = self._prog("step", self._chunk_step)
-        if rt is not None:
-            return self._dispatch("sharded.step", prog, carry, rt)
-        return self._dispatch("sharded.step", prog, carry)
-
     def _build_finish(self):
-        """Promote nxt -> cur between levels, REBALANCING the frontier
-        across the mesh: successors accumulate on the device that produced
-        them (the chunk step exchanges only fingerprints, never rows —
-        see _build_chunk_step), so without this every reachable state
-        would descend through the initial state's device alone and D-1
-        devices would expand empty chunks.  Each device splits its
-        occupied prefix into D equal contiguous shares (dynamic slices at
-        traced offsets — no computed-index row permutation), one
-        all_to_all moves the shares, and a single compaction scatter per
-        LEVEL re-densifies — wide row movement at level granularity is
-        ~1% of the level's chunk work."""
-        D = self.n_devices
-        F, lanes = self.f_cap, self.lanes
+        """Promote nxt -> cur between levels.  Successors already landed
+        on their owner's shard inside the superstep, so the promote is a
+        LOCAL buffer swap — no collective, no compaction — plus, under a
+        delta descriptor, the re-base of the promoted rows."""
+        F = self.f_cap
         plane = self.plane
         pk = self._pk
         delta = self._mesh_delta
-        ax = self.axis
-        share = F // D
 
         def promote(carry):
             with tel_mod.device_scope("promote"):
                 carry = dict(carry)
-                nxt, nxt_n = carry["nxt"], carry["nxt_n"][0]
-                if D == 1 or self.row_exchange:
-                    # Fused row exchange (ISSUE 12): successors already
-                    # landed on their owner's shard inside the superstep,
-                    # so the promote is a LOCAL buffer swap — zero ICI
-                    # traffic, zero wide compaction; on one device the
-                    # round-5 rebalance was an identity anyway.
-                    carry["cur"] = nxt[:F]
-                    carry["cur_n"] = carry["nxt_n"]
-                else:
-                    per = (nxt_n + D - 1) // D          # rows per share
-                    send = jnp.stack([
-                        jax.lax.dynamic_slice(nxt, (s * per, 0),
-                                              (share, plane))
-                        for s in range(D)])             # [D, share, plane]
-                    r = jnp.arange(share)
-                    send_valid = jnp.stack([
-                        (r < per) & (s * per + r < nxt_n) for s in range(D)])
-                    recv = jax.lax.all_to_all(send, ax, 0, 0)
-                    recv_valid = jax.lax.all_to_all(send_valid, ax, 0, 0)
-                    rows = recv.reshape(D * share, plane)
-                    v = recv_valid.reshape(-1)
-                    pos = jnp.cumsum(v) - 1
-                    dst = jnp.where(v, pos, F)
-                    carry["cur"] = jnp.zeros(
-                        (F + 1, plane), jnp.int32).at[dst].set(rows)[:F]
-                    carry["cur_n"] = jnp.sum(v).astype(jnp.int32)[None]
+                carry["cur"] = carry["nxt"][:F]
+                carry["cur_n"] = carry["nxt_n"]
                 if delta:
                     # Delta re-base (ISSUE 18 leg (b)): the promoted rows
                     # were packed against the OLD level base; re-encode them
                     # against the accumulated next-level base (pb_nxt, a
                     # global pmin computed inside the chunk steps — already
                     # value-identical on every device, so this stays
-                    # elementwise: the fused promote keeps ZERO collectives).
+                    # elementwise: the promote keeps ZERO collectives).
                     pb_old = carry["pb_cur"]
                     # A lane whose pb_nxt never saw a successor (empty next
                     # frontier) keeps the old base so the (vacuous)
@@ -1162,174 +900,6 @@ class ShardedTensorSearch(TensorSearch):
         drift apart."""
         return match_partition_rules(CARRY_PARTITION_RULES,
                                      self._carry_names(), self.axis)
-
-    # ------------------------------------------- boundary work stealing
-
-    def _build_steal(self):
-        """Chunk-granular work-stealing rebalance (ISSUE 18 leg (c)):
-        ONE extra all_to_all at a level boundary moves packed frontier
-        rows from overfull owners to underfull ones per a replicated
-        host-built [D, D] donation plan (plan[s, r] = rows device s
-        donates to device r, each entry <= one chunk).  Only EXPAND
-        work migrates — visited shards, and therefore dedup ownership
-        and every count, are untouched; the donated rows were already
-        deduplicated when they landed on their owner, so moving them
-        is a pure relabeling of who expands what.  Donors give away
-        their frontier TAIL (the suffix above the kept prefix), so the
-        surviving prefix needs no compaction."""
-        D = self.n_devices
-        F = self.f_cap
-        K = self.cpd
-        plane = self.plane
-        ax = self.axis
-
-        def steal(carry, plan):
-            carry = dict(carry)
-            cur, cur_n = carry["cur"], carry["cur_n"][0]
-            s = jax.lax.axis_index(ax)
-            give = plan[s]                          # [D] rows to donate
-            cum = jnp.cumsum(give)
-            tot = cum[-1]
-            # Donation r occupies [cur_n - cum[r], cur_n - cum[r] +
-            # give[r]) of the local frontier — disjoint tail slices.
-            starts = jnp.maximum(cur_n - cum, 0)
-            offs = jnp.arange(K)
-            # Exact gather (not dynamic_slice: its out-of-bounds start
-            # clamping would silently shift a tail window that sits
-            # within K of the cap).
-            send = jnp.stack([
-                jnp.take(cur, (starts[r] + offs).clip(0, F - 1),
-                         axis=0)
-                for r in range(D)])                 # [D, K, plane]
-            sv = offs[None, :] < give[:, None]
-            recv = jax.lax.all_to_all(send, ax, 0, 0).reshape(
-                D * K, plane)
-            rv = jax.lax.all_to_all(sv, ax, 0, 0).reshape(-1)
-            keep_n = cur_n - tot
-            pos = jnp.cumsum(rv) - 1
-            dst = jnp.where(rv, keep_n + pos, F)
-            got = jnp.sum(rv).astype(jnp.int32)
-            # A receiver past frontier_cap drops the excess — counted
-            # loudly (strict runs raise at the next sync); the host
-            # plan never builds one (targets <= total // D <= F).
-            lost = jnp.sum(rv & (dst >= F)).astype(jnp.int32)
-            carry["cur"] = cur.at[dst].set(recv, mode="drop")
-            carry["cur_n"] = (keep_n + got - lost)[None]
-            carry["drops"] = carry["drops"].at[0].add(lost)
-            return carry
-
-        spec = self._carry_specs()
-        return self._sharded_jit(
-            shard_map(steal, mesh=self.mesh, in_specs=(spec, P()),
-                      out_specs=spec, check_vma=False),
-            extra_in=(self._replicated(),))
-
-    def _steal_prog(self):
-        if self._steal_prog_cache is None:
-            self._steal_prog_cache = self._build_steal()
-        return self._steal_prog_cache
-
-    def _steal_plan(self, occ, depth):
-        """Host-side donation planner over the per-device frontier
-        occupancy lanes (read from the SAME fused stats vector as the
-        level sync — zero extra readbacks).  Returns a [D, D] int32
-        plan or None.  Two regimes:
-
-        * ``depth == 1`` — root-fanout seeding: the level-1 frontier is
-          the lone root's successor set; split it evenly across owners
-          unconditionally (no threshold, no chunk rounding) so the
-          early tree never serializes on one owner.
-        * deeper levels — gated on ``imbalance_max >``
-          DSLABS_MESH_STEAL_THRESHOLD, and donations move in WHOLE
-          chunks (the superstep's work quantum: a partial chunk costs a
-          full chunk step, so finer migration cannot help)."""
-        D, K = self.n_devices, self.cpd
-        occ = [int(x) for x in occ]
-        total = sum(occ)
-        if D == 1 or total < 2:
-            return None
-        mean = total / D
-        imb = max(occ) / mean
-        fanout = depth == 1
-        if not fanout and imb <= self._steal_threshold:
-            return None
-        target = total // D
-        if fanout:
-            # A successor set smaller than the mesh still fans out: one
-            # row per owner beats D-1 idle owners at level 2.
-            target = max(1, target)
-        donors = [[d, occ[d] - target] for d in range(D)
-                  if occ[d] > target]
-        recvs = [[d, target - occ[d]] for d in range(D)
-                 if occ[d] < target]
-        donors.sort(key=lambda x: -x[1])
-        recvs.sort(key=lambda x: -x[1])
-        plan = np.zeros((D, D), np.int32)
-        for d, ex in donors:
-            for r_ent in recvs:
-                if ex <= 0:
-                    break
-                r, need = r_ent
-                if need <= 0:
-                    continue
-                amt = min(ex, need, K)
-                if not fanout:
-                    amt = (amt // K) * K     # whole chunks only
-                if amt <= 0:
-                    continue
-                plan[d, r] = amt
-                ex -= amt
-                r_ent[1] -= amt
-        if not plan.any():
-            return None
-        return plan
-
-    def _maybe_steal(self, carry, depth):
-        """Boundary steal hook — runs right after the level promote,
-        using the per-device nxt_n lanes (== the promoted frontier
-        occupancy under the fused row exchange) from the level's stats
-        readback.  Updates the level record and emits a telemetry
-        event; counts stay bit-identical by construction (the visited
-        shards never move)."""
-        if not self._steal_on:
-            return carry
-        pdev = getattr(self, "_last_per_device", None)
-        if not pdev:
-            return carry
-        occ = pdev.get("frontier")
-        if occ is None:
-            return carry
-        plan = self._steal_plan(occ, depth)
-        if plan is None:
-            return carry
-        prog = self._prog("steal", self._steal_prog())
-        pl = jax.device_put(jnp.asarray(plan), self._replicated())
-        carry = self._dispatch("sharded.steal", prog, carry, pl)
-        moved = int(plan.sum())
-        occ_after = [int(o) - int(plan[d].sum()) + int(plan[:, d].sum())
-                     for d, o in enumerate(occ)]
-        self._steal_events += 1
-        self._steal_moved += moved
-        from dslabs_tpu.tpu.telemetry import skew_metrics
-        before = skew_metrics(occ)
-        after = skew_metrics(occ_after)
-        recs = getattr(self, "_level_records", None)
-        if recs:
-            recs[-1]["steal"] = {
-                "moved": moved,
-                "imbalance_before": before["imbalance"],
-                "imbalance_after": after["imbalance"],
-            }
-            sk = recs[-1].setdefault("skew", {})
-            sk["frontier_post_steal"] = after
-        pdev["frontier"] = occ_after
-        tel = getattr(self, "_telemetry", None)
-        if tel is not None:
-            tel.event("steal", engine="sharded", depth=depth,
-                      moved=moved,
-                      imbalance_before=round(before["imbalance"], 3),
-                      imbalance_after=round(after["imbalance"], 3))
-        return carry
 
     # ----------------------------------------------------------------- run
 
@@ -1465,9 +1035,9 @@ class ShardedTensorSearch(TensorSearch):
         return out
 
     def aot_warmup(self) -> float:
-        """Ahead-of-time compile the hot programs (superstep or legacy
-        chunk step + stats, the level promote, and the default root's
-        carry initializer) via ``.lower().compile()``, so compile cost
+        """Ahead-of-time compile the hot programs (the superstep, the
+        level promote, and the default root's carry initializer) via
+        ``.lower().compile()``, so compile cost
         is paid — and MEASURED — at construction instead of inside the
         first run's search window.  With the persistent compile cache
         (tpu/compile_cache.py) the second
@@ -1501,13 +1071,8 @@ class ShardedTensorSearch(TensorSearch):
             tel_mod.register_program(name, exes[key])
 
         with tel_mod.phase("compile.aot"):
-            if self.use_superstep:
-                compile_("superstep", "superstep", self._superstep,
-                         sds, b, *mask_args)
-            else:
-                compile_("step", "chunk_step", self._chunk_step,
-                         sds, *mask_args)
-                compile_("stats", "level_stats", self._stats, sds)
+            compile_("superstep", "superstep", self._superstep,
+                     sds, b, *mask_args)
             compile_("promote", "promote", self._finish_level, sds)
             rows0, key0, owner, home = self._root_ids(
                 self.initial_state())
@@ -1544,11 +1109,9 @@ class ShardedTensorSearch(TensorSearch):
 
     def dispatch_site_programs(self):
         """Sanitizer site registry (ISSUE 10; see the base-class
-        docstring): the ACTIVE driver's programs — the fused superstep
-        by default, the legacy per-chunk step + stats pair under
-        DSLABS_SHARDED_SUPERSTEP=0 — plus the level promote, the root
-        carry initializer, and the spill reset/evict shard_map programs
-        when the host tier is wired.  Args are the same abstract carry
+        docstring): the superstep, the level promote, the root carry
+        initializer, and the spill reset/evict shard_map programs when
+        the host tier is wired.  Args are the same abstract carry
         (ShapeDtypeStruct + NamedSharding) the AOT warm-up lowers, so
         the audit sees byte-identical programs to the ones dispatched."""
         sds = self._carry_sds()
@@ -1559,20 +1122,10 @@ class ShardedTensorSearch(TensorSearch):
                 "before dispatch_site_programs()")
         mask_args = (rt,) if rt is not None else ()
         b = jnp.asarray(1 << 30, jnp.int32)
-        sites = {}
-        if self.use_superstep:
-            sites["sharded.superstep"] = dict(
-                fn=self._superstep, args=(sds, b, *mask_args),
-                donate=(0,), multi=True,
-                builder=self._superstep_jit)
-        else:
-            sites["sharded.step"] = dict(
-                fn=self._chunk_step, args=(sds, *mask_args),
-                donate=(0,), multi=True,
-                builder=self._chunk_jit)
-            sites["sharded.sync"] = dict(
-                fn=self._stats, args=(sds,), donate=(), multi=False,
-                builder=None)
+        sites = {"sharded.superstep": dict(
+            fn=self._superstep, args=(sds, b, *mask_args),
+            donate=(0,), multi=True,
+            builder=self._superstep_jit)}
         sites["sharded.promote"] = dict(
             fn=self._finish_level, args=(sds,), donate=(0,),
             multi=True,
@@ -1638,12 +1191,6 @@ class ShardedTensorSearch(TensorSearch):
                     fn=jax.jit(pk.unpack_jnp), args=(packed_sds,),
                     donate=(), multi=False,
                     builder=lambda: jax.jit(pk.unpack_jnp))
-        if self._steal_on:
-            plan_sds = jax.ShapeDtypeStruct(
-                (self.n_devices, self.n_devices), jnp.int32)
-            sites["sharded.steal"] = dict(
-                fn=self._steal_prog(), args=(sds, plan_sds),
-                donate=(0,), multi=True, builder=self._build_steal)
         return sites
 
     def _terminal_from_flags(self, carry, explored, vis_total, depth, t0):
@@ -1704,13 +1251,12 @@ class ShardedTensorSearch(TensorSearch):
         """Device-side snapshot (fresh buffers — the live carry is
         donated to the next chunk step, so the dump thread must never
         alias it)."""
-        # Post-rebalance occupancy bound: ceil-split can give one device
-        # up to max_n + D - 1 rows (run()'s chunk-grid bound) — but on a
-        # 1-device mesh the rebalance is an identity, so no slack.
-        # Rounded UP to a power of two so the per-shape jitted snapshot
-        # programs number O(log f_cap), not one per frontier size (each
-        # is a synchronous shard_map compile in the level gap).
-        need = min(max_n + self._rebalance_slack(), self.f_cap)
+        # max_n (the level sync's nxt_max) is the exact per-device
+        # occupancy bound.  Rounded UP to a power of two so the
+        # per-shape jitted snapshot programs number O(log f_cap), not
+        # one per frontier size (each is a synchronous shard_map
+        # compile in the level gap).
+        need = min(max_n, self.f_cap)
         m = self.cpd
         while m < need:
             m <<= 1
@@ -2230,9 +1776,9 @@ class ShardedTensorSearch(TensorSearch):
                 if self.n_devices > 1 and self._pk is None:
                     # Identity-codec fallback on a real mesh (ISSUE 18
                     # satellite): the exchange shipped RAW lanes — hand
-                    # twins without domain declarations, or the
-                    # DSLABS_MESH_PACK=0 parity oracle.  Loud until
-                    # ROADMAP #1 deletes the hand twins.
+                    # twins without domain declarations, or
+                    # mesh_pack=False.  Loud until ROADMAP #1 deletes
+                    # the hand twins.
                     tel.event(
                         "mesh_unpacked", engine="sharded",
                         protocol=self.p.name,
@@ -2320,13 +1866,9 @@ class ShardedTensorSearch(TensorSearch):
                         shard = NamedSharding(self.mesh, P(self.axis))
                         carry["noapp"] = jax.device_put(
                             np.ones(self.n_devices, np.int32), shard)
-                    if self.use_superstep:
-                        (carry, out, explored, vis_total, drops, max_n,
-                         chunks) = self._level_superstep(carry, depth, t0,
-                                                         max_n)
-                    else:
-                        (carry, out, explored, vis_total, drops, max_n,
-                         chunks) = self._level_chunks(carry, depth, t0, max_n)
+                    (carry, out, explored, vis_total, drops, max_n,
+                     chunks) = self._level_superstep(carry, depth, t0,
+                                                     max_n)
                     if out is not None:
                         return out
                     if self._spill_on:
@@ -2468,11 +2010,6 @@ class ShardedTensorSearch(TensorSearch):
                 carry = self._dispatch(
                     "sharded.promote",
                     self._prog("promote", self._finish_level), carry)
-                # Boundary work stealing (ISSUE 18 leg (c)): root-fanout
-                # at depth 1 (split the lone root's successor set), the
-                # threshold-gated chunk-granular rebalance at deeper
-                # boundaries.  max_n stays the (safe, pre-steal) bound.
-                carry = self._maybe_steal(carry, depth)
                 if (self.checkpoint_every and self.checkpoint_path
                         and depth % self.checkpoint_every == 0):
                     self._save_checkpoint(carry, depth, time.time() - t0,
@@ -2483,19 +2020,6 @@ class ShardedTensorSearch(TensorSearch):
                 time.time() - t0, dropped=drops,
                 samples=getattr(self, "_deep_samples", None),
                 visited_overflow=getattr(self, "_vis_over", 0))
-
-    def _rebalance_slack(self) -> int:
-        """Post-rebalance occupancy slack over the pre-rebalance max_n:
-        ceil-split can hand one device up to ``max_n + D - 1`` rows — but
-        a 1-device mesh's rebalance is an identity, so the extra
-        (mostly-invalid) chunk the slack would force is pure waste on
-        the TPU bench path and is skipped.  The fused row exchange has
-        no rebalance at all (owner-side appends ARE the placement, and
-        the level sync's nxt_max is already the exact per-device
-        bound), so it needs no slack either."""
-        if self.n_devices == 1 or self.row_exchange:
-            return 0
-        return self.n_devices - 1
 
     def _level_superstep(self, carry, depth, t0, max_n):
         """One BFS level via the fused on-device superstep: each
@@ -2510,7 +2034,7 @@ class ShardedTensorSearch(TensorSearch):
         # legitimately runs a whole level's chunk work in one dispatch,
         # so the per-dispatch deadline scales by the expected trip count
         # (2x for event-window spill re-passes).
-        est = -(-(max_n + self._rebalance_slack()) // self.cpd)
+        est = -(-max_n // self.cpd)
         self._dispatch_deadline_scales = {
             "superstep": float(max(1, min(budget, 2 * est)))}
         nf = len(self._flag_names)
@@ -2520,10 +2044,9 @@ class ShardedTensorSearch(TensorSearch):
             chunks += int(stats[9 + nf])
             # The checks run BEFORE any time-budget return: a violation
             # or capacity loss in the chunks already completed is never
-            # masked by TIME_EXHAUSTED (same contract as the legacy
-            # driver's mid-level clock check).
-            (out, explored, vis_total, drops, nxt_max,
-             _j) = self._sync_checks(carry, depth, t0, stats=stats)
+            # masked by TIME_EXHAUSTED.
+            (out, explored, vis_total, drops,
+             nxt_max) = self._sync_checks(carry, depth, t0, stats)
             if out is not None:
                 return (carry, out, explored, vis_total, drops, nxt_max,
                         chunks)
@@ -2562,69 +2085,6 @@ class ShardedTensorSearch(TensorSearch):
                 out.cancelled = self._cancelled()
                 return (carry, out,
                         explored, vis_total, drops, nxt_max, chunks)
-
-    def _level_chunks(self, carry, depth, t0, max_n):
-        """The legacy host-driven per-chunk level driver (one jitted
-        dispatch per chunk + one stats sync) — kept behind
-        ``DSLABS_SHARDED_SUPERSTEP=0`` as the parity oracle the fused
-        superstep is tested against.  Same return contract as
-        :meth:`_level_superstep`."""
-        # max_n was read BEFORE the rebalance: a device can end up with
-        # ceil(total/D) <= max_n + D - 1 rows afterwards, so widen the
-        # chunk grid by that bound (at most one extra, mostly-invalid
-        # chunk; never silently skips rows).  1-device meshes skip the
-        # slack — the rebalance is an identity there.
-        n_chunks = -(-(max_n + self._rebalance_slack()) // self.cpd)
-        chunks = n_chunks
-        for j in range(n_chunks):
-            carry = self._step(carry)
-            # Respect the time budget inside long levels too.  The
-            # partial level runs the same overflow/terminal-flag
-            # checks as a full level before reporting, so a
-            # violation or capacity loss in the chunks already
-            # processed is never masked by TIME_EXHAUSTED.
-            # Dispatch is async — without the periodic block the
-            # whole level enqueues in milliseconds and the clock
-            # check below can never fire mid-level (round-3: a
-            # 120 s budget overran to 153 s, and the overrun runs
-            # the SLOWEST, highest-table-load chunks).
-            if (self.max_secs is not None and j % 16 == 15):
-                jax.block_until_ready(carry["j"])
-            if (self.max_secs is not None and j + 1 < n_chunks
-                    and time.time() - t0 > self.max_secs):
-                (out, explored, vis_total, drops, nxt_max,
-                 _j) = self._sync_checks(carry, depth, t0)
-                if out is None:
-                    out = self._limit_outcome("TIME_EXHAUSTED", carry,
-                                              depth, t0)
-                return (carry, out, explored, vis_total, drops, nxt_max,
-                        j + 1)
-        # ---- the one host sync per level.  With event-window spill, a
-        # chunk that had valid events past its window held j back —
-        # re-dispatch until the slowest device has completed all its
-        # chunks (no extra readbacks when nothing spilled: j_done rides
-        # the same stats vector).
-        while True:
-            (out, explored, vis_total, drops, nxt_max,
-             j_done) = self._sync_checks(carry, depth, t0)
-            if out is not None:
-                return (carry, out, explored, vis_total, drops, nxt_max,
-                        chunks)
-            if not self.ev_spill or j_done >= n_chunks:
-                return (carry, None, explored, vis_total, drops, nxt_max,
-                        chunks)
-            # Spill rounds respect the time budget too (the checks above
-            # already ran, so a verdict in the completed chunks is never
-            # masked).
-            if (self.max_secs is not None
-                    and time.time() - t0 > self.max_secs):
-                return (carry,
-                        self._limit_outcome("TIME_EXHAUSTED", carry,
-                                            depth, t0),
-                        explored, vis_total, drops, nxt_max, chunks)
-            for _ in range(n_chunks - j_done):
-                carry = self._step(carry)
-                chunks += 1
 
     def _spill_tmeta(self, carry) -> None:
         """Fold this level's appended (child_fp, parent_fp, event) rows
@@ -2684,30 +2144,22 @@ class ShardedTensorSearch(TensorSearch):
         events.reverse()
         return events
 
-    def _sync_checks(self, carry, depth, t0, stats=None):
+    def _sync_checks(self, carry, depth, t0, stats):
         """The per-sync check pipeline: semantic overflow (raise) ->
         strict-mode drops (raise) -> terminal flags (checkState order) ->
-        visited load factor (raise).  ONE device->host readback (the fused
-        ``_stats`` vector) — or zero when the superstep already returned
-        the vector in-program (``stats``); the expensive flag-row
-        readback happens only when a terminal flag actually fired.
-        Returns (outcome_or_none, explored, vis_total, drops, nxt_max,
-        j_done) where j_done is the slowest device's completed-chunk
-        count (the spill re-dispatch signal)."""
-        if stats is None:
-            s = np.asarray(self._dispatch(
-                "sharded.sync", self._prog("stats", self._stats), carry))
-        else:
-            s = np.asarray(stats)
+        visited load factor (raise), over the fused ``stats`` vector the
+        superstep returned in-program — no readback of its own; the
+        expensive flag-row readback happens only when a terminal flag
+        actually fired.  Returns (outcome_or_none, explored, vis_total,
+        drops, nxt_max)."""
+        s = np.asarray(stats)
         nf = len(self._flag_names)
-        (overflow, drops, vis_over, explored, vis_max, vis_total, nxt_max,
-         j_done) = (int(x) for x in s[:8])
+        (overflow, drops, vis_over, explored, vis_max, vis_total,
+         nxt_max) = (int(x) for x in s[:7])
         flag_counts = s[8:8 + nf]
-        # Per-device stats lanes: the LAST 4D slots of either driver's
-        # layout (superstep appends them after the tail/f_full slots,
-        # the legacy stats program after the flags) — stashed for the
-        # level record's skew derivation, same readback as everything
-        # above.
+        # Per-device stats lanes: the LAST 4D slots of the vector —
+        # stashed for the level record's skew derivation, same readback
+        # as everything above.
         D = self.n_devices
         pd = [int(x) for x in s[len(s) - 4 * D:]]
         self._last_per_device = {
@@ -2765,7 +2217,7 @@ class ShardedTensorSearch(TensorSearch):
             if out is not None:
                 out.dropped = drops
                 out.visited_overflow = vis_over
-                return out, explored, vis_total, drops, nxt_max, j_done
+                return out, explored, vis_total, drops, nxt_max
         if self._spill_on:
             # The abort protocol reverts any chunk that would leave
             # keys unresolved, and eviction replaces the 75% guard.
@@ -2773,7 +2225,7 @@ class ShardedTensorSearch(TensorSearch):
                 raise AssertionError(
                     "spill mode committed unresolved keys (abort "
                     "contract violated)")
-            return None, explored, vis_total, drops, nxt_max, j_done
+            return None, explored, vis_total, drops, nxt_max
         if vis_over and self.strict:
             raise CapacityOverflow(
                 f"{self.p.name}: visited hash table full at depth "
@@ -2785,7 +2237,7 @@ class ShardedTensorSearch(TensorSearch):
                 f"{self.p.name}: visited hash table > 75% full "
                 f"({vis_max}/{self.v_cap} per device) "
                 f"at depth {depth}; raise visited_cap")
-        return None, explored, vis_total, drops, nxt_max, j_done
+        return None, explored, vis_total, drops, nxt_max
 
     def _limit_outcome(self, cond, carry, depth, t0):
         unique = int(np.asarray(carry["vis_n"]).sum())

@@ -394,8 +394,9 @@ class DispatchBoundary:
     dispatch funnels through (``TensorSearch._dispatch``).
 
     Install on a search with :meth:`install`; tags are
-    ``"<engine>.<site>"`` (e.g. ``"sharded.step"``) and the engine half
-    keys both the fault plan and the per-rung dispatch/retry counters.
+    ``"<engine>.<site>"`` (e.g. ``"sharded.superstep"``) and the engine
+    half keys both the fault plan and the per-rung dispatch/retry
+    counters.
     """
 
     def __init__(self, policy: Optional[RetryPolicy] = None,
@@ -704,8 +705,7 @@ class SearchSupervisor:
                  spill=False,
                  telemetry=None,
                  elastic: Optional[bool] = None,
-                 max_knob_shrinks: Optional[int] = None,
-                 row_exchange: Optional[bool] = None):
+                 max_knob_shrinks: Optional[int] = None):
         for rung in ladder:
             if rung not in ("sharded", "device", "host"):
                 raise ValueError(f"unknown ladder rung {rung!r}")
@@ -723,12 +723,6 @@ class SearchSupervisor:
         self.frontier_cap = frontier_cap
         self.visited_cap = visited_cap
         self.ev_budget = ev_budget
-        # Fused in-superstep row exchange (ISSUE 12): None defers to
-        # the engine's DSLABS_SHARDED_EXCHANGE default; every ladder
-        # rung — degraded widths and knob-shrunk re-levels included —
-        # is built with the SAME exchange so a failover never silently
-        # changes what the verdict's dispatch path was.
-        self.row_exchange = row_exchange
         # AOT warm-up of the sharded rung's programs at build time —
         # compile wall-time lands on SearchOutcome.compile_secs instead
         # of inside the first run's measured window (bench.py).
@@ -888,20 +882,17 @@ class SearchSupervisor:
         # chunk budget — so an OOM retry runs strictly lighter.
         chunk = max(1, self.chunk >> shrink)
         if rung == "sharded":
-            from dslabs_tpu.tpu.sharded import ShardedTensorSearch
+            from dslabs_tpu.tpu.sharded import (SUPERSTEP_CHUNKS,
+                                                ShardedTensorSearch)
 
-            base_budget = int(
-                os.environ.get("DSLABS_SUPERSTEP_CHUNKS", "16") or "16")
             return ShardedTensorSearch(
                 self.protocol, self._mesh_for(width),
                 chunk_per_device=chunk,
-                superstep_chunks=(max(1, base_budget >> shrink)
-                                  if shrink else None),
+                superstep_chunks=max(1, SUPERSTEP_CHUNKS >> shrink),
                 frontier_cap=self.frontier_cap,
                 visited_cap=self.visited_cap, max_depth=self.max_depth,
                 max_secs=self.max_secs, strict=self.strict,
                 ev_budget=self.ev_budget,
-                row_exchange=self.row_exchange,
                 aot_warmup=self.aot_warmup, **ck)
         return TensorSearch(
             self.protocol, frontier_cap=self.frontier_cap,

@@ -149,13 +149,9 @@ DISPATCH_SITES = {
                                   program=False),
     "sharded.superstep":     dict(hot=True, donated=True, multi=True,
                                   program=True),
-    "sharded.step":          dict(hot=True, donated=True, multi=True,
-                                  program=True),
     "sharded.promote":       dict(hot=False, donated=True, multi=True,
                                   program=True),
     "sharded.init":          dict(hot=False, donated=False, multi=True,
-                                  program=True),
-    "sharded.sync":          dict(hot=False, donated=False, multi=False,
                                   program=True),
     "sharded.spill_drain":   dict(hot=False, donated=True, multi=True,
                                   program=True),
@@ -163,12 +159,6 @@ DISPATCH_SITES = {
                                   program=True),
     "sharded.spill_reinject": dict(hot=False, donated=True, multi=True,
                                    program=False),
-    # Boundary work stealing (ISSUE 18 leg (c)): one extra all_to_all
-    # at a level boundary moving packed frontier rows per a host-built
-    # donation plan — dispatched only when the skew gate trips (or at
-    # the depth-1 root fanout), never in the per-chunk hot loop.
-    "sharded.steal":         dict(hot=False, donated=True, multi=True,
-                                  program=True),
     "swarm.round":           dict(hot=True, donated=True, multi=True,
                                   program=True),
     "swarm.init":            dict(hot=False, donated=False, multi=True,
@@ -386,8 +376,7 @@ class MetricsRegistry:
 # ``phase``/``mark``/``annotate`` are only ever handed one of these, and
 # tests/test_program_spans.py holds every name a run emits to it.
 # PERF.md section 3 says which per-layer metric reads which.
-AOT_PROGRAMS = ("superstep", "chunk_step", "level_stats", "promote",
-                "init_carry")
+AOT_PROGRAMS = ("superstep", "promote", "init_carry")
 PHASES = (
     "entry.tensor_bfs", "entry.tensor_dfs",     # root of one lab call
     # one of each a ladder attempt, with ``cached`` = 1 where the lab
@@ -1007,21 +996,6 @@ class Telemetry:
                 self._status["spill"] = {k: v for k, v in rec.items()
                                          if k not in ("t", "ts")}
                 self._write_status()
-            elif kind == "steal":
-                # Boundary work-stealing (ISSUE 18c) fires AFTER the
-                # level feed, so the running skew aggregate picks the
-                # rebalance up here rather than from on_level.
-                agg = self._status.get("skew_agg") or {
-                    "imbalance_max": 1.0, "imbalance_mean": 0.0,
-                    "cv_max": 0.0, "levels": 0}
-                agg["steal_events"] = agg.get("steal_events", 0) + 1
-                agg["stolen_rows"] = (agg.get("stolen_rows", 0)
-                                      + int(fields.get("moved", 0)))
-                if fields.get("imbalance_after") is not None:
-                    agg["imbalance_post_steal"] = float(
-                        fields["imbalance_after"])
-                self._status["skew_agg"] = agg
-                self._write_status(force=True)
             else:
                 self._write_status()
 
@@ -2127,9 +2101,9 @@ def compare_ledger(records: List[dict],
     # payload row width on the mesh phase vs the BEST (smallest)
     # prior — a rise means the exchange fell back to raw rows (codec
     # disabled, identity descriptor) even when states/min holds.
-    # imbalance_max is the worst post-steal per-level frontier
-    # imbalance vs the BEST (lowest) prior — a rise means the stealing
-    # pass stopped levelling the shards.  Both rc-1 on regression.
+    # imbalance_max is the worst per-level per-device imbalance vs the
+    # BEST (lowest) prior — a rise means the owner hash stopped
+    # levelling the shards.  Both rc-1 on regression.
     cmp["mesh"] = {}
 
     def _wire(rec):

@@ -286,6 +286,25 @@ def wait_for_view(state, primary, backup, ticks=8):
     return v
 
 
+def wait_for_synced_pair(state, a, b, ticks):
+    """Poll until the view holds BOTH servers, in either role, and its
+    primary has acknowledged it — it pings a view's number only once its
+    backup holds its state.  A loaded host can cost either server its
+    place for a while; the pair comes back as the servers ping."""
+    def reached():
+        v = current_view(state)
+        return {v.primary, v.backup} == {a, b} and state.servers[VSA].acked
+
+    for _ in range(ticks):
+        if reached():
+            break
+        time.sleep(PING_CHECK_MILLIS / 1000)
+    assert reached(), (f"expected {a} and {b} in one acknowledged view, "
+                       f"got {current_view(state)}, acked "
+                       f"{state.servers[VSA].acked}")
+    return current_view(state)
+
+
 def setup_run_view(state, settings, primary, backup):
     """setupRunView (PrimaryBackupTest.java:249-264)."""
     state.start(settings)
@@ -396,19 +415,25 @@ def _concurrent_fail_to_backup(workload_factory, read_cmds, deliver_rate=None):
     for a in list(state.client_workers()):
         state.remove_node(a)
 
-    # Heal fully, then read the keys from the primary.
+    # Heal fully — wait, within the test's own time limit, until both
+    # servers share a synced view again — then read the keys from the
+    # primary.
+    limit = int(settings.max_time_secs * 1000 / PING_CHECK_MILLIS)
     settings.reset_network()
     state.start(settings)
-    time.sleep(PING_CHECK_MILLIS * 4 / 1000)
+    wait_for_synced_pair(state, server(1), server(2), limit)
     state.stop()
 
     state.add_client_worker(LocalAddress("client-readprimary"),
                             kv_workload(read_cmds))
     state.run(settings)
 
-    state.remove_node(server(1))
+    # Kill the primary of a synced pair (the read was a loaded stretch
+    # too); its backup takes over.
     state.start(settings)
-    wait_for_view(state, server(2), None)
+    view = wait_for_synced_pair(state, server(1), server(2), limit)
+    state.remove_node(view.primary)
+    wait_for_view(state, view.backup, None, ticks=limit)
     state.stop()
 
     state.add_client_worker(LocalAddress("client-readbackup"),

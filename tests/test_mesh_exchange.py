@@ -1,19 +1,20 @@
-"""Owner-sharded multi-chip superstep (ISSUE 12): the fused in-superstep
+"""Owner-sharded multi-chip superstep (ISSUE 12): the in-superstep
 row exchange, first-class carry placement, and the Pallas bucket-probe
 kernel.
 
-The fused exchange routes successor ROWS through the same owner-hashed
+The exchange routes successor ROWS through the same owner-hashed
 ``all_to_all`` as their fingerprints, so fresh states land on their
-owner's frontier shard as they are produced and the level promote
-shrinks to a local buffer swap (no reverse fresh-flag exchange, no
-boundary rebalance).  This suite is the acceptance matrix:
+owner's frontier shard as they are produced and the level promote is a
+local buffer swap.  This suite is the acceptance matrix:
 
-* exact unique/explored/verdict/depth parity between the fused-exchange
-  superstep and the legacy promote-boundary driver
-  (``DSLABS_SHARDED_SUPERSTEP=0`` / ``superstep=False``) at
-  n_devices in {1, 2, 4, 8} on pingpong + lab1;
+* exact unique/explored/verdict/depth parity between the sharded engine
+  and the host-dedup reference (``TensorSearch(use_host_visited=True)``,
+  ``run_host``) at n_devices in {1, 2, 4, 8} on pingpong + lab1, and
+  on pingpong against the object checker, which shares no code with
+  ``tpu/``;
 * per-level host dispatches stay within the PR 3 budget (<= 2/level)
-  and the fused promote program carries ZERO collectives;
+  and the promote program carries ZERO collectives;
+* per-device lanes at width 4: what ``imbalance_max.mesh4`` reads;
 * Pallas-vs-jnp visited-table parity — bit-exact tables, insert flags,
   and the unresolved/overflow contract — standalone and through a full
   sharded search (``DSLABS_VISITED_PALLAS=interpret``);
@@ -70,6 +71,16 @@ def _build(proto, n_devices, **kw):
     return ShardedTensorSearch(proto, make_mesh(n_devices), **kw)
 
 
+def _host_reference(proto, **kw):
+    """``run_host``: device expand, host sort-unique dedup — none of
+    the sharded engine's level loop, exchange or visited table."""
+    from dslabs_tpu.tpu.engine import TensorSearch
+
+    return TensorSearch(proto, chunk=16, frontier_cap=1 << 11,
+                        visited_cap=1 << 10, use_host_visited=True,
+                        **kw).run()
+
+
 def _assert_exact(a, b):
     assert a.end_condition == b.end_condition
     assert a.unique_states == b.unique_states
@@ -82,34 +93,43 @@ def _assert_exact(a, b):
 
 @pytest.mark.parametrize("n_devices", [1, 2, 4, 8])
 def test_width_parity_matrix_pingpong(n_devices):
-    """Acceptance: the fused-exchange superstep matches the legacy
-    promote-boundary driver EXACTLY at every mesh width."""
+    """Acceptance: the sharded engine matches the host-dedup reference
+    EXACTLY at every mesh width."""
     proto = _pruned_pingpong()
-    fused = _build(proto, n_devices, superstep=True,
-                   row_exchange=True).run()
-    legacy = _build(proto, n_devices, superstep=False).run()
-    assert fused.end_condition == "SPACE_EXHAUSTED"
-    _assert_exact(fused, legacy)
+    out = _build(proto, n_devices).run()
+    assert out.end_condition == "SPACE_EXHAUSTED"
+    _assert_exact(out, _host_reference(proto))
+
+
+@pytest.mark.parametrize("n_devices", [1, 2, 4, 8])
+def test_width_parity_matrix_pingpong_vs_object_checker(n_devices):
+    """The same matrix held to the INDEPENDENT reference: the object
+    checker (search/search.py BFS — no code shared with ``tpu/``)
+    exhausts the same pruned space with the same count."""
+    from dslabs_tpu.search.results import EndCondition
+    from tests.test_tpu_engine import object_search
+
+    obj = object_search(2, prune_done=True)
+    assert obj.end_condition == EndCondition.SPACE_EXHAUSTED
+    out = _build(_pruned_pingpong(), n_devices).run()
+    assert out.end_condition == "SPACE_EXHAUSTED"
+    assert out.unique_states == obj.discovered_count
+    assert out.dropped == out.visited_overflow == 0
 
 
 @pytest.mark.parametrize("n_devices", [1, 8])
 def test_width_parity_matrix_lab1(n_devices):
     proto = _pruned_lab1()
-    fused = _build(proto, n_devices, superstep=True,
-                   row_exchange=True).run()
-    legacy = _build(proto, n_devices, superstep=False).run()
-    assert fused.end_condition == "SPACE_EXHAUSTED"
-    _assert_exact(fused, legacy)
+    out = _build(proto, n_devices).run()
+    assert out.end_condition == "SPACE_EXHAUSTED"
+    _assert_exact(out, _host_reference(proto))
 
 
 @pytest.mark.slow
 @pytest.mark.parametrize("n_devices", [2, 4])
 def test_width_parity_matrix_lab1_mid_widths(n_devices):
     proto = _pruned_lab1()
-    fused = _build(proto, n_devices, superstep=True,
-                   row_exchange=True).run()
-    legacy = _build(proto, n_devices, superstep=False).run()
-    _assert_exact(fused, legacy)
+    _assert_exact(_build(proto, n_devices).run(), _host_reference(proto))
 
 
 @pytest.mark.slow
@@ -117,46 +137,40 @@ def test_width_parity_strict_vs_beam():
     """The exchange is verdict-preserving in BOTH capacity modes."""
     proto = _pruned_pingpong()
     for strict in (True, False):
-        fused = _build(proto, 8, superstep=True, row_exchange=True,
-                       strict=strict).run()
-        legacy = _build(proto, 8, superstep=False, strict=strict).run()
-        _assert_exact(fused, legacy)
+        _assert_exact(_build(proto, 8, strict=strict).run(),
+                      _host_reference(proto, strict=strict))
 
 
-def test_row_exchange_vs_legacy_exchange_superstep():
-    """Both superstep exchanges (fused rows vs promote-boundary) agree
-    — the DSLABS_SHARDED_EXCHANGE=0 escape hatch is a real oracle."""
-    proto = _pruned_pingpong()
-    fused = _build(proto, 8, superstep=True, row_exchange=True).run()
-    boundary = _build(proto, 8, superstep=True,
-                      row_exchange=False).run()
-    _assert_exact(fused, boundary)
-
-
-def test_row_exchange_knob_and_legacy_driver_forcing():
-    """The knob wiring: DSLABS_SHARDED_EXCHANGE gates the default, the
-    legacy per-chunk driver always keeps the promote-boundary
-    exchange (it IS the oracle)."""
-    proto = _pruned_pingpong()
-    assert _build(proto, 2).row_exchange is True        # default ON
-    assert _build(proto, 2, superstep=False).row_exchange is False
-    os.environ["DSLABS_SHARDED_EXCHANGE"] = "0"
-    try:
-        assert _build(proto, 2).row_exchange is False
-    finally:
-        del os.environ["DSLABS_SHARDED_EXCHANGE"]
-    assert _build(proto, 2, row_exchange=True).row_exchange is True
+def test_per_device_lanes_width_4_lab1():
+    """What ``imbalance_max.mesh4`` reads: at width 4 every level
+    record's ``per_device.explored`` has one entry a device, they sum to
+    the level's explored, and at the widest level the owner hash gave
+    every device work."""
+    cs = make_clientserver_protocol(n_clients=2, w=3)   # lab1-entry's
+    out = _build(dataclasses.replace(
+        cs, goals={}, prunes={"CLIENTS_DONE": cs.goals["CLIENTS_DONE"]}),
+        4, frontier_cap=1 << 9, visited_cap=1 << 12).run()
+    assert (out.end_condition, out.unique_states) == (
+        "SPACE_EXHAUSTED", 255)
+    before = 0
+    for rec in out.levels:
+        lanes = rec["per_device"]["explored"]
+        assert len(lanes) == 4
+        assert sum(lanes) == rec["explored"] - before, rec
+        before = rec["explored"]
+    widest = max(out.levels, key=lambda r: sum(r["per_device"]["explored"]))
+    assert all(e > 0 for e in widest["per_device"]["explored"]), widest
 
 
 # ---------------------------------------------- dispatch budget + promote
 
 def test_fused_exchange_dispatch_budget():
-    """The dispatch-counter pin (PR 3 budget): the fused-exchange level
-    spends <= 2 host dispatches (superstep + thin promote), and the
-    promote program moves ZERO rows over ICI — its lowering contains
-    no collective at width 8."""
+    """The dispatch-counter pin (PR 3 budget): a level spends <= 2 host
+    dispatches (superstep + thin promote), and the promote program
+    moves ZERO rows over ICI — its lowering contains no collective at
+    width 8."""
     proto = _pruned_pingpong()
-    search = _build(proto, 8, superstep=True, row_exchange=True)
+    search = _build(proto, 8)
     counts = {}
 
     def hook(tag, fn, *args):
@@ -166,18 +180,12 @@ def test_fused_exchange_dispatch_budget():
     search._dispatch_hook = hook
     out = search.run()
     assert out.depth >= 3
-    assert counts.get("sharded.step", 0) == 0
-    assert counts.get("sharded.sync", 0) == 0
     assert (counts["sharded.superstep"] + counts["sharded.promote"]
             <= 2 * out.depth)
 
     text = search._finish_level.lower(search._carry_sds()).as_text()
     assert not any(c in text for c in _COLLECTIVES), (
-        "fused-exchange promote must be a local buffer swap")
-    # ... while the legacy promote at the same width IS the rebalance.
-    legacy = _build(proto, 8, superstep=True, row_exchange=False)
-    text = legacy._finish_level.lower(legacy._carry_sds()).as_text()
-    assert any(c in text for c in _COLLECTIVES)
+        "the promote must be a local buffer swap")
 
 
 # --------------------------------------------------- carry placement (b)
@@ -301,9 +309,9 @@ def test_pallas_engine_parity(monkeypatch):
     the Pallas interpreter matches the jnp-path run exactly — the
     CapacityOverflow/visited_overflow contract is unchanged."""
     proto = _pruned_pingpong()
-    base = _build(proto, 2, superstep=True, row_exchange=True).run()
+    base = _build(proto, 2).run()
     monkeypatch.setenv("DSLABS_VISITED_PALLAS", "interpret")
-    out = _build(proto, 2, superstep=True, row_exchange=True).run()
+    out = _build(proto, 2).run()
     _assert_exact(out, base)
 
 
@@ -333,17 +341,16 @@ def test_cross_width_resume_8_4_2_1(tmp_path):
     every narrower width (owner re-hash at the new D) — the elastic
     ladder's resume contract holds on the new exchange path."""
     proto = _pruned_pingpong()
-    oracle = _build(proto, 8, row_exchange=True).run()
+    oracle = _build(proto, 8).run()
     assert oracle.end_condition == "SPACE_EXHAUSTED"
 
     path = str(tmp_path / "mesh.ckpt")
-    out = _build(proto, 8, row_exchange=True, checkpoint_path=path,
+    out = _build(proto, 8, checkpoint_path=path,
                  checkpoint_every=1, max_depth=2).run()
     assert out.end_condition == "DEPTH_EXHAUSTED"
     for width, depth in ((4, 3), (2, 4), (1, None)):
-        search = _build(proto, width, row_exchange=True,
-                        checkpoint_path=path, checkpoint_every=1,
-                        max_depth=depth)
+        search = _build(proto, width, checkpoint_path=path,
+                        checkpoint_every=1, max_depth=depth)
         out = search.run(resume=True)
     assert out.end_condition == oracle.end_condition
     assert out.unique_states == oracle.unique_states
@@ -364,7 +371,7 @@ def test_fused_exchange_transient_retry():
     def sup(**kw):
         return SearchSupervisor(
             proto, mesh=make_mesh(8), chunk=16, frontier_cap=1 << 8,
-            visited_cap=1 << 10, row_exchange=True, **kw)
+            visited_cap=1 << 10, **kw)
 
     base = sup().run()
     assert base.end_condition == "SPACE_EXHAUSTED"

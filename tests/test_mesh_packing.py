@@ -1,37 +1,30 @@
-"""Packed-wire mesh exchange + skew-balanced owner shards (ISSUE 18):
-the sharded carry moves PACKED rows through the fused owner-hashed
-``all_to_all`` and levels what it moves —
+"""Packed-wire mesh exchange (ISSUE 18): the sharded carry moves PACKED
+rows through the owner-hashed ``all_to_all`` —
 
 * the wire descriptor cuts bytes-per-state >= 8x on the generated lab1
   and paxos specs (13.7x / 13.5x measured — asserted from the
   descriptor the engine actually installs);
-* packed-vs-raw exchange (``mesh_pack=False`` = the parity oracle
-  behind DSLABS_MESH_PACK) is BIT-IDENTICAL
+* packed-vs-raw exchange (``mesh_pack=False`` = the codec's
+  reference) is BIT-IDENTICAL
   (unique/explored/verdict/depth/dropped) across widths {1, 2, 4, 8},
   strict and beam, and across a cross-width resume chain 8 -> 4 -> 2
   -> 1 through the packed checkpoint format;
 * delta-from-level-base lanes (``Field(delta=)``, the varint lane for
   view-number-style unbounded fields) pack the pb spec and stay exact;
-* root-fanout seeding + chunk-granular boundary stealing strictly
-  improve the skewed fixture's frontier imbalance at width 8 with
-  exact count parity (visited shards never move, so dedup ownership —
-  and therefore every count — is untouched by construction AND by
-  assertion);
 * the spill spool rides the packed encoding: 1/8-capacity strict runs
   keep exact parity with the full-table oracle;
-* the fused promote still lowers with ZERO collectives under packing
+* the promote still lowers with ZERO collectives under packing
   (raw-lane repack at the boundary is elementwise);
-* pack/decode/steal are first-class dispatch sites (DISPATCH_SITES +
+* pack/decode are first-class dispatch sites (DISPATCH_SITES +
   ``dispatch_site_programs()``) and their jaxprs audit clean;
-* a mesh job that runs UNPACKED (hand twin -> identity codec, or the
-  parity-oracle knob) is loud: a ``mesh_unpacked`` telemetry event,
+* a mesh job that runs UNPACKED (hand twin -> identity codec, or
+  ``mesh_pack=False``) is loud: a ``mesh_unpacked`` telemetry event,
   never silence.
 
 Marked ``mesh`` (``make mesh-smoke`` runs this suite too).
 """
 
 import dataclasses
-import os
 
 import numpy as np
 import pytest
@@ -73,7 +66,6 @@ def _build(proto, n_devices, **kw):
     kw.setdefault("chunk_per_device", 16)
     kw.setdefault("frontier_cap", 1 << 8)
     kw.setdefault("visited_cap", 1 << 10)
-    kw.setdefault("row_exchange", True)
     return ShardedTensorSearch(proto, make_mesh(n_devices), **kw)
 
 
@@ -104,7 +96,7 @@ def test_wire_bytes_per_state_floor(spec_fn, floor):
 
 
 def test_engine_installs_packed_wire_by_default():
-    """DSLABS_MESH_PACK defaults ON: a generated spec gets the
+    """``mesh_pack`` defaults ON: a generated spec gets the
     non-identity codec, the carry plane shrinks to the packed word
     count, and the verdict stamps the ratio (satellite: pack_ratio on
     SearchOutcome + levels)."""
@@ -195,75 +187,6 @@ def test_cross_width_resume_packed_8_4_2_1(tmp_path):
     assert out.depth == oracle.depth
 
 
-# ------------------------------------------------------- work stealing
-
-def test_steal_plan_conserves_rows():
-    """Host planner unit contract: donations conserve rows, never
-    exceed one chunk per (donor, receiver) pair, only move whole
-    chunks past depth 1, and respect the threshold gate."""
-    search = _build(_pingpong(), 8, steal_threshold=1.25)
-    D, K = search.n_devices, search.cpd
-
-    occ = [800] + [0] * (D - 1)          # the skewed fixture
-    plan = search._steal_plan(occ, depth=5)
-    assert plan is not None and plan.shape == (D, D)
-    assert plan.max() <= K
-    assert (plan.sum(axis=1) <= np.asarray(occ)).all()
-    after = [int(o - plan[d].sum() + plan[:, d].sum())
-             for d, o in enumerate(occ)]
-    assert sum(after) == sum(occ)        # conservation
-    mean = sum(occ) / D
-    assert max(after) / mean < max(occ) / mean   # strictly better
-    assert (plan[plan > 0] % K == 0).all()       # whole chunks only
-
-    # Depth 1 = root fanout: unconditional and unrounded.
-    plan1 = search._steal_plan([5] + [0] * (D - 1), depth=1)
-    assert plan1 is not None and plan1.sum() > 0
-
-    # Balanced frontier under the threshold: no plan, no dispatch.
-    assert search._steal_plan([100] * D, depth=5) is None
-    assert _build(_pingpong(), 1,
-                  steal_threshold=1.25)._steal_plan([100], 5) is None
-
-
-def test_steal_parity_and_imbalance_improves():
-    """ACCEPTANCE: on the skewed fixture (a lone root hashes to ONE
-    owner, so level 1 starts at imbalance D) stealing at width 8
-    strictly improves imbalance_max with exact count parity."""
-    proto = _pruned(clientserver_spec(3, 4).compile())
-    kw = dict(chunk_per_device=4, frontier_cap=1 << 9,
-              visited_cap=1 << 13, max_depth=8)
-    base = _build(proto, 8, **kw).run()
-    search = _build(proto, 8, steal_threshold=1.05, **kw)
-    assert search._steal_on
-    out = search.run()
-    _assert_exact(base, out)             # counts bit-identical
-    steals = [lv["steal"] for lv in (out.levels or [])
-              if lv.get("steal")]
-    assert steals, "the skewed fixture must trigger at least one steal"
-    for s in steals:
-        assert s["moved"] > 0
-        assert s["imbalance_after"] <= s["imbalance_before"]
-    # The worst post-steal frontier imbalance strictly beats the worst
-    # pre-steal one — the number bench --mesh reports and the ledger
-    # guards (mesh:imbalance_max).
-    assert (max(s["imbalance_after"] for s in steals)
-            < max(s["imbalance_before"] for s in steals))
-    post = [lv["skew"]["frontier_post_steal"] for lv in out.levels
-            if lv.get("skew", {}).get("frontier_post_steal")]
-    assert post and all("imbalance" in m for m in post)
-
-
-def test_steal_off_by_default():
-    """DSLABS_MESH_STEAL_THRESHOLD unset = no stealing: the knob is
-    opt-in (bench --mesh opts in; parity oracles stay untouched)."""
-    assert "DSLABS_MESH_STEAL_THRESHOLD" not in os.environ
-    search = _build(_pingpong(), 8)
-    assert not search._steal_on
-    out = search.run()
-    assert not any(lv.get("steal") for lv in (out.levels or []))
-
-
 # ------------------------------------------------------- spill + promote
 
 def test_packed_spill_parity_eighth_capacity():
@@ -300,29 +223,26 @@ def test_fused_promote_zero_collectives_under_packing(spec_fn):
 
 # ------------------------------------------------------- observability
 
-def test_dispatch_sites_cover_pack_decode_steal():
-    """CI satellite: pack/decode/steal are canonical dispatch sites —
+def test_dispatch_sites_cover_pack_decode():
+    """CI satellite: pack/decode are canonical dispatch sites —
     registered in DISPATCH_SITES, emitted by the sharded engine's
     dispatch_site_programs(), and their jaxprs audit clean (J1-J5)."""
     from dslabs_tpu.analysis.jaxpr_audit import audit_sites
     from dslabs_tpu.tpu.telemetry import DISPATCH_SITES
 
-    for site in ("packing.pack", "packing.unpack", "sharded.steal"):
+    for site in ("packing.pack", "packing.unpack"):
         assert site in DISPATCH_SITES
-    assert DISPATCH_SITES["sharded.steal"]["program"]
-    search = _build(_pingpong(), 2, steal_threshold=1.25)
+    search = _build(_pingpong(), 2)
     sites = search.dispatch_site_programs()
     picked = {k: v for k, v in sites.items()
-              if k in ("packing.pack", "packing.unpack",
-                       "sharded.steal")}
-    assert set(picked) == {"packing.pack", "packing.unpack",
-                           "sharded.steal"}
+              if k in ("packing.pack", "packing.unpack")}
+    assert set(picked) == {"packing.pack", "packing.unpack"}
     assert audit_sites(picked, "ShardedTensorSearch") == []
 
 
 def test_mesh_unpacked_event_is_loud():
     """Satellite: a mesh job shipping RAW lanes is loud — the hand
-    twin (identity codec) and the parity-oracle knob both emit a
+    twin (identity codec) and ``mesh_pack=False`` both emit a
     ``mesh_unpacked`` event; the packed default emits none."""
     def run(proto, **kw):
         tel = Telemetry()
@@ -351,7 +271,7 @@ def test_status_skew_agg_block_and_watch(tmp_path, capsys):
     ck = str(tmp_path / "search.ckpt")
     tel = Telemetry.for_checkpoint(ck)
     search = _build(_pruned(clientserver_spec(3, 4).compile()), 8,
-                    steal_threshold=1.05, chunk_per_device=4,
+                    chunk_per_device=4,
                     frontier_cap=1 << 9, visited_cap=1 << 13,
                     max_depth=6, telemetry=tel)
     search.run()
@@ -364,7 +284,6 @@ def test_status_skew_agg_block_and_watch(tmp_path, capsys):
         assert key in agg
     assert agg["levels"] > 0
     assert agg["imbalance_max"] >= agg["imbalance_mean"] > 0
-    assert agg["stolen_rows"] > 0        # the steal rode the feed
 
     assert tel_mod.main(["watch", str(tmp_path), "--once"]) == 0
     text = capsys.readouterr().out
@@ -375,8 +294,8 @@ def test_status_skew_agg_block_and_watch(tmp_path, capsys):
 def test_compare_ledger_guards_mesh_wire_and_imbalance():
     """Bench satellite: the ledger guards the two numbers this PR
     exists to hold — wire bytes-per-state rising (codec fell back to
-    raw) or post-steal imbalance_max rising (stealing stopped
-    levelling) past the threshold is an rc-1 regression."""
+    raw) or imbalance_max rising (the owner hash stopped levelling)
+    past the threshold is an rc-1 regression."""
     from dslabs_tpu.tpu.telemetry import compare_ledger
 
     def rec(wire_bps, imb):

@@ -256,7 +256,7 @@ def test_mesh_wire_packed_parity_generated_join():
         return ShardedTensorSearch(
             proto, make_mesh(2), chunk_per_device=16,
             frontier_cap=1 << 8, visited_cap=1 << 10,
-            row_exchange=True, mesh_pack=mesh_pack).run()
+            mesh_pack=mesh_pack).run()
 
     on, off = run(True), run(False)
     assert on.end_condition == off.end_condition

@@ -1,14 +1,15 @@
 """On-device level supersteps + persistent compile cache (ISSUE 3).
 
-The fused superstep (sharded.py ``_level_superstep``: one shard_map
-program whose ``lax.while_loop`` drains every device's own frontier
-shard) must match the legacy host-driven per-chunk driver
-(``DSLABS_SHARDED_SUPERSTEP=0``, the parity oracle) EXACTLY — end
-verdict, unique, explored, depth — while cutting host dispatches per
-level from ``n_chunks + 1`` to at most 2 (superstep + promote; the
-dispatch-counter tests assert it).  Mid-level time budgets keep their
-contract under both drivers: TIME_EXHAUSTED never masks a violation
-found in chunks already completed.  The persistent compile cache
+The superstep (sharded.py ``_level_superstep``: one shard_map program
+whose ``lax.while_loop`` drains every device's own frontier shard) must
+match the host-dedup reference (``TensorSearch(use_host_visited=True)``,
+``run_host``: it shares the expand with the sharded engine and nothing
+of the level loop, the exchange or the visited table) EXACTLY — end
+verdict, unique, explored, depth — at no more than 2 host dispatches a
+level (superstep + promote; the dispatch-counter tests assert it), on
+eight devices and on one.  Mid-level time budgets keep their contract
+at both widths: TIME_EXHAUSTED never masks a violation found in chunks
+already completed.  The persistent compile cache
 (tpu/compile_cache.py) plus AOT warm-up makes a
 second identical construction's compile near-zero.
 
@@ -41,67 +42,69 @@ def _pruned_pingpong():
 
 
 def _run_pair(proto, max_depth=None, **kw):
-    """The same config under the fused superstep and the legacy
-    per-chunk driver; returns (superstep_outcome, legacy_outcome)."""
-    mesh = make_mesh(8)
+    """The same config on the 8-device sharded engine and on the
+    host-dedup reference; returns (sharded_outcome, host_outcome)."""
     kw.setdefault("chunk_per_device", 16)
     kw.setdefault("frontier_cap", 1 << 8)
     kw.setdefault("visited_cap", 1 << 10)
-    fused = ShardedTensorSearch(proto, mesh, max_depth=max_depth,
-                                superstep=True, **kw).run()
-    legacy = ShardedTensorSearch(proto, mesh, max_depth=max_depth,
-                                 superstep=False, **kw).run()
-    return fused, legacy
+    sharded = ShardedTensorSearch(proto, make_mesh(8),
+                                  max_depth=max_depth, **kw).run()
+    host = TensorSearch(
+        proto, chunk=kw["chunk_per_device"], max_depth=max_depth,
+        frontier_cap=8 * kw["frontier_cap"],
+        visited_cap=kw["visited_cap"], strict=kw.get("strict", True),
+        use_host_visited=True).run()
+    return sharded, host
 
 
-def _assert_exact(fused, legacy):
-    assert fused.end_condition == legacy.end_condition
-    assert fused.unique_states == legacy.unique_states
-    assert fused.states_explored == legacy.states_explored
-    assert fused.depth == legacy.depth
-    assert fused.dropped == legacy.dropped
+def _assert_exact(a, b):
+    assert a.end_condition == b.end_condition
+    assert a.unique_states == b.unique_states
+    assert a.states_explored == b.states_explored
+    assert a.depth == b.depth
+    assert a.dropped == b.dropped
 
 
 # ------------------------------------------------------------- parity
 
 @pytest.mark.perf
 @pytest.mark.parametrize("strict", [True, False])
-def test_superstep_vs_legacy_parity_pingpong(strict):
-    fused, legacy = _run_pair(_pruned_pingpong(), strict=strict)
-    assert fused.end_condition == "SPACE_EXHAUSTED"
-    _assert_exact(fused, legacy)
+def test_superstep_vs_host_reference_parity_pingpong(strict):
+    sharded, host = _run_pair(_pruned_pingpong(), strict=strict)
+    assert sharded.end_condition == "SPACE_EXHAUSTED"
+    _assert_exact(sharded, host)
 
 
 @pytest.mark.perf
 @pytest.mark.slow
-def test_superstep_vs_legacy_parity_paxos_d5():
+def test_superstep_vs_host_reference_parity_paxos_d5():
     """The dry-run 8-device paxos rung of the perf-smoke parity gate
     (acceptance: exact verdict/unique/explored match at depth 5)."""
     from dslabs_tpu.tpu.specs_lab3 import make_paxos_protocol
 
     proto = make_paxos_protocol(n=3, n_clients=1, w=1, max_slots=2,
                                 net_cap=16, timer_cap=4)
-    fused, legacy = _run_pair(proto, max_depth=5, chunk_per_device=64,
+    sharded, host = _run_pair(proto, max_depth=5, chunk_per_device=64,
                               frontier_cap=1 << 12,
                               visited_cap=1 << 15)
-    assert fused.end_condition == "DEPTH_EXHAUSTED"
-    _assert_exact(fused, legacy)
+    assert sharded.end_condition == "DEPTH_EXHAUSTED"
+    _assert_exact(sharded, host)
 
 
 @pytest.mark.perf
 @pytest.mark.slow
-def test_superstep_vs_legacy_parity_shardstore_d4():
+def test_superstep_vs_host_reference_parity_shardstore_d4():
     """Second protocol family (lab 4 shardstore lane layout) through
     the same superstep machinery."""
     from dslabs_tpu.tpu.specs_lab4 import \
         make_shardstore_protocol
 
     proto = make_shardstore_protocol([[1], [2]])
-    fused, legacy = _run_pair(proto, max_depth=4, chunk_per_device=64,
+    sharded, host = _run_pair(proto, max_depth=4, chunk_per_device=64,
                               frontier_cap=1 << 12,
                               visited_cap=1 << 15)
-    assert fused.end_condition == "DEPTH_EXHAUSTED"
-    _assert_exact(fused, legacy)
+    assert sharded.end_condition == "DEPTH_EXHAUSTED"
+    _assert_exact(sharded, host)
 
 
 def test_superstep_ev_spill_parity():
@@ -112,22 +115,21 @@ def test_superstep_ev_spill_parity():
     mesh = make_mesh(8)
     full = ShardedTensorSearch(
         proto, mesh, chunk_per_device=16, frontier_cap=1 << 8,
-        visited_cap=1 << 10, superstep=True).run()
+        visited_cap=1 << 10).run()
     tiny = ShardedTensorSearch(
         proto, mesh, chunk_per_device=16, frontier_cap=1 << 8,
-        visited_cap=1 << 10, superstep=True, ev_budget=(2, 1),
+        visited_cap=1 << 10, ev_budget=(2, 1),
         ev_spill=True).run()
     _assert_exact(tiny, full)
 
 
 # ---------------------------------------------------- dispatch counting
 
-def _counted_run(proto, superstep, **kw):
-    mesh = make_mesh(8)
+def _counted_run(proto, n_devices, **kw):
     kw.setdefault("chunk_per_device", 16)
     kw.setdefault("frontier_cap", 1 << 8)
     kw.setdefault("visited_cap", 1 << 10)
-    search = ShardedTensorSearch(proto, mesh, superstep=superstep, **kw)
+    search = ShardedTensorSearch(proto, make_mesh(n_devices), **kw)
     counts = {}
 
     def hook(tag, fn, *args):
@@ -138,64 +140,48 @@ def _counted_run(proto, superstep, **kw):
     return search.run(), counts
 
 
-def test_superstep_host_dispatches_per_level_at_most_two():
-    """The acceptance bound: the superstep driver spends <= 2 host
-    dispatches per level (superstep + promote; the stats vector rides
-    inside the superstep program) vs the legacy driver's
-    n_chunks + sync (+ promote)."""
-    proto = _pruned_pingpong()
-    out, counts = _counted_run(proto, superstep=True)
+@pytest.mark.parametrize("n_devices", [8, 1])
+def test_superstep_host_dispatches_per_level_at_most_two(n_devices):
+    """The acceptance bound: a level costs <= 2 host dispatches
+    (superstep + promote; the stats vector rides inside the superstep
+    program), and the carry initialiser is the only other dispatch of a
+    run — on the mesh and on the one device a chip run has."""
+    out, counts = _counted_run(_pruned_pingpong(), n_devices)
     levels = out.depth
     assert levels >= 3
-    assert counts.get("sharded.step", 0) == 0
-    assert counts.get("sharded.sync", 0) == 0
+    assert set(counts) == {"sharded.init", "sharded.superstep",
+                           "sharded.promote"}
+    assert counts["sharded.init"] == 1
     assert counts["sharded.superstep"] + counts["sharded.promote"] <= (
         2 * levels)
 
-    legacy_out, legacy_counts = _counted_run(proto, superstep=False)
-    _assert_exact(out, legacy_out)
-    # The legacy driver pays at least one chunk step AND one sync per
-    # level on top of the promote — strictly more host dispatches.
-    assert legacy_counts["sharded.step"] >= levels
-    assert legacy_counts["sharded.sync"] >= levels
-    legacy_total = sum(v for k, v in legacy_counts.items())
-    fused_total = sum(v for k, v in counts.items())
-    assert fused_total < legacy_total
 
+def test_retired_options_are_gone():
+    """One design: the per-chunk driver, the promote-boundary exchange
+    and work stealing left no constructor option behind (an unknown
+    keyword is a TypeError, not a silently ignored knob) and no
+    environment name in the package."""
+    import inspect
+    import pathlib
 
-def test_single_device_mesh_skips_chunk_grid_widening():
-    """Satellite: on a 1-device mesh the level rebalance is an identity,
-    so the legacy chunk grid must NOT be widened by the
-    ``max_n + D - 1`` slack (no extra mostly-invalid chunk)."""
-    proto = _pruned_pingpong()
-    mesh = make_mesh(1)
-    search = ShardedTensorSearch(
-        proto, mesh, chunk_per_device=16, frontier_cap=1 << 8,
-        visited_cap=1 << 10, superstep=False)
-    assert search._rebalance_slack() == 0
-    counts = {}
+    from dslabs_tpu.tpu.supervisor import SearchSupervisor
 
-    def hook(tag, fn, *args):
-        counts[tag] = counts.get(tag, 0) + 1
-        return fn(*args)
-
-    search._dispatch_hook = hook
-    out = search.run()
-    assert out.end_condition == "SPACE_EXHAUSTED"
-    # Frontiers here never exceed one chunk: exactly one chunk step per
-    # level — the pre-fix driver dispatched two whenever
-    # max_n % chunk == 0 (the widening added a full invalid chunk).
-    assert counts["sharded.step"] == out.depth
-    mesh8 = make_mesh(8)
-    # The legacy promote-boundary exchange needs the ceil-split slack
-    # on a wide mesh; the fused row exchange (ISSUE 12 default) has no
-    # rebalance at all, so no slack either.
-    assert ShardedTensorSearch(
-        proto, mesh8, chunk_per_device=16, frontier_cap=1 << 8,
-        visited_cap=1 << 10, superstep=False)._rebalance_slack() == 7
-    assert ShardedTensorSearch(
-        proto, mesh8, chunk_per_device=16, frontier_cap=1 << 8,
-        visited_cap=1 << 10, row_exchange=True)._rebalance_slack() == 0
+    proto, mesh = _pruned_pingpong(), make_mesh(2)
+    params = inspect.signature(ShardedTensorSearch.__init__).parameters
+    for kw in ({"superstep": True}, {"row_exchange": True},
+               {"steal_threshold": 1.5}):
+        assert not set(kw) & set(params)
+        with pytest.raises(TypeError):
+            ShardedTensorSearch(proto, mesh, **kw)
+    with pytest.raises(TypeError):
+        SearchSupervisor(proto, mesh=mesh, row_exchange=True)
+    retired = ["DSLABS_" + n for n in (
+        "SHARDED_SUPERSTEP", "SHARDED_EXCHANGE", "MESH_STEAL_THRESHOLD",
+        "SUPERSTEP_CHUNKS", "MESH_PACK")]
+    pkg = pathlib.Path(sharded_mod.__file__).parents[1]
+    named = [(str(f.relative_to(pkg)), n) for f in pkg.rglob("*.py")
+             for n in retired if n in f.read_text()]
+    assert named == []
 
 
 # ------------------------------------------------------- level records
@@ -251,13 +237,12 @@ def _violating_clientserver():
         p, goals={}, invariants={"NEVER_DONE": lambda s, f=done: ~f(s)})
 
 
-def _clocked_run(proto, superstep, max_secs, clock, **kw):
-    mesh = make_mesh(8)
+def _clocked_run(proto, n_devices, max_secs, clock, **kw):
     kw.setdefault("chunk_per_device", 32)
     kw.setdefault("frontier_cap", 1 << 9)
     kw.setdefault("visited_cap", 1 << 12)
-    search = ShardedTensorSearch(proto, mesh, max_secs=max_secs,
-                                 superstep=superstep, **kw)
+    search = ShardedTensorSearch(proto, make_mesh(n_devices),
+                                 max_secs=max_secs, **kw)
 
     def hook(tag, fn, *args):
         clock.dispatches += 1
@@ -267,36 +252,35 @@ def _clocked_run(proto, superstep, max_secs, clock, **kw):
     return search.run()
 
 
-@pytest.mark.parametrize("superstep", [True, False],
-                         ids=["superstep", "legacy"])
-def test_time_budget_returns_time_exhausted_mid_run(superstep,
+@pytest.mark.parametrize("n_devices", [8, 1])
+def test_time_budget_returns_time_exhausted_mid_run(n_devices,
                                                     monkeypatch):
     """Satellite: a tiny max_secs returns TIME_EXHAUSTED (with the
-    partial counts, never a crash) under BOTH drivers.  The fake clock
-    charges one 'second' per dispatch, so the budget expires after the
-    first level's work — deterministically."""
+    partial counts, never a crash) on the mesh and on one device (what
+    a time-boxed chip run is).  The fake clock charges one 'second' per
+    dispatch, so the budget expires after the first level's work —
+    deterministically."""
     proto = _pruned_pingpong()
-    full = _clocked_run(proto, superstep, None, _DispatchClock(0.0))
+    full = _clocked_run(proto, n_devices, None, _DispatchClock(0.0))
     assert full.end_condition == "SPACE_EXHAUSTED"
 
     clock = _DispatchClock(1.0)
     monkeypatch.setattr(sharded_mod, "time", clock)
-    out = _clocked_run(proto, superstep, 3.5, clock)
+    out = _clocked_run(proto, n_devices, 3.5, clock)
     assert out.end_condition == "TIME_EXHAUSTED"
     assert 0 < out.states_explored < full.states_explored
     assert out.unique_states >= 1
 
 
-@pytest.mark.parametrize("superstep", [True, False],
-                         ids=["superstep", "legacy"])
+@pytest.mark.parametrize("n_devices", [8, 1])
 def test_time_budget_never_masks_violation_in_completed_chunks(
-        superstep, monkeypatch):
+        n_devices, monkeypatch):
     """Satellite: a violation found in chunks already completed must be
     reported even when the wall budget is ALREADY exhausted at the
     sync — the checks run before any TIME_EXHAUSTED return.  The fake
     clock makes the budget expire during the violation's own level."""
     proto = _violating_clientserver()
-    base = _clocked_run(proto, superstep, None, _DispatchClock(0.0))
+    base = _clocked_run(proto, n_devices, None, _DispatchClock(0.0))
     assert base.end_condition == "INVARIANT_VIOLATED"
 
     # The run takes `total` dispatches, the last being the one whose
@@ -305,30 +289,15 @@ def test_time_budget_never_masks_violation_in_completed_chunks(
     # total - 1) but is exhausted at its sync (elapsed == total) — the
     # violation must still win.
     counting = _DispatchClock(0.0)
-    total = _count_dispatches(proto, superstep, counting)
+    _clocked_run(proto, n_devices, None, counting)
+    total = counting.dispatches
     clock = _DispatchClock(1.0)
     monkeypatch.setattr(sharded_mod, "time", clock)
-    out = _clocked_run(proto, superstep, total - 0.5, clock)
+    out = _clocked_run(proto, n_devices, total - 0.5, clock)
     assert out.end_condition == "INVARIANT_VIOLATED", (
         "TIME_EXHAUSTED masked a violation found in completed chunks")
     assert out.predicate_name == base.predicate_name
     assert out.depth == base.depth
-
-
-def _count_dispatches(proto, superstep, clock):
-    mesh = make_mesh(8)
-    search = ShardedTensorSearch(proto, mesh, chunk_per_device=32,
-                                 frontier_cap=1 << 9,
-                                 visited_cap=1 << 12,
-                                 superstep=superstep)
-
-    def hook(tag, fn, *args):
-        clock.dispatches += 1
-        return fn(*args)
-
-    search._dispatch_hook = hook
-    search.run()
-    return clock.dispatches
 
 
 # ------------------------------------------- compile cache + AOT warm-up
