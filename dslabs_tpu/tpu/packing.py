@@ -45,6 +45,17 @@ Descriptor model (``LanePacking``):
 * lanes are laid out first-fit in declaration order and never straddle
   a word boundary, so pack/unpack are shift+mask on one word each.
 
+The device codec assembles all words at once (ISSUE 32): ``pack_jnp``
+contracts the shifted lanes over the lane axis with the constant 0/1
+``[lanes, words]`` matrix, one byte plane at a time, so no per-word
+``[N]`` / ``[N, 1]`` column is ever an operand (the TPU's (8, 128) tile
+pads such a column 128x).  Exact, because the fields of a word are
+bit-disjoint: a word's byte plane is a sum of disjoint bits, <= 255;
+each addend (0..255) is exact in bf16 and the 0/1 weights leave it so;
+the f32 accumulator holds every integer below 2^24.  ``pack_np`` /
+``unpack_np`` are the host ORACLE the device bits are held to
+(tests/test_packing.py, a case per descriptor).
+
 A protocol with no declared domains derives the **identity** descriptor
 (``words == lanes``, pack/unpack return their input), which is how the
 packed path ships ON by default without touching the hand twins'
@@ -217,7 +228,13 @@ class LanePacking:
         row's values OUTSIDE their declared domain (callers mask to
         live rows and raise loudly — a wrong bound must never silently
         corrupt a stored state).  ``base`` is the [lanes] int32 bias
-        vector, required iff the descriptor has delta lanes."""
+        vector, required iff the descriptor has delta lanes.
+
+        Bit-identical to :meth:`pack_np`, the oracle: a word's fields
+        are bit-disjoint, so each of its four byte planes is a sum of
+        disjoint bits <= 255 — exact as bf16 addends times a 0/1 matrix
+        accumulated in f32 — and the planes are OR'd back in place."""
+        import jax
         import jax.numpy as jnp
 
         self._require_base(base)
@@ -236,12 +253,19 @@ class LanePacking:
         enc = jnp.where(raw[None, :], rows.astype(jnp.uint32), enc)
         enc = jnp.where((sent & ~raw)[None, :] & is_sent, mask[None, :],
                         enc)
-        shifted = enc << shift[None, :]
-        cols = []
-        for _w, s, e in self._word_ranges():
-            cols.append(jnp.sum(shifted[:, s:e].astype(jnp.uint32),
-                                axis=1, dtype=jnp.uint32))
-        packed = jnp.stack(cols, axis=1).astype(jnp.int32)
+        shifted = (enc << shift[None, :]).astype(jnp.int32)
+        # lanes -> words as ONE dense contraction per byte plane (see
+        # the module docstring): no per-word column is ever an operand.
+        onto = jnp.asarray(
+            self.word[:, None] == np.arange(self.words)[None, :],
+            jnp.bfloat16)                              # [lanes, words]
+        packed = jnp.zeros((rows.shape[0], self.words), jnp.int32)
+        for b in range(0, RAW_WIDTH, 8):
+            plane = ((shifted >> b) & 0xFF).astype(jnp.bfloat16)
+            byte = jax.lax.dot_general(
+                plane, onto, (((1,), (0,)), ((), ())),
+                preferred_element_type=jnp.float32)
+            packed = packed | (byte.astype(jnp.int32) << b)
         if not count_bad:
             return packed
         # Out-of-domain detection on bounded lanes: value not SENTINEL

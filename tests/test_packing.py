@@ -26,6 +26,7 @@ import signal
 import subprocess
 import sys
 import time
+import zlib
 
 import numpy as np
 import pytest
@@ -36,8 +37,9 @@ from dslabs_tpu.tpu import checkpoint as ckpt_mod  # noqa: E402
 from dslabs_tpu.tpu import packing as packing_mod  # noqa: E402
 from dslabs_tpu.tpu.engine import (SENTINEL, CapacityOverflow,  # noqa: E402
                                    TensorSearch, flatten_state)
+from dslabs_tpu.tpu import specs_lab4  # noqa: E402
 from dslabs_tpu.tpu.specs import (clientserver_spec,  # noqa: E402
-                                  paxos_spec, pingpong_spec)
+                                  paxos_spec, pb_spec, pingpong_spec)
 
 pytestmark = pytest.mark.capacity2
 
@@ -115,6 +117,189 @@ def test_bytes_per_state_reduction_floor(proto, floor):
     assert pk is not None
     assert pk.pack_ratio >= floor, pk.descriptor()
     assert pk.bytes_per_state * floor <= pk.bytes_per_state_unpacked
+
+
+# ------------------------------------------------- device codec == oracle
+
+def _synthetic_protocol():
+    """Raw 32-bit lanes beside 1-bit lanes: a word boundary after every
+    raw lane, 1-bit fields filling the words between them."""
+    import types
+
+    # On a sentinel-capable lane one bit holds the domain {0} and the
+    # reserved all-ones code.
+    bit, flag, raw = (0, 1), (0, 0), None
+    return types.SimpleNamespace(
+        name="synthetic", n_nodes=1, net_cap=3, timer_cap=2,
+        node_width=9, msg_width=4, timer_width=3,
+        lane_domains={
+            "nodes": [bit, raw, bit, bit, bit, raw, raw, bit, (-3, 3)],
+            "msg": [flag, raw, flag, (0, 6)],
+            "timer": [raw, flag, flag],
+            "exc": bit})
+
+
+def _lab3(net_cap, max_slots):
+    from dslabs_tpu.tpu.specs_lab3 import make_paxos_protocol
+
+    return make_paxos_protocol(n=3, n_clients=2, w=1,
+                               max_slots=max_slots, net_cap=net_cap,
+                               timer_cap=6)
+
+
+# name -> (protocol, delta lanes on, (lanes, words), signature AS THE
+# PARENT OF ISSUE 32 DERIVED IT — the encoding's identity: checkpoints
+# and the mesh wire ride on it, so the strings are pinned, not computed).
+_DESCRIPTORS = {
+    "flagship-842-to-217": (
+        lambda: _lab3(64, 3), False, (842, 217), "packed:217w:6c75e8a3"),
+    # the lab 3 adapter's first rung: paxos3-suite's 115-word rows
+    "suite-net-cap-32": (
+        lambda: _lab3(32, 2), False, (503, 115), "packed:115w:a7d21a0a"),
+    "lab0-pingpong": (
+        lambda: pingpong_spec(2).compile(), False, (66, 4),
+        "packed:4w:11956876"),
+    "lab1-clientserver": (
+        lambda: clientserver_spec(3, 4).compile(), False, (151, 11),
+        "packed:11w:3a3ce4b8"),
+    "lab2-pb-raw-lanes": (
+        lambda: pb_spec(2, 1, 1).compile(), False, (316, 84),
+        "packed:84w:4bdc941f"),
+    "lab2-pb-delta-lanes": (
+        lambda: pb_spec(2, 1, 1).compile(), True, (316, 79),
+        "packed:79w:d0cdcd28"),
+    "lab3-paxos-spec": (
+        lambda: paxos_spec(3).compile(), False, (81, 6),
+        "packed:6w:6f6de3be"),
+    "lab4-join": (
+        lambda: specs_lab4.make_join_protocol(1), False, (85, 9),
+        "packed:9w:2a9748fd"),
+    "lab4-shardstore": (
+        lambda: specs_lab4.make_shardstore_protocol((1, 1)), False,
+        (376, 117), "packed:117w:5b9a55b6"),
+    "lab4-shardstore-tx": (
+        lambda: specs_lab4.make_shardstore_tx_protocol(1), False,
+        (430, 136), "packed:136w:635a2640"),
+    "lab4-shardstore-multi": (
+        specs_lab4.make_shardstore_multi_protocol, False,
+        (1272, 448), "packed:448w:d7f14aad"),
+    "synthetic-raw-beside-1-bit": (
+        _synthetic_protocol, False, (28, 16), "packed:16w:9dcf3d72"),
+}
+
+
+def _codes(pk):
+    """Per lane, how many codes the CODEC takes as in-domain: 2^width,
+    less the reserved all-ones code on a sentinel-capable lane."""
+    return (1 << pk.width.astype(np.int64)) - pk.sent.astype(np.int64)
+
+
+def _in_domain_rows(pk, base, rng, n):
+    """Random rows inside every lane's code range (SENTINEL on ~30 % of
+    the sentinel-capable lanes) followed by the edges: all-SENTINEL,
+    raw lanes at 0xFFFFFFFF and at INT32_MIN, every field at its
+    largest non-sentinel code, every field at code 0."""
+    lo = pk._lo_eff_np(base).astype(np.int64)
+    codes = _codes(pk)
+    rows = lo[None, :] + rng.integers(0, codes[None, :],
+                                      (n, pk.lanes), dtype=np.int64)
+    rows = np.where(pk.raw[None, :],
+                    rng.integers(-2**31, 2**31, (n, pk.lanes)), rows)
+    rows = np.where(pk.sent[None, :] & (rng.random((n, pk.lanes)) < 0.3),
+                    int(SENTINEL), rows)
+    zero = lo.copy()
+    top = np.where(pk.raw, -1, lo + codes - 1)
+    edges = [np.where(pk.sent, int(SENTINEL), zero),
+             np.where(pk.raw, -1, zero),
+             np.where(pk.raw, -2**31, zero),
+             top, zero,
+             np.where(pk.sent, int(SENTINEL), top)]
+    return np.concatenate([rows, np.stack(edges)]).astype(np.int32)
+
+
+@pytest.mark.parametrize("name", list(_DESCRIPTORS))
+def test_device_codec_is_bit_identical_to_the_host_oracle(name):
+    """ISSUE 32: ``pack_jnp`` (one dense lanes -> words contraction per
+    byte plane) gives ``pack_np``'s rows bit for bit and
+    ``unpack_jnp`` inverts it, on random in-domain rows and the edges;
+    ``count_bad`` equals a reckoning in int64; the descriptor's
+    ``signature()`` is the parent's."""
+    build, delta, shape, signature = _DESCRIPTORS[name]
+    proto = build()
+    doms, _ = packing_mod._flat_domains(proto)
+    pk = packing_mod.derive_packing(proto, len(doms), delta=delta)
+    assert not pk.identity and pk.has_delta == delta
+    assert (pk.lanes, pk.words) == shape
+    assert pk.signature() == signature
+    rng = np.random.default_rng(zlib.crc32(name.encode()))
+    base = None
+    if delta:
+        base = np.where(pk.dlt, rng.integers(-50, 5000, pk.lanes),
+                        0).astype(np.int32)
+    rows = _in_domain_rows(pk, base, rng, 250)
+    want = pk.pack_np(rows, base)
+    jbase = None if base is None else jax.numpy.asarray(base)
+    pack = jax.jit(lambda r: pk.pack_jnp(r, jbase, count_bad=True))
+    got, bad = pack(jax.numpy.asarray(rows))
+    assert (np.asarray(got) == want).all()
+    assert not np.asarray(bad).any()
+    assert (pk.unpack_np(want, base) == rows).all()
+    back = jax.jit(lambda w: pk.unpack_jnp(w, jbase))(got)
+    assert (np.asarray(back) == rows).all()
+
+    # Out of domain: one below the bias, one past the last code, and
+    # far past it, on a random third of the bounded lanes.
+    lo = pk._lo_eff_np(base).astype(np.int64)
+    codes = _codes(pk)
+    wrong = np.stack([lo - 1, lo + codes, lo + codes + 12345])
+    wrong = wrong[rng.integers(0, 3, (64, pk.lanes)),
+                  np.arange(pk.lanes)[None, :]]
+    hit = (rng.random((64, pk.lanes)) < 0.33) & ~pk.raw[None, :]
+    out = np.where(hit, wrong, rows[:64]).astype(np.int32)
+    d = out.astype(np.int64) - lo[None, :]
+    reckoned = (~pk.raw[None, :] & (out != SENTINEL)
+                & ((d < 0) | (d >= codes[None, :]))).sum(axis=1)
+    assert reckoned.any()
+    _, bad = pack(jax.numpy.asarray(out))
+    assert (np.asarray(bad) == reckoned).all()
+
+
+def test_the_synthetic_descriptor_puts_raw_words_beside_1_bit_fields():
+    """What the synthetic case is there for, held to the descriptor."""
+    proto = _synthetic_protocol()
+    pk = packing_mod.derive_packing(
+        proto, len(packing_mod._flat_domains(proto)[0]))
+    assert pk.raw.sum() == 8 and (pk.width == 1).sum() >= 12
+    assert pk.sent[~pk.raw].any() and not pk.sent[pk.raw].any()
+    # a raw lane owns its word; 1-bit lanes share theirs
+    per_word = np.bincount(pk.word, minlength=pk.words)
+    assert (per_word[pk.word[pk.raw]] == 1).all() and per_word.max() >= 3
+
+
+def test_pack_lowers_to_one_contraction_not_a_column_per_word():
+    """ISSUE 32, so that the column form cannot return unnoticed: the
+    lowering of ``pack_jnp`` for the flagship descriptor at one chunk
+    step's 49,152 rows has no reduction per word (217 before), no
+    ``concatenate`` of more than 8 operands (217 ``[N, 1]`` columns
+    before: each padded 128x by the TPU's (8, 128) tile) and no ``[N]``
+    or ``[N, 1]`` operand feeding one."""
+    import re
+
+    n = 49152
+    pk = packing_mod.derive_packing(_lab3(64, 3), 842)
+    text = jax.jit(lambda r: pk.pack_jnp(r, count_bad=True)).lower(
+        jax.ShapeDtypeStruct((n, pk.lanes), jax.numpy.int32)).as_text()
+    assert len(re.findall(r"stablehlo\.dot_general", text)) == 4
+    assert len(re.findall(r"stablehlo\.reduce\b", text)) < 8
+    for line in text.splitlines():
+        if "stablehlo.concatenate" not in line:
+            continue
+        operands = re.findall(r"tensor<([^>]*)>",
+                              line.split(":", 1)[1].split("->")[0])
+        assert len(operands) <= 8, line
+        for t in operands:
+            dims = t.split("x")[:-1]
+            assert dims not in ([str(n)], [str(n), "1"]), line
 
 
 # ------------------------------------------------------------- parity
