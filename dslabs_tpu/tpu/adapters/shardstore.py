@@ -28,6 +28,7 @@ from __future__ import annotations
 import dataclasses
 from typing import Dict, List, Optional
 
+from dslabs_tpu.tpu import telemetry
 from dslabs_tpu.tpu.adapters.paxos import _workload_pairs
 from dslabs_tpu.tpu.backend import (NoTensorTwin, TwinBinding,
                                     register_adapter)
@@ -438,7 +439,13 @@ class ShardStoreBinding(TwinBinding):
             raise NoTensorTwin(
                 "staged network ops on the joined root are not part of "
                 "the canonical lab4 shape")
+        # ``cached`` as every stage of a call has it: 1, the stage built
+        # nothing (the twin's own initial state is the root).
+        with telemetry.phase("entry.root.validate", cached=1):
+            self._validate(state)
+        return None, []
 
+    def _validate(self, state) -> None:
         _validate_joined_root(state, self.master_name,
                               self.server_names, self.client_names)
 
@@ -491,7 +498,6 @@ class ShardStoreBinding(TwinBinding):
             req(all(isinstance(t, ClientTimer) for t in cts)
                 and [t.sequence_num for t in cts] == list(range(1, G + 1)),
                 f"controller timer queue {cts} != ClientTimer(1..{G})")
-        return None, []
 
     # ------------------------------------------------------------- protocol
 
@@ -819,8 +825,9 @@ class ShardStoreTxBinding(TwinBinding):
             raise NoTensorTwin(
                 "staged network ops on the joined root are not part of "
                 "the canonical lab4 shape")
-        _validate_joined_root(state, self.master_name,
-                              self.server_names, [self.client_name])
+        with telemetry.phase("entry.root.validate", cached=1):
+            _validate_joined_root(state, self.master_name,
+                                  self.server_names, [self.client_name])
         return None, []
 
     def build_protocol(self, net_cap, timer_cap):

@@ -55,6 +55,7 @@ from __future__ import annotations
 import collections
 import contextlib
 import dataclasses
+import itertools
 import os
 import threading
 from typing import Any, Callable, Dict, List, Optional, Tuple
@@ -282,12 +283,34 @@ def predicate_signature(pred) -> tuple:
 
 # ---------------------------------------------- what is kept across calls
 
-@dataclasses.dataclass
+@dataclasses.dataclass(eq=False)
 class _Engine:
     """A kept ``ShardedTensorSearch`` — its traced and compiled programs
-    — and whether a warm run has completed on it."""
+    — and whether a warm run has completed on it.  ``serial`` names the
+    engine in a call's ``entry.build_engine`` span, and the engine is
+    registered under ``telemetry.KEPT_SUPERSTEP``, so that the reader of
+    a traced call finds the text of the very superstep an attempt ran
+    (a lab process keeps several engines)."""
     search: Any
     warmed: bool = False
+    serial: int = dataclasses.field(
+        default_factory=itertools.count(1).__next__)
+    _text: Optional[str] = None
+
+    def __post_init__(self):
+        telemetry.register_program(telemetry.KEPT_SUPERSTEP, self)
+
+    def as_text(self) -> str:
+        """The optimised text of this engine's superstep.  The lab entry
+        dispatches the lazy jit (no executable to keep), so the program
+        is lowered again here, from the dispatch site's own abstract
+        arguments, the first time a traced run asks: seconds of host
+        time, paid by that reader alone."""
+        if self._text is None:
+            site = self.search.dispatch_site_programs()["sharded.superstep"]
+            self._text = site["fn"].lower(
+                *site["args"]).compile().as_text()
+        return self._text
 
     def rest(self) -> None:
         """Drop what the finished run left on the engine for the replay.
@@ -617,10 +640,13 @@ def _run_tensor(binding: TwinBinding, settings, state, lease: set,
     last: Optional[Exception] = None
     # Where a call's seconds go, stage by stage (telemetry.PHASES):
     # each stage below is a phase of the call ``tensor_bfs`` opened, on
-    # every attempt, with ``cached`` = 1 where it built nothing.
+    # every attempt (``attempt``: a rung that overflows throws its root,
+    # its warm run and its search away), with ``cached`` = 1 where it
+    # built nothing.
     for attempt, (f_cap, v_cap) in enumerate(_LADDER):
         caps = (net_cap << attempt, timer_cap + 2 * attempt)
-        with telemetry.phase("entry.bind", attempt=attempt) as span:
+        with telemetry.phase("entry.bind", attempt=attempt,
+                             twin=str(binding.key[0])) as span:
             if attempt == 0:
                 # check_settings BEFORE build_protocol: bindings bind
                 # settings-dependent modelling flags there (lab4's
@@ -646,6 +672,7 @@ def _run_tensor(binding: TwinBinding, settings, state, lease: set,
                     protocol, mesh, chunk_per_device=chunk,
                     frontier_cap=f_cap, visited_cap=v_cap, strict=True,
                     record_trace=True)), lease)
+            span.set(engine=kept.serial)
             search = kept.search
             # Everything a call sets on its engine is set on EVERY call,
             # kept engine or new, so that nothing of the call before
@@ -656,8 +683,9 @@ def _run_tensor(binding: TwinBinding, settings, state, lease: set,
             # test; semantic errors like CapacityOverflow pass straight
             # through to the capacity ladder below), with this call's
             # own budget; the delivery masks; the once-a-search
-            # capacity-pressure warning and the dispatch annotations'
-            # running index; and, below, the limits.
+            # capacity-pressure warning, the dispatch annotations'
+            # running index and the last readback's per-device counters;
+            # and, below, the limits.
             recorder = telemetry.current()
             if recorder is not None:
                 recorder.attach(search)
@@ -666,6 +694,7 @@ def _run_tensor(binding: TwinBinding, settings, state, lease: set,
             install_retry(search)
             search.set_runtime_masks(marr, tarr)
             search._warned_visited, search._dispatch_i = False, -1
+            search._last_per_device = None
         rel = None
         if settings.depth_limited():
             rel = settings.max_depth - state.depth
@@ -676,7 +705,8 @@ def _run_tensor(binding: TwinBinding, settings, state, lease: set,
             # a higher ladder rung can overflow this rung's caps, and
             # must escalate rather than fail the test (ADVICE r4).
             built = _KEPT.built
-            with telemetry.phase("entry.derive_root") as span:
+            with telemetry.phase("entry.derive_root",
+                                 attempt=attempt) as span:
                 root, history = binding.derive_root(search, state)
                 span.set(cached=int(_KEPT.built == built))
             if (settings.max_time_secs is not None
@@ -691,19 +721,23 @@ def _run_tensor(binding: TwinBinding, settings, state, lease: set,
                 # compiled (a root it has not started from yet still
                 # compiles its small carry initialiser).
                 search.max_depth, search.max_secs = 2, None
-                with telemetry.phase("entry.warm_run"):
+                with telemetry.phase("entry.warm_run", attempt=attempt):
                     search.run(initial=root, check_initial=False)
                 kept.warmed = True
             search.max_depth = rel
             search.max_secs = (
                 None if settings.max_time_secs is None
                 else settings.max_time_secs * GlobalSettings.time_scale)
-            with telemetry.phase("entry.search"):
+            with telemetry.phase("entry.search", attempt=attempt):
                 outcome = search.run(initial=root)
             return search, outcome, history
         except CapacityOverflow as e:
+            # ``explored``: what the attempt's last stats readback had
+            # counted (sharded._sync_checks), 0 where it never ran.
             telemetry.mark("entry.capacity_retry", attempt=attempt,
-                           overflow=str(e)[:96])
+                           overflow=str(e)[:96], explored=sum(
+                               (search._last_per_device
+                                or {}).get("explored", ())))
             last = e
             continue
     raise last

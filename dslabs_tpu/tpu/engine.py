@@ -1353,13 +1353,20 @@ class TensorSearch:
         out.platform, out.device_kind = dev.platform, dev.device_kind
         return out
 
+    @property
+    def bytes_per_state(self) -> int:
+        """Bytes of one stored frontier row: packed where a packing is
+        on.  A property of the engine, not of a run: the lab entry's
+        higher ladder rungs bind a wider network, whose rows pack to
+        more."""
+        return (self._pk.bytes_per_state if self._pk is not None
+                else self.lanes * 4)
+
     def _stamp_capacity(self, out: "SearchOutcome") -> "SearchOutcome":
         """Attach the capacity-round-2 accounting every verdict
         carries (bench/STATUS render it; telemetry compare guards
         bytes_per_state)."""
-        out.bytes_per_state = (self._pk.bytes_per_state
-                               if self._pk is not None
-                               else self.lanes * 4)
+        out.bytes_per_state = self.bytes_per_state
         out.bytes_per_state_unpacked = self.lanes * 4
         out.pack_ratio = round(
             out.bytes_per_state_unpacked / max(out.bytes_per_state, 1),

@@ -50,7 +50,7 @@ __all__ = [
     "JOIN_REQ", "JOIN_REP",
     "JOIN_T_CLIENT", "JOIN_T_ELECTION", "JOIN_T_HEARTBEAT",
     "QRY", "QREP", "SSREQ", "SSREP", "WG", "SM", "SMACK", "JREQ",
-    "JREP", "T_CLIENT", "T_QUERY", "T_ELECTION", "T_HEARTBEAT",
+    "JREP", "TXP", "TXV", "TXD", "TXA", "T_CLIENT", "T_QUERY", "T_ELECTION", "T_HEARTBEAT",
     "CLIENT_MS", "QUERY_MS", "ELECTION_MIN", "ELECTION_MAX",
     "HEARTBEAT_MS",
 ]
@@ -62,10 +62,14 @@ HEARTBEAT_MS = 50
 
 # Wire tags, for the harness adapters (tpu/adapters/shardstore.py).
 # The join twin is its own enum space; the store twins share the first
-# seven tags (the tx twin appends TXP..TXA in its own factory).
+# seven tags; after them the part-1 twin has the controller's JREQ /
+# JREP and the tx twin its four 2PC records (``_twopc_fragment``,
+# included after the reconfiguration's two: make_shardstore_tx_spec
+# holds the order to these names).
 JOIN_REQ, JOIN_REP = 0, 1
 JOIN_T_CLIENT, JOIN_T_ELECTION, JOIN_T_HEARTBEAT = 1, 2, 3
 QRY, QREP, SSREQ, SSREP, WG, SM, SMACK, JREQ, JREP = range(9)
+TXP, TXV, TXD, TXA = range(SMACK + 1, SMACK + 5)
 T_CLIENT, T_QUERY, T_ELECTION, T_HEARTBEAT = 1, 2, 3, 4
 
 
@@ -793,6 +797,12 @@ def make_shardstore_tx_spec(n_tx: int = 1, net_cap: int = 48,
         max_live_sends=6)
     spec.include("group", _reconfig_fragment(1, N_CFG, [W], G))
     spec.include("group", _twopc_fragment(W, CLIENT))
+    # The lab adapter decodes recorded traces by these tags
+    # (tpu/adapters/shardstore.py ShardStoreTxBinding._decode_message).
+    if [m.name for m in spec.messages[SM:]] != [
+            "ShardMove", "ShardMoveAck", "TxPrepare", "TxVote",
+            "TxDecision", "TxAck"]:
+        raise AssertionError("tx twin's wire tags moved off SM..TXA")
 
     def reconfig_done(ctx, g):
         # _reconfig_done: no handoff in flight AND no 2PC state held

@@ -112,8 +112,8 @@ __all__ = ["Telemetry", "MetricsRegistry", "Counter", "Gauge",
            "read_ledger", "compare_ledger", "render_compare",
            "DISPATCH_SITES", "PHASES", "DEVICE_SCOPES", "AOT_PROGRAMS",
            "phase", "mark", "call", "annotate", "use", "current",
-           "device_scope", "register_program", "program_scopes",
-           "scopes_of_hlo", "main"]
+           "device_scope", "register_program", "registered_programs",
+           "program_scopes", "scopes_of_hlo", "KEPT_SUPERSTEP", "main"]
 
 # THE canonical dispatch-site registry (ISSUE 10): every tag the
 # engines route through ``TensorSearch._dispatch``, with the static
@@ -377,19 +377,30 @@ class MetricsRegistry:
 # tests/test_program_spans.py holds every name a run emits to it.
 # PERF.md section 3 says which per-layer metric reads which.
 AOT_PROGRAMS = ("superstep", "promote", "init_carry")
+# The lab entry's kept engines register themselves under this name
+# (tpu/backend.py ``_Engine``: ``serial``, ``as_text()``), apart from
+# the AOT executables that ``program_scopes`` reads.
+KEPT_SUPERSTEP = "superstep.kept"
 PHASES = (
     "entry.tensor_bfs", "entry.tensor_dfs",     # root of one lab call
-    # one of each a ladder attempt, with ``cached`` = 1 where the lab
-    # entry had kept what the stage would build (tpu/backend.py _Kept)
+    # one of each a ladder attempt (``attempt``), with ``cached`` = 1
+    # where the lab entry had kept what the stage would build
+    # (tpu/backend.py _Kept); ``entry.bind`` names the ``twin`` it bound,
+    # ``entry.build_engine`` the kept ``engine`` (its serial) it leased
     "entry.bind", "entry.build_engine", "entry.derive_root",
     # the trace step built and compiled (absent where it was kept: once
     # a twin and caps a process, in whichever of derive_root, replay or
     # recheck needs it first); a STAGED state's history replayed
-    # (events, staged_ops)
-    "entry.root.build", "entry.root.replay",
+    # (events, staged_ops), or VALIDATED as the canonical root a twin's
+    # initial state bakes in (lab 4's joined root: no replay under it)
+    "entry.root.build", "entry.root.replay", "entry.root.validate",
+    # ``attempt`` on these two as well: a rung that overflows throws its
+    # warm run and its search away (entry.capacity_retry ends it)
     "entry.warm_run", "entry.search", "entry.replay", "entry.recheck",
     "entry.probe",
-    "entry.capacity_retry",         # mark: a ladder attempt overflowed
+    # mark: a ladder attempt overflowed (``attempt``, ``overflow``, and
+    # ``explored``: the states its last stats readback had counted)
+    "entry.capacity_retry",
     "search.level",                 # one BFS level / wave
     "compile.aot",                  # aot_warmup, one child per program
     "compile.event",                # mark: one jax.monitoring event
@@ -564,6 +575,14 @@ def register_program(name: str, exe) -> None:
     _PROGRAMS.setdefault(name, weakref.WeakSet()).add(exe)
 
 
+def registered_programs(name: str) -> list:
+    """What is live of the things registered as ``name`` (each gives its
+    optimised module as ``as_text()``): the AOT executables of a
+    program, one an engine that compiled it, or under
+    ``KEPT_SUPERSTEP`` the lab entry's kept engines."""
+    return list(_PROGRAMS.get(name, ()))
+
+
 def scopes_of_hlo(text: str) -> Dict[str, Tuple[str, bool]]:
     """``{instruction: (scope, named)}`` of an optimised HLO module's
     text.  ``named``: the scope is the innermost ``dslabs.<scope>`` of
@@ -639,7 +658,7 @@ def program_scopes(name: str) -> Optional[Dict[str, Tuple[str, bool]]]:
     which of them a trace ran cannot be told from here, and no
     attribution is better than the other engine's."""
     maps: List[dict] = []
-    for exe in list(_PROGRAMS.get(name, ())):
+    for exe in registered_programs(name):
         if exe not in _PROGRAM_SCOPES:
             try:
                 text = exe.as_text()

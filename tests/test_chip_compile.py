@@ -305,6 +305,64 @@ def test_lab3_suite_programs_compile(topo, one_chip, attempt):
     assert "all-to-all" not in exes["superstep"].as_text()
 
 
+def _lab4_search(mesh, phase, attempt):
+    """A twin of ShardStorePart2Test test09's staged search (the
+    benchmark's ``shardtx-suite`` cell) exactly as ``backend._run_tensor``
+    builds it on rung ``attempt`` of the capacity ladder: ``join`` binds
+    the shard-master twin from the root, ``commit`` the 2PC twin from
+    the joined state (the object checker's: 0.02 s) plus the client."""
+    from benchmark.harness import manifest
+    from dslabs_tpu.search.search import BFS
+
+    cell = manifest.load_cell(
+        os.path.dirname(os.path.dirname(os.path.abspath(__file__))),
+        "shardtx-suite")
+    drv, spec = cell.driver, cell.config["deployment"]["object_state"]
+    state = drv.build_state(spec, 1)
+    if phase != "join":
+        state = drv.add_client(BFS(drv.build_settings(
+            cell.config["phases"]["join"], state)).run(
+                state).goal_matching_state, spec, 1)
+    settings = drv.build_settings(cell.config["phases"][phase], state)
+    binding = backend.resolve_binding(state)
+    binding.check_settings(settings)
+    net_cap, timer_cap = binding.initial_caps()
+    protocol, marr, tarr = backend._bind_protocol(
+        binding, settings, net_cap << attempt, timer_cap + 2 * attempt)
+    f_cap, v_cap = backend._LADDER[attempt]
+    search = ShardedTensorSearch(
+        protocol, mesh, chunk_per_device=512, frontier_cap=f_cap,
+        visited_cap=v_cap, strict=True, record_trace=True)
+    search.set_runtime_masks(marr, tarr)
+    return search
+
+
+@pytest.mark.parametrize("phase,attempt", [
+    ("join", 0), ("commit", 0), ("commit", 1)])
+def test_lab4_staged_programs_compile(topo, one_chip, phase, attempt):
+    """What a staged lab 4 call builds on one chip, on the rungs it
+    stands on (``join`` answers on the first; ``commit`` climbs to the
+    second on every call: PERF.md, PR 33): superstep (trace recording
+    on), promote, root init, and the chunk-1 trace step of the twin a
+    phase binds."""
+    import dataclasses
+
+    from dslabs_tpu.tpu.engine import TensorSearch
+
+    search = _lab4_search(_mesh(topo, 1), phase, attempt)
+    assert search.p.name == ("shardmaster-join-w2" if phase == "join"
+                             else "shardstore-tx-g2-w1")
+    exes = _aot(search)
+    replayer = TensorSearch(dataclasses.replace(
+        search.p, deliver_message=None, deliver_timer=None), chunk=1)
+    exes["step_one"] = jax.jit(replayer._step_one).lower(
+        jax.ShapeDtypeStruct((search.lanes,), jnp.int32,
+                             sharding=one_chip),
+        jax.ShapeDtypeStruct((), jnp.int32, sharding=one_chip)).compile()
+    _fits(exes)
+    assert "all-to-all" not in exes["superstep"].as_text()
+
+
 def _flagship_search(mesh, chunk):
     from bench import _bench_protocol
 

@@ -258,6 +258,62 @@ def test_lab3_test22_five_phase_parity(tensor_backend):
     assert sorted(goals) == ["decide", "finish13", "finish23"]
 
 
+@pytest.mark.skipif(SLOW, reason="a minute of object checker for the "
+                    "commit goal (DSLABS_SLOW_TESTS=1 enables)")
+def test_lab4_test09_staged_tx_parity(tensor_backend):
+    """ShardStorePart2Test test09's staged search as the benchmark's
+    ``shardtx-suite`` cell makes it (its configuration's phases, its
+    driver's states and settings): the Join search on the shard-master
+    twin, then from ITS goal state plus the client the cross-group 2PC
+    goal search and the done-pruned exhaust on the 2PC twin — which
+    validates the staged state as its root.  The object checker runs
+    every phase from the very state the tensor phase was given."""
+    import os
+
+    from benchmark.harness import manifest
+    from dslabs_tpu.search.search import BFS
+    from dslabs_tpu.tpu import telemetry
+
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    cell = manifest.load_cell(root, "shardtx-suite")
+    drv, spec = cell.driver, cell.config["deployment"]["object_state"]
+    phases = cell.config["phases"]
+    goals, starts, attempts = {}, {}, {}
+    for name in phases:
+        kind, _, frm = phases[name]["start"].partition(" of ")
+        start = (drv.build_state(spec, 9) if kind == "root"
+                 else goals[frm] if kind == "goal" else starts[frm])
+        if phases[name]["adds"] == "client":
+            drv.add_client(start, spec, 9)
+        settings = drv.build_settings(phases[name], start)
+        tel = telemetry.Telemetry(ring=1 << 14)
+        with telemetry.use(tel):
+            res = bfs(start, settings)
+        attempts[name] = [r["attempt"] for r in tel.ring
+                          if r["t"] == "phase"
+                          and r["name"] == "entry.search"]
+        assert res.tensor_outcome.dropped == 0
+        obj = BFS(settings).run(start)
+        want = cell.config["reference"][name]
+        assert (obj.end_condition.name == res.end_condition.name
+                == want["end_condition"]), name
+        starts[name] = start
+        if res.end_condition == EndCondition.GOAL_FOUND:
+            goals[name] = goal = res.goal_matching_state
+            assert (goal.depth == obj.goal_matching_state.depth
+                    == want["terminal_depth"]), name
+            assert any(g.check(goal).value for g in settings.goals)
+        else:
+            assert (res.discovered_count == obj.discovered_count
+                    == want["discovered_count"]), name
+    assert sorted(goals) == ["commit", "join"]
+    # commit's eighth level appends 16,836 rows: over rung 0's 2^14 a
+    # device on ONE device (the chip climbs on every call: PERF.md,
+    # PR 33), under it on this suite's eight
+    assert attempts["join"] == attempts["exhaust6"] == [0]
+    assert attempts["commit"] in ([0], [0, 1])
+
+
 def test_lab2_single_server_verdicts(tensor_backend):
     """test16-shaped lab2 search through the tensor backend: the
     ViewServer + PBServer + client stack reaches CLIENTS_DONE with the
