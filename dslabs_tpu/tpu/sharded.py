@@ -393,7 +393,8 @@ class ShardedTensorSearch(TensorSearch):
         """The per-device chunk-step body (runs INSIDE shard_map, as
         the body of the superstep's ``lax.while_loop``): one chunk
         expand + owner routing of keys and rows + owner dedup +
-        frontier append."""
+        frontier append.  Returns the carry and the write blocks the
+        step scattered (table and append: visited.block_width)."""
         p = self.p
         D = self.n_devices
         C = self.cpd
@@ -596,8 +597,9 @@ class ShardedTensorSearch(TensorSearch):
                 # counted into the vis_over flag, which _sync_checks raises
                 # on in strict mode and reports via
                 # SearchOutcome.visited_overflow in beam mode.
-                new_visited, ins_s, unres_s = visited_mod.insert(
-                    carry["visited"], recv_keys, recv_valid)
+                new_visited, ins_s, unres_s, blocks = visited_mod.insert(
+                    carry["visited"], recv_keys, recv_valid,
+                    count_blocks=True)
                 fresh_s = ins_s | unres_s
                 vis_over = jnp.sum(unres_s).astype(jnp.int32)
                 n_fresh = jnp.sum(ins_s).astype(jnp.int32)
@@ -630,11 +632,30 @@ class ShardedTensorSearch(TensorSearch):
                 # double-count.  noapp counting stays on sel_would — the
                 # DEPTH-vs-SPACE decision is about expandable successors.
                 sel = (fresh_s if spill_on else sel_would) & ~noapp
-                spos = jnp.cumsum(sel) - 1
-                nxt, nxt_n = carry["nxt"], carry["nxt_n"][0]
-                sdst = jnp.where(sel & (nxt_n + spos < F), nxt_n + spos, F)
-                nxt = nxt.at[sdst].set(app_rows)
-                n_sel = jnp.sum(sel).astype(jnp.int32)
+                nxt_n = carry["nxt_n"][0]
+                # Write narrow, as the table's scatter does
+                # (visited.write_in_blocks): the chip pays a scatter per
+                # INDEX it is handed, and most received rows are not
+                # selected.  The selected rows, in owner-received order, go
+                # out a block at a time to nxt_n, nxt_n + 1, ...; what a
+                # block holds past the last selected row, and what would
+                # land past F, goes to the dump row F.
+                srcs, bufs = (app_rows,), (carry["nxt"],)
+                if self.record_trace:
+                    # Trace meta rides the SAME append blocks as the rows.
+                    srcs += (app_meta,)
+                    bufs += (carry["tmeta"],)
+
+                def append_block(bufs, first, at, live):
+                    dst = nxt_n + first + jnp.arange(
+                        at.shape[0], dtype=jnp.int32)
+                    dst = jnp.where(live & (dst < F), dst, F)
+                    return tuple(buf.at[dst].set(src[at])
+                                 for buf, src in zip(bufs, srcs))
+
+                bufs, n_sel, app_blocks = visited_mod.write_in_blocks(
+                    sel, append_block, bufs)
+                nxt = bufs[0]
                 frontier_drop = jnp.maximum(nxt_n + n_sel - F, 0)
                 # Occupancy counts only rows that actually landed (<= F), else
                 # the next level's chunk loop would re-expand the tail.
@@ -675,8 +696,7 @@ class ShardedTensorSearch(TensorSearch):
                     "flag_cnt": flag_cnt, "flag_rows": flag_rows,
                 }
                 if self.record_trace:
-                    # Trace meta rides the SAME append scatter as the rows.
-                    out["tmeta"] = carry["tmeta"].at[sdst].set(app_meta)
+                    out["tmeta"] = bufs[1]
                     out["flag_meta"] = flag_meta
                 if delta:
                     out["pb_cur"] = carry["pb_cur"]
@@ -698,7 +718,7 @@ class ShardedTensorSearch(TensorSearch):
                         out[k] = jnp.where(abort, carry[k], out[k])
                     out["f_full"] = jnp.where(abort, code,
                                               jnp.int32(0))[None]
-                return out
+                return out, blocks + app_blocks
 
         return local
 
@@ -724,11 +744,11 @@ class ShardedTensorSearch(TensorSearch):
 
         Returns ``(carry', stats)`` where ``stats`` is the fused scalar
         vector _sync_checks parses: 8 scalars, the n_flags counts,
-        ``[remaining_devices, steps_taken]``, the spill abort code when
-        the host tier is wired, and the per-device lanes.  Computing the
-        stats in-program (psum/pmax over the mesh axis) folds the level
-        sync into the same dispatch: host involvement per level is
-        superstep + promote."""
+        ``[remaining_devices, steps_taken, write_blocks]``, the spill
+        abort code when the host tier is wired, and the per-device lanes.
+        Computing the stats in-program (psum/pmax over the mesh axis)
+        folds the level sync into the same dispatch: host involvement
+        per level is superstep + promote."""
         local = self._make_local_step()
         C = self.cpd
         ax = self.axis
@@ -738,7 +758,7 @@ class ShardedTensorSearch(TensorSearch):
 
         spill_on = self._spill is not None
 
-        def stats_local(c, steps):
+        def stats_local(c, steps, blocks):
             core = jnp.stack([
                 _psum(c["overflow"][0]),
                 _psum(c["drops"][0]),
@@ -754,7 +774,10 @@ class ShardedTensorSearch(TensorSearch):
             flags = _psum(c["flag_cnt"]).astype(jnp.int32)
             remaining = _psum(
                 (c["j"][0] * C < c["cur_n"][0]).astype(jnp.int32))
-            tail = jnp.stack([remaining, steps]).astype(jnp.int32)
+            # Write blocks scattered (table + append), summed over this
+            # dispatch's chunk steps, of the device that scattered most.
+            tail = jnp.stack([remaining, steps,
+                              jax.lax.pmax(blocks, ax)]).astype(jnp.int32)
             parts = [core, flags, tail]
             if spill_on:
                 # The abort is global, so any device's copy is the
@@ -773,7 +796,7 @@ class ShardedTensorSearch(TensorSearch):
 
         def super_local(carry, budget, masks=None):
             def cond(st):
-                c, k = st
+                c, k, _ = st
                 own = c["j"][0] * C < c["cur_n"][0]
                 keep = (jax.lax.psum(own.astype(jnp.int32), ax) > 0) & (
                     k < budget)
@@ -785,13 +808,14 @@ class ShardedTensorSearch(TensorSearch):
                 return keep
 
             def body(st):
-                c, k = st
-                return local(c, masks), k + 1
+                c, k, wb = st
+                c, blocks = local(c, masks)
+                return c, k + 1, wb + blocks
 
-            carry, k = jax.lax.while_loop(cond, body,
-                                          (carry, jnp.int32(0)))
+            carry, k, wb = jax.lax.while_loop(
+                cond, body, (carry, jnp.int32(0), jnp.int32(0)))
             with tel_mod.device_scope("level_sync"):
-                return carry, stats_local(carry, k)
+                return carry, stats_local(carry, k, wb)
 
         spec = self._carry_specs()
         # The function's name is the program's in a profile
@@ -1743,10 +1767,10 @@ class ShardedTensorSearch(TensorSearch):
         self._trace_root = jax.tree.map(np.asarray, state)
         self._fp_map = {}
         self._deep_samples = None
-        # Structured per-level throughput records (depth, chunks, wall,
-        # explored, unique, next_frontier) — attached to the outcome as
-        # SearchOutcome.levels; the ``search.level`` phase carries the
-        # same counters into a profile.
+        # Structured per-level throughput records (depth, chunks,
+        # write_blocks, wall, explored, unique, next_frontier) — attached
+        # to the outcome as SearchOutcome.levels; the ``search.level``
+        # phase carries the same counters into a profile.
         self._level_records: List[dict] = []
         self._pd_prev_explored = [0] * self.n_devices
         self._root_fp = tuple(np.asarray(
@@ -1867,8 +1891,8 @@ class ShardedTensorSearch(TensorSearch):
                         carry["noapp"] = jax.device_put(
                             np.ones(self.n_devices, np.int32), shard)
                     (carry, out, explored, vis_total, drops, max_n,
-                     chunks) = self._level_superstep(carry, depth, t0,
-                                                     max_n)
+                     chunks, blocks) = self._level_superstep(
+                         carry, depth, t0, max_n)
                     if out is not None:
                         return out
                     if self._spill_on:
@@ -1884,13 +1908,20 @@ class ShardedTensorSearch(TensorSearch):
                                 break
                             carry, per = self._sh_spill_inject(carry, seg)
                             (carry, out, explored, vis_total, drops, max_n,
-                             ch2) = self._level_superstep(carry, depth, t0,
-                                                          per)
+                             ch2, bl2) = self._level_superstep(
+                                 carry, depth, t0, per)
                             chunks += ch2
+                            blocks += bl2
                             if out is not None:
                                 return out
                     rec = {
                         "depth": depth, "chunks": int(chunks),
+                        # Scatter blocks the level's chunk steps wrote
+                        # (table + append; on a mesh, of the device that
+                        # wrote most): above one a probe iteration and one
+                        # an append, visited.block_width is too narrow
+                        # for the traffic.
+                        "write_blocks": int(blocks),
                         "wall": round(time.time() - t_lvl, 4),
                         "explored": int(explored), "unique": int(vis_total),
                         "next_frontier": int(max_n),
@@ -1940,7 +1971,8 @@ class ShardedTensorSearch(TensorSearch):
                             rec["hbm_peak"] = hbm
                     self._level_records.append(rec)
                     lvl.set(explored=int(explored), unique=int(vis_total),
-                            chunks=int(chunks), next_frontier=int(max_n))
+                            chunks=int(chunks), write_blocks=int(blocks),
+                            next_frontier=int(max_n))
                 if tel is not None:
                     # The SAME host scalars the fused stats readback
                     # already delivered — telemetry adds no transfers.
@@ -2027,7 +2059,7 @@ class ShardedTensorSearch(TensorSearch):
         wall-clock budget is set — the whole level in ONE dispatch) and
         returns the fused stats in the same program.  Returns
         ``(carry, outcome_or_none, explored, vis_total, drops, nxt_max,
-        chunk_steps_run)``."""
+        chunk_steps_run, write_blocks_run)``."""
         budget = ((1 << 30) if self.max_secs is None
                   else max(1, self._superstep_chunks))
         # Watchdog granularity (tpu/supervisor.py): a superstep
@@ -2038,10 +2070,11 @@ class ShardedTensorSearch(TensorSearch):
         self._dispatch_deadline_scales = {
             "superstep": float(max(1, min(budget, 2 * est)))}
         nf = len(self._flag_names)
-        chunks = 0
+        chunks = blocks = 0
         while True:
             carry, stats = self._superstep_call(carry, budget)
             chunks += int(stats[9 + nf])
+            blocks += int(stats[10 + nf])
             # The checks run BEFORE any time-budget return: a violation
             # or capacity loss in the chunks already completed is never
             # masked by TIME_EXHAUSTED.
@@ -2049,15 +2082,15 @@ class ShardedTensorSearch(TensorSearch):
              nxt_max) = self._sync_checks(carry, depth, t0, stats)
             if out is not None:
                 return (carry, out, explored, vis_total, drops, nxt_max,
-                        chunks)
-            if self._spill_on and int(stats[10 + nf]):
+                        chunks, blocks)
+            if self._spill_on and int(stats[11 + nf]):
                 # Spill abort: the superstep suspended on a frontier-
                 # full (bit 0) / table-full (bit 1) chunk, reverted
                 # wholesale.  Drain nxt through the refilter to the
                 # host spool, evict the tables if they were the wall,
                 # and re-enter the drain loop — the held-back chunk
                 # re-steps against recovered capacity.
-                code = int(stats[10 + nf])
+                code = int(stats[11 + nf])
                 if (code & 1) and nxt_max == 0:
                     raise CapacityOverflow(
                         f"{self.p.name}: one chunk's fresh successors "
@@ -2076,15 +2109,15 @@ class ShardedTensorSearch(TensorSearch):
                 continue
             if int(stats[8 + nf]) == 0:     # every device's shard drained
                 return (carry, None, explored, vis_total, drops, nxt_max,
-                        chunks)
+                        chunks, blocks)
             if (self.max_secs is not None
                     and time.time() - t0 > self.max_secs) \
                     or self._cancelled():
                 out = self._limit_outcome("TIME_EXHAUSTED", carry,
                                           depth, t0)
                 out.cancelled = self._cancelled()
-                return (carry, out,
-                        explored, vis_total, drops, nxt_max, chunks)
+                return (carry, out, explored, vis_total, drops, nxt_max,
+                        chunks, blocks)
 
     def _spill_tmeta(self, carry) -> None:
         """Fold this level's appended (child_fp, parent_fp, event) rows

@@ -401,7 +401,11 @@ PHASES = (
     # mark: a ladder attempt overflowed (``attempt``, ``overflow``, and
     # ``explored``: the states its last stats readback had counted)
     "entry.capacity_retry",
-    "search.level",                 # one BFS level / wave
+    # one BFS level / wave: ``depth``, ``explored0``; at close
+    # ``explored``, ``unique``, ``chunks``, ``next_frontier`` and, from
+    # the sharded engine, ``write_blocks`` (scatter blocks its chunk
+    # steps wrote, table + append: visited.block_width)
+    "search.level",
     "compile.aot",                  # aot_warmup, one child per program
     "compile.event",                # mark: one jax.monitoring event
 ) + tuple(f"compile.aot.{name}" for name in AOT_PROGRAMS) + tuple(

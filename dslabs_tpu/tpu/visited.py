@@ -13,12 +13,23 @@ l`` holds lane ``l`` of its slot ``s``.  This module owns the layout —
 :func:`table_shape`, :func:`empty_table`, :func:`with_root`,
 :func:`insert`, :func:`build_table` and :func:`host_occupied` are the
 only places that know it; per-device tables stack along the rows
-(``[D * 32, VB]``, sharded ``P(axis)``).  One probe iteration gathers
-each key's whole bucket column (``table[:, bkt_i]``), patches the
-winner's four words into the gathered column and scatters whole columns
-back; a key that did not win scatters to column ``VB``, which
-``mode="drop"`` discards — the no-op write the old ``[V + 1, 4]``
-layout spent a dump row on.  Gather and scatter address the table in
+(``[D * 32, VB]``, sharded ``P(axis)``).  PROBE WIDE, WRITE NARROW:
+one probe iteration gathers each key's whole bucket column
+(``table[:, bkt_i]``) and decides at the batch's full width who wins a
+slot; then only the WINNERS' columns are written — their positions
+compacted, lowest first, into blocks of ``K`` = :func:`block_width`
+indices (:func:`write_in_blocks`), each block's columns read again, patched
+with the winner's four words and scattered back whole; a block's unused
+places scatter to column ``VB``, which ``mode="drop"`` discards.  The
+chip pays a scatter per INDEX it is handed, written or dropped (103–125
+ns each on the 2^24-slot table: PERF.md, PR 34), and in a deep level
+nine keys in ten win nothing, so one block of ``n / 8`` does where the
+whole batch was scattered before.  The blocks LOOP (``ceil(winners /
+K)``, from the data) because the first levels of a search are the other
+way round — every successor fresh, every key a winner — and must stay
+exact at the price they had.  The tail's iterations write the same way
+at the tail's width; a batch no wider than ``K`` (256 keys or fewer) is
+its own block.  Gather and scatter address the table in
 the one layout the carry has (32 rows = 4 sublane tiles, VB a multiple
 of 128), so the chip converts nothing: compiled for a v5e at 2^24
 slots and 49,152 keys the insert takes 28 MB of temporaries, where
@@ -30,7 +41,8 @@ Membership and insert happen in one bounded probe loop; claim conflicts
 (equal keys or distinct keys hashing to one bucket) are serialised by a
 hashed per-bucket min-index reservation — at most one contender wins a
 bucket per iteration, which is what makes the whole-column write
-race-free — so no sort of the batch is needed.
+race-free and a block's second read of its columns the first one's —
+so no sort of the batch is needed.
 After ~2 full-batch iterations only deep bucket chains remain; those are
 compacted into a small tail so late iterations stop re-scanning the
 whole batch (the measured high-load pathology in round 3).
@@ -71,7 +83,7 @@ import numpy as np
 
 __all__ = ["BKT", "MAXU32", "table_shape", "empty_table", "with_root",
            "sanitize_keys", "host_sanitize_key", "host_home_slot",
-           "host_occupied",
+           "host_occupied", "block_width", "compact", "write_in_blocks",
            "insert", "insert_jnp", "pallas_insert", "pallas_mode",
            "force_jnp", "dispatch_site_program", "build_table"]
 
@@ -183,39 +195,111 @@ def build_table(cap: int, keys) -> Tuple[jnp.ndarray, int, int]:
             int(np.asarray(jnp.sum(unres))))
 
 
+def block_width(n: int) -> int:
+    """``K``: how many rows ONE write of a batch of ``n`` holds — an
+    eighth of the batch: the tail's width, the table scatter's block
+    (of the full batch and, an eighth again, of the tail) and the
+    sharded engine's frontier-append block (one compaction shape in
+    the tree).  A batch no wider than ``K`` (``n`` <= 256) is its own
+    block."""
+    return max(n // 8, min(256, n))
+
+
+def compact(mask: jnp.ndarray, K: int) -> Tuple[jnp.ndarray, jnp.ndarray]:
+    """Positions of the True rows of ``mask`` [n], lowest first, as
+    whole blocks of ``K``: ``(idx [ceil(n / K) * K] int32, count)``.
+    Entries past ``count`` read ``n``."""
+    n = mask.shape[0]
+    size = -(-n // K) * K
+    rank = jnp.cumsum(mask.astype(jnp.int32))
+    idx = jnp.full((size,), n, jnp.int32).at[
+        jnp.where(mask, rank - 1, size)].set(
+            jnp.arange(n, dtype=jnp.int32), mode="drop")
+    return idx, rank[-1]
+
+
+def write_in_blocks(mask: jnp.ndarray, write, bufs):
+    """WRITE NARROW: hand ``write`` the True rows of ``mask`` [n], lowest
+    first, ``K = block_width(n)`` a block, as many blocks as they fill
+    (a ``while_loop``: the count is the data's).  ``write(bufs, first,
+    at, live)`` returns the new ``bufs``; ``at`` [K] are the block's
+    rows (clipped), ``live`` which of them are real, ``first`` the rank
+    of its first row among the True rows.  Returns ``(bufs, count,
+    blocks)``: True rows, blocks written.  The one place the visited
+    table's scatter and the sharded engine's frontier append share: the
+    chip pays a scatter per INDEX it is handed, written or dropped, so
+    neither hands it the rows that write nothing."""
+    n = mask.shape[0]
+    K = block_width(n)
+    idx, count = compact(mask, K)
+    blocks = (count + (K - 1)) // K
+
+    def block(st):
+        b, bufs = st
+        at = jax.lax.dynamic_slice(idx, (b * K,), (K,))
+        return b + 1, write(bufs, b * K, jnp.minimum(at, n - 1), at < n)
+
+    _, bufs = jax.lax.while_loop(
+        lambda st: st[0] < blocks, block, (jnp.int32(0), bufs))
+    return bufs, count, blocks
+
+
+def _claimed(cols, keys_t):
+    """Gathered bucket columns [ROWS, k] with key ``i`` ([4, k]) written
+    into the first empty slot of column ``i``."""
+    bkt = cols.reshape(BKT, 4, -1)
+    first_empty = jnp.argmax(jnp.all(bkt == MAXU32, axis=1), axis=0)
+    return jnp.where(jnp.arange(BKT)[:, None, None] == first_empty,
+                     keys_t, bkt).reshape(cols.shape)
+
+
 def _probe_iter(table, keys_t, bkt_i, ps, unres, idx, RT, batch_n):
     """One probe iteration over any batch (keys_t = the keys, [4, n];
     idx = each row's identity for reservation tie-breaks; rows with
-    unres=False are inert).  Gathers each key's whole bucket column,
-    resolves membership across its BKT slots, and lets the
-    minimum-index contender of each bucket claim the first empty slot
-    and write the column back; losers re-read the same bucket next
-    iteration, full buckets advance by the key's double-hash step."""
+    unres=False are inert).  PROBE WIDE: gathers each key's whole
+    bucket column, resolves membership across its BKT slots, and lets
+    the minimum-index contender of each bucket claim the first empty
+    slot; losers re-read the same bucket next iteration, full buckets
+    advance by the key's double-hash step.  WRITE NARROW: only the
+    winners' columns are scattered back, ``K`` (:func:`block_width` of
+    this batch: an eighth of it) a block, lowest index first, as many
+    blocks as the winners fill — the chip pays a scatter per INDEX
+    handed to it, written or dropped, and in a deep level nine keys in
+    ten win nothing, in the tail's buffer more.  A batch no wider than
+    ``K`` (a small probe) is its own block.  Returns the blocks
+    scattered last."""
     VB = table.shape[1]
+    n = bkt_i.shape[0]
     cols = table[:, bkt_i]
     bkt = cols.reshape(BKT, 4, -1)
     eq = jnp.any(jnp.all(bkt == keys_t, axis=1), axis=0)
-    empty = jnp.all(bkt == MAXU32, axis=1)
-    has_empty = jnp.any(empty, axis=0)
-    first_empty = jnp.argmax(empty, axis=0)
+    has_empty = jnp.any(jnp.all(bkt == MAXU32, axis=1), axis=0)
     want = unres & ~eq & has_empty
     rcell = bkt_i & (RT - 1)
     res = jnp.full((RT + 1,), batch_n, jnp.int32).at[
         jnp.where(want, rcell, RT)].min(idx)
     winner = want & (res[rcell] == idx)
-    new = jnp.where(jnp.arange(BKT)[:, None, None] == first_empty,
-                    keys_t, bkt).reshape(cols.shape)
-    table = table.at[:, jnp.where(winner, bkt_i, VB)].set(
-        new, mode="drop")
+    if n <= block_width(n):
+        blocks = jnp.int32(1)
+        table = table.at[:, jnp.where(winner, bkt_i, VB)].set(
+            _claimed(cols, keys_t), mode="drop")
+    else:
+        # At most one winner a bucket, so a block's columns are as the
+        # gather above read them whatever the blocks before it wrote.
+        def write(tbl, _, at, won):
+            col = bkt_i[at]
+            return tbl.at[:, jnp.where(won, col, VB)].set(
+                _claimed(tbl[:, col], keys_t[:, at]), mode="drop")
+
+        table, _, blocks = write_in_blocks(winner, write, table)
     newly = eq | winner
     nb = (bkt_i.astype(jnp.uint32) + ps).astype(jnp.int32) & (VB - 1)
     bkt_i = jnp.where(unres & ~newly & ~has_empty, nb, bkt_i)
-    return table, bkt_i, newly & unres, winner & unres
+    return table, bkt_i, newly & unres, winner & unres, blocks
 
 
 def insert_jnp(table: jnp.ndarray, keys: jnp.ndarray, valid: jnp.ndarray,
-               max_iters: int = 64,
-               ) -> Tuple[jnp.ndarray, jnp.ndarray, jnp.ndarray]:
+               max_iters: int = 64, count_blocks: bool = False):
     """Membership + insert of a key batch in one bounded probe — the
     pure-jnp reference implementation (the Pallas kernel's parity
     oracle AND the CPU/interpret fallback; :func:`insert` dispatches).
@@ -231,6 +315,8 @@ def insert_jnp(table: jnp.ndarray, keys: jnp.ndarray, valid: jnp.ndarray,
     resolved — the table-full overflow case.  Callers MUST treat
     unresolved keys as fresh (sound re-exploration, never a silent
     drop) and surface ``sum(unresolved)`` as a visible overflow flag.
+    With ``count_blocks`` a fourth value: the write blocks the probe
+    scattered (:func:`_probe_iter`), an int32 scalar.
     Pure jnp — usable under jit, inside shard_map bodies, and inside
     the Pallas kernel body.
     """
@@ -252,11 +338,11 @@ def insert_jnp(table: jnp.ndarray, keys: jnp.ndarray, valid: jnp.ndarray,
         RT = 1 << max((n * 2 - 1).bit_length(), 10)
         # Tail threshold: once fewer than T keys remain unresolved, compact
         # them so late iterations stop re-scanning the whole batch.
-        T = max(n // 8, min(256, n))
+        T = block_width(n)
         ridx = jnp.arange(n, dtype=jnp.int32)
 
         def full_cond(st):
-            _, _, resolved, _, it = st
+            _, _, resolved, _, it, _ = st
             # ONE guaranteed full-batch iteration: below 50% table load the
             # first bucket read resolves all but the full-bucket collisions,
             # which fit the tail buffer.
@@ -264,17 +350,19 @@ def insert_jnp(table: jnp.ndarray, keys: jnp.ndarray, valid: jnp.ndarray,
                 it < max_iters) & jnp.any(~resolved)
 
         def full_body(st):
-            tbl, bkt_i, resolved, ins, it = st
-            tbl, bkt_i, newly, winner = _probe_iter(
+            tbl, bkt_i, resolved, ins, it, wb = st
+            tbl, bkt_i, newly, winner, blocks = _probe_iter(
                 tbl, keys_t, bkt_i, pstep, ~resolved, ridx, RT, n)
-            return tbl, bkt_i, resolved | newly, ins | winner, it + 1
+            return (tbl, bkt_i, resolved | newly, ins | winner, it + 1,
+                    wb + blocks)
 
-        table, bkt_i, resolved, inserted, _ = jax.lax.while_loop(
+        table, bkt_i, resolved, inserted, _, wb = jax.lax.while_loop(
             full_cond, full_body,
-            (table, slot0, ~valid, jnp.zeros(n, bool), jnp.int32(0)))
+            (table, slot0, ~valid, jnp.zeros(n, bool), jnp.int32(0),
+             jnp.int32(0)))
 
         # ---- tail phase: compact the unresolved few into [T] slots.
-        tail_idx = jnp.nonzero(~resolved, size=T, fill_value=n)[0]
+        tail_idx = compact(~resolved, T)[0][:T]
         tclip = tail_idx.clip(0, n - 1)
         tval = tail_idx < n
         t_keys_t = keys_t[:, tclip]
@@ -283,20 +371,23 @@ def insert_jnp(table: jnp.ndarray, keys: jnp.ndarray, valid: jnp.ndarray,
         t_id = jnp.arange(T, dtype=jnp.int32)
 
         def tail_cond(st):
-            _, _, t_unres, _, it = st
+            _, _, t_unres, _, it, _ = st
             return (it < max_iters) & jnp.any(t_unres)
 
         def tail_body(st):
-            tbl, tb, t_unres, t_ins, it = st
-            tbl, tb, newly, winner = _probe_iter(
+            tbl, tb, t_unres, t_ins, it, wb = st
+            tbl, tb, newly, winner, blocks = _probe_iter(
                 tbl, t_keys_t, tb, t_ps, t_unres, t_id, RT, n)
-            return tbl, tb, t_unres & ~newly, t_ins | winner, it + 1
+            return (tbl, tb, t_unres & ~newly, t_ins | winner, it + 1,
+                    wb + blocks)
 
-        table, _, t_unres, t_ins, _ = jax.lax.while_loop(
+        table, _, t_unres, t_ins, _, wb = jax.lax.while_loop(
             tail_cond, tail_body,
-            (table, t_bkt, tval, jnp.zeros(T, bool), jnp.int32(0)))
+            (table, t_bkt, tval, jnp.zeros(T, bool), jnp.int32(0), wb))
         resolved = resolved.at[tclip].max(tval & ~t_unres)
         inserted = inserted.at[tclip].max(t_ins & tval)
+        if count_blocks:
+            return table, inserted, ~resolved, wb
         return table, inserted, ~resolved
 
 
@@ -345,8 +436,7 @@ def _pallas_interpret() -> Optional[bool]:
 
 def pallas_insert(table: jnp.ndarray, keys: jnp.ndarray,
                   valid: jnp.ndarray, max_iters: int = 64, *,
-                  interpret: bool,
-                  ) -> Tuple[jnp.ndarray, jnp.ndarray, jnp.ndarray]:
+                  interpret: bool, count_blocks: bool = False):
     """:func:`insert_jnp` as one Pallas kernel: table + key batch load
     into VMEM, the bounded probe runs on-chip, and the table writes
     back through an input/output alias (the in-place update the
@@ -360,13 +450,14 @@ def pallas_insert(table: jnp.ndarray, keys: jnp.ndarray,
     n = keys.shape[0]
 
     def kernel(table_ref, keys_ref, valid_ref, out_table_ref,
-               ins_ref, unres_ref):
-        tbl, ins, unres = insert_jnp(
+               ins_ref, unres_ref, blocks_ref):
+        tbl, ins, unres, wb = insert_jnp(
             table_ref[...], keys_ref[...], valid_ref[...] != 0,
-            max_iters)
+            max_iters, count_blocks=True)
         out_table_ref[...] = tbl
         ins_ref[...] = ins.astype(jnp.int32)
         unres_ref[...] = unres.astype(jnp.int32)
+        blocks_ref[...] = wb[None]
 
     kwargs = {}
     if not interpret:
@@ -377,33 +468,35 @@ def pallas_insert(table: jnp.ndarray, keys: jnp.ndarray,
 
         vmem = pl.BlockSpec(memory_space=pltpu.VMEM)
         kwargs = dict(in_specs=[vmem, vmem, vmem],
-                      out_specs=(vmem, vmem, vmem))
-    table2, ins, unres = pl.pallas_call(
+                      out_specs=(vmem, vmem, vmem, vmem))
+    table2, ins, unres, wb = pl.pallas_call(
         kernel,
         out_shape=(jax.ShapeDtypeStruct(table.shape, table.dtype),
                    jax.ShapeDtypeStruct((n,), jnp.int32),
-                   jax.ShapeDtypeStruct((n,), jnp.int32)),
+                   jax.ShapeDtypeStruct((n,), jnp.int32),
+                   jax.ShapeDtypeStruct((1,), jnp.int32)),
         input_output_aliases={0: 0},
         interpret=bool(interpret), **kwargs)(
             table, keys, valid.astype(jnp.int32))
+    if count_blocks:
+        return table2, ins != 0, unres != 0, wb[0]
     return table2, ins != 0, unres != 0
 
 
 def insert(table: jnp.ndarray, keys: jnp.ndarray, valid: jnp.ndarray,
-           max_iters: int = 64,
-           ) -> Tuple[jnp.ndarray, jnp.ndarray, jnp.ndarray]:
+           max_iters: int = 64, count_blocks: bool = False):
     """THE probe/insert entry point both engines trace:
     :func:`insert_jnp` by default on every backend; the Pallas kernel
     only where :func:`pallas_mode` asks for it by name (``on`` compiles
     or raises, ``interpret`` interprets).  Contract and return values
     are identical across paths (see ``insert_jnp``)."""
     if _FORCE_JNP:
-        return insert_jnp(table, keys, valid, max_iters)
+        return insert_jnp(table, keys, valid, max_iters, count_blocks)
     interp = _pallas_interpret()
     if interp is None:
-        return insert_jnp(table, keys, valid, max_iters)
+        return insert_jnp(table, keys, valid, max_iters, count_blocks)
     return pallas_insert(table, keys, valid, max_iters,
-                         interpret=interp)
+                         interpret=interp, count_blocks=count_blocks)
 
 
 def dispatch_site_program(cap: int, batch: int):

@@ -149,6 +149,20 @@ def _table_sized_in_loops(text, limit=1 << 21):
     return big
 
 
+def _scatter_widths(text, shape):
+    """Update indices of every ``scatter`` in an optimised HLO module
+    whose operand has ``shape`` (``"u32[32,2097152]"``): the elements
+    of its indices operand (one index a row or column written)."""
+    shapes = dict(re.findall(r"%([\w.\-]+) = (\w+\[[\d,]*\])", text))
+    widths = []
+    for indices in re.findall(
+            r"= " + re.escape(shape) + r"\S* scatter\(%[\w.\-]+, "
+            r"%([\w.\-]+),", text):
+        dims = re.search(r"\[([\d,]*)\]", shapes[indices]).group(1)
+        widths.append(int(np.prod([int(d) for d in dims.split(",") if d])))
+    return widths
+
+
 @pytest.mark.parametrize("nested", [False, True],
                          ids=["alone", "in-a-16-step-loop"])
 def test_flagship_insert_converts_no_table(one_chip, nested, monkeypatch):
@@ -158,7 +172,10 @@ def test_flagship_insert_converts_no_table(one_chip, nested, monkeypatch):
     relayout loop, a reshape and a transposing copy of the 268 MB
     table per probe iteration) and, inside the loops, no table-sized
     instruction but the scatter that updates the carry in place —
-    alone and as the superstep nests it, in an outer loop."""
+    alone and as the superstep nests it, in an outer loop.  And it
+    writes narrow: no scatter on the table is handed more than one
+    block of ``K`` = 6,144 columns (the chip pays per index, written or
+    dropped: 49,152 of them cost 5 ms a chunk step until PR 34)."""
     monkeypatch.delenv("DSLABS_VISITED_PALLAS", raising=False)
 
     def insert16(t, k, v):
@@ -173,6 +190,9 @@ def test_flagship_insert_converts_no_table(one_chip, nested, monkeypatch):
     compiled = fn.lower(*_insert_args(1 << 24, 49152, one_chip)).compile()
     assert compiled.memory_analysis().temp_size_in_bytes < 64 << 20
     assert _table_sized_in_loops(compiled.as_text()) == []
+    widths = _scatter_widths(compiled.as_text(), "u32[32,2097152]")
+    assert visited.block_width(49152) == 6144
+    assert widths and max(widths) <= 6144, widths
 
 
 def test_pallas_insert_is_refused_by_mosaic(one_chip):
@@ -379,7 +399,10 @@ def _flagship_search(mesh, chunk):
 def test_flagship_programs_compile(topo, n_devices):
     """The flagship protocol at chip_smoke's caps: superstep, promote,
     root init and the bare ``_expand_chunk`` — carry plus temporaries
-    must fit one chip's HBM.  Minutes of compile: ``-m slow``."""
+    must fit one chip's HBM, no scatter on the visited table or on
+    ``nxt`` takes more than one write block of indices, and nothing in
+    the superstep's loops but those scatters is as large as the table.
+    Minutes of compile: ``-m slow``."""
     search = _flagship_search(_mesh(topo, n_devices),
                               chip_smoke.FLAGSHIP["chunk"])
     assert search.lanes == 842
@@ -394,8 +417,20 @@ def test_flagship_programs_compile(topo, n_devices):
                                  sharding=sh),
             jax.ShapeDtypeStruct((c,), jnp.bool_, sharding=sh)).compile()
     _fits(exes)
-    assert ("all-to-all" in exes["superstep"].as_text()) == (
-        n_devices > 1)
+    text = exes["superstep"].as_text()
+    assert ("all-to-all" in text) == (n_devices > 1)
+    # Write narrow: a chunk step hands the table's scatter and the
+    # frontier append one block of K indices at a time, of the 49,152
+    # slots one chip fills and the 98,312 four receive.
+    k = visited.block_width(49152 if n_devices == 1 else 98312)
+    assert k == (6144 if n_devices == 1 else 12289)
+    table = _scatter_widths(text, "u32[32,2097152]")
+    nxt = _scatter_widths(
+        text, f"s32[{chip_smoke.FLAGSHIP['frontier_cap'] + 1},"
+        f"{search.plane}]")
+    assert table and max(table) <= k, table
+    assert nxt and max(nxt) <= k, nxt
+    assert _table_sized_in_loops(text, limit=1 << 26) == []
 
 
 @pytest.mark.slow

@@ -189,26 +189,191 @@ def _invalid_batches():
     return 1 << 10, [(batch, valid), (batch, ~valid)]
 
 
+def _parallel_model(table, keys, valid, max_iters=64):
+    """The insert's PARALLEL semantics as plain numpy, every iteration
+    at the batch's full width and every winner written on its own: what
+    the table must hold SLOT FOR SLOT however the device issues the
+    write (a whole-batch scatter until PR 34; blocks of
+    ``visited.block_width`` winners since).  Returns ``(table, inserted,
+    unresolved, write_blocks)``."""
+    table = np.array(table)
+    vb, n = table.shape[1], len(keys)
+    keys = np.array([visited_mod.host_sanitize_key(k) if ok else k
+                     for k, ok in zip(keys, valid)], np.uint32)
+    at = (keys[:, 2] & np.uint32(vb - 1)).astype(np.int64)
+    step = (keys[:, 1] | np.uint32(1)).astype(np.int64)
+    cells = 1 << max((n * 2 - 1).bit_length(), 10)
+    width = visited_mod.block_width(n)
+    unres, ins = np.array(valid, bool), np.zeros(n, bool)
+    full = tail = blocks = 0
+    while unres.any():
+        if tail == 0 and full < max_iters and (
+                full < 1 or unres.sum() > width):
+            full += 1
+            batch = n
+        elif tail < max_iters:
+            tail += 1
+            batch = width
+        else:
+            break
+        slots = table[:, at].reshape(BKT, 4, n)
+        eq = (slots == keys.T[None]).all(axis=1).any(axis=0)
+        empty = (slots == visited_mod.MAXU32).all(axis=1)
+        want = unres & ~eq & empty.any(axis=0)
+        cell = at & (cells - 1)
+        first = np.full(cells, n)
+        np.minimum.at(first, cell[want], np.nonzero(want)[0])
+        winner = want & (first[cell] == np.arange(n))
+        for i in np.nonzero(winner)[0]:
+            slot = int(empty[:, i].argmax())
+            table[slot * 4:slot * 4 + 4, at[i]] = keys[i]
+        block = visited_mod.block_width(batch)
+        blocks += 1 if batch <= block else -(-int(winner.sum()) // block)
+        ins |= winner
+        at = np.where(unres & ~eq & ~winner & ~empty.any(axis=0),
+                      (at + step) & (vb - 1), at)
+        unres &= ~(eq | winner)
+    return table, ins, unres, blocks
+
+
+# Block-edge batches: 2,048 keys, so a write block holds K = 256
+# winners (visited.block_width) and the table's 4,096 buckets are the
+# reservation's 4,096 cells — keys of distinct home buckets all win in
+# the first iteration.
+_EDGE_N, _EDGE_CAP = 2048, 1 << 15
+
+
+def _own_bucket_keys(n, seed, first=0):
+    """n distinct keys, key i at home in bucket ``first + i``."""
+    keys = _rand_keys(n, seed)
+    vb = _EDGE_CAP // BKT
+    keys[:, 2] = (keys[:, 2] & ~np.uint32(vb - 1)) | (
+        np.arange(first, first + n, dtype=np.uint32))
+    return keys
+
+
+def _known_then(fresh, at_end=True):
+    """A first batch of 2,048 keys, then the same batch with ``fresh``
+    of its rows replaced by new keys (in the last positions, or spread
+    from the first): exactly ``fresh`` winners in one iteration."""
+    base = _own_bucket_keys(_EDGE_N, 11)
+    again = base.copy()
+    new = _own_bucket_keys(fresh, 12, first=_EDGE_N)
+    rows = (np.arange(_EDGE_N - fresh, _EDGE_N) if at_end
+            else np.arange(fresh) * (_EDGE_N // max(fresh, 1)))
+    again[rows] = new
+    return _EDGE_CAP, [(base, None), (again, None)]
+
+
+def _zero_winners_batches():
+    return _known_then(0)
+
+
+def _exactly_k_winners_batches():
+    return _known_then(256, at_end=False)
+
+
+def _k_plus_one_winners_batches():
+    return _known_then(257, at_end=False)
+
+
+def _every_key_fresh_batches():
+    return _EDGE_CAP, [(_own_bucket_keys(_EDGE_N, 13), None),
+                       (_rand_keys(_EDGE_N, 14), None)]
+
+
+def _winners_last_batches():
+    return _known_then(300)
+
+
+def _narrow_batches():
+    """n < 256: the batch is its own block."""
+    return 1 << 10, [(_rand_keys(200, 15), None),
+                     (_rand_keys(9, 16), None)]
+
+
+def _tail_winners_batches():
+    """Half the batch crowded into 40 buckets: the first iteration
+    leaves under K keys unresolved, each still to win a slot, and the
+    tail's iterations write them."""
+    cap = 1 << 13
+    keys = _rand_keys(_EDGE_N, 17)
+    keys[::2] = _rand_keys(_EDGE_N, 18, cap, buckets=40)[::2]
+    return cap, [(keys, None), (keys[::-1].copy(), None)]
+
+
+def _tail_blocks_batches():
+    """4,096 keys (a tail of 512, written 256 a block): 400 pairs of
+    fresh keys share a home bucket, so the first iteration seats one of
+    each pair and the tail's first iteration the other 400 — two
+    blocks."""
+    known = _own_bucket_keys(3296, 19)
+    pairs = np.concatenate([_own_bucket_keys(400, 20, first=3296),
+                            _own_bucket_keys(400, 21, first=3296)])
+    return _EDGE_CAP, [(known, None),
+                       (np.concatenate([known, pairs]), None)]
+
+
 @pytest.mark.parametrize("make", [
     _dup_batches, _crowded_batches, _reinsert_batches,
     _overflow_full_batches, _invalid_batches,
+    _zero_winners_batches, _exactly_k_winners_batches,
+    _k_plus_one_winners_batches, _every_key_fresh_batches,
+    _winners_last_batches, _narrow_batches, _tail_winners_batches,
+    _tail_blocks_batches,
 ], ids=["in-batch-duplicates", "crowded-buckets", "re-insert",
-        "overflow-full-table", "invalid-rows-inert"])
+        "overflow-full-table", "invalid-rows-inert",
+        "zero-winners", "exactly-K-winners", "K+1-winners",
+        "every-key-fresh", "winners-in-the-last-positions",
+        "batch-narrower-than-K", "tail-entered-with-winners-left",
+        "tail-writes-two-blocks"])
 def test_insert_matches_sequential_host_model(make):
     """``inserted``, ``unresolved`` and the table's key set against the
-    host model, batch after batch on one table."""
+    sequential host model, and the table SLOT FOR SLOT with the blocks
+    it was written in against the parallel one, batch after batch on
+    one table."""
     cap, batches = make()
     table, model = visited_mod.empty_table(cap), _HostTable(cap)
     for keys, valid in batches:
         valid = np.ones(len(keys), bool) if valid is None else valid
-        table, ins, unres = visited_mod.insert(
-            table, jnp.asarray(keys), jnp.asarray(valid))
+        want_table, par_ins, par_unres, want_blocks = _parallel_model(
+            table, keys, valid)
+        table, ins, unres, blocks = visited_mod.insert(
+            table, jnp.asarray(keys), jnp.asarray(valid),
+            count_blocks=True)
         want_ins, want_unres = model.insert(keys, valid)
         assert np.array_equal(np.asarray(ins), want_ins)
         assert np.array_equal(np.asarray(unres), want_unres)
+        assert np.array_equal(par_ins, want_ins)
+        assert np.array_equal(par_unres, want_unres)
+        assert np.array_equal(np.asarray(table), want_table)
+        assert int(blocks) == want_blocks
         got = visited_mod.host_occupied(table)
         assert sorted(map(tuple, got.tolist())) == model.keys()
     assert table.shape == visited_mod.table_shape(cap)
+
+
+@pytest.mark.parametrize("make,blocks", [
+    (_zero_winners_batches, [8, 0]),
+    (_exactly_k_winners_batches, [8, 1]),
+    (_k_plus_one_winners_batches, [8, 2]),
+    (_winners_last_batches, [8, 2]),
+    (_tail_blocks_batches, [8, 1 + 2]),
+], ids=["zero-winners", "exactly-K", "K+1", "last-positions",
+        "tail-of-two-blocks"])
+def test_write_blocks_follow_the_winners(make, blocks):
+    """An iteration scatters ``ceil(winners / K)`` blocks of its own
+    batch's ``K``: none where nothing won, 8 where all 2,048 keys did,
+    two in a tail whose 512 places hold 400 winners."""
+    cap, batches = make()
+    table = visited_mod.empty_table(cap)
+    for (keys, _), want in zip(batches, blocks):
+        table, ins, unres, got = visited_mod.insert(
+            table, jnp.asarray(keys), jnp.ones((len(keys),), bool),
+            count_blocks=True)
+        assert int(got) == want
+        assert int(ins.sum()) <= want * visited_mod.block_width(len(keys))
+        assert not np.asarray(unres).any()
 
 
 def test_overflow_unresolved_are_exactly_the_keys_that_did_not_fit():

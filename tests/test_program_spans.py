@@ -204,11 +204,39 @@ def test_level_phases_carry_the_levels_own_counters(sharded_run):
         note = levels[lv["depth"]]
         assert note["explored0"] == before
         assert (note["explored"], note["unique"], note["chunks"],
-                note["next_frontier"]) == (
+                note["write_blocks"], note["next_frontier"]) == (
             lv["explored"], lv["unique"], lv["chunks"],
-            lv["next_frontier"])
+            lv["write_blocks"], lv["next_frontier"])
         before = lv["explored"]
     assert before == out.states_explored
+    # A level that found something wrote it: at least a table block
+    # and an append block.
+    assert all(lv["write_blocks"] >= 2 for lv in out.levels
+               if lv["next_frontier"])
+
+
+@pytest.mark.parametrize("level,want", [
+    ({"chunks": 54, "write_blocks": 247}, 247 / 54),
+    ({"chunks": 54}, None),
+    ({"chunks": 0, "write_blocks": 0}, None),
+    (None, None),
+], ids=["counted", "a-program-from-before-PR-34", "no-chunk-step",
+        "no-traced-level"])
+def test_write_blocks_reader_reads_the_level_span(monkeypatch, level, want):
+    """``benchmark/layer_metrics/write_blocks_per_step.deep.py`` divides
+    the two fields the level's span closes with, and reports nothing
+    (no raise) where a program wrote none — the parent side of this
+    PR's check runs it on such a program."""
+    import os
+
+    from benchmark.harness import manifest, program_spans
+
+    monkeypatch.setattr(program_spans, "traced_level", lambda run: level)
+    reader = manifest.load_module(os.path.join(
+        os.path.dirname(os.path.dirname(os.path.abspath(__file__))),
+        "benchmark", "layer_metrics", "write_blocks_per_step.deep.py"),
+        "write_blocks_per_step.deep")
+    assert reader.compute({}) == want
 
 
 def test_dispatch_annotations_are_the_recorders_own_spans(lab_call,

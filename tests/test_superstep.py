@@ -188,7 +188,8 @@ def test_retired_options_are_gone():
 
 def test_level_records_on_outcome():
     """Satellite: structured per-level throughput records ride the
-    outcome (depth/chunks/wall/explored/unique/next_frontier) — the
+    outcome (depth/chunks/write_blocks/wall/explored/unique/
+    next_frontier) — the
     bench emits them as its throughput series."""
     proto = _pruned_pingpong()
     mesh = make_mesh(8)
@@ -198,8 +199,8 @@ def test_level_records_on_outcome():
     assert out.levels, "SearchOutcome.levels must carry per-level records"
     for i, rec in enumerate(out.levels):
         assert rec["depth"] == i + 1
-        for key in ("chunks", "wall", "explored", "unique",
-                    "next_frontier"):
+        for key in ("chunks", "write_blocks", "wall", "explored",
+                    "unique", "next_frontier"):
             assert key in rec, rec
         assert rec["chunks"] >= 1
     # Cumulative counters are monotone; the final record's totals match
@@ -208,6 +209,129 @@ def test_level_records_on_outcome():
     assert uniq == sorted(uniq)
     assert out.levels[-1]["explored"] == out.states_explored
     assert out.levels[-1]["unique"] == out.unique_states
+
+
+# ------------------------------------------------------- write blocks
+
+def _tree_protocol(branch=6, depth=4):
+    """A tree: delivering standing message ``i`` in state ``v`` gives
+    ``v * branch + i + 1`` down to ``depth``, so every successor of a
+    first visit is a state nobody has seen and a level numbers its
+    states ``lo .. hi`` in grid order — the traffic of a search's first
+    levels, at a width where one chunk step appends several blocks."""
+    import jax.numpy as jnp
+    import numpy as np
+
+    from dslabs_tpu.tpu.engine import SENTINEL, TensorProtocol
+
+    inner = sum(branch ** d for d in range(depth))
+    no_send = jnp.full((1, 1), SENTINEL, jnp.int32)
+    no_set = jnp.full((1, 2), SENTINEL, jnp.int32)
+
+    def step_message(nodes, msg):
+        v = nodes[0]
+        return (nodes.at[0].set(jnp.where(
+            v < inner, v * branch + msg[0] + 1, v)), no_send, no_set)
+
+    return TensorProtocol(
+        name=f"tree-b{branch}-d{depth}", n_nodes=1, node_width=1,
+        msg_width=1, timer_width=1, net_cap=branch, timer_cap=1,
+        max_sends=1, max_sets=1,
+        init_nodes=lambda: np.zeros(1, np.int32),
+        init_messages=lambda: np.arange(branch, dtype=np.int32)[:, None],
+        init_timers=lambda: np.zeros((0, 2), np.int32),
+        step_message=step_message,
+        step_timer=lambda nodes, node_idx, timer: (nodes, no_send, no_set),
+        msg_dest=lambda msg: jnp.int32(0))
+
+
+def _run_watched(proto, n_devices, **kw):
+    """Run the sharded engine; returns ``(outcome, after)`` where
+    ``after`` holds, for every superstep dispatch, host copies of each
+    device's ``nxt[:nxt_n]`` (and ``tmeta[:nxt_n]``) and of the visited
+    table."""
+    import numpy as np
+
+    search = ShardedTensorSearch(proto, make_mesh(n_devices), **kw)
+    inner, after = search._superstep_call, []
+
+    def watched(carry, budget):
+        carry, stats = inner(carry, budget)
+        counts = np.asarray(carry["nxt_n"]).reshape(-1)
+        kept = {"visited": np.asarray(carry["visited"])}
+        for name in ("nxt", "tmeta"):
+            if name in carry:
+                buf = np.asarray(carry[name]).reshape(
+                    n_devices, search.f_cap + 1, -1)
+                kept[name] = [buf[d, :min(int(c), search.f_cap)]
+                              for d, c in enumerate(counts)]
+        after.append(kept)
+        return carry, stats
+
+    search._superstep_call = watched
+    return search.run(), after, search
+
+
+@pytest.mark.parametrize("record_trace", [False, True],
+                         ids=["plain", "record-trace"])
+@pytest.mark.parametrize("n_devices,chunk,frontier_cap,strict", [
+    (1, 256, 1 << 11, True), (2, 256, 1 << 11, True),
+    (1, 384, 768, False)],
+    ids=["one-device", "two-devices", "a-block-crosses-frontier_cap"])
+def test_append_blocks_equal_one_whole_batch_scatter(
+        monkeypatch, n_devices, chunk, frontier_cap, strict, record_trace):
+    """A chunk step whose fresh successors fill several write blocks
+    (the tree's depth 4: 1,296 fresh rows of 216; ``K`` = 256 of 1,792
+    grid slots, 448 of the 3,588 two devices receive, 336 of 2,688 at
+    chunk 384, where the third block lies across row 768) leaves
+    ``nxt[:nxt_n]``, ``tmeta[:nxt_n]`` and the visited table, dispatch
+    after dispatch, exactly as ONE whole-batch scatter does (the engine
+    until PR 34: ``visited.block_width`` widened to the batch), rows
+    past ``frontier_cap`` dropped and counted as before; where nothing
+    drops, counts equal the host-dedup reference's."""
+    import numpy as np
+
+    from dslabs_tpu.tpu import visited as visited_mod
+
+    proto = _tree_protocol()
+    kw = dict(chunk_per_device=chunk, frontier_cap=frontier_cap,
+              visited_cap=1 << 14, strict=strict,
+              record_trace=record_trace)
+    out, after, search = _run_watched(proto, n_devices, **kw)
+    with monkeypatch.context() as m:
+        m.setattr(visited_mod, "block_width", lambda n: n)
+        whole, whole_after, _ = _run_watched(proto, n_devices, **kw)
+    _assert_exact(out, whole)
+    assert len(after) == len(whole_after) >= 5
+    for got, want in zip(after, whole_after):
+        assert np.array_equal(got["visited"], want["visited"])
+        for name in ("nxt", "tmeta")[:1 + record_trace]:
+            for g, w in zip(got[name], want[name]):
+                assert np.array_equal(g, w)
+    # Several blocks a step where it matters, one where the batch is
+    # the block.
+    deep, deep_whole = out.levels[3], whole.levels[3]
+    assert deep["chunks"] == deep_whole["chunks"] == 1
+    assert search.f_cap == frontier_cap
+    assert deep["write_blocks"] >= 2 * (
+        1296 // n_devices // visited_mod.block_width(
+            chunk * 7 if n_devices == 1 else 3588))
+    assert deep_whole["write_blocks"] < deep["write_blocks"]
+    if n_devices == 1:
+        # Grid order IS level order on one device: the rows that landed
+        # are the level's first, in order.
+        rows = after[3]["nxt"][0]
+        if search._pk is not None:
+            rows = search._pk.unpack_np(rows)
+        assert rows[:, 0].tolist() == list(
+            range(259, 259 + min(1296, frontier_cap)))
+        assert out.dropped == max(0, 1296 - frontier_cap)
+    if strict:
+        # (Past its frontier cap the host reference stops with
+        # CAPACITY_EXHAUSTED instead of truncating.)
+        _assert_exact(out, TensorSearch(
+            proto, chunk=chunk, frontier_cap=n_devices * frontier_cap,
+            visited_cap=1 << 14, use_host_visited=True).run())
 
 
 # ------------------------------------------------- mid-level time budget
