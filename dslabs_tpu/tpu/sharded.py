@@ -1870,7 +1870,8 @@ class ShardedTensorSearch(TensorSearch):
                 # Live depth for supervision heartbeats (tpu/warden.py).
                 self._current_depth = depth
                 with tel_mod.phase("search.level", depth=depth,
-                                   explored0=int(explored)) as lvl:
+                                   explored0=int(explored),
+                                   frontier0=int(max_n)) as lvl:
                     t_lvl = time.time()
                     # Final depth-limited level: count/check fresh successors
                     # without building the next frontier (it would never be

@@ -383,6 +383,51 @@ def test_lab4_staged_programs_compile(topo, one_chip, phase, attempt):
     assert "all-to-all" not in exes["superstep"].as_text()
 
 
+def test_shardkv_deep_programs_compile(topo):
+    """Lab 4's part 1 twin (the benchmark's ``shardkv-deep`` cell) as
+    its driver builds it, at the configuration's caps — a frontier of
+    6,815,744 rows a buffer (3.65 GB each at 536 bytes a row) and a
+    table of 2^25 slots: superstep, promote and root init compile for
+    one described chip and fit it.  The compiler's plan for the
+    superstep holds FOUR frontier-sized buffers (the carry's ``cur`` and
+    ``nxt`` and the two entry copies of ``nxt``, PERF.md section 7), so
+    its live bytes follow the rows: 14.61 GiB of 15.75 here, 9.46 at
+    2^22 rows, 13.58 at 6,291,456, 15.48 at 7,340,032 — what bounds
+    this cell's headroom for a faster program (the configuration's
+    ``sizing``).  And the dedup layer still writes narrow at these
+    shapes: no scatter on the table or on ``nxt`` is handed more than
+    one block of 6,144 indices.  Under a minute of compile (the program
+    is a fifth of Paxos' text)."""
+    from benchmark.drivers.timeboxed_bfs import build_protocol
+    from benchmark.harness import manifest
+
+    cell = manifest.load_cell(
+        os.path.dirname(os.path.dirname(os.path.abspath(__file__))),
+        "shardkv-deep")
+    eng = cell.config["engine"]
+    assert (eng["frontier_cap"], eng["visited_cap"]) == (6815744, 1 << 25)
+    search = ShardedTensorSearch(
+        build_protocol(cell.config["protocol"]),
+        _mesh(topo, 1), chunk_per_device=eng["chunk"],
+        frontier_cap=eng["frontier_cap"], visited_cap=eng["visited_cap"],
+        strict=True, ev_budget=tuple(eng["ev_budget"]))
+    assert (search.lanes, search.bytes_per_state) == (
+        cell.config["protocol"]["lanes"],
+        cell.config["protocol"]["packed_bytes_per_state"])
+    exes = _aot(search)
+    _fits(exes)
+    text = exes["superstep"].as_text()
+    assert "all-to-all" not in text
+    k = visited.block_width(eng["chunk"] * search._ev_slots)
+    assert k == 6144
+    table = _scatter_widths(text, f"u32[32,{eng['visited_cap'] // 8}]")
+    nxt = _scatter_widths(
+        text, f"s32[{eng['frontier_cap'] + 1},{search.plane}]")
+    assert table and max(table) <= k, table
+    assert nxt and max(nxt) <= k, nxt
+    assert _table_sized_in_loops(text, limit=1 << 27) == []
+
+
 def _flagship_search(mesh, chunk):
     from bench import _bench_protocol
 

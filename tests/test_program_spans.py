@@ -215,6 +215,16 @@ def test_level_phases_carry_the_levels_own_counters(sharded_run):
                if lv["next_frontier"])
 
 
+def _layer_reader(name):
+    import os
+
+    from benchmark.harness import manifest
+
+    return manifest.load_module(os.path.join(
+        os.path.dirname(os.path.dirname(os.path.abspath(__file__))),
+        "benchmark", "layer_metrics", name + ".py"), name)
+
+
 @pytest.mark.parametrize("level,want", [
     ({"chunks": 54, "write_blocks": 247}, 247 / 54),
     ({"chunks": 54}, None),
@@ -227,16 +237,72 @@ def test_write_blocks_reader_reads_the_level_span(monkeypatch, level, want):
     the two fields the level's span closes with, and reports nothing
     (no raise) where a program wrote none — the parent side of this
     PR's check runs it on such a program."""
-    import os
-
-    from benchmark.harness import manifest, program_spans
+    from benchmark.harness import program_spans
 
     monkeypatch.setattr(program_spans, "traced_level", lambda run: level)
-    reader = manifest.load_module(os.path.join(
-        os.path.dirname(os.path.dirname(os.path.abspath(__file__))),
-        "benchmark", "layer_metrics", "write_blocks_per_step.deep.py"),
-        "write_blocks_per_step.deep")
-    assert reader.compute({}) == want
+    assert _layer_reader("write_blocks_per_step.deep").compute({}) == want
+
+
+_ENGINE = {"engine": {"chunk": 1024, "ev_budget": [40, 8]}}
+
+
+@pytest.mark.parametrize("level,want", [
+    ({"chunks": 54, "frontier0": 55246}, 0.0),
+    ({"chunks": 126, "frontier0": 128545}, 0.0),
+    ({"chunks": 60, "frontier0": 55246}, 10.0),
+    ({"chunks": 54}, None),
+    ({"chunks": 0, "frontier0": 0}, None),
+    (None, None),
+], ids=["paxos-level-8", "shardkv-level-9", "six-chunks-re-stepped",
+        "a-program-from-before-PR-36", "no-chunk-step", "no-traced-level"])
+def test_event_resteps_reader_reads_the_level_span(monkeypatch, level, want):
+    """``benchmark/layer_metrics/event_resteps_pct.deep.py``: of the
+    level's chunk steps, the share beyond what its ``frontier0`` rows
+    need at the configuration's chunk — and nothing (no raise) from the
+    parent's span, which has no ``frontier0``."""
+    from benchmark.harness import program_spans
+
+    monkeypatch.setattr(program_spans, "traced_level", lambda run: level)
+    got = _layer_reader("event_resteps_pct.deep").compute(
+        {"config": _ENGINE, "chips": 1})
+    assert got == pytest.approx(want)       # a number, or None
+
+
+@pytest.mark.parametrize("level,chips,want", [
+    ({"chunks": 54, "explored": 1510933, "explored0": 317196}, 1,
+     100 * 1193737 / (54 * 49152)),
+    ({"chunks": 126, "explored": 3263205, "explored0": 986734}, 1,
+     100 * 2276471 / (126 * 49152)),
+    ({"chunks": 14, "explored": 1510933, "explored0": 317196}, 4,
+     100 * 1193737 / (14 * 4 * 49152)),
+    ({"chunks": 0, "explored": 0, "explored0": 0}, 1, None),
+    (None, 1, None),
+], ids=["paxos-level-8", "shardkv-level-9", "a-mesh-of-four",
+        "no-chunk-step", "no-traced-level"])
+def test_grid_fill_reader_reads_the_level_span(monkeypatch, level, chips,
+                                               want):
+    """``benchmark/layer_metrics/grid_fill_pct.deep.py``: the states the
+    level explored over the grid slots its chunk steps computed (chunk
+    rows a chip x chips x the window's 48 event slots a row)."""
+    from benchmark.harness import program_spans
+
+    monkeypatch.setattr(program_spans, "traced_level", lambda run: level)
+    got = _layer_reader("grid_fill_pct.deep").compute(
+        {"config": _ENGINE, "chips": chips})
+    assert got == pytest.approx(want)       # a number, or None
+    assert want is None or 0 < got < 100
+
+
+def test_the_level_span_opens_with_its_frontier_rows(sharded_run):
+    """``frontier0`` on every ``search.level`` span is the rows of the
+    device that held most when the level started: 1 at the root, then
+    what the level before closed with."""
+    out, _tel, profile = sharded_run
+    levels = {n["depth"]: n for n in profile.named("search.level")}
+    rows = 1
+    for lv in out.levels:
+        assert levels[lv["depth"]]["frontier0"] == rows
+        rows = lv["next_frontier"]
 
 
 def test_dispatch_annotations_are_the_recorders_own_spans(lab_call,
