@@ -393,8 +393,9 @@ class ShardedTensorSearch(TensorSearch):
         """The per-device chunk-step body (runs INSIDE shard_map, as
         the body of the superstep's ``lax.while_loop``): one chunk
         expand + owner routing of keys and rows + owner dedup +
-        frontier append.  Returns the carry and the write blocks the
-        step scattered (table and append: visited.block_width)."""
+        frontier append.  Returns the carry and the step's dedup counts
+        ``[write blocks scattered (table and append:
+        visited.block_width), bucket columns the probe gathered]``."""
         p = self.p
         D = self.n_devices
         C = self.cpd
@@ -597,9 +598,10 @@ class ShardedTensorSearch(TensorSearch):
                 # counted into the vis_over flag, which _sync_checks raises
                 # on in strict mode and reports via
                 # SearchOutcome.visited_overflow in beam mode.
-                new_visited, ins_s, unres_s, blocks = visited_mod.insert(
-                    carry["visited"], recv_keys, recv_valid,
-                    count_blocks=True)
+                (new_visited, ins_s, unres_s, blocks,
+                 probe_cols) = visited_mod.insert(
+                     carry["visited"], recv_keys, recv_valid,
+                     count_blocks=True, count_cols=True)
                 fresh_s = ins_s | unres_s
                 vis_over = jnp.sum(unres_s).astype(jnp.int32)
                 n_fresh = jnp.sum(ins_s).astype(jnp.int32)
@@ -718,7 +720,7 @@ class ShardedTensorSearch(TensorSearch):
                         out[k] = jnp.where(abort, carry[k], out[k])
                     out["f_full"] = jnp.where(abort, code,
                                               jnp.int32(0))[None]
-                return out, blocks + app_blocks
+                return out, jnp.stack([blocks + app_blocks, probe_cols])
 
         return local
 
@@ -744,8 +746,9 @@ class ShardedTensorSearch(TensorSearch):
 
         Returns ``(carry', stats)`` where ``stats`` is the fused scalar
         vector _sync_checks parses: 8 scalars, the n_flags counts,
-        ``[remaining_devices, steps_taken, write_blocks]``, the spill
-        abort code when the host tier is wired, and the per-device lanes.
+        ``[remaining_devices, steps_taken, write_blocks, probe_cols]``,
+        the spill abort code when the host tier is wired, and the
+        per-device lanes.
         Computing the stats in-program (psum/pmax over the mesh axis)
         folds the level sync into the same dispatch: host involvement
         per level is superstep + promote."""
@@ -758,7 +761,7 @@ class ShardedTensorSearch(TensorSearch):
 
         spill_on = self._spill is not None
 
-        def stats_local(c, steps, blocks):
+        def stats_local(c, steps, dedup):
             core = jnp.stack([
                 _psum(c["overflow"][0]),
                 _psum(c["drops"][0]),
@@ -774,10 +777,12 @@ class ShardedTensorSearch(TensorSearch):
             flags = _psum(c["flag_cnt"]).astype(jnp.int32)
             remaining = _psum(
                 (c["j"][0] * C < c["cur_n"][0]).astype(jnp.int32))
-            # Write blocks scattered (table + append), summed over this
-            # dispatch's chunk steps, of the device that scattered most.
-            tail = jnp.stack([remaining, steps,
-                              jax.lax.pmax(blocks, ax)]).astype(jnp.int32)
+            # Write blocks scattered (table + append) and bucket columns
+            # the probe gathered, each summed over this dispatch's chunk
+            # steps, of the device that counts most.
+            tail = jnp.concatenate([jnp.stack([remaining, steps]),
+                                    jax.lax.pmax(dedup, ax)]
+                                   ).astype(jnp.int32)
             parts = [core, flags, tail]
             if spill_on:
                 # The abort is global, so any device's copy is the
@@ -808,14 +813,15 @@ class ShardedTensorSearch(TensorSearch):
                 return keep
 
             def body(st):
-                c, k, wb = st
-                c, blocks = local(c, masks)
-                return c, k + 1, wb + blocks
+                c, k, dedup = st
+                c, counts = local(c, masks)
+                return c, k + 1, dedup + counts
 
-            carry, k, wb = jax.lax.while_loop(
-                cond, body, (carry, jnp.int32(0), jnp.int32(0)))
+            carry, k, dedup = jax.lax.while_loop(
+                cond, body,
+                (carry, jnp.int32(0), jnp.zeros((2,), jnp.int32)))
             with tel_mod.device_scope("level_sync"):
-                return carry, stats_local(carry, k, wb)
+                return carry, stats_local(carry, k, dedup)
 
         spec = self._carry_specs()
         # The function's name is the program's in a profile
@@ -1768,7 +1774,8 @@ class ShardedTensorSearch(TensorSearch):
         self._fp_map = {}
         self._deep_samples = None
         # Structured per-level throughput records (depth, chunks,
-        # write_blocks, wall, explored, unique, next_frontier) — attached
+        # write_blocks, probe_cols, wall, explored, unique,
+        # next_frontier) — attached
         # to the outcome as SearchOutcome.levels; the ``search.level``
         # phase carries the same counters into a profile.
         self._level_records: List[dict] = []
@@ -1892,7 +1899,7 @@ class ShardedTensorSearch(TensorSearch):
                         carry["noapp"] = jax.device_put(
                             np.ones(self.n_devices, np.int32), shard)
                     (carry, out, explored, vis_total, drops, max_n,
-                     chunks, blocks) = self._level_superstep(
+                     chunks, dedup) = self._level_superstep(
                          carry, depth, t0, max_n)
                     if out is not None:
                         return out
@@ -1909,10 +1916,10 @@ class ShardedTensorSearch(TensorSearch):
                                 break
                             carry, per = self._sh_spill_inject(carry, seg)
                             (carry, out, explored, vis_total, drops, max_n,
-                             ch2, bl2) = self._level_superstep(
+                             ch2, de2) = self._level_superstep(
                                  carry, depth, t0, per)
                             chunks += ch2
-                            blocks += bl2
+                            dedup += de2
                             if out is not None:
                                 return out
                     rec = {
@@ -1922,7 +1929,12 @@ class ShardedTensorSearch(TensorSearch):
                         # wrote most): above one a probe iteration and one
                         # an append, visited.block_width is too narrow
                         # for the traffic.
-                        "write_blocks": int(blocks),
+                        "write_blocks": int(dedup[0]),
+                        # Bucket columns the level's probes gathered
+                        # (indices handed to the table's gather, of the
+                        # device that gathered most): a step's live
+                        # blocks of visited.block_width, not its batch.
+                        "probe_cols": int(dedup[1]),
                         "wall": round(time.time() - t_lvl, 4),
                         "explored": int(explored), "unique": int(vis_total),
                         "next_frontier": int(max_n),
@@ -1972,7 +1984,9 @@ class ShardedTensorSearch(TensorSearch):
                             rec["hbm_peak"] = hbm
                     self._level_records.append(rec)
                     lvl.set(explored=int(explored), unique=int(vis_total),
-                            chunks=int(chunks), write_blocks=int(blocks),
+                            chunks=int(chunks),
+                            write_blocks=rec["write_blocks"],
+                            probe_cols=rec["probe_cols"],
                             next_frontier=int(max_n))
                 if tel is not None:
                     # The SAME host scalars the fused stats readback
@@ -2060,7 +2074,7 @@ class ShardedTensorSearch(TensorSearch):
         wall-clock budget is set — the whole level in ONE dispatch) and
         returns the fused stats in the same program.  Returns
         ``(carry, outcome_or_none, explored, vis_total, drops, nxt_max,
-        chunk_steps_run, write_blocks_run)``."""
+        chunk_steps_run, [write_blocks_run, probe_cols_run])``."""
         budget = ((1 << 30) if self.max_secs is None
                   else max(1, self._superstep_chunks))
         # Watchdog granularity (tpu/supervisor.py): a superstep
@@ -2071,11 +2085,11 @@ class ShardedTensorSearch(TensorSearch):
         self._dispatch_deadline_scales = {
             "superstep": float(max(1, min(budget, 2 * est)))}
         nf = len(self._flag_names)
-        chunks = blocks = 0
+        chunks, dedup = 0, np.zeros(2, np.int64)
         while True:
             carry, stats = self._superstep_call(carry, budget)
             chunks += int(stats[9 + nf])
-            blocks += int(stats[10 + nf])
+            dedup += stats[10 + nf:12 + nf]
             # The checks run BEFORE any time-budget return: a violation
             # or capacity loss in the chunks already completed is never
             # masked by TIME_EXHAUSTED.
@@ -2083,15 +2097,15 @@ class ShardedTensorSearch(TensorSearch):
              nxt_max) = self._sync_checks(carry, depth, t0, stats)
             if out is not None:
                 return (carry, out, explored, vis_total, drops, nxt_max,
-                        chunks, blocks)
-            if self._spill_on and int(stats[11 + nf]):
+                        chunks, dedup)
+            if self._spill_on and int(stats[12 + nf]):
                 # Spill abort: the superstep suspended on a frontier-
                 # full (bit 0) / table-full (bit 1) chunk, reverted
                 # wholesale.  Drain nxt through the refilter to the
                 # host spool, evict the tables if they were the wall,
                 # and re-enter the drain loop — the held-back chunk
                 # re-steps against recovered capacity.
-                code = int(stats[11 + nf])
+                code = int(stats[12 + nf])
                 if (code & 1) and nxt_max == 0:
                     raise CapacityOverflow(
                         f"{self.p.name}: one chunk's fresh successors "
@@ -2110,7 +2124,7 @@ class ShardedTensorSearch(TensorSearch):
                 continue
             if int(stats[8 + nf]) == 0:     # every device's shard drained
                 return (carry, None, explored, vis_total, drops, nxt_max,
-                        chunks, blocks)
+                        chunks, dedup)
             if (self.max_secs is not None
                     and time.time() - t0 > self.max_secs) \
                     or self._cancelled():
@@ -2118,7 +2132,7 @@ class ShardedTensorSearch(TensorSearch):
                                           depth, t0)
                 out.cancelled = self._cancelled()
                 return (carry, out, explored, vis_total, drops, nxt_max,
-                        chunks, blocks)
+                        chunks, dedup)
 
     def _spill_tmeta(self, carry) -> None:
         """Fold this level's appended (child_fp, parent_fp, event) rows

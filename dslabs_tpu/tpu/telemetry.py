@@ -404,7 +404,9 @@ PHASES = (
     # one BFS level / wave: ``depth``, ``explored0``; at close
     # ``explored``, ``unique``, ``chunks``, ``next_frontier`` and, from
     # the sharded engine, ``write_blocks`` (scatter blocks its chunk
-    # steps wrote, table + append: visited.block_width) and, at the
+    # steps wrote, table + append: visited.block_width), ``probe_cols``
+    # (bucket columns its probes gathered: the indices handed to the
+    # table's gather, a step's live blocks of that width) and, at the
     # start, ``frontier0`` (the frontier rows the level starts with, of
     # the device that holds most: ``chunks`` beyond ceil(frontier0 /
     # chunk) are chunk steps re-run at a later event window)

@@ -13,23 +13,31 @@ l`` holds lane ``l`` of its slot ``s``.  This module owns the layout —
 :func:`table_shape`, :func:`empty_table`, :func:`with_root`,
 :func:`insert`, :func:`build_table` and :func:`host_occupied` are the
 only places that know it; per-device tables stack along the rows
-(``[D * 32, VB]``, sharded ``P(axis)``).  PROBE WIDE, WRITE NARROW:
-one probe iteration gathers each key's whole bucket column
-(``table[:, bkt_i]``) and decides at the batch's full width who wins a
-slot; then only the WINNERS' columns are written — their positions
-compacted, lowest first, into blocks of ``K`` = :func:`block_width`
-indices (:func:`write_in_blocks`), each block's columns read again, patched
-with the winner's four words and scattered back whole; a block's unused
-places scatter to column ``VB``, which ``mode="drop"`` discards.  The
-chip pays a scatter per INDEX it is handed, written or dropped (103–125
-ns each on the 2^24-slot table: PERF.md, PR 34), and in a deep level
-nine keys in ten win nothing, so one block of ``n / 8`` does where the
-whole batch was scattered before.  The blocks LOOP (``ceil(winners /
-K)``, from the data) because the first levels of a search are the other
-way round — every successor fresh, every key a winner — and must stay
-exact at the price they had.  The tail's iterations write the same way
-at the tail's width; a batch no wider than ``K`` (256 keys or fewer) is
-its own block.  Gather and scatter address the table in
+(``[D * 32, VB]``, sharded ``P(axis)``).  PROBE NARROW, WRITE NARROW:
+the chip pays a gather or a scatter per INDEX it is handed, whether the
+index addresses anything or not (a gather 41–51 ns each on the 2^24 /
+2^25-slot tables, a scatter 103–125: PERF.md, PRs 34 and 36), so
+neither is handed the rows that have nothing to read or write.  One
+probe iteration gathers the whole bucket column of each key in the
+LIVE blocks of its batch — the blocks of ``K`` = :func:`block_width`
+consecutive rows that still hold an unresolved key
+(:func:`_live_columns`: the sharded engine sorts a batch's valid keys
+first, so under half of a deep level's blocks are live) — and decides
+at the batch's full width who wins a slot; then only the WINNERS'
+columns are written — their positions compacted, lowest first, into
+blocks of ``K`` indices (:func:`write_in_blocks`), each block's columns
+read again, patched with the winner's four words and scattered back
+whole; a block's unused places scatter to column ``VB``, which
+``mode="drop"`` discards.  In a deep level nine keys in ten win
+nothing, so one block of ``n / 8`` does where the whole batch was
+scattered before.  Both kinds of block LOOP, as many as the data fill
+(live blocks; ``ceil(winners / K)``), because the first levels of a
+search are the other way round — every successor fresh, every key a
+winner — and an unsorted batch (the single-device engine's) has keys
+in every block: both stay exact at the price they had.  The tail's
+iterations read and write the same way at the tail's width; a batch no
+wider than ``K`` (256 keys or fewer) is its own block.  Gather and
+scatter address the table in
 the one layout the carry has (32 rows = 4 sublane tiles, VB a multiple
 of 128), so the chip converts nothing: compiled for a v5e at 2^24
 slots and 49,152 keys the insert takes 28 MB of temporaries, where
@@ -253,24 +261,61 @@ def _claimed(cols, keys_t):
                      keys_t, bkt).reshape(cols.shape)
 
 
+def _live_columns(table, bkt_i, unres):
+    """PROBE NARROW: the bucket columns ``table[:, bkt_i]`` [ROWS, n] of
+    the LIVE blocks of a batch — the blocks of ``K`` =
+    :func:`block_width` consecutive rows in which some key is still
+    unresolved, lowest first, as many gathers of ``K`` indices as there
+    are (a ``while_loop``: the count is the data's; the table is only
+    read).  The chip pays a gather per INDEX it is handed (41–51 ns
+    each on the 2^24 / 2^25-slot tables: PERF.md, PR 36), resolved or
+    not, and the sharded engine's ``route`` sorts the valid keys of a
+    batch first, so most of a deep level's blocks hold nothing to
+    probe.  A dead block's columns stay zero: every use of them is
+    masked by ``unres``.  Where ``K`` does not divide the batch the last
+    block reads back over its neighbour's rows.  A batch no wider than
+    ``K`` is one gather.  Returns ``(cols, indices handed to the
+    gather)``."""
+    n = bkt_i.shape[0]
+    K = block_width(n)
+    if n <= K:
+        return table[:, bkt_i], jnp.int32(n)
+    nb = -(-n // K)
+    live, count = compact(jnp.any(jnp.pad(
+        unres, (0, nb * K - n)).reshape(nb, K), axis=1), nb)
+
+    def block(st):
+        b, cols = st
+        at = jnp.minimum(live[b] * K, n - K)
+        got = table[:, jax.lax.dynamic_slice(bkt_i, (at,), (K,))]
+        return b + 1, jax.lax.dynamic_update_slice(cols, got, (0, at))
+
+    _, cols = jax.lax.while_loop(
+        lambda st: st[0] < count, block,
+        (jnp.int32(0), jnp.zeros((ROWS, n), table.dtype)))
+    return cols, count * K
+
+
 def _probe_iter(table, keys_t, bkt_i, ps, unres, idx, RT, batch_n):
     """One probe iteration over any batch (keys_t = the keys, [4, n];
     idx = each row's identity for reservation tie-breaks; rows with
-    unres=False are inert).  PROBE WIDE: gathers each key's whole
-    bucket column, resolves membership across its BKT slots, and lets
-    the minimum-index contender of each bucket claim the first empty
-    slot; losers re-read the same bucket next iteration, full buckets
-    advance by the key's double-hash step.  WRITE NARROW: only the
-    winners' columns are scattered back, ``K`` (:func:`block_width` of
-    this batch: an eighth of it) a block, lowest index first, as many
-    blocks as the winners fill — the chip pays a scatter per INDEX
-    handed to it, written or dropped, and in a deep level nine keys in
-    ten win nothing, in the tail's buffer more.  A batch no wider than
-    ``K`` (a small probe) is its own block.  Returns the blocks
-    scattered last."""
+    unres=False are inert).  PROBE NARROW: gathers the whole bucket
+    column of each key in a block that still holds an unresolved one
+    (:func:`_live_columns`), resolves membership across its BKT slots
+    at the batch's width, and lets the minimum-index contender of each
+    bucket claim the first empty slot; losers re-read the same bucket
+    next iteration, full buckets advance by the key's double-hash step.
+    WRITE NARROW: only the winners' columns are scattered back, ``K``
+    (:func:`block_width` of this batch: an eighth of it) a block,
+    lowest index first, as many blocks as the winners fill — the chip
+    pays a scatter per INDEX handed to it, written or dropped, and in a
+    deep level nine keys in ten win nothing, in the tail's buffer more.
+    A batch no wider than ``K`` (a small probe) is its own block, read
+    and written.  Returns the blocks scattered and the columns gathered
+    last."""
     VB = table.shape[1]
     n = bkt_i.shape[0]
-    cols = table[:, bkt_i]
+    cols, gathered = _live_columns(table, bkt_i, unres)
     bkt = cols.reshape(BKT, 4, -1)
     eq = jnp.any(jnp.all(bkt == keys_t, axis=1), axis=0)
     has_empty = jnp.any(jnp.all(bkt == MAXU32, axis=1), axis=0)
@@ -295,11 +340,12 @@ def _probe_iter(table, keys_t, bkt_i, ps, unres, idx, RT, batch_n):
     newly = eq | winner
     nb = (bkt_i.astype(jnp.uint32) + ps).astype(jnp.int32) & (VB - 1)
     bkt_i = jnp.where(unres & ~newly & ~has_empty, nb, bkt_i)
-    return table, bkt_i, newly & unres, winner & unres, blocks
+    return table, bkt_i, newly & unres, winner & unres, blocks, gathered
 
 
 def insert_jnp(table: jnp.ndarray, keys: jnp.ndarray, valid: jnp.ndarray,
-               max_iters: int = 64, count_blocks: bool = False):
+               max_iters: int = 64, count_blocks: bool = False,
+               count_cols: bool = False):
     """Membership + insert of a key batch in one bounded probe — the
     pure-jnp reference implementation (the Pallas kernel's parity
     oracle AND the CPU/interpret fallback; :func:`insert` dispatches).
@@ -315,8 +361,10 @@ def insert_jnp(table: jnp.ndarray, keys: jnp.ndarray, valid: jnp.ndarray,
     resolved — the table-full overflow case.  Callers MUST treat
     unresolved keys as fresh (sound re-exploration, never a silent
     drop) and surface ``sum(unresolved)`` as a visible overflow flag.
-    With ``count_blocks`` a fourth value: the write blocks the probe
-    scattered (:func:`_probe_iter`), an int32 scalar.
+    With ``count_blocks`` one value more: the write blocks the probe
+    scattered (:func:`_probe_iter`), an int32 scalar; with ``count_cols``
+    another, last: the bucket columns it gathered (indices handed to
+    the table's gather, full phase and tail together), an int32 scalar.
     Pure jnp — usable under jit, inside shard_map bodies, and inside
     the Pallas kernel body.
     """
@@ -342,7 +390,7 @@ def insert_jnp(table: jnp.ndarray, keys: jnp.ndarray, valid: jnp.ndarray,
         ridx = jnp.arange(n, dtype=jnp.int32)
 
         def full_cond(st):
-            _, _, resolved, _, it, _ = st
+            _, _, resolved, _, it, _, _ = st
             # ONE guaranteed full-batch iteration: below 50% table load the
             # first bucket read resolves all but the full-bucket collisions,
             # which fit the tail buffer.
@@ -350,16 +398,16 @@ def insert_jnp(table: jnp.ndarray, keys: jnp.ndarray, valid: jnp.ndarray,
                 it < max_iters) & jnp.any(~resolved)
 
         def full_body(st):
-            tbl, bkt_i, resolved, ins, it, wb = st
-            tbl, bkt_i, newly, winner, blocks = _probe_iter(
+            tbl, bkt_i, resolved, ins, it, wb, pc = st
+            tbl, bkt_i, newly, winner, blocks, gathered = _probe_iter(
                 tbl, keys_t, bkt_i, pstep, ~resolved, ridx, RT, n)
             return (tbl, bkt_i, resolved | newly, ins | winner, it + 1,
-                    wb + blocks)
+                    wb + blocks, pc + gathered)
 
-        table, bkt_i, resolved, inserted, _, wb = jax.lax.while_loop(
+        table, bkt_i, resolved, inserted, _, wb, pc = jax.lax.while_loop(
             full_cond, full_body,
             (table, slot0, ~valid, jnp.zeros(n, bool), jnp.int32(0),
-             jnp.int32(0)))
+             jnp.int32(0), jnp.int32(0)))
 
         # ---- tail phase: compact the unresolved few into [T] slots.
         tail_idx = compact(~resolved, T)[0][:T]
@@ -371,24 +419,23 @@ def insert_jnp(table: jnp.ndarray, keys: jnp.ndarray, valid: jnp.ndarray,
         t_id = jnp.arange(T, dtype=jnp.int32)
 
         def tail_cond(st):
-            _, _, t_unres, _, it, _ = st
+            _, _, t_unres, _, it, _, _ = st
             return (it < max_iters) & jnp.any(t_unres)
 
         def tail_body(st):
-            tbl, tb, t_unres, t_ins, it, wb = st
-            tbl, tb, newly, winner, blocks = _probe_iter(
+            tbl, tb, t_unres, t_ins, it, wb, pc = st
+            tbl, tb, newly, winner, blocks, gathered = _probe_iter(
                 tbl, t_keys_t, tb, t_ps, t_unres, t_id, RT, n)
             return (tbl, tb, t_unres & ~newly, t_ins | winner, it + 1,
-                    wb + blocks)
+                    wb + blocks, pc + gathered)
 
-        table, _, t_unres, t_ins, _, wb = jax.lax.while_loop(
+        table, _, t_unres, t_ins, _, wb, pc = jax.lax.while_loop(
             tail_cond, tail_body,
-            (table, t_bkt, tval, jnp.zeros(T, bool), jnp.int32(0), wb))
+            (table, t_bkt, tval, jnp.zeros(T, bool), jnp.int32(0), wb, pc))
         resolved = resolved.at[tclip].max(tval & ~t_unres)
         inserted = inserted.at[tclip].max(t_ins & tval)
-        if count_blocks:
-            return table, inserted, ~resolved, wb
-        return table, inserted, ~resolved
+        return (table, inserted, ~resolved) + (
+            (wb,) if count_blocks else ()) + ((pc,) if count_cols else ())
 
 
 # ------------------------------------------------- Pallas bucket kernel
@@ -436,7 +483,8 @@ def _pallas_interpret() -> Optional[bool]:
 
 def pallas_insert(table: jnp.ndarray, keys: jnp.ndarray,
                   valid: jnp.ndarray, max_iters: int = 64, *,
-                  interpret: bool, count_blocks: bool = False):
+                  interpret: bool, count_blocks: bool = False,
+                  count_cols: bool = False):
     """:func:`insert_jnp` as one Pallas kernel: table + key batch load
     into VMEM, the bounded probe runs on-chip, and the table writes
     back through an input/output alias (the in-place update the
@@ -450,14 +498,14 @@ def pallas_insert(table: jnp.ndarray, keys: jnp.ndarray,
     n = keys.shape[0]
 
     def kernel(table_ref, keys_ref, valid_ref, out_table_ref,
-               ins_ref, unres_ref, blocks_ref):
-        tbl, ins, unres, wb = insert_jnp(
+               ins_ref, unres_ref, counts_ref):
+        tbl, ins, unres, wb, pc = insert_jnp(
             table_ref[...], keys_ref[...], valid_ref[...] != 0,
-            max_iters, count_blocks=True)
+            max_iters, count_blocks=True, count_cols=True)
         out_table_ref[...] = tbl
         ins_ref[...] = ins.astype(jnp.int32)
         unres_ref[...] = unres.astype(jnp.int32)
-        blocks_ref[...] = wb[None]
+        counts_ref[...] = jnp.stack([wb, pc])
 
     kwargs = {}
     if not interpret:
@@ -469,34 +517,34 @@ def pallas_insert(table: jnp.ndarray, keys: jnp.ndarray,
         vmem = pl.BlockSpec(memory_space=pltpu.VMEM)
         kwargs = dict(in_specs=[vmem, vmem, vmem],
                       out_specs=(vmem, vmem, vmem, vmem))
-    table2, ins, unres, wb = pl.pallas_call(
+    table2, ins, unres, counts = pl.pallas_call(
         kernel,
         out_shape=(jax.ShapeDtypeStruct(table.shape, table.dtype),
                    jax.ShapeDtypeStruct((n,), jnp.int32),
                    jax.ShapeDtypeStruct((n,), jnp.int32),
-                   jax.ShapeDtypeStruct((1,), jnp.int32)),
+                   jax.ShapeDtypeStruct((2,), jnp.int32)),
         input_output_aliases={0: 0},
         interpret=bool(interpret), **kwargs)(
             table, keys, valid.astype(jnp.int32))
-    if count_blocks:
-        return table2, ins != 0, unres != 0, wb[0]
-    return table2, ins != 0, unres != 0
+    return (table2, ins != 0, unres != 0) + (
+        (counts[0],) if count_blocks else ()) + (
+            (counts[1],) if count_cols else ())
 
 
 def insert(table: jnp.ndarray, keys: jnp.ndarray, valid: jnp.ndarray,
-           max_iters: int = 64, count_blocks: bool = False):
+           max_iters: int = 64, count_blocks: bool = False,
+           count_cols: bool = False):
     """THE probe/insert entry point both engines trace:
     :func:`insert_jnp` by default on every backend; the Pallas kernel
     only where :func:`pallas_mode` asks for it by name (``on`` compiles
     or raises, ``interpret`` interprets).  Contract and return values
     are identical across paths (see ``insert_jnp``)."""
-    if _FORCE_JNP:
-        return insert_jnp(table, keys, valid, max_iters, count_blocks)
-    interp = _pallas_interpret()
+    interp = None if _FORCE_JNP else _pallas_interpret()
     if interp is None:
-        return insert_jnp(table, keys, valid, max_iters, count_blocks)
-    return pallas_insert(table, keys, valid, max_iters,
-                         interpret=interp, count_blocks=count_blocks)
+        return insert_jnp(table, keys, valid, max_iters, count_blocks,
+                          count_cols)
+    return pallas_insert(table, keys, valid, max_iters, interpret=interp,
+                         count_blocks=count_blocks, count_cols=count_cols)
 
 
 def dispatch_site_program(cap: int, batch: int):

@@ -149,18 +149,34 @@ def _table_sized_in_loops(text, limit=1 << 21):
     return big
 
 
+def _shapes(text):
+    """``{instruction: shape}`` of an optimised HLO module's text."""
+    return dict(re.findall(r"%([\w.\-]+) = (\w+\[[\d,]*\])", text))
+
+
+def _elements(shape):
+    dims = re.search(r"\[([\d,]*)\]", shape).group(1)
+    return int(np.prod([int(d) for d in dims.split(",") if d]))
+
+
 def _scatter_widths(text, shape):
     """Update indices of every ``scatter`` in an optimised HLO module
     whose operand has ``shape`` (``"u32[32,2097152]"``): the elements
     of its indices operand (one index a row or column written)."""
-    shapes = dict(re.findall(r"%([\w.\-]+) = (\w+\[[\d,]*\])", text))
-    widths = []
-    for indices in re.findall(
-            r"= " + re.escape(shape) + r"\S* scatter\(%[\w.\-]+, "
-            r"%([\w.\-]+),", text):
-        dims = re.search(r"\[([\d,]*)\]", shapes[indices]).group(1)
-        widths.append(int(np.prod([int(d) for d in dims.split(",") if d])))
-    return widths
+    shapes = _shapes(text)
+    return [_elements(shapes[indices]) for indices in re.findall(
+        r"= " + re.escape(shape) + r"\S* scatter\(%[\w.\-]+, "
+        r"%([\w.\-]+),", text)]
+
+
+def _gather_widths(text, shape):
+    """Indices of every ``gather`` in an optimised HLO module whose
+    operand has ``shape``: the elements of its indices operand (one
+    index a column read)."""
+    shapes = _shapes(text)
+    return [_elements(shapes[indices]) for operand, indices in re.findall(
+        r" gather\(%([\w.\-]+), %([\w.\-]+)\)", text)
+        if shapes[operand] == shape]
 
 
 @pytest.mark.parametrize("nested", [False, True],
@@ -175,7 +191,10 @@ def test_flagship_insert_converts_no_table(one_chip, nested, monkeypatch):
     alone and as the superstep nests it, in an outer loop.  And it
     writes narrow: no scatter on the table is handed more than one
     block of ``K`` = 6,144 columns (the chip pays per index, written or
-    dropped: 49,152 of them cost 5 ms a chunk step until PR 34)."""
+    dropped: 49,152 of them cost 5 ms a chunk step until PR 34).  And
+    it probes narrow: no gather on the table is handed more than ``K``
+    indices either (the batch's 49,152 cost 2.0-2.5 ms a chunk step
+    until PR 37)."""
     monkeypatch.delenv("DSLABS_VISITED_PALLAS", raising=False)
 
     def insert16(t, k, v):
@@ -193,6 +212,8 @@ def test_flagship_insert_converts_no_table(one_chip, nested, monkeypatch):
     widths = _scatter_widths(compiled.as_text(), "u32[32,2097152]")
     assert visited.block_width(49152) == 6144
     assert widths and max(widths) <= 6144, widths
+    reads = _gather_widths(compiled.as_text(), "u32[32,2097152]")
+    assert reads and max(reads) <= 6144, reads
 
 
 def test_pallas_insert_is_refused_by_mosaic(one_chip):
@@ -394,10 +415,11 @@ def test_shardkv_deep_programs_compile(topo):
     its live bytes follow the rows: 14.61 GiB of 15.75 here, 9.46 at
     2^22 rows, 13.58 at 6,291,456, 15.48 at 7,340,032 — what bounds
     this cell's headroom for a faster program (the configuration's
-    ``sizing``).  And the dedup layer still writes narrow at these
-    shapes: no scatter on the table or on ``nxt`` is handed more than
-    one block of 6,144 indices.  Under a minute of compile (the program
-    is a fifth of Paxos' text)."""
+    ``sizing``).  And the dedup layer still probes and writes narrow at
+    these shapes: no gather on the table, and no scatter on the table
+    or on ``nxt``, is handed more than one block of 6,144 indices.
+    Under a minute of compile (the program is a fifth of Paxos'
+    text)."""
     from benchmark.drivers.timeboxed_bfs import build_protocol
     from benchmark.harness import manifest
 
@@ -416,6 +438,12 @@ def test_shardkv_deep_programs_compile(topo):
         cell.config["protocol"]["packed_bytes_per_state"])
     exes = _aot(search)
     _fits(exes)
+    # The margin is 1.14 GiB: the probe's live-block loop (PR 37) added
+    # no frontier- or table-sized buffer to PR 36's 15,689,473,536
+    # bytes (it read 97 KB under them).
+    mem = exes["superstep"].memory_analysis()
+    assert (mem.output_size_in_bytes + mem.temp_size_in_bytes
+            < 15_689_473_536 + (64 << 20)), mem
     text = exes["superstep"].as_text()
     assert "all-to-all" not in text
     k = visited.block_width(eng["chunk"] * search._ev_slots)
@@ -423,8 +451,10 @@ def test_shardkv_deep_programs_compile(topo):
     table = _scatter_widths(text, f"u32[32,{eng['visited_cap'] // 8}]")
     nxt = _scatter_widths(
         text, f"s32[{eng['frontier_cap'] + 1},{search.plane}]")
+    reads = _gather_widths(text, f"u32[32,{eng['visited_cap'] // 8}]")
     assert table and max(table) <= k, table
     assert nxt and max(nxt) <= k, nxt
+    assert reads and max(reads) <= k, reads
     assert _table_sized_in_loops(text, limit=1 << 27) == []
 
 
@@ -444,10 +474,10 @@ def _flagship_search(mesh, chunk):
 def test_flagship_programs_compile(topo, n_devices):
     """The flagship protocol at chip_smoke's caps: superstep, promote,
     root init and the bare ``_expand_chunk`` — carry plus temporaries
-    must fit one chip's HBM, no scatter on the visited table or on
-    ``nxt`` takes more than one write block of indices, and nothing in
-    the superstep's loops but those scatters is as large as the table.
-    Minutes of compile: ``-m slow``."""
+    must fit one chip's HBM, no gather on the visited table and no
+    scatter on it or on ``nxt`` takes more than one block of indices,
+    and nothing in the superstep's loops but those scatters is as large
+    as the table.  Minutes of compile: ``-m slow``."""
     search = _flagship_search(_mesh(topo, n_devices),
                               chip_smoke.FLAGSHIP["chunk"])
     assert search.lanes == 842
@@ -473,8 +503,10 @@ def test_flagship_programs_compile(topo, n_devices):
     nxt = _scatter_widths(
         text, f"s32[{chip_smoke.FLAGSHIP['frontier_cap'] + 1},"
         f"{search.plane}]")
+    reads = _gather_widths(text, "u32[32,2097152]")
     assert table and max(table) <= k, table
     assert nxt and max(nxt) <= k, nxt
+    assert reads and max(reads) <= k, reads
     assert _table_sized_in_loops(text, limit=1 << 26) == []
 
 

@@ -243,10 +243,11 @@ def _parallel_model(table, keys, valid, max_iters=64):
 _EDGE_N, _EDGE_CAP = 2048, 1 << 15
 
 
-def _own_bucket_keys(n, seed, first=0):
-    """n distinct keys, key i at home in bucket ``first + i``."""
+def _own_bucket_keys(n, seed, first=0, cap=_EDGE_CAP):
+    """n distinct keys, key i at home in bucket ``first + i`` of a
+    ``cap``-slot table."""
     keys = _rand_keys(n, seed)
-    vb = _EDGE_CAP // BKT
+    vb = cap // BKT
     keys[:, 2] = (keys[:, 2] & ~np.uint32(vb - 1)) | (
         np.arange(first, first + n, dtype=np.uint32))
     return keys
@@ -374,6 +375,126 @@ def test_write_blocks_follow_the_winners(make, blocks):
         assert int(got) == want
         assert int(ins.sum()) <= want * visited_mod.block_width(len(keys))
         assert not np.asarray(unres).any()
+
+
+# Live-block batches (PR 37): the probe gathers bucket columns only for
+# the blocks of K = visited.block_width(n) consecutive rows that still
+# hold an unresolved key.  2,048 rows are 8 blocks of 256; every valid
+# key has a home bucket of its own unless the case says otherwise, so
+# ONE full iteration resolves the batch and the tail never runs.
+
+def _valid_rows(n, rows):
+    valid = np.zeros(n, bool)
+    valid[rows] = True
+    return valid
+
+
+def _prefix_batches(rows):
+    def make():
+        return _EDGE_CAP, [(_own_bucket_keys(_EDGE_N, 31),
+                            _valid_rows(_EDGE_N, np.arange(rows)))]
+    return make
+
+
+def _bucket_prefix_batches():
+    """The mesh's shape: four received buckets of two blocks each, the
+    valid keys a prefix of each bucket."""
+    rows = np.concatenate([np.arange(b, b + 100)
+                           for b in (0, 512, 1024, 1536)])
+    return _EDGE_CAP, [(_own_bucket_keys(_EDGE_N, 32),
+                        _valid_rows(_EDGE_N, rows))]
+
+
+def _scattered_batches():
+    """Valid keys all over an unsorted batch (the single-device
+    engine's): every block live, the parent's width."""
+    return _EDGE_CAP, [(_own_bucket_keys(_EDGE_N, 33),
+                        _valid_rows(_EDGE_N, np.arange(3, _EDGE_N, 7)))]
+
+
+def _last_block_batches():
+    return _EDGE_CAP, [(_own_bucket_keys(_EDGE_N, 34),
+                        _valid_rows(_EDGE_N, np.arange(2000, _EDGE_N)))]
+
+
+def _no_valid_key_batches():
+    return _EDGE_CAP, [(_own_bucket_keys(_EDGE_N, 35),
+                        np.zeros(_EDGE_N, bool))]
+
+
+def _ragged_last_block_batches():
+    """2,055 rows: K = 256 does not divide the batch, and the ninth
+    block (rows 2,048-2,054) reads back over its neighbour's rows."""
+    n = _EDGE_N + 7
+    return _EDGE_CAP, [(_own_bucket_keys(n, 36),
+                        _valid_rows(n, np.arange(_EDGE_N, n)))]
+
+
+def _second_iteration_batches():
+    """Rows 1,700-1,999 (blocks 6 and 7) share the home buckets of rows
+    0-299, which win them first: 300 keys, more than K, go into a
+    SECOND full iteration, and only their two blocks are read again (a
+    second full iteration needs more than K unresolved keys, so two
+    live blocks are the fewest it can have)."""
+    keys = _own_bucket_keys(_EDGE_N, 37)
+    keys[1700:2000, 2] = keys[:300, 2]
+    return _EDGE_CAP, [(keys, None)]
+
+
+def _tail_one_block_batches():
+    """16,384 rows: the tail's 2,048 places are 8 blocks of 256.  200
+    pairs of fresh keys share a home bucket; the first iteration seats
+    one of each pair, and the tail compacts the other 200, lowest
+    first, into the first of its eight blocks."""
+    cap, n = 1 << 17, 1 << 14
+    known = _own_bucket_keys(n - 400, 38, cap=cap)
+    pairs = np.concatenate([
+        _own_bucket_keys(200, seed, first=n - 400, cap=cap)
+        for seed in (39, 40)])
+    return cap, [(known, None), (np.concatenate([known, pairs]), None)]
+
+
+@pytest.mark.parametrize("make,cols", [
+    (_prefix_batches(200), [256]),
+    (_prefix_batches(600), [3 * 256]),
+    (_prefix_batches(_EDGE_N), [8 * 256]),
+    (_bucket_prefix_batches, [4 * 256]),
+    (_scattered_batches, [8 * 256]),
+    (_last_block_batches, [256]),
+    (_no_valid_key_batches, [0]),
+    (_ragged_last_block_batches, [256]),
+    (_second_iteration_batches, [8 * 256 + 2 * 256]),
+    (_tail_one_block_batches, [8 * 1998, 8 * 2048 + 256]),
+], ids=["prefix-of-1-block", "prefix-of-3-blocks", "prefix-of-8-blocks",
+        "four-bucket-prefixes", "scattered-all-live", "last-block-only",
+        "no-valid-key", "K-does-not-divide-the-batch",
+        "second-iteration-two-blocks-live", "tail-fills-one-block-of-8"])
+def test_probe_gathers_only_the_live_blocks(make, cols):
+    """PROBE NARROW: an iteration hands the table's gather ``K`` indices
+    for each block of its batch that still holds an unresolved key and
+    none for the others — and the table, SLOT FOR SLOT, ``inserted``,
+    ``unresolved`` and the write blocks are what the host models give,
+    which read every column at the batch's full width."""
+    cap, batches = make()
+    table, model = visited_mod.empty_table(cap), _HostTable(cap)
+    for (keys, valid), want_cols in zip(batches, cols):
+        valid = np.ones(len(keys), bool) if valid is None else valid
+        want_table, par_ins, par_unres, want_blocks = _parallel_model(
+            table, keys, valid)
+        table, ins, unres, blocks, got_cols = visited_mod.insert(
+            table, jnp.asarray(keys), jnp.asarray(valid),
+            count_blocks=True, count_cols=True)
+        want_ins, want_unres = model.insert(keys, valid)
+        assert np.array_equal(np.asarray(ins), want_ins)
+        assert np.array_equal(np.asarray(unres), want_unres)
+        assert np.array_equal(par_ins, want_ins)
+        assert np.array_equal(par_unres, want_unres)
+        assert np.array_equal(np.asarray(table), want_table)
+        assert int(blocks) == want_blocks
+        assert int(got_cols) == want_cols
+        assert not want_unres.any()
+    got = visited_mod.host_occupied(table)
+    assert sorted(map(tuple, got.tolist())) == model.keys()
 
 
 def test_overflow_unresolved_are_exactly_the_keys_that_did_not_fit():

@@ -204,15 +204,45 @@ def test_level_phases_carry_the_levels_own_counters(sharded_run):
         note = levels[lv["depth"]]
         assert note["explored0"] == before
         assert (note["explored"], note["unique"], note["chunks"],
-                note["write_blocks"], note["next_frontier"]) == (
+                note["write_blocks"], note["probe_cols"],
+                note["next_frontier"]) == (
             lv["explored"], lv["unique"], lv["chunks"],
-            lv["write_blocks"], lv["next_frontier"])
+            lv["write_blocks"], lv["probe_cols"], lv["next_frontier"])
         before = lv["explored"]
     assert before == out.states_explored
     # A level that found something wrote it: at least a table block
     # and an append block.
     assert all(lv["write_blocks"] >= 2 for lv in out.levels
                if lv["next_frontier"])
+
+
+def test_probe_cols_count_the_live_blocks_of_a_step(sharded_run):
+    """``probe_cols`` on the level record and the ``search.level`` span:
+    the bucket columns the level's probes gathered, of the device that
+    gathered most.  A chunk step's probe reads whole blocks of ``K`` =
+    ``visited.block_width`` rows of the batch a device receives, only
+    those that hold an unresolved key: one block an iteration where the
+    chunk holds ONE row (its few successors are the batch's prefix),
+    never more than the batch's blocks a full iteration and the tail's
+    width a tail iteration."""
+    from dslabs_tpu.tpu import sharded, visited
+
+    out, _tel, _profile = sharded_run
+    search = _sharded()
+    n = search.n_devices * (
+        search.cpd * search._num_events() // search.n_devices + 1
+    ) * sharded.OVERFLOW_FACTOR
+    k = visited.block_width(n)
+    assert n > k                    # the batch is more than one block
+    first = out.levels[0]
+    assert (first["depth"], first["chunks"], first["explored"]) == (1, 1, 2)
+    assert first["probe_cols"] == k
+    # this small space resolves every step's keys in one full iteration
+    # and at most one of the tail's
+    full = -(-n // k) * k
+    for lv in out.levels:
+        assert lv["chunks"] * k <= lv["probe_cols"] <= lv["chunks"] * (
+            full + k)
 
 
 def _layer_reader(name):
@@ -241,6 +271,24 @@ def test_write_blocks_reader_reads_the_level_span(monkeypatch, level, want):
 
     monkeypatch.setattr(program_spans, "traced_level", lambda run: level)
     assert _layer_reader("write_blocks_per_step.deep").compute({}) == want
+
+
+@pytest.mark.parametrize("level,want", [
+    ({"chunks": 126, "probe_cols": 3096576}, 24576.0),
+    ({"chunks": 126}, None),
+    ({"chunks": 0, "probe_cols": 0}, None),
+    (None, None),
+], ids=["counted", "a-program-from-before-PR-37", "no-chunk-step",
+        "no-traced-level"])
+def test_probe_cols_reader_reads_the_level_span(monkeypatch, level, want):
+    """``benchmark/layer_metrics/probe_cols_per_step.deep.py`` divides
+    the two fields the level's span closes with, and reports nothing
+    (no raise) where a program counted none — the parent side of this
+    PR's check runs it on such a program."""
+    from benchmark.harness import program_spans
+
+    monkeypatch.setattr(program_spans, "traced_level", lambda run: level)
+    assert _layer_reader("probe_cols_per_step.deep").compute({}) == want
 
 
 _ENGINE = {"engine": {"chunk": 1024, "ev_budget": [40, 8]}}

@@ -188,9 +188,9 @@ def test_retired_options_are_gone():
 
 def test_level_records_on_outcome():
     """Satellite: structured per-level throughput records ride the
-    outcome (depth/chunks/write_blocks/wall/explored/unique/
-    next_frontier) — the
-    bench emits them as its throughput series."""
+    outcome (depth/chunks/write_blocks/probe_cols/wall/explored/
+    unique/next_frontier) — the bench emits them as its throughput
+    series."""
     proto = _pruned_pingpong()
     mesh = make_mesh(8)
     out = ShardedTensorSearch(
@@ -199,8 +199,8 @@ def test_level_records_on_outcome():
     assert out.levels, "SearchOutcome.levels must carry per-level records"
     for i, rec in enumerate(out.levels):
         assert rec["depth"] == i + 1
-        for key in ("chunks", "write_blocks", "wall", "explored",
-                    "unique", "next_frontier"):
+        for key in ("chunks", "write_blocks", "probe_cols", "wall",
+                    "explored", "unique", "next_frontier"):
             assert key in rec, rec
         assert rec["chunks"] >= 1
     # Cumulative counters are monotone; the final record's totals match
