@@ -555,7 +555,8 @@ def derive_root(binding: TwinBinding, search, state):
     events = sum(op[0] in ("ev_msg", "ev_tmr") for op in prov.history)
     p = search.p
     step = _trace_step(binding, p)
-    row = np.asarray(flatten_state(search.initial_state()))[0]
+    with telemetry.phase("entry.root.eager"):
+        row = np.asarray(flatten_state(search.initial_state()))[0]
     o0, o1 = search._off[0], search._off[1]
     dropped: List[np.ndarray] = []
     with telemetry.phase("entry.root.replay", events=events,
@@ -608,7 +609,9 @@ def derive_root(binding: TwinBinding, search, state):
                 row[o0:o1] = net.reshape(-1)
             else:
                 raise NoTensorTwin(f"unknown staged op {op!r}")
-    return search.unflatten_rows(jnp.asarray(row[None])), list(prov.history)
+    with telemetry.phase("entry.root.eager"):
+        return (search.unflatten_rows(jnp.asarray(row[None])),
+                list(prov.history))
 
 
 # ------------------------------------------------------------------- run

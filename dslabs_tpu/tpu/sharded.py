@@ -1767,25 +1767,28 @@ class ShardedTensorSearch(TensorSearch):
         search restarts at its last checkpointed level with identical
         final verdict and unique count)."""
         t0 = time.time()
-        state = (jax.tree.map(jnp.asarray, initial) if initial is not None
-                 else self.initial_state())
-        # Root of this run's trace (tpu/trace.py replays from here).
-        self._trace_root = jax.tree.map(np.asarray, state)
-        self._fp_map = {}
-        self._deep_samples = None
-        # Structured per-level throughput records (depth, chunks,
-        # write_blocks, probe_cols, wall, explored, unique,
-        # next_frontier) — attached
-        # to the outcome as SearchOutcome.levels; the ``search.level``
-        # phase carries the same counters into a profile.
-        self._level_records: List[dict] = []
-        self._pd_prev_explored = [0] * self.n_devices
-        self._root_fp = tuple(np.asarray(
-            self._canonical_root_fp(state), np.uint32)[0].tolist())
-        if check_initial:
-            out = self._check_initial(state, t0)
-            if out is not None:
-                return self._stamp_device(out)
+        # The host's work before the first level (telemetry.PHASES;
+        # ``search.carry`` in _run_levels is its second half).
+        with tel_mod.phase("search.start"):
+            state = (jax.tree.map(jnp.asarray, initial)
+                     if initial is not None else self.initial_state())
+            # Root of this run's trace (tpu/trace.py replays from here).
+            self._trace_root = jax.tree.map(np.asarray, state)
+            self._fp_map = {}
+            self._deep_samples = None
+            # Structured per-level throughput records (depth, chunks,
+            # write_blocks, probe_cols, wall, explored, unique,
+            # next_frontier) — attached
+            # to the outcome as SearchOutcome.levels; the ``search.level``
+            # phase carries the same counters into a profile.
+            self._level_records: List[dict] = []
+            self._pd_prev_explored = [0] * self.n_devices
+            self._root_fp = tuple(np.asarray(
+                self._canonical_root_fp(state), np.uint32)[0].tolist())
+            if check_initial:
+                out = self._check_initial(state, t0)
+                if out is not None:
+                    return self._stamp_device(out)
 
         tel = getattr(self, "_telemetry", None)
         if tel is not None and self._spill is not None:
@@ -1839,29 +1842,31 @@ class ShardedTensorSearch(TensorSearch):
 
     def _run_levels(self, t0, state, resume) -> SearchOutcome:
         with self.mesh:
-            resumed = self._load_checkpoint() if resume else None
-            if resumed is not None:
-                carry, depth, prev_elapsed = resumed
-                t0 = time.time() - prev_elapsed
-                max_n = int(np.asarray(carry["cur_n"]).max())
-                # Pre-loop totals: a checkpoint saved after the FINAL
-                # level has an empty frontier, so the while body (which
-                # normally binds these) never runs.
-                explored = int(np.asarray(carry["explored"]).sum())
-                vis_total = int(np.asarray(carry["vis_n"]).sum())
-                if self._spill_on:
-                    vis_total = self._spill.unique(vis_total)
-                drops = int(np.asarray(carry["drops"]).sum())
-            else:
-                if self._spill_on:
-                    # Fresh start: run N must not refilter against run
-                    # N-1's tier (engine-reuse pattern; the resumed
-                    # branch restores the tier from the dump instead).
-                    self._spill.reset_run()
-                carry = self._init_carry(state)
-                depth = 0
-                max_n = 1
-                explored, vis_total, drops = 0, 1, 0   # the root state
+            with tel_mod.phase("search.carry"):
+                resumed = self._load_checkpoint() if resume else None
+                if resumed is not None:
+                    carry, depth, prev_elapsed = resumed
+                    t0 = time.time() - prev_elapsed
+                    max_n = int(np.asarray(carry["cur_n"]).max())
+                    # Pre-loop totals: a checkpoint saved after the FINAL
+                    # level has an empty frontier, so the while body
+                    # (which normally binds these) never runs.
+                    explored = int(np.asarray(carry["explored"]).sum())
+                    vis_total = int(np.asarray(carry["vis_n"]).sum())
+                    if self._spill_on:
+                        vis_total = self._spill.unique(vis_total)
+                    drops = int(np.asarray(carry["drops"]).sum())
+                else:
+                    if self._spill_on:
+                        # Fresh start: run N must not refilter against
+                        # run N-1's tier (engine-reuse pattern; the
+                        # resumed branch restores the tier from the dump
+                        # instead).
+                        self._spill.reset_run()
+                    carry = self._init_carry(state)
+                    depth = 0
+                    max_n = 1
+                    explored, vis_total, drops = 0, 1, 0  # the root state
             while max_n > 0:
                 if self.max_depth is not None and depth >= self.max_depth:
                     return self._limit_outcome("DEPTH_EXHAUSTED", carry,
@@ -1988,6 +1993,10 @@ class ShardedTensorSearch(TensorSearch):
                             write_blocks=rec["write_blocks"],
                             probe_cols=rec["probe_cols"],
                             next_frontier=int(max_n))
+                    if self.record_trace and not noapp_level:
+                        # A final depth-limited level returns below:
+                        # nothing it appended is ever expanded.
+                        self._spill_tmeta(carry)
                 if tel is not None:
                     # The SAME host scalars the fused stats readback
                     # already delivered — telemetry adds no transfers.
@@ -2022,8 +2031,6 @@ class ShardedTensorSearch(TensorSearch):
                         time.time() - t0, dropped=drops,
                         samples=getattr(self, "_deep_samples", None),
                         visited_overflow=getattr(self, "_vis_over", 0))
-                if self.record_trace:
-                    self._spill_tmeta(carry)
                 sp = self._spill
                 if self._spill_on and (sp.active or sp.should_evict(
                         getattr(self, "_last_vis_max", 0), self.v_cap)):
@@ -2140,39 +2147,43 @@ class ShardedTensorSearch(TensorSearch):
         Vectorised: a per-row Python loop at frontier scale would dwarf
         the device time per level."""
         F = self.f_cap
-        meta = np.asarray(carry["tmeta"]).reshape(
-            self.n_devices, F + 1, 9)
-        counts = np.asarray(carry["nxt_n"]).reshape(-1)
-        rows = np.concatenate([meta[d, :counts[d]]
-                               for d in range(self.n_devices)])
-        if not len(rows):
-            return
-        children = list(map(tuple, rows[:, :4].tolist()))
-        parents = list(map(tuple, rows[:, 4:8].tolist()))
-        events = rows[:, 8].tolist()
-        # Keep FIRST occurrence (BFS parent) both within the level's batch
-        # (reversed zip: earlier rows overwrite later duplicates — today
-        # owner-side dedup already makes within-level children unique, but
-        # first-wins must not depend on that) and across levels (existing
-        # entries win via the update order below).
-        new = dict(zip(reversed(children),
-                       zip(reversed(parents), reversed(events))))
-        new.update(self._fp_map)
-        self._fp_map = new
-        # Sample a few of this level's children (spread across the batch)
-        # and keep their root-first traces; at an exhaust verdict these
-        # are the deepest states available for the object-side
-        # value-invariant re-check (ADVICE r4).  The rows are already on
-        # the host — only K short chain walks per level.
-        k = min(3, len(rows))
-        picks = {0, len(rows) // 2, len(rows) - 1}
-        samples = []
-        for i in sorted(picks)[:k]:
-            tr = self._walk_fp_chain(parents[i], int(events[i]))
-            if tr is not None:
-                samples.append(tr)
-        if samples:
-            self._deep_samples = samples
+        with tel_mod.phase("level.trace_meta") as span:
+            meta = np.asarray(carry["tmeta"]).reshape(
+                self.n_devices, F + 1, 9)
+            counts = np.asarray(carry["nxt_n"]).reshape(-1)
+            rows = np.concatenate([meta[d, :counts[d]]
+                                   for d in range(self.n_devices)])
+            span.set(rows=len(rows), bytes=meta.nbytes + counts.nbytes)
+            if not len(rows):
+                return
+            children = list(map(tuple, rows[:, :4].tolist()))
+            parents = list(map(tuple, rows[:, 4:8].tolist()))
+            events = rows[:, 8].tolist()
+            # Keep FIRST occurrence (BFS parent) both within the level's
+            # batch (reversed zip: earlier rows overwrite later
+            # duplicates — today owner-side dedup already makes
+            # within-level children unique, but first-wins must not
+            # depend on that) and across levels (existing entries win
+            # via the update order below).
+            new = dict(zip(reversed(children),
+                           zip(reversed(parents), reversed(events))))
+            new.update(self._fp_map)
+            self._fp_map = new
+            # Sample a few of this level's children (spread across the
+            # batch) and keep their root-first traces; at an exhaust
+            # verdict these are the deepest states available for the
+            # object-side value-invariant re-check (ADVICE r4).  The rows
+            # are already on the host — only K short chain walks per
+            # level.
+            k = min(3, len(rows))
+            picks = {0, len(rows) // 2, len(rows) - 1}
+            samples = []
+            for i in sorted(picks)[:k]:
+                tr = self._walk_fp_chain(parents[i], int(events[i]))
+                if tr is not None:
+                    samples.append(tr)
+            if samples:
+                self._deep_samples = samples
 
     def _walk_fp_chain(self, parent_fp, event_id) -> Optional[list]:
         """flag_meta (parent fp, event) -> grid event ids root-first, by

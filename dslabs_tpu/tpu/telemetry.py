@@ -411,6 +411,24 @@ PHASES = (
     # the device that holds most: ``chunks`` beyond ceil(frontier0 /
     # chunk) are chunk steps re-run at a later event window)
     "search.level",
+    # the host's own work around a run's dispatches, each a ``with`` in
+    # the frame that does it (ISSUE 38; benchmark/harness/
+    # idle_by_span.py sets the device's idle seconds against them).
+    # ``search.start``: run() up to its initial check — the root onto
+    # the device and read back, its fingerprint, the check itself;
+    # ``search.carry``: _run_levels up to the first level — the root's
+    # row, key, owner and home slot again (``_root_ids``) and the
+    # carry's initialiser (``dispatch.init`` lies inside it), or a
+    # checkpoint's load
+    "search.start", "search.carry",
+    # under ``search.level``: the level's appended (child, parent,
+    # event) rows read back and folded into the host's chain map
+    # (``rows`` kept, ``bytes`` read), where the engine records traces
+    "level.trace_meta",
+    # under ``entry.derive_root``: the eager operations around a staged
+    # root's replay (the twin's initial row read back, the replayed row
+    # unflattened onto the device)
+    "entry.root.eager",
     "compile.aot",                  # aot_warmup, one child per program
     "compile.event",                # mark: one jax.monitoring event
 ) + tuple(f"compile.aot.{name}" for name in AOT_PROGRAMS) + tuple(
