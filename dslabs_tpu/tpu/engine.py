@@ -1259,6 +1259,16 @@ class TensorSearch:
             lambda: ckpt_mod.save(self.checkpoint_path, ck))
 
     def initial_state(self) -> dict:
+        """The twin's initial state, a batch-1 pytree on the device.  It
+        is a constant of the protocol the engine was built with, so it
+        is built ONCE an engine (a ``CapacityOverflow`` of the initial
+        timers raises on that first build) and handed out again."""
+        state = getattr(self, "_initial", None)
+        if state is None:
+            state = self._initial = self._build_initial_state()
+        return dict(state)
+
+    def _build_initial_state(self) -> dict:
         p = self.p
         nodes = jnp.asarray(p.init_nodes(), jnp.int32)[None]
         net = jnp.full((1, p.net_cap, p.msg_width), SENTINEL, jnp.int32)
@@ -1332,6 +1342,37 @@ class TensorSearch:
         from dslabs_tpu.tpu.kernels import fingerprint_rows
 
         return fingerprint_rows(self._canon_rows(flatten_state(state)))
+
+    def _root_program(self):
+        """ONE compiled program for all a run asks of its root, built
+        once an engine: a batch-1 state pytree (host numpy or device
+        arrays alike) -> ``(row0 [1, lanes] int32, fp0 [1, 4] uint32,
+        inv_hits [n_inv] bool, goal_hits [n_goal] bool)``, the hits in
+        ``p.invariants`` / ``p.goals`` order.  Made of the functions the
+        eager helpers call (``flatten_state``, ``_canon_rows``,
+        ``fingerprint_rows``, the protocol's own predicates), so the
+        symmetry reduction and the hash are the expand programs' own.
+        A plain jitted helper, not a dispatch site: a profile names it
+        ``jit_root_program``."""
+        fn = getattr(self, "_root_prog", None)
+        if fn is None:
+            from dslabs_tpu.tpu.kernels import fingerprint_rows
+
+            p = self.p
+
+            def hits(preds, state):
+                if not preds:
+                    return jnp.zeros((0,), bool)
+                return jnp.stack([jax.vmap(f)(state)[0].astype(bool)
+                                  for f in preds.values()])
+
+            def root_program(state):
+                rows = flatten_state(state)
+                return (rows, fingerprint_rows(self._canon_rows(rows)),
+                        hits(p.invariants, state), hits(p.goals, state))
+
+            fn = self._root_prog = jax.jit(root_program)
+        return fn
 
     def _pack_rows(self, rows):
         """[N, lanes] -> [N, plane] native frontier-storage encoding."""
@@ -1967,21 +2008,33 @@ class TensorSearch:
     # ----------------------------------------------------------------- run
 
     def _check_initial(self, state, t0) -> Optional[SearchOutcome]:
+        """The root's verdict by one eager launch a predicate, stopping
+        at the first that decides (the engines no benchmark cell runs;
+        the sharded engine reads ``_root_program``'s hits instead)."""
+        def hits(preds):
+            return (bool(jax.vmap(fn)(state)[0]) for fn in preds.values())
+
+        return self._initial_verdict(hits(self.p.invariants),
+                                     hits(self.p.goals), state, t0)
+
+    def _initial_verdict(self, inv_hits, goal_hits, state,
+                         t0) -> Optional[SearchOutcome]:
+        """checkState at the root: the first invariant that does not
+        hold, then the first goal that does; hits in ``p.invariants`` /
+        ``p.goals`` order."""
         import time
-        p = self.p
-        for kind, preds in (("inv", p.invariants), ("goal", p.goals)):
-            for name, fn in preds.items():
-                hit = bool(jax.vmap(fn)(state)[0])
-                if kind == "inv" and not hit:
-                    return SearchOutcome("INVARIANT_VIOLATED", 1, 1, 0,
-                                         time.time() - t0,
-                                         violating_state=state,
-                                         predicate_name=name)
-                if kind == "goal" and hit:
-                    return SearchOutcome("GOAL_FOUND", 1, 1, 0,
-                                         time.time() - t0,
-                                         goal_state=state,
-                                         predicate_name=name)
+        for name, hit in zip(self.p.invariants, inv_hits):
+            if not hit:
+                return SearchOutcome("INVARIANT_VIOLATED", 1, 1, 0,
+                                     time.time() - t0,
+                                     violating_state=state,
+                                     predicate_name=name)
+        for name, hit in zip(self.p.goals, goal_hits):
+            if hit:
+                return SearchOutcome("GOAL_FOUND", 1, 1, 0,
+                                     time.time() - t0,
+                                     goal_state=state,
+                                     predicate_name=name)
         return None
 
     def _terminal_outcome(self, rows, np_valids, np_exc, flags,

@@ -933,22 +933,33 @@ class ShardedTensorSearch(TensorSearch):
 
     # ----------------------------------------------------------------- run
 
-    def _root_ids(self, state):
-        """Root row + sanitized key + its owner device and home slot —
-        shared by _init_carry and the AOT warm-up."""
-        rows0 = flatten_state(state)                     # [1, lanes] device
-        # Root key through the same canonicalize-then-hash step the
-        # expand programs use (symmetry reduction, ISSUE 15b).
-        fp0 = np.asarray(self._canonical_root_fp(state),
-                         np.uint32)                      # [1, 4]
+    def _root(self, state, hits: bool = True):
+        """All a run asks of its root, by ONE launch of the engine's
+        root program and one readback: host ``(row0 [1, lanes], fp0
+        [1, 4])`` and, with ``hits``, the invariants' and the goals'
+        verdicts at the root after them.  The key comes through the
+        same canonicalize-then-hash step the expand programs use
+        (symmetry reduction, ISSUE 15b)."""
+        out = self._root_program()(state)
+        return jax.device_get(out if hits else out[:2])
+
+    def _root_ids(self, row0, fp0):
+        """From what ``_root`` read back: the carry initialiser's
+        arguments (the root's row and its sanitized key), and the owner
+        device and home slot that pick the initialiser — shared by
+        ``_root_carry``, the AOT warm-up and the dispatch-site table."""
         owner = int(fp0[0, 0]) % self.n_devices
         key0 = visited_mod.host_sanitize_key(fp0[0])
         # The root key sits in slot 0 of its home BUCKET — addressing
         # mirrored from visited.py (bucket keyed by lane 2).
         home = visited_mod.host_home_slot(key0, self.v_cap)
-        return rows0, key0, owner, home
+        return (row0[0], key0), owner, home
 
     def _init_carry(self, state) -> dict:
+        """The sharded carry of a search that starts at ``state``."""
+        return self._root_carry(*self._root(state, hits=False))
+
+    def _root_carry(self, row0, fp0) -> dict:
         """Build the sharded carry ON DEVICE: the big buffers (frontier,
         next-frontier, visited table — hundreds of MB) are jnp
         allocations inside a jitted initializer, with only the root row
@@ -956,11 +967,10 @@ class ShardedTensorSearch(TensorSearch):
         device_put would ship the whole carry (~750 MB at the bench
         caps) host->device on every run() — inside the bench's measured
         window."""
-        rows0, key0, owner, home = self._root_ids(state)
+        args, owner, home = self._root_ids(row0, fp0)
         init = self._prog(("init", owner, home),
                           self._init_prog(owner, home))
-        return self._dispatch("sharded.init", init, rows0[0],
-                              jnp.asarray(key0))
+        return self._dispatch("sharded.init", init, *args)
 
     def _init_prog(self, owner: int, home: int):
         """The jitted carry initializer for a given root owner/home slot
@@ -1104,11 +1114,12 @@ class ShardedTensorSearch(TensorSearch):
             compile_("superstep", "superstep", self._superstep,
                      sds, b, *mask_args)
             compile_("promote", "promote", self._finish_level, sds)
-            rows0, key0, owner, home = self._root_ids(
-                self.initial_state())
+            # The root program compiles where it is first called: here,
+            # on the twin's own root, so that run() finds it compiled.
+            args, owner, home = self._root_ids(
+                *self._root(self.initial_state(), hits=False))
             compile_(("init", owner, home), "init_carry",
-                     self._init_prog(owner, home), rows0[0],
-                     jnp.asarray(key0))
+                     self._init_prog(owner, home), *args)
         secs = time.time() - t0
         self.compile_secs = getattr(self, "compile_secs", 0.0) + secs
         tel = getattr(self, "_telemetry", None)
@@ -1172,10 +1183,10 @@ class ShardedTensorSearch(TensorSearch):
                   * OVERFLOW_FACTOR)
         sites["visited.insert"] = visited_mod.dispatch_site_program(
             self.v_cap, self.n_devices * bucket)
-        rows0, key0, owner, home = self._root_ids(self.initial_state())
+        args, owner, home = self._root_ids(
+            *self._root(self.initial_state(), hits=False))
         sites["sharded.init"] = dict(
-            fn=self._init_prog(owner, home),
-            args=(rows0[0], jnp.asarray(key0)), donate=(),
+            fn=self._init_prog(owner, home), args=args, donate=(),
             multi=True, builder=None)
         if self._spill_on:
             progs = self._sh_spill_progs()
@@ -1770,10 +1781,14 @@ class ShardedTensorSearch(TensorSearch):
         # The host's work before the first level (telemetry.PHASES;
         # ``search.carry`` in _run_levels is its second half).
         with tel_mod.phase("search.start"):
-            state = (jax.tree.map(jnp.asarray, initial)
-                     if initial is not None else self.initial_state())
-            # Root of this run's trace (tpu/trace.py replays from here).
-            self._trace_root = jax.tree.map(np.asarray, state)
+            # One launch, one readback (``_root``): row, key and the
+            # initial check's verdicts; what follows is host work.
+            root = self._root(
+                initial if initial is not None else self.initial_state(),
+                hits=check_initial)
+            # Root of this run's trace (tpu/trace.py replays from here):
+            # host views of the row read back.
+            self._trace_root = self.unflatten_rows(root[0])
             self._fp_map = {}
             self._deep_samples = None
             # Structured per-level throughput records (depth, chunks,
@@ -1783,10 +1798,10 @@ class ShardedTensorSearch(TensorSearch):
             # phase carries the same counters into a profile.
             self._level_records: List[dict] = []
             self._pd_prev_explored = [0] * self.n_devices
-            self._root_fp = tuple(np.asarray(
-                self._canonical_root_fp(state), np.uint32)[0].tolist())
+            self._root_fp = tuple(root[1][0].tolist())
             if check_initial:
-                out = self._check_initial(state, t0)
+                out = self._initial_verdict(*root[2:], self._trace_root,
+                                            t0)
                 if out is not None:
                     return self._stamp_device(out)
 
@@ -1794,7 +1809,7 @@ class ShardedTensorSearch(TensorSearch):
         if tel is not None and self._spill is not None:
             self._spill.telemetry = tel
         try:
-            out = self._run_levels(t0, state, resume)
+            out = self._run_levels(t0, root, resume)
             out.levels = self._level_records or None
             out.compile_secs = round(getattr(self, "compile_secs", 0.0), 3)
             self._stamp_device(out)
@@ -1840,7 +1855,7 @@ class ShardedTensorSearch(TensorSearch):
             # dump landing; the thread holds device snapshots alive).
             self._join_checkpoint()
 
-    def _run_levels(self, t0, state, resume) -> SearchOutcome:
+    def _run_levels(self, t0, root, resume) -> SearchOutcome:
         with self.mesh:
             with tel_mod.phase("search.carry"):
                 resumed = self._load_checkpoint() if resume else None
@@ -1863,7 +1878,7 @@ class ShardedTensorSearch(TensorSearch):
                         # resumed branch restores the tier from the dump
                         # instead).
                         self._spill.reset_run()
-                    carry = self._init_carry(state)
+                    carry = self._root_carry(*root[:2])
                     depth = 0
                     max_n = 1
                     explored, vis_total, drops = 0, 1, 0  # the root state

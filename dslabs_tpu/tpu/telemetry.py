@@ -414,12 +414,14 @@ PHASES = (
     # the host's own work around a run's dispatches, each a ``with`` in
     # the frame that does it (ISSUE 38; benchmark/harness/
     # idle_by_span.py sets the device's idle seconds against them).
-    # ``search.start``: run() up to its initial check — the root onto
-    # the device and read back, its fingerprint, the check itself;
+    # ``search.start``: run() up to its initial check — ONE launch of
+    # the engine's root program (row, canonical key, every predicate at
+    # the root: ``jit_root_program`` in a profile, ISSUE 39) and its one
+    # readback, then host work: the trace's root, the key, the verdict;
     # ``search.carry``: _run_levels up to the first level — the root's
-    # row, key, owner and home slot again (``_root_ids``) and the
-    # carry's initialiser (``dispatch.init`` lies inside it), or a
-    # checkpoint's load
+    # owner and home slot from that key (``_root_ids``, host arithmetic)
+    # and the carry's initialiser (``dispatch.init`` lies inside it), or
+    # a checkpoint's load
     "search.start", "search.carry",
     # under ``search.level``: the level's appended (child, parent,
     # event) rows read back and folded into the host's chain map

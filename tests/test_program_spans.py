@@ -149,8 +149,11 @@ def test_every_name_emitted_is_in_the_table(lab_call, sharded_run):
     names = {n["name"] for n in lab_call[2].notes}
     assert {"entry.tensor_bfs", "entry.bind", "entry.build_engine",
             "entry.derive_root", "entry.search", "entry.replay",
-            "search.level", "dispatch.superstep",
-            "compile.event"} <= names
+            "search.level", "dispatch.superstep"} <= names
+    # the traced call is warm, and since ISSUE 39 a warm call's prologue
+    # is one compiled program: JAX traces nothing in it, so it leaves no
+    # ``compile.event`` (a cold jit's are held further down)
+    assert "compile.event" not in names
     assert len(set(tel_mod.PHASES)) == len(tel_mod.PHASES)
     assert len(set(tel_mod.DEVICE_SCOPES)) == len(tel_mod.DEVICE_SCOPES)
 
