@@ -17,7 +17,13 @@ tests/test_lab4_shardstore.py):
    NoTensorTwin) instead of replaying provenance.  This also lets
    object-staged roots (no tensor provenance) seed tensor searches.
 
-Both bindings re-check what the twins value-collapse: app results
+Where a replica group has SEVERAL servers (ShardStoreBaseTest
+``setupStates(G, n, 1, shards)`` with n > 1) the main phase binds
+:class:`ShardStoreMultiBinding` instead: the multi-server twin
+(tpu/specs_lab4.py make_shardstore_multi_protocol), each group a
+Paxos-replicated log, within that twin's stated scope.
+
+All bindings re-check what the twins value-collapse: app results
 resolve from the replayed object state's network via MessageTemplate,
 and RESULTS_OK-class invariants are marked ``value_level`` so the
 backend's sampled exhaust re-check covers them object-side.
@@ -33,9 +39,13 @@ from dslabs_tpu.tpu.adapters.paxos import _workload_pairs
 from dslabs_tpu.tpu.backend import (NoTensorTwin, TwinBinding,
                                     register_adapter)
 
-__all__ = ["JoinBinding", "ShardStoreBinding"]
+__all__ = ["JoinBinding", "ShardStoreBinding", "ShardStoreMultiBinding"]
 
 PAXOS_ID = "paxos"
+# What the multi-server twin models, for every refusal that names it.
+MULTI_SCOPE = ("2 groups of n >= 2 servers each, one shard master with "
+               "its timers off, the config controller off, one store "
+               "client whose one command is PUT key-1")
 
 
 def _single(seq, what: str):
@@ -113,6 +123,28 @@ def _validate_joined_root(state, master_name, server_names,
             "config-less send_pending fallback)")
         req(c.pending is not None and c.pending.sequence_num == 1,
             f"{name}'s first command is not pending")
+
+
+def _derive_validated_root(binding, search, state, validate):
+    """``derive_root`` of a binding whose twin's initial state IS the
+    canonical joined root: a state this binding's own searches produced
+    replays its provenance; any other staged state is VALIDATED as the
+    root by ``validate(state)`` (every deviation a loud NoTensorTwin),
+    never replayed."""
+    prov = getattr(state, "_tensor_provenance", None)
+    if prov is not None and prov.key == binding.key:
+        from dslabs_tpu.tpu import backend as _b
+
+        return _b.derive_root(binding, search, state)
+    if getattr(state, "_staged_ops", None):
+        raise NoTensorTwin(
+            "staged network ops on the joined root are not part of "
+            "the canonical lab4 shape")
+    # ``cached`` as every stage of a call has it: 1, the stage built
+    # nothing (the twin's own initial state is the root).
+    with telemetry.phase("entry.root.validate", cached=1):
+        validate(state)
+    return None, []
 
 
 class JoinBinding(TwinBinding):
@@ -281,15 +313,11 @@ class ShardStoreBinding(TwinBinding):
         self.ctl_names = [str(a) for a in ctl_addrs]
         master = state.servers[master_addr]
 
-        # Store groups: exactly one server per group, contiguous ids.
+        # Store groups: one server per group (match_shardstore hands a
+        # state with several to ShardStoreMultiBinding), contiguous ids.
         by_group: Dict[int, object] = {}
         for a, s in state.servers.items():
             if isinstance(s, ShardStoreServer):
-                if s.group_id in by_group:
-                    raise NoTensorTwin(
-                        "shardstore twin models ONE server per group "
-                        f"(group {s.group_id} has several) — use the "
-                        "multi-server twin shapes")
                 by_group[s.group_id] = (a, s)
         self.G = len(by_group)
         if sorted(by_group) != list(range(1, self.G + 1)):
@@ -430,20 +458,7 @@ class ShardStoreBinding(TwinBinding):
         """The twin's initial state IS the canonical joined root — so
         instead of provenance replay, VALIDATE that the staged object
         state matches it field by field (any deviation is loud)."""
-        prov = getattr(state, "_tensor_provenance", None)
-        if prov is not None and prov.key == self.key:
-            from dslabs_tpu.tpu import backend as _b
-
-            return _b.derive_root(self, search, state)
-        if getattr(state, "_staged_ops", None):
-            raise NoTensorTwin(
-                "staged network ops on the joined root are not part of "
-                "the canonical lab4 shape")
-        # ``cached`` as every stage of a call has it: 1, the stage built
-        # nothing (the twin's own initial state is the root).
-        with telemetry.phase("entry.root.validate", cached=1):
-            self._validate(state)
-        return None, []
+        return _derive_validated_root(self, search, state, self._validate)
 
     def _validate(self, state) -> None:
         _validate_joined_root(state, self.master_name,
@@ -816,19 +831,10 @@ class ShardStoreTxBinding(TwinBinding):
                 "tx twin does not model their debris")
 
     def derive_root(self, search, state):
-        prov = getattr(state, "_tensor_provenance", None)
-        if prov is not None and prov.key == self.key:
-            from dslabs_tpu.tpu import backend as _b
-
-            return _b.derive_root(self, search, state)
-        if getattr(state, "_staged_ops", None):
-            raise NoTensorTwin(
-                "staged network ops on the joined root are not part of "
-                "the canonical lab4 shape")
-        with telemetry.phase("entry.root.validate", cached=1):
-            _validate_joined_root(state, self.master_name,
-                                  self.server_names, [self.client_name])
-        return None, []
+        return _derive_validated_root(
+            self, search, state, lambda st: _validate_joined_root(
+                st, self.master_name, self.server_names,
+                [self.client_name]))
 
     def build_protocol(self, net_cap, timer_cap):
         from dslabs_tpu.tpu.specs_lab4 import             make_shardstore_tx_protocol
@@ -1010,6 +1016,486 @@ class ShardStoreTxBinding(TwinBinding):
         return None
 
 
+class ShardStoreMultiBinding(TwinBinding):
+    """Main-phase binding for replica groups of SEVERAL servers
+    (ShardStoreBaseTest ``setupStates(2, n, 1, shards)``): two groups of
+    n Paxos-replicated ShardStoreServers, one frozen shard master, one
+    ShardStoreClient worker — the multi-server twin's exact scope
+    (tpu/specs_lab4.py make_shardstore_multi_spec: one ``gpaxos``
+    fragment a group driving the store's effect switch; oracle-verified
+    against the object checker at n = 2 and 3 with one PUT,
+    tests/test_lab4_multi.py).  Anything else is a loud NoTensorTwin
+    that says what binds (:data:`MULTI_SCOPE`).
+
+    Node order mirrors the twin: master 0, group g's server i at
+    ``1 + g * n + i`` (i = its position in the servers' own ``group``
+    tuple, which is the Paxos sub-node's ballot index), client last.
+    In-group Paxos messages and timers travel between the servers'
+    ``paxos`` sub-addresses; log commands are decoded from the twin's
+    command ids (:meth:`_cmd_id` is the map, read off the object
+    network's own messages through MessageTemplate, so a snapshot's
+    values are never guessed)."""
+
+    G, NC, W = 2, 1, 1
+
+    def __init__(self, state, master_addr, kv_addrs, ctl_addrs):
+        from dslabs_tpu.labs.clientserver.kvstore import Put
+        from dslabs_tpu.labs.shardedstore.shardmaster import ShardConfig
+        from dslabs_tpu.labs.shardedstore.shardstore import \
+            ShardStoreServer
+
+        def scope(cond, what):
+            if not cond:
+                raise NoTensorTwin(
+                    f"multi-server shardstore twin: {what}; it models "
+                    + MULTI_SCOPE)
+
+        self.master_name = str(master_addr)
+        self.ctl_names = [str(a) for a in ctl_addrs]
+        scope(len(kv_addrs) == self.NC,
+              f"{len(kv_addrs)} store clients")
+        self.client_name = str(kv_addrs[0])
+
+        by_group: Dict[int, dict] = {}
+        for a, s in state.servers.items():
+            if isinstance(s, ShardStoreServer):
+                by_group.setdefault(s.group_id, {})[a] = s
+        scope(sorted(by_group) == list(range(1, self.G + 1)),
+              f"group ids {sorted(by_group)}")
+        self.server_addrs: List[list] = []
+        for g in range(1, self.G + 1):
+            members = list(next(iter(by_group[g].values())).group)
+            scope(set(members) == set(by_group[g])
+                  and all(list(s.group) == members
+                          for s in by_group[g].values()),
+                  f"group {g}'s servers disagree on its members")
+            self.server_addrs.append(members)
+        self.n = len(self.server_addrs[0])
+        scope(self.n >= 2 and all(len(m) == self.n
+                                  for m in self.server_addrs),
+              f"groups of {[len(m) for m in self.server_addrs]} servers")
+        self.server_names = [[str(a) for a in m]
+                             for m in self.server_addrs]
+        first = by_group[1][self.server_addrs[0][0]]
+        self.num_shards = first.num_shards
+
+        master = state.servers[master_addr]
+        app = master.app.application if master.app is not None else None
+        configs = getattr(app, "configs", None)
+        scope(bool(configs) and len(configs) == self.G
+              and all(isinstance(c, ShardConfig) for c in configs),
+              f"the master holds {len(configs or [])} configs, not one "
+              "a group")
+        self.configs: List[ShardConfig] = list(configs)
+        self._check_config_walk(scope)
+
+        pairs = _workload_pairs(state.client_workers()[kv_addrs[0]],
+                                kv_addrs[0])
+        scope(len(pairs) == self.W, f"{len(pairs)} client commands")
+        for k, (cmd, _res) in enumerate(pairs, start=1):
+            scope(isinstance(cmd, Put) and cmd.key == f"key-{k}",
+                  f"client command {k} is {cmd!r}")
+        self.pairs = pairs
+
+        self.addr_index = {self.master_name: 0}
+        for g, names in enumerate(self.server_names):
+            for i, name in enumerate(names):
+                self.addr_index[name] = 1 + g * self.n + i
+        self.CLIENT = 1 + self.G * self.n
+        self.addr_index[self.client_name] = self.CLIENT
+        # what the benchmark's driver holds to the factory's kwargs
+        self.shape = (self.G, self.n, self.num_shards, self.W)
+        # the twin's command ids (specs_lab4.make_shardstore_multi_spec)
+        ncmd = self.NC * self.W
+        self.CMD_NC0 = ncmd + 1
+        self.CMD_IS0 = self.CMD_NC0 + self.G
+        self.CMD_MD = self.CMD_IS0 + ncmd + 1
+        self._k_lane = None         # read off the spec's layout on demand
+        self.key = ("shardstore-multi", self.master_name,
+                    self.client_name,
+                    tuple(tuple(names) for names in self.server_names),
+                    self.num_shards,
+                    tuple(repr(c) for c, _ in pairs))
+
+    def _check_config_walk(self, scope) -> None:
+        """The staged master's configs are the walk the twin bakes in
+        (``specs_lab4._staged_configs``: the object ShardMaster on
+        ``Join(1)``, ``Join(2)``), shard mask for shard mask."""
+        from dslabs_tpu.tpu.specs_lab4 import _staged_configs
+
+        got = []
+        for cfg in self.configs:
+            masks = {}
+            for gid, (_members, shards) in cfg.group_info:
+                masks[gid] = sum(1 << (s - 1) for s in shards)
+            got.append(masks)
+        want = _staged_configs(self.G, self.n, self.num_shards)
+        scope(got == want,
+              f"the master's config walk {got} is not the staged "
+              f"Join(1), Join(2) walk {want}")
+
+    def initial_caps(self):
+        return 48, 6
+
+    def twin_key(self):
+        # Beyond ``key``: what the decoders read (expected results, the
+        # configs' numbers).
+        return self.key + (repr([r for _, r in self.pairs]),
+                           tuple(c.config_num for c in self.configs))
+
+    def check_settings(self, settings) -> None:
+        from dslabs_tpu.core.address import LocalAddress
+
+        if settings.should_deliver_timer(LocalAddress(self.master_name)):
+            raise NoTensorTwin(
+                "multi-server shardstore twin freezes the master's "
+                "timers — settings must deliver_timers(master, False)")
+        live = _ctl_live(settings, self.ctl_names, self.master_name)
+        if live:
+            raise NoTensorTwin(
+                f"controllers {live} must be fully suppressed — the "
+                "multi-server shardstore twin does not model their "
+                "debris")
+
+    # ----------------------------------------------------------------- root
+
+    def derive_root(self, search, state):
+        """The twin's initial state IS the canonical joined root: the
+        staged state is VALIDATED as it, field by field, never
+        replayed."""
+        return _derive_validated_root(self, search, state, self._validate)
+
+    def _validate(self, state) -> None:
+        from dslabs_tpu.labs.paxos.paxos import (ElectionTimer,
+                                                 PaxosRequest)
+        from dslabs_tpu.labs.shardedstore.shardmaster import Query
+        from dslabs_tpu.labs.shardedstore.shardstore import (ClientTimer,
+                                                             QueryTimer)
+
+        flat = [name for names in self.server_names for name in names]
+        _validate_joined_root(state, self.master_name, flat,
+                              [self.client_name])
+
+        def req(cond, what):
+            if not cond:
+                raise NoTensorTwin(
+                    f"staged state is not the canonical joined root: "
+                    f"{what}")
+
+        by_name = {str(a): s for a, s in state.servers.items()}
+        for name in flat:
+            px = by_name[name].paxos
+            req(px.ballot == (0, 0) and not px.leader
+                and not px.heard_from_leader and not px.p1b_votes
+                and (px.slot_in, px.executed_through,
+                     px.cleared_through) == (1, 0, 0),
+                f"{name}'s paxos sub-node has already taken a step")
+            kinds = [type(t.timer) for t in
+                     state.timers(self._addr(name))]
+            req(kinds == [ElectionTimer, QueryTimer],
+                f"{name}'s timer queue {[k.__name__ for k in kinds]} "
+                "!= [ElectionTimer, QueryTimer]")
+        timers = [t.timer for t in
+                  state.timers(self._addr(self.client_name))]
+        req(timers == [ClientTimer(1)],
+            f"{self.client_name}'s timer queue {timers} != "
+            "[ClientTimer(1)]")
+        # Beside the join's debris (controller <-> master, undeliverable
+        # with the controller off) the network holds the client's two
+        # config queries and nothing else.
+        seqs = []
+        for m in state.network():
+            frm, to = str(m.frm.root_address()), str(m.to.root_address())
+            if frm in self.ctl_names or to in self.ctl_names:
+                continue
+            cmd = getattr(getattr(m.message, "command", None),
+                          "command", None)
+            req(frm == self.client_name and to == self.master_name
+                and isinstance(m.message, PaxosRequest)
+                and cmd == Query(-1),
+                f"unexpected message {m.message!r} from {frm} to {to}")
+            seqs.append(m.message.command.sequence_num)
+        req(sorted(seqs) == [1, 2],
+            f"the client's config queries {sorted(seqs)} != [1, 2]")
+
+    # ------------------------------------------------------------- protocol
+
+    def _spec(self, net_cap=48, timer_cap=6):
+        from dslabs_tpu.tpu.specs_lab4 import make_shardstore_multi_spec
+
+        return make_shardstore_multi_spec(
+            self.G, self.n, self.num_shards, self.W,
+            net_cap=max(net_cap, 48), timer_cap=max(timer_cap, 6))
+
+    def build_protocol(self, net_cap, timer_cap):
+        spec = self._spec(net_cap, timer_cap)
+        # The decoders read records by the spec's OWN tag and field
+        # tables: no second copy of the enum to drift.
+        messages = {tag: spec._mspec[name]
+                    for name, tag in spec._mtag.items()}
+        timers = {tag: name for name, tag in spec._ttag.items()}
+        return dataclasses.replace(
+            spec.compile(),
+            decode_message=lambda rec: self._decode_message(messages,
+                                                            rec),
+            decode_timer=lambda node, rec: self._decode_timer(
+                timers, node, rec))
+
+    # ------------------------------------------------------------ decoders
+
+    def _addr(self, name):
+        from dslabs_tpu.core.address import LocalAddress
+
+        return LocalAddress(name)
+
+    def _node(self, idx: int, sub: bool = False):
+        """Twin node index -> object address (a server's ``paxos``
+        sub-address where the in-group log is meant)."""
+        from dslabs_tpu.core.address import SubAddress
+
+        idx = int(idx)
+        if idx == 0:
+            return self._addr(self.master_name)
+        if idx == self.CLIENT:
+            return self._addr(self.client_name)
+        g, i = divmod(idx - 1, self.n)
+        addr = self.server_addrs[g][i]
+        return SubAddress(addr, PAXOS_ID) if sub else addr
+
+    def _ballot(self, b: int):
+        return (int(b) // self.n, int(b) % self.n)
+
+    def _amo(self, k: int):
+        from dslabs_tpu.labs.clientserver.amo import AMOCommand
+
+        return AMOCommand(self.pairs[k - 1][0],
+                          self._addr(self.client_name), k)
+
+    def _cmd_id(self, c) -> int:
+        """An object log command -> the twin's command id (-1: not in
+        the twin's alphabet, so it matches no record)."""
+        from dslabs_tpu.labs.clientserver.amo import AMOCommand
+        from dslabs_tpu.labs.shardedstore.shardstore import (
+            InstallShards, MoveDone, NewConfig)
+
+        if c is None:
+            return 0
+        if isinstance(c, AMOCommand):
+            return (c.sequence_num
+                    if str(c.client_address) == self.client_name
+                    and 1 <= c.sequence_num <= self.W else -1)
+        if isinstance(c, NewConfig):
+            nums = [cfg.config_num for cfg in self.configs]
+            return (self.CMD_NC0 + nums.index(c.config.config_num)
+                    if c.config.config_num in nums else -1)
+        if isinstance(c, InstallShards):
+            return self.CMD_IS0 + self._snapshot_seq(c.amo)
+        if isinstance(c, MoveDone):
+            return self.CMD_MD
+        return -1
+
+    def _snapshot_seq(self, amo) -> int:
+        """The store client's executed sequence number in a shard
+        snapshot's AMO table (0: none) — the twin's ``samo`` lane."""
+        return max((seq for client, (seq, _res) in amo
+                    if str(client) == self.client_name), default=0)
+
+    def _cmd_fallback(self, cid: int):
+        """``(command, True)`` where the twin's id says everything the
+        command holds; ``(None, False)`` for a shard snapshot, whose
+        values only the object network knows."""
+        from dslabs_tpu.labs.shardedstore.shardstore import (MoveDone,
+                                                             NewConfig)
+
+        if cid == 0:
+            return None, True
+        if 1 <= cid <= self.NC * self.W:
+            return self._amo(cid), True
+        if self.CMD_NC0 <= cid < self.CMD_IS0:
+            return NewConfig(self.configs[cid - self.CMD_NC0]), True
+        if cid == self.CMD_MD:
+            final = self.configs[-1]
+            moved = (self.configs[0].groups()[1][1]
+                     - final.groups()[1][1])
+            return MoveDone(final.config_num, 2, frozenset(moved)), True
+        return None, False
+
+    def _decode_message(self, messages, rec):
+        from dslabs_tpu.labs.clientserver.amo import AMOCommand, AMOResult
+        from dslabs_tpu.labs.paxos import paxos as P
+        from dslabs_tpu.labs.shardedstore.shardmaster import (Query,
+                                                              ShardConfig)
+        from dslabs_tpu.labs.shardedstore.shardstore import (
+            ShardMove, ShardMoveAck, ShardStoreReply, ShardStoreRequest,
+            WrongGroup)
+        from dslabs_tpu.tpu.trace import MessageTemplate
+
+        # Compiled rows are [tag, frm, to, payload...], the payload in
+        # the MessageType's own field order.
+        r = [int(x) for x in rec]
+        mtype = messages.get(r[0])
+        if mtype is None:
+            raise NoTensorTwin(
+                f"unknown multi-server shardstore message tag {r[0]}")
+        f = dict(zip(mtype.fields, r[3:]))
+        name = mtype.name
+        final_num = self.configs[-1].config_num
+        if name == "Query":
+            frm = self._node(r[1])
+            return frm, self._node(0), P.PaxosRequest(
+                AMOCommand(Query(f["arg"]), frm, f["seq"]))
+        if name == "QueryReply":
+            num = self.configs[f["kind"]].config_num
+            return self._node(0), self._node(r[2]), MessageTemplate(
+                P.PaxosReply, None,
+                lambda m, s=f["seq"]: (
+                    m.result.sequence_num == s
+                    and isinstance(m.result.result, ShardConfig)
+                    and m.result.result.config_num == num))
+        if name == "ShardStoreRequest":
+            return (self._node(r[1]), self._node(r[2]),
+                    ShardStoreRequest(self._amo(f["k"])))
+        if name == "ShardStoreReply":
+            res = self.pairs[f["k"] - 1][1]
+            fallback = (ShardStoreReply(AMOResult(res, f["k"]))
+                        if res is not None else None)
+            return self._node(r[1]), self._node(r[2]), MessageTemplate(
+                ShardStoreReply, fallback,
+                lambda m, s=f["k"]: m.result.sequence_num == s)
+        if name == "WrongGroup":
+            return (self._node(r[1]), self._node(r[2]),
+                    WrongGroup(f["k"]))
+        if name == "ShardMove":
+            return self._node(r[1]), self._node(r[2]), MessageTemplate(
+                ShardMove, None,
+                lambda m, v=f["v"]: (
+                    m.config_num == final_num and m.from_group == 1
+                    and self._snapshot_seq(m.amo) == v))
+        if name == "ShardMoveAck":
+            return self._node(r[1]), self._node(r[2]), MessageTemplate(
+                ShardMoveAck, None,
+                lambda m: m.config_num == final_num)
+        # ---- the group's replicated log: sub-node to sub-node
+        frm, to = self._node(r[1], sub=True), self._node(r[2], sub=True)
+        if name == "PaxosRequest":
+            cmd, known = self._cmd_fallback(f["cmd"])
+            return frm, to, MessageTemplate(
+                P.PaxosRequest, P.PaxosRequest(cmd) if known else None,
+                lambda m, c=f["cmd"]: self._cmd_id(m.command) == c)
+        if name == "CatchupRequest":
+            return frm, to, P.CatchupRequest(f["slot"])
+        if name == "CatchupReply":
+            return self._decode_catchup_reply(frm, to, f)
+        ballot = self._ballot(f["b"])
+        if name == "P1a":
+            return frm, to, P.P1a(ballot)
+        if name == "P1b":
+            want = {}
+            for s in range(1, len(mtype.fields)):
+                e = f[f"e{s}"]
+                if e & 1:       # specs_lab4 pack_entry: ex | ch<<1 | ...
+                    want[s] = ((e >> 2) & 0xFFF, e >> 14,
+                               bool((e >> 1) & 1))
+            return frm, to, MessageTemplate(
+                P.P1b, None,
+                lambda m: (m.ballot == ballot and {
+                    s: (b[0] * self.n + b[1], self._cmd_id(c), ch)
+                    for s, (b, c, ch) in m.log} == want))
+        if name == "P2a":
+            cmd, known = self._cmd_fallback(f["cmd"])
+            return frm, to, MessageTemplate(
+                P.P2a, P.P2a(ballot, f["slot"], cmd) if known else None,
+                lambda m, sl=f["slot"], c=f["cmd"]: (
+                    m.ballot == ballot and m.slot == sl
+                    and self._cmd_id(m.command) == c))
+        if name == "P2b":
+            return frm, to, P.P2b(ballot, f["slot"])
+        if name == "Heartbeat":
+            return frm, to, P.Heartbeat(ballot, f["commit"], f["gc"])
+        if name == "HeartbeatReply":
+            return frm, to, P.HeartbeatReply(ballot, f["exec"])
+        raise NoTensorTwin(
+            f"multi-server shardstore twin has no decoder for {name}")
+
+    def _decode_catchup_reply(self, frm, to, f):
+        """specs_lab4's CatchupReply: ``c{k}`` is 1 + the command id of
+        slot base + k - 1, 0 past the reply's last entry."""
+        from dslabs_tpu.labs.paxos import paxos as P
+        from dslabs_tpu.tpu.trace import MessageTemplate
+
+        want = []
+        for k in range(1, len(f)):
+            if f[f"c{k}"] == 0:
+                break
+            want.append((f["base"] + k - 1, f[f"c{k}"] - 1))
+        cmds = [self._cmd_fallback(cid) for _slot, cid in want]
+        fallback = (P.CatchupReply(tuple(
+            (slot, cmd) for (slot, _cid), (cmd, _known)
+            in zip(want, cmds))) if all(known for _cmd, known in cmds)
+            else None)
+        return frm, to, MessageTemplate(
+            P.CatchupReply, fallback,
+            lambda m: [(slot, self._cmd_id(c))
+                       for slot, c in m.entries] == want)
+
+    def _decode_timer(self, timers, node_idx, rec):
+        from dslabs_tpu.labs.paxos import paxos as P
+        from dslabs_tpu.labs.shardedstore.shardstore import (ClientTimer,
+                                                             QueryTimer)
+        from dslabs_tpu.tpu.specs_lab4 import (CLIENT_MS, ELECTION_MAX,
+                                               ELECTION_MIN, HEARTBEAT_MS,
+                                               QUERY_MS)
+
+        # Timer rows are [tag, min, max, payload...].
+        name, p0 = timers.get(int(rec[0])), int(rec[3])
+        if name == "Client":
+            return (self._node(self.CLIENT), ClientTimer(p0),
+                    CLIENT_MS, CLIENT_MS)
+        if name == "Query":
+            return self._node(node_idx), QueryTimer(), QUERY_MS, QUERY_MS
+        sub = self._node(node_idx, sub=True)
+        if name == "Election":
+            return sub, P.ElectionTimer(), ELECTION_MIN, ELECTION_MAX
+        if name == "Heartbeat":
+            return (sub, P.HeartbeatTimer(self._ballot(p0)),
+                    HEARTBEAT_MS, HEARTBEAT_MS)
+        raise NoTensorTwin(
+            f"unknown multi-server shardstore timer tag {int(rec[0])}")
+
+    # ----------------------------------------------------------- predicates
+
+    def predicate(self, tkey):
+        kind = tkey[0]
+        W = self.W
+        if self._k_lane is None:
+            # the client's ``k`` lane, from the spec's own layout
+            self._k_lane = self._spec()._layout()[0][("client", 0, "k")][0]
+        lane = self._k_lane
+
+        def k(s):
+            return s["nodes"][lane]
+
+        def const_true(s):
+            return k(s) >= 1
+        const_true.value_level = True
+
+        if kind in ("RESULTS_OK", "RESULTS_LINEARIZABLE",
+                    "ALL_RESULTS_SAME"):
+            return const_true
+        if kind == "CLIENTS_DONE":
+            return lambda s: k(s) == W + 1
+        if kind in ("CLIENT_DONE", "CLIENT_HAS_RESULTS"):
+            if str(tkey[1].root_address()) != self.client_name:
+                return None
+            if kind == "CLIENT_DONE":
+                return lambda s: k(s) == W + 1
+            num = tkey[2]
+            return lambda s: k(s) >= num + 1
+        if kind == "NONE_DECIDED":
+            return lambda s: k(s) == 1
+        return None
+
+
 @register_adapter
 def match_shardstore(state):
     from dslabs_tpu.labs.paxos.paxos import PaxosClient, PaxosServer
@@ -1057,6 +1543,11 @@ def match_shardstore(state):
     from dslabs_tpu.labs.shardedstore.txkvstore import Transaction
 
     master_addr = _single(masters, "shard master")
+    if len({servers[a].group_id for a in stores}) < len(stores):
+        # Some group has several servers: each group is a replicated
+        # log, which only the multi-server twin carries.
+        return ShardStoreMultiBinding(state, master_addr,
+                                      sorted(kv, key=str), ctl)
     master = servers[master_addr]
     app = master.app.application if master.app is not None else None
     configs = getattr(app, "configs", None)

@@ -35,7 +35,9 @@ the hand-written ones state-for-state (tests/test_compiler.py).
 
 from __future__ import annotations
 
+import contextlib
 import dataclasses
+import functools
 from typing import Callable, Dict, List, Optional, Sequence, Tuple
 
 import numpy as np
@@ -184,6 +186,41 @@ class Fragment:
             self.timer_handlers[timer] = fn
             return fn
         return reg
+
+    def scoped(self, fn):
+        """``fn`` run under this fragment's device scope
+        (:func:`handler_scope`): for a helper of the fragment that
+        OTHER code calls — the including spec's handlers injecting a
+        command into ``gpaxos``' log — so that its operations name the
+        fragment they belong to, whoever called them.  The fragment's
+        own handlers are scoped where they are invoked
+        (:meth:`ProtocolSpec._invoke`)."""
+        return _scoped(self.name, fn)
+
+
+# What a spec built of fragments calls its OWN handlers' scope (the
+# store's effect switch and wiring around lab 4's ``gpaxos``).
+OWN_SCOPE = "spec"
+
+
+def handler_scope(owner: str):
+    """``dslabs.expand.handlers.<owner>``: one scope level under the
+    engine's ``expand.handlers`` naming the :class:`Fragment` (or the
+    spec itself, :data:`OWN_SCOPE`) an operation of the handler vmaps
+    came from.  HLO metadata only; a profile's reader splits the
+    handlers' device time by it (``benchmark/layer_metrics/
+    gpaxos_handlers_pct.deep.py``)."""
+    from dslabs_tpu.tpu import telemetry
+
+    return telemetry.device_scope("expand.handlers." + owner)
+
+
+def _scoped(owner: str, fn):
+    @functools.wraps(fn)
+    def run(*args, **kwargs):
+        with handler_scope(owner):
+            return fn(*args, **kwargs)
+    return run
 
 
 class Ctx:
@@ -431,6 +468,9 @@ class ProtocolSpec:
         # inclusion order — structural identity for the memo
         # fingerprint (service/memo.py).
         self.fragments: List[Tuple[str, str]] = []
+        # handler function -> the fragment that brought it (the device
+        # scope its operations name, :meth:`_invoke`)
+        self._fragment_of: Dict[Callable, str] = {}
         self.max_live_sends = max_live_sends
         # Declarative fault model (ISSUE 19, tpu/faults.py): when set,
         # a hidden controller node kind ("$fault") is appended LAST so
@@ -553,6 +593,7 @@ class ProtocolSpec:
                     f"kind {kind!r}", spec=self.name, kind=kind,
                     field=msg)
             self.handlers[(kind, msg)] = fn
+            self._fragment_of[fn] = fragment.name
         for tmr, fn in fragment.timer_handlers.items():
             if (kind, tmr) in self.timer_handlers:
                 raise SpecError(
@@ -561,6 +602,7 @@ class ProtocolSpec:
                     f"kind {kind!r}", spec=self.name, kind=kind,
                     field=tmr)
             self.timer_handlers[(kind, tmr)] = fn
+            self._fragment_of[fn] = fragment.name
         self.fragments.append((kind, fragment.name))
         self._reindex_types()
 
@@ -1087,13 +1129,25 @@ class ProtocolSpec:
             decode_timer=self.decode_timer,
         )
 
+    def scoped(self, fn):
+        """``fn`` run under the spec's OWN handler scope: for a callback
+        the spec hands to a fragment (lab 4's effect switch, which
+        ``gpaxos`` drives for every executed slot)."""
+        return _scoped(OWN_SCOPE, fn)
+
     def _invoke(self, fn, ctx: "Ctx", payload: dict, typ: str):
         """Run one handler under the compile gate: a KeyError on the
         payload dict (reading a field the message/timer type does not
         declare) surfaces as a structured SpecError naming the handler
-        — the bare-KeyError shape this satellite retires."""
+        — the bare-KeyError shape this satellite retires.  In a spec
+        built of fragments the handler runs under the device scope of
+        the fragment that brought it (the spec's own: ``spec``); a spec
+        with none adds no scope, so its lowered text is what it was."""
+        scope = (handler_scope(self._fragment_of.get(fn, OWN_SCOPE))
+                 if self.fragments else contextlib.nullcontext())
         try:
-            return fn(ctx, payload)
+            with scope:
+                return fn(ctx, payload)
         except KeyError as e:
             name, line = self._handler_id(fn)
             missing = e.args[0] if e.args else "?"

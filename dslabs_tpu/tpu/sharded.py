@@ -38,6 +38,7 @@ through the all_to_all, not a reserved fingerprint value).
 
 from __future__ import annotations
 
+import gc
 import math
 import os
 import re
@@ -1120,6 +1121,11 @@ class ShardedTensorSearch(TensorSearch):
                 *self._root(self.initial_state(), hits=False))
             compile_(("init", owner, home), "init_carry",
                      self._init_prog(owner, home), *args)
+        # Tracing leaves a heap (0.7 M objects for lab 4's multi-server
+        # twin) whose next full collection is a second or more of host
+        # time, due whenever the allocator's counters say: collected
+        # here, it is set-up's and never a search window's.
+        gc.collect()
         secs = time.time() - t0
         self.compile_secs = getattr(self, "compile_secs", 0.0) + secs
         tel = getattr(self, "_telemetry", None)

@@ -1074,6 +1074,18 @@ def _gpaxos_fragment(kind: str, base: int, n: int, S: int,
                                 "gc": (0, S)}),
             MessageType("HeartbeatReply", ("b", "exec"),
                         bounds={"b": bal, "exec": (0, S)}),
+            MessageType("CatchupRequest", ("slot",),
+                        bounds={"slot": (1, S + 1)}),
+            # Chosen entries from slot ``base`` up, contiguous: ``c{k}``
+            # is 1 + the command of slot base + k - 1, and 0 where the
+            # reply holds no such entry (a count would be a seventh
+            # payload lane; P1b's six stay the widest message).
+            MessageType("CatchupReply",
+                        ("base",) + tuple(f"c{s}"
+                                          for s in range(1, S + 1)),
+                        bounds={"base": (1, S + 1)}
+                        | {f"c{s}": (0, cmd_hi + 1)
+                           for s in range(1, S + 1)}),
         ),
         timers=(
             TimerType("Election", (), min_ms=ELECTION_MIN,
@@ -1104,6 +1116,7 @@ def _gpaxos_fragment(kind: str, base: int, n: int, S: int,
         ctx.slot_put("log", "ch", slot, ch, when=when)
 
     def gc_to(ctx, through, when):
+        through = jnp.minimum(through, ctx.get("ex"))
         do = when & (through > ctx.get("cl"))
         ctx.slot_clear_upto("log", through + 1, when=do)
         ctx.put("cl", through, when=do)
@@ -1315,8 +1328,11 @@ def _gpaxos_fragment(kind: str, base: int, n: int, S: int,
         ctx.put("b", hb_b, when=ok)
         ctx.put("hd", 1, when=ok)
         gc_to(ctx, hb_gc, ok)
-        # NO catchup exchange in this lab's alphabet (the object
-        # harness runs small windows; decisions re-arrive via P2a).
+        # A follower learns that a slot was chosen by this exchange and
+        # by a later phase-1 only: nobody broadcasts a decision.
+        ctx.send("CatchupRequest", to=p["_from"],
+                 when=ok & (ctx.get("ex") < hb_commit),
+                 slot=ctx.get("ex") + 1)
         ctx.send("HeartbeatReply", to=p["_from"], when=ok,
                  b=ctx.get("b"), exec=ctx.get("ex"))
 
@@ -1331,6 +1347,30 @@ def _gpaxos_fragment(kind: str, base: int, n: int, S: int,
                    when=ok)
         ctx.put("pm", ctx.get("pm") | (1 << frm_i), when=ok)
         maybe_gc(ctx, ok)
+
+    @frag.on("CatchupRequest")
+    def srv_catchup_request(ctx, p):
+        from_slot = jnp.maximum(p["slot"], ctx.get("cl") + 1)
+        cmds = {}
+        contiguous = jnp.asarray(True)
+        for k in range(S):
+            slot = from_slot + k
+            e_ex, _lb, e_cmd, e_ch = log_get(ctx, slot)
+            contiguous = contiguous & (slot <= ctx.get("ex")) \
+                & (e_ex == 1) & (e_ch == 1)
+            cmds[f"c{k + 1}"] = jnp.where(contiguous, e_cmd + 1, 0)
+        ctx.send("CatchupReply", to=p["_from"], when=cmds["c1"] > 0,
+                 base=from_slot, **cmds)
+
+    @frag.on("CatchupReply")
+    def srv_catchup_reply(ctx, p):
+        for k in range(S):
+            slot, c = p["base"] + k, p[f"c{k + 1}"]
+            e_ex, _lb, _c, e_ch = log_get(ctx, slot)
+            install = (c > 0) & (slot > ctx.get("cl")) \
+                & ~((e_ex == 1) & (e_ch == 1))
+            log_set(ctx, slot, 1, ctx.get("b"), c - 1, 1, when=install)
+        exec_chain(ctx)
 
     @frag.on_timer("Election")
     def srv_election(ctx, p):
@@ -1372,7 +1412,9 @@ def _gpaxos_fragment(kind: str, base: int, n: int, S: int,
             send_p2a(ctx.cond(inflight), s)
         ctx.set_timer("Heartbeat", when=live, b=p["b"])
 
-    return frag, handle_request
+    # The including spec's handlers inject commands through this: its
+    # operations are the log's, whoever calls (device scope ``gpaxos``).
+    return frag, frag.scoped(handle_request)
 
 
 def make_shardstore_multi_spec(n_groups: int = 2, n: int = 3,
@@ -1534,8 +1576,9 @@ def make_shardstore_multi_spec(n_groups: int = 2, n: int = 3,
             # MoveDone
             ctx.put("outf", 0, when=cmd == CMD_MD)
 
+        # the effect switch is the store's, though ``gpaxos`` drives it
         frag, handle_request = _gpaxos_fragment(
-            kname, base, n, S, cmd_hi, exec_effect)
+            kname, base, n, S, cmd_hi, spec.scoped(exec_effect))
         spec.include(kname, frag)
 
         # ---- store-layer wiring (QueryReply/SSREQ/SM/SMACK inject

@@ -438,7 +438,12 @@ PHASES = (
 
 # The ``jax.named_scope`` names inside the device programs
 # (``dslabs.<scope>``): the stages of the chunk step, the level sync
-# and the promote.  Scopes are HLO metadata only.
+# and the promote.  Scopes are HLO metadata only.  Under
+# ``expand.handlers`` a twin built of fragments adds one level more,
+# ``expand.handlers.<fragment>`` (``spec``: the spec's own handlers;
+# tpu/compiler.py ``handler_scope``): a reader that asks for
+# ``expand.`` counts them with the handlers, one that asks by fragment
+# splits them.
 DEVICE_SCOPES = (
     "expand.events", "expand.handlers", "expand.canon", "fingerprint",
     "flags", "pack", "trace_meta", "route", "exchange", "visited_insert",

@@ -458,6 +458,52 @@ def test_shardkv_deep_programs_compile(topo):
     assert _table_sized_in_loops(text, limit=1 << 27) == []
 
 
+@pytest.mark.slow
+def test_shardkv_n3_deep_programs_compile(topo):
+    """Lab 4's multi-server twin (the benchmark's ``shardkv-n3-deep``
+    cell, ``setupStates(2, 3, 1, 10)``) as its driver builds it, at the
+    configuration's caps: superstep, promote and root init compile for
+    one described chip, and the superstep's plan is what the
+    configuration's ``sizing`` records — four frontier-sized buffers of
+    1,507,328 rows of 1,792 bytes (10.8 GB), the 2^24-slot table and
+    4.1 GiB of chunk temporaries (49,152 successors of 1,272 lanes:
+    this twin's rows are 2.9 times ``shardkv-deep``'s), 14.10 GiB of
+    15.75 (14.47 before the group log carried its catch-up handlers;
+    one variant of them compiled with ONE entry copy of ``nxt``, 11.64
+    GiB: the plan follows the handler table, and the cap is sized for
+    two copies).  Five minutes of compile here (28 MB of optimised
+    text): ``-m slow``."""
+    from benchmark.drivers.timeboxed_bfs import build_protocol
+    from benchmark.harness import manifest
+
+    cell = manifest.load_cell(
+        os.path.dirname(os.path.dirname(os.path.abspath(__file__))),
+        "shardkv-n3-deep")
+    eng = cell.config["engine"]
+    search = ShardedTensorSearch(
+        build_protocol(cell.config["protocol"]),
+        _mesh(topo, 1), chunk_per_device=eng["chunk"],
+        frontier_cap=eng["frontier_cap"], visited_cap=eng["visited_cap"],
+        strict=True, ev_budget=tuple(eng["ev_budget"]))
+    assert (search.lanes, search.bytes_per_state) == (
+        cell.config["protocol"]["lanes"],
+        cell.config["protocol"]["packed_bytes_per_state"])
+    exes = _aot(search)
+    _fits(exes)
+    mem = exes["superstep"].memory_analysis()
+    live = (max(mem.argument_size_in_bytes, mem.output_size_in_bytes)
+            + mem.temp_size_in_bytes)
+    assert live == pytest.approx(
+        cell.config["sizing"]["bytes"]["superstep_live_by_memory_analysis"],
+        abs=64 << 20)
+    assert live < 14.6 * (1 << 30)
+    text = exes["superstep"].as_text()
+    assert "all-to-all" not in text
+    # the handlers' operations name their fragment in the chip's text
+    assert "dslabs.expand.handlers.gpaxos" in text
+    assert "dslabs.expand.handlers.spec" in text
+
+
 def _flagship_search(mesh, chunk):
     from bench import _bench_protocol
 

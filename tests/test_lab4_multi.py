@@ -1,4 +1,6 @@
-"""Multi-server-group lab 4 twin (tpu/protocols/shardstore_multi.py):
+"""Multi-server-group lab 4 twin (tpu/specs_lab4.py
+``make_shardstore_multi_protocol``; the hand twin it replaced is kept as
+a fixture, tests/fixtures/hand_twins/shardstore_multi.py):
 depth-by-depth unique-count parity for the ``setupStates(2, 3, 1, 10)``
 shape — 2 groups x 3 Paxos-replicated ShardStoreServers with REAL
 in-group log lanes (the round-3 verdict's missing capability).
@@ -24,7 +26,14 @@ git history; the deeper runs are round-5 additions):
 The twin starts from the equivalent staged state by construction
 (init_* in the twin factory mirror the object staging: two pending
 client config queries, per-server election + query timers, client retry
-timer)."""
+timer).
+
+These two sweeps are raw ``TensorSearch`` to depth 5 behind
+``DSLABS_SLOW_TESTS``.  The tier-1 run holds the n = 2 shape at depths
+1-3 through the packed ``ShardedTensorSearch`` and through the lab entry
+(tests/test_lab4_multi_deep.py, tests/test_lab4_multi_entry.py), and the
+benchmark's cell ``shardkv-n3-deep`` holds n = 3 to depth 6 on the chip
+on every run (PR 40)."""
 
 import os
 

@@ -182,7 +182,7 @@ _DESCRIPTORS = {
         (430, 136), "packed:136w:635a2640"),
     "lab4-shardstore-multi": (
         specs_lab4.make_shardstore_multi_protocol, False,
-        (1272, 448), "packed:448w:d7f14aad"),
+        (1272, 448), "packed:448w:7782eaa4"),
     "synthetic-raw-beside-1-bit": (
         _synthetic_protocol, False, (28, 16), "packed:16w:9dcf3d72"),
 }
