@@ -22,7 +22,10 @@ back to the CPU.  Phases, each printing one JSON line:
                  a pinned unique count, then depth 10 / 60 s (printed,
                  not judged).
 3. ``warm``      a second construction of the flagship search whose
-                 compile seconds show the persistent cache was hit.
+                 compile seconds show that its programs were LOADED —
+                 from the executable store (tpu/compile_cache.py) or
+                 the persistent cache — and whose superstep has the
+                 text, hash for hash, of the one compiled in place.
 
 ``--four-chips`` runs ONE phase and nothing else: the flagship protocol
 on ``make_mesh(4)`` of four real devices against the one-device engine
@@ -113,10 +116,15 @@ def _peak_bytes(devices=None):
 
 @contextlib.contextmanager
 def _cache_events():
-    """Count JAX's own persistent-compile-cache events (hits, misses)
-    while the block runs — the direct evidence of whether a
-    construction compiled or read the cache."""
+    """Count what a construction LOADED instead of compiling while the
+    block runs — JAX's own persistent-compile-cache events (hits,
+    misses) and the executable store's hits (tpu/compile_cache.py: a
+    program loaded there is never looked up in JAX's cache), which
+    count among ``hits`` — the direct evidence of whether a
+    construction compiled or read the disk."""
     import jax.monitoring
+
+    from dslabs_tpu.tpu import compile_cache
 
     seen = {"hits": 0, "misses": 0}
 
@@ -126,11 +134,22 @@ def _cache_events():
         elif event == "/jax/compilation_cache/cache_misses":
             seen["misses"] += 1
 
+    stored = compile_cache.totals()["exe_store_hit_n"]
     jax.monitoring.register_event_listener(listener)
     try:
         yield seen
     finally:
         jax.monitoring.unregister_event_listener(listener)
+        seen["hits"] += compile_cache.totals()["exe_store_hit_n"] - stored
+
+
+def _text_hash(search) -> str:
+    """SHA-256 of the optimised text of an engine's superstep
+    executable, as its warm-up compiled or loaded it."""
+    import hashlib
+
+    return hashlib.sha256(
+        search._aot_exes["superstep"].as_text().encode()).hexdigest()
 
 
 def _outcome_fields(out) -> dict:
@@ -331,6 +350,7 @@ def flagship_phase(chunk: int, frontier_cap: int, visited_cap: int,
         "wall_secs": round(wall, 3),
         "cache_dir": compile_cache.cache_dir(),
         "peak_bytes": _peak_bytes(mesh.devices.flat),
+        "superstep_text": _text_hash(sup._engines["sharded"]),
     })
 
     sup.max_depth, sup.max_secs = deep_depth, deep_secs
@@ -351,9 +371,10 @@ def flagship_phase(chunk: int, frontier_cap: int, visited_cap: int,
 def warm_phase(chunk: int, frontier_cap: int, visited_cap: int,
                first: dict) -> dict:
     """A SECOND construction of the flagship engine in this process:
-    new jit objects, so the in-memory caches miss and the AOT warm-up's
-    ``.lower().compile()`` goes to the persistent cache.  It must HIT
-    (JAX's own cache-hit events), and where the ``first`` construction
+    new jit objects, so the in-memory caches miss and the AOT warm-up
+    goes to the disk — the executable store, else ``.lower().compile()``
+    and the persistent cache.  It must HIT (the store's counter, JAX's
+    own cache-hit events), and where the ``first`` construction
     (``flagship_phase``'s record) was cold its compile seconds must
     drop to under half."""
     from bench import _bench_protocol
@@ -375,7 +396,15 @@ def warm_phase(chunk: int, frontier_cap: int, visited_cap: int,
         or search.compile_secs < 0.5 * first["compile_secs"]),
         f"warm construction: {events['hits']} cache hits, compiled for "
         f"{search.compile_secs:.1f}s against {first['compile_secs']:.1f}s"
-        f" — the persistent cache at {cache} was not hit")
+        f" — neither the store nor the persistent cache at {cache} was "
+        f"hit")
+    # Whatever the warm-up was handed — an executable loaded from the
+    # store, or from the persistent cache — is the program the first
+    # construction compiled, hash for hash.
+    text = _text_hash(search)
+    _check(text == first.get("superstep_text", text),
+           f"warm construction: superstep text {text[:12]} is not the "
+           f"first construction's {first.get('superstep_text', '')[:12]}")
     out = search.run()
     _check(out.end_condition == "DEPTH_EXHAUSTED", out.end_condition)
     return _emit({
@@ -385,7 +414,8 @@ def warm_phase(chunk: int, frontier_cap: int, visited_cap: int,
         "first_was_cold": first_was_cold,
         "warm_compile_secs": round(search.compile_secs, 3),
         "cache_hits": events["hits"], "cache_misses": events["misses"],
-        "cache_dir": cache,
+        "store_hits": compile_cache.totals()["exe_store_hit_n"],
+        "superstep_text": text, "cache_dir": cache,
         "cache_entries": len(os.listdir(cache)),
         "verdict": out.end_condition, "unique": out.unique_states,
     })

@@ -52,6 +52,7 @@ table, and prints one JSON line.
 from __future__ import annotations
 
 import ast
+import dataclasses
 import hashlib
 import inspect
 import json
@@ -64,7 +65,8 @@ from typing import Dict, List, Optional, Tuple
 
 __all__ = ["MemoStore", "MemoPlan", "MEMO_FORMAT", "memo_enabled",
            "memo_dir", "introspect_protocol", "introspect_child",
-           "factory_source_hash", "env_fingerprint", "key_fields",
+           "program_fingerprint", "factory_source_hash",
+           "env_fingerprint", "key_fields",
            "verdict_key", "sig_key", "divergence_depth",
            "witness_digest", "UNCACHEABLE_ENDS"]
 
@@ -117,10 +119,14 @@ def _sha(obj) -> str:
 class _HashAcc:
     """Accumulates value hashes; remembers when a closure cell could
     only be hashed by TYPE (not value) — such fingerprints are marked
-    weak and the store refuses to memoize on them."""
+    weak and the store refuses to memoize on them — and the source
+    ``files`` of every function hashed (``co_filename``): a function's
+    globals are not in its hash, its module's file is what they come
+    from."""
 
     def __init__(self):
         self.weak = False
+        self.files: set = set()
 
 
 def _fn_ast_hash(fn, acc: _HashAcc) -> str:
@@ -129,6 +135,7 @@ def _fn_ast_hash(fn, acc: _HashAcc) -> str:
     but behaviorally identical function hashes the same.  Closure cell
     VALUES participate (a spec parameterized by ``workload_size``
     captures it), via :func:`_code_hash`."""
+    acc.files.add(fn.__code__.co_filename)
     try:
         src = textwrap.dedent(inspect.getsource(fn))
         tree = ast.parse(src)
@@ -170,6 +177,7 @@ def _closure_values(fn, acc: _HashAcc) -> list:
 
 def _code_hash(fn, acc: _HashAcc) -> str:
     code = fn.__code__
+    acc.files.add(code.co_filename)
     consts = [_value_hash(c, acc) for c in code.co_consts]
     return _sha({"co": hashlib.sha256(code.co_code).hexdigest(),
                  "consts": consts, "names": code.co_names,
@@ -213,6 +221,13 @@ def _value_hash(v, acc: _HashAcc, depth: int = 0) -> str:
             pass
     if isinstance(v, type(os)):  # a module: name is its identity
         return f"<module:{v.__name__}>"
+    if dataclasses.is_dataclass(v) and not isinstance(v, type):
+        # A declaration captured whole (a ``Slots`` block in an election
+        # handler's closure): its class by name, its fields by value.
+        return _sha([type(v).__module__, type(v).__qualname__,
+                     [[f.name, _value_hash(getattr(v, f.name), acc,
+                                           depth + 1)]
+                      for f in dataclasses.fields(v)]])
     acc.weak = True
     return f"<type:{type(v).__module__}.{type(v).__qualname__}>"
 
@@ -480,6 +495,97 @@ def _union_effects(a: Dict[str, dict],
     return out
 
 
+def _structure(proto, acc: _HashAcc):
+    """``(spec or None, base, handlers)`` of one live protocol object:
+    what :func:`introspect_protocol` and :func:`program_fingerprint`
+    both hash, read without running a handler."""
+    spec = _recover_spec(proto)
+    if spec is not None:
+        base = _spec_base(spec, acc)
+        handlers = {
+            f"m:{k}:{m}": _fn_ast_hash(fn, acc)
+            for (k, m), fn in sorted(spec.handlers.items())}
+        handlers.update({
+            f"t:{k}:{t}": _fn_ast_hash(fn, acc)
+            for (k, t), fn in sorted(spec.timer_handlers.items())})
+    else:
+        base = _twin_base(proto, acc)
+        handlers = {
+            "step_message": _fn_ast_hash(proto.step_message, acc),
+            "step_timer": _fn_ast_hash(proto.step_timer, acc)}
+    return spec, base, handlers
+
+
+def _predicate_hash(proto, fn, acc: _HashAcc) -> str:
+    """One state predicate of ``proto`` for :func:`program_fingerprint`:
+    its AST and closure values where those hash by value, else its
+    TRACE — a lab binding's predicates close over the binding itself
+    (an object, hashed by type alone), whose commands' values the twin
+    is blind to; the jaxpr of the predicate over the protocol's
+    abstract state, constants by their bytes, is all a program can take
+    from it, and is the same for every such value.  A predicate that
+    cannot be traced marks the fingerprint weak."""
+    own = _HashAcc()
+    structural = _fn_ast_hash(fn, own)   # the wrapper too: it is traced
+    acc.files |= own.files
+    if not own.weak:
+        return structural
+    try:
+        import jax
+        import jax.numpy as jnp
+
+        def lanes(*shape):
+            return jax.ShapeDtypeStruct(shape, jnp.int32)
+
+        closed = jax.make_jaxpr(fn)({
+            "nodes": lanes(proto.node_width),
+            "net": lanes(proto.net_cap, proto.msg_width),
+            "timers": lanes(proto.n_nodes, proto.timer_cap,
+                            proto.timer_width),
+            "exc": lanes()})
+        return _sha({"jaxpr": str(closed.jaxpr),
+                     "consts": [_value_hash(c, acc) for c in closed.consts]})
+    except Exception:  # noqa: BLE001 — no trace, no voucher
+        acc.weak = True
+        return structural
+
+
+# Fields of a ``TensorProtocol`` that no traced program reads (the
+# display name and the host-side decoders of a witness), and those
+# :func:`_predicate_hash` reads.
+_HOST_ONLY = ("name", "decode_message", "decode_timer")
+_PREDICATES = ("goals", "invariants", "prunes")
+
+
+def program_fingerprint(proto) -> dict:
+    """What the programs an engine traces from ``proto`` are a function
+    of, as far as the protocol goes, for the executable store
+    (tpu/compile_cache.py): the structural fingerprint the verdict
+    cache uses — spec base or twin base, handler ASTs with closure
+    values hashed by VALUE — without the handler-effect pass (that one
+    RUNS the handlers); the state predicates (:func:`_predicate_hash`);
+    and what a compiled twin carries beside its spec: its widths, caps
+    and initial arrays (``_twin_base``) and EVERY other field of the
+    dataclass by value — the masks, and whatever is added later — less
+    :data:`_HOST_ONLY` (a field left out here by mistake is a stale
+    program; one hashed twice is nothing).  ``weak``: some value could
+    only be hashed by type; ``files``: the source file of every function
+    hashed."""
+    acc = _HashAcc()
+    spec, base, handlers = _structure(proto, acc)
+    twin = _twin_base(proto, acc) if spec is not None else base
+    predicates = [[role, name, _predicate_hash(proto, fn, acc)]
+                  for role in _PREDICATES
+                  for name, fn in sorted(getattr(proto, role).items())]
+    fields = [[f.name, _value_hash(getattr(proto, f.name), acc)]
+              for f in dataclasses.fields(proto)
+              if f.name not in _HOST_ONLY + _PREDICATES]
+    fp = _sha({"base": base, "twin": twin,
+               "handlers": sorted(handlers.items()),
+               "predicates": predicates, "fields": fields})
+    return {"fp": fp, "weak": acc.weak, "files": sorted(acc.files)}
+
+
 def introspect_protocol(proto, env: Optional[dict] = None) -> dict:
     """The full memo view of one live protocol object: structural
     fingerprint (base + handlers + predicates), handler effect table
@@ -490,27 +596,16 @@ def introspect_protocol(proto, env: Optional[dict] = None) -> dict:
 
     e = env if env is not None else os.environ
     acc = _HashAcc()
-    spec = _recover_spec(proto)
+    spec, base, handlers = _structure(proto, acc)
+    predicates = _proto_predicates(proto, acc)
     if spec is not None:
-        base = _spec_base(spec, acc)
-        handlers = {
-            f"m:{k}:{m}": _fn_ast_hash(fn, acc)
-            for (k, m), fn in sorted(spec.handlers.items())}
-        handlers.update({
-            f"t:{k}:{t}": _fn_ast_hash(fn, acc)
-            for (k, t), fn in sorted(spec.timer_handlers.items())})
         effects = _handler_effects(spec)
         initial = _initial_events(spec)
         kind = "spec"
     else:
-        base = _twin_base(proto, acc)
-        handlers = {
-            "step_message": _fn_ast_hash(proto.step_message, acc),
-            "step_timer": _fn_ast_hash(proto.step_timer, acc)}
         effects = None
         initial = None
         kind = "twin"
-    predicates = _proto_predicates(proto, acc)
     base_fp = _sha(base)
     spec_fp = _sha({"base": base_fp, "handlers": sorted(handlers.items()),
                     "predicates": sorted(predicates.items())})

@@ -62,7 +62,7 @@ from typing import Any, Callable, Dict, List, Optional, Tuple
 
 import numpy as np
 
-from dslabs_tpu.tpu import telemetry
+from dslabs_tpu.tpu import compile_cache, telemetry
 
 __all__ = ["NoTensorTwin", "TensorProvenance", "TwinBinding",
            "cache_info", "clear_cache", "register_adapter", "tensor_bfs",
@@ -301,15 +301,11 @@ class _Engine:
         telemetry.register_program(telemetry.KEPT_SUPERSTEP, self)
 
     def as_text(self) -> str:
-        """The optimised text of this engine's superstep.  The lab entry
-        dispatches the lazy jit (no executable to keep), so the program
-        is lowered again here, from the dispatch site's own abstract
-        arguments, the first time a traced run asks: seconds of host
-        time, paid by that reader alone."""
+        """The optimised text of this engine's superstep: the
+        executable's that its build compiled or loaded
+        (``aot_warmup``), asked once."""
         if self._text is None:
-            site = self.search.dispatch_site_programs()["sharded.superstep"]
-            self._text = site["fn"].lower(
-                *site["args"]).compile().as_text()
+            self._text = self.search._aot_exes["superstep"].as_text()
         return self._text
 
     def rest(self) -> None:
@@ -468,14 +464,25 @@ def _trace_step(binding: TwinBinding, p):
         from dslabs_tpu.tpu.engine import TensorSearch
 
         with telemetry.phase("entry.root.build"):
+            # No predicate is read by a step: without them the step's
+            # key in the executable store is the twin's and the caps',
+            # as its key in ``_Kept`` is, whichever call asks first.
             replayer = TensorSearch(
                 dataclasses.replace(p, deliver_message=None,
-                                    deliver_timer=None), chunk=1)
+                                    deliver_timer=None, invariants={},
+                                    goals={}, prunes={}), chunk=1)
+            args = (jax.ShapeDtypeStruct((replayer.lanes,), jnp.int32),
+                    jax.ShapeDtypeStruct((), jnp.int32))
             # Compiled here, ahead of the first event, so that a replay
-            # is its events' round trips and nothing else.
-            step = _KEPT.put(key, jax.jit(replayer._step_one).lower(
-                jax.ShapeDtypeStruct((replayer.lanes,), jnp.int32),
-                jax.ShapeDtypeStruct((), jnp.int32)).compile())
+            # is its events' round trips and nothing else — or loaded:
+            # the step traces every handler once more, so the executable
+            # store (tpu/compile_cache.py) is asked first.
+            step = _KEPT.put(key, compile_cache.stored(
+                compile_cache.program_key(replayer.store_key(),
+                                          "step_one", args),
+                "step_one",
+                lambda: jax.jit(replayer._step_one).lower(*args).compile(),
+                replayer._store_devices()))
     return step
 
 
@@ -671,10 +678,21 @@ def _run_tensor(binding: TwinBinding, settings, state, lease: set,
             kept = _KEPT.get(key, lease)
             span.set(cached=int(kept is not None))
             if kept is None:
-                kept = _KEPT.put(key, _Engine(ShardedTensorSearch(
+                # The engine's programs are compiled where it is built,
+                # or loaded from the executable store before anything is
+                # traced (aot_warmup; the masks are arguments of the
+                # superstep, so they are set first): a kept engine
+                # dispatches executables, as a supervisor's does.
+                search = ShardedTensorSearch(
                     protocol, mesh, chunk_per_device=chunk,
                     frontier_cap=f_cap, visited_cap=v_cap, strict=True,
-                    record_trace=True)), lease)
+                    record_trace=True)
+                search.set_runtime_masks(marr, tarr)
+                search.aot_warmup()
+                kept = _KEPT.put(key, _Engine(search), lease)
+            else:
+                # The build's seconds are the call's that built it.
+                kept.search.compile_secs = 0.0
             span.set(engine=kept.serial)
             search = kept.search
             # Everything a call sets on its engine is set on EVERY call,

@@ -57,6 +57,7 @@ fingerprints by hash ownership (see ``dslabs_tpu/tpu/sharded.py``).
 from __future__ import annotations
 
 import dataclasses
+import hashlib
 import os
 from typing import Callable, Dict, List, Optional, Tuple
 
@@ -998,6 +999,57 @@ class TensorSearch:
         from dslabs_tpu.analysis.jaxpr_audit import sanitize_engine
 
         sanitize_engine(self)
+
+    def _store_shape(self) -> tuple:
+        """What this engine's constructor arguments contribute to the
+        shape of the programs it traces (subclasses add theirs): the
+        engine's part of an executable-store key."""
+        return (type(self).__qualname__, self.chunk, self.frontier_cap,
+                self.visited_cap, self.lanes, self.plane, self._off,
+                self._ev_msg, self._ev_tmr, self._ev_flt, self.strict,
+                self.record_trace, self._in_chunk_dedup,
+                self.use_host_visited, self._canon is not None,
+                repr(self._spill.config) if self._spill is not None
+                else None, tuple(self._flag_names))
+
+    def _store_devices(self) -> list:
+        """The devices this engine's programs are compiled for, in
+        their order: where the store loads an executable onto."""
+        return jax.devices()[:1]
+
+    def store_key(self) -> Optional[str]:
+        """The key under which the executable store
+        (tpu/compile_cache.py) keeps this engine's programs, less each
+        program's own part (``compile_cache.program_key`` adds its name
+        and abstract arguments): a digest of everything they are traced
+        from — the process's environment (``environment_key``) with
+        every ``DSLABS_*`` variable (the constructors read several), the
+        protocol's structural fingerprint (service/memo.py
+        ``program_fingerprint``) and :meth:`_store_shape`.  None — the
+        engine then traces as it always did, and nothing is stored —
+        where the fingerprint is weak (a closure hashed by type), where
+        a handler's source is no file, or where building the key fails
+        at all."""
+        from dslabs_tpu.tpu import compile_cache
+
+        with tel_mod.phase("compile.store.key"):
+            try:
+                from dslabs_tpu.service.memo import program_fingerprint
+
+                fp = program_fingerprint(self.p)
+                env = (None if fp["weak"] else
+                       compile_cache.environment_key(
+                           self._store_devices(), fp["files"]))
+                if env is None:
+                    return None
+                knobs = tuple(sorted(
+                    kv for kv in os.environ.items()
+                    if kv[0].startswith("DSLABS_")))
+                return hashlib.sha256(repr(
+                    (env, knobs, fp["fp"], self._store_shape())
+                ).encode()).hexdigest()
+            except Exception:  # noqa: BLE001 — no key, no store
+                return None
 
     def dispatch_site_programs(self) -> Dict[str, dict]:
         """The site-program registry for the sanitizer's jaxpr auditor

@@ -51,6 +51,7 @@ import numpy as np
 from jax import shard_map
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
+from dslabs_tpu.tpu import compile_cache
 from dslabs_tpu.tpu import telemetry as tel_mod
 from dslabs_tpu.tpu import visited as visited_mod
 from dslabs_tpu.tpu.engine import (CapacityOverflow, SearchOutcome,
@@ -1103,12 +1104,18 @@ class ShardedTensorSearch(TensorSearch):
         # would absorb the XLA half, but not the tracing).  A compile
         # error propagates: a program the backend refuses is a fault
         # to report, not a warm-up to skip.
-        def compile_(key, name, jitted, *args):
+        base = self.store_key()
+        devices = self._store_devices()
+
+        def compile_(key, name, jitted, *args, baked=()):
             # ``name`` is the program's in a profile (``jit_<name>``);
             # the executable is also registered under that name for
-            # telemetry.program_scopes (a set insert).
+            # telemetry.program_scopes (a set insert).  The store
+            # (tpu/compile_cache.py) is asked before anything is traced.
             with tel_mod.phase("compile.aot." + name):
-                exes[key] = jitted.lower(*args).compile()
+                exes[key] = compile_cache.stored(
+                    compile_cache.program_key(base, name, args, *baked),
+                    name, lambda: jitted.lower(*args).compile(), devices)
             tel_mod.register_program(name, exes[key])
 
         with tel_mod.phase("compile.aot"):
@@ -1120,7 +1127,8 @@ class ShardedTensorSearch(TensorSearch):
             args, owner, home = self._root_ids(
                 *self._root(self.initial_state(), hits=False))
             compile_(("init", owner, home), "init_carry",
-                     self._init_prog(owner, home), *args)
+                     self._init_prog(owner, home), *args,
+                     baked=(owner, home))
         # Tracing leaves a heap (0.7 M objects for lab 4's multi-server
         # twin) whose next full collection is a second or more of host
         # time, due whenever the allocator's counters say: collected
@@ -1138,6 +1146,19 @@ class ShardedTensorSearch(TensorSearch):
             tel.event("compile", engine="sharded",
                       secs=round(secs, 4), aot=True)
         return secs
+
+    def _store_devices(self) -> list:
+        return list(self.mesh.devices.flat)
+
+    def _store_shape(self) -> tuple:
+        """The base engine's, and what this constructor adds to the
+        programs' shape: the mesh (axes, extent, the devices in their
+        order), the per-device caps and the exchange's options."""
+        return super()._store_shape() + (
+            self.mesh.axis_names, self.mesh.devices.shape,
+            tuple(int(d.id) for d in self._store_devices()),
+            self.cpd, self.f_cap, self.v_cap, self.ev_spill,
+            self.mesh_pack, self._mesh_delta)
 
     def _prog(self, name, default):
         """The AOT-compiled executable for a program when the warm-up

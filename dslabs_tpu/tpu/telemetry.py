@@ -432,7 +432,14 @@ PHASES = (
     # unflattened onto the device)
     "entry.root.eager",
     "compile.aot",                  # aot_warmup, one child per program
-    "compile.event",                # mark: one jax.monitoring event
+    # mark: one jax.monitoring event, or one lookup in the executable
+    # store (``kind`` store_hit / store_miss, ``fun`` the program)
+    "compile.event",
+    # the executable store (tpu/compile_cache.py), under the
+    # ``compile.aot.<program>`` (or ``entry.root.build``) that asks it:
+    # an engine's key built, an entry read and loaded onto the devices,
+    # an entry serialized and written
+    "compile.store.key", "compile.store.load", "compile.store.write",
 ) + tuple(f"compile.aot.{name}" for name in AOT_PROGRAMS) + tuple(
     sorted({"dispatch." + tag.split(".", 1)[1] for tag in DISPATCH_SITES}))
 

@@ -504,6 +504,91 @@ def test_shardkv_n3_deep_programs_compile(topo):
     assert "dslabs.expand.handlers.spec" in text
 
 
+@pytest.mark.slow
+@pytest.mark.parametrize("cell_name", ["paxos3-deep", "shardkv-deep",
+                                       "shardkv-n3-deep"])
+def test_deep_programs_from_the_store_have_the_text_compiled_in_place(
+        topo, cell_name, tmp_path, request):
+    """The executable store (tpu/compile_cache.py, ISSUE 41) under each
+    deep configuration's engine, for the described chip: a first
+    engine's warm-up compiles superstep, promote and root init in place
+    and keeps each — whole, under the stamp of the runtime and the
+    backend the engine's OWN devices belong to (the described TPU's,
+    not the CPU this process runs on) — and a second engine, built
+    anew, asks for them under the very same keys.  What the second is
+    handed has the text of the first, hash for hash (less the tables
+    that say where in the source an operation was traced).  A described
+    chip's client compiles and serializes but cannot LOAD
+    (``DeserializeLoadedExecutable`` is UNIMPLEMENTED there: a doubt,
+    so a miss), so here the second is compiled in place again and the
+    equality says that an entry holds what this source compiles to;
+    with a chip attached the second IS the entry (three hits), and
+    ``chip_smoke.py``'s warm phase holds the loaded superstep to the
+    same equality.  Minutes of compile, twice: ``-m slow``."""
+    import hashlib
+    import pickle
+    import zlib
+
+    from benchmark.drivers.timeboxed_bfs import build_protocol
+    from benchmark.harness import manifest
+    from dslabs_tpu.tpu import compile_cache
+
+    # the store under ``tmp_path`` (by JAX's own setting: a function
+    # patched into ``compile_cache`` would itself be a reason for no key)
+    was = jax.config.jax_compilation_cache_dir
+    jax.config.update("jax_compilation_cache_dir", str(tmp_path))
+    request.addfinalizer(lambda: jax.config.update(
+        "jax_compilation_cache_dir", was))
+    cell = manifest.load_cell(
+        os.path.dirname(os.path.dirname(os.path.abspath(__file__))),
+        cell_name)
+    eng = cell.config["engine"]
+
+    def program(text):
+        # Two traces of one function differ in WHERE each operation came
+        # from — the call stack a second trace runs under is shorter
+        # (FileLocations, StackFrames, every ``stack_frame_id``) — and in
+        # nothing the chip executes: compared without the tables.
+        head, _, rest = text.partition("\nFileNames\n")
+        body = rest[rest.index("\n\n\n"):] if rest else ""
+        return re.sub(r" ?stack_frame_id=\d+", "", head + body)
+
+    def texts():
+        search = ShardedTensorSearch(
+            build_protocol(cell.config["protocol"]), _mesh(topo, 1),
+            chunk_per_device=eng["chunk"],
+            frontier_cap=eng["frontier_cap"],
+            visited_cap=eng["visited_cap"], strict=True,
+            ev_budget=tuple(eng["ev_budget"]))
+        assert search.store_key() is not None
+        return search, {
+            name: hashlib.sha256(
+                program(exe.as_text()).encode()).hexdigest()
+            for name, exe in _aot(search).items()}
+
+    before = compile_cache.totals()
+    first, compiled = texts()
+    entries = sorted(os.listdir(compile_cache.store_dir()))
+    assert len(entries) == 3 and all(e.endswith(".exe") for e in entries)
+    for entry in entries:
+        with open(os.path.join(compile_cache.store_dir(), entry),
+                  "rb") as f:
+            stamp, payload, _in_tree, _out_tree = pickle.loads(
+                zlib.decompress(f.read()))
+        assert stamp == compile_cache._stamp(first._store_devices())
+        assert stamp[2] == "tpu" and payload
+    _second, again = texts()
+    assert again == compiled
+    assert sorted(os.listdir(compile_cache.store_dir())) == entries
+    after = compile_cache.totals()
+    lookups = (after["exe_store_hit_n"] + after["exe_store_miss_n"]
+               - before["exe_store_hit_n"] - before["exe_store_miss_n"])
+    assert lookups == 6
+    print(f"{cell_name}: {after['exe_store_hit_n'] - before['exe_store_hit_n']}"
+          f" of the second engine's 3 programs were loaded; entries "
+          f"{[os.path.getsize(os.path.join(compile_cache.store_dir(), e)) for e in entries]} bytes")
+
+
 def _flagship_search(mesh, chunk):
     from bench import _bench_protocol
 

@@ -342,7 +342,8 @@ def _the_manifest_reads_the_cell_where_the_issue_says(man, entry, traffic,
         "expand_us_per_state.deep", "insert_us_per_state.deep",
         "pack_us_per_state.deep", "scope_coverage_pct.deep",
         "write_blocks_per_step.deep", "compile_s", "peak_hbm_gb",
-        "trace_lower_s", "event_resteps_pct.deep", "grid_fill_pct.deep",
+        "trace_lower_s", "exe_store_hit_pct", "event_resteps_pct.deep",
+        "grid_fill_pct.deep",
         "probe_cols_per_step.deep"}
     for m in man["per_layer"]:
         if m["name"] in ("event_resteps_pct.deep", "grid_fill_pct.deep"):

@@ -359,7 +359,8 @@ def _the_manifest_reads_the_cell_where_the_issue_says(man, params, entry,
             "expand_us_per_state.lab4", "superstep_roofline.lab4",
             "ladder_attempts_per_call.suite", "engine_cache_hit_pct.lab",
             "dispatches_per_call.lab"} <= reads
-    assert all(m["moves"] == "verdict_s" or m["name"] == "warmup_s.lab"
+    assert all(m["moves"] == "verdict_s"
+               or m["name"] in ("warmup_s.lab", "exe_store_hit_pct")
                for m in man["per_layer"] if m["name"] in reads)
     assert not {m for m in reads if m.endswith(".deep")}
 

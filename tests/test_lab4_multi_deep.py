@@ -319,7 +319,8 @@ def _the_manifest_reads_the_cell_where_the_issue_says(man, entry, traffic,
         "expand_us_per_state.deep", "insert_us_per_state.deep",
         "pack_us_per_state.deep", "scope_coverage_pct.deep",
         "write_blocks_per_step.deep", "compile_s", "peak_hbm_gb",
-        "trace_lower_s", "event_resteps_pct.deep", "grid_fill_pct.deep",
+        "trace_lower_s", "exe_store_hit_pct", "event_resteps_pct.deep",
+        "grid_fill_pct.deep",
         "probe_cols_per_step.deep", "gpaxos_handlers_pct.deep"}
     for m in man["per_layer"]:
         if m["name"] == "gpaxos_handlers_pct.deep":
@@ -337,7 +338,10 @@ def _the_manifest_reads_the_cell_where_the_issue_says(man, entry, traffic,
     # them on four chips
     assert man["workloads"][-1]["name"] == CELL
     assert man["configs"][-1]["name"] == entry["config"]
-    assert man["per_layer"][-1]["name"] == "gpaxos_handlers_pct.deep"
+    # (PR 41 appended its one metric behind it)
+    names = [m["name"] for m in man["per_layer"]]
+    assert names[names.index("gpaxos_handlers_pct.deep"):] == [
+        "gpaxos_handlers_pct.deep", "exe_store_hit_pct"]
     assert [w["chips"] for w in man["workloads"]].count(4) == 1
     assert len(man["workloads"]) == 7
 

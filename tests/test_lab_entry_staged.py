@@ -231,7 +231,17 @@ def test_derive_root_writes_its_phases(recorded):
             backend.derive_root(_GenBinding(), search,
                                 _staged(history + [("drop",)]))
         phases = [r for r in tel.ring if r["t"] == "phase"]
+        # the executable store (ISSUE 41) is asked where the step is
+        # built, inside ``entry.root.build``, and nowhere else
+        store = [r["name"] for r in phases
+                 if r["name"].startswith("compile.store.")]
+        phases = [r for r in phases
+                  if not r["name"].startswith("compile.store.")]
         assert [r["name"] for r in phases] == names
+        assert store in ([], ["compile.store.key", "compile.store.load"],
+                         ["compile.store.key", "compile.store.load",
+                          "compile.store.write"])
+        assert bool(store) == (names[0] == "entry.root.build")
         assert (phases[-2]["events"], phases[-2]["staged_ops"]) == (3, 1)
     assert backend.cache_info()["step"] == 1
 
