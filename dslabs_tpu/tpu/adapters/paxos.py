@@ -63,29 +63,31 @@ def _num_suffix(name: str, prefix: str) -> Optional[int]:
 
 class PaxosBinding(TwinBinding):
 
-    def __init__(self, state):
+    def __init__(self, server_names, client_names, pairs,
+                 spare_slots: int = 0):
+        """``pairs``: every client's ``(command, result)`` pairs, one
+        list a client (:meth:`from_state` reads them off a search
+        state).  ``spare_slots``: log slots beyond one a command — the
+        swarm probe's twin (:meth:`probe_binding`), whose leaders refuse
+        a proposal past the last slot LOUDLY."""
         from dslabs_tpu.tpu.specs_lab3 import paxos_layout
 
-        servers = sorted(state.servers,
-                         key=lambda a: _num_suffix(str(a), "server") or 0)
-        clients = sorted(state.client_workers(),
-                         key=lambda a: _num_suffix(str(a), "client") or 0)
-        self.n = len(servers)
-        self.nc = len(clients)
-        self.server_names = [str(a) for a in servers]
-        self.client_names = [str(a) for a in clients]
+        self.n = len(server_names)
+        self.nc = len(client_names)
+        self.server_names = list(server_names)
+        self.client_names = list(client_names)
         self.addr_index = {s: i for i, s in enumerate(self.server_names)}
         self.addr_index.update(
             {c: self.n + j for j, c in enumerate(self.client_names)})
-        workers = state.client_workers()
-        pairs = [_workload_pairs(workers[a], a) for a in clients]
+        self.pairs = pairs
         sizes = {len(p) for p in pairs}
         if len(sizes) != 1:
             raise NoTensorTwin(
                 f"per-client workload sizes differ ({sizes}); the twin "
                 "models a uniform per-client command count")
         self.w = sizes.pop()
-        self.S = self.w * self.nc
+        self.spare_slots = int(spare_slots)
+        self.S = self.w * self.nc + self.spare_slots
         # command object -> twin cmd ids (clients may send EQUAL raw
         # commands — each occurrence has its own id; has_command matches
         # any of them, exactly the object predicate's equality)
@@ -105,8 +107,50 @@ class PaxosBinding(TwinBinding):
                     tuple(repr(self.cmd_objs[i])
                           for i in sorted(self.cmd_objs)))
 
+    @classmethod
+    def from_state(cls, state):
+        servers = sorted(state.servers,
+                         key=lambda a: _num_suffix(str(a), "server") or 0)
+        clients = sorted(state.client_workers(),
+                         key=lambda a: _num_suffix(str(a), "client") or 0)
+        workers = state.client_workers()
+        return cls([str(a) for a in servers], [str(a) for a in clients],
+                   [_workload_pairs(workers[a], a) for a in clients])
+
     def initial_caps(self):
         return 32, 6
+
+    # Network rows the swarm probe's walkers are given at five servers,
+    # where the ladder's top rung (128) does not hold the network of a
+    # deep walk.  Search-mode delivery never consumes a message, and
+    # every election a walk fires adds a ballot's P1a/P1b round, n - 1
+    # messages each way: five servers' walks to depth 1,000 reach
+    # PROBE_NET_CAP[5] / 2 rows and more (PERF.md section 6, PR 43).
+    PROBE_NET_CAP = {5: 2048}
+
+    def probe_caps(self):
+        net_cap, timer_cap = super().probe_caps()
+        return max(net_cap, self.PROBE_NET_CAP.get(self.n, 0)), timer_cap
+
+    # Log slots the swarm probe's twin has beyond one a command.  The
+    # object servers fill holes with no-ops and can log one command
+    # twice under competing leaders, so a deep walk's log outgrows
+    # ``w * nc`` slots (slot 3 of test25's state 59 events down a random
+    # walk), and a twin without the slot refuses the proposal: in
+    # silence on the strict BFS's twins, which keep ``w * nc`` and never
+    # get that deep (ROADMAP R5a), LOUDLY on the probe's, where a walk
+    # that outgrows the spare slot too is a truncated step the fleet
+    # counts (``refused``; found by the cell ``paxos5-random``'s replay,
+    # PR 43).  One, not two: a message of 5 + S = 8 lanes fills the
+    # 8-row tile a message of 7 pads to, and one of 9 doubles the
+    # merge's time (12.3 us a walker step for 6.5 on a v5e).
+    PROBE_SPARE_SLOTS = 1
+
+    def probe_binding(self):
+        if self.spare_slots:
+            return self
+        return PaxosBinding(self.server_names, self.client_names,
+                            self.pairs, self.PROBE_SPARE_SLOTS)
 
     def twin_key(self):
         # The REPLY decoder's fallback reads the workloads' expected
@@ -123,7 +167,8 @@ class PaxosBinding(TwinBinding):
 
         p = make_paxos_protocol(n=self.n, n_clients=self.nc, w=self.w,
                                 max_slots=self.S, net_cap=net_cap,
-                                timer_cap=timer_cap)
+                                timer_cap=timer_cap,
+                                loud_refusal=self.spare_slots > 0)
         return dataclasses.replace(
             p, decode_message=self._decode_message,
             decode_timer=self._decode_timer)
@@ -394,4 +439,4 @@ def match_paxos(state):
     if not all(isinstance(wk.client, PaxosClient)
                for wk in workers.values()):
         return None
-    return PaxosBinding(state)
+    return PaxosBinding.from_state(state)

@@ -65,7 +65,9 @@ import numpy as np
 from dslabs_tpu.tpu import compile_cache, telemetry
 
 __all__ = ["NoTensorTwin", "TensorProvenance", "TwinBinding",
-           "cache_info", "clear_cache", "register_adapter", "tensor_bfs",
+           "cache_info", "clear_cache", "probe_fleet", "probe_round",
+           "probe_secs", "probe_table", "probe_walker_steps",
+           "probe_walkers", "register_adapter", "tensor_bfs",
            "tensor_dfs"]
 
 
@@ -144,6 +146,26 @@ class TwinBinding:
 
     def initial_caps(self) -> Tuple[int, int]:
         raise NotImplementedError
+
+    def probe_caps(self) -> Tuple[int, int]:
+        """(net_cap, timer_cap) of the dfs entry's swarm probe
+        (:func:`probe_fleet`).  Default: the capacity ladder's TOP rung
+        outright — walkers hold K rows, not a frontier, so wide caps
+        cost little, and at base caps every truncated step would
+        restart a walker below the very depths the probe exists to
+        reach (the truncation count is loud:
+        ``SearchOutcome.swarm_overflow``).  A binding whose deployments
+        outgrow that rung along a deep walk overrides it."""
+        net_cap, timer_cap = self.initial_caps()
+        top = len(_LADDER) - 1
+        return net_cap << top, timer_cap + 2 * top
+
+    def probe_binding(self) -> "TwinBinding":
+        """The binding the dfs entry's swarm probe is built through
+        (:func:`probe_fleet`): this one, unless a deep walk needs a
+        wider twin than the strict BFS's (``PaxosBinding``: spare log
+        slots).  Idempotent."""
+        return self
 
     def twin_key(self) -> tuple:
         """Hashable identity of the twin ``build_protocol`` returns at
@@ -455,7 +477,9 @@ def _trace_step(binding: TwinBinding, p):
     ``deliver_timers(False)`` phase 3 must still replay phase 1's
     election timers); masks only gate validity, never the transition,
     so the unmasked step reproduces each original successor exactly."""
-    key = _key("step", binding, p.net_cap, p.timer_cap)
+    # By the twin's name too: a probe's twin is stepped under the
+    # caller's binding as well as its own (``probe_fleet``).
+    key = _key("step", binding, p.name, p.net_cap, p.timer_cap)
     step = _KEPT.get(key)
     if step is None:
         import jax
@@ -827,6 +851,135 @@ def _bind_protocol(binding, settings, net_cap, timer_cap,
     return protocol, marr, tarr
 
 
+# What the dfs entry's probe is built at where the caller names no
+# size.  The width follows the probe's BUDGET, not the platform alone:
+# a walker has to make its bound's steps inside the budget to reach the
+# depths the probe exists for, so the fleet is as wide as the walker
+# steps the platform makes in the budget pay for, each walker walking
+# its whole bound — 128 at the least (what the probe always had, and
+# all a CPU's rate ever pays for: its tests are timed at it) and 8,192
+# at the most (a v5e's step is device-bound well before: wider buys no
+# throughput).  The rates are measured: five servers' Paxos, the widest
+# twin the labs bind, 8.2 us a walker step on a v5e at 8,192 walkers
+# and 4.9 at 256 (PERF.md section 6, PR 43) — the slower is entered;
+# narrower twins and fleets walk faster and get a fleet narrower than
+# they could fill, which costs nothing.  A CPU
+# makes 7-14 K walker steps a second at 128 walkers of three servers'
+# Paxos (tests/test_swarm_probe.py's hit); it is entered lower, so that
+# no budget the settings can give widens a CPU's fleet.
+PROBE_WALKERS_MIN, PROBE_WALKERS_MAX = 128, 8192
+PROBE_WALKER_STEPS_PER_SEC = {"cpu": 3_000, "tpu": 120_000}
+PROBE_DEPTH = 192           # the deepest bound where settings give none
+PROBE_SECS = 10.0           # the probe's budget where settings give none
+# Table slots a walker step of the budget: six a fresh key (twice the
+# keys at a third full), a third of the steps fresh (25.8 % over five
+# servers' window) — and 2^18 at the least, what the probe always had.
+PROBE_TABLE_SLOTS_A_STEP, PROBE_TABLE_MIN = 2, 1 << 18
+# A dispatch is at most 64 steps of the fleet (the module's default) and
+# at most 32,768 walker steps: the clock is read between dispatches, and
+# five servers' 8,192 walkers take 67 ms a step on a v5e (PERF.md).
+PROBE_ROUND_STEPS, PROBE_ROUND_WALKER_STEPS = 64, 1 << 15
+
+
+def probe_secs(settings) -> float:
+    """The probe's share of a dfs call's time: a third of the settings'
+    ``max_time`` and PROBE_SECS at the most, by the global time
+    scale."""
+    from dslabs_tpu.utils.flags import GlobalSettings
+
+    budget = PROBE_SECS
+    if settings.max_time_secs is not None:
+        budget = min(budget, settings.max_time_secs / 3)
+    return budget * GlobalSettings.time_scale
+
+
+def probe_walker_steps(secs: float) -> int:
+    """Walker steps the platform makes in ``secs`` seconds of walking
+    (PROBE_WALKER_STEPS_PER_SEC; a platform with no measured rate gets
+    the CPU's)."""
+    import jax
+
+    rate = PROBE_WALKER_STEPS_PER_SEC
+    return int(secs * rate.get(jax.default_backend(), rate["cpu"]))
+
+
+def probe_walkers(walker_steps: int, depth: int) -> int:
+    """The probe's fleet width where the caller names none: the power
+    of two of walkers that each make ``depth`` of ``walker_steps``
+    steps (PROBE_DEPTH where the bound is shallower: several probes a
+    walker), within PROBE_WALKERS_MIN and PROBE_WALKERS_MAX."""
+    fit = max(walker_steps // max(depth, PROBE_DEPTH), 1)
+    return max(PROBE_WALKERS_MIN,
+               min(PROBE_WALKERS_MAX, 1 << (fit.bit_length() - 1)))
+
+
+def probe_round(walkers: int) -> int:
+    """Steps of the fleet a dispatch, for a fleet ``walkers`` wide (128
+    walkers: 64, what the probe always had; 8,192: 4)."""
+    return max(1, min(PROBE_ROUND_STEPS,
+                      PROBE_ROUND_WALKER_STEPS // walkers))
+
+
+def probe_table(walker_steps: int) -> int:
+    """Visited-table slots a device for a walk of ``walker_steps``: the
+    power of two that holds PROBE_TABLE_SLOTS_A_STEP slots a step,
+    PROBE_TABLE_MIN at the least (five servers' 27 s window of 3.3 M
+    walker steps: 2^23, a tenth full at its end)."""
+    need = max(PROBE_TABLE_SLOTS_A_STEP * walker_steps, PROBE_TABLE_MIN)
+    return 1 << (need - 1).bit_length()
+
+
+def probe_fleet(binding, settings, state, walkers=None,
+                steps_per_round=None, visited_cap=None, strict=False):
+    """THE builder of the dfs entry's swarm fleet (tpu/swarm.py
+    ``SwarmSearch``), for ``_rollout_probe`` and for the benchmark's
+    driver alike: the twin bound WITHOUT goals through
+    ``binding.probe_binding()`` at its probe caps, one device, depth
+    bounds spread up to the settings' relative ``max_depth``
+    (``PROBE_DEPTH`` where they give none), retry boundary, recorder,
+    runtime masks, and the root derived from ``state``.  ->
+    ``(search, root, history, probe binding)`` with ``root`` None for
+    the twin's own initial state — the fleet's rows are the PROBE
+    binding's twin's, so whatever decodes or steps them goes through
+    that binding — or None where the settings leave no depth to walk.
+    ``walkers`` defaults to what the probe's budget pays for
+    (:func:`probe_secs`, :func:`probe_walker_steps`,
+    :func:`probe_walkers`), ``steps_per_round`` to
+    :func:`probe_round`'s and ``visited_cap`` to :func:`probe_table`'s
+    for that budget; ``strict``: a truncated or refused step or a full
+    table raises."""
+    from dslabs_tpu.tpu.sharded import make_mesh
+    from dslabs_tpu.tpu.supervisor import install_retry
+    from dslabs_tpu.tpu.swarm import SwarmSearch
+
+    # ``state``'s provenance is the caller's binding's, whose key the
+    # root is derived under (events replay alike on either twin; the
+    # trace step is kept by the twin's name beside the binding's key).
+    base, binding = binding, binding.probe_binding()
+    binding.check_settings(settings)
+    protocol, marr, tarr = _bind_protocol(
+        binding, settings, *binding.probe_caps(), with_goals=False)
+    rel = (settings.max_depth - state.depth
+           if settings.depth_limited() else PROBE_DEPTH)
+    if rel <= 0:
+        return None
+    budget = probe_walker_steps(probe_secs(settings))
+    walkers = int(walkers or probe_walkers(budget, rel))
+    search = SwarmSearch(
+        protocol, mesh=make_mesh(1), walkers_per_device=walkers,
+        max_steps=rel, seed=0,
+        steps_per_round=int(steps_per_round or probe_round(walkers)),
+        visited_cap=int(visited_cap or probe_table(budget)),
+        strict=strict)
+    install_retry(search)
+    recorder = telemetry.current()
+    if recorder is not None:
+        recorder.attach(search)
+    search.set_runtime_masks(marr, tarr)
+    root, history = base.derive_root(search, state)
+    return search, root, history, binding
+
+
 def _rollout_probe(binding, settings, state):
     """Swarm deep probe before a dfs-routed BFS: a diversified
     random-walk fleet (tpu/swarm.py ``SwarmSearch`` — ONE walker
@@ -834,53 +987,30 @@ def _rollout_probe(binding, settings, state):
     reaches depth d in O(d) steps, so the deep-narrow violations the
     object RandomDFS could hit inside a time budget are covered BEFORE
     the level-by-level search starts.  This function keeps only the
-    BUDGET ACCOUNTING — walker mechanics, dedup, overflow-restart
-    counting, and the minimize/replay witness pipeline all live in the
-    swarm subsystem.  Returns ((search, outcome, history), probe_secs)
-    on a terminal hit, else (None, probe_secs) — capacity overflows
-    skip the probe (the BFS ladder owns caps)."""
+    BUDGET ACCOUNTING — the fleet is :func:`probe_fleet`'s; walker
+    mechanics, dedup, overflow-restart counting, and the
+    minimize/replay witness pipeline all live in the swarm subsystem.
+    Returns ((probe binding, search, outcome, history), probe_secs) on
+    a terminal hit — the fleet's rows are the probe binding's twin's —
+    else (None, probe_secs); capacity overflows skip the probe (the BFS
+    ladder owns caps)."""
     import time
 
     import jax
 
     from dslabs_tpu.tpu.engine import CapacityOverflow
-    from dslabs_tpu.tpu.sharded import make_mesh
-    from dslabs_tpu.tpu.swarm import SwarmSearch
-    from dslabs_tpu.utils.flags import GlobalSettings
 
     t_probe = time.time()
     try:
-        binding.check_settings(settings)
-        net_cap, timer_cap = binding.initial_caps()
-        # Probe at the capacity ladder's TOP rung outright: walkers
-        # hold K rows, not a frontier, so the wide caps cost nothing —
-        # and at base caps every truncated step would restart a walker
-        # below the very depths the probe exists to reach (the
-        # truncation count is loud now: SearchOutcome.swarm_overflow).
-        top = len(_LADDER) - 1
-        protocol, marr, tarr = _bind_protocol(
-            binding, settings, net_cap << top, timer_cap + 2 * top,
-            with_goals=False)
-        rel = (settings.max_depth - state.depth
-               if settings.depth_limited() else 192)
-        if rel <= 0:
+        fleet = probe_fleet(binding, settings, state)
+        if fleet is None:
             return None, time.time() - t_probe
-        search = SwarmSearch(protocol, mesh=make_mesh(1),
-                             walkers_per_device=128,
-                             max_steps=min(rel, 192), seed=0)
-        from dslabs_tpu.tpu.supervisor import install_retry
-
-        install_retry(search)
-        recorder = telemetry.current()
-        if recorder is not None:
-            recorder.attach(search)
-        search.set_runtime_masks(marr, tarr)
-        root, history = binding.derive_root(search, state)
-        budget = 10.0 * GlobalSettings.time_scale
-        if settings.max_time_secs is not None:
-            budget = min(budget, settings.max_time_secs / 3
-                         * GlobalSettings.time_scale)
-        search.max_secs = budget
+        search, root, history, binding = fleet
+        span = telemetry.current_phase()
+        if span is not None:            # ``entry.probe``, tensor_bfs's
+            span.set(walkers=search.n_devices * search.walkers,
+                     max_steps=search.max_steps)
+        search.max_secs = probe_secs(settings)
         outcome = search.run(
             initial=(jax.tree.map(jax.numpy.asarray, root)
                      if root is not None else None),
@@ -889,7 +1019,8 @@ def _rollout_probe(binding, settings, state):
         return None, time.time() - t_probe
     if outcome.end_condition in ("INVARIANT_VIOLATED",
                                  "EXCEPTION_THROWN"):
-        return (search, outcome, history), time.time() - t_probe
+        return ((binding, search, outcome, history),
+                time.time() - t_probe)
     return None, time.time() - t_probe
 
 
@@ -961,7 +1092,8 @@ def tensor_bfs(initial_state, settings=None, _probe_first=False):
                 settings.max_time_secs = max(
                     1.0, settings.max_time_secs - probe_secs)
         if trip is not None:
-            search, outcome, history = trip
+            # the probe's rows are its own binding's twin's
+            binding, search, outcome, history = trip
         else:
             search, outcome, history = _run_tensor(
                 binding, settings, initial_state, lease)

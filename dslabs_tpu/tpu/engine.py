@@ -204,6 +204,13 @@ class TensorProtocol:
     # at trace time on this, so fault-free specs lower to the
     # byte-identical pre-fault program.
     fault: Optional[object] = None
+    # The ``exc`` code (0 = none) by which a handler says that the
+    # TWIN's own bounded state ran out where the object's has no end (a
+    # log slot past the last: specs_lab3 ``loud_refusal``).  A step that
+    # ends on it is truncated like one past net_cap / timer_cap, not a
+    # handler that threw: the swarm restarts the walker and counts it
+    # (``refused``), a strict fleet raises.
+    capacity_exc: int = 0
 
 
 @dataclasses.dataclass

@@ -25,6 +25,8 @@ key ``w`` times; command ids ``c * w + s`` (1-based), 0 = no-op.
 
 from __future__ import annotations
 
+import dataclasses
+
 import jax.numpy as jnp
 import numpy as np
 
@@ -55,10 +57,20 @@ T_ELECTION, T_HEARTBEAT, T_CLIENT = 1, 2, 3
 # lane's declared domain) — never silent aliasing.
 BALLOT_HI = (1 << 12) - 1
 
+# The exception code of a leader that refuses a proposal because its log
+# has no slot left (``loud_refusal``): the twin's own capacity ran out,
+# no handler of the object server threw.
+EXC_LOG_FULL = 1
+
 
 def make_paxos_spec(n: int = 3, n_clients: int = 1, w: int = 1,
                     max_slots: int = 2, net_cap: int = 64,
-                    timer_cap: int = 8, fault=None) -> ProtocolSpec:
+                    timer_cap: int = 8, fault=None,
+                    loud_refusal: bool = False) -> ProtocolSpec:
+    """``loud_refusal``: a leader handed a new command with every one of
+    its ``max_slots`` log slots taken raises ``EXC_LOG_FULL`` instead of
+    dropping the proposal in silence (the object server's log has no
+    end).  Off, the compiled programs are what they always were."""
     S, NC = max_slots, n_clients
     cmd_hi = NC * w
 
@@ -332,6 +344,9 @@ def make_paxos_spec(n: int = 3, n_clients: int = 1, w: int = 1,
         prop = ctx.get_at("prop", ci)
         slot = ctx.get("si")
         do_prop = ~already & is_leader & (seq > prop) & (slot <= S)
+        if loud_refusal:
+            ctx.fail(EXC_LOG_FULL, when=~already & is_leader
+                     & (seq > prop) & (slot > S))
         ctx.put_at("prop", ci, seq, when=do_prop)
         ctx.put("si", slot + 1, when=do_prop)
         pctx = ctx.cond(do_prop)
@@ -572,12 +587,19 @@ def make_paxos_spec(n: int = 3, n_clients: int = 1, w: int = 1,
 
 def make_paxos_protocol(n: int = 3, n_clients: int = 1, w: int = 1,
                         max_slots: int = 2, net_cap: int = 64,
-                        timer_cap: int = 8, fault=None):
+                        timer_cap: int = 8, fault=None,
+                        loud_refusal: bool = False):
     """Drop-in replacement for the deleted hand twin's factory: same
     signature, same protocol name, same searched state space (exact
-    pinned-count parity) — now compiled from the spec."""
-    return make_paxos_spec(n, n_clients, w, max_slots, net_cap,
-                           timer_cap, fault=fault).compile()
+    pinned-count parity) — now compiled from the spec.  With
+    ``loud_refusal`` the twin's ``capacity_exc`` names the refusal's
+    code, so a walk counts it as a truncated step, not as a handler
+    that threw."""
+    p = make_paxos_spec(n, n_clients, w, max_slots, net_cap, timer_cap,
+                        fault=fault, loud_refusal=loud_refusal).compile()
+    if loud_refusal:
+        p = dataclasses.replace(p, capacity_exc=EXC_LOG_FULL)
+    return p
 
 
 def make_paxos_partition_spec(n: int = 3, n_clients: int = 1,

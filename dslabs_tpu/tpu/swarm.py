@@ -95,11 +95,13 @@ from jax import shard_map
 from jax.sharding import NamedSharding, PartitionSpec as P
 
 from dslabs_tpu.tpu import checkpoint as ckpt_mod
+from dslabs_tpu.tpu import compile_cache
+from dslabs_tpu.tpu import telemetry as tel_mod
 from dslabs_tpu.tpu import visited as visited_mod
-from dslabs_tpu.tpu.engine import (CapacityOverflow, SearchOutcome,
-                                   TensorProtocol, TensorSearch,
-                                   device_get, flatten_state,
-                                   row_fingerprints)
+from dslabs_tpu.tpu.engine import (SENTINEL, CapacityOverflow,
+                                   SearchOutcome, TensorProtocol,
+                                   TensorSearch, device_get,
+                                   flatten_state, row_fingerprints)
 
 __all__ = ["SwarmSearch", "Witness", "minimize_event_trace",
            "replay_events"]
@@ -113,6 +115,29 @@ RESTART_WARN = int(os.environ.get("DSLABS_SWARM_RESTART_WARN",
 OVERFLOW_WARN = int(os.environ.get("DSLABS_SWARM_OVERFLOW_WARN", "0"))
 
 _TERMINAL = ("INVARIANT_VIOLATED", "EXCEPTION_THROWN", "GOAL_FOUND")
+
+# Fresh inserts are also counted by the walk depth they were made at,
+# for depths 1..FRESH_DEPTHS (a state first seen at walk depth d lies at
+# BFS depth <= d: the cumulative count is held to an exhaustive search's
+# by benchmark/drivers/timeboxed_swarm.py and tests/test_swarm_probe.py).
+FRESH_DEPTHS = 16
+# The scalar counters of the carry, in the order of the stats vector's
+# head (``stats[:len(COUNTERS)]``, then the steps the round ran).
+COUNTERS = ("explored", "fresh", "revisit", "restarts", "over",
+            "vis_over", "deepest")
+# What follows the flag counts in the stats vector, before the
+# per-device tail: events beyond the event window, finished probes
+# (restarts by a prune, the depth bound or a dead end), the most
+# network rows and timer slots any successor held, steps the twin
+# refused for want of room in its own state (``capacity_exc``), and the
+# fresh inserts by depth.
+EXTRAS = ("ev_rem", "probes", "net_peak", "tmr_peak", "refused")
+# The round program's name (``jit_swarm_round`` in a profile).
+ROUND = "swarm_round"
+# What a ``swarm.round`` span is closed with, of :meth:`_stats_dict`.
+_SPAN_FIELDS = ("explored", "unique", "revisits", "restarts",
+                "overflow_restarts", "vis_over", "deepest", "probes",
+                "refused")
 
 
 # ------------------------------------------------------------- witnesses
@@ -293,6 +318,14 @@ def build_witness(search: TensorSearch, root_row: np.ndarray,
 
 # ------------------------------------------------------------ the swarm
 
+def _abstract(x):
+    """The shape, dtype and sharding of a device array: what a program
+    is lowered for."""
+    return jax.ShapeDtypeStruct(
+        x.shape, x.dtype, sharding=getattr(x, "sharding", None))
+
+
+
 class SwarmSearch(TensorSearch):
     """Diversified random-walk fleets over a device mesh (module
     docstring).  ``run()`` returns the standard :class:`SearchOutcome`:
@@ -359,6 +392,9 @@ class SwarmSearch(TensorSearch):
                          checkpoint_path=checkpoint_path,
                          checkpoint_every=checkpoint_every)
         self._round = jax.jit(self._build_round(), donate_argnums=0)
+        self._round_exe = None          # :meth:`_load_round`
+        self._init_progs = {}           # :meth:`_init_carry`
+        self._final_carry = None        # :meth:`walker_snapshot`
         self.compile_secs = 0.0
         # Watchdog granularity (tpu/supervisor.py): one round dispatch
         # legitimately runs up to steps_per_round walk steps.
@@ -376,12 +412,7 @@ class SwarmSearch(TensorSearch):
         once and abstracts its result; the audit itself still only
         lowers."""
         carry = self._init_carry(self.initial_state())
-
-        def _sds(x):
-            return jax.ShapeDtypeStruct(
-                x.shape, x.dtype, sharding=getattr(x, "sharding", None))
-
-        carry_sds = jax.tree.map(_sds, carry)
+        carry_sds = jax.tree.map(_abstract, carry)
         b = jnp.asarray(self.steps_per_round, jnp.int32)
         rt = getattr(self, "_rt_masks", None)
         args = ((carry_sds, b, rt) if rt is not None
@@ -426,8 +457,11 @@ class SwarmSearch(TensorSearch):
                                          np.ndarray]:
         """-> (seeds [D, P, lanes], seeds_n [D], preseed_keys [M, 4]).
 
-        Root mode: every device's pool is the one root row, no
-        pre-seeded keys.  Frontier mode (``frontier_seed`` = a BFS
+        Root mode: every device's pool is the one root row, and its
+        key is pre-seeded: the root is a visited state, so a walk that
+        comes back to it revisits, and ``fresh`` counts the states
+        other than the seeds (one less than a BFS's count of the same
+        region, which holds its root).  Frontier mode (``frontier_seed`` = a BFS
         checkpoint path): the dumped frontier rows split contiguously
         across devices (distinct seeds per device = another
         diversification axis) and the dump's visited keys pre-seed
@@ -437,8 +471,8 @@ class SwarmSearch(TensorSearch):
         D = self.n_devices
         if not self.frontier_seed:
             seeds = np.broadcast_to(root, (D, 1, self.lanes)).copy()
-            return seeds, np.ones((D,), np.int32), np.zeros((0, 4),
-                                                            np.uint32)
+            return seeds, np.ones((D,), np.int32), np.asarray(
+                row_fingerprints(jnp.asarray(root[None])), np.uint32)
         ck = self._load_bfs_seed(self.frontier_seed)
         rows = ck.frontier
         if not len(rows):
@@ -483,15 +517,54 @@ class SwarmSearch(TensorSearch):
         ax = self.axis
         keys = ["rows", "depths", "hists", "streak", "seed_idx",
                 "bounds", "temps", "affin", "key", "seeds", "seeds_n",
-                "visited", "explored", "fresh", "revisit", "restarts",
-                "over", "vis_over", "deepest",
+                "visited", *COUNTERS, *EXTRAS, "fresh_by_depth",
                 "hit_cnt", "hit_rows", "hit_hist", "hit_depth",
                 "hit_seed"]
         return {k: P(ax) for k in keys}
 
+    def _zero_counters(self) -> dict:
+        """The carry's counters at the start of a walk (per device)."""
+        out = {k: jnp.zeros((1,), jnp.int32) for k in COUNTERS + EXTRAS}
+        out["fresh_by_depth"] = jnp.zeros((FRESH_DEPTHS,), jnp.int32)
+        return out
+
+    def _step_rows(self, rows, ev):
+        """K rows, one grid event id each -> (successor rows, valid,
+        over): what ``vmap(_step_one)`` gives, with only the HANDLER
+        half under the vmap and the network merge as the engine's one
+        batched, transposed tail (``_batched_tail`` at one pair a row:
+        walkers ride the minor axis, as the BFS chunk's pairs do)."""
+        p = self.p
+        K = self.walkers
+        with tel_mod.device_scope("expand.handlers"):
+            is_msg = ev < p.net_cap
+            m = jax.vmap(self._msg_step_raw)(
+                rows, jnp.minimum(ev, p.net_cap - 1))
+            t = jax.vmap(self._tmr_step_raw)(
+                rows, jnp.maximum(ev - p.net_cap, 0))
+            nodes2, sends, timers2, exc, ok, t_over = jax.tree.map(
+                lambda a, b: jnp.where(
+                    is_msg.reshape((K,) + (1,) * (a.ndim - 1)), a, b),
+                m, t)
+        with tel_mod.device_scope("expand.canon"):
+            succ, over = self._batched_tail(rows, K, 1, nodes2, sends,
+                                            timers2, exc, ok, t_over)
+            if p.fault is not None and self._ev_flt:
+                tgrid = p.n_nodes * p.timer_cap
+                is_flt = ev >= p.net_cap + tgrid
+                f_rows, f_ok, f_over = jax.vmap(self._flt_step)(
+                    rows, jnp.maximum(ev - p.net_cap - tgrid, 0))
+                succ = jnp.where(is_flt[:, None], f_rows, succ)
+                ok = jnp.where(is_flt, f_ok, ok)
+                over = jnp.where(is_flt, f_over, over)
+        return succ, ok, over
+
     def _build_walk_step(self):
         """One walk step for this device's K walkers (runs INSIDE the
-        round's shard_map/while_loop)."""
+        round's shard_map/while_loop).  Its stages are named in the
+        HLO's metadata (tpu/telemetry.py DEVICE_SCOPES): the BFS chunk
+        step's own names where the stage is the same work, ``walk.*``
+        for what only a walker does."""
         p = self.p
         K = self.walkers
         S = self.max_steps
@@ -500,101 +573,136 @@ class SwarmSearch(TensorSearch):
         def walk(c, masks=None):
             rows, depths, hists = c["rows"], c["depths"], c["hists"]
             key, sub, sub2 = jax.random.split(c["key"][0], 3)
-            msg_ids, tmr_ids, flt_ids, _rem = self._event_tables(
-                rows, jnp.ones((K,), bool), masks=masks)
-            segs = [msg_ids,
-                    jnp.where(tmr_ids >= 0, tmr_ids + p.net_cap, -1)]
-            if flt_ids is not None:
-                tgrid = p.n_nodes * p.timer_cap
-                segs.append(jnp.where(
-                    flt_ids >= 0, flt_ids + p.net_cap + tgrid, -1))
-            ids = jnp.concatenate(segs, axis=1)              # [K, B]
-            ok = ids >= 0
-            # Diversified pick: kind-affinity bias over valid events,
-            # scaled by each walker's temperature (cold = committed to
-            # its bias, hot = uniform), resolved by one categorical
-            # draw per walker.
-            is_tmr = (jnp.arange(ids.shape[1])
-                      >= self._ev_msg)[None, :]               # [1, B]
-            bias = (c["affin"][:, None]
-                    * jnp.where(is_tmr, 1.0, -1.0)
-                    / c["temps"][:, None])
-            logits = jnp.where(ok, bias, -jnp.inf)
-            pick = jax.random.categorical(sub, logits, axis=-1)  # [K]
-            ev = jnp.take_along_axis(ids, pick[:, None], axis=1)[:, 0]
-            any_ok = ok.any(axis=1)
-            ev = jnp.where(any_ok, ev, 0)
-            succ, s_ok, s_over = jax.vmap(self._step_one)(rows, ev)
+            with tel_mod.device_scope("expand.events"):
+                msg_ids, tmr_ids, flt_ids, ev_rem = self._event_tables(
+                    rows, jnp.ones((K,), bool), masks=masks)
+            with tel_mod.device_scope("walk.pick"):
+                segs = [msg_ids,
+                        jnp.where(tmr_ids >= 0, tmr_ids + p.net_cap, -1)]
+                if flt_ids is not None:
+                    tgrid = p.n_nodes * p.timer_cap
+                    segs.append(jnp.where(
+                        flt_ids >= 0, flt_ids + p.net_cap + tgrid, -1))
+                ids = jnp.concatenate(segs, axis=1)          # [K, B]
+                ok = ids >= 0
+                # Diversified pick: kind-affinity bias over valid
+                # events, scaled by each walker's temperature (cold =
+                # committed to its bias, hot = uniform), resolved by
+                # one categorical draw per walker.
+                is_tmr = (jnp.arange(ids.shape[1])
+                          >= self._ev_msg)[None, :]           # [1, B]
+                bias = (c["affin"][:, None]
+                        * jnp.where(is_tmr, 1.0, -1.0)
+                        / c["temps"][:, None])
+                logits = jnp.where(ok, bias, -jnp.inf)
+                pick = jax.random.categorical(sub, logits, axis=-1)
+                ev = jnp.take_along_axis(ids, pick[:, None],
+                                         axis=1)[:, 0]
+                any_ok = ok.any(axis=1)
+                ev = jnp.where(any_ok, ev, 0)
+            succ, s_ok, s_over = self._step_rows(rows, ev)
             # A capacity-overflowed successor is TRUNCATED — checking
             # predicates on it would be unsound.  The walker restarts,
             # and the truncation is COUNTED (c["over"]) — the old
-            # rollout probe's silent-restart bug, fixed.
+            # rollout probe's silent-restart bug, fixed.  So is a step
+            # the twin refused for want of room in its own state (the
+            # row's last lane, ``exc``, reads ``capacity_exc``): the
+            # object system would have gone on (c["refused"]).
             over = any_ok & s_ok & (s_over != 0)
-            advance = any_ok & s_ok & ~over
-            sstate = self.unflatten_rows(succ)
+            refused = (any_ok & s_ok & ~over
+                       & (succ[:, -1] == p.capacity_exc)
+                       if p.capacity_exc else jnp.zeros((K,), bool))
+            cut = over | refused
+            advance = any_ok & s_ok & ~cut
 
-            # Terminal flags, checkState order (exception -> invariant
-            # -> goal; shared _flag_names layout with the BFS drivers).
-            hit_list = [advance & (sstate["exc"] != 0)]
-            for n in p.invariants:
-                hit_list.append(advance
-                                & ~jax.vmap(p.invariants[n])(sstate))
-            for n in p.goals:
-                hit_list.append(advance & jax.vmap(p.goals[n])(sstate))
-            hits = jnp.stack(hit_list)                        # [nf, K]
-            pruned = jnp.zeros((K,), bool)
-            for fn in p.prunes.values():
-                pruned = pruned | jax.vmap(fn)(sstate)
+            with tel_mod.device_scope("flags"):
+                sstate = self.unflatten_rows(succ)
+                # Terminal flags, checkState order (exception ->
+                # invariant -> goal; shared _flag_names layout with the
+                # BFS drivers).
+                hit_list = [advance & (sstate["exc"] != 0)]
+                for n in p.invariants:
+                    hit_list.append(
+                        advance & ~jax.vmap(p.invariants[n])(sstate))
+                for n in p.goals:
+                    hit_list.append(advance
+                                    & jax.vmap(p.goals[n])(sstate))
+                hits = jnp.stack(hit_list)                    # [nf, K]
+                pruned = jnp.zeros((K,), bool)
+                for fn in p.prunes.values():
+                    pruned = pruned | jax.vmap(fn)(sstate)
+                # How full the caps ran: the most network rows, and the
+                # most slots of one node's timer queue, any successor
+                # held (what the probe's caps are sized from).
+                net_n = jnp.max(jnp.sum(
+                    sstate["net"][:, :, 0] != SENTINEL, axis=1))
+                tmr_n = jnp.max(jnp.sum(
+                    sstate["timers"][:, :, :, 0] != SENTINEL, axis=2))
 
             # History records the event BEFORE restart resolution: a
             # violating successor's trace must include its final edge.
-            hists2 = jnp.where(
-                (jnp.arange(S)[None, :] == depths[:, None])
-                & advance[:, None], ev[:, None], hists)
-            depths2 = depths + advance.astype(jnp.int32)
+            with tel_mod.device_scope("walk.history"):
+                hists2 = jnp.where(
+                    (jnp.arange(S)[None, :] == depths[:, None])
+                    & advance[:, None], ev[:, None], hists)
+                depths2 = depths + advance.astype(jnp.int32)
 
             # Shared dedup: fingerprints of advanced successors insert
             # into this device's table (visited.py contract: unresolved
             # = table full = treated as fresh, counted).
-            fp = row_fingerprints(succ)
-            table, ins, unres = visited_mod.insert(
-                c["visited"], fp, advance)
-            revisit = advance & ~ins & ~unres
-            streak2 = jnp.where(revisit, c["streak"] + 1,
-                                jnp.zeros_like(c["streak"]))
-            if patience > 0:
-                rv_restart = streak2 >= patience
-            else:
-                rv_restart = jnp.zeros((K,), bool)
+            with tel_mod.device_scope("fingerprint"):
+                fp = row_fingerprints(succ)
+            with tel_mod.device_scope("visited_insert"):
+                table, ins, unres = visited_mod.insert(
+                    c["visited"], fp, advance)
 
-            # First-hit capture per flag (one walker's full history),
-            # taken from the PRE-restart arrays.
-            cnts = jnp.sum(hits, axis=1).astype(jnp.int32)
-            idxs = jnp.argmax(hits, axis=1)
-            freshf = (c["hit_cnt"] == 0) & (cnts > 0)
-            hit_rows = jnp.where(freshf[:, None], succ[idxs],
-                                 c["hit_rows"])
-            hit_hist = jnp.where(freshf[:, None], hists2[idxs],
-                                 c["hit_hist"])
-            hit_depth = jnp.where(freshf, depths2[idxs], c["hit_depth"])
-            hit_seed = jnp.where(freshf, c["seed_idx"][idxs],
-                                 c["hit_seed"])
+            with tel_mod.device_scope("walk.restart"):
+                revisit = advance & ~ins & ~unres
+                streak2 = jnp.where(revisit, c["streak"] + 1,
+                                    jnp.zeros_like(c["streak"]))
+                if patience > 0:
+                    rv_restart = streak2 >= patience
+                else:
+                    rv_restart = jnp.zeros((K,), bool)
 
-            # Restarts: dead end / truncated step / prune / depth bound
-            # / revisit patience -> re-seed from the pool.
-            restart = (~advance | pruned | (depths2 >= c["bounds"])
-                       | rv_restart)
-            nsd = jnp.maximum(c["seeds_n"][0], 1)
-            ridx = jax.random.randint(sub2, (K,), 0, nsd)
-            new_rows = c["seeds"][ridx]
-            rows2 = jnp.where(restart[:, None], new_rows, succ)
-            depths3 = jnp.where(restart, 0, depths2)
-            hists3 = jnp.where(restart[:, None], -1, hists2)
-            streak3 = jnp.where(restart, 0, streak2)
-            seed_idx2 = jnp.where(restart, ridx, c["seed_idx"])
+                # First-hit capture per flag (one walker's full
+                # history), taken from the PRE-restart arrays.
+                cnts = jnp.sum(hits, axis=1).astype(jnp.int32)
+                idxs = jnp.argmax(hits, axis=1)
+                freshf = (c["hit_cnt"] == 0) & (cnts > 0)
+                hit_rows = jnp.where(freshf[:, None], succ[idxs],
+                                     c["hit_rows"])
+                hit_hist = jnp.where(freshf[:, None], hists2[idxs],
+                                     c["hit_hist"])
+                hit_depth = jnp.where(freshf, depths2[idxs],
+                                      c["hit_depth"])
+                hit_seed = jnp.where(freshf, c["seed_idx"][idxs],
+                                     c["hit_seed"])
+
+                # Restarts: a probe FINISHED (dead end / prune / depth
+                # bound), a truncated or refused step, or revisit
+                # patience -> re-seed from the pool.
+                finished = ((~advance & ~cut) | pruned
+                            | (depths2 >= c["bounds"]))
+                restart = finished | cut | rv_restart
+                nsd = jnp.maximum(c["seeds_n"][0], 1)
+                ridx = jax.random.randint(sub2, (K,), 0, nsd)
+                new_rows = c["seeds"][ridx]
+                rows2 = jnp.where(restart[:, None], new_rows, succ)
+                depths3 = jnp.where(restart, 0, depths2)
+                hists3 = jnp.where(restart[:, None], -1, hists2)
+                streak3 = jnp.where(restart, 0, streak2)
+                seed_idx2 = jnp.where(restart, ridx, c["seed_idx"])
+                by_depth = jnp.sum(
+                    ins[:, None] & (depths2[:, None] == jnp.arange(
+                        1, FRESH_DEPTHS + 1)[None, :]),
+                    axis=0).astype(jnp.int32)
 
             def bump(name, val):
                 return c[name].at[0].add(val.astype(jnp.int32))
+
+            def peak(name, val):
+                return c[name].at[0].max(val.astype(jnp.int32))
 
             return {
                 "rows": rows2, "depths": depths3, "hists": hists3,
@@ -609,8 +717,13 @@ class SwarmSearch(TensorSearch):
                 "restarts": bump("restarts", jnp.sum(restart)),
                 "over": bump("over", jnp.sum(over)),
                 "vis_over": bump("vis_over", jnp.sum(unres)),
-                "deepest": c["deepest"].at[0].max(
-                    jnp.max(depths2).astype(jnp.int32)),
+                "deepest": peak("deepest", jnp.max(depths2)),
+                "ev_rem": bump("ev_rem", ev_rem),
+                "probes": bump("probes", jnp.sum(finished)),
+                "net_peak": peak("net_peak", net_n),
+                "tmr_peak": peak("tmr_peak", tmr_n),
+                "refused": bump("refused", jnp.sum(refused)),
+                "fresh_by_depth": c["fresh_by_depth"] + by_depth,
                 "hit_cnt": c["hit_cnt"] + cnts,
                 "hit_rows": hit_rows, "hit_hist": hit_hist,
                 "hit_depth": hit_depth, "hit_seed": hit_seed,
@@ -623,7 +736,8 @@ class SwarmSearch(TensorSearch):
         ``lax.while_loop``, stopping early when ANY device's flag count
         goes nonzero (psum'd first-hit stop).  Returns (carry', stats)
         with the psum'd scalar stats in-program, so host involvement
-        per round is one dispatch."""
+        per round is one dispatch.  The function is named: the program
+        is ``jit_swarm_round`` in a profile."""
         walk = self._build_walk_step()
         ax = self.axis
 
@@ -631,21 +745,32 @@ class SwarmSearch(TensorSearch):
             def ps(x):
                 return jax.lax.psum(x, ax)
 
+            def pm(x):
+                return jax.lax.pmax(x, ax)
+
             core = jnp.stack([
                 ps(c["explored"][0]), ps(c["fresh"][0]),
                 ps(c["revisit"][0]), ps(c["restarts"][0]),
                 ps(c["over"][0]), ps(c["vis_over"][0]),
-                jax.lax.pmax(c["deepest"][0], ax), k,
+                pm(c["deepest"][0]), k,
+            ]).astype(jnp.int32)
+            extras = jnp.stack([
+                ps(c["ev_rem"][0]), ps(c["probes"][0]),
+                pm(c["net_peak"][0]), pm(c["tmr_peak"][0]),
+                ps(c["refused"][0]),
             ]).astype(jnp.int32)
             # Per-device stats lanes (ISSUE 8): the pre-psum per-device
             # scalars ride the SAME readback, LAST so every absolute
             # index parse stays valid — [explored×D, fresh×D,
             # restarts×D, deepest×D], one all_gather in the fused round
-            # program, zero extra dispatches or transfers.
+            # program, zero extra dispatches or transfers.  Between the
+            # flag counts and that tail: EXTRAS, then the fresh inserts
+            # by walk depth (:meth:`_stats_dict`).
             per_dev = jnp.stack([c["explored"][0], c["fresh"][0],
                                  c["restarts"][0], c["deepest"][0]])
             return jnp.concatenate([
-                core, ps(c["hit_cnt"]).astype(jnp.int32),
+                core, ps(c["hit_cnt"]).astype(jnp.int32), extras,
+                ps(c["fresh_by_depth"]).astype(jnp.int32),
                 jax.lax.all_gather(per_dev, ax).T.reshape(-1)
                 .astype(jnp.int32)])
 
@@ -664,32 +789,80 @@ class SwarmSearch(TensorSearch):
             return carry, stats_local(carry, k)
 
         spec = self._carry_specs()
-        if (self.p.deliver_message_rt is not None
-                or self.p.deliver_timer_rt is not None):
-            return shard_map(
-                lambda c, b, m: round_local(c, b, m), mesh=self.mesh,
-                in_specs=(spec, P(), (P(), P())),
-                out_specs=(spec, P()), check_vma=False)
-        return shard_map(
-            lambda c, b: round_local(c, b), mesh=self.mesh,
-            in_specs=(spec, P()), out_specs=(spec, P()),
-            check_vma=False)
+        masked = (self.p.deliver_message_rt is not None
+                  or self.p.deliver_timer_rt is not None)
+        sm = shard_map(
+            round_local, mesh=self.mesh,
+            in_specs=(spec, P()) + (((P(), P()),) if masked else ()),
+            out_specs=(spec, P()), check_vma=False)
+
+        def swarm_round(*args):
+            return sm(*args)
+
+        return swarm_round
 
     def _round_call(self, carry, budget: int):
         """Dispatch one round through the supervisor seam; the
         dispatched callable blocks on the scalar stats readback so the
-        watchdog bounds the fused round."""
+        watchdog bounds the fused round.  Inside the seam's
+        ``dispatch.round`` the round is one ``swarm.round`` span, closed
+        with the fleet's counters as that readback gives them."""
         b = jnp.asarray(budget, jnp.int32)
         rt = getattr(self, "_rt_masks", None)
+        prog = self._round_exe or self._round
+        rounds = int(getattr(self, "_current_depth", 0) or 0)
 
         def run(c, bb, *masks):
-            c2, stats = (self._round(c, bb, masks[0]) if masks
-                         else self._round(c, bb))
-            return c2, device_get(stats)
+            with tel_mod.phase("swarm.round", round=rounds,
+                               steps=budget) as span:
+                c2, stats = prog(c, bb, *masks)
+                stats = device_get(stats)
+                span.set(**{k: v for k, v in self._stats_dict(
+                    stats, rounds, 0.0).items() if k in _SPAN_FIELDS})
+            return c2, stats
 
         if rt is not None:
             return self._dispatch("swarm.round", run, carry, b, rt)
         return self._dispatch("swarm.round", run, carry, b)
+
+    def _store_devices(self) -> list:
+        return list(self.mesh.devices.flat)
+
+    def _store_shape(self) -> tuple:
+        """The base engine's, and what this constructor bakes into the
+        round program: the mesh, the fleet's shape (walkers a device,
+        history length), the patience, the depth bins of the fresh
+        count.  Bounds, temperatures, affinities, the PRNG key and the
+        steps a round runs are arguments, not shape."""
+        return super()._store_shape() + (
+            self.mesh.axis_names, self.mesh.devices.shape,
+            tuple(int(d.id) for d in self._store_devices()),
+            self.walkers, self.max_steps, self.revisit_patience,
+            FRESH_DEPTHS)
+
+    def _load_round(self, carry) -> None:
+        """The round program as an executable, before the first round
+        is dispatched: LOADED from the executable store
+        (tpu/compile_cache.py) where a process of this source has
+        compiled it for this twin, caps, predicates, mesh and fleet
+        shape, else traced, lowered and compiled here and kept there.
+        Either way the rounds dispatch the executable, and it is
+        registered for ``telemetry.program_scopes``.  An engine with no
+        key (a weak fingerprint, a patched package) compiles the same
+        way and stores nothing."""
+        if self._round_exe is not None:
+            return
+        rt = getattr(self, "_rt_masks", None)
+        args = (jax.tree.map(_abstract, carry),
+                jax.ShapeDtypeStruct((), jnp.int32))
+        if rt is not None:
+            args += (jax.tree.map(_abstract, rt),)
+        with tel_mod.phase("compile.aot.swarm_round"):
+            self._round_exe = compile_cache.stored(
+                compile_cache.program_key(self.store_key(), ROUND, args),
+                ROUND, lambda: self._round.lower(*args).compile(),
+                self._store_devices())
+        tel_mod.register_program(ROUND, self._round_exe)
 
     # ------------------------------------------------------------- carry
 
@@ -736,13 +909,7 @@ class SwarmSearch(TensorSearch):
                 "affin": s["affin"], "key": s["key"],
                 "seeds": s["seeds"], "seeds_n": s["seeds_n"],
                 "visited": table,
-                "explored": jnp.zeros((1,), jnp.int32),
-                "fresh": jnp.zeros((1,), jnp.int32),
-                "revisit": jnp.zeros((1,), jnp.int32),
-                "restarts": jnp.zeros((1,), jnp.int32),
-                "over": jnp.zeros((1,), jnp.int32),
-                "vis_over": jnp.zeros((1,), jnp.int32),
-                "deepest": jnp.zeros((1,), jnp.int32),
+                **self._zero_counters(),
                 "hit_cnt": jnp.zeros((nf,), jnp.int32),
                 "hit_rows": jnp.zeros((nf, lanes), jnp.int32),
                 "hit_hist": jnp.full((nf, S), -1, jnp.int32),
@@ -751,12 +918,16 @@ class SwarmSearch(TensorSearch):
             }
             return out, jnp.sum(unres).astype(jnp.int32)[None]
 
-        ax = self.axis
-        in_spec = {k: P(ax) for k in dev_in}
-        fn = jax.jit(shard_map(local, mesh=self.mesh,
-                               in_specs=(in_spec,),
-                               out_specs=(self._carry_specs(), P(ax)),
-                               check_vma=False))
+        # One initialiser a pool and pre-seed shape an engine: a second
+        # run() of this fleet compiles nothing.
+        fn = self._init_progs.get((pool, m))
+        if fn is None:
+            ax = self.axis
+            in_spec = {k: P(ax) for k in dev_in}
+            fn = self._init_progs[(pool, m)] = jax.jit(shard_map(
+                local, mesh=self.mesh, in_specs=(in_spec,),
+                out_specs=(self._carry_specs(), P(ax)),
+                check_vma=False))
 
         def build(inputs):
             carry, unres = fn(inputs)
@@ -770,6 +941,21 @@ class SwarmSearch(TensorSearch):
                 f"pre-seed {m} BFS keys ({n_unres} unresolved); raise "
                 "visited_cap")
         return carry
+
+    def walker_snapshot(self, walkers) -> List[Tuple[np.ndarray, List[int]]]:
+        """``(row, events)`` of each walker of ``walkers`` (indices into
+        the whole fleet) as the last run left it: the state row it
+        stands on and the grid event ids, seed-first, that took it
+        there from its seed — its recorded history, which a replay from
+        that seed must end on that row."""
+        c = self._final_carry
+        if c is None:
+            raise RuntimeError("no finished run to read walkers from")
+        idx = jnp.asarray(np.asarray(walkers, np.int32))
+        rows, depths, hists = (device_get(c[k][idx])
+                               for k in ("rows", "depths", "hists"))
+        return [(rows[i], [int(e) for e in hists[i][:int(depths[i])]])
+                for i in range(len(rows))]
 
     # ------------------------------------------------------- checkpoints
 
@@ -808,9 +994,11 @@ class SwarmSearch(TensorSearch):
             "seeds_n": np.asarray(carry["seeds_n"]),
             "vdev": vdev,
             "counters": np.stack([
-                np.asarray(carry[k]).reshape(-1)
-                for k in ("explored", "fresh", "revisit", "restarts",
-                          "over", "vis_over", "deepest")]),
+                np.asarray(carry[k]).reshape(-1) for k in COUNTERS]),
+            "extras": np.stack([
+                np.asarray(carry[k]).reshape(-1) for k in EXTRAS]),
+            "fresh_by_depth": np.asarray(
+                carry["fresh_by_depth"]).reshape(D, FRESH_DEPTHS),
         }
         ck = ckpt_mod.SearchCheckpoint(
             fingerprint=self._ckpt_fingerprint(), depth=rounds,
@@ -905,6 +1093,20 @@ class SwarmSearch(TensorSearch):
         c_new = np.zeros((7, D), np.int64)
         c_new[:, 0] = totals
         x["counters"] = c_new
+        # EXTRAS likewise (sums; max for the two peaks), and the fresh
+        # inserts by depth; a dump from before they existed has none.
+        e_old = np.asarray(x.get("extras", np.zeros((len(EXTRAS), d_old))),
+                           np.int64).reshape(len(EXTRAS), d_old)
+        e_new = np.zeros((len(EXTRAS), D), np.int64)
+        peaks = [k.endswith("_peak") for k in EXTRAS]
+        e_new[:, 0] = np.where(peaks, e_old.max(axis=1, initial=0),
+                               e_old.sum(axis=1))
+        x["extras"] = e_new
+        f_new = np.zeros((D, FRESH_DEPTHS), np.int64)
+        f_new[0] = np.asarray(
+            x.get("fresh_by_depth", np.zeros((d_old, FRESH_DEPTHS))),
+            np.int64).reshape(d_old, FRESH_DEPTHS).sum(axis=0)
+        x["fresh_by_depth"] = f_new
         import dataclasses as _dc
 
         return _dc.replace(ck, frontier=rows_old[idx],
@@ -943,6 +1145,11 @@ class SwarmSearch(TensorSearch):
             kval[d, :n] = True
             off += n
         counters = np.asarray(x["counters"], np.int32)
+        extras = np.asarray(
+            x.get("extras", np.zeros((len(EXTRAS), D))), np.int32)
+        by_depth = np.asarray(
+            x.get("fresh_by_depth", np.zeros((D, FRESH_DEPTHS))),
+            np.int32).reshape(D * FRESH_DEPTHS)
         shard = NamedSharding(self.mesh, P(self.axis))
         bounds, temps, affin = self._schedules()
         dev_in = {k: jax.device_put(v, shard) for k, v in {
@@ -957,12 +1164,15 @@ class SwarmSearch(TensorSearch):
             "bounds": bounds, "temps": temps, "affin": affin,
             "pkeys": kbuf.reshape(-1, 4), "pval": kval.reshape(-1),
             "counters": counters.T.copy(),          # [D, 7]
+            "extras": extras.T.copy(),              # [D, len(EXTRAS)]
+            "fresh_by_depth": by_depth,
         }.items()}
 
         def local(s):
             table, ins, unres = visited_mod.insert(
                 visited_mod.empty_table(V), s["pkeys"], s["pval"])
             cnt = s["counters"][0]
+            ext = s["extras"][0]
             out = {
                 "rows": s["rows"], "depths": s["depths"],
                 "hists": s["hists"], "streak": s["streak"],
@@ -971,10 +1181,9 @@ class SwarmSearch(TensorSearch):
                 "affin": s["affin"], "key": s["key"],
                 "seeds": s["seeds"], "seeds_n": s["seeds_n"],
                 "visited": table,
-                "explored": cnt[0][None], "fresh": cnt[1][None],
-                "revisit": cnt[2][None], "restarts": cnt[3][None],
-                "over": cnt[4][None], "vis_over": cnt[5][None],
-                "deepest": cnt[6][None],
+                **{k: cnt[i][None] for i, k in enumerate(COUNTERS)},
+                **{k: ext[i][None] for i, k in enumerate(EXTRAS)},
+                "fresh_by_depth": s["fresh_by_depth"],
                 "hit_cnt": jnp.zeros((nf,), jnp.int32),
                 "hit_rows": jnp.zeros((nf, lanes), jnp.int32),
                 "hit_hist": jnp.full((nf, S), -1, jnp.int32),
@@ -1012,6 +1221,7 @@ class SwarmSearch(TensorSearch):
         state = (jax.tree.map(jnp.asarray, initial)
                  if initial is not None else self.initial_state())
         self._trace_root = jax.tree.map(np.asarray, state)
+        self._final_carry = None        # the last run's buffers go
         t0 = time.time()
         if check_initial:
             out = self._check_initial(state, t0)
@@ -1034,10 +1244,14 @@ class SwarmSearch(TensorSearch):
         else:
             carry = self._init_carry(state)
             rounds, prev_elapsed = 0, 0.0
-        # Warm-up: a zero-step round compiles the fused program OUTSIDE
-        # the wall budget; the persistent compile cache makes the
-        # second construction near-free.
+        # Warm-up: the round program is loaded from the executable
+        # store, or compiled and kept there, and a zero-step round runs
+        # it once, all OUTSIDE the wall budget.
         t_c = time.time()
+        self._load_round(carry)
+        # Live "depth" for supervision heartbeats and for the round's
+        # span = round count.
+        self._current_depth = rounds
         carry, _ = self._round_call(carry, 0)
         self.compile_secs += time.time() - t_c
         tel = getattr(self, "_telemetry", None)
@@ -1056,10 +1270,10 @@ class SwarmSearch(TensorSearch):
             round_cap = (self.max_rounds is not None
                          and rounds >= self.max_rounds)
             if cancelled or timed_out or round_cap:
+                self._final_carry = carry
                 return self._exhaust_outcome(stats, rounds, t0,
                                              cancelled)
             rounds += 1
-            # Live "depth" for supervision heartbeats = round count.
             self._current_depth = rounds
             t_round = time.time()
             carry, stats = self._round_call(carry,
@@ -1067,8 +1281,6 @@ class SwarmSearch(TensorSearch):
             stats = np.asarray(stats)
             tel = getattr(self, "_telemetry", None)
             if tel is not None:
-                from dslabs_tpu.tpu import telemetry as tel_mod
-
                 # Fed from the round's fused stats vector — the same
                 # scalars this loop reads anyway (zero extra syncs).
                 rec = {
@@ -1132,6 +1344,12 @@ class SwarmSearch(TensorSearch):
                 raise CapacityOverflow(
                     f"{self.p.name}: {over} walker steps truncated by "
                     "net/timer caps (strict swarm); raise the caps")
+            refused = int(stats[8 + nf + EXTRAS.index("refused")])
+            if self.strict and refused:
+                raise CapacityOverflow(
+                    f"{self.p.name}: {refused} walker steps refused by "
+                    "the twin for want of room in its own state "
+                    "(strict swarm); bind a wider twin")
             if (self.checkpoint_path and self.checkpoint_every
                     and rounds % self.checkpoint_every == 0):
                 self._save_swarm_ckpt(carry, rounds, time.time() - t0)
@@ -1140,12 +1358,19 @@ class SwarmSearch(TensorSearch):
         (explored, fresh, revisit, restarts, over, vis_over,
          deepest, _steps) = (int(x) for x in stats[:8])
         el = max(elapsed, 1e-9)
+        # After the flag counts: EXTRAS, then the fresh inserts by walk
+        # depth 1..FRESH_DEPTHS (the per-device tail follows).
+        at = 8 + len(self._flag_names)
+        extras = {k: int(x) for k, x in zip(EXTRAS, stats[at:])}
+        at += len(EXTRAS)
         return {
             "walkers": self.n_devices * self.walkers,
             "rounds": rounds, "explored": explored, "unique": fresh,
             "revisits": revisit, "restarts": restarts,
             "overflow_restarts": over, "vis_over": vis_over,
-            "deepest": deepest,
+            "deepest": deepest, **extras,
+            "fresh_by_depth": [int(x)
+                               for x in stats[at:at + FRESH_DEPTHS]],
             "walkers_per_sec": round(explored / el, 1),
             "unique_per_min": round(fresh / el * 60.0, 1),
         }
@@ -1159,12 +1384,14 @@ class SwarmSearch(TensorSearch):
         out.compile_secs = round(self.compile_secs, 3)
         self._stamp_device(out)
         out.resumed_from_depth = getattr(self, "_resumed_from_depth", 0)
-        if out.swarm_overflow > OVERFLOW_WARN:
+        if out.swarm_overflow + sd["refused"] > OVERFLOW_WARN:
             warnings.warn(
                 f"{self.p.name}: {out.swarm_overflow} walker steps "
                 "were capacity-truncated and restarted (net/timer caps "
-                "too small for the walked region) — deep coverage is "
-                "degraded; raise the caps or run a strict swarm",
+                f"too small for the walked region), {sd['refused']} "
+                "refused by the twin for want of room in its own state "
+                "— deep coverage is degraded; raise the caps or run a "
+                "strict swarm",
                 RuntimeWarning, stacklevel=3)
         if out.walker_restarts > RESTART_WARN:
             warnings.warn(
@@ -1184,7 +1411,8 @@ class SwarmSearch(TensorSearch):
                          cancelled: bool) -> SearchOutcome:
         elapsed = time.time() - t0
         if stats is None:
-            stats = np.zeros((8 + len(self._flag_names),), np.int64)
+            stats = np.zeros((8 + len(self._flag_names) + len(EXTRAS)
+                              + FRESH_DEPTHS,), np.int64)
         sd = self._stats_dict(stats, rounds, elapsed)
         out = SearchOutcome(
             "TIME_EXHAUSTED", sd["explored"], sd["unique"],

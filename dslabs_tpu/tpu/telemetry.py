@@ -112,7 +112,7 @@ __all__ = ["Telemetry", "MetricsRegistry", "Counter", "Gauge",
            "read_ledger", "compare_ledger", "render_compare",
            "DISPATCH_SITES", "PHASES", "DEVICE_SCOPES", "AOT_PROGRAMS",
            "phase", "mark", "call", "annotate", "use", "current",
-           "device_scope", "register_program", "registered_programs",
+           "current_phase", "device_scope", "register_program", "registered_programs",
            "program_scopes", "scopes_of_hlo", "KEPT_SUPERSTEP", "main"]
 
 # THE canonical dispatch-site registry (ISSUE 10): every tag the
@@ -397,7 +397,15 @@ PHASES = (
     # ``attempt`` on these two as well: a rung that overflows throws its
     # warm run and its search away (entry.capacity_retry ends it)
     "entry.warm_run", "entry.search", "entry.replay", "entry.recheck",
-    "entry.probe",
+    # ``entry.probe``: the dfs entry's swarm probe (``walkers``: the
+    # fleet's width, ``max_steps``: its history length = the deepest
+    # bound); under it, inside each ``dispatch.round`` the seam opens,
+    # ``swarm.round`` (``round``, ``steps``: the budget; at close the
+    # fleet's cumulative ``explored``, ``unique``, ``revisits``,
+    # ``restarts``, ``overflow_restarts``, ``vis_over``, ``deepest``,
+    # ``probes``: restarts by a prune, the bound or a dead end, and
+    # ``refused``: steps the twin had no room in its own state for)
+    "entry.probe", "swarm.round",
     # mark: a ladder attempt overflowed (``attempt``, ``overflow``, and
     # ``explored``: the states its last stats readback had counted)
     "entry.capacity_retry",
@@ -440,6 +448,9 @@ PHASES = (
     # an engine's key built, an entry read and loaded onto the devices,
     # an entry serialized and written
     "compile.store.key", "compile.store.load", "compile.store.write",
+    # the swarm's round program (tpu/swarm.py ``_load_round``), asked of
+    # the store before its first round as the AOT programs are
+    "compile.aot.swarm_round",
 ) + tuple(f"compile.aot.{name}" for name in AOT_PROGRAMS) + tuple(
     sorted({"dispatch." + tag.split(".", 1)[1] for tag in DISPATCH_SITES}))
 
@@ -454,7 +465,11 @@ PHASES = (
 DEVICE_SCOPES = (
     "expand.events", "expand.handlers", "expand.canon", "fingerprint",
     "flags", "pack", "trace_meta", "route", "exchange", "visited_insert",
-    "append", "level_sync", "promote")
+    "append", "level_sync", "promote",
+    # the swarm's walk step (tpu/swarm.py): what only a walker does —
+    # ids, logits and the categorical pick; the seed gather and the
+    # ``where``s of restart resolution; the history's write
+    "walk.pick", "walk.restart", "walk.history")
 
 ANNOTATION_PREFIX = "dslabs:"
 SCOPE_PREFIX = "dslabs."
@@ -559,8 +574,9 @@ class phase:
         self._note.__enter__()
         self._recorder = _CURRENT.get()
         self._t0 = time.time()
-        self._parent = _PARENT.get()
-        self._token = _PARENT.set(self.name)
+        parent = _PARENT.get()
+        self._parent = parent.name if parent is not None else None
+        self._token = _PARENT.set(self)
         return self
 
     def set(self, **fields) -> None:
@@ -575,6 +591,13 @@ class phase:
                 self.name, self._t0, time.time() - self._t0,
                 self._parent, self.fields)
         return False
+
+
+def current_phase() -> Optional[phase]:
+    """The innermost phase open in this context (None outside any): for
+    code that learns a field of the span it runs under only once it is
+    inside — the probe's fleet width under ``entry.probe``."""
+    return _PARENT.get()
 
 
 @contextlib.contextmanager

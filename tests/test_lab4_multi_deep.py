@@ -328,22 +328,26 @@ def _the_manifest_reads_the_cell_where_the_issue_says(man, entry, traffic,
                          "source": "device_trace", "layer": "expand",
                          "moves": "states_per_s", "workloads": [CELL]}
         if m["name"] in reads:
-            assert m["workloads"][-1] == CELL        # appended
+            # appended (PR 43's cell behind it where it reads the metric)
+            assert CELL in m["workloads"][-2:]
             assert os.path.isfile(os.path.join(
                 ROOT, "benchmark", "layer_metrics", m["name"] + ".py"))
     e2e = {m["name"] for m in man["end_to_end"]
            if "workloads" not in m or CELL in m["workloads"]}
     assert e2e == {"states_per_s", "setup_s"}
-    # appended, never put first or in the middle; seven cells, one of
-    # them on four chips
-    assert man["workloads"][-1]["name"] == CELL
-    assert man["configs"][-1]["name"] == entry["config"]
-    # (PR 41 appended its one metric behind it)
+    # appended, never put first or in the middle: the seventh cell and
+    # the sixth configuration (PR 43 appended its own behind them); one
+    # cell on four chips
+    assert man["workloads"][6]["name"] == CELL
+    assert man["configs"][5]["name"] == entry["config"]
+    # (PR 41 appended its one metric behind it, PR 43 its four)
     names = [m["name"] for m in man["per_layer"]]
     assert names[names.index("gpaxos_handlers_pct.deep"):] == [
-        "gpaxos_handlers_pct.deep", "exe_store_hit_pct"]
+        "gpaxos_handlers_pct.deep", "exe_store_hit_pct",
+        "walk_us_per_step.swarm", "fresh_pct.swarm", "restarts_pct.swarm",
+        "round_roofline.swarm"]
     assert [w["chips"] for w in man["workloads"]].count(4) == 1
-    assert len(man["workloads"]) == 7
+    assert len(man["workloads"]) == 8        # PR 43 appended one
 
 
 _CELL_CHECKS = [_both_files_say_what_the_manifest_says,

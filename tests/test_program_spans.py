@@ -589,12 +589,35 @@ def compiled():
     return s
 
 
-def test_every_device_scope_is_in_the_compiled_programs(compiled):
+@pytest.fixture(scope="module")
+def walked():
+    """A swarm fleet whose round program has run (and so is loaded or
+    compiled, and registered as ``swarm_round``)."""
+    from dslabs_tpu.tpu.swarm import SwarmSearch
+
+    s = SwarmSearch(_pruned_pingpong(), mesh=make_mesh(1),
+                    walkers_per_device=8, max_steps=8, max_rounds=1,
+                    steps_per_round=2, visited_cap=1 << 10)
+    s.run()
+    return s
+
+
+def test_every_device_scope_is_in_the_compiled_programs(compiled, walked):
     text = compiled._aot_exes["superstep"].as_text()
+    walk = walked._round_exe.as_text()
     for scope in tel_mod.DEVICE_SCOPES:
         where = (compiled._aot_exes["promote"].as_text()
-                 if scope == "promote" else text)
+                 if scope == "promote"
+                 else walk if scope.startswith("walk.") else text)
         assert f"/{tel_mod.SCOPE_PREFIX}{scope}/" in where, scope
+    # the walk step names the BFS step's scopes where the work is the
+    # same, and nothing outside the table
+    for scope in ("expand.events", "expand.handlers", "expand.canon",
+                  "fingerprint", "flags", "visited_insert"):
+        assert f"/{tel_mod.SCOPE_PREFIX}{scope}/" in walk, scope
+    assert walked._round_exe in tel_mod.registered_programs("swarm_round")
+    assert {s for s, _named in tel_mod.scopes_of_hlo(walk).values()} <= set(
+        tel_mod.DEVICE_SCOPES)
     scopes = tel_mod.scopes_of_hlo(text)
     assert {s for s, _named in scopes.values()} <= set(
         tel_mod.DEVICE_SCOPES)

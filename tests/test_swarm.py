@@ -220,15 +220,20 @@ def test_frontier_seeding_resume_parity(tmp_path):
     """A frontier-seeded swarm cut mid-flight resumes from its round
     checkpoint (walker rows, histories, PRNG keys, seed pool, table)
     to a BIT-IDENTICAL continuation: same verdict, same witness, same
-    counters as the uncut run."""
+    counters as the uncut run.  A round is FOUR steps: the uncut run
+    meets its violation at its eighth step, so the cut run's one round
+    stops it halfway there and the resumed fleet has a round to run
+    (at eight steps a round the cut run found the violation itself,
+    and nothing was left to resume: red from the seed to PR 43)."""
     proto = _violating(make_pingpong_protocol(3))
     bfs_ck = str(tmp_path / "bfs.npz")
     TensorSearch(proto, chunk=64, max_depth=2, checkpoint_path=bfs_ck,
                  checkpoint_every=1).run()
-    kw = dict(walkers_per_device=8, max_steps=24, steps_per_round=8,
+    kw = dict(walkers_per_device=8, max_steps=24, steps_per_round=4,
               seed=3, frontier_seed=bfs_ck)
     full = _swarm(proto, **kw).run()
     assert full.end_condition == "INVARIANT_VIOLATED"
+    assert full.swarm["rounds"] == 2
     sw_ck = str(tmp_path / "swarm.npz")
     cut = _swarm(proto, max_rounds=1, checkpoint_path=sw_ck,
                  checkpoint_every=1, **kw).run()
