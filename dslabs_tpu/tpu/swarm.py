@@ -134,6 +134,20 @@ COUNTERS = ("explored", "fresh", "revisit", "restarts", "over",
 EXTRAS = ("ev_rem", "probes", "net_peak", "tmr_peak", "refused")
 # The round program's name (``jit_swarm_round`` in a profile).
 ROUND = "swarm_round"
+# A fleet whose network operand in the walk step's transposed merge —
+# walkers x net_cap x msg_width x 4 bytes — passes STEP_BLOCK_BYTES runs
+# its handlers and merge (:meth:`SwarmSearch._step_rows`) over blocks of
+# STEP_BLOCK_ROWS walkers: one tile of the 128 lanes the walkers ride in
+# that merge, the one block width of 64 to 2,048 at which a v5e's
+# compiler keeps the merge's per-send planes ``[1, net_cap, rows]`` in
+# whole (8, 128) tiles near the cores (at 256 and up: one sublane of
+# eight, in HBM).  Five servers' probe twin, 64 KiB of network a walker,
+# us a walker step at once / in blocks of 128 (PERF.md section 6, PR 44):
+# 8,192 walkers (512 MiB) 8.2 / 5.7 — and 11-36 in blocks of 256 to 2,048
+# — but 1,024 walkers (64 MiB) 3.9 / 5.2 and 256 walkers 3.8 / 4.9: the
+# byte constant is the largest fleet measured faster at once.
+STEP_BLOCK_ROWS = 128
+STEP_BLOCK_BYTES = 64 << 20
 # What a ``swarm.round`` span is closed with, of :meth:`_stats_dict`.
 _SPAN_FIELDS = ("explored", "unique", "revisits", "restarts",
                 "overflow_restarts", "vis_over", "deepest", "probes",
@@ -318,6 +332,16 @@ def build_witness(search: TensorSearch, root_row: np.ndarray,
 
 # ------------------------------------------------------------ the swarm
 
+def step_block_rows(walkers: int, net_cap: int, msg_width: int) -> int:
+    """Walkers a block of :meth:`SwarmSearch._step_rows`:
+    ``STEP_BLOCK_ROWS`` where the fleet's network operand passes
+    ``STEP_BLOCK_BYTES`` — else, or where the block does not divide the
+    fleet, the whole fleet (ONE block: the program without the loop)."""
+    small = 4 * walkers * net_cap * msg_width <= STEP_BLOCK_BYTES
+    return (walkers if small or walkers % STEP_BLOCK_ROWS
+            else STEP_BLOCK_ROWS)
+
+
 def _abstract(x):
     """The shape, dtype and sharding of a device array: what a program
     is lowered for."""
@@ -391,6 +415,9 @@ class SwarmSearch(TensorSearch):
                          strict=strict,
                          checkpoint_path=checkpoint_path,
                          checkpoint_every=checkpoint_every)
+        self.block_rows = step_block_rows(
+            self.walkers, self.p.net_cap, self.p.msg_width)
+        self.step_blocks = self.walkers // self.block_rows   # 1 = idle
         self._round = jax.jit(self._build_round(), donate_argnums=0)
         self._round_exe = None          # :meth:`_load_round`
         self._init_progs = {}           # :meth:`_init_carry`
@@ -530,12 +557,27 @@ class SwarmSearch(TensorSearch):
 
     def _step_rows(self, rows, ev):
         """K rows, one grid event id each -> (successor rows, valid,
-        over): what ``vmap(_step_one)`` gives, with only the HANDLER
-        half under the vmap and the network merge as the engine's one
-        batched, transposed tail (``_batched_tail`` at one pair a row:
-        walkers ride the minor axis, as the BFS chunk's pairs do)."""
+        over): :meth:`_step_block` of the fleet at once or, where the
+        fleet is wider than a block (``step_blocks`` > 1), of one block
+        of ``block_rows`` consecutive walkers after another — a pure
+        function of each row and its event, so the same values either
+        way."""
+        n, kb = self.step_blocks, self.block_rows
+        if n == 1:
+            return self._step_block(rows, ev)
+        succ, ok, over = jax.lax.map(
+            lambda block: self._step_block(*block),
+            (rows.reshape(n, kb, -1), ev.reshape(n, kb)))
+        return succ.reshape(n * kb, -1), ok.reshape(-1), over.reshape(-1)
+
+    def _step_block(self, rows, ev):
+        """What ``vmap(_step_one)`` gives for a block of rows, with only
+        the HANDLER half under the vmap and the network merge as the
+        engine's one batched, transposed tail (``_batched_tail`` at one
+        pair a row: walkers ride the minor axis, as the BFS chunk's
+        pairs do)."""
         p = self.p
-        K = self.walkers
+        K = rows.shape[0]
         with tel_mod.device_scope("expand.handlers"):
             is_msg = ev < p.net_cap
             m = jax.vmap(self._msg_step_raw)(
@@ -813,8 +855,9 @@ class SwarmSearch(TensorSearch):
         rounds = int(getattr(self, "_current_depth", 0) or 0)
 
         def run(c, bb, *masks):
-            with tel_mod.phase("swarm.round", round=rounds,
-                               steps=budget) as span:
+            with tel_mod.phase("swarm.round", round=rounds, steps=budget,
+                               blocks=self.step_blocks,
+                               block_rows=self.block_rows) as span:
                 c2, stats = prog(c, bb, *masks)
                 stats = device_get(stats)
                 span.set(**{k: v for k, v in self._stats_dict(
@@ -833,7 +876,11 @@ class SwarmSearch(TensorSearch):
         round program: the mesh, the fleet's shape (walkers a device,
         history length), the patience, the depth bins of the fresh
         count.  Bounds, temperatures, affinities, the PRNG key and the
-        steps a round runs are arguments, not shape."""
+        steps a round runs are arguments, not shape.  The walk step's
+        blocks are not listed: they follow from two constants of this
+        module (in the key's hash of the package's source), ``walkers``
+        and the protocol's ``net_cap`` and ``msg_width`` (its
+        fingerprint)."""
         return super()._store_shape() + (
             self.mesh.axis_names, self.mesh.devices.shape,
             tuple(int(d.id) for d in self._store_devices()),
@@ -1365,6 +1412,7 @@ class SwarmSearch(TensorSearch):
         at += len(EXTRAS)
         return {
             "walkers": self.n_devices * self.walkers,
+            "step_blocks": self.step_blocks,
             "rounds": rounds, "explored": explored, "unique": fresh,
             "revisits": revisit, "restarts": restarts,
             "overflow_restarts": over, "vis_over": vis_over,

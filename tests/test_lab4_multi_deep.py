@@ -340,12 +340,13 @@ def _the_manifest_reads_the_cell_where_the_issue_says(man, entry, traffic,
     # cell on four chips
     assert man["workloads"][6]["name"] == CELL
     assert man["configs"][5]["name"] == entry["config"]
-    # (PR 41 appended its one metric behind it, PR 43 its four)
+    # (PR 41 appended its one metric behind it, PR 43 its four, PR 44
+    # its one)
     names = [m["name"] for m in man["per_layer"]]
     assert names[names.index("gpaxos_handlers_pct.deep"):] == [
         "gpaxos_handlers_pct.deep", "exe_store_hit_pct",
         "walk_us_per_step.swarm", "fresh_pct.swarm", "restarts_pct.swarm",
-        "round_roofline.swarm"]
+        "round_roofline.swarm", "blocks_per_step.swarm"]
     assert [w["chips"] for w in man["workloads"]].count(4) == 1
     assert len(man["workloads"]) == 8        # PR 43 appended one
 

@@ -42,6 +42,7 @@ from dslabs_tpu.tpu.protocols.pingpong import \
 from dslabs_tpu.tpu.sharded import make_mesh  # noqa: E402
 from dslabs_tpu.tpu.supervisor import (FaultPlan, RetryPolicy,  # noqa: E402
                                        SearchSupervisor, install_retry)
+from dslabs_tpu.tpu import swarm as swarm_mod  # noqa: E402
 from dslabs_tpu.tpu.swarm import SwarmSearch  # noqa: E402
 
 pytestmark = pytest.mark.swarm
@@ -315,6 +316,140 @@ def test_strict_swarm_raises_on_truncation():
                 strict=True)
     with pytest.raises(CapacityOverflow):
         sw.run()
+
+
+# ------------------------------------------ the walk step in blocks
+
+BLOCK_KW = dict(walkers_per_device=64, max_steps=24, steps_per_round=8,
+                seed=11, visited_cap=1 << 13)
+
+
+def _pb_crash():
+    """Primary-backup under one crash: message, timer AND fault events
+    are enabled a few steps from the root."""
+    from dslabs_tpu.tpu.specs import pb_crash_spec
+
+    p = pb_crash_spec().compile()
+    return dataclasses.replace(p, goals={}, prunes=dict(p.goals))
+
+
+def _one_and_blocked(monkeypatch, proto):
+    """Two fleets of 64 walkers on one device: ONE block (the module's
+    constants leave any CPU twin's fleet whole), and blocks of 8
+    walkers."""
+    one = _swarm(proto, mesh=make_mesh(1), **BLOCK_KW)
+    monkeypatch.setattr(swarm_mod, "STEP_BLOCK_BYTES", 0)
+    monkeypatch.setattr(swarm_mod, "STEP_BLOCK_ROWS", 8)
+    blocked = _swarm(proto, mesh=make_mesh(1), **BLOCK_KW)
+    assert (one.step_blocks, one.block_rows) == (1, 64)
+    assert (blocked.step_blocks, blocked.block_rows) == (8, 8)
+    return one, blocked
+
+
+def _walk(search, rounds):
+    """The carry after ``rounds`` rounds from the root, and each
+    round's stats vector."""
+    stats = []
+    with search.mesh:
+        carry = search._init_carry(search.initial_state())
+        for _ in range(rounds):
+            carry, st = search._round(
+                carry, jnp.asarray(search.steps_per_round, jnp.int32))
+            stats.append(np.asarray(st))
+    return jax.tree.map(np.asarray, carry), stats
+
+
+def test_step_rows_in_blocks_equals_one_block(monkeypatch):
+    """``_step_rows`` over blocks of 8 walkers gives what it gives over
+    the fleet at once, bit for bit — successor rows, validity,
+    truncation — with message, timer and fault events among the
+    picks, enabled and not."""
+    proto = _pb_crash()
+    one, blocked = _one_and_blocked(monkeypatch, proto)
+    rows = jnp.asarray(_walk(one, 1)[0]["rows"])
+    msg_ids, tmr_ids, flt_ids, _rem = (
+        np.asarray(x) for x in one._event_tables(
+            rows, jnp.ones((64,), bool)))
+    tgrid = proto.n_nodes * proto.timer_cap
+    kinds = [msg_ids, np.where(tmr_ids >= 0, tmr_ids + proto.net_cap, -1),
+             np.where(flt_ids >= 0, flt_ids + proto.net_cap + tgrid, -1)]
+    # walker i asks for an event of kind i % 3: its first enabled one,
+    # else that kind's first grid slot, which is not enabled
+    base = [0, proto.net_cap, proto.net_cap + tgrid]
+    ev = np.zeros((64,), np.int32)
+    for i in range(64):
+        ids = kinds[i % 3][i]
+        ev[i] = ids[ids >= 0][0] if (ids >= 0).any() else base[i % 3]
+    want = jax.jit(one._step_rows)(rows, jnp.asarray(ev))
+    got = jax.jit(blocked._step_rows)(rows, jnp.asarray(ev))
+    for a, b in zip(want, got):
+        np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
+    ok = np.asarray(want[1])
+    for k in range(3):
+        mine = ok[k::3]
+        assert mine.any(), f"no enabled event of kind {k} was stepped"
+    assert not ok.all()
+    assert "while" in jax.jit(blocked._step_rows).lower(
+        rows, jnp.asarray(ev)).as_text()
+
+
+@pytest.mark.parametrize("maker", [
+    _pb_crash, lambda: make_clientserver_protocol(n_clients=2, w=2),
+], ids=["pb-crash", "lab1"])
+def test_blocked_walk_equals_the_one_block_walk(monkeypatch, maker):
+    """Whole-walk parity: after 6 rounds the blocked fleet's carry is
+    the one-block fleet's leaf for leaf — rows, depths, histories,
+    table, PRNG key, every counter, ``fresh_by_depth`` — and so is every
+    round's stats vector: blocks change where the handlers and the merge
+    run, not what the walk is."""
+    one, blocked = _one_and_blocked(monkeypatch, maker())
+    want, want_stats = _walk(one, 6)
+    got, got_stats = _walk(blocked, 6)
+    assert sorted(want) == sorted(got)
+    for k in want:
+        np.testing.assert_array_equal(want[k], got[k], err_msg=k)
+    for a, b in zip(want_stats, got_stats):
+        np.testing.assert_array_equal(a, b)
+    assert int(want["restarts"][0]) > 0 and int(want["fresh"][0]) > 16
+    assert blocked._stats_dict(got_stats[-1], 6, 1.0)["step_blocks"] == 8
+    assert one._stats_dict(want_stats[-1], 6, 1.0)["step_blocks"] == 1
+
+
+@pytest.mark.parametrize("walkers,net_cap,msg_width,rows", [
+    (8192, 2048, 8, 128),    # the five-server probe twin: 64 blocks
+    (2048, 2048, 8, 128),    # 128 MiB of network: sixteen blocks
+    (1024, 2048, 8, 1024),   # 64 MiB, measured faster at once: one block
+    (256, 2048, 8, 256),     # test26's lab fleet: one block
+    (128, 8192, 32, 128),    # no wider than a block: one block
+    (8192, 128, 8, 8192),    # the ladder's top rung at its widest, 32 MiB
+    (4096, 128, 9, 4096),    # lab 4's widest messages, the lab's fleets
+    (128, 128, 3, 128),      # a CPU's probe
+    (8192 + 64, 2048, 8, 8192 + 64),     # a width 128 does not divide
+], ids=["five-servers", "sixteen-blocks", "measured-at-once", "test26-lab",
+        "one-block", "ladder-top-widest", "lab4-multi", "cpu-probe",
+        "undivided"])
+def test_the_block_rule(walkers, net_cap, msg_width, rows):
+    """A fleet whose network operand (walkers x net_cap x msg_width x 4
+    bytes) passes the module's STEP_BLOCK_BYTES walks in blocks of
+    STEP_BLOCK_ROWS walkers, one lane tile; a smaller fleet, or one the
+    block does not divide, is ONE block."""
+    assert swarm_mod.step_block_rows(walkers, net_cap, msg_width) == rows
+    assert walkers % rows == 0
+
+
+def test_a_fleet_of_one_block_has_no_block_loop():
+    """A fleet no wider than its block lowers ``_step_rows`` with no
+    loop at all (no loop of one trip): the program it always was."""
+    proto = make_clientserver_protocol(n_clients=1, w=2)
+    sw = _swarm(proto, mesh=make_mesh(1), **BLOCK_KW)
+    assert (sw.step_blocks, sw.block_rows) == (1, 64)
+    rows = jnp.zeros((64, sw.lanes), jnp.int32)
+    text = jax.jit(sw._step_rows).lower(
+        rows, jnp.zeros((64,), jnp.int32)).as_text()
+    assert "while" not in text
+    assert text == jax.jit(sw._step_block).lower(
+        rows, jnp.zeros((64,), jnp.int32)).as_text().replace(
+            "_step_block", "_step_rows")
 
 
 # ------------------------------------------------------- portfolio

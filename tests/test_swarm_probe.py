@@ -270,14 +270,21 @@ def test_the_lab_entry_and_the_cell_build_one_fleet(five, cell, monkeypatch):
         budget, 1000) == 128
     assert lab.pop("steps_per_round") == backend.probe_round(128) == 64
     assert lab.pop("visited_cap") == backend.probe_table(budget) == 1 << 18
+    # the walk step's blocks follow the twin's caps and the width alone:
+    # at the cell's width the lab entry's fleet walks in the cell's
+    # blocks, and the cell's fleet is wide enough to have any
+    blocks = []
     for args in (lab, bench):
         p = args.pop("protocol")
         assert (p.name, p.net_cap, p.timer_cap) == (
             "paxos-n5-c2-w1-s3", 2048, 10)
+        blocks.append(fleet_cfg["walkers"] // swarm.step_block_rows(
+            fleet_cfg["walkers"], p.net_cap, p.msg_width))
         assert list(p.invariants) == [q.name for q
                                       in five.settings.invariants]
         assert not p.goals and len(p.prunes) == 1
         assert args.pop("mesh").devices.size == 1
+    assert blocks[0] == blocks[1] > 1
     assert lab == bench == {"max_steps": 1000, "seed": 0}
 
 
@@ -472,7 +479,8 @@ def test_the_manifest_holds_the_cell_and_its_metrics():
     new = {m["name"]: m for m in man["per_layer"]
            if m.get("workloads") == [CELL]}
     assert set(new) == {"walk_us_per_step.swarm", "fresh_pct.swarm",
-                        "restarts_pct.swarm", "round_roofline.swarm"}
+                        "restarts_pct.swarm", "round_roofline.swarm",
+                        "blocks_per_step.swarm"}
     assert {m["moves"] for m in new.values()} == {"states_per_s"}
     assert {m["layer"] for m in new.values()} == {"random walk", "kernels"}
     for name in ("states_per_s", "compile_s", "trace_lower_s",
