@@ -163,10 +163,18 @@ class ShardedTensorSearch(TensorSearch):
     one promote per level.
 
     Per-device carry (global shapes have a leading D factor):
-      cur      [F, lanes] int32   current frontier shard (owned states)
-      cur_n    [1]        int32   occupancy of cur
-      nxt      [F+1, lanes]       next-frontier accumulator (+1 dump row)
-      nxt_n    [1]                occupancy of nxt
+      cur      [(F+K)*plane] int32  current frontier shard (owned
+                                  states) as a LOG of packed words: row
+                                  r at words [r*plane, (r+1)*plane).
+                                  Words past cur_n rows are UNSPECIFIED:
+                                  every reader masks by cur_n
+      cur_n    [1]        int32   occupancy of cur (rows)
+      nxt      [(F+K)*plane]      next-frontier log, the same shape (the
+                                  promote SWAPS the two), appended by
+                                  contiguous block writes; the K =
+                                  visited.block_width slack rows take a
+                                  block that would cross row F
+      nxt_n    [1]                occupancy of nxt (rows)
       visited  [32, V/8]  uint32  open-addressing hash table of 128-bit
                                   keys, one bucket a column
                                   (visited.table_shape); EMPTY = all-MAX
@@ -245,7 +253,7 @@ class ShardedTensorSearch(TensorSearch):
         # without it, duplicate successors (all sharing one fingerprint,
         # hence one owner) can pile onto a single fixed-size routing
         # bucket.  On ONE device the bucket holds the entire successor
-        # batch exactly (bucket = C * ne below), so pileup cannot
+        # batch exactly (_bucket), so pileup cannot
         # overflow and even strict runs skip the prefilter (it measured
         # ~60% of a loaded chunk step).  Multi-device strict keeps it:
         # per-owner buckets have only 2x-mean headroom.
@@ -285,6 +293,11 @@ class ShardedTensorSearch(TensorSearch):
         else:
             self._pk = None
         self.plane = self._pk.words if self._pk is not None else self.lanes
+        if self._log_words() >= 1 << 31:
+            raise ValueError(
+                f"frontier_cap={self.f_cap}/device x {self.plane} words a "
+                "row passes the 2^31 words an int32 offset into the next "
+                "frontier's log can address; lower frontier_cap")
         self._mesh_delta = (self._pk is not None and self._pk.has_delta)
         if self._mesh_delta:
             self._delta_lanes = np.asarray(self._pk.delta_lanes, np.int32)
@@ -344,6 +357,25 @@ class ShardedTensorSearch(TensorSearch):
             keys += ["pb_cur", "pb_nxt"]
         return keys
 
+    def _bucket(self) -> int:
+        """Rows of ONE owner bucket of a chunk step's exchange.  On one
+        device every successor routes to the sole owner, so the bucket
+        can hold the whole batch exactly (no overflow headroom needed)
+        — halving the rows the probe loop and the append touch.
+        Multi-device buckets keep 2x-mean headroom for skew."""
+        D, n = self.n_devices, self.cpd * self._num_events()
+        return n if D == 1 else (n // D + 1) * OVERFLOW_FACTOR
+
+    def _log_words(self) -> int:
+        """int32 words of one device's frontier log (``cur`` and ``nxt``
+        alike): ``f_cap`` rows and the slack of one append block (``K``
+        rows of the batch a chunk step receives), so that a block that
+        would cross row ``f_cap`` still lies inside the buffer —
+        ``dynamic_update_slice`` CLAMPS a start that overruns, and a
+        clamped start would overwrite good rows."""
+        K = visited_mod.block_width(self.n_devices * self._bucket())
+        return (self.f_cap + K) * self.plane
+
     # Delta-lane level bases (ISSUE 18 leg (b)).  pb_cur/pb_nxt are
     # [n_delta] int32 per device: the per-lane minimum over the live
     # frontier / accumulating next frontier of every ("delta", bits)
@@ -402,7 +434,6 @@ class ShardedTensorSearch(TensorSearch):
         D = self.n_devices
         C = self.cpd
         F = self.f_cap
-        ne = self._num_events()
         ax = self.axis
         # Packed wire format (ISSUE 18): frontier shards and the
         # row-exchange payload hold PACKED words; owners decode
@@ -414,12 +445,7 @@ class ShardedTensorSearch(TensorSearch):
         pk = self._pk
         plane = self.plane
         delta = self._mesh_delta
-        # On one device every successor routes to the sole owner, so the
-        # bucket can hold the whole batch exactly (no overflow headroom
-        # needed) — halving the rows the probe loop and the append
-        # touch.  Multi-device buckets keep 2x-mean headroom for skew.
-        bucket = (C * ne if D == 1
-                  else (C * ne // D + 1) * OVERFLOW_FACTOR)
+        bucket = self._bucket()
         # Spill mode (tpu/spill.py): frontier/table exhaustion ABORTS
         # the chunk step GLOBALLY — the decision is psum'd and every
         # device reverts its whole update (owner-side inserts included:
@@ -441,8 +467,8 @@ class ShardedTensorSearch(TensorSearch):
             # Stage names for a profile (tpu/telemetry.py
             # DEVICE_SCOPES): HLO metadata only.
             with tel_mod.device_scope("pack"):
-                rows_chunk = jax.lax.dynamic_slice(cur, (start, 0),
-                                                   (C, plane))
+                rows_chunk = jax.lax.dynamic_slice(
+                    cur, (start * plane,), (C * plane,)).reshape(C, plane)
                 base_cur = (self._base_vec(carry["pb_cur"]) if delta
                             else None)
                 if pk is not None:
@@ -638,24 +664,36 @@ class ShardedTensorSearch(TensorSearch):
                 sel = (fresh_s if spill_on else sel_would) & ~noapp
                 nxt_n = carry["nxt_n"][0]
                 # Write narrow, as the table's scatter does
-                # (visited.write_in_blocks): the chip pays a scatter per
-                # INDEX it is handed, and most received rows are not
+                # (visited.write_in_blocks): most received rows are not
                 # selected.  The selected rows, in owner-received order, go
-                # out a block at a time to nxt_n, nxt_n + 1, ...; what a
-                # block holds past the last selected row, and what would
-                # land past F, goes to the dump row F.
-                srcs, bufs = (app_rows,), (carry["nxt"],)
+                # out a block at a time to rows nxt_n, nxt_n + 1, ... of the
+                # log: ONE contiguous write of the block's K rows.  What a
+                # block holds past its last selected row is garbage that
+                # the next block (it starts at nxt_n + n_sel) overwrites or
+                # that lies past the final nxt_n; what would land at row F
+                # or beyond falls in the slack (_log_words) and is dropped.
+                # (A [rows, plane] buffer written at a dynamic ROW made the
+                # compiler carry it lane-padded through the loop and copy
+                # the whole frontier in and out of that layout every
+                # dispatch: PERF.md, PR 45.  A 1-D array has one layout.)
+                bufs = (carry["nxt"],)
                 if self.record_trace:
-                    # Trace meta rides the SAME append blocks as the rows.
-                    srcs += (app_meta,)
+                    # Trace meta rides the SAME append blocks as the rows,
+                    # scattered by row into its [F + 1, 9] buffer: what a
+                    # block holds past the last selected row, and what would
+                    # land past F, goes to the dump row F.
                     bufs += (carry["tmeta"],)
 
                 def append_block(bufs, first, at, live):
-                    dst = nxt_n + first + jnp.arange(
-                        at.shape[0], dtype=jnp.int32)
-                    dst = jnp.where(live & (dst < F), dst, F)
-                    return tuple(buf.at[dst].set(src[at])
-                                 for buf, src in zip(bufs, srcs))
+                    row = nxt_n + first
+                    out = (jax.lax.dynamic_update_slice(
+                        bufs[0], app_rows[at].reshape(-1),
+                        (jnp.minimum(row, F) * plane,)),)
+                    if self.record_trace:
+                        dst = row + jnp.arange(at.shape[0], dtype=jnp.int32)
+                        dst = jnp.where(live & (dst < F), dst, F)
+                        out += (bufs[1].at[dst].set(app_meta[at]),)
+                    return out
 
                 bufs, n_sel, app_blocks = visited_mod.write_in_blocks(
                     sel, append_block, bufs)
@@ -713,7 +751,9 @@ class ShardedTensorSearch(TensorSearch):
                     tb = jax.lax.psum(tbl_full.astype(jnp.int32), ax) > 0
                     abort = fa | tb
                     code = fa.astype(jnp.int32) + 2 * tb.astype(jnp.int32)
-                    revert = ["j", "evp", "nxt", "nxt_n", "visited",
+                    # (nxt itself needs no revert: nxt_n's takes the
+                    # aborted step's rows out of the log.)
+                    revert = ["j", "evp", "nxt_n", "visited",
                               "vis_n", "explored", "overflow", "vis_over",
                               "drops", "flag_cnt", "flag_rows"]
                     if delta:
@@ -872,8 +912,16 @@ class ShardedTensorSearch(TensorSearch):
     def _build_finish(self):
         """Promote nxt -> cur between levels.  Successors already landed
         on their owner's shard inside the superstep, so the promote is a
-        LOCAL buffer swap — no collective, no compaction — plus, under a
-        delta descriptor, the re-base of the promoted rows."""
+        LOCAL buffer SWAP — no collective, no compaction, no word moved:
+        the log the level appended becomes ``cur``, the drained ``cur``
+        becomes the empty log (``nxt_n`` = 0; its words are not
+        re-zeroed, nothing reads a log past its occupancy).  The swap
+        itself is the HOST's (:meth:`_promote_call`: two names changing
+        place — inside the program the donated carry pairs each input
+        with the output of its own name, and a swap would be three
+        frontier-sized copies); this program, handed the swapped carry,
+        moves the counters and, under a delta descriptor, re-bases the
+        promoted rows."""
         F = self.f_cap
         plane = self.plane
         pk = self._pk
@@ -882,7 +930,6 @@ class ShardedTensorSearch(TensorSearch):
         def promote(carry):
             with tel_mod.device_scope("promote"):
                 carry = dict(carry)
-                carry["cur"] = carry["nxt"][:F]
                 carry["cur_n"] = carry["nxt_n"]
                 if delta:
                     # Delta re-base (ISSUE 18 leg (b)): the promoted rows
@@ -898,20 +945,20 @@ class ShardedTensorSearch(TensorSearch):
                     pb_new = jnp.where(
                         carry["pb_nxt"] == jnp.int32(self._PB_EMPTY),
                         pb_old, carry["pb_nxt"])
-                    raw_rows = pk.unpack_jnp(carry["cur"],
-                                             self._base_vec(pb_old))
+                    raw_rows = pk.unpack_jnp(
+                        carry["cur"][:F * plane].reshape(F, plane),
+                        self._base_vec(pb_old))
                     repacked, bad = pk.pack_jnp(raw_rows,
                                                 self._base_vec(pb_new),
                                                 count_bad=True)
                     occ = jnp.arange(F) < carry["cur_n"][0]
-                    carry["cur"] = jnp.where(occ[:, None], repacked,
-                                             jnp.int32(0))
+                    carry["cur"] = jax.lax.dynamic_update_slice(
+                        carry["cur"], repacked.reshape(-1), (0,))
                     carry["overflow"] = carry["overflow"].at[0].add(
                         jnp.sum(jnp.where(occ, bad, 0)).astype(jnp.int32))
                     carry["pb_cur"] = pb_new
                     carry["pb_nxt"] = jnp.full_like(
                         pb_old, jnp.int32(self._PB_EMPTY))
-                carry["nxt"] = jnp.zeros((F + 1, plane), jnp.int32)
                 carry["nxt_n"] = jnp.zeros((1,), jnp.int32)
                 carry["j"] = jnp.zeros((1,), jnp.int32)
                 carry["evp"] = jnp.zeros((1,), jnp.int32)
@@ -924,6 +971,14 @@ class ShardedTensorSearch(TensorSearch):
         return shard_map(promote, mesh=self.mesh,
                          in_specs=(spec,), out_specs=spec,
                          check_vma=False)
+
+    def _promote_call(self, carry):
+        """Promote through the supervisor boundary: swap the two logs
+        (same shape, same placement) and dispatch the level's reset."""
+        carry = dict(carry, cur=carry["nxt"], nxt=carry["cur"])
+        return self._dispatch(
+            "sharded.promote",
+            self._prog("promote", self._finish_level), carry)
 
     def _carry_specs(self):
         """shard_map in/out specs for the carry — derived from the
@@ -986,7 +1041,7 @@ class ShardedTensorSearch(TensorSearch):
             return fn
         D, F, V, lanes = self.n_devices, self.f_cap, self.v_cap, self.lanes
         plane, pk, delta = self.plane, self._pk, self._mesh_delta
-        nf = len(self._flag_names)
+        nf, W = len(self._flag_names), self._log_words()
 
         def init_carry(row0, k0):
             onehot_d = jnp.arange(D) == owner
@@ -1001,13 +1056,13 @@ class ShardedTensorSearch(TensorSearch):
             else:
                 row0s = row0
             out = {
-                "cur": jnp.zeros((D * F, plane), jnp.int32).at[
-                    owner * F].set(row0s),
+                "cur": jnp.zeros((D * W,), jnp.int32).at[
+                    owner * W:owner * W + plane].set(row0s),
                 "cur_n": onehot_d.astype(jnp.int32),
                 "j": jnp.zeros((D,), jnp.int32),
                 "evp": jnp.zeros((D,), jnp.int32),
                 "noapp": jnp.zeros((D,), jnp.int32),
-                "nxt": jnp.zeros((D * (F + 1), plane), jnp.int32),
+                "nxt": jnp.zeros((D * W,), jnp.int32),
                 "nxt_n": jnp.zeros((D,), jnp.int32),
                 "visited": visited_mod.with_root(
                     visited_mod.empty_table(V, D), k0, home, owner),
@@ -1041,7 +1096,7 @@ class ShardedTensorSearch(TensorSearch):
         AOT lowering — shapes mirror _init_prog's builds, shardings come
         from the SAME partition-rule table every dispatch uses."""
         D, F, V, lanes = self.n_devices, self.f_cap, self.v_cap, self.lanes
-        nf = len(self._flag_names)
+        nf, W = len(self._flag_names), self._log_words()
         shards = self._carry_shardings()
 
         def sd(name, shape, dtype=jnp.int32):
@@ -1049,11 +1104,11 @@ class ShardedTensorSearch(TensorSearch):
                                         sharding=shards[name])
 
         out = {
-            "cur": sd("cur", (D * F, self.plane)),
+            "cur": sd("cur", (D * W,)),
             "cur_n": sd("cur_n", (D,)),
             "j": sd("j", (D,)), "evp": sd("evp", (D,)),
             "noapp": sd("noapp", (D,)),
-            "nxt": sd("nxt", (D * (F + 1), self.plane)),
+            "nxt": sd("nxt", (D * W,)),
             "nxt_n": sd("nxt_n", (D,)),
             "visited": sd("visited", visited_mod.table_shape(V, D),
                           jnp.uint32),
@@ -1204,12 +1259,8 @@ class ShardedTensorSearch(TensorSearch):
         # batch — the profiler's hot-site table and the J1/J2/J4 audit
         # cover the kernel itself, not just the superstep it inlines
         # into.
-        ne = self._num_events()
-        bucket = (self.cpd * ne if self.n_devices == 1
-                  else (self.cpd * ne // self.n_devices + 1)
-                  * OVERFLOW_FACTOR)
         sites["visited.insert"] = visited_mod.dispatch_site_program(
-            self.v_cap, self.n_devices * bucket)
+            self.v_cap, self.n_devices * self._bucket())
         args, owner, home = self._root_ids(
             *self._root(self.initial_state(), hits=False))
         sites["sharded.init"] = dict(
@@ -1340,7 +1391,7 @@ class ShardedTensorSearch(TensorSearch):
         def checkpoint_snapshot(c):
             out = {
                 "cur": jax.lax.dynamic_slice(
-                    c["cur"], (0, 0), (m, plane)),
+                    c["cur"], (0,), (m * plane,)),
                 "cur_n": c["cur_n"] + 0,
                 "visited": c["visited"] + jnp.uint32(0),
                 "vis_n": c["vis_n"] + 0,
@@ -1539,17 +1590,19 @@ class ShardedTensorSearch(TensorSearch):
             "drops": spread0(ck.dropped),
         }.items()}
 
+        W = self._log_words()
+
         def resume_carry(s):
             table, ins, unres = visited_mod.insert(
                 visited_mod.empty_table(V), s["keys"], s["kval"])
             out = {
-                "cur": jnp.zeros((F, plane), jnp.int32).at[:per].set(
-                    s["cur0"]),
+                "cur": jnp.zeros((W,), jnp.int32).at[
+                    :per * plane].set(s["cur0"].reshape(-1)),
                 "cur_n": s["cur_n"],
                 "j": jnp.zeros((1,), jnp.int32),
                 "evp": jnp.zeros((1,), jnp.int32),
                 "noapp": jnp.zeros((1,), jnp.int32),
-                "nxt": jnp.zeros((F + 1, plane), jnp.int32),
+                "nxt": jnp.zeros((W,), jnp.int32),
                 "nxt_n": jnp.zeros((1,), jnp.int32),
                 "visited": table,
                 "vis_n": jnp.sum(ins).astype(jnp.int32)[None],
@@ -1600,12 +1653,11 @@ class ShardedTensorSearch(TensorSearch):
         progs = getattr(self, "_sh_spill_prog_cache", None)
         if progs is not None:
             return progs
-        F, V, lanes = self.f_cap, self.v_cap, self.lanes
+        V = self.v_cap
         spec = self._carry_specs()
 
         def spill_reset(c):
             out = dict(c)
-            out["nxt"] = jnp.zeros((F + 1, self.plane), jnp.int32)
             out["nxt_n"] = jnp.zeros((1,), jnp.int32)
             out["f_full"] = jnp.zeros((1,), jnp.int32)
             return out
@@ -1644,7 +1696,7 @@ class ShardedTensorSearch(TensorSearch):
         spool_packed = pk is not None and not self._mesh_delta
 
         def fetch():
-            nxt = np.asarray(carry["nxt"]).reshape(D, F + 1, plane)
+            nxt = np.asarray(carry["nxt"]).reshape(D, -1, plane)
             counts = np.asarray(carry["nxt_n"]).reshape(-1)
             if counts.sum():
                 rows = np.concatenate(
@@ -1742,8 +1794,8 @@ class ShardedTensorSearch(TensorSearch):
 
             def spill_inject(c, seg, nn):
                 out = dict(c)
-                out["cur"] = jnp.zeros((F, plane),
-                                       jnp.int32).at[:m].set(seg)
+                out["cur"] = jnp.zeros((self._log_words(),), jnp.int32).at[
+                    :m * plane].set(seg.reshape(-1))
                 out["cur_n"] = nn
                 out["j"] = jnp.zeros((1,), jnp.int32)
                 out["evp"] = jnp.zeros((1,), jnp.int32)
@@ -2103,9 +2155,7 @@ class ShardedTensorSearch(TensorSearch):
                     seg = sp.spool_cur.pop()
                     carry, max_n = self._sh_spill_inject(carry, seg)
                     continue
-                carry = self._dispatch(
-                    "sharded.promote",
-                    self._prog("promote", self._finish_level), carry)
+                carry = self._promote_call(carry)
                 if (self.checkpoint_every and self.checkpoint_path
                         and depth % self.checkpoint_every == 0):
                     self._save_checkpoint(carry, depth, time.time() - t0,

@@ -31,6 +31,7 @@ LAB_TEST_MODULES = [
     "tests.test_lab3_paxos",
     "tests.test_lab4_shardmaster",
     "tests.test_lab4_shardstore",
+    "tests.test_lab4_shardstore_tx",
 ]
 
 

@@ -101,8 +101,8 @@ def _table_sized_in_loops(text, limit=1 << 21):
     module: every instruction with ``limit`` or more elements in a
     computation that a ``while`` reaches (body, condition, and what they
     call), less the plumbing that only passes the carry along and less
-    the scatter that updates it in place (the ``scatter`` itself and
-    the fusion around it)."""
+    the write that updates it in place (the ``scatter`` itself and the
+    fusion around it; the frontier log's ``dynamic-update-slice``)."""
     comps, cur = {}, None
     for line in text.splitlines():
         head = re.match(r"(?:ENTRY\s+)?%?([\w.\-]+)\s*\(.*->.*\{\s*$", line)
@@ -140,7 +140,7 @@ def _table_sized_in_loops(text, limit=1 << 21):
             sizes = [int(np.prod([int(d) for d in dims.split(",") if d]))
                      for dims in re.findall(r"\w+\[([\d,]*)\]",
                                             m.group(2))]
-            in_place = m.group(3) == "scatter" or (
+            in_place = m.group(3) in ("scatter", "dynamic-update-slice") or (
                 m.group(3) == "fusion" and any(
                     " scatter(" in x
                     for x in comps.get(called.search(ln).group(1), ())))
@@ -404,22 +404,41 @@ def test_lab4_staged_programs_compile(topo, one_chip, phase, attempt):
     assert "all-to-all" not in exes["superstep"].as_text()
 
 
+def _frontier_sized_moves(text, elements):
+    """``[(instruction, opcode)]`` of an optimised HLO module: every
+    ``copy``, ``transpose`` or ``scatter`` (``copy-start`` too) whose
+    result has ``elements`` or more elements — a whole frontier moved
+    or re-laid."""
+    return [(name, op) for name, shape, op in re.findall(
+        r"%([\w.\-]+) = (\w+\[[\d,]*\])\S* "
+        r"(copy|copy-start|transpose|scatter)\(", text)
+        if _elements(shape) >= elements]
+
+
 def test_shardkv_deep_programs_compile(topo):
     """Lab 4's part 1 twin (the benchmark's ``shardkv-deep`` cell) as
     its driver builds it, at the configuration's caps — a frontier of
     6,815,744 rows a buffer (3.65 GB each at 536 bytes a row) and a
     table of 2^25 slots: superstep, promote and root init compile for
-    one described chip and fit it.  The compiler's plan for the
-    superstep holds FOUR frontier-sized buffers (the carry's ``cur`` and
-    ``nxt`` and the two entry copies of ``nxt``, PERF.md section 7), so
-    its live bytes follow the rows: 14.61 GiB of 15.75 here, 9.46 at
-    2^22 rows, 13.58 at 6,291,456, 15.48 at 7,340,032 — what bounds
-    this cell's headroom for a faster program (the configuration's
-    ``sizing``).  And the dedup layer still probes and writes narrow at
-    these shapes: no gather on the table, and no scatter on the table
-    or on ``nxt``, is handed more than one block of 6,144 indices.
-    Under a minute of compile (the program is a fifth of Paxos'
-    text)."""
+    one described chip and fit it.  Since PR 45 the two frontiers are
+    flat logs of packed words (``s32[(F + K) * 134]``: a 1-D array has
+    ONE layout) and the append a contiguous ``dynamic-update-slice`` of
+    a block, so the compiler's plan for the superstep holds the carry's
+    TWO frontier-sized buffers and nothing of their size beside them:
+    7.74 GiB live, 0.46 GB of it temporaries.  (Until then ``nxt`` was
+    ``s32[6815745,134]``, kept rows-minor by the runtime because 134
+    words are not a multiple of 128 lanes, and the append a row scatter
+    whose dynamic offset the compiler wanted on the MAJOR dimension: the
+    loop carried ``nxt`` lane-padded, 6.98 GB for 3.65, behind a
+    frontier-sized ``copy`` in and another out every dispatch — FOUR
+    frontier-sized buffers, 14.61 GiB of 15.75 with 7.74 GB of
+    temporaries, 9.46 at 2^22 rows, 15.48 at 7,340,032: what bounded
+    this cell's frontier, PERF.md section 4.)  The promote is a swap of
+    the two logs on the host: its program moves no frontier either.
+    And the dedup layer still probes and writes narrow at these shapes:
+    no gather or scatter on the table is handed more than one block of
+    6,144 indices.  Under a minute of compile (the program is a fifth
+    of Paxos' text)."""
     from benchmark.drivers.timeboxed_bfs import build_protocol
     from benchmark.harness import manifest
 
@@ -438,22 +457,31 @@ def test_shardkv_deep_programs_compile(topo):
         cell.config["protocol"]["packed_bytes_per_state"])
     exes = _aot(search)
     _fits(exes)
-    # The margin is 1.14 GiB: the probe's live-block loop (PR 37) added
-    # no frontier- or table-sized buffer to PR 36's 15,689,473,536
-    # bytes (it read 97 KB under them).
-    mem = exes["superstep"].memory_analysis()
-    assert (mem.output_size_in_bytes + mem.temp_size_in_bytes
-            < 15_689_473_536 + (64 << 20)), mem
-    text = exes["superstep"].as_text()
-    assert "all-to-all" not in text
     k = visited.block_width(eng["chunk"] * search._ev_slots)
     assert k == 6144
+    frontier = eng["frontier_cap"] * search.plane
+    log = (eng["frontier_cap"] + k) * search.plane
+    for name in ("superstep", "promote"):
+        mem = exes[name].memory_analysis()
+        assert (max(mem.argument_size_in_bytes, mem.output_size_in_bytes)
+                + mem.temp_size_in_bytes < 9 << 30), (name, mem)
+        assert mem.temp_size_in_bytes < 10 ** 9, (name, mem)
+        text = exes[name].as_text()
+        # both frontiers enter as 1-D logs ...
+        for leaf in ("cur", "nxt"):
+            assert re.search(
+                rf"%c(?:arry)?__{leaf}__\S* = s32\[{log}\]\S* parameter\(",
+                text), (name, leaf)
+        # ... and nothing moves or re-lays a buffer of their size
+        assert _frontier_sized_moves(text, frontier) == [], name
+    text = exes["superstep"].as_text()
+    assert "all-to-all" not in text
+    # the append: a block of K rows written in place into the log
+    assert re.search(
+        rf"= s32\[{log}\]\S* dynamic-update-slice\(", text)
     table = _scatter_widths(text, f"u32[32,{eng['visited_cap'] // 8}]")
-    nxt = _scatter_widths(
-        text, f"s32[{eng['frontier_cap'] + 1},{search.plane}]")
     reads = _gather_widths(text, f"u32[32,{eng['visited_cap'] // 8}]")
     assert table and max(table) <= k, table
-    assert nxt and max(nxt) <= k, nxt
     assert reads and max(reads) <= k, reads
     assert _table_sized_in_loops(text, limit=1 << 27) == []
 
@@ -463,15 +491,17 @@ def test_shardkv_n3_deep_programs_compile(topo):
     """Lab 4's multi-server twin (the benchmark's ``shardkv-n3-deep``
     cell, ``setupStates(2, 3, 1, 10)``) as its driver builds it, at the
     configuration's caps: superstep, promote and root init compile for
-    one described chip, and the superstep's plan is what the
-    configuration's ``sizing`` records — four frontier-sized buffers of
-    1,507,328 rows of 1,792 bytes (10.8 GB), the 2^24-slot table and
-    4.1 GiB of chunk temporaries (49,152 successors of 1,272 lanes:
-    this twin's rows are 2.9 times ``shardkv-deep``'s), 14.10 GiB of
-    15.75 (14.47 before the group log carried its catch-up handlers;
-    one variant of them compiled with ONE entry copy of ``nxt``, 11.64
-    GiB: the plan follows the handler table, and the cap is sized for
-    two copies).  Five minutes of compile here (28 MB of optimised
+    one described chip.  Since PR 45 the superstep's plan is the
+    carry's TWO frontier logs of 1,507,328 (+ 6,144 slack) rows of
+    1,792 bytes (5.42 GB), the 2^24-slot table and 2.53 GB of chunk
+    temporaries (49,152 successors of 1,272 lanes: this twin's rows are
+    2.9 times ``shardkv-deep``'s): 7.66 GiB of 15.75, and no ``copy``,
+    ``transpose`` or ``scatter`` of a frontier's size.  The
+    configuration's ``sizing`` still records the plan the cap was sized
+    under — FOUR frontier-sized buffers (the carry's two and both entry
+    copies of a row-scattered ``nxt``), 10.8 GB, with 4.1 GiB of
+    temporaries, 14.10 GiB; a ``benchmark`` PR's to restate (PERF.md
+    section 7).  Five minutes of compile here (28 MB of optimised
     text): ``-m slow``."""
     from benchmark.drivers.timeboxed_bfs import build_protocol
     from benchmark.harness import manifest
@@ -493,12 +523,14 @@ def test_shardkv_n3_deep_programs_compile(topo):
     mem = exes["superstep"].memory_analysis()
     live = (max(mem.argument_size_in_bytes, mem.output_size_in_bytes)
             + mem.temp_size_in_bytes)
-    assert live == pytest.approx(
-        cell.config["sizing"]["bytes"]["superstep_live_by_memory_analysis"],
-        abs=64 << 20)
-    assert live < 14.6 * (1 << 30)
+    assert live == pytest.approx(8_219_662_848, abs=64 << 20)
+    # ... 6 GiB and more under the plan the configuration was sized for
+    assert live + (6 << 30) < cell.config["sizing"]["bytes"][
+        "superstep_live_by_memory_analysis"]
     text = exes["superstep"].as_text()
     assert "all-to-all" not in text
+    assert _frontier_sized_moves(
+        text, eng["frontier_cap"] * search.plane) == []
     # the handlers' operations name their fragment in the chip's text
     assert "dslabs.expand.handlers.gpaxos" in text
     assert "dslabs.expand.handlers.spec" in text

@@ -213,12 +213,13 @@ def test_level_records_on_outcome():
 
 # ------------------------------------------------------- write blocks
 
-def _tree_protocol(branch=6, depth=4):
+def _tree_protocol(branch=6, depth=4, goal=None):
     """A tree: delivering standing message ``i`` in state ``v`` gives
     ``v * branch + i + 1`` down to ``depth``, so every successor of a
     first visit is a state nobody has seen and a level numbers its
     states ``lo .. hi`` in grid order — the traffic of a search's first
-    levels, at a width where one chunk step appends several blocks."""
+    levels, at a width where one chunk step appends several blocks.
+    ``goal``: the state whose discovery ends the search."""
     import jax.numpy as jnp
     import numpy as np
 
@@ -242,7 +243,9 @@ def _tree_protocol(branch=6, depth=4):
         init_timers=lambda: np.zeros((0, 2), np.int32),
         step_message=step_message,
         step_timer=lambda nodes, node_idx, timer: (nodes, no_send, no_set),
-        msg_dest=lambda msg: jnp.int32(0))
+        msg_dest=lambda msg: jnp.int32(0),
+        goals=({} if goal is None
+               else {"LEAF": lambda s: s["nodes"][0] == goal}))
 
 
 def _run_watched(proto, n_devices, **kw):
@@ -259,10 +262,12 @@ def _run_watched(proto, n_devices, **kw):
         carry, stats = inner(carry, budget)
         counts = np.asarray(carry["nxt_n"]).reshape(-1)
         kept = {"visited": np.asarray(carry["visited"])}
-        for name in ("nxt", "tmeta"):
+        for name, width in (("nxt", search.plane), ("tmeta", 9)):
             if name in carry:
+                # nxt: a device's log of packed words (rows past
+                # f_cap are its slack); tmeta: [f_cap + 1, 9].
                 buf = np.asarray(carry[name]).reshape(
-                    n_devices, search.f_cap + 1, -1)
+                    n_devices, -1, width)
                 kept[name] = [buf[d, :min(int(c), search.f_cap)]
                               for d, c in enumerate(counts)]
         after.append(kept)
@@ -332,6 +337,111 @@ def test_append_blocks_equal_one_whole_batch_scatter(
         _assert_exact(out, TensorSearch(
             proto, chunk=chunk, frontier_cap=n_devices * frontier_cap,
             visited_cap=1 << 14, use_host_visited=True).run())
+
+
+# What the engine gave while ``nxt`` was ``[F + 1, plane]`` and the append
+# a row scatter (the parent of PR 45, commit ade35e1), on the tree of
+# branch 7 and depth 5 (levels of 7, 49, 343, 2,401 and 16,807 states)
+# with the leaf 19,607 — the last child of level 4's last row — as goal
+# where the trace is recorded: ``(chunk, frontier_cap)``, the run's end,
+# unique, explored and dropped states, per level ``(explored, unique,
+# next_frontier, write_blocks, chunks)``, the witness, and after every
+# promote each device's ``cur_n`` and the CRC-32 of the promoted states,
+# sorted, as int64.
+_LOG_LEVELS = {
+    1: [(7, 8, 7, 2, 1), (56, 57, 49, 2, 1), (399, 400, 343, 3, 1),
+        (2800, 2801, 2401, 17, 1), (19607, 19608, 3200, 114, 7),
+        (42007, 19608, 0, 0, 8)],
+    4: [(7, 8, 4, 2, 1), (56, 57, 18, 2, 1), (399, 400, 94, 3, 1),
+        (2800, 2801, 614, 5, 1), (19607, 19608, 1224, 26, 3),
+        (53879, 19608, 0, 0, 4)]}
+_LOG_FRONTS = {
+    1: [([7], 1602182657), ([49], 4002130850), ([343], 4091600918),
+        ([2401], 3287508765), ([3200], 1698545458), ([0], 0)],
+    4: [([2, 4, 0, 1], 1602182657), ([18, 9, 12, 10], 4002130850),
+        ([89, 94, 85, 75], 4091600918),
+        ([605, 599, 583, 614], 3287508765),
+        ([1224, 1224, 1224, 1224], 3049208788), ([0, 0, 0, 0], 0)]}
+_LOG_PARENT = {
+    # (n_devices, record_trace): shape, end, unique, explored, dropped
+    (1, False): ((400, 3200), "SPACE_EXHAUSTED", 19608, 42007, 13607),
+    (1, True): ((400, 3200), "GOAL_FOUND", 19608, 19607, 13607),
+    (4, False): ((306, 1224), "SPACE_EXHAUSTED", 19608, 53879, 26567),
+    (4, True): ((306, 1224), "GOAL_FOUND", 19608, 19607, 11911)}
+# Strict mode raises at level 5's first dispatch that counts a drop.
+_LOG_STRICT_DROPS = {1: 13607, 4: 11911}
+
+
+@pytest.mark.parametrize("strict", [False, True], ids=["beam", "strict"])
+@pytest.mark.parametrize("record_trace", [False, True],
+                         ids=["plain", "record-trace"])
+@pytest.mark.parametrize("n_devices", [1, 4])
+def test_log_appends_equal_the_parents_row_scatter(
+        n_devices, record_trace, strict):
+    """The next frontier as a flat word log appended by contiguous
+    block writes (PR 45) against the numbers of the parent commit, in a
+    search whose levels append every shape of block.  One device, chunk
+    400 (``K`` = 400 of 3,200 grid slots): level 4 appends 2,401 rows =
+    6 ``K`` + ONE ROW MORE (a seventh block of one live row, then 399
+    rows of garbage that level 5 must never read); level 5's first
+    chunk step appends 2,800 = exactly 7 ``K``, its second crosses
+    ``frontier_cap`` = 3,200 inside its first block (400 rows land, the
+    rest falls in the log's slack), every later one starts past it.
+    Four devices, chunk 306 (``K`` = 613 of the 4,904 rows a device
+    receives): the owners' shares are the hash's — 605, 599, 583 and
+    614 = ``K`` + 1 at level 4 — and level 5 crosses 1,224 rows a
+    device.  Per-level counts, drops, the promoted ``cur[:cur_n]`` as a
+    SET of states and the witness are the parent's; strict mode raises
+    at the level that drops, the levels before it equal."""
+    import zlib
+
+    import numpy as np
+
+    from dslabs_tpu.tpu.engine import CapacityOverflow
+
+    (chunk, cap), end, unique, explored, dropped = _LOG_PARENT[
+        n_devices, record_trace]
+    search = ShardedTensorSearch(
+        _tree_protocol(7, 5, goal=19607 if record_trace else None),
+        make_mesh(n_devices), chunk_per_device=chunk, frontier_cap=cap,
+        visited_cap=1 << 15, strict=strict, record_trace=record_trace)
+    promote, fronts = search._finish_level, []
+
+    def watched(carry):
+        carry = promote(carry)
+        counts = np.asarray(carry["cur_n"]).reshape(-1)
+        rows = np.asarray(carry["cur"]).reshape(
+            n_devices, -1, search.plane)
+        rows = np.concatenate([rows[d, :c] for d, c in enumerate(counts)])
+        if search._pk is not None:
+            rows = search._pk.unpack_np(rows)
+        fronts.append((counts.tolist(), zlib.crc32(
+            np.sort(rows[:, 0]).astype(np.int64).tobytes())))
+        return carry
+
+    search._finish_level = watched
+    if strict:
+        with pytest.raises(CapacityOverflow, match=(
+                f"{_LOG_STRICT_DROPS[n_devices]} capacity drops at "
+                "depth 5")):
+            search.run()
+        assert fronts == _LOG_FRONTS[n_devices][:4]
+        return
+    out = search.run()
+    assert (out.end_condition, out.unique_states, out.states_explored,
+            out.dropped) == (end, unique, explored, dropped)
+    levels = [(r["explored"], r["unique"], r["next_frontier"],
+               r["write_blocks"], r["chunks"]) for r in out.levels]
+    # (A goal ends the run inside level 5: its record is not kept.)
+    want = _LOG_LEVELS[n_devices]
+    assert levels == (want[:4] if record_trace else want)
+    assert fronts == _LOG_FRONTS[n_devices][:len(levels)]
+    if record_trace:
+        assert out.trace == [6] * 5
+        state = 0
+        for event in out.trace:
+            state = state * 7 + event + 1
+        assert state == int(out.goal_state["nodes"].reshape(-1)[0]) == 19607
 
 
 # ------------------------------------------------- mid-level time budget
