@@ -3,7 +3,7 @@
 
 PY ?= python
 
-.PHONY: test test-fast lint analysis-smoke perf-smoke fault-smoke swarm-smoke capacity-smoke capacity2-smoke obs-smoke chaos-smoke service-smoke trace-smoke mesh-smoke lanes-smoke memo-smoke scenario-smoke spec-smoke lab0 lab1 lab2 lab3 lab4 bench dryrun handout clean
+.PHONY: test test-fast lint analysis-smoke perf-smoke fault-smoke swarm-smoke capacity-smoke capacity2-smoke obs-smoke chaos-smoke service-smoke trace-smoke mesh-smoke lanes-smoke memo-smoke scenario-smoke spec-smoke lab0 lab1 lab2 lab3 lab4 dryrun handout clean
 
 test:            ## full acceptance + parity suite
 	$(PY) -m pytest tests/ -q
@@ -34,9 +34,6 @@ lint:            ## soundness sanitizer: conformance linter + jaxpr auditor
 analysis-smoke:  ## sanitizer suite (red fixtures per rule + shipped-tree clean pin) on CPU
 	JAX_PLATFORMS=cpu $(PY) -m pytest tests/ -q -m analysis -p no:cacheprovider
 	$(PY) -m dslabs_tpu.analysis all
-
-bench:           ## TPU states/min benchmark (one JSON line)
-	$(PY) bench.py
 
 # perf-smoke = the BASELINE.json states/min floor PLUS the dry-run
 # 8-virtual-device superstep-vs-host-reference parity gate (exact unique/
@@ -72,9 +69,7 @@ swarm-smoke:     ## swarm explorer suite incl. slow deep-narrow scenarios, on CP
 # visited table capped at ~1/8 of the state count (single-device AND
 # sharded engines), SIGKILL-mid-spill resume parity, the supervisor's
 # CapacityOverflow->spill-retry capacity ladder, spill-dispatch fault
-# injection, and the foreign-checkpoint refusal — plus the bench's
-# `--spill` phase shape (states/min at 1/8 capacity vs uncapped) via
-# `python bench.py --spill` if you want the number itself.
+# injection, and the foreign-checkpoint refusal.
 capacity-smoke:  ## host-RAM spill tier + capacity-ladder suite on CPU
 	JAX_PLATFORMS=cpu $(PY) -m pytest tests/ -q -m capacity -p no:cacheprovider
 
@@ -88,8 +83,7 @@ capacity-smoke:  ## host-RAM spill tier + capacity-ladder suite on CPU
 # cross-resume conversion/refusal, the symmetry-reduced paxos quotient
 # (pinned canonical counts, verdict parity, replay-verified witness),
 # and the async drain's exactness + overlap accounting — PLUS the
-# packed end-to-end leg of tools/obs_smoke.py (STATUS capacity block +
-# the ledger's capacity:bytes_per_state guard rc 0/1 both ways).
+# packed end-to-end leg of tools/obs_smoke.py (STATUS capacity block).
 capacity2-smoke: ## capacity round 2: packed encoding + symmetry reduction + async spill, on CPU
 	JAX_PLATFORMS=cpu $(PY) -m pytest tests/ -q -m capacity2 -p no:cacheprovider
 	JAX_PLATFORMS=cpu $(PY) tools/obs_smoke.py
@@ -100,14 +94,11 @@ capacity2-smoke: ## capacity round 2: packed encoding + symmetry reduction + asy
 # writer enabled), per-device skew lanes on the 8-device mesh, SIGKILL
 # flight-log survival with the in-flight dispatch named, the
 # report-CLI golden sections + --json schema pin, the live-monitor
-# watch view, the bench-ledger compare, supervisor retry/failover
-# event plumbing, and the bench-JSON schema pin for the `telemetry`
-# block + error-with-spans shape (the slow bench run tier-1 skips) —
-# PLUS the CLI end-to-end steps via tools/obs_smoke.py: `telemetry
-# watch --once` on a finished run and `telemetry compare` on a parity
-# ledger and an injected-regression ledger.  docs/observability.md is
+# watch view, and supervisor retry/failover event plumbing — PLUS the
+# CLI end-to-end steps via tools/obs_smoke.py: `telemetry watch --once`
+# and `telemetry report` on a finished run.  docs/observability.md is
 # the field guide.
-obs-smoke:       ## unified telemetry suite (flight recorder / metrics / reports / watch / ledger) on CPU
+obs-smoke:       ## unified telemetry suite (flight recorder / metrics / reports / watch) on CPU
 	JAX_PLATFORMS=cpu $(PY) -m pytest tests/ -q -m obs -p no:cacheprovider
 	JAX_PLATFORMS=cpu $(PY) tools/obs_smoke.py
 
@@ -147,8 +138,7 @@ service-smoke:   ## multi-tenant checking service suite (queue / admission / fai
 # still renders the full causal chain from disk alone and names the
 # in-flight dispatch), per-tenant COSTS.jsonl sums agreeing with the
 # jobs' SearchOutcome counters exactly, torn SERVER_STATUS/COSTS
-# reads, the run-dir retention sweep, and the compile-creep /
-# cost-per-unique ledger-compare guards — then the trace-assembler
+# reads, and the run-dir retention sweep — then the trace-assembler
 # leg of tools/obs_smoke.py (the CLI end to end).
 # docs/observability.md "Tracing a job end-to-end" is the field guide.
 trace-smoke:     ## causal tracing + cost-ledger suite (assembler / COSTS / retention) on CPU
@@ -165,9 +155,8 @@ trace-smoke:     ## causal tracing + cost-ledger suite (assembler / COSTS / rete
 # visited-table bit-exact parity (incl. the table-full overflow
 # contract) standalone AND through a full sharded search, the
 # cross-width checkpoint resume chain 8->4->2->1, first-class carry
-# placement (partition rules -> NamedSharding everywhere), and the
-# bench --mesh phase schema — all on the CPU virtual 8-device mesh, no
-# TPU hardware needed.  ISSUE 18 adds the packed-wire suite
+# placement (partition rules -> NamedSharding everywhere) — all on the
+# CPU virtual 8-device mesh, no TPU hardware needed.  ISSUE 18 adds the packed-wire suite
 # (tests/test_mesh_packing.py): packed-vs-raw exchange parity across
 # widths {1,2,4,8} + the >= 8x wire bytes-per-state floor, the
 # delta-lane (varint) pb parity, cross-width resume through the packed
@@ -186,11 +175,10 @@ mesh-smoke:      ## owner-sharded superstep width-parity + packed-wire suite on 
 # (4-lane batch <= 0.5x the 4-solo dispatch count), SIGKILL-mid-batch
 # per-lane checkpoint resume through the LaneBatchWarden child,
 # poisoned-lane eviction leaving neighbors bit-exact, per-tenant
-# COSTS sums across a batched drain == the solo drain's, the lane
-# compare guards, and the solo-path overhead guard (lanes off = solo
-# dispatch/device_get counts untouched) — all CPU, no TPU needed.
-# PLUS the lanes leg of tools/obs_smoke.py (bench phase schema +
-# compare guards end-to-end).  docs/service.md "Batched job lanes"
+# COSTS sums across a batched drain == the solo drain's, and the
+# solo-path overhead guard (lanes off = solo dispatch/device_get counts
+# untouched) — all CPU, no TPU needed.  PLUS the lanes leg of
+# tools/obs_smoke.py (a lane batch's STATUS.json through `watch`).  docs/service.md "Batched job lanes"
 # is the field guide.
 lanes-smoke:     ## batched job lanes: parity matrix + continuous batching + resume + cost split on CPU
 	JAX_PLATFORMS=cpu $(PY) -m pytest tests/ -q -m lanes -p no:cacheprovider
@@ -205,9 +193,8 @@ lanes-smoke:     ## batched job lanes: parity matrix + continuous batching + res
 # strict/beam x packed on/off sweep and SIGKILL-mid-warm-start
 # resume), stale-verdict impossibility, the 3-tenant <10% resubmit
 # billing pin, and the memo-off overhead guard — all CPU.  PLUS the
-# memo leg of tools/obs_smoke.py (bench --memo schema + the
-# memo:hit_rate compare guard rc 0/1 both ways).  docs/memo.md is
-# the field guide.
+# memo leg of tools/obs_smoke.py (one job drained twice: the second
+# is a journaled memo_hit).  docs/memo.md is the field guide.
 memo-smoke:      ## cross-job memoization: verdict cache + warm start + incremental re-check parity on CPU
 	JAX_PLATFORMS=cpu $(PY) -m pytest tests/ -q -m memo -p no:cacheprovider
 	JAX_PLATFORMS=cpu $(PY) tools/obs_smoke.py

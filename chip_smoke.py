@@ -15,11 +15,11 @@ back to the CPU.  Phases, each printing one JSON line:
                  equal verdict and unique count, a violating predicate
                  whose witness replays on the object twin, and one
                  ``dfs`` call (rollout probe, then BFS).
-2. ``flagship``  the bench protocol (lab 3 multi-Paxos: 3 replicas, 2
+2. ``flagship``  the flagship protocol (lab 3 multi-Paxos: 3 replicas, 2
                  clients, 842 lanes, net_cap 64, timer_cap 6) under the
                  strict sharded engine at the flagship caps — one
                  construction, two runs: depth 6 to DEPTH_EXHAUSTED with
-                 a pinned unique count, then depth 10 / 60 s (printed,
+                 a pinned unique count, then depth 9 / 60 s (printed,
                  not judged).
 3. ``warm``      a second construction of the flagship search whose
                  compile seconds show that its programs were LOADED —
@@ -53,31 +53,38 @@ if ROOT not in sys.path:
 
 # ---------------------------------------------------------------- constants
 #
-# The flagship caps are bench.py's strict phase (frontier, visited
-# table, event window) with ONE difference, the chunk: at chunk 8192
-# the TPU compiler refuses the superstep — 42.16 GB of HBM against the
-# chip's 15.75 GB, almost all of it [chunk*48, 1] uint32 columns padded
-# 128x by the (8, 128) tile (CHANGES.md, PR 22, has the timings and
-# memory per chunk).  A chunk is a batch size, not a width: the
-# protocol's shape and every cap are bench.py's.  1024 is the largest
-# power of two whose carry + temporaries fit one v5e chip.
+# The flagship caps are the `engine` block of
+# benchmark/configs/lab3-paxos-n3c2.json (chunk, frontier, visited
+# table, event window), the configuration of the cells paxos3-deep and
+# paxos3-deep-mesh4.  Why the chunk is 1024: until PR 32 the TPU
+# compiler refused the superstep at chunk 8192 — 42.16 GB of HBM against
+# the chip's 15.75 GB, almost all of it [chunk*48, 1] uint32 columns
+# padded 128x by the (8, 128) tile (CHANGES.md, PR 22, has the timings
+# and memory per chunk).  It compiles there now
+# (tests/test_chip_compile.py, -m slow), but no chip run has timed a
+# larger chunk.  A chunk is a batch size, not a width.
 FLAGSHIP = dict(chunk=1024, frontier_cap=(1 << 20) + (1 << 18),
                 visited_cap=1 << 24)
-# bench.py's FALLBACK_EV_BUDGET — the (message, timer) event window the
-# strict path compiles when no calibration is at hand; strict runs
-# re-step over-budget chunks, so it is a throughput knob, never a bound
-# on correctness.
+# That file's `ev_budget` — the (message, timer) event window the
+# strict path compiles; strict runs re-step over-budget chunks, so it
+# is a throughput knob, never a bound on correctness.
 EV_BUDGET = (40, 8)
-# Unique states of the bench protocol after BFS depth d, strict (exact,
-# so independent of device, chunk and mesh width).  Both are the OBJECT
-# checker's counts (search.BFS on the lab 3 PaxosServer x3 + two
-# one-PUT clients, max_depth d: 8 / 38 / 162 / 713 / 3,258 / 15,102 /
-# 69,673 at depths 1-7; depth 6 took 45 s, depth 7 250 s, PR 22), and
-# the CPU run of the same tensor engine gives the same series.
+# Unique states of the flagship protocol after BFS depth d, strict
+# (exact, so independent of device, chunk and mesh width).  Both are
+# the OBJECT checker's counts (search.BFS on the lab 3 PaxosServer x3 +
+# two one-PUT clients, max_depth d: 8 / 38 / 162 / 713 / 3,258 /
+# 15,102 / 69,673 at depths 1-7; depth 6 took 45 s, depth 7 250 s,
+# PR 22), and the CPU run of the same tensor engine gives the same
+# series.
 FLAGSHIP_UNIQUE = {6: 15102, 7: 69673}
 FLAGSHIP_DEPTH = 6
 FOUR_CHIP_DEPTH = 7
-DEEP_DEPTH, DEEP_SECS = 10, 60.0
+# Depth 9 is the deepest level these caps hold whole (the cell
+# paxos3-deep completes it and is stopped by its clock inside depth 10,
+# whose frontier passes frontier_cap): a bound by depth holds at any
+# speed, where "depth 10 under 60 s" overflowed once the engine reached
+# depth 10 inside the minute (PR 46's chip run: CapacityOverflow).
+DEEP_DEPTH, DEEP_SECS = 9, 60.0
 
 
 _T0 = time.time()
@@ -294,16 +301,31 @@ def lab_phase(num_clients: int = 2, rounds: int = 2) -> dict:
 
 # ----------------------------------------------------------- flagship phases
 
+def flagship_protocol():
+    """The `protocol` block of benchmark/configs/lab3-paxos-n3c2.json
+    (``tests/test_chip_smoke.py`` holds the two together)."""
+    import dataclasses
+
+    from dslabs_tpu.tpu.specs_lab3 import make_paxos_protocol
+
+    # Two clients widen the space enough to sustain large frontiers.
+    # Goals are stripped: the phases judge exploration to a depth
+    # against pinned counts, and a run that hit CLIENTS_DONE would end
+    # early with a verdict the counts say nothing about.
+    protocol = make_paxos_protocol(n=3, n_clients=2, w=1, max_slots=3,
+                                   net_cap=64, timer_cap=6)
+    return dataclasses.replace(protocol, goals={})
+
+
 def _flagship_supervisor(mesh, chunk, frontier_cap, visited_cap,
                          max_depth):
-    """bench.py's strict phase: the bench protocol under the search
-    supervisor, ladder = sharded only (a failover would change what is
-    being proven), AOT warm-up on."""
-    from bench import _bench_protocol
+    """The flagship protocol under the search supervisor, as the cell
+    paxos3-deep builds it: ladder = sharded only (a failover would
+    change what is being proven), AOT warm-up on."""
     from dslabs_tpu.tpu.supervisor import RetryPolicy, SearchSupervisor
 
     return SearchSupervisor(
-        _bench_protocol(), ladder=("sharded",), mesh=mesh, chunk=chunk,
+        flagship_protocol(), ladder=("sharded",), mesh=mesh, chunk=chunk,
         frontier_cap=frontier_cap, visited_cap=visited_cap,
         max_depth=max_depth, strict=True, ev_budget=EV_BUDGET,
         policy=RetryPolicy(max_retries=3), aot_warmup=True)
@@ -377,7 +399,6 @@ def warm_phase(chunk: int, frontier_cap: int, visited_cap: int,
     own cache-hit events), and where the ``first`` construction
     (``flagship_phase``'s record) was cold its compile seconds must
     drop to under half."""
-    from bench import _bench_protocol
     from dslabs_tpu.tpu import compile_cache
     from dslabs_tpu.tpu.sharded import ShardedTensorSearch, make_mesh
 
@@ -385,7 +406,7 @@ def warm_phase(chunk: int, frontier_cap: int, visited_cap: int,
     _hb("warm: second construction")
     with _cache_events() as events:
         search = ShardedTensorSearch(
-            _bench_protocol(), make_mesh(1), chunk_per_device=chunk,
+            flagship_protocol(), make_mesh(1), chunk_per_device=chunk,
             frontier_cap=frontier_cap, visited_cap=visited_cap,
             max_depth=2, strict=True, ev_budget=EV_BUDGET,
             aot_warmup=True)

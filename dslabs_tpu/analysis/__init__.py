@@ -27,7 +27,6 @@ exceptions.  docs/analysis.md is the field guide; ``make lint`` and
 from __future__ import annotations
 
 import json as _json
-import os
 import sys
 from typing import List, Optional, Sequence
 
@@ -38,7 +37,7 @@ from dslabs_tpu.analysis.core import (Finding, RULES, Waiver,  # noqa: F401
 
 __all__ = ["Finding", "Waiver", "RULES", "load_waivers", "apply_waivers",
            "render_findings", "default_waiver_path", "run_conformance",
-           "run_jaxpr", "run_all", "sanitizer_summary", "main"]
+           "run_jaxpr", "run_all", "main"]
 
 
 def run_conformance(paths: Optional[Sequence[str]] = None,
@@ -71,28 +70,6 @@ def run_all(paths: Optional[Sequence[str]] = None,
             waivers: Optional[str] = None) -> List[Finding]:
     return (run_conformance(paths, waivers=waivers)
             + run_jaxpr(waivers=waivers))
-
-
-def sanitizer_summary(timeout: int = 180) -> dict:
-    """The bench's ``sanitizer`` block (ISSUE 10 satellite): findings
-    per leg + waived count, computed in a CPU-pinned SUBPROCESS so the
-    bench parent never imports jax or touches the accelerator.  Never
-    raises; failures come back as ``{"error": ...}``."""
-    import subprocess
-
-    env = dict(os.environ, JAX_PLATFORMS="cpu")
-    try:
-        proc = subprocess.run(
-            [sys.executable, "-m", "dslabs_tpu.analysis", "all",
-             "--json"],
-            capture_output=True, text=True, timeout=timeout,
-            cwd=repo_root(), env=env)
-        data = _json.loads(proc.stdout.strip().splitlines()[-1])
-        return {"conformance": data["conformance"],
-                "jaxpr": data["jaxpr"], "waived": data["waived"],
-                "findings": data["findings"]}
-    except Exception as e:  # noqa: BLE001 — the bench JSON must land
-        return {"error": f"{type(e).__name__}: {e}"}
 
 
 # ------------------------------------------------------------------ CLI

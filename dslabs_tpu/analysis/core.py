@@ -18,8 +18,8 @@ e.g. ::
 
 ``<CODE>`` is a rule code or ``*``; ``<target-glob>`` is an
 ``fnmatch`` pattern over ``Finding.target``.  A waived finding still
-prints (marked ``waived``) but does not fail the CLI / the compile
-gate / the bench sanitizer block — the waiver IS the documentation of
+prints (marked ``waived``) but does not fail the CLI or the compile
+gate — the waiver IS the documentation of
 the justified exception (docs/analysis.md).
 """
 

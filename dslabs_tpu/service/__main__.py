@@ -6,7 +6,7 @@ returns (the structured accept/reject line on stdout), a later
 backlog under the scheduler, and ``status`` renders SERVER_STATUS.json
 plus the journal summary without touching either.  Every subcommand
 prints exactly one JSON line on stdout (stderr is free-form), so the
-CLI composes with scripts the same way bench.py does.
+CLI composes with scripts.
 """
 
 from __future__ import annotations

@@ -28,9 +28,8 @@ submission order (the soak's isolation proof depends on that):
     failure — retrying a deterministic failure buys nothing, the job
     lands a structured failure verdict instead.
 
-``fairness_index`` is the bench/ledger metric: max over tenants of
-verdicts-per-budget divided by the mean (1.0 = perfectly fair; the
-ledger compare flags a rise past the threshold — telemetry.py).
+``fairness_index`` is the drain's fairness metric: max over tenants of
+verdicts-per-budget divided by the mean (1.0 = perfectly fair).
 """
 
 from __future__ import annotations
@@ -225,10 +224,10 @@ class DeficitRoundRobin:
 
 
 def fairness_index(per_tenant: Dict[str, dict]) -> float:
-    """max/mean of per-tenant verdicts-per-budget — the metric the
-    bench's ``service`` phase reports and ``telemetry compare`` tracks.
-    1.0 = perfectly fair; a rising index means some tenant converts
-    budget into verdicts disproportionately (a starved neighbor).
+    """max/mean of per-tenant verdicts-per-budget, reported by every
+    drain (``CheckServer.drain``'s summary).  1.0 = perfectly fair; a
+    rising index means some tenant converts budget into verdicts
+    disproportionately (a starved neighbor).
     Tenants that spent no budget are excluded; no data = 1.0."""
     rates = []
     for stats in per_tenant.values():

@@ -183,7 +183,7 @@ class CheckServer:
                      else _env_int("DSLABS_SERVICE_KEEP", 64))
         # Optional parent-side telemetry recorder: retention prunes and
         # scheduler-level events become flight-log events when one is
-        # attached (the bench's service phase does).
+        # attached.
         self.telemetry = telemetry
         # ONE job child at a time by default: every job child takes
         # the chip, and a chip belongs to one process at a time.
@@ -1009,19 +1009,17 @@ class CheckServer:
             "fairness_index": fairness_index(per_tenant),
             # Lane amortisation (ISSUE 14): packing decisions + the
             # mean dispatches billed per job (share-scaled across lane
-            # batches), the number the ledger compare guards.
+            # batches).
             "lanes": self._lane_block(),
             # Cross-job reuse (ISSUE 16): hits / warm starts /
-            # incremental re-checks and the device-seconds they saved
-            # — the multiplier the ledger compare guards.
+            # incremental re-checks and the device-seconds they saved.
             "memo": (self.memo.stats_block() if self.memo is not None
                      else {"enabled": False}),
             "dispatches_per_job": totals.get("dispatches_per_job"),
             "per_tenant": per_tenant,
             # The cost ledger's view (tpu/tracing.py CostMeter):
             # per-tenant device-seconds / dispatches / compile split /
-            # cost-per-unique-state, and the aggregate headline the
-            # ledger compare tracks.
+            # cost-per-unique-state, and the aggregate headline.
             "costs": self.costs.tenant_summary(),
             "cost_per_unique": totals.get("cost_per_unique"),
             "device_secs": totals.get("device_secs"),

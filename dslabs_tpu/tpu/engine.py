@@ -1464,8 +1464,7 @@ class TensorSearch:
 
     def _stamp_capacity(self, out: "SearchOutcome") -> "SearchOutcome":
         """Attach the capacity-round-2 accounting every verdict
-        carries (bench/STATUS render it; telemetry compare guards
-        bytes_per_state)."""
+        carries (STATUS.json renders it)."""
         out.bytes_per_state = self.bytes_per_state
         out.bytes_per_state_unpacked = self.lanes * 4
         out.pack_ratio = round(

@@ -585,8 +585,8 @@ class LaneSearch(TensorSearch):
         # ---- the level loop: superstep -> sync -> per-lane verdict
         # extraction -> masked promote -> per-lane checkpoints ->
         # swap-ins.  One superstep + one promote per LEVEL for the
-        # whole batch — the amortisation the bench's
-        # dispatches-per-job phase measures.
+        # whole batch — the dispatches-per-job amortisation
+        # tests/test_lanes.py pins.
         tel = getattr(self, "_telemetry", None)
         while True:
             active = [ln for ln in lanes if ln is not None and ln.active]

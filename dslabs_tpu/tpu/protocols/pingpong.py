@@ -149,9 +149,9 @@ def make_exhaustive_pingpong(workload_size: int = 2) -> TensorProtocol:
     """The goal-pruned exhaustive variant: CLIENTS_DONE becomes a prune
     so a strict search measures full-space parity instead of a
     first-goal race — the canonical small JOB UNIT the checking
-    service, its chaos-isolation soak, and the bench's ``service``
-    phase all submit (a ``"module:callable"`` factory spec that crosses
-    the warden spawn boundary with no transform needed)."""
+    service and its chaos-isolation soak submit (a
+    ``"module:callable"`` factory spec that crosses the warden spawn
+    boundary with no transform needed)."""
     import dataclasses
 
     p = make_pingpong_protocol(workload_size)

@@ -1915,9 +1915,8 @@ class ShardedTensorSearch(TensorSearch):
                                 else "identity descriptor"),
                         wire_lanes=self.lanes)
             if out.dropped and out.dropped >= _DROPPED_WARN():
-                # The BENCH_r03 shape (5.8M beam drops, one flag to
-                # show for it) must be LOUD — dropped_states is also a
-                # first-class bench JSON field now.
+                # Millions of beam drops with one flag to show for
+                # them (an early chip run's shape) must be LOUD.
                 import warnings
 
                 warnings.warn(

@@ -4,9 +4,9 @@ ROADMAP item #4 ("bigger-than-HBM searches").  Before this module the
 device visited table and the frontier buffer were hard walls: a strict
 search that crossed either raised :class:`CapacityOverflow` and the
 failover ladder could not help (smaller rungs have LESS capacity), and
-a beam search silently narrowed (BENCH_r03 dropped 5.8M states with
-only a flag to show for it).  This module turns both walls into the
-classic explicit-state tiering trick (disk-based / hash-compaction
+a beam search silently narrowed (an early chip run dropped millions of
+states with only a flag to show for it).  This module turns both walls
+into the classic explicit-state tiering trick (disk-based / hash-compaction
 checkers a la Stern & Dill): cold state moves OFF the fast device onto
 host RAM, and "full" degrades to "slower, still exact".
 

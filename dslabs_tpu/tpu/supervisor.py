@@ -22,8 +22,7 @@ production training/inference stack assumes:
   each dispatch runs on a watchdog thread; a dispatch exceeding its
   deadline (wedged device) is ABANDONED — :class:`DispatchTimeout`,
   classified wedged, no retry — and the supervisor restarts on the
-  next rung from the last checkpoint.  ``bench.py``'s wedged-TPU
-  preflight is a thin client (:func:`probe_device`).
+  next rung from the last checkpoint.
 * **Engine failover ladder.**  :class:`SearchSupervisor` runs the
   search on the first healthy rung of ``sharded -> device -> host``
   (the host loop is the parity oracle — every rung has identical
@@ -97,7 +96,7 @@ __all__ = ["TransientDeviceError", "DispatchTimeout", "EngineFailure",
            "SupervisorExhausted", "RetryPolicy", "FaultRule", "FaultPlan",
            "DispatchBoundary", "SearchSupervisor", "classify_failure",
            "classify_oom", "classify_child_death", "CHILD_RC_FAILED",
-           "expand_ladder", "install_retry", "probe_device"]
+           "expand_ladder", "install_retry"]
 
 # In-process watchdog abandonment LEAKS a blocked daemon thread (a
 # wedged XLA runtime cannot be interrupted from Python).  Past this many
@@ -423,8 +422,8 @@ class DispatchBoundary:
         # Watchdog-abandoned daemon threads (the in-process mode's
         # unavoidable leak: a wedged XLA dispatch cannot be interrupted
         # from Python, only abandoned).  Tracked so the degradation is
-        # VISIBLE — SearchOutcome.abandoned_threads, bench JSON — and
-        # warned about past ABANDONED_WARN_THRESHOLD.
+        # VISIBLE — SearchOutcome.abandoned_threads — and warned about
+        # past ABANDONED_WARN_THRESHOLD.
         self.abandoned: List[threading.Thread] = []
 
     def abandoned_alive(self) -> int:
@@ -635,32 +634,6 @@ def install_retry(search, policy: Optional[RetryPolicy] = None,
     return boundary
 
 
-def probe_device(deadline_secs: float = 60.0) -> dict:
-    """Watchdog-bounded accelerator liveness probe: a tiny matmul
-    through the same dispatch boundary the search loops use.  Returns
-    ``{platform, n_devices, secs}``; a wedged runtime surfaces as
-    :class:`EngineFailure` (kind ``wedged``) instead of a hang —
-    ``bench.py``'s preflight is a thin client of this."""
-    import jax
-    import jax.numpy as jnp
-    import numpy as np
-
-    t0 = time.time()
-    boundary = DispatchBoundary(
-        RetryPolicy(max_retries=0, deadline_secs=deadline_secs))
-    devs = jax.devices()
-
-    def _mm():
-        x = jnp.ones((256, 256), jnp.float32)
-        return jax.block_until_ready(x @ x)
-
-    y = boundary.dispatch("probe.matmul", _mm)
-    if float(np.asarray(y)[0, 0]) != 256.0:
-        raise RuntimeError("probe matmul returned a wrong result")
-    return {"platform": devs[0].platform, "n_devices": len(devs),
-            "secs": round(time.time() - t0, 1)}
-
-
 # ------------------------------------------------------------- supervisor
 
 class SearchSupervisor:
@@ -725,7 +698,8 @@ class SearchSupervisor:
         self.ev_budget = ev_budget
         # AOT warm-up of the sharded rung's programs at build time —
         # compile wall-time lands on SearchOutcome.compile_secs instead
-        # of inside the first run's measured window (bench.py).
+        # of inside the first run's measured window (the benchmark's
+        # drivers build their supervisor with it on).
         self.aot_warmup = aot_warmup
         self.dispatch_observer = dispatch_observer
         # Process isolation (tpu/warden.py): the accelerator-facing

@@ -1,10 +1,9 @@
 """Unified telemetry: dispatch-span flight recorder, metrics, reports.
 
 Before this module every subsystem emitted its own ad-hoc signals —
-SearchOutcome counters, warden heartbeat lines, bench JSON fragments,
-per-level stderr lines — and a wedged run left almost nothing
-behind (one scraped stderr line to explain a hang).  This is the one
-observability substrate
+SearchOutcome counters, warden heartbeat lines, per-level stderr
+lines — and a wedged run left almost nothing behind (one scraped stderr
+line to explain a hang).  This is the one observability substrate
 they all feed, built on the paper's discipline that **every signal must
 come from scalar readbacks already paid for**: the recorder never adds
 a device dispatch and never reads anything off the device beyond the
@@ -31,7 +30,7 @@ Pieces:
   (all three engines + the swarm's rounds), spill/overflow counters,
   supervisor retry/failover/rung events, and warden heartbeats
   re-emitted from the child→parent JSON protocol.  ``summary()`` is
-  the JSON block bench phases attach to their output.
+  the compact JSON block a caller attaches to its own output.
 
 * **Program spans.**  :func:`phase` / :func:`mark` name the host's
   work where it happens — the lab entry point's stages, every level,
@@ -53,10 +52,9 @@ Pieces:
   retry/failover/heartbeat timeline, spill and overflow counts, the
   compile-vs-search wall split, and the in-flight dispatch of a torn
   tail.  ``report --json`` emits the same structure machine-readable
-  (one schema shared with the grading scripts and the ledger compare
-  path; pinned by test).  docs/observability.md documents the span
-  model and the "diagnosing a wedge" recipe rides it
-  (docs/resilience.md).
+  (one schema shared with the grading scripts; pinned by test).
+  docs/observability.md documents the span model and the "diagnosing
+  a wedge" recipe rides it (docs/resilience.md).
 
 * **Per-device skew (mesh scope).**  The sharded / swarm engines keep
   their pre-``psum`` per-device scalars in the SAME fused stats
@@ -74,16 +72,9 @@ Pieces:
   rung/lane, in-flight dispatch) at level/event boundaries —
   ``python -m dslabs_tpu.tpu.telemetry watch <run-dir>`` tails it plus
   the flight log to render a live terminal view of ANY run, including
-  a warden child or a bench phase in another process, and survives
+  a warden child in another process, and survives
   the run being SIGKILLed mid-level (atomic replace = never torn;
   the flight tail names the in-flight dispatch).
-
-* **Cross-run bench ledger.**  bench.py appends each run's last-line
-  JSON to ``BENCH_HISTORY.jsonl`` (:func:`append_ledger`);
-  ``telemetry compare <ledger>`` diffs the latest run against the
-  best prior run per phase and flags regressions past
-  ``DSLABS_BENCH_REGRESS_PCT`` — the BENCH_r0N trajectory as a
-  queryable artifact instead of loose files.
 
 Thread-safe (the portfolio runs two lanes against one recorder); pure
 host-side Python + stdlib — importing this module never imports jax.
@@ -105,11 +96,10 @@ from collections import deque
 from typing import Dict, List, Optional, Tuple
 
 __all__ = ["Telemetry", "MetricsRegistry", "Counter", "Gauge",
-           "Histogram", "read_flight", "tail_records", "build_report",
+           "Histogram", "read_flight", "build_report",
            "render_report", "render_sites", "skew_metrics",
            "device_memory_stats", "default_status_path", "load_status",
-           "render_watch", "watch_frame", "append_ledger",
-           "read_ledger", "compare_ledger", "render_compare",
+           "render_watch", "watch_frame",
            "DISPATCH_SITES", "PHASES", "DEVICE_SCOPES", "AOT_PROGRAMS",
            "phase", "mark", "call", "annotate", "use", "current",
            "current_phase", "device_scope", "register_program", "registered_programs",
@@ -260,7 +250,7 @@ def default_status_path(flight_log: Optional[str]) -> Optional[str]:
     """The live-monitor file that pairs with a flight log: the run-dir
     convention is ``STATUS.json`` beside ``flight.jsonl``
     (checkpoint.run_dir_layout); a named phase log
-    (``<phase>.flight.jsonl``, the bench layout) gets
+    (``<phase>.flight.jsonl``) gets
     ``<phase>.STATUS.json`` so concurrent phases in one dir never
     clobber each other."""
     if not flight_log:
@@ -809,7 +799,7 @@ class Telemetry:
             # Line-buffered append: each record hits the OS on its own
             # write, so a SIGKILL leaves complete lines (the reader
             # tolerates one torn tail line).  An unwritable location
-            # (read-only FS — the bench fallback case) degrades to
+            # (a read-only FS) degrades to
             # RAM-only recording, never takes the run down.
             try:
                 d = os.path.dirname(os.path.abspath(flight_log))
@@ -883,7 +873,7 @@ class Telemetry:
             # Live skew aggregate (ISSUE 18 satellite): running
             # imbalance_max/mean/cv over the per-level explored lanes
             # — the rebalance health of the CURRENT run, visible in
-            # `telemetry watch` instead of only in bench phase JSON.
+            # `telemetry watch` instead of only in the outcome.
             # Always present (schema-pinned); None until a sharded
             # level reports per-device lanes.
             "skew_agg": None,
@@ -982,7 +972,7 @@ class Telemetry:
     @contextlib.contextmanager
     def span(self, tag: str, **fields):
         """Manual span for host-side work that is not a device dispatch
-        (bench preflight, the profiling tools' timed blocks).  Same
+        (the profiling tools' timed blocks).  Same
         record shape, same registry feeds."""
         engine, _, site = tag.partition(".")
         with self._lock:
@@ -1286,7 +1276,7 @@ class Telemetry:
     # ------------------------------------------------------------ summary
 
     def summary(self) -> dict:
-        """The compact JSON block bench phases attach to their output:
+        """The compact JSON block a caller attaches to its output:
         span totals, per-site latency snapshots, event counts, and the
         flight-log path for the deep dive."""
         with self._lock:
@@ -1334,20 +1324,6 @@ def read_flight(path: str) -> List[dict]:
                 break                     # torn tail: expected crash shape
             raise
     return records
-
-
-def tail_records(path: Optional[str], n: int = 6,
-                 kinds=("dispatch", "span", "event")) -> List[dict]:
-    """The last ``n`` span/dispatch/event records of a flight log —
-    the wedge-diagnostics payload bench.py attaches to a phase error.
-    Never raises: diagnostics must not mask the error they describe."""
-    if not path:
-        return []
-    try:
-        recs = [r for r in read_flight(path) if r.get("t") in kinds]
-    except Exception:
-        return []
-    return recs[-n:]
 
 
 # --------------------------------------------------------------- report
@@ -1622,7 +1598,7 @@ def render_sites(summary: dict) -> str:
 def _resolve_status(path: str) -> Optional[str]:
     """STATUS.json for a run dir (or a direct path): ``STATUS.json``
     first (the checkpoint run-dir convention), else the newest
-    ``*.STATUS.json`` (the bench per-phase convention)."""
+    ``*.STATUS.json`` (a named phase log's, ``default_status_path``)."""
     if os.path.isdir(path):
         cand = os.path.join(path, "STATUS.json")
         if os.path.exists(cand):
@@ -1788,582 +1764,6 @@ def render_watch(path: str, now: Optional[float] = None) -> str:
     return "\n".join(out)
 
 
-# ---------------------------------------------------- cross-run ledger
-
-def append_ledger(path: str, record: dict) -> Optional[str]:
-    """Append one run's record to a JSONL bench ledger.  Never raises
-    (the ledger is an artifact, not a dependency); returns the path on
-    success, None on failure."""
-    try:
-        d = os.path.dirname(os.path.abspath(path))
-        os.makedirs(d, exist_ok=True)
-        with open(path, "a") as f:
-            f.write(json.dumps(record) + "\n")
-        return path
-    except (OSError, ValueError, TypeError):
-        return None
-
-
-def read_ledger(path: str) -> List[dict]:
-    """Ledger reader — same torn-tail tolerance as the flight log (a
-    run killed mid-append leaves one torn line, not a dead ledger)."""
-    return read_flight(path)
-
-
-# The bench phases a ledger compare diffs ("headline" is the last-line
-# JSON's top-level value — the number the BENCH_r0N trajectory tracks).
-_LEDGER_PHASES = ("headline", "mesh", "strict", "beam", "swarm",
-                  "spill", "capacity2", "service", "lanes", "memo",
-                  "scenarios", "labs")
-
-# Resilience counters the ledger tracks beside the rates (ISSUE 9):
-# a bench run that suddenly needs mesh shrinks / knob re-levels /
-# failovers to land its number is a regression even at equal states/min.
-_RESILIENCE_COUNTERS = ("mesh_shrinks", "knob_retries", "failovers")
-
-# Sanitizer counters off the bench JSON's ``sanitizer`` block
-# (ISSUE 10): a run whose soundness-sanitizer findings INCREASE over
-# the best (fewest-findings) prior run regressed static correctness —
-# flagged with the same rc-1 severity as a rate regression.
-_SANITIZER_COUNTERS = ("findings", "conformance", "jaxpr")
-
-
-def _sanitizer_value(rec: dict, counter: str) -> Optional[int]:
-    s = rec.get("sanitizer")
-    if not isinstance(s, dict) or counter not in s:
-        return None
-    try:
-        return int(s[counter])
-    except (TypeError, ValueError):
-        return None
-
-
-def _counter_value(rec: dict, counter: str) -> Optional[int]:
-    v = rec.get(counter)
-    if v is None:
-        return None
-    try:
-        return int(v)
-    except (TypeError, ValueError):
-        return None
-
-
-def _phase_value(rec: dict, phase: str) -> Optional[float]:
-    if phase == "headline":
-        v = rec.get("value")
-    else:
-        p = rec.get(phase)
-        v = p.get("value") if isinstance(p, dict) else None
-    try:
-        v = float(v)
-    except (TypeError, ValueError):
-        return None
-    return v if v > 0 else None
-
-
-def compare_ledger(records: List[dict],
-                   threshold: Optional[float] = None) -> dict:
-    """Diff the LATEST run against the BEST prior run per phase.
-    ``threshold`` is the tolerated fractional slowdown
-    (DSLABS_BENCH_REGRESS_PCT, default 0.25 = flag anything >25%
-    below the best prior rate — states/min is noisy on shared boxes,
-    and the best-prior baseline already biases toward flagging)."""
-    if threshold is None:
-        threshold = _env_float("DSLABS_BENCH_REGRESS_PCT", 0.25)
-    runs = [r for r in records if isinstance(r, dict)
-            and ("value" in r or r.get("t") == "bench")]
-    cmp = {"runs": len(runs), "threshold_pct": round(threshold * 100, 1),
-           "phases": {}, "regressions": [], "improvements": []}
-    if len(runs) < 2:
-        cmp["note"] = "need >= 2 runs to compare"
-        return cmp
-    latest, prior = runs[-1], runs[:-1]
-    for phase in _LEDGER_PHASES:
-        lv = _phase_value(latest, phase)
-        priors = [v for v in (_phase_value(r, phase) for r in prior)
-                  if v is not None]
-        if lv is None or not priors:
-            continue
-        best = max(priors)
-        delta = (lv - best) / best
-        entry = {"phase": phase, "latest": round(lv, 1),
-                 "best_prior": round(best, 1),
-                 "delta_pct": round(delta * 100, 1)}
-        cmp["phases"][phase] = entry
-        if delta < -threshold:
-            cmp["regressions"].append(entry)
-        elif delta > threshold:
-            cmp["improvements"].append(entry)
-    # Headline mesh-width regression (ISSUE 12): the headline number
-    # is only comparable at equal (or wider) mesh width — a run that
-    # silently fell back to a narrower mesh (elastic re-level, wedged
-    # devices, lost XLA_FLAGS) must NOT compare as a headline win even
-    # if its states/min happens to be higher.  Width rides the
-    # last-line JSON as top-level ``mesh_width`` (bench._set_headline).
-    cmp["mesh_width"] = {}
-
-    def _width(rec) -> Optional[int]:
-        try:
-            w = int(rec.get("mesh_width"))
-        except (TypeError, ValueError):
-            return None
-        return w if w > 0 else None
-
-    lw = _width(latest)
-    priors_w = [w for w in (_width(r) for r in prior) if w is not None]
-    if lw is not None and priors_w:
-        best_w = max(priors_w)
-        entry = {"phase": "headline:mesh_width", "latest": lw,
-                 "best_prior": best_w,
-                 "delta_pct": round((lw - best_w) / best_w * 100, 1)}
-        cmp["mesh_width"]["mesh_width"] = entry
-        if lw < best_w:
-            cmp["regressions"].append(entry)
-    # Resilience regressions: the latest run needed MORE degradation
-    # (mesh shrinks / knob re-levels / failovers) than any prior run —
-    # flagged alongside the rate regressions (same rc).
-    cmp["resilience"] = {}
-    for counter in _RESILIENCE_COUNTERS:
-        lv = _counter_value(latest, counter)
-        if lv is None:
-            continue
-        priors = [v for v in (_counter_value(r, counter) for r in prior)
-                  if v is not None]
-        worst = max(priors) if priors else 0
-        entry = {"phase": f"resilience:{counter}", "latest": lv,
-                 "best_prior": worst,
-                 "delta_pct": 0.0}
-        cmp["resilience"][counter] = entry
-        if lv > worst:
-            cmp["regressions"].append(entry)
-    # Sanitizer regressions (ISSUE 10): the latest run's soundness
-    # findings vs the BEST (fewest) prior — any increase is a
-    # regression; waived findings never count (they are documented
-    # exceptions, not drift).
-    cmp["sanitizer"] = {}
-    for counter in _SANITIZER_COUNTERS:
-        lv = _sanitizer_value(latest, counter)
-        if lv is None:
-            continue
-        priors = [v for v in (_sanitizer_value(r, counter)
-                              for r in prior) if v is not None]
-        if not priors:
-            continue
-        best = min(priors)
-        entry = {"phase": f"sanitizer:{counter}", "latest": lv,
-                 "best_prior": best, "delta_pct": 0.0}
-        cmp["sanitizer"][counter] = entry
-        if lv > best:
-            cmp["regressions"].append(entry)
-    # Fairness regressions (ISSUE 11): the service phase's fairness
-    # index (max/mean verdicts-per-tenant-budget; 1.0 = perfectly
-    # fair) vs the BEST (lowest) prior — a rise past the threshold
-    # means one tenant converted shared budget into verdicts at a
-    # neighbor's expense, a regression even at equal aggregate rate.
-    cmp["fairness"] = {}
-
-    def _fair(rec):
-        s = rec.get("service")
-        if not isinstance(s, dict):
-            return None
-        try:
-            v = float(s.get("fairness_index"))
-        except (TypeError, ValueError):
-            return None
-        return v if v > 0 else None
-
-    lv = _fair(latest)
-    priors_f = [v for v in (_fair(r) for r in prior) if v is not None]
-    if lv is not None and priors_f:
-        best = min(priors_f)
-        entry = {"phase": "service:fairness_index",
-                 "latest": round(lv, 4), "best_prior": round(best, 4),
-                 "delta_pct": round((lv - best) / best * 100, 1)}
-        cmp["fairness"]["fairness_index"] = entry
-        if lv > best * (1.0 + threshold):
-            cmp["regressions"].append(entry)
-    # Per-phase compile-time creep (ISSUE 13 satellite): each phase's
-    # measured compile_secs vs the BEST (fastest) prior — compile
-    # regressions are invisible in states/min (the measured window
-    # excludes them by design), so they get their own guard with the
-    # same threshold / rc-1 discipline.  Sub-second bests are skipped:
-    # a warm-cache 0.2s -> 0.5s jitter is noise, not creep.
-    cmp["compile"] = {}
-
-    def _compile_value(rec, phase) -> Optional[float]:
-        p = rec.get(phase)
-        if not isinstance(p, dict):
-            return None
-        try:
-            v = float(p.get("compile_secs"))
-        except (TypeError, ValueError):
-            return None
-        return v if v >= 0 else None
-
-    floor = _env_float("DSLABS_COMPILE_REGRESS_FLOOR", 1.0)
-    for phase in _LEDGER_PHASES:
-        lv = _compile_value(latest, phase)
-        if lv is None:
-            continue
-        priors_c = [v for v in (_compile_value(r, phase)
-                                for r in prior) if v is not None]
-        if not priors_c:
-            continue
-        best = min(priors_c)
-        entry = {"phase": f"compile:{phase}", "latest": round(lv, 1),
-                 "best_prior": round(best, 1),
-                 "delta_pct": round((lv - best) / best * 100, 1)
-                 if best > 0 else 0.0}
-        cmp["compile"][phase] = entry
-        if (lv > max(best, floor) * (1.0 + threshold)
-                and lv - best > floor):
-            cmp["regressions"].append(entry)
-    # Cost-per-unique-state creep (ISSUE 13): the service phase's
-    # aggregate device-seconds per unique state vs the BEST (cheapest)
-    # prior — a tenant's billed budget buying fewer states is a
-    # regression even when verdicts/min holds (e.g. retries burning
-    # device time the verdict count hides).
-    cmp["cost"] = {}
-
-    def _cost(rec):
-        s = rec.get("service")
-        if not isinstance(s, dict):
-            return None
-        try:
-            v = float(s.get("cost_per_unique"))
-        except (TypeError, ValueError):
-            return None
-        return v if v > 0 else None
-
-    lv = _cost(latest)
-    priors_k = [v for v in (_cost(r) for r in prior) if v is not None]
-    if lv is not None and priors_k:
-        best = min(priors_k)
-        entry = {"phase": "service:cost_per_unique",
-                 "latest": lv, "best_prior": best,
-                 "delta_pct": round((lv - best) / best * 100, 1)}
-        cmp["cost"]["cost_per_unique"] = entry
-        if lv > best * (1.0 + threshold):
-            cmp["regressions"].append(entry)
-    # Batched-lane amortisation guards (ISSUE 14, tpu/lanes.py).
-    # dispatches-per-job is THE number continuous batching exists to
-    # shrink: a rise past the threshold over the best (fewest) prior
-    # means jobs stopped sharing dispatch streams — a regression even
-    # at equal verdicts/min.  Lane occupancy (mean resident lanes per
-    # level of the lanes phase) dropping past the threshold means the
-    # packer stopped filling lanes — same severity.
-    cmp["lanes"] = {}
-
-    def _dpj(rec):
-        for block in ("lanes", "service"):
-            s = rec.get(block)
-            if isinstance(s, dict):
-                try:
-                    v = float(s.get("dispatches_per_job"))
-                except (TypeError, ValueError):
-                    continue
-                if v > 0:
-                    return v
-        return None
-
-    lv = _dpj(latest)
-    priors_d = [v for v in (_dpj(r) for r in prior) if v is not None]
-    if lv is not None and priors_d:
-        best = min(priors_d)
-        entry = {"phase": "service:dispatches_per_job",
-                 "latest": round(lv, 2), "best_prior": round(best, 2),
-                 "delta_pct": round((lv - best) / best * 100, 1)
-                 if best > 0 else 0.0}
-        cmp["lanes"]["dispatches_per_job"] = entry
-        if lv > best * (1.0 + threshold):
-            cmp["regressions"].append(entry)
-
-    def _occ(rec):
-        s = rec.get("lanes")
-        if not isinstance(s, dict):
-            return None
-        try:
-            v = float(s.get("occupancy"))
-        except (TypeError, ValueError):
-            return None
-        return v if v > 0 else None
-
-    lv = _occ(latest)
-    priors_o = [v for v in (_occ(r) for r in prior) if v is not None]
-    if lv is not None and priors_o:
-        best = max(priors_o)
-        entry = {"phase": "lanes:occupancy",
-                 "latest": round(lv, 3), "best_prior": round(best, 3),
-                 "delta_pct": round((lv - best) / best * 100, 1)}
-        cmp["lanes"]["occupancy"] = entry
-        if lv < best * (1.0 - threshold):
-            cmp["regressions"].append(entry)
-    # Capacity-round-2 guard (ISSUE 15): HBM bytes per stored frontier
-    # state on the capacity2 phase vs the BEST (smallest) prior — a
-    # rise past the threshold means the packed encoding regressed
-    # (domain declarations lost, codec disabled), shrinking
-    # frontier/visited capacity at fixed HBM even when states/min
-    # holds.  Same rc-1 severity as a rate regression.
-    cmp["capacity"] = {}
-
-    def _bps(rec):
-        s = rec.get("capacity2")
-        if not isinstance(s, dict):
-            return None
-        try:
-            v = float(s.get("bytes_per_state"))
-        except (TypeError, ValueError):
-            return None
-        return v if v > 0 else None
-
-    lv = _bps(latest)
-    priors_b = [v for v in (_bps(r) for r in prior) if v is not None]
-    if lv is not None and priors_b:
-        best = min(priors_b)
-        entry = {"phase": "capacity:bytes_per_state",
-                 "latest": round(lv, 1), "best_prior": round(best, 1),
-                 "delta_pct": round((lv - best) / best * 100, 1)}
-        cmp["capacity"]["bytes_per_state"] = entry
-        if lv > best * (1.0 + threshold):
-            cmp["regressions"].append(entry)
-    # Cross-job memoization guard (ISSUE 16, service/memo.py): the
-    # memo phase's hit_rate vs the BEST (highest) prior — a drop past
-    # the threshold means identical resubmits stopped reusing verdicts
-    # (fingerprint churn, store invalidation bug), the throughput
-    # multiplier silently lost even at equal cold-run states/min.
-    # device_secs_saved is tracked beside it (rendered, not guarded:
-    # its magnitude scales with workload, the RATE is the invariant).
-    cmp["memo"] = {}
-
-    def _hit_rate(rec):
-        s = rec.get("memo")
-        if not isinstance(s, dict):
-            return None
-        try:
-            v = float(s.get("hit_rate"))
-        except (TypeError, ValueError):
-            return None
-        return v if v >= 0 else None
-
-    lv = _hit_rate(latest)
-    priors_h = [v for v in (_hit_rate(r) for r in prior)
-                if v is not None]
-    if lv is not None and priors_h:
-        best = max(priors_h)
-        entry = {"phase": "memo:hit_rate",
-                 "latest": round(lv, 3), "best_prior": round(best, 3),
-                 "delta_pct": round((lv - best) / best * 100, 1)
-                 if best > 0 else 0.0}
-        cmp["memo"]["hit_rate"] = entry
-        if lv < best * (1.0 - threshold):
-            cmp["regressions"].append(entry)
-
-    def _saved(rec):
-        for block in ("memo", "service"):
-            s = rec.get(block)
-            if isinstance(s, dict):
-                try:
-                    v = float(s.get("device_secs_saved"))
-                except (TypeError, ValueError):
-                    continue
-                if v >= 0:
-                    return v
-        return None
-
-    lv = _saved(latest)
-    priors_s = [v for v in (_saved(r) for r in prior) if v is not None]
-    if lv is not None and priors_s:
-        best = max(priors_s)
-        cmp["memo"]["device_secs_saved"] = {
-            "phase": "service:device_secs_saved",
-            "latest": round(lv, 3), "best_prior": round(best, 3),
-            "delta_pct": round((lv - best) / best * 100, 1)
-            if best > 0 else 0.0}
-    # Packed-wire mesh guards (ISSUE 18): two invariants the wire
-    # refactor exists to hold.  wire_bytes_per_state is the ICI
-    # payload row width on the mesh phase vs the BEST (smallest)
-    # prior — a rise means the exchange fell back to raw rows (codec
-    # disabled, identity descriptor) even when states/min holds.
-    # imbalance_max is the worst per-level per-device imbalance vs the
-    # BEST (lowest) prior — a rise means the owner hash stopped
-    # levelling the shards.  Both rc-1 on regression.
-    cmp["mesh"] = {}
-
-    def _wire(rec):
-        s = rec.get("mesh")
-        if not isinstance(s, dict):
-            return None
-        w = s.get("wire")
-        if not isinstance(w, dict):
-            return None
-        try:
-            v = float(w.get("wire_bytes_per_state"))
-        except (TypeError, ValueError):
-            return None
-        return v if v > 0 else None
-
-    lv = _wire(latest)
-    priors_w = [v for v in (_wire(r) for r in prior) if v is not None]
-    if lv is not None and priors_w:
-        best = min(priors_w)
-        entry = {"phase": "mesh:wire_bytes_per_state",
-                 "latest": round(lv, 1), "best_prior": round(best, 1),
-                 "delta_pct": round((lv - best) / best * 100, 1)}
-        cmp["mesh"]["wire_bytes_per_state"] = entry
-        if lv > best * (1.0 + threshold):
-            cmp["regressions"].append(entry)
-
-    def _imb(rec):
-        s = rec.get("mesh")
-        if not isinstance(s, dict):
-            return None
-        try:
-            v = float(s.get("imbalance_max"))
-        except (TypeError, ValueError):
-            return None
-        return v if v >= 1.0 else None
-
-    lv = _imb(latest)
-    priors_i = [v for v in (_imb(r) for r in prior) if v is not None]
-    if lv is not None and priors_i:
-        best = min(priors_i)
-        entry = {"phase": "mesh:imbalance_max",
-                 "latest": round(lv, 2), "best_prior": round(best, 2),
-                 "delta_pct": round((lv - best) / best * 100, 1)
-                 if best > 0 else 0.0}
-        cmp["mesh"]["imbalance_max"] = entry
-        if lv > best * (1.0 + threshold):
-            cmp["regressions"].append(entry)
-    # Fault-scenario parity guard (ISSUE 19, bench --scenarios):
-    # verdict_parity is BINARY — 1 means the zero-budget FaultModel
-    # landed the exact fault-free verdict/explored/unique on both
-    # engines (the overhead-guard invariant scenarios ride on); 0 is a
-    # soundness break, flagged regardless of threshold or priors.
-    cmp["scenarios"] = {}
-
-    def _parity(rec):
-        s = rec.get("scenarios")
-        if not isinstance(s, dict) or "verdict_parity" not in s:
-            return None
-        try:
-            return int(s["verdict_parity"])
-        except (TypeError, ValueError):
-            return None
-
-    lv = _parity(latest)
-    priors_p = [v for v in (_parity(r) for r in prior) if v is not None]
-    if lv is not None:
-        best = max(priors_p) if priors_p else 1
-        entry = {"phase": "scenarios:verdict_parity", "latest": lv,
-                 "best_prior": best, "delta_pct": 0.0}
-        cmp["scenarios"]["verdict_parity"] = entry
-        if lv < 1:
-            cmp["regressions"].append(entry)
-    # Generated-labs packing guard (ISSUE 20, bench --labs): summed
-    # packed bytes per stored state across the ProtocolSpec-compiled
-    # lab3/lab4 protocols vs the BEST (smallest) prior — a rise past
-    # the threshold means the spec-declared Field/Slots domains
-    # stopped reaching the bit-packer (declarations dropped in a
-    # refactor, identity descriptor re-derived), silently shrinking
-    # frontier capacity at fixed HBM.  Same rc-1 severity as a rate
-    # regression.
-    cmp["labs"] = {}
-
-    def _labs_bps(rec):
-        s = rec.get("labs")
-        if not isinstance(s, dict):
-            return None
-        try:
-            v = float(s.get("bytes_per_state"))
-        except (TypeError, ValueError):
-            return None
-        return v if v > 0 else None
-
-    lv = _labs_bps(latest)
-    priors_lb = [v for v in (_labs_bps(r) for r in prior)
-                 if v is not None]
-    if lv is not None and priors_lb:
-        best = min(priors_lb)
-        entry = {"phase": "labs:bytes_per_state",
-                 "latest": round(lv, 1), "best_prior": round(best, 1),
-                 "delta_pct": round((lv - best) / best * 100, 1)}
-        cmp["labs"]["bytes_per_state"] = entry
-        if lv > best * (1.0 + threshold):
-            cmp["regressions"].append(entry)
-    return cmp
-
-
-def render_compare(cmp: dict, source: str = "") -> str:
-    out = [f"== bench ledger compare: {source or 'ledger'} "
-           f"({cmp['runs']} runs, threshold "
-           f"{cmp['threshold_pct']:.0f}%) =="]
-    if cmp.get("note"):
-        out.append(cmp["note"])
-        return "\n".join(out)
-    out.append(f"{'phase':14s} {'latest':>12s} {'best_prior':>12s} "
-               f"{'delta':>8s}")
-    for phase in _LEDGER_PHASES:
-        e = cmp["phases"].get(phase)
-        if e is None:
-            continue
-        out.append(f"{phase:14s} {e['latest']:12.1f} "
-                   f"{e['best_prior']:12.1f} {e['delta_pct']:+7.1f}%")
-    for c, e in sorted(cmp.get("mesh_width", {}).items()):
-        out.append(f"headline {c:16s} latest={e['latest']} "
-                   f"prior_widest={e['best_prior']}")
-    for c, e in sorted(cmp.get("resilience", {}).items()):
-        out.append(f"resilience {c:14s} latest={e['latest']} "
-                   f"prior_worst={e['best_prior']}")
-    for c, e in sorted(cmp.get("sanitizer", {}).items()):
-        out.append(f"sanitizer {c:15s} latest={e['latest']} "
-                   f"prior_best={e['best_prior']}")
-    for c, e in sorted(cmp.get("fairness", {}).items()):
-        out.append(f"fairness {c:16s} latest={e['latest']} "
-                   f"prior_best={e['best_prior']} "
-                   f"({e['delta_pct']:+.1f}%)")
-    for c, e in sorted(cmp.get("compile", {}).items()):
-        out.append(f"compile {c:17s} latest={e['latest']}s "
-                   f"prior_best={e['best_prior']}s "
-                   f"({e['delta_pct']:+.1f}%)")
-    for c, e in sorted(cmp.get("cost", {}).items()):
-        out.append(f"cost {c:20s} latest={e['latest']} "
-                   f"prior_best={e['best_prior']} "
-                   f"({e['delta_pct']:+.1f}%)")
-    for c, e in sorted(cmp.get("lanes", {}).items()):
-        out.append(f"lanes {c:19s} latest={e['latest']} "
-                   f"prior_best={e['best_prior']} "
-                   f"({e['delta_pct']:+.1f}%)")
-    for c, e in sorted(cmp.get("capacity", {}).items()):
-        out.append(f"capacity {c:16s} latest={e['latest']} "
-                   f"prior_best={e['best_prior']} "
-                   f"({e['delta_pct']:+.1f}%)")
-    for c, e in sorted(cmp.get("memo", {}).items()):
-        out.append(f"memo {c:20s} latest={e['latest']} "
-                   f"prior_best={e['best_prior']} "
-                   f"({e['delta_pct']:+.1f}%)")
-    for c, e in sorted(cmp.get("mesh", {}).items()):
-        out.append(f"mesh {c:20s} latest={e['latest']} "
-                   f"prior_best={e['best_prior']} "
-                   f"({e['delta_pct']:+.1f}%)")
-    for c, e in sorted(cmp.get("scenarios", {}).items()):
-        out.append(f"scenarios {c:15s} latest={e['latest']} "
-                   f"prior_best={e['best_prior']}")
-    for c, e in sorted(cmp.get("labs", {}).items()):
-        out.append(f"labs {c:20s} latest={e['latest']} "
-                   f"prior_best={e['best_prior']} "
-                   f"({e['delta_pct']:+.1f}%)")
-    for e in cmp["regressions"]:
-        out.append(f"REGRESSION: phase={e['phase']} "
-                   f"latest={e['latest']} vs best={e['best_prior']} "
-                   f"({e['delta_pct']:+.1f}%)")
-    for e in cmp["improvements"]:
-        out.append(f"improvement: phase={e['phase']} "
-                   f"({e['delta_pct']:+.1f}%)")
-    if not cmp["regressions"]:
-        out.append("parity: no phase regressed past the threshold")
-    return "\n".join(out)
-
-
 # ------------------------------------------------------------------ CLI
 
 _USAGE = """usage: python -m dslabs_tpu.tpu.telemetry <command> ...
@@ -2373,7 +1773,6 @@ _USAGE = """usage: python -m dslabs_tpu.tpu.telemetry <command> ...
                                              live monitor of any run
   trace   <run-dir|server-dir> [--job ID] [--json] [--perfetto F]
                                              assemble the causal trace
-  compare <ledger.jsonl> [--threshold F]     diff latest vs best prior
 """
 
 
@@ -2381,8 +1780,7 @@ def main(argv: Optional[List[str]] = None) -> int:
     import sys
 
     argv = list(sys.argv[1:] if argv is None else argv)
-    if len(argv) < 2 or argv[0] not in ("report", "watch", "compare",
-                                        "trace"):
+    if len(argv) < 2 or argv[0] not in ("report", "watch", "trace"):
         print(_USAGE, file=sys.stderr)
         return 2
     cmd, path = argv[0], argv[1]
@@ -2402,25 +1800,17 @@ def main(argv: Optional[List[str]] = None) -> int:
         if "--json" in flags:
             # The machine-readable schema (pinned by test): the same
             # sections the renderer draws, one structure shared with
-            # grading scripts and the ledger compare path.
+            # grading scripts.
             print(json.dumps(dict(report, source=flight)))
         else:
             print(render_report(report, source=flight))
         return 0
 
-    if cmd == "compare":
-        threshold = None
-        if "--threshold" in flags:
-            threshold = float(flags[flags.index("--threshold") + 1])
-        cmp = compare_ledger(read_ledger(path), threshold)
-        print(render_compare(cmp, source=path))
-        return 1 if cmp["regressions"] else 0
-
     # watch: redraw until interrupted (--once = one frame, for smoke
     # tests and scripts; --json = one machine-readable frame with the
     # staleness verdict, the satellite's scripting hook).  Reads only
     # the run dir — the run itself can be any process, a warden child
-    # or a bench phase included.
+    # included.
     if "--json" in flags:
         print(json.dumps(watch_frame(path)))
         return 0

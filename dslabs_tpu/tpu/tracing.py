@@ -36,9 +36,7 @@ halves:
   artifacts that already exist; the overhead-guard test pins it).
   Records append to ``COSTS.jsonl`` beside the journal (line-buffered,
   torn-tail-tolerant — the flight-recorder discipline) and surface in
-  SERVER_STATUS.json per-tenant ledgers, the bench ``--service``
-  phase, and ``telemetry compare`` (cost-per-unique-state regression
-  flagging).
+  SERVER_STATUS.json per-tenant ledgers and the drain's summary.
 
 Pure host-side Python + stdlib — importing this module never imports
 jax; the telemetry module is imported lazily (it is the lower layer).
@@ -351,8 +349,7 @@ class CostMeter:
         """Per-tenant ledger totals (the SERVER_STATUS.json ``costs``
         block): explored/unique sums, device seconds, dispatch count,
         compile-vs-search split, retries/failovers burned, and
-        cost-per-unique-state (device seconds per unique state — the
-        number ``telemetry compare`` tracks)."""
+        cost-per-unique-state (device seconds per unique state)."""
         with self._lock:
             records = list(self.records)
         return aggregate_costs(records)

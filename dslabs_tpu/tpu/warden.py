@@ -50,9 +50,10 @@ restart a worker stuck in a hung collective:
   ``resumed_from_depth``) plus ``child_restarts`` and
   ``killed_dispatches``.
 
-:class:`LineWatch` is the shared child-stream monitor: bench.py's
-phase subprocesses ride it so a wedged preflight is killed at heartbeat
-silence (seconds) instead of the full phase budget (minutes).
+:class:`LineWatch` is the shared child-stream monitor: the warden's
+children and the lane runner's (tpu/lanes.py) ride it, so a wedged
+child is killed at heartbeat silence (seconds) instead of at its full
+budget (minutes).
 
 Exercised by the deterministic kill/hang/crash matrix in
 tests/test_warden.py (``make fault-smoke``) — injected via the
@@ -170,8 +171,7 @@ def outcome_from_dict(d: dict):
 class LineWatch:
     """Watch a child process's text stream line by line, tracking
     last-activity time, so a caller can enforce BOTH a total budget and
-    a heartbeat-silence budget (the warden-probe contract bench.py's
-    phase subprocesses ride).  The reader thread forwards each line to
+    a heartbeat-silence budget.  The reader thread forwards each line to
     ``on_line`` and keeps a short tail for attributable errors."""
 
     def __init__(self, proc: subprocess.Popen, stream, on_line=None):

@@ -186,9 +186,10 @@ def _visualize_trace(path: str) -> int:
 def main(argv=None) -> int:
     args = _parse_args(argv if argv is not None else sys.argv[1:])
     # Object-backend runs keep any transitive jax import off the
-    # accelerator (the bench owns the real chip); the tensor backend —
-    # via flag or DSLABS_SEARCH_BACKEND — runs search tests ON it.  Must
-    # happen before _discover() imports anything jax-flavoured.
+    # accelerator (a chip belongs to one process at a time); the tensor
+    # backend — via flag or DSLABS_SEARCH_BACKEND — runs search tests ON
+    # it.  Must happen before _discover() imports anything
+    # jax-flavoured.
     backend = args.search_backend or os.environ.get(
         "DSLABS_SEARCH_BACKEND", "object")
     if backend != "tensor":

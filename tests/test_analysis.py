@@ -697,27 +697,7 @@ def test_sanitize_off_is_off(monkeypatch):
                 if e.get("kind") == "sanitizer_finding"]
 
 
-# --------------------------------------------- ledger compare + bench
-
-def test_compare_ledger_flags_sanitizer_regression():
-    from dslabs_tpu.tpu.telemetry import compare_ledger
-
-    prior = {"t": "bench", "value": 100.0,
-             "sanitizer": {"findings": 0, "conformance": 0, "jaxpr": 0,
-                           "waived": 0}}
-    worse = {"t": "bench", "value": 100.0,
-             "sanitizer": {"findings": 2, "conformance": 1, "jaxpr": 1,
-                           "waived": 0}}
-    cmp = compare_ledger([prior, worse])
-    regressed = {e["phase"] for e in cmp["regressions"]}
-    assert "sanitizer:findings" in regressed
-    # parity: equal findings is not a regression
-    cmp = compare_ledger([prior, dict(prior)])
-    assert not any(e["phase"].startswith("sanitizer")
-                   for e in cmp["regressions"])
-    # waived findings never count (summary only carries live counts)
-    assert cmp["sanitizer"]["findings"]["latest"] == 0
-
+# ------------------------------------------------------ run_tests --lint
 
 def test_run_tests_lint_flag(tmp_path, capsys):
     """run_tests.py --lint runs the conformance pass before the labs

@@ -622,11 +622,10 @@ def test_deep_programs_from_the_store_have_the_text_compiled_in_place(
 
 
 def _flagship_search(mesh, chunk):
-    from bench import _bench_protocol
-
     caps = dict(chip_smoke.FLAGSHIP, chunk=chunk)
     return ShardedTensorSearch(
-        _bench_protocol(), mesh, chunk_per_device=caps["chunk"],
+        chip_smoke.flagship_protocol(), mesh,
+        chunk_per_device=caps["chunk"],
         frontier_cap=caps["frontier_cap"],
         visited_cap=caps["visited_cap"], strict=True,
         ev_budget=chip_smoke.EV_BUDGET)
@@ -677,7 +676,7 @@ def test_flagship_programs_compile(topo, n_devices):
 @pytest.mark.parametrize("chunk", [1024, 2048, 8192])
 def test_flagship_superstep_fits_at_chunk(topo, chunk):
     """PR 22's finding (``test_flagship_chunk_8192_does_not_fit``),
-    turned around by ISSUE 32.  At bench.py's chunk 8192 the compiler
+    turned around by ISSUE 32.  At chunk 8192 the compiler
     used to refuse the flagship superstep — 42 GB of HBM against 15.75,
     nearly all of it the codec's [chunk*48, 1] uint32 columns padded
     128x by the (8, 128) tile.  The codec now assembles all words in one

@@ -106,3 +106,32 @@ def test_four_chip_phase_rehearsal_on_virtual_devices():
     for leaf in ("visited", "cur", "nxt"):
         assert len(set(rec["layout_device_ids"][leaf])) == 4
     assert all(e > 0 for e in rec["explored_per_device"])
+
+
+def test_flagship_protocol_is_the_benchmark_configuration():
+    """``chip_smoke.flagship_protocol()`` and the `protocol` block of
+    ``benchmark/configs/lab3-paxos-n3c2.json`` build one program: two
+    engines at the same caps share a store key, and a changed kwarg
+    does not."""
+    from benchmark.drivers.timeboxed_bfs import build_protocol
+    from benchmark.harness import manifest
+    from dslabs_tpu.tpu.sharded import ShardedTensorSearch, make_mesh
+
+    spec = manifest.load_cell(
+        chip_smoke.ROOT, "paxos3-deep").config["protocol"]
+    assert spec["strip_goals"]
+    other = dict(spec, kwargs=dict(spec["kwargs"], net_cap=32))
+
+    def key(protocol):
+        return ShardedTensorSearch(
+            protocol, make_mesh(1), chunk_per_device=TINY["chunk"],
+            frontier_cap=TINY["frontier_cap"],
+            visited_cap=TINY["visited_cap"], strict=True,
+            ev_budget=chip_smoke.EV_BUDGET).store_key()
+
+    smoke = chip_smoke.flagship_protocol()
+    assert smoke.goals == {}
+    smoke_key = key(smoke)
+    assert smoke_key is not None
+    assert smoke_key == key(build_protocol(spec)) != key(
+        build_protocol(other))

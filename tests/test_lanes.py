@@ -10,7 +10,7 @@ amortisation pin (a 4-lane batch spends <= 0.5x the dispatches of four
 solo runs — the economics the feature exists for), the solo-path
 overhead guard (lanes off = solo engines untouched), the service
 integration (lane packer quotas, COSTS sums, eviction-to-solo), and
-the observability schema (STATUS lanes block, ledger compare guards).
+the observability schema (STATUS lanes block).
 """
 
 import dataclasses
@@ -525,31 +525,3 @@ def test_status_lanes_schema_and_watch(tmp_path):
     assert lane_levels
     first = lane_levels[0]
     assert len(first["per_device"]["explored"]) == len(first["lanes"])
-
-
-def test_compare_guards_dispatches_per_job_and_occupancy(tmp_path):
-    """`telemetry compare`: a dispatches-per-job RISE or an occupancy
-    DROP past the threshold is a regression (rc 1); parity is quiet."""
-    from dslabs_tpu.tpu import telemetry as tel_mod
-
-    ok = str(tmp_path / "ok.jsonl")
-    rec = {"t": "bench", "value": 100.0,
-           "lanes": {"value": 400.0, "dispatches_per_job": 8.0,
-                     "occupancy": 4.0},
-           "service": {"dispatches_per_job": 8.0}}
-    for _ in range(2):
-        tel_mod.append_ledger(ok, rec)
-    cmp = tel_mod.compare_ledger(tel_mod.read_ledger(ok))
-    assert cmp["regressions"] == []
-    bad = str(tmp_path / "bad.jsonl")
-    tel_mod.append_ledger(bad, rec)
-    tel_mod.append_ledger(bad, {
-        "t": "bench", "value": 100.0,
-        "lanes": {"value": 400.0, "dispatches_per_job": 20.0,
-                  "occupancy": 1.5}})
-    cmp = tel_mod.compare_ledger(tel_mod.read_ledger(bad))
-    flagged = {e["phase"] for e in cmp["regressions"]}
-    assert "service:dispatches_per_job" in flagged
-    assert "lanes:occupancy" in flagged
-    rendered = tel_mod.render_compare(cmp)
-    assert "dispatches_per_job" in rendered

@@ -383,36 +383,3 @@ def test_fused_exchange_transient_retry():
     assert out.states_explored == base.states_explored
     assert out.retries == 2
     assert out.failovers == 0
-
-
-# ------------------------------------------------------- bench mesh phase
-
-@pytest.mark.slow
-def test_bench_mesh_phase_schema():
-    """The bench's --mesh phase (the new headline): last-line JSON
-    carries mesh_width, finite skew, per-level per-device lanes, and
-    clean recovery counters on the CPU virtual 8-device mesh."""
-    import json
-    import subprocess
-    import sys
-
-    env = dict(os.environ, JAX_PLATFORMS="cpu")
-    env.pop("DSLABS_BENCH_PROTOCOL", None)
-    proc = subprocess.run(
-        [sys.executable, "bench.py", "--mesh", "90"],
-        capture_output=True, text=True, timeout=600, env=env,
-        cwd=os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
-    line = proc.stdout.strip().splitlines()[-1]
-    phase = json.loads(line)
-    assert phase["mesh_width"] == 8
-    assert phase["virtual_cpu_mesh"] is True
-    assert phase["value"] > 0
-    assert phase["unique"] > 0
-    sk = phase["skew"]
-    assert np.isfinite(sk["imbalance_max"])
-    assert sk["imbalance_max"] >= 1.0
-    assert phase["mesh_shrinks"] == 0
-    assert phase["knob_retries"] == 0
-    levels = phase["levels"]
-    assert levels and "per_device" in levels[-1]
-    assert len(levels[-1]["per_device"]["explored"]) == 8

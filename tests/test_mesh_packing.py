@@ -289,29 +289,3 @@ def test_status_skew_agg_block_and_watch(tmp_path, capsys):
     text = capsys.readouterr().out
     assert "skew agg:" in text
     assert "imbalance_max=" in text
-
-
-def test_compare_ledger_guards_mesh_wire_and_imbalance():
-    """Bench satellite: the ledger guards the two numbers this PR
-    exists to hold — wire bytes-per-state rising (codec fell back to
-    raw) or imbalance_max rising (the owner hash stopped levelling)
-    past the threshold is an rc-1 regression."""
-    from dslabs_tpu.tpu.telemetry import compare_ledger
-
-    def rec(wire_bps, imb):
-        return {"t": "bench", "value": 1000.0,
-                "mesh": {"value": 1000.0,
-                         "wire": {"wire_bytes_per_state": wire_bps,
-                                  "wire_bytes_per_state_raw": 264,
-                                  "key_bytes_per_state": 16},
-                         "imbalance_max": imb}}
-
-    cmp = compare_ledger([rec(16, 1.2), rec(264, 8.0)], threshold=0.1)
-    phases = {e["phase"] for e in cmp["regressions"]}
-    assert "mesh:wire_bytes_per_state" in phases
-    assert "mesh:imbalance_max" in phases
-    assert cmp["mesh"]["wire_bytes_per_state"]["best_prior"] == 16
-
-    cmp = compare_ledger([rec(16, 1.2), rec(16, 1.2)], threshold=0.1)
-    assert not [e for e in cmp["regressions"]
-                if str(e["phase"]).startswith("mesh:")]

@@ -437,26 +437,6 @@ def test_fault_counters_reach_telemetry_and_status(tmp_path):
     assert "faults:" in render_report(report)
 
 
-def test_scenarios_verdict_parity_ledger_guard():
-    """``telemetry compare`` treats ``scenarios.verdict_parity`` as a
-    BINARY guard: a latest run with parity 0 is a regression
-    regardless of the rate threshold; parity 1 never flags."""
-    from dslabs_tpu.tpu.telemetry import compare_ledger
-
-    def run(parity):
-        return {"t": "bench", "value": 1.0,
-                "scenarios": {"value": 100.0,
-                              "verdict_parity": parity}}
-
-    ok = compare_ledger([run(1), run(1)])
-    assert ok["scenarios"]["verdict_parity"]["latest"] == 1
-    assert not any(e["phase"] == "scenarios:verdict_parity"
-                   for e in ok["regressions"])
-    bad = compare_ledger([run(1), run(0)])
-    assert any(e["phase"] == "scenarios:verdict_parity"
-               for e in bad["regressions"])
-
-
 def test_fault_counters_in_warden_scalar_fields():
     """The supervisor's merged-outcome accounting carries the fault
     counters (a failover mustn't silently zero them)."""

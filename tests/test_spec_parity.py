@@ -16,9 +16,7 @@ tests/fixtures/hand_twins/ as parity ORACLES —
   the checkpoint format, and the mesh wire descriptor (the PR-18
   parity-oracle pattern: packed vs unpacked is assertion-exact);
 * spec-declared domains reach the bit-packer: >= 2x bytes-per-state
-  reduction on every generated lab3/lab4 spec (the bench ``--labs``
-  phase records the same numbers behind the ``labs:bytes_per_state``
-  ledger guard).
+  reduction on every generated lab3/lab4 spec.
 
 Marked ``spec`` (``make spec-smoke``)."""
 

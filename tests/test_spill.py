@@ -359,8 +359,8 @@ def test_visited_warn_fires_before_overflow():
 
 def test_dropped_states_surfaced_and_warned(monkeypatch):
     """Beam drops are a COUNT everywhere (SearchOutcome.dropped_states)
-    and loud past DSLABS_DROPPED_WARN — the BENCH_r03 5.8M-drop shape
-    can no longer hide behind a flag."""
+    and loud past DSLABS_DROPPED_WARN — millions of drops can no
+    longer hide behind a flag."""
     monkeypatch.setenv("DSLABS_DROPPED_WARN", "1")
     proto = _pruned_clientserver(nc=3, w=3)
     with pytest.warns(RuntimeWarning, match="dropped"):

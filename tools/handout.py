@@ -32,7 +32,7 @@ import tarfile
 
 ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 
-SHIP = ["dslabs_tpu", "tests", "run_tests.py", "bench.py", "Makefile",
+SHIP = ["dslabs_tpu", "tests", "run_tests.py", "Makefile",
         "README.md", "docs", "__graft_entry__.py"]
 # Instructor-only material and SOLUTION MIRRORS never ship: the tensor
 # protocol twins + compiler specs are handler-for-handler readable
