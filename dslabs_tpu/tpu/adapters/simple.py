@@ -341,9 +341,19 @@ def match_clientserver(state):
 
 class PrimaryBackupBinding(TwinBinding):
     """Lab 2: ViewServer + NS PBServers + NC ClientWorker(PBClient)s with
-    finite KV workloads; twin node indices: viewserver 0, server{s} -> s,
-    client c -> NS + 1 + c (tpu/protocols/primarybackup.py lane table).
-    The StateTransfer's full application payload — the one field the twin
+    finite KV workloads, on the COMPILED twin (tpu/specs.py ``pb_spec``;
+    the hand twin tpu/protocols/primarybackup.py stays the CI oracle of
+    tests/test_compiler.py and is not built here).  Twin node indices:
+    viewserver 0, server{s} -> s, client c -> NS + 1 + c.  Lanes, tags
+    and timer lengths are read off the spec's own layout, never from
+    hand offsets.
+
+    ``shared_key``: every client's one command is an APPEND to ONE key
+    (test18 / test20's ``append_same_key_workload(1)``).  The twin then
+    carries the order the applications ran the APPENDs in (``pb_spec``'s
+    docstring), which makes it exact count for count, and the decoders
+    rebuild replies and state transfers with their values.  Otherwise
+    the StateTransfer's application payload — the one field the twin
     collapses to per-client AMO seqs — resolves from the replayed object
     state's network, discriminated by (view_num, per-client last-executed
     seqs), which is exact within the twin's collapse."""
@@ -375,9 +385,20 @@ class PrimaryBackupBinding(TwinBinding):
                 f"per-client workload sizes differ ({sizes})")
         self.w = sizes.pop()
         self.pairs = pairs
+        self.shared_key = self._shares_a_key(pairs)
         self.key = ("primarybackup", self.vs_name,
                     tuple(self.server_names), tuple(self.client_names),
                     tuple(repr(c) for p in pairs for c, _ in p))
+
+    @staticmethod
+    def _shares_a_key(pairs) -> bool:
+        """Two clients or more, one APPEND each, all to one key."""
+        from dslabs_tpu.labs.clientserver.kvstore import Append
+
+        cmds = [c for p in pairs for c, _ in p]
+        return (len(pairs) > 1 and len(cmds) == len(pairs)
+                and all(isinstance(c, Append) for c in cmds)
+                and len({c.key for c in cmds}) == 1)
 
     def initial_caps(self):
         return 32, 4
@@ -385,15 +406,28 @@ class PrimaryBackupBinding(TwinBinding):
     def twin_key(self):
         # The Reply decoder's fallback reads the expected results.
         return self.key + (tuple(repr(r) for p in self.pairs
-                                 for _, r in p),)
+                                 for _, r in p), self.shared_key)
+
+    def _spec(self, net_cap=32, timer_cap=4):
+        from dslabs_tpu.tpu.specs import pb_spec
+
+        return pb_spec(ns=self.ns, n_clients=self.nc, w=self.w,
+                       net_cap=net_cap, timer_cap=timer_cap,
+                       shared_key=self.shared_key)
+
+    def _layout(self):
+        """What the decoders and predicates read off the spec, whatever
+        its caps: (message tag -> name, timer tag -> name, (kind,
+        instance, field) -> node lane)."""
+        got = getattr(self, "_spec_layout", None)
+        if got is None:
+            got = self._spec_layout = self._spec().decode_tables()
+        return got
 
     def build_protocol(self, net_cap, timer_cap):
-        from dslabs_tpu.tpu.protocols.primarybackup import make_pb_protocol
-
-        p = make_pb_protocol(ns=self.ns, n_clients=self.nc, w=self.w,
-                             net_cap=net_cap, timer_cap=timer_cap)
         return dataclasses.replace(
-            p, decode_message=self._decode_message,
+            self._spec(net_cap, timer_cap).compile(),
+            decode_message=self._decode_message,
             decode_timer=self._decode_timer)
 
     # ------------------------------------------------------------ decoders
@@ -418,89 +452,102 @@ class PrimaryBackupBinding(TwinBinding):
         return AMOCommand(self.pairs[c][s - 1][0],
                           LocalAddress(self.client_names[c]), s)
 
+    def _appended(self, ranks) -> str:
+        """The shared key's value after the APPENDs ``ranks`` orders
+        (``ranks[c]``: client c's rank, 0 = not run)."""
+        ran = sorted((r, c) for c, r in enumerate(ranks) if r)
+        return "".join(self.pairs[c][0][0].value for _, c in ran)
+
     def _decode_message(self, rec):
         from dslabs_tpu.labs.clientserver.amo import AMOResult
+        from dslabs_tpu.labs.clientserver.kvstore import AppendResult
         from dslabs_tpu.labs.primarybackup import pb as P
         from dslabs_tpu.labs.primarybackup import viewserver as V
-        from dslabs_tpu.tpu.protocols.primarybackup import (
-            FWD, FWDACK, GETVIEW, PING, REPLY, REQ, VIEWREPLY, XFER,
-            XFERACK)
         from dslabs_tpu.tpu.trace import MessageTemplate
 
         r = [int(x) for x in rec]
-        tag, frm, to, p = r[0], r[1], r[2], r[3:]
+        frm, to, p = r[1], r[2], r[3:]
+        name = self._layout()[0].get(r[0])
         fa, ta = self._addr(frm), self._addr(to)
-        if tag == PING:
+        if name == "PING":
             return fa, ta, V.Ping(p[0])
-        if tag == GETVIEW:
+        if name == "GETVIEW":
             return fa, ta, V.GetView()
-        if tag == VIEWREPLY:
+        if name == "VIEWREPLY":
             return fa, ta, V.ViewReply(self._view(p[0], p[1], p[2]))
-        if tag == REQ:
+        if name == "REQ":
             return fa, ta, P.Request(self._amo(p[0], p[1]))
-        if tag == REPLY:
+        if name == "REPLY":
             c, s = p[0], p[1]
+            if self.shared_key:
+                return fa, ta, P.Reply(AMOResult(AppendResult(
+                    self._appended(p[2:2 + self.nc])), s))
             fallback = P.Reply(AMOResult(self.pairs[c][s - 1][1], s))
             return fa, ta, MessageTemplate(
                 P.Reply, fallback,
                 lambda m, s=s: m.result.sequence_num == s)
-        if tag == FWD:
+        if name == "FWD":
             return fa, ta, P.ForwardRequest(p[0], self._amo(p[1], p[2]))
-        if tag == FWDACK:
+        if name == "FWDACK":
             return fa, ta, P.ForwardAck(p[0], self._amo(p[1], p[2]))
-        if tag == XFER:
-            vn, amo = p[0], p[3:3 + self.nc]
+        if name == "XFER":
+            vn, app = p[0], tuple(p[3:3 + self.nc])
+            if self.shared_key:
+                # the payload is ranks (w = 1: a seq is "ran or not"),
+                # and the store's value says the order
+                stored = (self.pairs[0][0][0].key, self._appended(app))
+                app = tuple(int(rank > 0) for rank in app)
+            else:
+                stored = None
 
-            def match(m, vn=vn, amo=tuple(amo)):
+            def match(m, vn=vn, seqs=app, stored=stored):
                 from dslabs_tpu.core.address import LocalAddress
 
                 if m.view.view_num != vn:
                     return False
-                for c, want in enumerate(amo):
+                for c, want in enumerate(seqs):
                     got = m.app.last.get(
                         LocalAddress(self.client_names[c]))
                     if (got[0] if got else 0) != want:
                         return False
-                return True
+                return (stored is None or m.app.application.store.get(
+                    stored[0], "") == stored[1])
 
             return fa, ta, MessageTemplate(P.StateTransfer, None, match)
-        if tag == XFERACK:
+        if name == "XFERACK":
             return fa, ta, P.StateTransferAck(p[0])
-        raise NoTensorTwin(f"unknown pb message tag {tag}")
+        raise NoTensorTwin(f"unknown pb message tag {r[0]}")
 
     def _decode_timer(self, node_idx, rec):
         from dslabs_tpu.labs.primarybackup import pb as P
         from dslabs_tpu.labs.primarybackup import viewserver as V
-        from dslabs_tpu.tpu.protocols.primarybackup import (
-            CLIENT_MS, PING_MS, PINGCHECK_MS, T_CLIENT, T_PING,
-            T_PINGCHECK)
 
-        tag, p0 = int(rec[0]), int(rec[3])
+        name = self._layout()[1].get(int(rec[0]))
+        lo, hi, p0 = int(rec[1]), int(rec[2]), int(rec[3])
         a = self._addr(node_idx)
-        if tag == T_PINGCHECK:
-            return a, V.PingCheckTimer(), PINGCHECK_MS, PINGCHECK_MS
-        if tag == T_PING:
-            return a, P.PingTimer(), PING_MS, PING_MS
-        if tag == T_CLIENT:
+        if name == "PINGCHECK":
+            return a, V.PingCheckTimer(), lo, hi
+        if name == "PING":
+            return a, P.PingTimer(), lo, hi
+        if name == "CLIENT":
             c = int(node_idx) - 1 - self.ns
-            return a, P.ClientTimer(self._amo(c, p0)), CLIENT_MS, CLIENT_MS
-        raise NoTensorTwin(f"unknown pb timer tag {tag}")
+            return a, P.ClientTimer(self._amo(c, p0)), lo, hi
+        raise NoTensorTwin(f"unknown pb timer tag {int(rec[0])}")
 
     # ---------------------------------------------------------- predicates
 
     def predicate(self, tkey):
         import jax.numpy as jnp
 
-        from dslabs_tpu.tpu.protocols.primarybackup import make_pb_protocol  # noqa: F401
-
         kind = tkey[0]
-        ns, nc, w = self.ns, self.nc, self.w
-        VSW = 5 + 2 * ns
-        SW = 6 + nc
-        cb = VSW + ns * SW
+        nc, w = self.nc, self.w
+        table = self._layout()[2]
+
+        def lane(node_kind, i, field):
+            return table[(node_kind, i, field)]
 
         def k(s, c):
-            return s["nodes"][cb + c * 4]
+            return s["nodes"][lane("client", c, "k")]
 
         if kind in ("RESULTS_OK", "RESULTS_LINEARIZABLE",
                     "ALL_RESULTS_SAME"):
@@ -527,17 +574,20 @@ class PrimaryBackupBinding(TwinBinding):
         if kind == "CLIENT_HAS_RESULTS":
             c = self.client_names.index(str(tkey[1].root_address()))
             return lambda s: k(s, c) >= tkey[2] + 1
+
+        def srv(s, i, field):
+            return s["nodes"][lane("server", i, field)]
+
         if kind == "PB_PROMOTED":
             # A named server serves a view with itself primary, no
             # backup, synced (the failover goal, test19).
             pi = self.server_names.index(tkey[1]) + 1
 
             def fn(s):
-                def srv(i, off):
-                    return s["nodes"][VSW + i * SW + off]
-                return ((srv(pi - 1, 1) == pi) & (srv(pi - 1, 2) == 0)
-                        & (srv(pi - 1, 3) == 1)
-                        & (srv(pi - 1, 0) > 0))
+                return ((srv(s, pi - 1, "sp") == pi)
+                        & (srv(s, pi - 1, "sb") == 0)
+                        & (srv(s, pi - 1, "sync") == 1)
+                        & (srv(s, pi - 1, "svn") > 0))
             return fn
         if kind == "PB_VIEW_SYNCED":
             # The lab tests' staged goal: the NAMED primary reports view
@@ -551,15 +601,14 @@ class PrimaryBackupBinding(TwinBinding):
             want_acked = len(tkey) > 4 and tkey[4] == "acked"
 
             def fn(s):
-                def srv(i, off):
-                    return s["nodes"][VSW + i * SW + off]
-                ok = ((srv(pi - 1, 0) == vn) & (srv(pi - 1, 1) == pi)
-                      & (srv(pi - 1, 2) == bi) & (srv(pi - 1, 3) == 1)
-                      & (srv(bi - 1, 0) == vn) & (srv(bi - 1, 3) == 1))
+                ok = ((srv(s, pi - 1, "svn") == vn)
+                      & (srv(s, pi - 1, "sp") == pi)
+                      & (srv(s, pi - 1, "sb") == bi)
+                      & (srv(s, pi - 1, "sync") == 1)
+                      & (srv(s, bi - 1, "svn") == vn)
+                      & (srv(s, bi - 1, "sync") == 1))
                 if want_acked:
-                    # ViewServer acked flag (lane 3 of the master block,
-                    # tpu/protocols/primarybackup.py _unpack).
-                    ok = ok & (s["nodes"][3] == 1)
+                    ok = ok & (s["nodes"][lane("vs", 0, "acked")] == 1)
                 return ok
             return fn
         return None

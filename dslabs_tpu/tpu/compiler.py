@@ -651,6 +651,14 @@ class ProtocolSpec:
                 off += f.size
         return table, off
 
+    def decode_tables(self):
+        """What an adapter's decoders and predicates address a compiled
+        twin's rows by, whatever its caps: (message tag -> name, timer
+        tag -> name, (kind, instance, field) -> first node lane)."""
+        return ({tag: name for name, tag in self._mtag.items()},
+                {tag: name for name, tag in self._ttag.items()},
+                {k: off for k, (off, _) in self._layout()[0].items()})
+
     def _msg_row(self, name, frm, to, fields):
         import jax.numpy as jnp
 

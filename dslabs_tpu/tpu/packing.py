@@ -306,6 +306,29 @@ class LanePacking:
         return jnp.where((sent & ~raw)[None, :] & (bits == mask[None, :]),
                          _SENTINEL, val)
 
+    def rebase_words(self, base_old, base_new):
+        """[words] int32: what to ADD to every word of a packed row to
+        move it from ``base_old`` to ``base_new`` (both [lanes] int32
+        bias vectors; only the delta lanes are read).  A delta lane
+        stores ``value - base`` in its field, so the field changes by
+        ``base_old - base_new``; a word's fields are bit-disjoint, so
+        the shifted differences of its delta lanes sum to one addend,
+        two's complement.  Exact — bit for bit ``pack(unpack(row,
+        base_old), base_new)`` — while every re-based field stays in
+        its window: the CALLER's to know (tpu/sharded.py
+        ``_rebase_rows`` says why the level promote does)."""
+        import jax.numpy as jnp
+
+        lanes = self.delta_lanes
+        diff = (jnp.asarray(base_old, jnp.int32)
+                - jnp.asarray(base_new, jnp.int32))[jnp.asarray(lanes)]
+        shifted = jnp.left_shift(
+            diff, jnp.asarray(self.shift[lanes], jnp.int32))
+        onto = jnp.asarray(
+            self.word[lanes][:, None] == np.arange(self.words)[None, :])
+        return jnp.sum(jnp.where(onto, shifted[:, None], 0),
+                       axis=0).astype(jnp.int32)
+
     # ------------------------------------------------------ host path
 
     def _lo_eff_np(self, base) -> np.ndarray:

@@ -112,8 +112,9 @@ CARRY_PARTITION_RULES = (
     # Delta-encoding level bases (ISSUE 18 leg (b)): one [n_delta]
     # int32 vector per device, value-replicated by construction (the
     # chunk step pmin's them) but stored per-device so the carry stays
-    # uniformly sharded and donation-friendly.
-    (r"^(pb_cur|pb_nxt)$", lambda ax: P(ax)),
+    # uniformly sharded and donation-friendly.  pb_peak: one int32 a
+    # device, the level's largest ``value - base`` so far.
+    (r"^(pb_cur|pb_nxt|pb_peak)$", lambda ax: P(ax)),
     # Per-device scalar lanes: occupancies, loop counters, stats.
     (r"^(cur_n|nxt_n|vis_n|j|evp|noapp|explored|overflow|vis_over"
      r"|drops|f_full)$", lambda ax: P(ax)),
@@ -322,6 +323,9 @@ class ShardedTensorSearch(TensorSearch):
         # (in_shardings/out_shardings): placement is an explicit
         # contract, not an inference XLA re-derives per dispatch.
         self._finish_level = self._sharded_jit(self._build_finish())
+        if self._mesh_delta:
+            self._finish_rebase = self._sharded_jit(
+                self._build_finish(rebase=True))
         self._superstep = self._superstep_jit()
         # Chunk-step budget per superstep dispatch when a wall-clock
         # budget is active: bounds device work between host clock checks
@@ -354,7 +358,7 @@ class ShardedTensorSearch(TensorSearch):
         if self._spill_on:
             keys += ["f_full"]
         if self._mesh_delta:
-            keys += ["pb_cur", "pb_nxt"]
+            keys += ["pb_cur", "pb_nxt", "pb_peak"]
         return keys
 
     def _bucket(self) -> int:
@@ -381,7 +385,10 @@ class ShardedTensorSearch(TensorSearch):
     # frontier / accumulating next frontier of every ("delta", bits)
     # lane.  The chunk step pmin's candidate minima across devices, so
     # the per-device copies are value-identical by construction and the
-    # promote's re-encode needs no collective.
+    # promote's re-encode needs no collective.  pb_peak [1] is the
+    # largest ``value - pb_cur`` any live successor's delta lane held in
+    # this level's chunk steps on this device: how much of the lanes'
+    # window the level used (the level record's ``delta_peak``).
     _PB_EMPTY = np.int32(2 ** 31 - 1)
 
     def _base_vec(self, pb):
@@ -555,6 +562,9 @@ class ShardedTensorSearch(TensorSearch):
                     # bound of the stored subset — a valid (just possibly
                     # looser) base.
                     dvals = rows[:, jnp.asarray(self._delta_lanes)]
+                    pb_peak = jnp.maximum(carry["pb_peak"], jnp.max(
+                        jnp.where(valids[:, None],
+                                  dvals - carry["pb_cur"][None, :], 0)))
                     dvals = jnp.where(valids[:, None], dvals,
                                       jnp.int32(self._PB_EMPTY))
                     cand = jnp.min(dvals, axis=0).astype(jnp.int32)
@@ -743,6 +753,7 @@ class ShardedTensorSearch(TensorSearch):
                 if delta:
                     out["pb_cur"] = carry["pb_cur"]
                     out["pb_nxt"] = pb_nxt
+                    out["pb_peak"] = pb_peak
                 if spill_on:
                     front_full = (nxt_n + jnp.sum(sel).astype(jnp.int32)
                                   ) > F
@@ -757,7 +768,7 @@ class ShardedTensorSearch(TensorSearch):
                               "vis_n", "explored", "overflow", "vis_over",
                               "drops", "flag_cnt", "flag_rows"]
                     if delta:
-                        revert.append("pb_nxt")
+                        revert += ["pb_nxt", "pb_peak"]
                     for k in revert:
                         out[k] = jnp.where(abort, carry[k], out[k])
                     out["f_full"] = jnp.where(abort, code,
@@ -789,8 +800,8 @@ class ShardedTensorSearch(TensorSearch):
         Returns ``(carry', stats)`` where ``stats`` is the fused scalar
         vector _sync_checks parses: 8 scalars, the n_flags counts,
         ``[remaining_devices, steps_taken, write_blocks, probe_cols]``,
-        the spill abort code when the host tier is wired, and the
-        per-device lanes.
+        the spill abort code when the host tier is wired, under a delta
+        descriptor ``[base moved, delta_peak]``, and the per-device lanes.
         Computing the stats in-program (psum/pmax over the mesh axis)
         folds the level sync into the same dispatch: host involvement
         per level is superstep + promote."""
@@ -802,6 +813,7 @@ class ShardedTensorSearch(TensorSearch):
             return jax.lax.psum(x, ax)
 
         spill_on = self._spill is not None
+        delta = self._mesh_delta
 
         def stats_local(c, steps, dedup):
             core = jnp.stack([
@@ -831,6 +843,18 @@ class ShardedTensorSearch(TensorSearch):
                 # fleet's (pmax for robustness).
                 parts.append(jax.lax.pmax(
                     c["f_full"], ax).astype(jnp.int32))
+            if delta:
+                # What the promote will do with this level's rows, and
+                # how much of the delta window the level used — known
+                # here, so the host picks the promote's program and
+                # writes the level's record from the readback it makes
+                # anyway.  pb_nxt is pmin'd every chunk step: any
+                # device's copy is the mesh's.
+                moved = jnp.any((c["pb_nxt"] != jnp.int32(self._PB_EMPTY))
+                                & (c["pb_nxt"] != c["pb_cur"]))
+                parts.append(jnp.stack([
+                    moved.astype(jnp.int32),
+                    jax.lax.pmax(c["pb_peak"][0], ax)]).astype(jnp.int32))
             # Per-device stats lanes (ISSUE 8), LAST so all absolute
             # index parses above stay valid: one all_gather inside the
             # SAME fused program — the replicated stats vector simply
@@ -909,7 +933,7 @@ class ShardedTensorSearch(TensorSearch):
             return self._dispatch("sharded.superstep", run, carry, b, rt)
         return self._dispatch("sharded.superstep", run, carry, b)
 
-    def _build_finish(self):
+    def _build_finish(self, rebase: bool = False):
         """Promote nxt -> cur between levels.  Successors already landed
         on their owner's shard inside the superstep, so the promote is a
         LOCAL buffer SWAP — no collective, no compaction, no word moved:
@@ -920,45 +944,40 @@ class ShardedTensorSearch(TensorSearch):
         place — inside the program the donated carry pairs each input
         with the output of its own name, and a swap would be three
         frontier-sized copies); this program, handed the swapped carry,
-        moves the counters and, under a delta descriptor, re-bases the
-        promoted rows."""
+        moves the counters.
+
+        Under a delta descriptor the promoted rows were packed against
+        the OLD level base.  Where the next level's base is that same
+        base — the superstep's stats say so before the promote is
+        dispatched — this program is all there is.  Where it moved, the
+        host dispatches the ``rebase`` program instead: the counters, and
+        :meth:`_rebase_rows` over the occupied prefix of the log."""
         F = self.f_cap
-        plane = self.plane
-        pk = self._pk
         delta = self._mesh_delta
 
-        def promote(carry):
+        def finish(carry):
             with tel_mod.device_scope("promote"):
                 carry = dict(carry)
                 carry["cur_n"] = carry["nxt_n"]
                 if delta:
-                    # Delta re-base (ISSUE 18 leg (b)): the promoted rows
-                    # were packed against the OLD level base; re-encode them
-                    # against the accumulated next-level base (pb_nxt, a
-                    # global pmin computed inside the chunk steps — already
-                    # value-identical on every device, so this stays
-                    # elementwise: the promote keeps ZERO collectives).
+                    # A lane whose pb_nxt never saw a successor (empty
+                    # next frontier) keeps the old base.  pb_nxt is a
+                    # global pmin computed inside the chunk steps —
+                    # value-identical on every device, so the promote
+                    # keeps ZERO collectives.
                     pb_old = carry["pb_cur"]
-                    # A lane whose pb_nxt never saw a successor (empty next
-                    # frontier) keeps the old base so the (vacuous)
-                    # re-encode stays in-window.
-                    pb_new = jnp.where(
-                        carry["pb_nxt"] == jnp.int32(self._PB_EMPTY),
-                        pb_old, carry["pb_nxt"])
-                    raw_rows = pk.unpack_jnp(
-                        carry["cur"][:F * plane].reshape(F, plane),
-                        self._base_vec(pb_old))
-                    repacked, bad = pk.pack_jnp(raw_rows,
-                                                self._base_vec(pb_new),
-                                                count_bad=True)
-                    occ = jnp.arange(F) < carry["cur_n"][0]
-                    carry["cur"] = jax.lax.dynamic_update_slice(
-                        carry["cur"], repacked.reshape(-1), (0,))
-                    carry["overflow"] = carry["overflow"].at[0].add(
-                        jnp.sum(jnp.where(occ, bad, 0)).astype(jnp.int32))
-                    carry["pb_cur"] = pb_new
+                    if rebase:
+                        pb_new = jnp.where(
+                            carry["pb_nxt"] == jnp.int32(self._PB_EMPTY),
+                            pb_old, carry["pb_nxt"])
+                        with tel_mod.device_scope("promote.rebase"):
+                            carry["cur"] = self._rebase_rows(
+                                carry["cur"], carry["cur_n"][0], pb_old,
+                                pb_new)
+                        carry["pb_cur"] = pb_new
                     carry["pb_nxt"] = jnp.full_like(
                         pb_old, jnp.int32(self._PB_EMPTY))
+                    carry["pb_peak"] = jnp.zeros((1,), jnp.int32)
                 carry["nxt_n"] = jnp.zeros((1,), jnp.int32)
                 carry["j"] = jnp.zeros((1,), jnp.int32)
                 carry["evp"] = jnp.zeros((1,), jnp.int32)
@@ -967,15 +986,55 @@ class ShardedTensorSearch(TensorSearch):
                     carry["tmeta"] = jnp.zeros((F + 1, 9), jnp.uint32)
                 return carry
 
+        # The function's name is the program's in a profile
+        # (``jit_promote``, ``jit_promote_rebase``).
+        finish.__name__ = "promote_rebase" if rebase else "promote"
         spec = self._carry_specs()
-        return shard_map(promote, mesh=self.mesh,
+        return shard_map(finish, mesh=self.mesh,
                          in_specs=(spec,), out_specs=spec,
                          check_vma=False)
 
-    def _promote_call(self, carry):
+    def _rebase_rows(self, log, n, pb_old, pb_new):
+        """Re-encode the first ``n`` rows of a frontier log from the
+        level base ``pb_old`` to ``pb_new`` (both [n_delta]).
+
+        A delta lane stores ``value - base`` in its bit field, so a new
+        base changes the field by ``pb_old - pb_new`` and nothing else
+        of the row: ONE add a word (``LanePacking.rebase_words``: the
+        shifted differences of a word's delta lanes, summed).  The add
+        is exact while every field stays inside its window, and it does:
+        the chunk steps packed every live successor against ``pb_old``
+        and counted what fell outside (``pack_bad``, raised at the level
+        sync, before any promote), and ``pb_new`` is the minimum over
+        those very successors, so ``0 <= value - pb_new <= value -
+        pb_old``.  No row is unpacked: the program holds no
+        ``[rows, lanes]`` intermediate and no column of a row.
+
+        Only the OCCUPIED prefix is touched, a block of the append's
+        ``K`` rows at a time (the log's slack, :meth:`_log_words`, is
+        one such block, so the last block lies inside the buffer; what
+        it re-encodes past row ``n`` is words nothing reads)."""
+        plane = self.plane
+        K = visited_mod.block_width(self.n_devices * self._bucket())
+        step = jnp.tile(self._pk.rebase_words(
+            self._base_vec(pb_old), self._base_vec(pb_new)), K)
+
+        def block(i, log):
+            at = i * (K * plane)
+            rows = jax.lax.dynamic_slice(log, (at,), (K * plane,))
+            return jax.lax.dynamic_update_slice(log, rows + step, (at,))
+
+        return jax.lax.fori_loop(0, (n + K - 1) // K, block, log)
+
+    def _promote_call(self, carry, rebase: bool = False):
         """Promote through the supervisor boundary: swap the two logs
-        (same shape, same placement) and dispatch the level's reset."""
+        (same shape, same placement) and dispatch the level's reset —
+        with the re-base where the level's stats said the base moved."""
         carry = dict(carry, cur=carry["nxt"], nxt=carry["cur"])
+        if rebase:
+            return self._dispatch(
+                "sharded.promote_rebase",
+                self._prog("promote_rebase", self._finish_rebase), carry)
         return self._dispatch(
             "sharded.promote",
             self._prog("promote", self._finish_level), carry)
@@ -1083,6 +1142,7 @@ class ShardedTensorSearch(TensorSearch):
                 out["pb_cur"] = jnp.tile(pb0, D)
                 out["pb_nxt"] = jnp.full(
                     (D * pb0.shape[0],), jnp.int32(self._PB_EMPTY))
+                out["pb_peak"] = jnp.zeros((D,), jnp.int32)
             return out
 
         fn = jax.jit(init_carry, out_shardings=self._carry_shardings())
@@ -1129,6 +1189,7 @@ class ShardedTensorSearch(TensorSearch):
             nd = len(self._delta_lanes)
             out["pb_cur"] = sd("pb_cur", (D * nd,))
             out["pb_nxt"] = sd("pb_nxt", (D * nd,))
+            out["pb_peak"] = sd("pb_peak", (D,))
         return out
 
     def aot_warmup(self) -> float:
@@ -1177,6 +1238,9 @@ class ShardedTensorSearch(TensorSearch):
             compile_("superstep", "superstep", self._superstep,
                      sds, b, *mask_args)
             compile_("promote", "promote", self._finish_level, sds)
+            if self._mesh_delta:
+                compile_("promote_rebase", "promote_rebase",
+                         self._finish_rebase, sds)
             # The root program compiles where it is first called: here,
             # on the twin's own root, so that run() finds it compiled.
             args, owner, home = self._root_ids(
@@ -1253,6 +1317,12 @@ class ShardedTensorSearch(TensorSearch):
             fn=self._finish_level, args=(sds,), donate=(0,),
             multi=True,
             builder=lambda: self._sharded_jit(self._build_finish()))
+        if self._mesh_delta:
+            sites["sharded.promote_rebase"] = dict(
+                fn=self._finish_rebase, args=(sds,), donate=(0,),
+                multi=True,
+                builder=lambda: self._sharded_jit(
+                    self._build_finish(rebase=True)))
         # The bucket-probe kernel (ISSUE 12): the ACTIVE visited.insert
         # variant (Pallas or jnp per DSLABS_VISITED_PALLAS) as a
         # standalone single-device program over one owner-side dedup
@@ -1622,6 +1692,7 @@ class ShardedTensorSearch(TensorSearch):
                 out["pb_cur"] = jnp.asarray(pb0, jnp.int32)
                 out["pb_nxt"] = jnp.full((len(pb0),), jnp.int32(
                     self._PB_EMPTY))
+                out["pb_peak"] = jnp.zeros((1,), jnp.int32)
             return out, jnp.sum(unres).astype(jnp.int32)[None]
 
         ax = self.axis
@@ -2046,6 +2117,32 @@ class ShardedTensorSearch(TensorSearch):
                         # gap the run()-level telemetry event makes loud.
                         "pack_ratio": (round(self._pk.pack_ratio, 3)
                                        if self._pk is not None else 1.0)}
+                    rebase = False
+                    if self._mesh_delta:
+                        # Delta lanes (the stats of the level's last
+                        # superstep): ``rebased`` 1 where the next
+                        # level's base differs from this one's, so that
+                        # the promote below re-encodes the
+                        # ``rebase_rows`` rows the level appended (over
+                        # the mesh); ``delta_peak``
+                        # the largest value - base a live successor's
+                        # delta lane held, of a window of 2^bits - 1.
+                        moved, peak = self._last_delta
+                        # Not with the spill tier wired: whether this
+                        # level ends in the promote or in a drain and a
+                        # re-inject is decided below, after this record
+                        # is written, and the re-inject packs a spooled
+                        # segment against pb_cur as it stands.  Not
+                        # re-basing is always sound; the window is not
+                        # re-centred, and a lane past it raises.
+                        rebase = bool(moved) and not (
+                            noapp_level or self._spill_on)
+                        delta_rec = dict(
+                            rebased=int(rebase), delta_peak=peak,
+                            rebase_rows=(sum(self._last_per_device[
+                                "frontier"]) if rebase else 0))
+                        rec.update(delta_rec)
+                        lvl.set(**delta_rec)
                     # Mesh-scope lanes (ISSUE 8): the pre-psum per-device
                     # scalars the fused stats vector already carried, plus
                     # skew metrics — what the owner-hashed all_to_all
@@ -2154,7 +2251,7 @@ class ShardedTensorSearch(TensorSearch):
                     seg = sp.spool_cur.pop()
                     carry, max_n = self._sh_spill_inject(carry, seg)
                     continue
-                carry = self._promote_call(carry)
+                carry = self._promote_call(carry, rebase)
                 if (self.checkpoint_every and self.checkpoint_path
                         and depth % self.checkpoint_every == 0):
                     self._save_checkpoint(carry, depth, time.time() - t0,
@@ -2315,6 +2412,9 @@ class ShardedTensorSearch(TensorSearch):
         self._last_per_device = {
             "explored": pd[:D], "vis_n": pd[D:2 * D],
             "frontier": pd[2 * D:3 * D], "drops": pd[3 * D:]}
+        if self._mesh_delta:
+            # [base moved, delta_peak], just before the per-device lanes.
+            self._last_delta = (int(s[-4 * D - 2]), int(s[-4 * D - 1]))
         # Running total for outcome plumbing (SearchOutcome
         # .visited_overflow): keys the full table degraded to
         # treat-as-fresh — sound, but unique counts may over-report.
@@ -2352,7 +2452,10 @@ class ShardedTensorSearch(TensorSearch):
         if overflow:
             raise CapacityOverflow(
                 f"{self.p.name}: {overflow} semantic drops at depth "
-                f"{depth} (net_cap/timer_cap overflowed; raise the caps)")
+                f"{depth} (net_cap/timer_cap overflowed; raise the caps"
+                + ("; or a delta lane's value left the window above its "
+                   "level base: raise the Field(delta=) bits"
+                   if self._mesh_delta else "") + ")")
         if drops and self.strict:
             raise CapacityOverflow(
                 f"{self.p.name}: {drops} capacity drops at depth "

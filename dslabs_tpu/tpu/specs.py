@@ -25,6 +25,9 @@ from __future__ import annotations
 from dslabs_tpu.tpu.compiler import (Field, MessageType, NodeKind,
                                      ProtocolSpec, TimerType)
 
+# (every name here is a spec FACTORY: the conformance linter calls each,
+# dslabs_tpu/analysis/conformance.py ``lint_specs``;
+# ``compile_pb_protocol`` returns a compiled twin and stays out)
 __all__ = ["pingpong_spec", "clientserver_spec", "pb_spec",
            "paxos_spec", "paxos_partition_spec", "pb_crash_spec"]
 
@@ -150,7 +153,8 @@ def clientserver_spec(n_clients: int = 1, w: int = 1) -> ProtocolSpec:
 
 
 def pb_spec(ns: int = 2, n_clients: int = 1, w: int = 1,
-            fault=None) -> ProtocolSpec:
+            net_cap: int = 32, timer_cap: int = 4,
+            shared_key: bool = False, fault=None) -> ProtocolSpec:
     """Lab 2 primary-backup: ViewServer + PBServers + clients — the
     first STATEFUL multi-role protocol through the compiler (round-4
     verdict item 7: "a new protocol becomes searchable without
@@ -160,10 +164,33 @@ def pb_spec(ns: int = 2, n_clients: int = 1, w: int = 1,
     labs/primarybackup/{viewserver,pb}.py: first-ping-rank idle
     selection, ack-before-view-change, primary state transfer with
     refusal to serve until acked, one-outstanding-op forwarding, and the
-    client's view re-poll on every retry."""
+    client's view re-poll on every retry.
+
+    ``shared_key`` is for workloads whose clients APPEND to ONE key
+    (PrimaryBackupTest test18 / test20: ``append_same_key_workload(1)``).
+    The default twin collapses an application to one last-executed seq
+    per client, which is exact while every client writes a key of its
+    own; two APPENDs to one key leave ``xy`` or ``yx`` in the store, in
+    the replies and in the clients' results, and the object checker
+    tells those states apart.  With the flag on, every application
+    carries the ORDER it executed the clients' commands in (``ord[c]``:
+    the rank of client c's APPEND, 0 while it has not run), the state
+    transfer ships that order, and a REPLY — and the client that takes
+    it — holds the result it stands for: the order up to and including
+    the command (``r{c}``).  One command a client (``w`` = 1): a longer
+    same-key workload needs the order of every command, not of every
+    client.  With the flag off the spec is lane for lane what it was."""
     NS, NC = ns, n_clients
     DEAD = 2
-    amo_fields = tuple(f"a{c}" for c in range(NC))
+    if shared_key and w != 1:
+        raise ValueError(
+            f"pb_spec(shared_key=True) models one APPEND a client, "
+            f"not w={w}")
+    # The state transfer's application payload: last-executed seqs, or
+    # (w = 1, so a seq is "ran or not") the order they ran in.
+    amo_fields = tuple(f"{'o' if shared_key else 'a'}{c}"
+                       for c in range(NC))
+    res_fields = tuple(f"r{c}" for c in range(NC)) if shared_key else ()
     # Declared domains (ISSUE 15): server/client ids, sync/acked bits,
     # amo seqs, and rank are all tiny.  View numbers (vn/svn/cvn)
     # genuinely grow with depth and defeat a static hi= — they carry
@@ -174,9 +201,13 @@ def pb_spec(ns: int = 2, n_clients: int = 1, w: int = 1,
     # from the level base without bound, so a delta window would
     # overflow (loudly) on exactly the executions lab2 must explore.
     sid, cid, seq = (0, NS), (0, max(NC - 1, 0)), (0, w)
-    amo_b = {f: seq for f in amo_fields}
+    rank = (0, NC)
+    amo_b = {f: rank if shared_key else seq for f in amo_fields}
+    res_b = {f: rank for f in res_fields}
+    ord_field = (Field("ord", size=NC, hi=NC),) if shared_key else ()
+    res_field = (Field("res", size=NC, hi=NC),) if shared_key else ()
     spec = ProtocolSpec(
-        "pb-gen",
+        "pb-gen-shared" if shared_key else "pb-gen",
         nodes=[NodeKind("vs", 1, (
                    Field("vn", delta=8), Field("prim", hi=NS),
                    Field("back", hi=NS),
@@ -188,19 +219,20 @@ def pb_spec(ns: int = 2, n_clients: int = 1, w: int = 1,
                    Field("sb", hi=NS),
                    Field("sync", init=1, hi=1), Field("pc", hi=NC),
                    Field("ps", hi=w),
-                   Field("amo", size=NC, hi=w))),
+                   Field("amo", size=NC, hi=w)) + ord_field),
                NodeKind("client", NC, (
                    Field("k", init=1, hi=w + 1),
                    Field("cvn", init=-1, delta=8),
-                   Field("cp", hi=NS), Field("cb", hi=NS)))],
+                   Field("cp", hi=NS), Field("cb", hi=NS))
+                   + res_field)],
         messages=[MessageType("PING", ("vn",)),
                   MessageType("GETVIEW", ()),
                   MessageType("VIEWREPLY", ("vn", "prim", "back"),
                               bounds={"prim": sid, "back": sid}),
                   MessageType("REQ", ("c", "s"),
                               bounds={"c": cid, "s": seq}),
-                  MessageType("REPLY", ("c", "s"),
-                              bounds={"c": cid, "s": seq}),
+                  MessageType("REPLY", ("c", "s") + res_fields,
+                              bounds={"c": cid, "s": seq, **res_b}),
                   MessageType("FWD", ("vn", "c", "s"),
                               bounds={"c": cid, "s": seq}),
                   MessageType("FWDACK", ("vn", "c", "s"),
@@ -214,7 +246,7 @@ def pb_spec(ns: int = 2, n_clients: int = 1, w: int = 1,
                 TimerType("PING", (), 25, 25),
                 TimerType("CLIENT", ("s",), 100, 100,
                           bounds={"s": seq})],
-        net_cap=32, timer_cap=4, fault=fault)
+        net_cap=net_cap, timer_cap=timer_cap, fault=fault)
 
     # ------------------------------------------------ ViewServer helpers
 
@@ -228,7 +260,9 @@ def pb_spec(ns: int = 2, n_clients: int = 1, w: int = 1,
         order; 0 if none (viewserver.py:112-116)."""
         import jax.numpy as jnp
 
-        rank, ticks = ctx.get("rank"), ctx.get("ticks")
+        # (a size-1 array field unpacks as a scalar: one server)
+        rank = jnp.atleast_1d(ctx.get("rank"))
+        ticks = jnp.atleast_1d(ctx.get("ticks"))
         prim, back = ctx.get("prim"), ctx.get("back")
         best_rank = jnp.full((), 1 << 30, jnp.int32)
         best = jnp.zeros((), jnp.int32)
@@ -293,6 +327,35 @@ def pb_spec(ns: int = 2, n_clients: int = 1, w: int = 1,
 
     # -------------------------------------------------- PBServer helpers
 
+    def app_state(ctx):
+        """What a state transfer ships of the application."""
+        if shared_key:
+            return {f"o{c}": ctx.get_at("ord", c) for c in range(NC)}
+        return {f"a{c}": ctx.get_at("amo", c) for c in range(NC)}
+
+    def app_execute(ctx, c, sq, when):
+        """The application runs client ``c``'s command ``sq`` (the AMO
+        layer has passed it as new): the last-executed seq moves and,
+        under ``shared_key``, the command takes the next rank."""
+        ctx.put_at("amo", c, sq, when=when)
+        if shared_key:
+            ran = 0
+            for o in range(NC):
+                ran = ran + (ctx.get_at("ord", o) > 0)
+            ctx.put_at("ord", c, ran + 1, when=when)
+
+    def app_result(ctx, c):
+        """The stored result of client ``c``'s last command, as REPLY
+        payload fields: the order up to and including it."""
+        if not shared_key:
+            return {}
+        mine = ctx.get_at("ord", c)
+        out = {}
+        for o in range(NC):
+            at = ctx.get_at("ord", o)
+            out[f"r{o}"] = at * (at <= mine)
+        return out
+
     def srv_adopt(ctx, vn, prim, back, can_send):
         """_adopt (pb.py:123-137); mutations ride ``vn > svn``."""
         sid = ctx.node_index()
@@ -307,9 +370,7 @@ def pb_spec(ns: int = 2, n_clients: int = 1, w: int = 1,
         ctx.put("sync", 0, when=(is_p & (back != 0)) | is_b)
         if can_send:
             ctx.send("XFER", back, when=is_p & (back != 0), vn=vn,
-                     prim=prim, back=back,
-                     **{f"a{c}": ctx.get_at("amo", c)
-                        for c in range(NC)})
+                     prim=prim, back=back, **app_state(ctx))
 
     @spec.on("server", "VIEWREPLY")
     def srv_viewreply(ctx, m):
@@ -324,13 +385,13 @@ def pb_spec(ns: int = 2, n_clients: int = 1, w: int = 1,
         already = serving & (sq <= amo_c)
         reply_cached = already & (sq == amo_c)
         solo = serving & ~already & (ctx.get("sb") == 0)
-        ctx.put_at("amo", c, sq, when=solo)
+        app_execute(ctx, c, sq, solo)
         can_fwd = (serving & ~already & (ctx.get("sb") != 0)
                    & (ctx.get("pc") == 0))
         ctx.put("pc", c + 1, when=can_fwd)
         ctx.put("ps", sq, when=can_fwd)
         ctx.send("REPLY", 1 + NS + c, when=reply_cached | solo, c=c,
-                 s=sq)
+                 s=sq, **app_result(ctx, c))
         ctx.send("FWD", ctx.get("sb"), when=can_fwd,
                  vn=ctx.get("svn"), c=c, s=sq)
 
@@ -340,8 +401,7 @@ def pb_spec(ns: int = 2, n_clients: int = 1, w: int = 1,
         ok = ((ctx.get("sb") == sid) & (m["vn"] == ctx.get("svn"))
               & (ctx.get("sync") == 1))
         fc, fs = m["c"], m["s"]
-        ctx.put_at("amo", fc, fs,
-                   when=ok & (fs > ctx.get_at("amo", fc)))
+        app_execute(ctx, fc, fs, ok & (fs > ctx.get_at("amo", fc)))
         ctx.send("FWDACK", m["_from"], when=ok, vn=m["vn"], c=fc, s=fs)
 
     @spec.on("server", "FWDACK")
@@ -353,9 +413,9 @@ def pb_spec(ns: int = 2, n_clients: int = 1, w: int = 1,
         ctx.put("pc", 0, when=ok)
         ctx.put("ps", 0, when=ok)
         reply = ok & (asq >= ctx.get_at("amo", ac))
-        ctx.put_at("amo", ac, asq,
-                   when=ok & (asq > ctx.get_at("amo", ac)))
-        ctx.send("REPLY", 1 + NS + ac, when=reply, c=ac, s=asq)
+        app_execute(ctx, ac, asq, ok & (asq > ctx.get_at("amo", ac)))
+        ctx.send("REPLY", 1 + NS + ac, when=reply, c=ac, s=asq,
+                 **app_result(ctx, ac))
 
     @spec.on("server", "XFER")
     def srv_xfer(ctx, m):
@@ -366,7 +426,11 @@ def pb_spec(ns: int = 2, n_clients: int = 1, w: int = 1,
         cur = mine & (ctx.get("svn") == m["vn"])
         install = cur & (ctx.get("sync") == 0)
         for c in range(NC):
-            ctx.put_at("amo", c, m[f"a{c}"], when=install)
+            if shared_key:
+                ctx.put_at("ord", c, m[f"o{c}"], when=install)
+                ctx.put_at("amo", c, m[f"o{c}"] > 0, when=install)
+            else:
+                ctx.put_at("amo", c, m[f"a{c}"], when=install)
         ctx.put("sync", 1, when=install)
         ctx.send("XFERACK", m["_from"], when=cur, vn=m["vn"])
 
@@ -393,7 +457,7 @@ def pb_spec(ns: int = 2, n_clients: int = 1, w: int = 1,
         ctx.send("XFER", ctx.get("sb"),
                  when=is_p & has_b & (sync == 0), vn=svn,
                  prim=ctx.get("sp"), back=ctx.get("sb"),
-                 **{f"a{c}": ctx.get_at("amo", c) for c in range(NC)})
+                 **app_state(ctx))
         ctx.send("FWD", ctx.get("sb"),
                  when=is_p & has_b & (sync == 1) & (ctx.get("pc") > 0),
                  vn=svn, c=ctx.get("pc") - 1, s=ctx.get("ps"))
@@ -421,6 +485,8 @@ def pb_spec(ns: int = 2, n_clients: int = 1, w: int = 1,
         k = ctx.get("k")
         match = (m["c"] == c) & (m["s"] == k) & (k <= w)
         ctx.put("k", k + 1, when=match)
+        for o in range(NC if shared_key else 0):
+            ctx.put_at("res", o, m[f"r{o}"], when=match)
         k2 = ctx.get("k")
         has_next = match & (k2 <= w)
         cp = ctx.get("cp")
@@ -456,6 +522,16 @@ def pb_spec(ns: int = 2, n_clients: int = 1, w: int = 1,
 
     spec.goals["CLIENTS_DONE"] = clients_done
     return spec
+
+
+def compile_pb_protocol(ns: int = 2, n_clients: int = 1, w: int = 1,
+                        net_cap: int = 32, timer_cap: int = 4,
+                        shared_key: bool = False):
+    """Lab 2's COMPILED twin: what the lab entry binds
+    (tpu/adapters/simple.py ``PrimaryBackupBinding``) and the factory
+    of the configuration ``lab2-primarybackup-s2c2``."""
+    return pb_spec(ns=ns, n_clients=n_clients, w=w, net_cap=net_cap,
+                   timer_cap=timer_cap, shared_key=shared_key).compile()
 
 
 def paxos_spec(n_acceptors: int = 3, quorum: int = 0,

@@ -141,6 +141,10 @@ DISPATCH_SITES = {
                                   program=True),
     "sharded.promote":       dict(hot=False, donated=True, multi=True,
                                   program=True),
+    # the promote of a level whose delta-lane base moved (twins with
+    # ``Field(delta=)``): the counters, and the re-base of the rows
+    "sharded.promote_rebase": dict(hot=False, donated=True, multi=True,
+                                   program=True),
     "sharded.init":          dict(hot=False, donated=False, multi=True,
                                   program=True),
     "sharded.spill_drain":   dict(hot=False, donated=True, multi=True,
@@ -366,7 +370,7 @@ class MetricsRegistry:
 # ``phase``/``mark``/``annotate`` are only ever handed one of these, and
 # tests/test_program_spans.py holds every name a run emits to it.
 # PERF.md section 3 says which per-layer metric reads which.
-AOT_PROGRAMS = ("superstep", "promote", "init_carry")
+AOT_PROGRAMS = ("superstep", "promote", "promote_rebase", "init_carry")
 # The lab entry's kept engines register themselves under this name
 # (tpu/backend.py ``_Engine``: ``serial``, ``as_text()``), apart from
 # the AOT executables that ``program_scopes`` reads.
@@ -407,7 +411,11 @@ PHASES = (
     # table's gather, a step's live blocks of that width) and, at the
     # start, ``frontier0`` (the frontier rows the level starts with, of
     # the device that holds most: ``chunks`` beyond ceil(frontier0 /
-    # chunk) are chunk steps re-run at a later event window)
+    # chunk) are chunk steps re-run at a later event window); from a
+    # twin with delta lanes (``Field(delta=)``) also ``rebased`` (1: the
+    # level base moved, so this level's promote re-encodes its rows),
+    # ``rebase_rows`` (the rows it re-encodes) and ``delta_peak`` (the
+    # largest value - base a live successor's delta lane held)
     "search.level",
     # the host's own work around a run's dispatches, each a ``with`` in
     # the frame that does it (ISSUE 38; benchmark/harness/
@@ -456,6 +464,9 @@ DEVICE_SCOPES = (
     "expand.events", "expand.handlers", "expand.canon", "fingerprint",
     "flags", "pack", "trace_meta", "route", "exchange", "visited_insert",
     "append", "level_sync", "promote",
+    # inside ``promote``, where a delta twin's level base moved: the
+    # re-encode of the promoted rows (tpu/sharded.py ``_rebase_rows``)
+    "promote.rebase",
     # the swarm's walk step (tpu/swarm.py): what only a walker does —
     # ids, logits and the categorical pick; the seed gather and the
     # ``where``s of restart resolution; the history's write

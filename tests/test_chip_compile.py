@@ -486,6 +486,75 @@ def test_shardkv_deep_programs_compile(topo):
     assert _table_sized_in_loops(text, limit=1 << 27) == []
 
 
+def test_pb_deep_programs_compile(topo):
+    """Lab 2's compiled twin (the benchmark's ``pb-deep`` cell, the
+    tree's one twin with delta lanes) as its driver builds it, at the
+    configuration's caps, for one described chip: superstep, both
+    promotes and root init compile and fit.  The carry is the two
+    frontier logs, the table and nothing of their size beside them.
+    The promote of a level whose base stayed moves counters only, as
+    every other twin's.  The promote of a level whose base MOVED
+    (``promote_rebase``) adds one word-wise add over the occupied
+    prefix, a block of the append's K rows at a time, written in place:
+    no temporaries to speak of, no ``[rows, 1]`` column, no
+    ``[rows, lanes]`` intermediate, no frontier-sized move.  (Until PR
+    47 the one promote unpacked and re-packed all ``frontier_cap`` rows
+    at every level: 38 GB of temporaries at 2^22 rows, headed by 128x
+    padded ``u32[4194304,1]`` columns — it did not compile.)"""
+    from benchmark.drivers.timeboxed_bfs import build_protocol
+    from benchmark.harness import manifest
+
+    cell = manifest.load_cell(
+        os.path.dirname(os.path.dirname(os.path.abspath(__file__))),
+        "pb-deep")
+    eng = cell.config["engine"]
+    search = ShardedTensorSearch(
+        build_protocol(cell.config["protocol"]),
+        _mesh(topo, 1), chunk_per_device=eng["chunk"],
+        frontier_cap=eng["frontier_cap"], visited_cap=eng["visited_cap"],
+        strict=True, ev_budget=tuple(eng["ev_budget"]))
+    assert (search.lanes, search.bytes_per_state) == (
+        cell.config["protocol"]["lanes"],
+        cell.config["protocol"]["packed_bytes_per_state"])
+    assert search._mesh_delta and len(search._delta_lanes) == cell.config[
+        "protocol"]["delta_lanes"]
+    exes = _aot(search)
+    exes["promote_rebase"] = search._aot_exes["promote_rebase"]
+    _fits(exes)
+    k = visited.block_width(eng["chunk"] * search._ev_slots)
+    frontier = eng["frontier_cap"] * search.plane
+    log = (eng["frontier_cap"] + k) * search.plane
+    for name in ("superstep", "promote", "promote_rebase"):
+        mem = exes[name].memory_analysis()
+        assert mem.temp_size_in_bytes < 10 ** 9, (name, mem)
+        text = exes[name].as_text()
+        for leaf in ("cur", "nxt"):
+            assert re.search(
+                rf"%c(?:arry)?__{leaf}__\S* = s32\[{log}\]\S* parameter\(",
+                text), (name, leaf)
+        assert _frontier_sized_moves(text, frontier) == [], name
+    for name in ("promote", "promote_rebase"):
+        mem = exes[name].memory_analysis()
+        assert mem.temp_size_in_bytes < 1 << 24, (name, mem)
+        # nothing shaped [rows, 1] or [rows, lanes] for a block's rows
+        # or more: the rows are never unpacked (the re-base's addend is
+        # one [K, words] tile, made once)
+        wide = [m.groups() for m in (
+            re.fullmatch(r"\w+\[(\d+),(\d+)\]", shape)
+            for shape in _shapes(exes[name].as_text()).values()) if m]
+        assert [rc for rc in wide if int(rc[0]) >= k and int(rc[1]) in (
+            1, search.lanes)] == [], (name, wide)
+    plain, rebase = (exes[n].as_text() for n in ("promote",
+                                                 "promote_rebase"))
+    # a level without a re-base: no loop, no write into a log
+    assert " while(" not in plain and "dynamic-update-slice" not in plain
+    # a re-base: one block of K rows sliced, added to and written back
+    assert re.search(rf"= s32\[{log}\]\S* dynamic-update-slice\(", rebase)
+    assert re.search(rf"s32\[{k * search.plane}\]\S* dynamic-slice\(", rebase)
+    assert "dslabs.promote.rebase" in rebase
+    assert "all-to-all" not in exes["superstep"].as_text()
+
+
 @pytest.mark.slow
 def test_shardkv_n3_deep_programs_compile(topo):
     """Lab 4's multi-server twin (the benchmark's ``shardkv-n3-deep``

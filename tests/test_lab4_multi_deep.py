@@ -328,8 +328,9 @@ def _the_manifest_reads_the_cell_where_the_issue_says(man, entry, traffic,
                          "source": "device_trace", "layer": "expand",
                          "moves": "states_per_s", "workloads": [CELL]}
         if m["name"] in reads:
-            # appended (PR 43's cell behind it where it reads the metric)
-            assert CELL in m["workloads"][-2:]
+            # appended (PR 43's and PR 47's cells behind it where they
+            # read the metric)
+            assert CELL in m["workloads"][-3:]
             assert os.path.isfile(os.path.join(
                 ROOT, "benchmark", "layer_metrics", m["name"] + ".py"))
     e2e = {m["name"] for m in man["end_to_end"]
@@ -341,14 +342,15 @@ def _the_manifest_reads_the_cell_where_the_issue_says(man, entry, traffic,
     assert man["workloads"][6]["name"] == CELL
     assert man["configs"][5]["name"] == entry["config"]
     # (PR 41 appended its one metric behind it, PR 43 its four, PR 44
-    # its one)
+    # its one, PR 47 its two)
     names = [m["name"] for m in man["per_layer"]]
     assert names[names.index("gpaxos_handlers_pct.deep"):] == [
         "gpaxos_handlers_pct.deep", "exe_store_hit_pct",
         "walk_us_per_step.swarm", "fresh_pct.swarm", "restarts_pct.swarm",
-        "round_roofline.swarm", "blocks_per_step.swarm"]
+        "round_roofline.swarm", "blocks_per_step.swarm",
+        "promote_us_per_state.deep", "rebased_levels_pct.deep"]
     assert [w["chips"] for w in man["workloads"]].count(4) == 1
-    assert len(man["workloads"]) == 8        # PR 43 appended one
+    assert len(man["workloads"]) == 9        # PRs 43 and 47: one each
 
 
 _CELL_CHECKS = [_both_files_say_what_the_manifest_says,

@@ -606,6 +606,10 @@ def test_every_device_scope_is_in_the_compiled_programs(compiled, walked):
     text = compiled._aot_exes["superstep"].as_text()
     walk = walked._round_exe.as_text()
     for scope in tel_mod.DEVICE_SCOPES:
+        if scope == "promote.rebase":
+            # only a twin with delta lanes has the program that names
+            # it: tests/test_delta_rebase.py holds it there
+            continue
         where = (compiled._aot_exes["promote"].as_text()
                  if scope == "promote"
                  else walk if scope.startswith("walk.") else text)
