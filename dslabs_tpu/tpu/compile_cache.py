@@ -43,8 +43,9 @@ import threading
 import time
 from typing import Callable, Optional
 
-__all__ = ["setup", "cache_dir", "totals", "DEFAULT_DIR", "store_dir",
-           "environment_key", "program_key", "stored", "STORE_BOUND"]
+__all__ = ["setup", "cache_dir", "totals", "twin_built", "DEFAULT_DIR",
+           "store_dir", "environment_key", "program_key", "stored",
+           "STORE_BOUND"]
 
 DEFAULT_DIR = os.path.join(
     os.path.dirname(os.path.dirname(os.path.dirname(
@@ -91,6 +92,8 @@ _totals["trace_folded_n"] = 0
 # and written.
 _totals.update(exe_store_hit_n=0, exe_store_miss_n=0, exe_store_load_s=0.0,
                exe_store_write_s=0.0, exe_store_bytes=0)
+# The twins built (``ProtocolSpec.compile()`` calls) and their seconds.
+_totals.update(twin_build_n=0, twin_build_s=0.0)
 
 
 def _on_duration(event, secs, **kw) -> None:
@@ -140,9 +143,17 @@ def totals() -> dict:
     ``exe_store_miss_n`` (lookups under a key; a program whose key
     could not be built is not looked up), ``exe_store_load_s``,
     ``exe_store_write_s``, ``exe_store_bytes`` (entries loaded and
-    written)."""
+    written).  The spec compiler's: ``twin_build_n`` /
+    ``twin_build_s``, the twins built (:func:`twin_built`)."""
     with _lock:
         return dict(_totals)
+
+
+def twin_built(secs: float) -> None:
+    """One ``ProtocolSpec.compile()`` took ``secs`` (its
+    ``compile.twin`` span): a lab call builds a twin a ladder rung and
+    a binding, and the totals sum them, as set-up pays them."""
+    _count(twin_build_n=1, twin_build_s=float(secs))
 
 
 def setup() -> str:

@@ -38,6 +38,7 @@ from __future__ import annotations
 import contextlib
 import dataclasses
 import functools
+import time
 from typing import Callable, Dict, List, Optional, Sequence, Tuple
 
 import numpy as np
@@ -962,7 +963,24 @@ class ProtocolSpec:
     # ------------------------------------------------------------ compile
 
     def compile(self):
-        """-> TensorProtocol (the engine contract, engine.py:94-146)."""
+        """-> TensorProtocol (the engine contract, engine.py:94-146).
+
+        One ``compile.twin`` span a call (``spec``, ``instances``: node
+        instances, ``invocations``: handlers the budget dry-run ran);
+        its seconds are the process's ``twin_build_s``
+        (``compile_cache.totals()``)."""
+        from dslabs_tpu.tpu import compile_cache, telemetry
+
+        t0 = time.monotonic()
+        with telemetry.phase(
+                "compile.twin", spec=self.name,
+                instances=sum(k.count for k in self.nodes)) as span:
+            protocol = self._compile()
+            span.set(invocations=self._invocations)
+        compile_cache.twin_built(time.monotonic() - t0)
+        return protocol
+
+    def _compile(self):
         import jax.numpy as jnp
 
         from dslabs_tpu.tpu.engine import SENTINEL, TensorProtocol
@@ -1188,13 +1206,6 @@ class ProtocolSpec:
         import jax.numpy as jnp
 
         table, _ = self._layout()
-
-        def dummy_state():
-            return {key: (jnp.zeros((), jnp.int32) if size == 1
-                          else jnp.zeros((size,), jnp.int32))
-                    for key, (_, size) in table.items()}
-
-        false = jnp.asarray(False)
         max_sends = max_sets = 0
         self._exc_hi = 0
         # Coverage record for the conformance linter's soft C4 half:
@@ -1202,40 +1213,57 @@ class ProtocolSpec:
         self._touched_slots = set()
         self._touched_quorums = set()
         self._touched_sends = set()
-        for kind, i in self._instances():
-            for m in self.messages:
-                fn = self.handlers.get((kind.name, m.name))
-                if fn is None:
-                    continue
+        # The dry-run's values are thrown away, so none of it belongs
+        # on an accelerator: under the host's CPU device as the default
+        # one, its operands, every primitive a handler binds and every
+        # tiny program those compile are the CPU backend's (60,000
+        # eager operations at lab 4's n = 3, each a launch where the
+        # default device is a TPU).  A process without a CPU backend
+        # (JAX_PLATFORMS names another alone) runs it where it always
+        # ran.  Not under jit / eval_shape: tracing that many binds was
+        # measured slower than executing them (docs/specs.md).
+        with _on_host_cpu():
+            # ONE set of zero operands a call: arrays are immutable,
+            # and each invocation's Ctx gets its own dict of them (a
+            # ``ctx.put`` rebinds a key of THAT dict).
+            zero = jnp.zeros((), jnp.int32)
+            zeros = {key: (zero if size == 1
+                           else jnp.zeros((size,), jnp.int32))
+                     for key, (_, size) in table.items()}
+            false = jnp.asarray(False)
+            # an instance's message handlers, then its timer handlers
+            calls = [(kind, i, typ, fn, frm)
+                     for kind, i in self._instances()
+                     for types, handlers, frm in (
+                         (self.messages, self.handlers, {"_from": zero}),
+                         (self.timers, self.timer_handlers, {}))
+                     for typ in types
+                     for fn in [handlers.get((kind.name, typ.name))]
+                     if fn is not None]
+            self._invocations = len(calls)
+            for kind, i, typ, fn, frm in calls:
                 sends, sets, excs = [], [], []
-                ctx = Ctx(self, dummy_state(), kind.name, i, false,
-                          sends, sets, handler=self._handler_id(fn),
-                          excs=excs)
-                self._invoke(
-                    fn, ctx, {f: jnp.zeros((), jnp.int32)
-                              for f in m.fields} | {"_from": jnp.zeros(
-                                  (), jnp.int32)}, m.name)
-                max_sends = max(max_sends, len(sends))
-                max_sets = max(max_sets, len(sets))
-                for code, _c in excs:
-                    self._exc_hi = max(self._exc_hi, code)
-            for t in self.timers:
-                fn = self.timer_handlers.get((kind.name, t.name))
-                if fn is None:
-                    continue
-                sends, sets, excs = [], [], []
-                ctx = Ctx(self, dummy_state(), kind.name, i, false,
-                          sends, sets, handler=self._handler_id(fn),
-                          excs=excs)
-                self._invoke(
-                    fn, ctx,
-                    {f: jnp.zeros((), jnp.int32) for f in t.fields},
-                    t.name)
+                ctx = Ctx(self, dict(zeros), kind.name, i, false, sends,
+                          sets, handler=self._handler_id(fn), excs=excs)
+                self._invoke(fn, ctx,
+                             {f: zero for f in typ.fields} | frm, typ.name)
                 max_sends = max(max_sends, len(sends))
                 max_sets = max(max_sets, len(sets))
                 for code, _c in excs:
                     self._exc_hi = max(self._exc_hi, code)
         return (max_sends, max_sets)
+
+
+def _on_host_cpu():
+    """The process's first CPU device as JAX's default device, for the
+    block; no change where JAX has no CPU backend (``JAX_PLATFORMS``
+    names another platform alone)."""
+    import jax
+
+    try:
+        return jax.default_device(jax.devices("cpu")[0])
+    except RuntimeError:
+        return contextlib.nullcontext()
 
 
 class _View:

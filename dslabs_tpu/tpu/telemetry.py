@@ -437,6 +437,11 @@ PHASES = (
     # root's replay (the twin's initial row read back, the replayed row
     # unflattened onto the device)
     "entry.root.eager",
+    # one ``ProtocolSpec.compile()`` (tpu/compiler.py): ``spec``, its
+    # node ``instances`` and the handler ``invocations`` of its budget
+    # dry-run; the seconds add up in ``compile_cache.totals()`` as
+    # ``twin_build_s``
+    "compile.twin",
     "compile.aot",                  # aot_warmup, one child per program
     # mark: one jax.monitoring event, or one lookup in the executable
     # store (``kind`` store_hit / store_miss, ``fun`` the program)

@@ -360,7 +360,8 @@ def _the_manifest_reads_the_cell_where_the_issue_says(man, params, entry,
             "ladder_attempts_per_call.suite", "engine_cache_hit_pct.lab",
             "dispatches_per_call.lab"} <= reads
     assert all(m["moves"] == "verdict_s"
-               or m["name"] in ("warmup_s.lab", "exe_store_hit_pct")
+               or m["name"] in ("warmup_s.lab", "exe_store_hit_pct",
+                                "twin_build_s")
                for m in man["per_layer"] if m["name"] in reads)
     assert not {m for m in reads if m.endswith(".deep")}
 

@@ -16,8 +16,9 @@ What is held here:
   ``program_scopes`` maps most of the superstep's instructions, and the
   programs have names of their own in a profile;
 * a cold jit leaves ``compile.event`` marks and grows
-  ``compile_cache.totals()``; a phase with neither recorder nor profiler
-  writes nothing;
+  ``compile_cache.totals()``; a twin's build is one ``compile.twin``
+  span and grows ``twin_build_n`` / ``twin_build_s``; a phase with
+  neither recorder nor profiler writes nothing;
 * the spans of the host's work between dispatches (ISSUE 38) carry the
   call's id, nest under their parents without crossing a sibling, and
   ``level.trace_meta`` counts the rows the level appended; the
@@ -746,6 +747,45 @@ def test_compile_events_reach_the_flight_log(tmp_path):
                                                      for m in marks}
     mine = [m for m in marks if "also_never_seen" in m.get("fun", "")]
     assert mine and all(m["wall"] < 0.01 and m["secs"] >= 0 for m in mine)
+
+
+
+# ------------------------------------------------------- a twin's build
+
+def test_a_twin_build_is_one_compile_twin_span_and_the_totals_grow():
+    """``ProtocolSpec.compile()`` (ISSUE 48): one ``compile.twin`` span
+    a call, and ``twin_build_n`` / ``twin_build_s`` grow by it."""
+    from dslabs_tpu.tpu.specs import pingpong_spec
+
+    assert "compile.twin" in tel_mod.PHASES
+    before = compile_cache.totals()
+    tel = Telemetry()
+    with tel_mod.use(tel):
+        pingpong_spec().compile()
+    after = compile_cache.totals()
+    (span,) = [r for r in tel.ring
+               if r["t"] == "phase" and r["name"] == "compile.twin"]
+    # a server and a client; REQ at the server, REPLY and PING at the client
+    assert (span["spec"], span["instances"], span["invocations"]) == (
+        "pingpong-gen", 2, 3)
+    assert after["twin_build_n"] - before["twin_build_n"] == 1
+    grown = after["twin_build_s"] - before["twin_build_s"]
+    assert 0 < grown and abs(grown - span["wall"]) < 0.05
+
+
+@pytest.mark.parametrize("run,totals,want", [
+    ({"trace": {}}, {"twin_build_s": 4.25, "twin_build_n": 1}, None),
+    ({"trace": {"busy_s": 1.0}}, {"twin_build_s": 4.25}, 4.25),
+    ({"trace": {"busy_s": 1.0}}, {"twin_build_s": 0.0}, 0.0),
+    ({"trace": {"busy_s": 1.0}}, {"exe_store_hit_n": 3}, None),
+], ids=["untraced", "counted", "no-twin-compiled",
+        "a-program-from-before-PR-48"])
+def test_twin_build_reader_reads_the_total(monkeypatch, run, totals, want):
+    """``benchmark/layer_metrics/twin_build_s.py`` reads the total, and
+    reports nothing (no raise) on a program that lacks it — the parent
+    side of this PR's check runs it on such a program."""
+    monkeypatch.setattr(compile_cache, "totals", lambda: dict(totals))
+    assert _layer_reader("twin_build_s").compute(run) == want
 
 
 # ------------------------------------------------------------ off means off
