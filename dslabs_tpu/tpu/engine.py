@@ -887,12 +887,20 @@ class TensorSearch:
         # round-2 select-both design computed BOTH branches for every
         # pair).  ``ev_budget``: None = full grid per kind (always safe);
         # int b = message slots capped at b, timer slots full; tuple
-        # (bm, bt) caps both (bench protocol: (40, 8) vs the 64+30
-        # grid; measured mean ~30 valid events at depth 16).  A state
-        # with more valid events than a budget overflows LOUDLY (base
-        # engine: CapacityOverflow; sharded strict: same; sharded beam:
-        # counted in SearchOutcome.dropped — coverage truncation, same
-        # class as a frontier-cap drop).
+        # (bm, bt) caps both — the benchmark's deep cells run (40, 8):
+        # sized on Paxos' 64 + 30 grid, which it never overflows; lab
+        # 4's three-server groups hold 13 live timers at the root and
+        # overflow its 8 timer slots in every chunk.  A state with more
+        # valid events of a kind than the kind's budget is never cut
+        # short where the driver spills (device loop, sharded
+        # ``ev_spill``): its chunk is stepped again at the next window —
+        # a whole chunk step more (tables, fingerprints, pack, route,
+        # insert at the full successor shape), less the handlers and
+        # merge of a kind whose window is then empty (_expand_chunk
+        # branches on each kind's table: _kinds_live).  Without spill it
+        # overflows LOUDLY (host loop: CapacityOverflow; sharded strict:
+        # same; sharded beam: counted in SearchOutcome.dropped —
+        # coverage truncation, same class as a frontier-cap drop).
         tgrid = protocol.n_nodes * protocol.timer_cap
         if ev_budget is None:
             bm, bt = protocol.net_cap, tgrid
@@ -1829,9 +1837,13 @@ class TensorSearch:
         mechanism re-steps a chunk with the next window when
         ``remaining`` (valid events at rank >= offset + budget) is
         nonzero, so a budget smaller than the worst-case event count
-        truncates nothing — it just costs extra passes on the rare
-        over-budget chunk (the round-3 drop-or-abort became round 4's
-        count-then-respill)."""
+        truncates nothing.  What it costs: every over-budget chunk is a
+        whole chunk step more a window, whatever the window holds —
+        half a search's steps where one kind overflows in every chunk
+        (lab 4's three-server groups under (40, 8)) — of which
+        _expand_chunk leaves out the handlers and merge of a kind whose
+        table for the pass is all -1 (the round-3 drop-or-abort became
+        round 4's count-then-respill)."""
         c, g = valid_ev.shape
         if budget >= g:
             # Window 0 covers every rank (remaining always 0) — but the
@@ -1936,6 +1948,13 @@ class TensorSearch:
             ev_pass * self._ev_tmr)
         return msg_ids, tmr_ids, flt_ids, m_rem + t_rem
 
+    def _kinds_live(self, msg_ids: jnp.ndarray, tmr_ids: jnp.ndarray):
+        """(messages, timers): whether a pass's table of the kind
+        (:meth:`_event_tables`) holds an event at all — the two scalars
+        :meth:`_expand_chunk` branches on, computed from the tables
+        alone."""
+        return jnp.any(msg_ids >= 0), jnp.any(tmr_ids >= 0)
+
     def _expand_chunk(self, chunk_rows: jnp.ndarray,
                       chunk_valid: jnp.ndarray, ev_pass=0, masks=None,
                       dedup: Optional[bool] = None):
@@ -1945,8 +1964,10 @@ class TensorSearch:
         Returns (rows [C*B, lanes], valids [C*B], fp [C*B, 4] uint32,
         unique [C*B] in-chunk-first-occurrence mask, overflow scalar,
         ev_remaining scalar (valid events past this pass's window — see
-        :meth:`_event_tables`), event_ids [C, B], flags dict) — all
-        device arrays; no host sync inside.  B = Bm + Bt, message pair
+        :meth:`_event_tables`), event_ids [C, B], flags dict, kind_skips
+        scalar (the kinds, 0-2, whose table held no event in this pass
+        and whose handlers and merge did not run)) — all device arrays;
+        no host sync inside.  B = Bm + Bt, message pair
         slots first per state (successor row = chunk_row * B + slot, the
         arithmetic run()/_reconstruct and the sharded driver use)."""
         p = self.p
@@ -1960,6 +1981,7 @@ class TensorSearch:
         with tel_mod.device_scope("expand.events"):
             msg_ids, tmr_ids, flt_ids, ev_drops = self._event_tables(
                 chunk_rows, chunk_valid, ev_pass, masks)
+            live_m, live_t = self._kinds_live(msg_ids, tmr_ids)
         # TWO flat vmaps — one per event kind, each running only its own
         # machinery (the round-2 select-both design ran BOTH handlers for
         # every pair).  Flat, not nested: a nested
@@ -1970,24 +1992,52 @@ class TensorSearch:
         # per-state repeat is a broadcast (XLA fuses it into the reads).
         # Only the HANDLER half is vmapped; the network merge runs as
         # ONE batched transposed program per kind (_batched_tail).
-        with tel_mod.device_scope("expand.handlers"):
-            rep_m = jnp.repeat(chunk_rows, bm, axis=0)
-            (nodes_m, sends_m, timers_m, exc_m, ok_m,
-             tover_m) = jax.vmap(self._msg_step_raw)(
-                rep_m, jnp.maximum(msg_ids, 0).reshape(-1))
-            rep_t = jnp.repeat(chunk_rows, bt, axis=0)
-            (nodes_t, sends_t, timers_t, exc_t, ok_t,
-             tover_t) = jax.vmap(self._tmr_step_raw)(
-                rep_t, jnp.maximum(tmr_ids, 0).reshape(-1))
+        #
+        # Each kind runs under a device BRANCH on its table: a kind with
+        # no event in this pass (a re-step whose other kind spilled, a
+        # search with every timer frozen) computes nothing and hands back
+        # invalid rows of the same shape, so the successor block keeps
+        # its layout.  No collective sits inside, so on a mesh each chip
+        # branches for itself.
+        def kind(step_raw, ids, b, live):
+            def run(_):
+                with tel_mod.device_scope("expand.handlers"):
+                    rep = jnp.repeat(chunk_rows, b, axis=0)
+                    (nodes2, sends, timers2, exc, ok,
+                     tover) = jax.vmap(step_raw)(
+                        rep, jnp.maximum(ids, 0).reshape(-1))
+                with tel_mod.device_scope("expand.canon"):
+                    rows, over = self._batched_tail(
+                        chunk_rows, c, b, nodes2, sends, timers2, exc,
+                        ok, tover)
+                    return rows, ok & (ids >= 0).reshape(-1), over
+
+            def skip(_):
+                return (jnp.zeros((c * b, self.lanes), jnp.int32),
+                        jnp.zeros((c * b,), bool),
+                        jnp.zeros((c * b,), jnp.int32))
+
+            return jax.lax.cond(live, run, skip, None)
+
+        # The barrier does two things, both read off the text compiled
+        # for a v5e (PERF.md section 6, PR 49) and pinned in
+        # tests/test_chip_compile.py.  It keeps the compiler from moving
+        # what follows INTO the branches (its conditional code motion
+        # hoisted the interleave's pads, and in one placement the
+        # fingerprints' converts, into each branch and handed a whole
+        # padded block out of every one).  And, a side effect of the
+        # compiler's own heuristics that the tree's speed now rests on:
+        # behind it a branch assembles its kind's rows by ONE
+        # concatenate, where without it (and before there was a branch)
+        # they are written by one in-place dynamic-update-slice a lane
+        # group, 42 of them in lab 4 — the cells that never skip a kind
+        # gained 2-9 % by that, and without the barrier the branches
+        # cost them 1-3 %.
+        (rows_m, val_m, over_m, rows_t, val_t,
+         over_t) = jax.lax.optimization_barrier(
+            kind(self._msg_step_raw, msg_ids, bm, live_m)
+            + kind(self._tmr_step_raw, tmr_ids, bt, live_t))
         with tel_mod.device_scope("expand.canon"):
-            rows_m, over_m = self._batched_tail(
-                chunk_rows, c, bm, nodes_m, sends_m, timers_m, exc_m,
-                ok_m, tover_m)
-            val_m = ok_m & (msg_ids >= 0).reshape(-1)
-            rows_t, over_t = self._batched_tail(
-                chunk_rows, c, bt, nodes_t, sends_t, timers_t, exc_t,
-                ok_t, tover_t)
-            val_t = ok_t & (tmr_ids >= 0).reshape(-1)
             # Fault segment (ISSUE 19): no handlers, no sends —
             # _flt_step returns full successor rows directly, so the
             # pairs skip the batched merge tail entirely.
@@ -2025,6 +2075,9 @@ class TensorSearch:
                                          p.net_cap + tgrid + flt_ids, -1))
             event_ids = jnp.concatenate(ev_segs, axis=1)       # [C, B]
             overflow = jnp.sum(overs * valids.astype(jnp.int32))
+            # The kinds this pass did not compute (0-2).
+            kind_skips = (2 - live_m.astype(jnp.int32)
+                          - live_t.astype(jnp.int32))
         # Symmetry hash step (ISSUE 15b): fingerprints — and through
         # them the sharded owner-hash — key on the canonical orbit
         # representative; the stored rows stay the real states.
@@ -2061,7 +2114,7 @@ class TensorSearch:
                     flags[f"{kind}:{name}"] = (jax.vmap(fn)(succ_states)
                                                & valids)
         return (rows, valids, fp, unique, overflow, ev_drops, event_ids,
-                flags)
+                flags, kind_skips)
 
     # ----------------------------------------------------------------- run
 
@@ -2347,11 +2400,12 @@ class TensorSearch:
                         [jnp.ones(c, bool), jnp.zeros(pad, bool)])
                     rt = getattr(self, "_rt_masks", None)
                     (rows_d, valids, fp, unique, overflow, ev_drops, event_ids,
-                     flags) = (self._dispatch("host.expand", self._expand,
-                                              chunk_rows, chunk_valid, 0, rt)
-                               if rt is not None
-                               else self._dispatch("host.expand", self._expand,
-                                                   chunk_rows, chunk_valid))
+                     flags, _) = (
+                        self._dispatch("host.expand", self._expand,
+                                       chunk_rows, chunk_valid, 0, rt)
+                        if rt is not None
+                        else self._dispatch("host.expand", self._expand,
+                                            chunk_rows, chunk_valid))
                     if int(overflow):
                         raise CapacityOverflow(
                             f"{self.p.name}: net_cap={self.p.net_cap}, "
@@ -2521,8 +2575,8 @@ class TensorSearch:
             # path measured.  run_host keeps the prefilter (its host
             # merge requires batch-unique keys).
             (rows, valids, fp, unique, overflow, ev_rem, _event_ids,
-             flags) = self._expand_chunk(rows_chunk, valid, ev_pass,
-                                         masks, dedup=False)
+             flags, _) = self._expand_chunk(rows_chunk, valid, ev_pass,
+                                            masks, dedup=False)
             # Event-window spill (round-4 semantics): valid events past
             # this pass's window re-step the SAME chunk at the next
             # window before j advances — a finite ev_budget costs extra

@@ -434,9 +434,10 @@ class ShardedTensorSearch(TensorSearch):
         """The per-device chunk-step body (runs INSIDE shard_map, as
         the body of the superstep's ``lax.while_loop``): one chunk
         expand + owner routing of keys and rows + owner dedup +
-        frontier append.  Returns the carry and the step's dedup counts
+        frontier append.  Returns the carry and the step's counts
         ``[write blocks scattered (table and append:
-        visited.block_width), bucket columns the probe gathered]``."""
+        visited.block_width), bucket columns the probe gathered, event
+        kinds the expand skipped (0-2)]``."""
         p = self.p
         D = self.n_devices
         C = self.cpd
@@ -486,7 +487,8 @@ class ShardedTensorSearch(TensorSearch):
             valid = (start + jnp.arange(C)) < cur_n
             ev_pass = carry["evp"][0]
             (rows, valids, fp, unique, overflow, ev_rem, event_ids,
-             flags) = self._expand_chunk(rows_chunk, valid, ev_pass, masks)
+             flags, kind_skips) = self._expand_chunk(
+                 rows_chunk, valid, ev_pass, masks)
             # Spill: valid events past this pass's window mean the SAME
             # chunk must re-step at the next window before j advances
             # (run() re-dispatches until every device's j reaches its
@@ -773,7 +775,8 @@ class ShardedTensorSearch(TensorSearch):
                         out[k] = jnp.where(abort, carry[k], out[k])
                     out["f_full"] = jnp.where(abort, code,
                                               jnp.int32(0))[None]
-                return out, jnp.stack([blocks + app_blocks, probe_cols])
+                return out, jnp.stack([blocks + app_blocks, probe_cols,
+                                       kind_skips])
 
         return local
 
@@ -799,7 +802,8 @@ class ShardedTensorSearch(TensorSearch):
 
         Returns ``(carry', stats)`` where ``stats`` is the fused scalar
         vector _sync_checks parses: 8 scalars, the n_flags counts,
-        ``[remaining_devices, steps_taken, write_blocks, probe_cols]``,
+        ``[remaining_devices, steps_taken, write_blocks, probe_cols,
+        kind_skips]``,
         the spill abort code when the host tier is wired, under a delta
         descriptor ``[base moved, delta_peak]``, and the per-device lanes.
         Computing the stats in-program (psum/pmax over the mesh axis)
@@ -815,7 +819,7 @@ class ShardedTensorSearch(TensorSearch):
         spill_on = self._spill is not None
         delta = self._mesh_delta
 
-        def stats_local(c, steps, dedup):
+        def stats_local(c, steps, counts):
             core = jnp.stack([
                 _psum(c["overflow"][0]),
                 _psum(c["drops"][0]),
@@ -831,11 +835,12 @@ class ShardedTensorSearch(TensorSearch):
             flags = _psum(c["flag_cnt"]).astype(jnp.int32)
             remaining = _psum(
                 (c["j"][0] * C < c["cur_n"][0]).astype(jnp.int32))
-            # Write blocks scattered (table + append) and bucket columns
-            # the probe gathered, each summed over this dispatch's chunk
-            # steps, of the device that counts most.
+            # Write blocks scattered (table + append), bucket columns
+            # the probe gathered and event kinds the expand skipped,
+            # each summed over this dispatch's chunk steps, of the
+            # device that counts most.
             tail = jnp.concatenate([jnp.stack([remaining, steps]),
-                                    jax.lax.pmax(dedup, ax)]
+                                    jax.lax.pmax(counts, ax)]
                                    ).astype(jnp.int32)
             parts = [core, flags, tail]
             if spill_on:
@@ -879,15 +884,15 @@ class ShardedTensorSearch(TensorSearch):
                 return keep
 
             def body(st):
-                c, k, dedup = st
-                c, counts = local(c, masks)
-                return c, k + 1, dedup + counts
+                c, k, counts = st
+                c, step_counts = local(c, masks)
+                return c, k + 1, counts + step_counts
 
-            carry, k, dedup = jax.lax.while_loop(
+            carry, k, counts = jax.lax.while_loop(
                 cond, body,
-                (carry, jnp.int32(0), jnp.zeros((2,), jnp.int32)))
+                (carry, jnp.int32(0), jnp.zeros((3,), jnp.int32)))
             with tel_mod.device_scope("level_sync"):
-                return carry, stats_local(carry, k, dedup)
+                return carry, stats_local(carry, k, counts)
 
         spec = self._carry_specs()
         # The function's name is the program's in a profile
@@ -1942,7 +1947,7 @@ class ShardedTensorSearch(TensorSearch):
             self._fp_map = {}
             self._deep_samples = None
             # Structured per-level throughput records (depth, chunks,
-            # write_blocks, probe_cols, wall, explored, unique,
+            # write_blocks, probe_cols, kind_skips, wall, explored, unique,
             # next_frontier) — attached
             # to the outcome as SearchOutcome.levels; the ``search.level``
             # phase carries the same counters into a profile.
@@ -2068,7 +2073,7 @@ class ShardedTensorSearch(TensorSearch):
                         carry["noapp"] = jax.device_put(
                             np.ones(self.n_devices, np.int32), shard)
                     (carry, out, explored, vis_total, drops, max_n,
-                     chunks, dedup) = self._level_superstep(
+                     chunks, counts) = self._level_superstep(
                          carry, depth, t0, max_n)
                     if out is not None:
                         return out
@@ -2085,10 +2090,10 @@ class ShardedTensorSearch(TensorSearch):
                                 break
                             carry, per = self._sh_spill_inject(carry, seg)
                             (carry, out, explored, vis_total, drops, max_n,
-                             ch2, de2) = self._level_superstep(
+                             ch2, cn2) = self._level_superstep(
                                  carry, depth, t0, per)
                             chunks += ch2
-                            dedup += de2
+                            counts += cn2
                             if out is not None:
                                 return out
                     rec = {
@@ -2098,12 +2103,20 @@ class ShardedTensorSearch(TensorSearch):
                         # wrote most): above one a probe iteration and one
                         # an append, visited.block_width is too narrow
                         # for the traffic.
-                        "write_blocks": int(dedup[0]),
+                        "write_blocks": int(counts[0]),
                         # Bucket columns the level's probes gathered
                         # (indices handed to the table's gather, of the
                         # device that gathered most): a step's live
                         # blocks of visited.block_width, not its batch.
-                        "probe_cols": int(dedup[1]),
+                        "probe_cols": int(counts[1]),
+                        # (chunk step, event kind) pairs the expand did
+                        # not compute because the pass's table held no
+                        # event of the kind (engine._expand_chunk): of
+                        # 2 x chunks, of the device that skipped most —
+                        # on a mesh a device whose shard ran out skips
+                        # both kinds while it waits, so the count says
+                        # what was saved on one device only.
+                        "kind_skips": int(counts[2]),
                         "wall": round(time.time() - t_lvl, 4),
                         "explored": int(explored), "unique": int(vis_total),
                         "next_frontier": int(max_n),
@@ -2182,6 +2195,7 @@ class ShardedTensorSearch(TensorSearch):
                             chunks=int(chunks),
                             write_blocks=rec["write_blocks"],
                             probe_cols=rec["probe_cols"],
+                            kind_skips=rec["kind_skips"],
                             next_frontier=int(max_n))
                     if self.record_trace and not noapp_level:
                         # A final depth-limited level returns below:
@@ -2269,7 +2283,8 @@ class ShardedTensorSearch(TensorSearch):
         wall-clock budget is set — the whole level in ONE dispatch) and
         returns the fused stats in the same program.  Returns
         ``(carry, outcome_or_none, explored, vis_total, drops, nxt_max,
-        chunk_steps_run, [write_blocks_run, probe_cols_run])``."""
+        chunk_steps_run, [write_blocks_run, probe_cols_run,
+        kind_skips_run])``."""
         budget = ((1 << 30) if self.max_secs is None
                   else max(1, self._superstep_chunks))
         # Watchdog granularity (tpu/supervisor.py): a superstep
@@ -2280,11 +2295,11 @@ class ShardedTensorSearch(TensorSearch):
         self._dispatch_deadline_scales = {
             "superstep": float(max(1, min(budget, 2 * est)))}
         nf = len(self._flag_names)
-        chunks, dedup = 0, np.zeros(2, np.int64)
+        chunks, counts = 0, np.zeros(3, np.int64)
         while True:
             carry, stats = self._superstep_call(carry, budget)
             chunks += int(stats[9 + nf])
-            dedup += stats[10 + nf:12 + nf]
+            counts += stats[10 + nf:13 + nf]
             # The checks run BEFORE any time-budget return: a violation
             # or capacity loss in the chunks already completed is never
             # masked by TIME_EXHAUSTED.
@@ -2292,15 +2307,15 @@ class ShardedTensorSearch(TensorSearch):
              nxt_max) = self._sync_checks(carry, depth, t0, stats)
             if out is not None:
                 return (carry, out, explored, vis_total, drops, nxt_max,
-                        chunks, dedup)
-            if self._spill_on and int(stats[12 + nf]):
+                        chunks, counts)
+            if self._spill_on and int(stats[13 + nf]):
                 # Spill abort: the superstep suspended on a frontier-
                 # full (bit 0) / table-full (bit 1) chunk, reverted
                 # wholesale.  Drain nxt through the refilter to the
                 # host spool, evict the tables if they were the wall,
                 # and re-enter the drain loop — the held-back chunk
                 # re-steps against recovered capacity.
-                code = int(stats[12 + nf])
+                code = int(stats[13 + nf])
                 if (code & 1) and nxt_max == 0:
                     raise CapacityOverflow(
                         f"{self.p.name}: one chunk's fresh successors "
@@ -2319,7 +2334,7 @@ class ShardedTensorSearch(TensorSearch):
                 continue
             if int(stats[8 + nf]) == 0:     # every device's shard drained
                 return (carry, None, explored, vis_total, drops, nxt_max,
-                        chunks, dedup)
+                        chunks, counts)
             if (self.max_secs is not None
                     and time.time() - t0 > self.max_secs) \
                     or self._cancelled():
@@ -2327,7 +2342,7 @@ class ShardedTensorSearch(TensorSearch):
                                           depth, t0)
                 out.cancelled = self._cancelled()
                 return (carry, out, explored, vis_total, drops, nxt_max,
-                        chunks, dedup)
+                        chunks, counts)
 
     def _spill_tmeta(self, carry) -> None:
         """Fold this level's appended (child_fp, parent_fp, event) rows

@@ -408,7 +408,10 @@ PHASES = (
     # the sharded engine, ``write_blocks`` (scatter blocks its chunk
     # steps wrote, table + append: visited.block_width), ``probe_cols``
     # (bucket columns its probes gathered: the indices handed to the
-    # table's gather, a step's live blocks of that width) and, at the
+    # table's gather, a step's live blocks of that width),
+    # ``kind_skips`` ((chunk step, event kind) pairs whose handlers and
+    # merge the expand skipped, the pass's table holding no event of
+    # the kind: engine._expand_chunk; of 2 x ``chunks``) and, at the
     # start, ``frontier0`` (the frontier rows the level starts with, of
     # the device that holds most: ``chunks`` beyond ceil(frontier0 /
     # chunk) are chunk steps re-run at a later event window); from a

@@ -415,7 +415,27 @@ def _frontier_sized_moves(text, elements):
         if _elements(shape) >= elements]
 
 
-def test_shardkv_deep_programs_compile(topo):
+@pytest.fixture(scope="module")
+def shardkv_deep(topo):
+    """``(cell, search, {name: compiled})``: the benchmark's
+    ``shardkv-deep`` cell as its driver builds it, compiled once for one
+    described chip (under a minute)."""
+    from benchmark.drivers.timeboxed_bfs import build_protocol
+    from benchmark.harness import manifest
+
+    cell = manifest.load_cell(
+        os.path.dirname(os.path.dirname(os.path.abspath(__file__))),
+        "shardkv-deep")
+    eng = cell.config["engine"]
+    search = ShardedTensorSearch(
+        build_protocol(cell.config["protocol"]),
+        _mesh(topo, 1), chunk_per_device=eng["chunk"],
+        frontier_cap=eng["frontier_cap"], visited_cap=eng["visited_cap"],
+        strict=True, ev_budget=tuple(eng["ev_budget"]))
+    return cell, search, _aot(search)
+
+
+def test_shardkv_deep_programs_compile(shardkv_deep):
     """Lab 4's part 1 twin (the benchmark's ``shardkv-deep`` cell) as
     its driver builds it, at the configuration's caps — a frontier of
     6,815,744 rows a buffer (3.65 GB each at 536 bytes a row) and a
@@ -439,23 +459,12 @@ def test_shardkv_deep_programs_compile(topo):
     no gather or scatter on the table is handed more than one block of
     6,144 indices.  Under a minute of compile (the program is a fifth
     of Paxos' text)."""
-    from benchmark.drivers.timeboxed_bfs import build_protocol
-    from benchmark.harness import manifest
-
-    cell = manifest.load_cell(
-        os.path.dirname(os.path.dirname(os.path.abspath(__file__))),
-        "shardkv-deep")
+    cell, search, exes = shardkv_deep
     eng = cell.config["engine"]
     assert (eng["frontier_cap"], eng["visited_cap"]) == (6815744, 1 << 25)
-    search = ShardedTensorSearch(
-        build_protocol(cell.config["protocol"]),
-        _mesh(topo, 1), chunk_per_device=eng["chunk"],
-        frontier_cap=eng["frontier_cap"], visited_cap=eng["visited_cap"],
-        strict=True, ev_budget=tuple(eng["ev_budget"]))
     assert (search.lanes, search.bytes_per_state) == (
         cell.config["protocol"]["lanes"],
         cell.config["protocol"]["packed_bytes_per_state"])
-    exes = _aot(search)
     _fits(exes)
     k = visited.block_width(eng["chunk"] * search._ev_slots)
     assert k == 6144
@@ -484,6 +493,30 @@ def test_shardkv_deep_programs_compile(topo):
     assert table and max(table) <= k, table
     assert reads and max(reads) <= k, reads
     assert _table_sized_in_loops(text, limit=1 << 27) == []
+
+
+def test_a_kind_assembles_its_rows_by_one_concatenate(shardkv_deep):
+    """What the guard cells' speed rests on since PR 49, and nothing in
+    the program says: behind ``_expand_chunk``'s
+    ``optimization_barrier`` each event kind's branch hands out its
+    successor rows ``s32[C * b, lanes]`` built by ONE ``concatenate``.
+    Without the barrier — and in the tree before the branch — the
+    compiler wrote the same rows in place, one
+    ``dynamic-update-slice`` a lane group into ``s32[C, b, lanes]`` (42
+    of them in this twin, 29 + 13), which cost ``shardkv-deep`` and
+    ``pb-deep`` 7 % of their states a second (PERF.md section 6, PR
+    49).  A compiler that goes back to the in-place writes fails here,
+    on the sandbox, before a chip reads it as a loss."""
+    cell, search, exes = shardkv_deep
+    text = exes["superstep"].as_text()
+    c, lanes = cell.config["engine"]["chunk"], search.lanes
+    assert text.count(" conditional(") == 2
+    for b in (search._ev_msg, search._ev_tmr):
+        assert len(re.findall(
+            rf"= s32\[{c * b},{lanes}\]\S* concatenate\(", text)) == 1, b
+        assert re.findall(
+            rf"= s32\[(?:{c},{b}|{c * b}),{lanes}\]\S* "
+            r"dynamic-update-slice\(", text) == [], b
 
 
 def test_pb_deep_programs_compile(topo):
@@ -565,7 +598,10 @@ def test_shardkv_n3_deep_programs_compile(topo):
     1,792 bytes (5.42 GB), the 2^24-slot table and 2.53 GB of chunk
     temporaries (49,152 successors of 1,272 lanes: this twin's rows are
     2.9 times ``shardkv-deep``'s): 7.66 GiB of 15.75, and no ``copy``,
-    ``transpose`` or ``scatter`` of a frontier's size.  The
+    ``transpose`` or ``scatter`` of a frontier's size.  Since PR 49 each
+    event kind's handlers and merge sit under a ``conditional`` whose
+    outputs are buffers of their own (a kind's rows, valid mask and
+    overflow counts): 2.65 GB of temporaries, 7.77 GiB.  The
     configuration's ``sizing`` still records the plan the cap was sized
     under — FOUR frontier-sized buffers (the carry's two and both entry
     copies of a row-scattered ``nxt``), 10.8 GB, with 4.1 GiB of
@@ -592,7 +628,7 @@ def test_shardkv_n3_deep_programs_compile(topo):
     mem = exes["superstep"].memory_analysis()
     live = (max(mem.argument_size_in_bytes, mem.output_size_in_bytes)
             + mem.temp_size_in_bytes)
-    assert live == pytest.approx(8_219_662_848, abs=64 << 20)
+    assert live == pytest.approx(8_340_699_648, abs=64 << 20)
     # ... 6 GiB and more under the plan the configuration was sized for
     assert live + (6 << 30) < cell.config["sizing"]["bytes"][
         "superstep_live_by_memory_analysis"]
@@ -603,6 +639,8 @@ def test_shardkv_n3_deep_programs_compile(topo):
     # the handlers' operations name their fragment in the chip's text
     assert "dslabs.expand.handlers.gpaxos" in text
     assert "dslabs.expand.handlers.spec" in text
+    # a device branch an event kind, in the chip's text too
+    assert text.count(" conditional(") >= 2
 
 
 @pytest.mark.slow

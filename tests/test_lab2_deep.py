@@ -288,7 +288,7 @@ def _the_manifest_reads_the_cell_where_the_issue_says(man, entry, traffic,
         "event_resteps_pct.deep", "grid_fill_pct.deep", "compile_s",
         "trace_lower_s", "peak_hbm_gb", "exe_store_hit_pct",
         "promote_us_per_state.deep", "rebased_levels_pct.deep",
-        "twin_build_s"}
+        "twin_build_s", "kind_skips_pct.deep"}
     for m in man["per_layer"]:
         if m["name"] in reads:
             # appended behind the cells that were there

@@ -344,7 +344,8 @@ def _the_manifest_reads_the_cell_where_the_issue_says(man, entry, traffic,
         "write_blocks_per_step.deep", "compile_s", "peak_hbm_gb",
         "trace_lower_s", "exe_store_hit_pct", "event_resteps_pct.deep",
         "grid_fill_pct.deep",
-        "probe_cols_per_step.deep", "twin_build_s"}
+        "probe_cols_per_step.deep", "twin_build_s",
+        "kind_skips_pct.deep"}
     for m in man["per_layer"]:
         if m["name"] in ("event_resteps_pct.deep", "grid_fill_pct.deep"):
             # (PR 40 appended its own cell behind these two)

@@ -322,7 +322,7 @@ def _the_manifest_reads_the_cell_where_the_issue_says(man, entry, traffic,
         "trace_lower_s", "exe_store_hit_pct", "event_resteps_pct.deep",
         "grid_fill_pct.deep",
         "probe_cols_per_step.deep", "gpaxos_handlers_pct.deep",
-        "twin_build_s"}
+        "twin_build_s", "kind_skips_pct.deep"}
     for m in man["per_layer"]:
         if m["name"] == "gpaxos_handlers_pct.deep":
             assert m == {"name": m["name"], "unit": "%", "better": "lower",
@@ -343,14 +343,14 @@ def _the_manifest_reads_the_cell_where_the_issue_says(man, entry, traffic,
     assert man["workloads"][6]["name"] == CELL
     assert man["configs"][5]["name"] == entry["config"]
     # (PR 41 appended its one metric behind it, PR 43 its four, PR 44
-    # its one, PR 47 its two, PR 48 its one)
+    # its one, PR 47 its two, PR 48 its one, PR 49 its one)
     names = [m["name"] for m in man["per_layer"]]
     assert names[names.index("gpaxos_handlers_pct.deep"):] == [
         "gpaxos_handlers_pct.deep", "exe_store_hit_pct",
         "walk_us_per_step.swarm", "fresh_pct.swarm", "restarts_pct.swarm",
         "round_roofline.swarm", "blocks_per_step.swarm",
         "promote_us_per_state.deep", "rebased_levels_pct.deep",
-        "twin_build_s"]
+        "twin_build_s", "kind_skips_pct.deep"]
     assert [w["chips"] for w in man["workloads"]].count(4) == 1
     assert len(man["workloads"]) == 9        # PRs 43 and 47: one each
 

@@ -111,6 +111,24 @@ def test_lane_parity_lab1(strict):
         _same(out, solo)
 
 
+def test_lane_parity_over_a_spilling_window():
+    """A window that one kind overflows: solo, the re-step skips the
+    kind whose table is empty (a device branch, engine._kinds_live);
+    under the lane ``vmap`` the branch's predicate is batched and
+    lowers to a select over both results — the counts stay solo's, and
+    the full grid's."""
+    proto = _lab1_wide()
+    full = TensorSearch(proto, strict=True, **KW).run()
+    solo = TensorSearch(proto, strict=True, ev_budget=(16, 1), **KW).run()
+    _same(solo, full)
+    ls = LaneSearch(proto, n_lanes=2, strict=True, ev_budget=(16, 1),
+                    **KW)
+    res = ls.run_lanes([LaneJob("a"), LaneJob("b")])
+    assert not res.errors
+    for out in res.outcomes.values():
+        _same(out, solo)
+
+
 def test_lane_parity_mixed_depth_limits():
     """Lane-mates at DIFFERENT per-lane depth limits finish at
     different levels; each still matches its own solo run exactly —

@@ -245,9 +245,10 @@ def test_level_phases_carry_the_levels_own_counters(sharded_run):
         assert note["explored0"] == before
         assert (note["explored"], note["unique"], note["chunks"],
                 note["write_blocks"], note["probe_cols"],
-                note["next_frontier"]) == (
+                note["kind_skips"], note["next_frontier"]) == (
             lv["explored"], lv["unique"], lv["chunks"],
-            lv["write_blocks"], lv["probe_cols"], lv["next_frontier"])
+            lv["write_blocks"], lv["probe_cols"], lv["kind_skips"],
+            lv["next_frontier"])
         before = lv["explored"]
     assert before == out.states_explored
     # A level that found something wrote it: at least a table block
@@ -329,6 +330,25 @@ def test_probe_cols_reader_reads_the_level_span(monkeypatch, level, want):
 
     monkeypatch.setattr(program_spans, "traced_level", lambda run: level)
     assert _layer_reader("probe_cols_per_step.deep").compute({}) == want
+
+
+@pytest.mark.parametrize("level,want", [
+    ({"chunks": 16, "kind_skips": 8}, 25.0),
+    ({"chunks": 126, "kind_skips": 0}, 0.0),
+    ({"chunks": 126}, None),
+    ({"chunks": 0, "kind_skips": 0}, None),
+    (None, None),
+], ids=["every-chunk-twice-one-kind-the-second-time", "no-skip",
+        "a-program-from-before-PR-49", "no-chunk-step", "no-traced-level"])
+def test_kind_skips_reader_reads_the_level_span(monkeypatch, level, want):
+    """``benchmark/layer_metrics/kind_skips_pct.deep.py``: the level's
+    ``kind_skips`` over two kinds a chunk step, and nothing (no raise)
+    where a program counted none — the parent side of this PR's check
+    runs it on such a program."""
+    from benchmark.harness import program_spans
+
+    monkeypatch.setattr(program_spans, "traced_level", lambda run: level)
+    assert _layer_reader("kind_skips_pct.deep").compute({}) == want
 
 
 _ENGINE = {"engine": {"chunk": 1024, "ev_budget": [40, 8]}}

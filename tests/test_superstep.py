@@ -123,6 +123,164 @@ def test_superstep_ev_spill_parity():
     _assert_exact(tiny, full)
 
 
+# ------------------------------------------------- skipped event kinds
+#
+# A pass computes an event kind only if the pass's table holds an event
+# of it (engine._kinds_live: a device branch around the kind's handlers
+# and merge).  Two clients against one server: up to 2 live timers and
+# 4 messages a state, goal CLIENTS_DONE at depth 4.
+
+def _two_clients():
+    return make_clientserver_protocol(n_clients=2, w=1, net_cap=8,
+                                      timer_cap=2)
+
+
+def _kinds_run(proto, n_devices=1, masks=None, **kw):
+    search = ShardedTensorSearch(
+        proto, make_mesh(n_devices), chunk_per_device=16,
+        frontier_cap=1 << 9, visited_cap=1 << 12, **kw)
+    if masks is not None:
+        search.set_runtime_masks(*masks)
+    return search.run()
+
+
+def _never_skipping(monkeypatch):
+    """The program as it was before the branch: both kinds always
+    computed (and, so, no skip counted)."""
+    import jax.numpy as jnp
+
+    monkeypatch.setattr(
+        TensorSearch, "_kinds_live",
+        lambda self, msg_ids, tmr_ids: (jnp.bool_(True), jnp.bool_(True)))
+
+
+def _per_level(out, *keys):
+    return [tuple(rec[k] for k in keys) for rec in out.levels]
+
+
+@pytest.fixture(scope="module")
+def full_grid():
+    return _kinds_run(_two_clients())
+
+
+@pytest.mark.parametrize("window, spills, skips", [
+    ((8, 1), "timers", True),
+    ((2, 6), "messages", True),
+    ((1, 1), "both", None),
+    ((4, 2), "neither", False),
+])
+def test_a_restepped_chunk_skips_the_kind_that_did_not_spill(
+        full_grid, window, spills, skips):
+    """Whatever kind overflows its window, counts and verdict are the
+    full grid's, level for level; the re-steps of a chunk whose OTHER
+    kind spilled skip the kind with the empty table, and a window that
+    holds every event skips nothing."""
+    out = _kinds_run(_two_clients(), ev_budget=window, ev_spill=True)
+    assert out.end_condition == full_grid.end_condition == "GOAL_FOUND"
+    assert out.predicate_name == full_grid.predicate_name
+    assert out.dropped == 0
+    assert _per_level(out, "depth", "explored", "unique") == _per_level(
+        full_grid, "depth", "explored", "unique")
+    chunks = sum(rec["chunks"] for rec in out.levels)
+    skipped = sum(rec["kind_skips"] for rec in out.levels)
+    assert all(0 <= rec["kind_skips"] <= 2 * rec["chunks"]
+               for rec in out.levels)
+    if skips is True:
+        # one kind's re-steps, each skipping the other kind
+        assert skipped == chunks - len(out.levels) > 0
+    elif skips is False:
+        assert skipped == 0 and chunks == len(out.levels)
+    assert _per_level(full_grid, "kind_skips") == [(0,)] * len(
+        full_grid.levels)
+
+
+def test_skipping_changes_nothing_but_the_counter(monkeypatch):
+    """Under one window, the run that skips and the run that computes
+    both kinds on every pass give the same level records (the dedup
+    counters too) and the same witness trace."""
+    kw = dict(ev_budget=(8, 1), ev_spill=True, record_trace=True)
+    out = _kinds_run(_two_clients(), **kw)
+    _never_skipping(monkeypatch)
+    ref = _kinds_run(_two_clients(), **kw)
+    keys = ("depth", "chunks", "explored", "unique", "next_frontier",
+            "write_blocks", "probe_cols")
+    assert _per_level(out, *keys) == _per_level(ref, *keys)
+    assert out.end_condition == ref.end_condition == "GOAL_FOUND"
+    assert out.trace is not None and out.trace == ref.trace
+    assert sum(rec["kind_skips"] for rec in out.levels) > 0
+    assert sum(rec["kind_skips"] for rec in ref.levels) == 0
+
+
+def test_each_device_of_a_mesh_branches_for_itself(full_grid):
+    """Two devices, the root on one of them: in level 1's steps the
+    device without a row skips both kinds while the other computes —
+    ``kind_skips`` is the count of the device that skipped most — and
+    the counts are the one-device full grid's."""
+    out = _kinds_run(_two_clients(), n_devices=2, ev_budget=(8, 1),
+                     ev_spill=True)
+    assert out.end_condition == "GOAL_FOUND"
+    assert _per_level(out, "depth", "explored", "unique") == _per_level(
+        full_grid, "depth", "explored", "unique")
+    first = out.levels[0]
+    assert first["explored"] > 0
+    assert first["kind_skips"] == 2 * first["chunks"]
+    assert sorted(first["per_device"]["explored"])[0] == 0
+
+
+def test_frozen_timers_skip_the_timer_kind_on_the_full_grid(monkeypatch):
+    """Every timer frozen by a runtime mask, no window: pass 0 alone,
+    its timer table empty in every step — the timer kind is skipped
+    once a chunk step and the counts are those of the program that
+    computes it."""
+    import numpy as np
+
+    import jax.numpy as jnp
+
+    proto = dataclasses.replace(
+        _two_clients(), goals={},
+        deliver_message_rt=lambda msg, marr: marr[0],
+        deliver_timer_rt=lambda node, tarr: jnp.sum(
+            jnp.where(jnp.arange(tarr.shape[0]) == node, tarr, False)))
+    masks = (np.ones(1, bool), np.zeros(proto.n_nodes, bool))
+    out = _kinds_run(proto, masks=masks)
+    assert out.end_condition == "SPACE_EXHAUSTED"
+    assert _per_level(out, "kind_skips") == _per_level(out, "chunks")
+    _never_skipping(monkeypatch)
+    ref = _kinds_run(proto, masks=masks)
+    _assert_exact(out, ref)
+    assert _per_level(out, "explored", "unique") == _per_level(
+        ref, "explored", "unique")
+    # the same twin with its timers live explores more
+    live = _kinds_run(proto, masks=(masks[0], ~masks[1]), max_depth=out.depth)
+    assert live.states_explored > out.states_explored
+
+
+def _lowered_superstep():
+    import jax.numpy as jnp
+
+    search = ShardedTensorSearch(
+        _two_clients(), make_mesh(1), chunk_per_device=16,
+        frontier_cap=1 << 9, visited_cap=1 << 12, ev_budget=(8, 1),
+        ev_spill=True)
+    return search._superstep.lower(search._carry_sds(),
+                                   jnp.asarray(1, jnp.int32))
+
+
+def _branches(lowered):
+    text = lowered.as_text()
+    return text.count("stablehlo.case") + text.count("stablehlo.if")
+
+
+def test_the_superstep_holds_a_conditional_a_kind():
+    """The skip is a device branch, not a select over both results: the
+    superstep's module carries the two conditionals, lowered and
+    compiled (a refactor that traced the kinds under ``vmap`` or a
+    ``where`` would leave none)."""
+    lowered = _lowered_superstep()
+    assert _branches(lowered) >= 2, "no branch in the lowered superstep"
+    assert lowered.compile().as_text().count(" conditional(") >= 2
+
+
 # ---------------------------------------------------- dispatch counting
 
 def _counted_run(proto, n_devices, **kw):
@@ -188,9 +346,9 @@ def test_retired_options_are_gone():
 
 def test_level_records_on_outcome():
     """Satellite: structured per-level throughput records ride the
-    outcome (depth/chunks/write_blocks/probe_cols/wall/explored/
-    unique/next_frontier) — the bench emits them as its throughput
-    series."""
+    outcome (depth/chunks/write_blocks/probe_cols/kind_skips/wall/
+    explored/unique/next_frontier) — the bench emits them as its
+    throughput series."""
     proto = _pruned_pingpong()
     mesh = make_mesh(8)
     out = ShardedTensorSearch(
@@ -199,8 +357,8 @@ def test_level_records_on_outcome():
     assert out.levels, "SearchOutcome.levels must carry per-level records"
     for i, rec in enumerate(out.levels):
         assert rec["depth"] == i + 1
-        for key in ("chunks", "write_blocks", "probe_cols", "wall",
-                    "explored", "unique", "next_frontier"):
+        for key in ("chunks", "write_blocks", "probe_cols", "kind_skips",
+                    "wall", "explored", "unique", "next_frontier"):
             assert key in rec, rec
         assert rec["chunks"] >= 1
     # Cumulative counters are monotone; the final record's totals match
